@@ -22,7 +22,6 @@ from jax import lax
 
 from apex_tpu.models import layers as L
 from apex_tpu.transformer import parallel_state as ps
-from apex_tpu.utils.compat import axis_size
 
 
 class BatchNorm2d_NHWC:
@@ -52,7 +51,7 @@ class BatchNorm2d_NHWC:
         return L.init_batchnorm(self.num_features)
 
     def _groups(self):
-        n = axis_size(self.axis_name)
+        n = lax.axis_size(self.axis_name)
         k = n if self.bn_group == 0 else self.bn_group
         if n % k:
             raise ValueError(

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.transformer import parallel_state as ps
-from apex_tpu.utils.compat import axis_size
 
 
 def halo_exchange_1d(x: jax.Array, halo: int, *, axis: int = 1,
@@ -37,7 +36,7 @@ def halo_exchange_1d(x: jax.Array, halo: int, *, axis: int = 1,
     Returns x extended to ``2*halo + x.shape[axis]`` along ``axis``:
     ``[prev-rank's last halo | x | next-rank's first halo]``.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     if halo <= 0:
         raise ValueError(f"halo must be positive, got {halo}")

@@ -6,12 +6,12 @@ with stale hand-written ``in_specs`` shards nothing the table says it
 should. This check stages the entry's builder under its mesh with
 ``jax.make_jaxpr`` (abstract — no compile, no devices touched beyond
 the CPU world) and verifies, per flattened operand, that the traced
-``shard_map`` equation's ``in_names`` equal the dim->axes mapping of
+``shard_map`` equation's ``in_specs`` give the dim->axes mapping of
 the expected ``PartitionSpec`` the builder derived from the table.
 
 It also walks the shard_map body for the classic silent failure GSPMD
-makes easy: an operand that arrives FULLY REPLICATED (empty
-``in_names``), is at least ``replication_floor`` bytes, and flows into
+makes easy: an operand that arrives FULLY REPLICATED (an empty
+spec), is at least ``replication_floor`` bytes, and flows into
 a ``dot_general`` — i.e. a weight matrix every rank stores and
 multiplies whole. Taint propagates only through layout-preserving ops
 (convert/transpose/reshape/...) and inlined calls, so the finding
@@ -33,8 +33,8 @@ _TAINT_THROUGH = {
 
 
 def spec_to_names(spec: PartitionSpec) -> dict:
-    """``shard_map``'s ``in_names`` encoding of one spec:
-    ``{dim: (axis, ...)}`` with replicated dims absent."""
+    """One spec as ``{dim: (axis, ...)}`` with replicated dims absent
+    (so ``P("x")`` and ``P("x", None)`` compare equal)."""
     names = {}
     for dim, entry in enumerate(tuple(spec)):
         if entry is None:
@@ -86,18 +86,19 @@ def check(closed, in_specs: Any, path: str, entry) -> List[Finding]:
     for eqn in jl.all_eqns(closed, into_pallas=False):
         if eqn.primitive.name != "shard_map":
             continue
-        actual = eqn.params.get("in_names")
-        if actual is None or len(actual) != len(expected):
+        specs = eqn.params.get("in_specs")
+        if specs is None or len(specs) != len(expected):
             continue  # an inner shard_map with a different signature
         matched = True
+        actual = [spec_to_names(s) for s in specs]
         for i, (want, got) in enumerate(zip(expected, actual)):
-            if dict(got) != want:
+            if got != want:
                 aval = eqn.invars[i].aval
                 findings.append(Finding(
                     "APX703", path, 1,
                     f"entry '{entry.name}': shard_map operand {i} "
                     f"(shape {tuple(getattr(aval, 'shape', ()))}) "
-                    f"traced with in_names {dict(got)} but the rule "
+                    f"traced with in_specs {got} but the rule "
                     f"table derives {want} — the staged program does "
                     f"not shard what the table says"))
 
@@ -105,9 +106,8 @@ def check(closed, in_specs: Any, path: str, entry) -> List[Finding]:
         bj = jl.open_jaxpr(body)
         floor = entry.replication_floor
         seeds = {}
-        for i, (names, bv) in enumerate(zip(eqn.params["in_names"],
-                                            bj.invars)):
-            if dict(names):
+        for i, (names, bv) in enumerate(zip(actual, bj.invars)):
+            if names:
                 continue
             nbytes = jl.aval_bytes(bv.aval)
             if nbytes >= floor:

@@ -5,7 +5,7 @@ the repo derives from it: the abstract trees it must cover (params,
 optimizer families, the serving KV cache), the hand-maintained
 reference spec trees it must reproduce, and — for train-step entries —
 a builder staging the rule-derived ``shard_map`` program whose
-``in_names`` and per-rank collective schedule are verified against the
+``in_specs`` and per-rank collective schedule are verified against the
 table. The table is data; these entries are what make a wrong table a
 lint finding instead of a silent mis-sharding on a pod slice.
 
@@ -15,7 +15,7 @@ Check dispatch per entry:
   dead rules, :mod:`rules_check`)
 - ``optimizer_families`` /
   ``reference_specs`` / ``kv_*``   -> APX702 (cross-tree consistency)
-- ``build``                        -> APX703 (in_names vs table,
+- ``build``                        -> APX703 (in_specs vs table,
   replicated-matmul floor, :mod:`propagation`) and APX704 (per-rank
   schedule + collective volume vs budgets.json,
   :mod:`schedule_check`)
@@ -290,7 +290,7 @@ def repo_entries() -> List[ShardedEntry]:
             budget_name="gpt_tiny_dp2xtp2_zero"),
         # the ROADMAP item-5 headline shape: the same rule-derived
         # builder at dp4 x tp2 on the full 8-device world, so APX703/704
-        # verify in_names and the per-rank schedule at the shape the
+        # verify in_specs and the per-rank schedule at the shape the
         # training headline will actually run (the APX9xx scaling tier
         # additionally sweeps the whole grid)
         ShardedEntry(

@@ -80,7 +80,7 @@ def _check_jaxpr(jaxpr_like, path, entry, counts, findings):
     invars = set(jaxpr.invars)
     for eqn in jaxpr.eqns:
         if eqn.primitive.name != "pallas_call":
-            if eqn.primitive.name == "pjit":
+            if eqn.primitive.name == "jit":
                 _check_donations(eqn, path, entry, counts, findings)
             for _, sub in jl.sub_jaxprs(eqn):
                 _check_jaxpr(sub, path, entry, counts, findings)

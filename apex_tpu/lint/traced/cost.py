@@ -286,7 +286,7 @@ def _walk(jaxpr_like, mult: int, in_donated: bool, in_shard_map: bool,
                 for k, v in best["coll"].items():
                     acc["coll"][k] = acc["coll"].get(k, 0) + v
             continue
-        if name == "pjit":
+        if name == "jit":
             donated = in_donated or any(
                 eqn.params.get("donated_invars") or ())
             for _, sub in jl.sub_jaxprs(eqn):
@@ -326,7 +326,7 @@ def _peak_live(jaxpr_like, inplace_out=frozenset(), depth: int = 0) -> int:
 
     immortal = {v for v in jaxpr.outvars if not jl.is_literal(v)}
     for e in jaxpr.eqns:
-        if e.primitive.name == "pjit":
+        if e.primitive.name == "jit":
             for in_idx, _ in _donation_pairs(e):
                 if not jl.is_literal(e.invars[in_idx]):
                     # the donated buffer IS the output: never released
@@ -345,7 +345,7 @@ def _peak_live(jaxpr_like, inplace_out=frozenset(), depth: int = 0) -> int:
     for i, e in enumerate(jaxpr.eqns):
         inplace_idx = set()
         extra = 0
-        if e.primitive.name == "pjit":
+        if e.primitive.name == "jit":
             pairs = _donation_pairs(e)
             inplace_idx = {oi for _, oi in pairs}
             body = e.params.get("jaxpr")
@@ -399,7 +399,7 @@ def compute(closed, path: str, entry: str) -> CostReport:
     # through layout-preserving views to the jaxpr outvars
     inplace = set()
     for e in jaxpr.eqns:
-        if e.primitive.name == "pjit":
+        if e.primitive.name == "jit":
             for _, out_idx in _donation_pairs(e):
                 inplace.add(e.outvars[out_idx])
     changed = True

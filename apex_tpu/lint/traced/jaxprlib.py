@@ -19,7 +19,7 @@ from typing import Iterator, List, Tuple
 
 
 def _jaxpr_types():
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     return Jaxpr, ClosedJaxpr
 
@@ -52,7 +52,7 @@ def all_eqns(jaxpr, *, into_pallas: bool = True) -> Iterator[object]:
 
 
 def is_literal(v) -> bool:
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     return isinstance(v, Literal)
 

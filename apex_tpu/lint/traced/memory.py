@@ -103,7 +103,7 @@ _FUSIBLE = {
     "reduce_or", "cumsum", "cumprod", "cumlogsumexp", "argmax", "argmin",
     "reduce_precision", "broadcast_in_dim", "reshape", "squeeze",
     "expand_dims", "transpose", "rev", "slice", "dynamic_slice", "copy",
-    "stop_gradient", "pjit", "remat", "remat2", "checkpoint", "nextafter",
+    "stop_gradient", "jit", "remat", "remat2", "checkpoint", "nextafter",
     "square", "add_any", "mul_add", "real", "imag", "device_put",
 }
 

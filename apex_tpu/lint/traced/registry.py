@@ -23,9 +23,8 @@ Builder conventions:
 
 The registry needs the 8-virtual-device CPU world the test rig uses
 (pipeline/TP/context entries shard over it); ``ensure_cpu_devices``
-arranges that BEFORE first backend use, falling back to ``XLA_FLAGS``
-on older jax, and degrades to APX100 findings for mesh entries when the
-backend was already initialized too small.
+arranges that BEFORE first backend use, and degrades to APX100 findings
+for mesh entries when the backend was already initialized too small.
 """
 
 import functools
@@ -58,21 +57,13 @@ def ensure_cpu_devices(n: int = _DEFAULT_DEVICES) -> int:
     the equivalent). Afterwards it is a no-op and the caller sees the
     actual device count.
     """
-    import os
-
     import jax
 
     try:
         jax.config.update("jax_platforms", "cpu")
-    except Exception:  # noqa: BLE001 - backend already up; keep going
-        pass
-    try:
         jax.config.update("jax_num_cpu_devices", n)
-    except Exception:  # noqa: BLE001 - older jax: XLA flag, read at init
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count={n}")
+    except RuntimeError:
+        pass  # backend already initialized: the caller sees its world
     return jax.device_count()
 
 
@@ -1268,7 +1259,7 @@ def zero_parts(dp: int = 2, tp: int = 2):
     rule-table-sharded GPT train step, dp x tp, ZeRO optimizer state
     (bf16 m) row-sharded over ``(model, data)`` jointly. Returns
     ``(fn, args, in_specs)`` — the spec tree is consumed by the APX7xx
-    sharded tier (APX703 checks the shard_map in_names against it), the
+    sharded tier (APX703 checks the shard_map in_specs against it), the
     ``(fn, args)`` pair by the plain trace/cost tiers, and the APX9xx
     scaling tier re-stages this builder at every swept ``(dp, tp)``
     shape. Everything sharded here derives from

@@ -33,7 +33,10 @@ Two usage styles:
 
 2. whole-program GSPMD: just shard the batch with
    ``ddp.shard_batch(batch)`` and jit — XLA inserts the same reduction
-   (summed, so divide the loss, not the grads, for averaging).
+   (summed, so divide the loss, not the grads, for averaging). Only for
+   programs with no Pallas kernel in them: on more than one real chip
+   XLA cannot partition a Mosaic kernel and the jit fails to lower; a
+   model that uses the fused kernels takes style 1.
 """
 
 from typing import Any, Optional
@@ -64,15 +67,8 @@ class DistributedDataParallel:
         """Per-rank replica of replicated params (call inside shard_map
         before taking grads) — the torch "module replica" of the
         reference; see the module docstring for why this is load-bearing."""
-        pcast = getattr(lax, "pcast", None)
-        if pcast is None:
-            # jax without varying-axes tracking: ps.shard_map runs with
-            # check_rep=False there, so replicated inputs are already
-            # plain per-rank values and the broadcast transpose inserts
-            # no psum — the identity IS the per-rank replica.
-            return params
         return jax.tree.map(
-            lambda p: pcast(p, self.axis_name, to="varying"), params)
+            lambda p: lax.pcast(p, self.axis_name, to="varying"), params)
 
     def allreduce_grads(self, grads: Any) -> Any:
         """psum grads over the data axis (call inside shard_map/pmap).

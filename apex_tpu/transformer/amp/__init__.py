@@ -10,22 +10,17 @@ from jax import lax
 
 from apex_tpu.amp.scaler import LossScaler, LossScalerState  # noqa: F401
 from apex_tpu.transformer import parallel_state as ps
-from apex_tpu.utils.compat import axis_size
 
 
 def _axis_is_bound(name: str) -> bool:
     """True iff ``name`` is a mapped axis in the current trace context.
 
-    Probes with ``utils.compat.axis_size`` (``lax.axis_size`` where it
-    exists: pure trace-time metadata — unlike the earlier private
-    ``jax._src.core.get_axis_env`` query it adds nothing to the jaxpr
-    and touches no internals; the older-jax fallback is a psum-of-1
-    probe that constant-folds at trace time). The unbound case is a
-    trace-time ``NameError`` either way, so no runtime branch is
-    compiled.
+    Probes with ``lax.axis_size``: pure trace-time metadata — it adds
+    nothing to the jaxpr and touches no internals. The unbound case is
+    a trace-time ``NameError``, so no runtime branch is compiled.
     """
     try:
-        axis_size(name)
+        lax.axis_size(name)
         return True
     except NameError:
         # the unbound-axis trace error; anything else must propagate —

@@ -39,7 +39,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.transformer import parallel_state as ps
-from apex_tpu.utils.compat import axis_size
 
 _NEG = -1e30
 
@@ -67,7 +66,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     if softmax_scale is None:
         softmax_scale = 1.0 / (q.shape[-1] ** 0.5)
-    cp = axis_size(axis_name)
+    cp = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     b, h, s_loc, d = q.shape
     perm = _ring_perm(cp)
@@ -161,7 +160,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     Returns (b, h, s_local, d) in q's dtype.
     """
-    cp = axis_size(axis_name)
+    cp = lax.axis_size(axis_name)
     b, h, s_loc, d = q.shape
     if h % cp:
         raise ValueError(

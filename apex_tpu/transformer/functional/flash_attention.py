@@ -88,8 +88,8 @@ def _causal_live(qt, kt, bq, bk):
 
 
 # Causal tile-skipping toggles. Measured on v5e (seq 2048, d 64, fwd+bwd,
-# several same-process A/B sweeps; cross-process numbers drift +-20% with
-# relay conditions): gating whole tiles behind pl.when costs MORE than the
+# several same-process A/B sweeps): gating whole tiles behind pl.when
+# costs MORE than the
 # skipped matmuls save (the kernels are VPU-bound, and the per-tile
 # control flow defeats Mosaic's copy/compute overlap), and index-map
 # clamping adds further cost. The win that did land is the mask-free
@@ -102,8 +102,8 @@ _DIM_SEMANTICS = True
 
 # VPU-diet toggles (see module docstring). Same contract as the causal
 # toggles above: module-level so `bench.py ab` can trace a legacy-variant
-# callable against the default one IN THE SAME PROCESS — the only
-# comparison that resolves <20% effects on a relay-attached rig. Flip via
+# callable against the default one IN THE SAME PROCESS, where both sides
+# share whatever drifts between processes. Flip via
 # `kernel_variant(...)`; the toggles are read at TRACE time, so a
 # callable must be traced (first call / warmup) inside the context.
 _EXP2 = True    # base-2 online softmax, log2e folded into the q prescale

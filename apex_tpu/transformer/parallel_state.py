@@ -151,17 +151,9 @@ def shard_map(f, *, mesh: Optional[Mesh] = None, in_specs, out_specs,
       for replicated params) — the torch model the reference's DDP and TP
       layers are written against; collectives stay explicit.
     """
-    # jax promoted shard_map out of experimental and renamed check_rep ->
-    # check_vma along the way; support both so this imports on every rig
-    # (CI pins an older jax than the driver).
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        kwargs["check_rep"] = kwargs.pop("check_vma", False)
-    else:
-        kwargs.setdefault("check_vma", False)
-    return sm(f, mesh=mesh or get_mesh(), in_specs=in_specs,
-              out_specs=out_specs, **kwargs)
+    kwargs.setdefault("check_vma", False)
+    return jax.shard_map(f, mesh=mesh or get_mesh(), in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 # ---------------------------------------------------------------------------

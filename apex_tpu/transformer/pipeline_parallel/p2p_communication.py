@@ -36,7 +36,6 @@ import jax
 from jax import lax
 
 from apex_tpu.transformer import parallel_state as ps
-from apex_tpu.utils.compat import axis_size
 
 
 def _ring(n: int, step: int):
@@ -45,7 +44,7 @@ def _ring(n: int, step: int):
 
 def _shift(x: Any, step: int) -> Any:
     """ppermute every leaf of ``x`` by ``step`` stages along ``pipe``."""
-    n = axis_size(ps.PIPE_AXIS)
+    n = lax.axis_size(ps.PIPE_AXIS)
     perm = _ring(n, step)
     return jax.tree.map(lambda a: lax.ppermute(a, ps.PIPE_AXIS, perm), x)
 

@@ -73,7 +73,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.transformer import parallel_state as ps
-from apex_tpu.utils.compat import axis_size
 from apex_tpu.transformer.pipeline_parallel import microbatches as mb_calc
 from apex_tpu.transformer.pipeline_parallel.p2p_communication import (
     send_backward_recv_backward,
@@ -237,7 +236,7 @@ def forward_backward_pipelining_without_interleaving(
     del checkpoint_stages  # recompute-from-saved-input is inherent
     M = _num_microbatches(num_microbatches)
     mbs = split_batch_into_microbatches(batch, M)
-    pp = axis_size(ps.PIPE_AXIS)
+    pp = lax.axis_size(ps.PIPE_AXIS)
     d = lax.axis_index(ps.PIPE_AXIS)
     stage = model.stage_fn
     stage_p = jax.tree.map(lambda a: a[0], params["stages"])
@@ -366,7 +365,7 @@ def forward_backward_pipelining_with_interleaving(
     del checkpoint_stages  # recompute-from-saved-input is inherent
     M = _num_microbatches(num_microbatches)
     mbs = split_batch_into_microbatches(batch, M)
-    pp = axis_size(ps.PIPE_AXIS)
+    pp = lax.axis_size(ps.PIPE_AXIS)
     d = lax.axis_index(ps.PIPE_AXIS)
     stage = model.stage_fn
     stage_p = jax.tree.map(lambda a: a[:, 0], params["stages"])  # (vpp,...)

@@ -27,13 +27,12 @@ import jax
 from jax import lax
 
 from apex_tpu.transformer import parallel_state as ps
-from apex_tpu.utils.compat import axis_size
 
 _AXIS = ps.TENSOR_AXIS
 
 
 def _tp_size():
-    return axis_size(_AXIS)
+    return lax.axis_size(_AXIS)
 
 
 def _split_along(x, dim):

@@ -1,6 +1,5 @@
 from apex_tpu.utils.platform import (  # noqa: F401
     has_tpu,
-    interpret_default,
     pallas_interpret,
 )
 from apex_tpu.utils.math import (  # noqa: F401

@@ -37,8 +37,4 @@ def dimsem(*sem):
     one core's partial writes."""
     from jax.experimental.pallas import tpu as pltpu
 
-    # jax renamed TPUCompilerParams -> CompilerParams; support both so the
-    # kernels import on every rig (CI pins an older jax than the driver)
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)

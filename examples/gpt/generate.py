@@ -30,6 +30,7 @@ from apex_tpu.models.gpt import GPTConfig, init_gpt  # noqa: E402
 from apex_tpu.serving import (  # noqa: E402
     ContinuousBatchingScheduler, DecodeEngine, Request,
 )
+from apex_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def parse_args():
@@ -61,6 +62,7 @@ def parse_args():
 
 
 def main():
+    enable_compile_cache()
     ns = parse_args()
     cfg = GPTConfig(
         vocab_size=ns.vocab_size, hidden_size=ns.hidden_size,

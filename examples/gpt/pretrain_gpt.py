@@ -38,6 +38,7 @@ from apex_tpu.transformer.pipeline_parallel.schedules import (  # noqa: E402
     forward_backward_pipelining_without_interleaving,
 )
 from apex_tpu.transformer.testing import arguments  # noqa: E402
+from apex_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def extra_flags(p):
@@ -52,6 +53,7 @@ def extra_flags(p):
 
 
 def main():
+    enable_compile_cache()
     ns = arguments.parse_args(extra_args_provider=extra_flags)
     tp_sz, pp = ns.tensor_model_parallel_size, \
         ns.pipeline_model_parallel_size

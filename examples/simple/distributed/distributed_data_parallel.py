@@ -28,6 +28,7 @@ from apex_tpu import amp  # noqa: E402
 from apex_tpu.optimizers import FusedSGD  # noqa: E402
 from apex_tpu.parallel import DistributedDataParallel  # noqa: E402
 from apex_tpu.transformer import parallel_state as ps  # noqa: E402
+from apex_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def parse_args():
@@ -45,6 +46,7 @@ def parse_args():
 
 
 def main():
+    enable_compile_cache()
     args = parse_args()
     dp = args.dp or jax.device_count()
     mesh = ps.initialize_model_parallel(devices=jax.devices()[:dp])

@@ -1,16 +1,13 @@
-"""Driver-contract tests for ``__graft_entry__``.
-
-Round-1 postmortem (VERDICT.md "What's weak" #1): the driver imports the
-module and calls ``dryrun_multichip(8)`` directly — it does NOT run the
-``__main__`` block — and in r01 that path failed because the n-device CPU
-world was only configured under ``__main__``. These tests exercise the
-function exactly the way the driver does, in-process and in a fresh
-interpreter with a pre-initialized too-small backend.
+"""Driver-contract tests for ``__graft_entry__``: the driver imports the
+module and calls ``dryrun_multichip(n)`` directly (it does NOT run the
+``__main__`` block), so the function is exercised the same way here, on
+the devices the process already has.
 """
 
 import os
-import subprocess
 import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -36,24 +33,22 @@ def test_entry_returns_jittable():
         sys.path.remove(REPO)
 
 
-def test_dryrun_multichip_resets_small_world():
-    """Simulate the exact r01 failure: JAX already initialized with ONE
-    device when ``dryrun_multichip(8)`` is called. The function must tear
-    down and rebuild an 8-device world itself."""
-    code = (
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        "assert len(jax.devices()) == 1, jax.devices()\n"
-        "import __graft_entry__\n"
-        # phases=1: only the world-reset contract is under test here;
-        # the in-process test runs every phase
-        "__graft_entry__.dryrun_multichip(8, phases=1)\n"
-    )
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env.pop("JAX_NUM_CPU_DEVICES", None)
-    proc = subprocess.run(
-        [sys.executable, "-c", code], cwd=REPO, env=env,
-        capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    assert "OK" in proc.stdout, proc.stdout
+def test_dryrun_multichip_raises_on_too_few_devices():
+    """Too few devices is a clear error naming the count and the platform
+    — never a teardown, a re-exec or a move to another platform."""
+    sys.path.insert(0, REPO)
+    try:
+        import __graft_entry__
+        import jax
+        have = jax.device_count()
+        with pytest.raises(RuntimeError,
+                           match=rf"needs {have + 1} devices.*found {have}"
+                                 r".*'cpu'"):
+            __graft_entry__.dryrun_multichip(have + 1)
+        assert jax.device_count() == have
+        with open(os.path.join(REPO, "__graft_entry__.py")) as fh:
+            src = fh.read()
+        for gone in ("clear_backends", "subprocess", "jax_platforms"):
+            assert gone not in src, gone
+    finally:
+        sys.path.remove(REPO)
