@@ -105,10 +105,17 @@ class Amp:
         return self.scaler.update_scale(state, found_inf)
 
     def value_and_grad(
-        self, loss_fn: Callable, has_aux: bool = False, **grad_kwargs
+        self, loss_fn: Callable, has_aux: bool = False,
+        reduce_grads: Optional[Callable] = None, **grad_kwargs
     ) -> Callable:
         """Scaled value_and_grad: computes grads of the *scaled* loss,
         unscales them, and advances the scaler state.
+
+        ``reduce_grads`` (e.g. ``DistributedDataParallel.allreduce_grads``
+        inside ``shard_map``) runs on the still-SCALED grads, before the
+        overflow check — the reference's order, where the allreduce fires
+        during backward. Every replica then unscales the same grads, so
+        ``found_inf`` and the scaler state cannot differ between them.
 
         Returned callable: ``(params, state, *args, **kw) ->
         (value, grads, found_inf, new_state)`` where ``value`` is the
@@ -126,6 +133,8 @@ class Amp:
             (_, (loss, aux)), grads = jax.value_and_grad(
                 scaled_loss_fn, has_aux=True, **grad_kwargs
             )(params, *args, **kw)
+            if reduce_grads is not None:
+                grads = reduce_grads(grads)
             grads, found_inf = self.scaler.unscale(grads, state)
             new_state = self.scaler.update_scale(state, found_inf)
             value = (loss, aux) if has_aux else loss

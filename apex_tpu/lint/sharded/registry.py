@@ -217,31 +217,9 @@ def _draft_reference():
             "kv_cache": cache_partition_specs(kv_cache_rules())}
 
 
-def _bert_trees():
-    import jax
-
-    from apex_tpu.models.bert import bert_tiny, init_bert
-
-    params = jax.eval_shape(
-        lambda k: init_bert(k, bert_tiny()), jax.random.PRNGKey(0))
-    return {"params": params}
-
-
-def _bert_reference():
-    import jax
-
-    from apex_tpu.models.bert import (
-        bert_partition_specs, bert_tiny, init_bert,
-    )
-
-    params = jax.eval_shape(
-        lambda k: init_bert(k, bert_tiny()), jax.random.PRNGKey(0))
-    return {"params": bert_partition_specs(params)}
-
-
 def repo_entries() -> List[ShardedEntry]:
     from apex_tpu.partition import (
-        bert_rules, draft_gpt_rules, gpt_quant_rules, gpt_rules,
+        draft_gpt_rules, gpt_quant_rules, gpt_rules,
     )
 
     return [
@@ -273,11 +251,6 @@ def repo_entries() -> List[ShardedEntry]:
             reference_specs=_draft_reference,
             kv_cache_tree="kv_cache",
             qkv_kernel_re=r"layers/qkv/kernel"),
-        ShardedEntry(
-            "bert_tiny_rules", "apex_tpu.partition.tables",
-            rules=bert_rules, trees=_bert_trees,
-            reference_specs=_bert_reference,
-            optimizer_families=("m", "v", "master")),
         # trace-staged: same builder as the gpt_tiny_dp2xtp2_zero
         # TraceEntry, so APX703/704 see exactly the program the APX5xx
         # and APX6xx tiers gate

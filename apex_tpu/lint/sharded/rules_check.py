@@ -15,7 +15,7 @@ identical per tensor family: optimizer moments / master weights
 root-anchored pattern shows up as drift), the serving KV cache's head
 axis against the attention qkv weights' tensor-parallel axis, and the
 rule-derived spec tree against the hand-maintained reference
-(``gpt_partition_specs``/``bert_partition_specs``) where one is
+(``gpt_partition_specs``) where one is
 registered. A flipped axis in one rule fires here before it ever
 reaches a pod slice.
 """

@@ -1,12 +1,12 @@
 """APX6xx cost tier — abstract HBM-traffic / communication / FLOP
 interpreter over registered trace entries.
 
-Every headline claim in BASELINE.md is a roofline argument: r7 prices
-the optimizer ladder in GB/step, r8 derives the decode tokens/s ceiling
-from a ~2.3 GB/step HBM read. A jaxpr is a complete statement of what a
-step reads, writes, and communicates, so this module *computes* those
-bytes per registered entrypoint and ``budgets.py`` gates them against a
-committed manifest (APX601-604).
+The repo's byte claims are roofline arguments: the optimizer ladder is
+priced in GB/step, the decode tokens/s ceiling follows from a ~2.3
+GB/step HBM read. A jaxpr is a complete statement of what a step reads,
+writes, and communicates, so this module *computes* those bytes per
+registered entrypoint and ``budgets.py`` gates them against a committed
+manifest (APX601-604).
 
 The cost model, per entry (all numbers static, from abstract shapes):
 
@@ -23,7 +23,7 @@ The cost model, per entry (all numbers static, from abstract shapes):
   counts. A donated KV cache therefore counts once (its read), not
   twice. Pallas ``input_output_aliases`` outputs deliberately still
   charge the full write: the kernel physically rewrites every byte of
-  the aliased buffer (r7's flat-optimizer hand math reads g+p+m+v and
+  the aliased buffer (the flat optimizer reads g+p+m+v and
   writes p+m+v — aliasing saves the *allocation*, not the traffic).
 - **peak live bytes** — a liveness walk over equation order: inputs
   start resident, each equation's outputs join the live set (donation-

@@ -736,9 +736,8 @@ def _page_handoff_medium_entry():
     full prompt's tiles (8 pages x 64 tokens = a 512-token prompt)
     into the ragged medium pool (32 slots, s_max 512, page 64, bf16).
     The donated in-place scatter prices the handoff at ~the shipped
-    tile bytes (2 x L x H x page x head_dim x 2 per page), which is
-    what the BASELINE r15 verdict compares against a decode step's
-    parameter read — the bytes disaggregation moves once per prompt to
+    tile bytes (2 x L x H x page x head_dim x 2 per page), to be set
+    against a decode step's parameter read — the bytes disaggregation moves once per prompt to
     unblock every co-tenant decode tick."""
     def build():
         import functools as ft
@@ -809,8 +808,8 @@ def _page_spill_extract_medium_entry():
     (32 slots, s_max 512, page 64, bf16) on their way to the
     :class:`~apex_tpu.serving.paging.PrefixRegistry`. The gather
     prices a spill at ~the page tile bytes, the same per-page unit the
-    r15 handoff pins — BASELINE r16 compares this against a decode
-    step's parameter read to justify ``promote_ticks_per_page``."""
+    handoff entry pins; set against a decode step's parameter read it
+    is the reasoning behind ``promote_ticks_per_page``."""
     def build():
         import functools as ft
 
@@ -840,7 +839,7 @@ def _page_promote_insert_quant_medium_entry():
     prompt's quantized tiles plus their per-page-per-head scale planes
     back into HBM. The int8 payload is half the bf16 handoff's bytes
     (the scale planes are noise: L x n x H fp32 values per side), which
-    is the capacity-doubling arithmetic BASELINE r16 banks for BOTH
+    is the capacity-doubling arithmetic of BOTH
     tiers — the registry budgets bytes, so kv8 doubles its page count
     exactly as it does HBM's."""
     def build():
@@ -952,12 +951,12 @@ def _spec_verify_step_entry(tp=None):
 
 
 def _spec_verify_step_medium_ragged_entry():
-    """The verify step at the r10 ragged medium shape (32 slots, bf16
+    """The verify step at the ragged medium shape (32 slots, bf16
     params, uniform 32..512 ladder), k+1 = 4 positions per slot —
     cost-tier only. Its budgets.json row divided by the expected
     committed tokens per slot at the bench acceptance rate is the
-    bytes/accepted-token headline BASELINE.md r11 prices against the
-    plain-decode ``model_bytes_per_token``."""
+    bytes per accepted token, to set against the plain-decode
+    ``model_bytes_per_token``."""
     def build():
         import functools as ft
 
@@ -1009,11 +1008,11 @@ def _tree_verify_step_entry(tp=None):
 
 
 def _draft_forward_step_entry():
-    """The r13 draft-forward anchor: ``draft_gpt_medium`` decoding one
+    """The draft-forward anchor: ``draft_gpt_medium`` decoding one
     greedy token per slot through its dense lockstep cache — 32 slots
     at the target's s_max = 512 plus DraftModel's chunk = 5 catch-up
     headroom, bf16 params. Its budgets.json row is the ``draft_bytes``
-    numerator of the BASELINE r13 break-even condition; the ceiling is
+    numerator of the model-draft break-even condition; the ceiling is
     hand-tightened to < 3% of the target's per-step parameter read
     (the ``gpt_paged_decode_step_medium_ragged`` row)."""
     def build():
@@ -1128,10 +1127,10 @@ def _w8_decode_step_tp2_entry():
 
 
 def _quant_paged_decode_medium_ragged_entry():
-    """The r12 quantized twin of the ragged medium paged decode: int8
+    """The quantized twin of the ragged medium paged decode: int8
     params (fp32 scales) + int8 page pool at the identical ladder —
     its budgets.json row pins the halved byte claim (≤ 0.95 GB/step vs
-    1.68 GB bf16, BASELINE.md r12). Cost-tier only."""
+    1.68 GB bf16). Cost-tier only."""
     def build():
         import functools as ft
 
@@ -1162,9 +1161,9 @@ def _quant_paged_decode_medium_ragged_entry():
 
 
 def _decode_step_medium_entry():
-    """The BASELINE.md r8 roofline shape: gpt_medium-class decode, bf16
-    params, 32 slots parked at depth 512 (the steady-state mid-cache
-    occupancy the hand derivation prices). Cost-tier only — APX5xx
+    """The decode roofline shape: gpt_medium-class decode, bf16
+    params, 32 slots parked at depth 512 (steady-state mid-cache
+    occupancy). Cost-tier only — APX5xx
     already runs on the tiny-shape decode entries."""
     def build():
         import functools as ft
@@ -1498,20 +1497,20 @@ def repo_entries() -> List[TraceEntry]:
                    _tree_verify_step_entry(tp=2),
                    checks=("precision", "memory", "schedule", "aliases"),
                    mesh=_mesh(tp=2), min_devices=2, min_alias_pairs=4),
-        # cost-tier anchor for the BASELINE r8/r9 decode roofline; no
+        # cost-tier anchor for the decode roofline; no
         # APX5xx checks (the tiny-shape decode entries above carry them
         # — this one exists so budgets.json pins the headline bytes)
         TraceEntry("gpt_decode_step_medium", "apex_tpu.serving.decode",
                    _decode_step_medium_entry(), checks=()),
-        # r10: ragged-length paged pool at the same model shape — its
+        # ragged-length paged pool at the same model shape — its
         # budgets.json row demonstrates the K/V-read cut vs the dense
-        # slots x S_max charge above (BASELINE.md r10)
+        # slots x S_max charge above
         TraceEntry("gpt_paged_decode_step_medium_ragged",
                    "apex_tpu.serving.decode",
                    _paged_decode_step_medium_ragged_entry(), checks=()),
-        # r11: the verify step at the same ragged shape — one parameter
+        # the verify step at the same ragged shape — one parameter
         # read priced over k+1 candidate positions; budgets.json pins
-        # the bytes/accepted-token headline (BASELINE.md r11)
+        # the bytes per accepted token
         TraceEntry("gpt_spec_verify_step_medium_ragged",
                    "apex_tpu.serving.decode",
                    _spec_verify_step_medium_ragged_entry(), checks=()),
@@ -1549,9 +1548,9 @@ def repo_entries() -> List[TraceEntry]:
                    "apex_tpu.serving.transfer",
                    _page_promote_insert_quant_medium_entry(),
                    checks=()),
-        # r13: the model drafter's per-token forward at the medium
+        # the model drafter's per-token forward at the medium
         # shape — the draft_bytes numerator of the break-even condition
-        # (BASELINE.md r13); its hand-tightened ceiling pins the draft
+        # (docs/source/serving.rst); its hand-tightened ceiling pins the draft
         # under 3% of the target parameter read. The dense-cache
         # donation (3 leaves) rides along.
         TraceEntry("gpt_draft_forward_step",

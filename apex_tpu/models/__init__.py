@@ -11,7 +11,6 @@ from apex_tpu.models.bert import (  # noqa: F401
     apply_bert,
     bert_base,
     bert_large,
-    bert_partition_specs,
     bert_tiny,
     init_bert,
     mlm_loss,

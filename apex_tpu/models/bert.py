@@ -219,37 +219,3 @@ def mlm_loss(logits: jax.Array, labels: jax.Array,
                                         flat_labels)
     m = label_mask.astype(jnp.float32)
     return losses.sum() / jnp.maximum(m.sum(), 1.0)
-
-
-def bert_partition_specs(params: Dict[str, Any]):
-    """Megatron-style PartitionSpecs for a BERT param tree over the global
-    mesh axes (ref layout: ``apex/transformer/tensor_parallel/layers.py`` —
-    qkv/fc1 column-sharded, out/fc2 row-sharded, embeddings vocab-sharded).
-
-    Used by pjit/GSPMD sharding of the whole-model path; the explicit
-    shard_map TP layers (phase 7) reproduce the same layout per-layer.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
-    tp = ps.TENSOR_AXIS
-
-    def spec_for(path) -> P:
-        keys = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
-        joined = "/".join(keys)
-        name = keys[-1]
-        if "layernorm" in joined or name == "bias" and "mlm_head" in joined:
-            return P()
-        if "word" in joined and name == "embedding":
-            return P(tp, None)          # vocab-sharded
-        if name == "embedding":
-            return P()                   # position / token-type replicated
-        if "qkv" in joined or "fc1" in joined:
-            return P(None, tp) if name == "kernel" else P(tp)
-        if ("attention/out" in joined or "fc2" in joined) and name == "kernel":
-            return P(tp, None)           # row-parallel
-        return P()
-
-    return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: spec_for(path), params)

@@ -123,8 +123,8 @@ def draft_gpt_medium() -> GPTConfig:
     """Draft model pairing :func:`gpt_medium` — the cost-model config
     behind the ``gpt_draft_forward_step`` budget entry: its per-step HBM
     traffic (params + draft cache) must stay under 3% of the target's
-    per-step parameter read, the amortization condition BASELINE r13
-    derives for model-draft break-even.
+    per-step parameter read, the amortization condition for model-draft
+    break-even.
 
     ``num_heads=4`` (head_dim 32), not 2: the drafter shares the
     target's pod slice, so its KV-cache head axis must divide every

@@ -15,28 +15,28 @@ job. What survives of the reference API is the numerics policy:
 - ``delay_allreduce`` — moot (there is one fused reduction anyway), kept
   as an accepted no-op for signature parity.
 
-Two usage styles:
+Use it inside ``shard_map`` over the data axis — the one multi-chip route
+of this package (a plain ``jit`` over batch-sharded inputs cannot carry the
+fused kernels: XLA does not partition a Mosaic kernel)::
 
-1. inside ``shard_map`` over the data axis (closest to the reference)::
+    ddp = DistributedDataParallel()
+    replica = ddp.local_replica(params)  # per-rank replica (torch-style)
+    grads = jax.grad(loss)(replica, shard_of_batch)
+    grads = ddp.allreduce_grads(grads)   # psum over "data"
 
-       ddp = DistributedDataParallel()
-       replica = ddp.local_replica(params)  # per-rank replica (torch-style)
-       grads = jax.grad(loss)(replica, shard_of_batch)
-       grads = ddp.allreduce_grads(grads)   # psum over "data"
+With amp, hand the reduction to the scaler so it runs on the scaled grads
+before the overflow check, as the reference's backward hooks do, and
+every replica takes the same skip decision::
 
-   ``local_replica`` matters under shard_map's varying-axes semantics:
-   differentiating w.r.t. a REPLICATED (unvarying) input makes JAX insert
-   the cross-axis psum itself (the transpose of the implicit broadcast),
-   so grads arrive pre-summed and another allreduce would double-count.
-   ``pcast(..., to='varying')`` gives each rank its own replica — exactly
-   the torch DDP model — leaving the reduction to this wrapper.
+    loss, grads, found_inf, scaler = h.value_and_grad(
+        loss_fn, reduce_grads=ddp.allreduce_grads)(replica, scaler)
 
-2. whole-program GSPMD: just shard the batch with
-   ``ddp.shard_batch(batch)`` and jit — XLA inserts the same reduction
-   (summed, so divide the loss, not the grads, for averaging). Only for
-   programs with no Pallas kernel in them: on more than one real chip
-   XLA cannot partition a Mosaic kernel and the jit fails to lower; a
-   model that uses the fused kernels takes style 1.
+``local_replica`` matters under shard_map's varying-axes semantics:
+differentiating w.r.t. a REPLICATED (unvarying) input makes JAX insert
+the cross-axis psum itself (the transpose of the implicit broadcast),
+so grads arrive pre-summed and another allreduce would double-count.
+``pcast(..., to='varying')`` gives each rank its own replica — exactly
+the torch DDP model — leaving the reduction to this wrapper.
 """
 
 from typing import Any, Optional
@@ -44,7 +44,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import NamedSharding, PartitionSpec
 
 from apex_tpu.transformer import parallel_state as ps
 
@@ -62,7 +61,6 @@ class DistributedDataParallel:
         self.gradient_average = gradient_average
         self.axis_name = axis_name or ps.DATA_AXIS
 
-    # -- shard_map style ------------------------------------------------
     def local_replica(self, params: Any) -> Any:
         """Per-rank replica of replicated params (call inside shard_map
         before taking grads) — the torch "module replica" of the
@@ -109,17 +107,3 @@ class DistributedDataParallel:
             return lax.psum(masked, axis)
 
         return jax.tree.map(bcast, params)
-
-    # -- GSPMD style ----------------------------------------------------
-    def shard_batch(self, batch: Any, mesh=None) -> Any:
-        """Place a global batch sharded over the data axis (leading dim)."""
-        mesh = mesh or ps.get_mesh()
-        spec = PartitionSpec(self.axis_name)
-        return jax.tree.map(
-            lambda x: jax.device_put(x, NamedSharding(mesh, spec)), batch)
-
-    def replicate(self, tree: Any, mesh=None) -> Any:
-        mesh = mesh or ps.get_mesh()
-        spec = PartitionSpec()
-        return jax.tree.map(
-            lambda x: jax.device_put(x, NamedSharding(mesh, spec)), tree)

@@ -2,7 +2,7 @@
 
 ``match_partition_rules`` turns an ordered ``(pattern, PartitionSpec)``
 table into the spec pytree for any parameter-shaped tree;
-``gpt_rules``/``bert_rules`` are the default Megatron-layout tables;
+``gpt_rules`` is the default Megatron-layout table;
 ``optimizer_state_specs`` re-derives moment/master-weight specs from
 the same table; ``make_shard_and_gather_fns`` materializes per-leaf
 placement closures; ``make_mesh`` builds the dp x tp x pp x cp mesh
@@ -23,7 +23,6 @@ from apex_tpu.partition.rules import (
     tree_paths,
 )
 from apex_tpu.partition.tables import (
-    bert_rules,
     draft_gpt_rules,
     gpt_quant_rules,
     gpt_rules,
@@ -32,7 +31,6 @@ from apex_tpu.partition.tables import (
 )
 
 __all__ = [
-    "bert_rules",
     "draft_gpt_rules",
     "gpt_quant_rules",
     "gpt_rules",

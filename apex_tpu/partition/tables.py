@@ -1,4 +1,4 @@
-"""Default partition-rule tables (GPT / BERT / serving KV cache).
+"""Default partition-rule tables (GPT / serving KV cache).
 
 One table per model family covers everything that family shards: the
 parameter tree, the optimizer moments/master weights derived from it
@@ -8,9 +8,8 @@ GPT — the serving KV cache
 ``KVCache`` template against the same table). The tables are written
 OVERLAP-FREE: every leaf matches exactly one rule, which APX701
 enforces for each registered tree, and the layouts reproduce the
-hand-maintained references (``models.gpt.gpt_partition_specs``,
-``models.bert.bert_partition_specs``) that APX702 cross-checks them
-against.
+hand-maintained reference (``models.gpt.gpt_partition_specs``) that
+APX702 cross-checks them against.
 
 Layout recap (Megatron over the ``model`` mesh axis):
 
@@ -115,28 +114,6 @@ def draft_gpt_rules():
     draft configs (``models.gpt.draft_gpt_tiny``/``draft_gpt_medium``)
     are RoPE-only — no ``embedding/position`` leaf — and the lockstep
     draft cache is DENSE (``KVCache``: k/v/lengths, no block tables).
-    A rule that can never match would be an APX701 dead-rule finding
-    (the BERT table's KV-cache omission, same reasoning)."""
+    A rule that can never match would be an APX701 dead-rule finding."""
     dead = ("embedding/position/embedding", r"(^|/)block_tables$")
     return tuple(rule for rule in gpt_rules() if rule[0] not in dead)
-
-
-def bert_rules():
-    """Rule table for the BERT param tree (``models.bert.init_bert``).
-    BERT layers are a list (paths carry ``encoder/<i>/``), so patterns
-    stay unanchored; layer norms everywhere replicate via one rule."""
-    t = ps.TENSOR_AXIS
-    return (
-        ("embeddings/word/embedding", P(t, None)),
-        ("embeddings/(position|token_type)/embedding", P()),
-        ("layernorm/(weight|bias)", P()),
-        ("(qkv|fc1)/kernel", P(None, t)),
-        ("(qkv|fc1)/bias", P(t)),
-        ("(attention/out|fc2)/kernel", P(t, None)),
-        ("(attention/out|fc2)/bias", P()),
-        ("mlm_head/transform/(kernel|bias)", P()),
-        ("mlm_head/bias", P()),
-        ("pooler/(kernel|bias)", P()),
-        # no KV-cache rules: BERT is not served incrementally, and a
-        # rule that can never match would be an APX701 dead-rule finding
-    )

@@ -6,8 +6,8 @@ m̄ = 1 on non-repetitive text. This module runs a LEARNED draft model
 — a 2–4 layer GPT sharing the target's vocab (``models.draft_gpt_tiny``
 pairs ``gpt_tiny``) — whose forward costs a few percent of the
 target's parameter read (the ``gpt_draft_forward_step`` budget pins
-<3%), so even modest acceptance amortizes (BASELINE r13's adjusted
-break-even m̄ > 1.017 + draft_bytes/target_bytes).
+<3%), so even modest acceptance amortizes (adjusted break-even
+m̄ > 1.017 + draft_bytes/target_bytes, from the computed byte budgets).
 
 Lockstep + resync contract
 --------------------------

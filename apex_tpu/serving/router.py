@@ -1,6 +1,6 @@
 """Disaggregated prefill/decode serving: two engines, one scheduler.
 
-Prefill is MXU-bound and decode is HBM-bound (BASELINE r8/r9), so the
+Prefill is MXU-bound and decode is HBM-bound, so the
 "millions of users" topology runs them on SEPARATE replicas — prompt
 forwards on a prefill engine, decode ticks on a decode engine — with
 the finished prompt pages shipped between them by the fault-tolerant

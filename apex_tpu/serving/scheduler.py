@@ -122,7 +122,7 @@ The engine's cache is DONATED to each jitted step (see
 
 import dataclasses
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -695,6 +695,35 @@ class PagedDecodeEngine(DecodeEngine):
             cfg, compute_dtype, quantized) if tree_spec else None
         self._copy = make_copy_page_fn()
         self._init_samplers()
+
+    @staticmethod
+    def full_pool_pages(num_slots: int, max_len: int,
+                        page_size: int) -> int:
+        """The ``num_pages`` at which every slot can hold ``max_len``
+        tokens at once, so nothing is ever preempted for want of a page
+        (the reserved null and scratch pages included)."""
+        return num_slots * max_pages_per_slot(max_len, page_size) \
+            + RESERVED_PAGES
+
+    def trace_programs(self) -> Dict[str, Any]:
+        """This engine's own jitted prefill (at its largest bucket) and
+        decode, traced at the shapes :meth:`prefill` and :meth:`decode`
+        call them with. Nothing runs and nothing is donated: the result
+        is for looking at the programs — ``.jaxpr`` for the kernels they
+        hold, ``.lower().compile()`` for XLA's account of their memory."""
+        bucket = max(self.buckets)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        return {
+            f"prefill_{bucket}": self._prefill.trace(
+                self.params, self.cache, i32(1, bucket), i32(bucket),
+                i32(), i32(bucket // self.page_size), i32(self.max_pages)),
+            "decode": self._decode.trace(
+                self.params, self.cache, i32(self.num_slots),
+                jax.ShapeDtypeStruct((self.num_slots,), jnp.bool_)),
+        }
 
     def page_demand(self, total_len: int) -> None:
         need = max_pages_per_slot(min(total_len, self.max_len),

@@ -253,9 +253,14 @@ def bench_flat_vs_tree_many_tensors(on_tpu):
 
 # -- shared BERT train-step builder ----------------------------------------
 
-def _bert_step(batch, seq, cfg, m_dtype=jnp.float32, emit_compute=False):
+def _bert_step(batch, seq, cfg, m_dtype=jnp.float32, emit_compute=False,
+               ddp=None):
     """Returns (train_step, make_state, (ids, mask)); ``make_state`` is
     a zero-arg factory so the donating timer holds ONE state copy.
+
+    ``ddp`` (a ``DistributedDataParallel``) makes ``train_step`` the body
+    of a data-parallel ``shard_map``: per-rank replica, grads all-reduced
+    before the overflow check.
 
     ``m_dtype``/``emit_compute`` are the reduced-precision state levers:
     bf16 Adam first moment, and the fused bf16 cast-out carried in the
@@ -292,8 +297,10 @@ def _bert_step(batch, seq, cfg, m_dtype=jnp.float32, emit_compute=False):
             out = apply_bert(p, cfg, ids, mask)
             return mlm_loss(out["mlm_logits"], ids, mask)
 
-        p = h.cast_model(master, precast=compute[0] if compute else None)
-        loss, grads, found_inf, scaler_state = h.value_and_grad(loss_fn)(
+        p = h.cast_model(ddp.local_replica(master) if ddp else master,
+                         precast=compute[0] if compute else None)
+        loss, grads, found_inf, scaler_state = h.value_and_grad(
+            loss_fn, reduce_grads=ddp.allreduce_grads if ddp else None)(
             p, scaler_state)
         if emit_compute:
             master, opt_state, c = opt.step(
@@ -310,9 +317,11 @@ def _bert_step(batch, seq, cfg, m_dtype=jnp.float32, emit_compute=False):
 # -- config 4: DDP BERT over all local devices ------------------------------
 
 def bench_ddp_bert(on_tpu):
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
     from apex_tpu.models import bert_large, bert_tiny
+    from apex_tpu.parallel import DistributedDataParallel
+    from apex_tpu.transformer import parallel_state as ps
 
     n = jax.device_count()
     cfg = bert_large() if on_tpu else bert_tiny()
@@ -320,17 +329,20 @@ def bench_ddp_bert(on_tpu):
     # (see bench_headline's sweep record)
     per_dev_batch, seq = (64, 128) if on_tpu else (2, 64)
     batch = per_dev_batch * n
-    mesh = Mesh(jax.devices(), ("data",))
-    train_step, make_state, (ids, mask) = _bert_step(batch, seq, cfg)
-    # GSPMD DP: batch sharded over the data axis, params replicated —
-    # jit propagates the sharding; XLA inserts the grad all-reduce.
-    data_sharding = NamedSharding(mesh, P("data", None))
-    ids = jax.device_put(ids, data_sharding)
-    mask = jax.device_put(mask, data_sharding)
+    ps.destroy_model_parallel()
+    mesh = ps.initialize_model_parallel()   # every device on the data axis
+    train_step, make_state, (ids, mask) = _bert_step(
+        batch, seq, cfg, ddp=DistributedDataParallel())
+    # shard_map DP: each chip runs the one-chip step on its slice of the
+    # batch over its own replica of the state; the grad all-reduce is the
+    # only collective.
+    rep, data = P(), P(ps.DATA_AXIS)
+    step = ps.shard_map(
+        train_step, mesh=mesh, in_specs=(rep, rep, rep, data, data),
+        out_specs=(rep, rep, rep, rep))
 
     def body(st):
-        m, o, sc, _ = train_step(st[0], st[1], st[2], ids, mask)
-        return (m, o, sc, _)
+        return step(st[0], st[1], st[2], ids, mask)
 
     dt = timed(body, lambda: (*make_state(), jnp.float32(0)),
                lambda s: s[3], M=10 if on_tpu else 2, donate=True)
@@ -443,8 +455,8 @@ def _decode_cost_numbers(cfg, slots, depth, param_dtype, cache_dtype,
     slice of that traffic: the full K/V read (both cache invars, charged
     once per step by the interpreter) plus the in-place row writes
     (``delta_write_bytes``) — exactly the term the paged layout makes
-    length-proportional (see the ``decode_paged_vs_dense`` A/B pair and
-    BASELINE r10). ``weight_bytes_per_token`` isolates the parameter
+    length-proportional (see the ``decode_paged_vs_dense`` A/B pair).
+    ``weight_bytes_per_token`` isolates the parameter
     slice of the interpreter's invar read charge, amortized over the
     batch — the term weight-only int8 halves (``quantized=True`` prices
     the int8 tree: same program, int8 kernel invars + fp32 scales)."""
@@ -1139,8 +1151,8 @@ def _paged_vs_dense_decode_ab_pair(on_tpu):
     """(side_a, side_b): paged ragged-length decode vs the dense
     slots x S_max step — prices the length-proportional K/V read the
     page pool banks on. Same medium shape and uniform 32..512 ragged
-    ladder as the ``gpt_paged_decode_step_medium_ragged`` cost entry
-    (BASELINE r10), so the measured ratio lands next to the static
+    ladder as the ``gpt_paged_decode_step_medium_ragged`` cost entry,
+    so the measured ratio lands next to the static
     ~40% K/V-read cut. ``active`` is all-False on BOTH sides: lengths
     never advance, so every scan iteration re-measures the same
     in-range program (no page-boundary host work inside the timed

@@ -123,21 +123,16 @@ class Smoke:
                   peak_bytes_in_use=stats.get(
                       "peak_bytes_in_use", "not reported by this backend"))
 
-    def compile(self, what: str, jitted, *args):
-        """Trace, compile and inspect one program: returns (compiled,
-        ``{"<module>.<kernel>": calls}`` over its ``pallas_call``
-        equations, seconds the compile took). On the chip no call may be
-        in interpret mode, and the compiled text must hold one Mosaic
-        custom call per ``pallas_call`` traced — a kernel that silently
-        became something else would not. In the rehearsal every call is
-        in interpret mode and lowers to plain HLO."""
-        from apex_tpu.lint.traced.jaxprlib import all_eqns
-
-        traced = jitted.trace(*args)
+    def compile(self, what: str, traced):
+        """Compile and inspect one traced program (``jitted.trace(...)``):
+        returns (compiled, ``{"<module>.<kernel>": calls}`` over its
+        ``pallas_call`` equations, seconds the compile took). On the chip
+        no call may be in interpret mode, and the compiled text must hold
+        one Mosaic custom call per ``pallas_call`` traced — a kernel that
+        silently became something else would not. In the rehearsal every
+        call is in interpret mode and lowers to plain HLO."""
         census = collections.Counter()
-        for eqn in all_eqns(traced.jaxpr, into_pallas=False):
-            if eqn.primitive.name != "pallas_call":
-                continue
+        for eqn in _pallas_calls(traced.jaxpr):
             info = eqn.params["jaxpr"].debug_info
             module = os.path.basename(
                 info.func_src_info.split(" at ")[-1]).split(".py")[0]
@@ -157,6 +152,20 @@ class Smoke:
                   f"the trace held {sum(census.values())} pallas_call(s): "
                   f"{dict(census)}")
         return compiled, dict(census), seconds
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a (closed) jaxpr, through scans,
+    remats, custom derivatives and mapped regions, not into the kernels'
+    own bodies."""
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                    yield from _pallas_calls(sub)
 
 
 def check(ok: bool, message: str) -> None:
@@ -226,16 +235,15 @@ def phase_server(smoke: Smoke) -> None:
     from apex_tpu import amp
     from apex_tpu.models import gpt as gpt_models
     from apex_tpu.models.gpt import apply_gpt_unsharded, init_gpt
-    from apex_tpu.serving import (PagedDecodeEngine, make_paged_decode_fn,
-                                  make_paged_prefill_fn)
-    from apex_tpu.serving.cache import RESERVED_PAGES
+    from apex_tpu.serving import PagedDecodeEngine
 
     since, sz = smoke.cache_counts(), smoke.sizes
     cfg = getattr(gpt_models, sz.gpt)()
     # bf16 inference params: the O2 model cast (norms stay float32)
     params = amp.initialize("O2", verbosity=0).cast_model(
         init_gpt(jax.random.PRNGKey(0), cfg))
-    num_pages = sz.slots * (sz.max_len // sz.page_size) + RESERVED_PAGES
+    num_pages = PagedDecodeEngine.full_pool_pages(sz.slots, sz.max_len,
+                                                  sz.page_size)
     engine = PagedDecodeEngine(params, cfg, num_slots=sz.slots,
                                max_len=sz.max_len, num_pages=num_pages,
                                page_size=sz.page_size)
@@ -310,20 +318,13 @@ def phase_server(smoke: Smoke) -> None:
     rms = float(np.sqrt(np.mean((got - want) ** 2)))
     agree = float(np.mean(np.argmax(got, -1) == np.argmax(want, -1)))
 
-    # the programs the engine just ran, built through the same public
-    # factories, must hold their kernels as Mosaic custom calls
+    # the engine's own prefill (largest bucket) and decode programs, the
+    # executables it just ran, must hold their kernels as Mosaic custom
+    # calls
     census, programs = {}, {}
     longest = max(engine.buckets)
-    prefill_args = (params, engine.cache, jnp.zeros((1, longest), jnp.int32),
-                    jnp.ones((longest,), jnp.int32), jnp.int32(0),
-                    jnp.ones((longest // sz.page_size,), jnp.int32),
-                    jnp.zeros((engine.max_pages,), jnp.int32))
-    decode_args = (params, engine.cache, jnp.zeros((sz.slots,), jnp.int32),
-                   jnp.ones((sz.slots,), bool))
-    for name, fn, args in (
-            (f"prefill_{longest}", make_paged_prefill_fn(cfg), prefill_args),
-            ("decode", make_paged_decode_fn(cfg), decode_args)):
-        compiled, census[name], _ = smoke.compile(name, fn, *args)
+    for name, traced in engine.trace_programs().items():
+        compiled, census[name], _ = smoke.compile(name, traced)
         programs[name] = compiled_bytes(compiled)
     if longest >= 512:
         check(any(k.startswith("flash_attention.")
@@ -381,8 +382,9 @@ def phase_trainer(smoke: Smoke) -> None:
         return master, opt_state, scaler, loss
 
     compiled, census, compile_s = smoke.compile(
-        "BERT train step", jax.jit(train_step, donate_argnums=(0, 1, 2)),
-        *state, ids, mask)
+        "BERT train step",
+        jax.jit(train_step, donate_argnums=(0, 1, 2)).trace(
+            *state, ids, mask))
     # every LayerNorm (embeddings, two per layer, the MLM head) and the
     # loss run as kernels, forward and backward
     norms = 2 * cfg.num_layers + 2
@@ -494,7 +496,7 @@ def phase_four_chip(smoke: Smoke) -> None:
         train_step, mesh=mesh, in_specs=(pspecs, ospecs, bspecs),
         out_specs=(pspecs, ospecs, P())), donate_argnums=(0, 1))
     compiled, census, compile_s = smoke.compile(
-        "dp2 x tp2 step", step, pipe_params, opt_state, batch)
+        "dp2 x tp2 step", step.trace(pipe_params, opt_state, batch))
     check("all-reduce" in compiled.as_text(),
           "the compiled step holds no all-reduce")
 

@@ -74,9 +74,11 @@ def main():
         # rank-0 params everywhere first (the DDP constructor broadcast)
         master = ddp.broadcast_params(master)
         p = h.cast_model(master)
+        # the DDP hook: mean over dp, on the scaled grads, so every
+        # replica sees the same overflow
         loss, grads, found_inf, scaler_state = h.value_and_grad(
-            lambda p: loss_fn(p, h.cast_input(x), y))(p, scaler_state)
-        grads = ddp.allreduce_grads(grads)   # the DDP hook: mean over dp
+            lambda p: loss_fn(p, h.cast_input(x), y),
+            reduce_grads=ddp.allreduce_grads)(p, scaler_state)
         master, opt_state = opt.step(grads, master, opt_state,
                                      found_inf=found_inf)
         loss = jax.lax.pmean(loss, ps.DATA_AXIS)

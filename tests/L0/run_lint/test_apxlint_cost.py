@@ -13,7 +13,8 @@ Four layers, per the tier's contract:
   schema validation, and hand-tightened ceilings surviving regen;
 - the repo itself: every registered entry must cost-analyze, the
   committed budgets.json must gate them clean, and the medium decode
-  entry must agree with BASELINE.md r8's hand roofline within 10%.
+  entry must agree with the hand roofline (params + parked K/V) within
+  10%.
 """
 
 import dataclasses
@@ -241,8 +242,8 @@ def test_repo_costs_clean_under_committed_budgets(repo_reports):
 
 
 def test_medium_decode_matches_r8_hand_roofline(repo_reports):
-    """BASELINE.md r8 derives the decode ceiling by hand: every param
-    byte plus the parked K/V history per step. The interpreter must
+    """The decode ceiling by hand: every param byte plus the parked K/V
+    history per step. The interpreter must
     land within 10% of that independent derivation."""
     rep = {r.entry: r for r in repo_reports}["gpt_decode_step_medium"]
 

@@ -365,8 +365,7 @@ def test_seeded_qkv_axis_flip_fires_apx702(tmp_path):
 
 def test_sharded_registry_populated_and_clean():
     names = {e.name for e in repo_entries()}
-    assert {"gpt_tiny_rules", "bert_tiny_rules",
-            "gpt_tiny_dp2xtp2_zero"} <= names, names
+    assert {"gpt_tiny_rules", "gpt_tiny_dp2xtp2_zero"} <= names, names
     findings = check_repo()
     assert findings == [], "\n".join(f.render() for f in findings)
 

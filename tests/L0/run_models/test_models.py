@@ -7,7 +7,7 @@ import numpy as np
 
 from apex_tpu import amp
 from apex_tpu.models import (
-    apply_bert, apply_resnet, bert_partition_specs, bert_tiny,
+    apply_bert, apply_resnet, bert_tiny,
     cross_entropy_loss, init_bert, init_resnet, mlm_loss,
 )
 from apex_tpu.optimizers import FusedAdam, FusedSGD
@@ -101,21 +101,6 @@ def test_bert_amp_o2_train_step():
     # master params stay fp32
     assert params["encoder"][0]["attention"]["qkv"]["kernel"].dtype \
         == jnp.float32
-
-
-def test_bert_partition_specs_cover_tree():
-    from jax.sharding import PartitionSpec as P
-    cfg = bert_tiny()
-    params = init_bert(jax.random.PRNGKey(0), cfg)
-    specs = bert_partition_specs(params)
-    flat_p = jax.tree_util.tree_leaves(params)
-    flat_s = jax.tree_util.tree_leaves(
-        specs, is_leaf=lambda x: isinstance(x, P))
-    assert len(flat_p) == len(flat_s)
-    qkv = specs["encoder"][0]["attention"]["qkv"]
-    assert qkv["kernel"] == P(None, "model") and qkv["bias"] == P("model")
-    assert specs["encoder"][0]["mlp"]["fc2"]["kernel"] == P("model", None)
-    assert specs["embeddings"]["word"]["embedding"] == P("model", None)
 
 
 def test_resnet18_forward_and_step():

@@ -262,6 +262,37 @@ def test_ddp_bert_tiny_train_step():
     assert losses[-1] < losses[0], losses
 
 
+def test_ddp_amp_overflow_on_one_rank_skips_every_rank():
+    """With ``reduce_grads`` the all-reduce runs on the scaled grads, before
+    the overflow check: one rank's inf makes EVERY rank report found_inf and
+    halve the scale, so the replicas cannot take different skip decisions."""
+    from apex_tpu import amp
+
+    mesh = dp_mesh()
+    ddp = DistributedDataParallel()
+    h = amp.initialize(opt_level="O2", loss_scale="dynamic")
+    w = jnp.ones((4,))
+    # rank 3's shard overflows float32 once scaled; the others are tame
+    x = jnp.ones((8, 4)).at[3].set(3e38)
+
+    def per_shard(w, scaler, x):
+        def loss(w):
+            return jnp.sum(w * x)
+        _, _, found_local, _ = h.value_and_grad(loss)(
+            ddp.local_replica(w), scaler)
+        _, _, found, new = h.value_and_grad(
+            loss, reduce_grads=ddp.allreduce_grads)(
+            ddp.local_replica(w), scaler)
+        return found_local[None], found[None], new.loss_scale[None]
+
+    found_local, found, scale = jax.jit(shard_map(
+        per_shard, mesh, in_specs=(P(), P(), P(ps.DATA_AXIS)),
+        out_specs=(P(ps.DATA_AXIS),) * 3))(w, h.init_state(), x)
+    assert found_local.tolist() == [i == 3 for i in range(8)]
+    assert found.tolist() == [True] * 8
+    assert scale.tolist() == [float(h.init_state().loss_scale) / 2] * 8
+
+
 def test_broadcast_params_exact_for_int_leaves():
     # masked-psum broadcast must not round-trip through fp32: an int32
     # value above 2^24 would silently lose low bits there

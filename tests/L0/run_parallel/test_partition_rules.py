@@ -11,7 +11,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.partition import (
-    bert_rules,
     gpt_rules,
     kv_cache_rules,
     make_mesh,
@@ -84,17 +83,6 @@ def test_gpt_rules_reproduce_hand_specs():
         lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0))
     assert _flat(match_partition_rules(gpt_rules(), params)) == \
         _flat(gpt_partition_specs(cfg))
-
-
-def test_bert_rules_reproduce_hand_specs():
-    from apex_tpu.models.bert import (
-        bert_partition_specs, bert_tiny, init_bert,
-    )
-
-    params = jax.eval_shape(
-        lambda k: init_bert(k, bert_tiny()), jax.random.PRNGKey(0))
-    assert _flat(match_partition_rules(bert_rules(), params)) == \
-        _flat(bert_partition_specs(params))
 
 
 def test_optimizer_state_specs_track_param_specs():

@@ -274,3 +274,23 @@ def test_page_demand_rejects_oversized_requests():
     eng.page_demand(12)  # 3 pages: fits
     with pytest.raises(ValueError, match="pages"):
         eng.page_demand(13)  # 4 pages > 3 usable
+
+
+def test_full_pool_pages_and_trace_programs():
+    """``full_pool_pages`` is the pool in which every slot fits whole, and
+    ``trace_programs`` hands out the engine's OWN prefill/decode jits at
+    the shapes it runs them with — tracing runs nothing and leaves the
+    cache alone."""
+    cfg = _cfg()
+    params = init_gpt(jax.random.PRNGKey(0), cfg)
+    n = PagedDecodeEngine.full_pool_pages(2, S_MAX, 4)
+    assert n == 2 * (S_MAX // 4) + RESERVED_PAGES
+    eng = _engine(params, cfg, num_pages=n)
+    eng.page_demand(S_MAX)
+    cache = eng.cache
+    traced = eng.trace_programs()
+    assert set(traced) == {"prefill_32", "decode"}
+    assert eng.cache is cache and eng.pool.num_free == n - RESERVED_PAGES
+    logits = traced["decode"].out_info[1]
+    assert logits.shape == (2, cfg.vocab_size)
+    assert traced["prefill_32"].out_info[1].shape == (1, cfg.vocab_size)

@@ -2,10 +2,10 @@
 the reference's L1 ``cross_product`` suite trains fp16 vs fp32 pairs and
 compares loss curves per step; the north star's "loss parity" clause).
 
-The reference publishes no numbers (BASELINE.md), so the golden curve is
-the package's own fp32 (O0) run: every amp level must track it within
-mixed-precision tolerance step by step, and training must actually
-converge (final < initial)."""
+The reference publishes no numbers (``BASELINE.json``: "published": {}),
+so the golden curve is the package's own fp32 (O0) run: every amp level
+must track it within mixed-precision tolerance step by step, and
+training must actually converge (final < initial)."""
 
 import jax
 import jax.numpy as jnp
