@@ -23,15 +23,20 @@ statistic counts live arrays only, not a running program's temporaries:
 and code) is printed beside it. Weights are random, made from a seed;
 nothing is read from disk or the network. Every phase checks
 what it produced and prints one JSON line; an exception in any phase ends
-the run with a traceback and a non-zero exit code. The last line of
-standard output is the summary, ``{"ok": true, "device": {...}, ...,
-"claim": null}``: this script measures nothing and claims nothing. The
-seconds it prints are smoke observations, not benchmark results.
+the run with a traceback and a non-zero exit code. After the phases comes
+a summary line, ``{"summary": {<phase>: "passed" | "skipped"}, ...,
+"claim": null}``: this script measures nothing and claims nothing, and
+the seconds it prints are smoke observations, not benchmark results. The
+last line of standard output is the result, with the device as JAX
+reports it and no other key::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
 Without a TPU the script exits non-zero before any phase and prints no
 result. ``--cpu-rehearsal`` is the one way to run it off the chip: tiny
 presets, Pallas kernels in interpret mode, every line labelled
-``"rehearsal": true``. There is no automatic switch between the two.
+``"rehearsal": true``, and no result line, because a rehearsal is not
+one. There is no automatic switch between the two.
 """
 
 import argparse
@@ -81,6 +86,18 @@ TINY = Sizes(bert="bert_tiny", bert_batch=4, bert_seq=64, steps=4,
              four_batch=8, four_micro=2, four_seq=32)
 
 
+def describe(devices) -> dict:
+    """The device as JAX reports it, in the result line's three keys."""
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
+def result_line(device: dict) -> str:
+    """The last line of a run on the chip: these keys and no other —
+    whoever runs this check reads exactly that."""
+    return json.dumps({"ok": True, "device": device})
+
+
 class Smoke:
     """Shared plumbing of the phases: the device, the labels every line
     carries, the compile-cache counters and the compile-and-inspect
@@ -92,9 +109,7 @@ class Smoke:
         self.rehearsal = rehearsal
         self.sizes = TINY if rehearsal else FULL
         self.devices = jax.devices()   # starts the backend
-        self.device = {"platform": self.devices[0].platform,
-                       "kind": self.devices[0].device_kind,
-                       "count": len(self.devices)}
+        self.device = describe(self.devices)
         self.summary = {}
         self._events = collections.Counter()
         jax.monitoring.register_event_listener(
@@ -600,9 +615,11 @@ def main() -> int:
         # (its arrays died with its frame)
         jax.clear_caches()
 
-    print(json.dumps({"ok": True, "device": dev,
-                      "rehearsal": smoke.rehearsal,
-                      "phases": smoke.summary, "claim": None}), flush=True)
+    print(json.dumps({"summary": smoke.summary,
+                      "rehearsal": smoke.rehearsal, "claim": None}),
+          flush=True)
+    if not smoke.rehearsal:
+        print(result_line(dev), flush=True)   # on the chip only
     return 0
 
 

@@ -19,3 +19,20 @@ def test_refuses_to_run_without_a_tpu():
     assert r.returncode not in (0, None), r.stdout
     assert "platform 'cpu'" in r.stderr and "not a TPU" in r.stderr, r.stderr
     assert r.stdout == "", r.stdout  # no phase ran, no result printed
+
+
+def test_result_line_holds_the_contract_keys_and_no_other():
+    import collections
+    import json
+
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    Dev = collections.namedtuple("Dev", "platform device_kind")
+    line = chip_smoke.result_line(
+        chip_smoke.describe([Dev("tpu", "TPU v5 lite")]))
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
