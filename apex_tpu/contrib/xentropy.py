@@ -118,21 +118,23 @@ def _fwd_call(logits, labels, eps, interpret):
     grid = (n_p // _BR, v_p // bv)
     x_spec = pl.BlockSpec((_BR, bv), lambda rt, vt: (rt, vt),
                           memory_space=pltpu.VMEM)
-    loss, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, n=n, v=v, eps=eps),
-        grid=grid,
-        in_specs=[x_spec, _row_spec(n_p)],
-        out_specs=(_row_spec(n_p), _row_spec(n_p)),
-        out_shape=(jax.ShapeDtypeStruct((1, n_p), jnp.float32),
-                   jax.ShapeDtypeStruct((1, n_p), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((_BR, 128), jnp.float32)] * 4,
-        # BOTH dims arbitrary: the (1, n_p) loss/lse outputs are one
-        # revisited block each row-tile writes a slice of — a "parallel"
-        # rt could be split across megacore TensorCores, each holding a
-        # private copy and losing the other's slices
-        compiler_params=_dimsem("arbitrary", "arbitrary"),
-        interpret=pallas_interpret(interpret),
-    )(xp, lab)
+    with jax.named_scope("apex_xentropy_fwd"):
+        loss, lse = pl.pallas_call(
+            functools.partial(_fwd_kernel, n=n, v=v, eps=eps),
+            grid=grid,
+            in_specs=[x_spec, _row_spec(n_p)],
+            out_specs=(_row_spec(n_p), _row_spec(n_p)),
+            out_shape=(jax.ShapeDtypeStruct((1, n_p), jnp.float32),
+                       jax.ShapeDtypeStruct((1, n_p), jnp.float32)),
+            scratch_shapes=[pltpu.VMEM((_BR, 128), jnp.float32)] * 4,
+            # BOTH dims arbitrary: the (1, n_p) loss/lse outputs are one
+            # revisited block each row-tile writes a slice of — a "parallel"
+            # rt could be split across megacore TensorCores, each holding a
+            # private copy and losing the other's slices
+            compiler_params=_dimsem("arbitrary", "arbitrary"),
+            interpret=pallas_interpret(interpret),
+            name="apex_xentropy_fwd",
+        )(xp, lab)
     return loss[0, :n], lse  # lse stays padded (1, n_p)
 
 
@@ -148,15 +150,17 @@ def _bwd_call(logits, labels, lse_p, dloss, eps, interpret):
     grid = (n_p // _BR, v_p // bv)
     x_spec = pl.BlockSpec((_BR, bv), lambda rt, vt: (rt, vt),
                           memory_space=pltpu.VMEM)
-    dx = pl.pallas_call(
-        functools.partial(_bwd_kernel, n=n, v=v, eps=eps),
-        grid=grid,
-        in_specs=[x_spec, _row_spec(n_p), _row_spec(n_p), _row_spec(n_p)],
-        out_specs=x_spec,
-        out_shape=jax.ShapeDtypeStruct((n_p, v_p), logits.dtype),
-        compiler_params=_dimsem("parallel", "parallel"),
-        interpret=pallas_interpret(interpret),
-    )(xp, lab, lse_p, dl)
+    with jax.named_scope("apex_xentropy_bwd"):
+        dx = pl.pallas_call(
+            functools.partial(_bwd_kernel, n=n, v=v, eps=eps),
+            grid=grid,
+            in_specs=[x_spec, _row_spec(n_p), _row_spec(n_p), _row_spec(n_p)],
+            out_specs=x_spec,
+            out_shape=jax.ShapeDtypeStruct((n_p, v_p), logits.dtype),
+            compiler_params=_dimsem("parallel", "parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_xentropy_bwd",
+        )(xp, lab, lse_p, dl)
     return dx[:n, :v]
 
 

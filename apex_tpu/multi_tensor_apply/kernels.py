@@ -85,18 +85,20 @@ def flat_scale(buf: jax.Array, scale, out_dtype=None,
     x = _pad_to_block(buf)
     n_tiles = x.shape[0] // BLOCK_ROWS
     sc = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    out, finite = pl.pallas_call(
-        _scale_kernel,
-        grid=(n_tiles,),
-        in_specs=[_smem_spec(), _tile_spec()],
-        out_specs=[_tile_spec(), _partial_spec()],
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, out_dtype or buf.dtype),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32),
-        ],
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, x)
+    with jax.named_scope("apex_mt_scale"):
+        out, finite = pl.pallas_call(
+            _scale_kernel,
+            grid=(n_tiles,),
+            in_specs=[_smem_spec(), _tile_spec()],
+            out_specs=[_tile_spec(), _partial_spec()],
+            out_shape=[
+                jax.ShapeDtypeStruct(x.shape, out_dtype or buf.dtype),
+                jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32),
+            ],
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_mt_scale",
+        )(sc, x)
     return out[:rows], jnp.logical_not(jnp.all(finite == 1))
 
 
@@ -120,18 +122,20 @@ def flat_axpby(a, x: jax.Array, b, y: jax.Array, out_dtype=None,
     n_tiles = xp.shape[0] // BLOCK_ROWS
     sc = jnp.stack([jnp.asarray(a, jnp.float32),
                     jnp.asarray(b, jnp.float32)]).reshape(1, 2)
-    out, finite = pl.pallas_call(
-        _axpby_kernel,
-        grid=(n_tiles,),
-        in_specs=[_smem_spec(), _tile_spec(), _tile_spec()],
-        out_specs=[_tile_spec(), _partial_spec()],
-        out_shape=[
-            jax.ShapeDtypeStruct(xp.shape, out_dtype or x.dtype),
-            jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32),
-        ],
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, xp, yp)
+    with jax.named_scope("apex_mt_axpby"):
+        out, finite = pl.pallas_call(
+            _axpby_kernel,
+            grid=(n_tiles,),
+            in_specs=[_smem_spec(), _tile_spec(), _tile_spec()],
+            out_specs=[_tile_spec(), _partial_spec()],
+            out_shape=[
+                jax.ShapeDtypeStruct(xp.shape, out_dtype or x.dtype),
+                jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32),
+            ],
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_mt_axpby",
+        )(sc, xp, yp)
     return out[:rows], jnp.logical_not(jnp.all(finite == 1))
 
 
@@ -162,17 +166,19 @@ def flat_l2norm_partials(buf: jax.Array,
     """
     x = _pad_to_block(buf)
     n_tiles = x.shape[0] // BLOCK_ROWS
-    parts = pl.pallas_call(
-        _l2_kernel,
-        grid=(n_tiles,),
-        in_specs=[_tile_spec()],
-        out_specs=pl.BlockSpec((1, _SUBS_PER_BLOCK), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, _SUBS_PER_BLOCK),
-                                       jnp.float32),
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(x)
+    with jax.named_scope("apex_mt_l2norm"):
+        parts = pl.pallas_call(
+            _l2_kernel,
+            grid=(n_tiles,),
+            in_specs=[_tile_spec()],
+            out_specs=pl.BlockSpec((1, _SUBS_PER_BLOCK), lambda i: (i, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((n_tiles, _SUBS_PER_BLOCK),
+                                           jnp.float32),
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_mt_l2norm",
+        )(x)
     return parts.reshape(-1)
 
 
@@ -267,16 +273,18 @@ def flat_sgd(grads: jax.Array, params: jax.Array, momentum_buf: jax.Array,
                  jax.ShapeDtypeStruct(pp.shape, bp.dtype)]
     if emit_compute_dtype is not None:
         out_shape.append(jax.ShapeDtypeStruct(pp.shape, emit_compute_dtype))
-    outs = pl.pallas_call(
-        _sgd_kernel,
-        grid=(n_tiles,),
-        in_specs=[_smem_spec()] + [_tile_spec()] * 3,
-        out_specs=[_tile_spec()] * len(out_shape),
-        out_shape=out_shape,
-        input_output_aliases={2: 0, 3: 1},
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, gp, pp, bp)
+    with jax.named_scope("apex_mt_sgd"):
+        outs = pl.pallas_call(
+            _sgd_kernel,
+            grid=(n_tiles,),
+            in_specs=[_smem_spec()] + [_tile_spec()] * 3,
+            out_specs=[_tile_spec()] * len(out_shape),
+            out_shape=out_shape,
+            input_output_aliases={2: 0, 3: 1},
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_mt_sgd",
+        )(sc, gp, pp, bp)
     return tuple(o[:rows] for o in outs)
 
 
@@ -359,19 +367,22 @@ def flat_lamb(grads: jax.Array, params: jax.Array, m: jax.Array,
     ]).reshape(1, 9)
     part_spec = pl.BlockSpec((1, _SUBS_PER_BLOCK), lambda i: (i, 0),
                              memory_space=pltpu.VMEM)
-    m_new, v_new, u, p_parts, u_parts = pl.pallas_call(
-        _lamb_stage1_kernel,
-        grid=(n_tiles,),
-        in_specs=[_smem_spec()] + [_tile_spec()] * 4,
-        out_specs=[_tile_spec()] * 3 + [part_spec] * 2,
-        out_shape=[jax.ShapeDtypeStruct(pp.shape, mp.dtype),
-                   jax.ShapeDtypeStruct(pp.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(pp.shape, jnp.float32)]
-        + [jax.ShapeDtypeStruct((n_tiles, _SUBS_PER_BLOCK), jnp.float32)] * 2,
-        input_output_aliases={3: 0, 4: 1},
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, gp, pp, mp, vp)
+    with jax.named_scope("apex_mt_lamb"):
+        m_new, v_new, u, p_parts, u_parts = pl.pallas_call(
+            _lamb_stage1_kernel,
+            grid=(n_tiles,),
+            in_specs=[_smem_spec()] + [_tile_spec()] * 4,
+            out_specs=[_tile_spec()] * 3 + [part_spec] * 2,
+            out_shape=[jax.ShapeDtypeStruct(pp.shape, mp.dtype),
+                       jax.ShapeDtypeStruct(pp.shape, jnp.float32),
+                       jax.ShapeDtypeStruct(pp.shape, jnp.float32)]
+            + [jax.ShapeDtypeStruct((n_tiles, _SUBS_PER_BLOCK),
+                                    jnp.float32)] * 2,
+            input_output_aliases={3: 0, 4: 1},
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_mt_lamb",
+        )(sc, gp, pp, mp, vp)
 
     # stage 2: per-tensor trust ratios from the fused partials
     ids = jnp.asarray(tile_ids, jnp.int32)
@@ -439,16 +450,18 @@ def flat_adagrad(grads: jax.Array, params: jax.Array, gsum: jax.Array,
     out_shape = [jax.ShapeDtypeStruct(pp.shape, jnp.float32)] * 2
     if emit_compute_dtype is not None:
         out_shape.append(jax.ShapeDtypeStruct(pp.shape, emit_compute_dtype))
-    outs = pl.pallas_call(
-        _adagrad_kernel,
-        grid=(n_tiles,),
-        in_specs=[_smem_spec()] + [_tile_spec()] * 3,
-        out_specs=[_tile_spec()] * len(out_shape),
-        out_shape=out_shape,
-        input_output_aliases={2: 0, 3: 1},
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, gp, pp, sp)
+    with jax.named_scope("apex_mt_adagrad"):
+        outs = pl.pallas_call(
+            _adagrad_kernel,
+            grid=(n_tiles,),
+            in_specs=[_smem_spec()] + [_tile_spec()] * 3,
+            out_specs=[_tile_spec()] * len(out_shape),
+            out_shape=out_shape,
+            input_output_aliases={2: 0, 3: 1},
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_mt_adagrad",
+        )(sc, gp, pp, sp)
     return tuple(o[:rows] for o in outs)
 
 
@@ -536,16 +549,18 @@ def flat_novograd(grads: jax.Array, params: jax.Array, m: jax.Array,
                  jax.ShapeDtypeStruct(pp.shape, mp.dtype)]
     if emit_compute_dtype is not None:
         out_shape.append(jax.ShapeDtypeStruct(pp.shape, emit_compute_dtype))
-    outs = pl.pallas_call(
-        _novograd_kernel,
-        grid=(n_tiles,),
-        in_specs=[_smem_spec(), denom_spec] + [_tile_spec()] * 3,
-        out_specs=[_tile_spec()] * len(out_shape),
-        out_shape=out_shape,
-        input_output_aliases={3: 0, 4: 1},
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, row_denom, gp, pp, mp)
+    with jax.named_scope("apex_mt_novograd"):
+        outs = pl.pallas_call(
+            _novograd_kernel,
+            grid=(n_tiles,),
+            in_specs=[_smem_spec(), denom_spec] + [_tile_spec()] * 3,
+            out_specs=[_tile_spec()] * len(out_shape),
+            out_shape=out_shape,
+            input_output_aliases={3: 0, 4: 1},
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_mt_novograd",
+        )(sc, row_denom, gp, pp, mp)
     if emit_compute_dtype is not None:
         return outs[0][:rows], outs[1][:rows], v_new, outs[2][:rows]
     return outs[0][:rows], outs[1][:rows], v_new
@@ -590,14 +605,16 @@ def flat_adam(grads: jax.Array, params: jax.Array, m: jax.Array, v: jax.Array,
     ]
     if emit_compute_dtype is not None:
         out_shape.append(jax.ShapeDtypeStruct(pp.shape, emit_compute_dtype))
-    outs = pl.pallas_call(
-        _adam_kernel,
-        grid=(n_tiles,),
-        in_specs=[_smem_spec()] + [_tile_spec()] * 4,
-        out_specs=[_tile_spec()] * n_out,
-        out_shape=out_shape,
-        input_output_aliases={2: 0, 3: 1, 4: 2},
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, gp, pp, mp, vp)
+    with jax.named_scope("apex_mt_adam"):
+        outs = pl.pallas_call(
+            _adam_kernel,
+            grid=(n_tiles,),
+            in_specs=[_smem_spec()] + [_tile_spec()] * 4,
+            out_specs=[_tile_spec()] * n_out,
+            out_shape=out_shape,
+            input_output_aliases={2: 0, 3: 1, 4: 2},
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_mt_adam",
+        )(sc, gp, pp, mp, vp)
     return tuple(o[:rows] for o in outs)

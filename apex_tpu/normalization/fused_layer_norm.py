@@ -308,16 +308,18 @@ def _bwd_call_colsplit(dy2d, x2d, w, mean, rstd, mode, has_b, interpret):
         out_specs.append(grow)
         out_shape.append(jax.ShapeDtypeStruct((1, h_p), jnp.float32))
 
-    outs = pl.pallas_call(
-        functools.partial(_bwd_colsum_kernel, mode=mode, has_w=has_w,
-                          has_b=has_b),
-        grid=(nri, nci),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=_dimsem("arbitrary", "arbitrary"),
-        interpret=pallas_interpret(interpret),
-    )(*args)
+    with jax.named_scope("apex_ln_bwd_colsum"):
+        outs = pl.pallas_call(
+            functools.partial(_bwd_colsum_kernel, mode=mode, has_w=has_w,
+                              has_b=has_b),
+            grid=(nri, nci),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            compiler_params=_dimsem("arbitrary", "arbitrary"),
+            interpret=pallas_interpret(interpret),
+            name="apex_ln_bwd_colsum",
+        )(*args)
     outs = list(outs)
     c1s = outs.pop(0)
     c2s = outs.pop(0) if mode == "ln" else None
@@ -347,16 +349,18 @@ def _bwd_call_colsplit(dy2d, x2d, w, mean, rstd, mode, has_b, interpret):
         in_specs2.append(stat2)
         args2.append(c2s)
 
-    dx = pl.pallas_call(
-        functools.partial(_bwd_dx_kernel, mode=mode, has_w=has_w,
-                          inv_h=1.0 / h),
-        grid=(nci, nri),
-        in_specs=in_specs2,
-        out_specs=blk2,
-        out_shape=jax.ShapeDtypeStruct((padded, h_p), x2d.dtype),
-        compiler_params=_dimsem("parallel", "parallel"),
-        interpret=pallas_interpret(interpret),
-    )(*args2)
+    with jax.named_scope("apex_ln_bwd_coldx"):
+        dx = pl.pallas_call(
+            functools.partial(_bwd_dx_kernel, mode=mode, has_w=has_w,
+                              inv_h=1.0 / h),
+            grid=(nci, nri),
+            in_specs=in_specs2,
+            out_specs=blk2,
+            out_shape=jax.ShapeDtypeStruct((padded, h_p), x2d.dtype),
+            compiler_params=_dimsem("parallel", "parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_ln_bwd_coldx",
+        )(*args2)
     return dx[:rows, :h], dw, db
 
 
@@ -398,15 +402,17 @@ def _fwd_call(x2d, w, b, mode, eps, interpret):
     kernel = functools.partial(
         _fwd_kernel, mode=mode, eps=eps, has_w=w is not None, has_b=b is not None
     )
-    outs = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=_dimsem("parallel"),
-        interpret=pallas_interpret(interpret),
-    )(*args)
+    with jax.named_scope("apex_ln_fwd"):
+        outs = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_ln_fwd",
+        )(*args)
     outs = [o[:rows] for o in outs]
     if mode == "ln":
         y, mean, rstd = outs
@@ -468,15 +474,17 @@ def _bwd_call(dy2d, x2d, w, mean, rstd, mode, has_b, interpret):
         _bwd_kernel, mode=mode, has_w=has_w, has_b=has_b,
         accum_parts=accum_parts,
     )
-    outs = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=_dimsem("arbitrary"),
-        interpret=pallas_interpret(interpret),
-    )(*args)
+    with jax.named_scope("apex_ln_bwd"):
+        outs = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            compiler_params=_dimsem("arbitrary"),
+            interpret=pallas_interpret(interpret),
+            name="apex_ln_bwd",
+        )(*args)
     outs = list(outs)
     dx = outs.pop(0)[:rows]
     dw = outs.pop(0).sum(axis=0) if has_w else None

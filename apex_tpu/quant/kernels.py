@@ -161,33 +161,37 @@ def w8_matmul(x, wq, scale, bias=None, out_dtype=None, interpret=None):
     bn = _block_n(n)
     scale2 = scale.reshape(1, n)
     if bias is None:
-        out = pl.pallas_call(
-            _w8_matmul_nobias_kernel,
-            grid=(n // bn,),
-            in_specs=[
-                pl.BlockSpec((m, k), lambda i: (0, 0)),
-                pl.BlockSpec((k, bn), lambda i: (0, i)),
-                pl.BlockSpec((1, bn), lambda i: (0, i)),
-            ],
-            out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
-            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-            interpret=pallas_interpret(interpret),
-        )(x2, wq, scale2)
+        with jax.named_scope("apex_w8_matmul"):
+            out = pl.pallas_call(
+                _w8_matmul_nobias_kernel,
+                grid=(n // bn,),
+                in_specs=[
+                    pl.BlockSpec((m, k), lambda i: (0, 0)),
+                    pl.BlockSpec((k, bn), lambda i: (0, i)),
+                    pl.BlockSpec((1, bn), lambda i: (0, i)),
+                ],
+                out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
+                out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+                interpret=pallas_interpret(interpret),
+                name="apex_w8_matmul",
+            )(x2, wq, scale2)
     else:
         bias2 = bias.reshape(1, n)
-        out = pl.pallas_call(
-            _w8_matmul_kernel,
-            grid=(n // bn,),
-            in_specs=[
-                pl.BlockSpec((m, k), lambda i: (0, 0)),
-                pl.BlockSpec((k, bn), lambda i: (0, i)),
-                pl.BlockSpec((1, bn), lambda i: (0, i)),
-                pl.BlockSpec((1, bn), lambda i: (0, i)),
-            ],
-            out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
-            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-            interpret=pallas_interpret(interpret),
-        )(x2, wq, scale2, bias2)
+        with jax.named_scope("apex_w8_matmul_bias"):
+            out = pl.pallas_call(
+                _w8_matmul_kernel,
+                grid=(n // bn,),
+                in_specs=[
+                    pl.BlockSpec((m, k), lambda i: (0, 0)),
+                    pl.BlockSpec((k, bn), lambda i: (0, i)),
+                    pl.BlockSpec((1, bn), lambda i: (0, i)),
+                    pl.BlockSpec((1, bn), lambda i: (0, i)),
+                ],
+                out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
+                out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+                interpret=pallas_interpret(interpret),
+                name="apex_w8_matmul_bias",
+            )(x2, wq, scale2, bias2)
     return out.reshape(lead + (n,))
 
 
@@ -207,18 +211,20 @@ def w8_matmul_nk(x, wq, scale, out_dtype=jnp.float32, interpret=None):
         return _w8_ref(x2, wq, scale, None, out_dtype, True).reshape(
             lead + (n,))
     bn = _block_n(n)
-    out = pl.pallas_call(
-        _w8_matmul_nk_kernel,
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec((m, k), lambda i: (0, 0)),
-            pl.BlockSpec((bn, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=pallas_interpret(interpret),
-    )(x2, wq, scale.reshape(1, n))
+    with jax.named_scope("apex_w8_matmul_nk"):
+        out = pl.pallas_call(
+            _w8_matmul_nk_kernel,
+            grid=(n // bn,),
+            in_specs=[
+                pl.BlockSpec((m, k), lambda i: (0, 0)),
+                pl.BlockSpec((bn, k), lambda i: (i, 0)),
+                pl.BlockSpec((1, bn), lambda i: (0, i)),
+            ],
+            out_specs=pl.BlockSpec((m, bn), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+            interpret=pallas_interpret(interpret),
+            name="apex_w8_matmul_nk",
+        )(x2, wq, scale.reshape(1, n))
     return out.reshape(lead + (n,))
 
 

@@ -13,7 +13,14 @@ APX512 donation discipline are never perturbed:
   for humans and Perfetto, excluded from the replay contract).
   ``dump_jsonl`` writes chrome-tracing / Perfetto "JSON object per
   line" events (``ph``/``ts``/``name``; ``ts`` is ticks scaled so one
-  tick renders as 1ms, real wall time rides in ``args``).
+  tick renders as 1ms, real wall time rides in ``args``). The tick
+  clock is for replay and tests. For PERFORMANCE every ``begin`` /
+  ``end`` pair also opens and closes a span on the profiler's own
+  clock (``apex:sched/<phase>``, through the injected ``annotate``
+  factory — ``utils.profiler.span`` on an engine), enabled or not:
+  under a ``jax.profiler`` session the tick's phases then lie on the
+  same timeline as the device's operations, and with no session a
+  span costs about a microsecond.
 - :class:`MetricsRegistry` — counters, gauges, and fixed-bucket
   histograms (TTFT in ticks, inter-token ticks, committed tokens per
   tick, per-stream acceptance, pool occupancy, queue depth),
@@ -27,10 +34,10 @@ APX512 donation discipline are never perturbed:
   so a chaos failure ships its own last-N-events diagnosis.
 
 The inert contract mirrors ``FaultInjector``: an engine constructed
-without a tracer gets ``Tracer(enabled=False)``, and every hook site
-in the scheduler is guarded by a single attribute check
-(``if trc.enabled:``) — the disabled path adds one branch per site and
-records nothing.
+without a tracer gets ``Tracer(enabled=False)``. Its ``begin``/``end``
+open and close the profiler span and record nothing; every other hook
+site in the scheduler (instants, metric hooks) is guarded by a single
+attribute check (``if trc.enabled:``).
 
 Everything here is plain host-side Python state: no jax imports, and
 like ``serving.health`` / ``serving.faults`` this module is registered
@@ -43,11 +50,18 @@ import bisect
 import json
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, NamedTuple,
+                    Optional, Tuple)
 
-#: Per-tick phase spans, in tick order. ``prefill`` one jitted
-#: whole-prompt forward at admission; ``exec`` covers the jitted
-#: decode / verify / tree-verify dispatch inside the engine;
+#: Per-tick phase spans, in tick order. ``step`` is the whole tick and
+#: every other span lies inside it: ``expire`` the deadline sweep,
+#: ``admit`` admission (``prefill`` spans nest in it), ``build_inputs``
+#: the host arrays and per-slot sampling keys a decode / verify step
+#: takes, ``flush`` the end-of-tick stream delivery. ``prefill`` one
+#: jitted whole-prompt forward at admission, padding included;
+#: ``exec`` covers the jitted decode / verify / tree-verify DISPATCH
+#: inside the engine (asynchronous: the host waits for the device in
+#: ``accept``, where the samples are read back);
 #: ``chunk_prefill`` one jitted prompt-chunk forward (several may run
 #: per tick, one span each); ``page_transfer`` one host-staged
 #: cross-replica page handoff (``serving.transfer.PageTransfer``,
@@ -56,8 +70,9 @@ from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
 #: pool router's default handoff); the rest are host-side scheduler
 #: phases. apxlint APX804 resolves every ``begin``/``end`` emit site
 #: against this tuple.
-PHASES = ("prefill", "draft", "prepare_decode", "exec", "accept",
-          "commit", "chunk_prefill", "page_transfer", "reshard")
+PHASES = ("step", "expire", "admit", "prefill", "draft",
+          "prepare_decode", "build_inputs", "exec", "accept", "commit",
+          "flush", "chunk_prefill", "page_transfer", "reshard")
 
 #: Per-request lifecycle instants. ``host_spill`` / ``host_promote``
 #: mark KV pages crossing the HBM <-> host-tier boundary (one instant
@@ -341,24 +356,34 @@ class Tracer:
     """Span/event tracer + metric hooks for the scheduler's tick loop.
 
     Hook contract (mirrors the inert ``FaultInjector``): the scheduler
-    holds ``trc = self.tracer`` and guards EVERY call with
-    ``if trc.enabled:`` — a disabled tracer costs one attribute check
-    per site and records nothing. The scheduler advances :attr:`tick`
-    once per loop iteration, so all events within a tick share its
-    deterministic timestamp.
+    holds ``trc = self.tracer``, calls :meth:`begin` / :meth:`end`
+    unguarded (one emit site per phase: the profiler span always, the
+    tick-clock event when enabled) and guards every OTHER call with
+    ``if trc.enabled:`` — a disabled tracer records nothing. The
+    scheduler advances :attr:`tick` once per loop iteration, so all
+    events within a tick share its deterministic timestamp.
+
+    ``annotate(name, **counts)`` makes the profiler span (a context
+    manager); the engine injects ``utils.profiler.span`` when none was
+    given, so this module imports no jax. ``admitting`` is the request
+    the scheduler is admitting, stamped before ``engine.prefill`` so
+    the engine's ``prefill`` span can name its request.
     """
 
     def __init__(self, enabled: bool = True,
                  registry: Optional[MetricsRegistry] = None,
                  recorder: Optional[FlightRecorder] = None,
-                 max_events: int = 1_000_000):
+                 max_events: int = 1_000_000,
+                 annotate: Optional[Callable[..., Any]] = None):
         self.enabled = enabled
+        self.annotate = annotate
+        self.admitting = -1
         self.registry = registry if registry is not None else MetricsRegistry()
         self.recorder = recorder if recorder is not None else FlightRecorder()
         self.events: List[TraceEvent] = []
         self.tick = 0
         self.dropped = 0
-        self._open: Dict[str, Tuple[int, float]] = {}
+        self._open: Dict[str, Tuple] = {}
         self._max_events = max_events
         # per-tick metric hooks resolve their registry entry once and
         # keep the object — the (name, labels)-keyed lookup is off the
@@ -384,15 +409,40 @@ class Tracer:
             request_id, slot,
             tuple(sorted(args.items())) if args else ()))
 
-    def begin(self, name: str) -> None:
+    def begin(self, name: str, request_id: int = -1, slot: int = -1,
+              **args) -> None:
         """Open a span; close it with :meth:`end`. Spans are keyed by
         name — the tick loop is single-threaded and phases never nest
-        under the same name."""
-        self._open[name] = (self.tick, time.perf_counter())
+        under the same name. ``args`` are what is known now: they ride
+        on the profiler span (with ``rid`` / ``slot`` when given) and,
+        when enabled, in the recorded event."""
+        ann = None
+        if self.annotate is not None:
+            stats = args
+            if request_id >= 0 or slot >= 0:
+                stats = dict(args)
+                if request_id >= 0:
+                    stats["rid"] = request_id
+                if slot >= 0:
+                    stats["slot"] = slot
+            ann = self.annotate("sched/" + name, **stats)
+            ann.__enter__()
+        self._open[name] = (self.tick, time.perf_counter(), ann,
+                            request_id, slot, args)
 
-    def end(self, name: str, request_id: int = -1, slot: int = -1,
-            **args) -> None:
-        tick, t0 = self._open.pop(name, (self.tick, time.perf_counter()))
+    def end(self, name: str, **args) -> None:
+        """Close the span ``name``; ``args`` (what only the close
+        knows, e.g. a transfer's attempts) join the recorded event."""
+        opened = self._open.pop(name, None)
+        if opened is None:
+            return
+        tick, t0, ann, request_id, slot, at_open = opened
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if not self.enabled:
+            return
+        if at_open:
+            args = {**at_open, **args}
         self._record(TraceEvent(
             name, "X", tick, t0, time.perf_counter() - t0,
             request_id, slot,

@@ -162,6 +162,7 @@ from apex_tpu.serving.sampling import (
     finite_rows, sample_token_grid, sample_tokens,
     tree_speculative_accept,
 )
+from apex_tpu.utils.profiler import span as profiler_span
 from apex_tpu.utils.seqlen import bucket_for, default_buckets, pad_to_bucket
 
 
@@ -196,6 +197,17 @@ class _PrefillProgress:
     state: Dict
 
 
+def _on_profiler_clock(tracer: Optional[Tracer]) -> Tracer:
+    """The engine's tracer (a disabled one when none was given), its
+    spans also opened on the profiler's clock: ``observe`` imports no
+    jax, so the ``apex:`` span factory is injected here."""
+    if tracer is None:
+        tracer = Tracer(enabled=False)
+    if tracer.annotate is None:
+        tracer.annotate = profiler_span
+    return tracer
+
+
 @dataclasses.dataclass
 class _Slot:
     request_id: int
@@ -213,8 +225,9 @@ class DecodeEngine:
     into the programs (``spec_k`` is the DRAFT DEPTH; 0 disables
     speculation). ``injector`` hooks the fault sites (inert by
     default); ``tracer`` hooks the observability sites the same way
-    (``serving.observe`` — disabled by default, one attribute check
-    per site); ``stats`` is the
+    (``serving.observe`` — disabled by default: it records nothing,
+    and its ``begin``/``end`` still open the phase's ``apex:sched/*``
+    span on the profiler's clock); ``stats`` is the
     :class:`~apex_tpu.serving.health.ServingStats` counter block the
     scheduler shares, a view over the tracer's metrics registry."""
 
@@ -253,7 +266,7 @@ class DecodeEngine:
         self.tree_spec = tree_spec
         self.adaptive_spec = adaptive_spec
         self.injector = injector or FaultInjector()
-        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
+        self.tracer = _on_profiler_clock(tracer)
         self.stats = ServingStats(registry=self.tracer.registry)
         if jnp.dtype(cache_dtype) == jnp.int8:
             raise ValueError(
@@ -310,14 +323,14 @@ class DecodeEngine:
             raise InjectedFault("prefill_exec",
                                 self.injector.calls("prefill_exec") - 1)
         ids = np.asarray(prompt, np.int32)[None, :]
-        ids, mask = pad_to_bucket(ids, ids.shape[1], buckets=self.buckets)
         trc = self.tracer
-        if trc.enabled:
-            trc.begin("prefill")
+        trc.begin("prefill", request_id=trc.admitting, slot=slot,
+                  bucket=bucket_for(ids.shape[1], self.buckets),
+                  prompt_tokens=ids.shape[1])
+        ids, mask = pad_to_bucket(ids, ids.shape[1], buckets=self.buckets)
         self.cache, logits = self._prefill(
             self.params, self.cache, ids, mask, jnp.int32(slot))
-        if trc.enabled:
-            trc.end("prefill", slot=slot, bucket=int(ids.shape[1]))
+        trc.end("prefill")
         return logits
 
     # -- chunked prefill ------------------------------------------------
@@ -351,14 +364,12 @@ class DecodeEngine:
         ids = np.asarray(chunk, np.int32)[None, :]
         ids, mask = pad_to_bucket(ids, ids.shape[1], buckets=(bucket,))
         trc = self.tracer
-        if trc.enabled:
-            trc.begin("chunk_prefill")
+        trc.begin("chunk_prefill", slot=slot, pos=pos, bucket=bucket,
+                  final=final)
         self.cache, logits = self._chunk_prefill(
             self.params, self.cache, ids, mask, jnp.int32(slot),
             jnp.int32(pos))
-        if trc.enabled:
-            trc.end("chunk_prefill", slot=slot, pos=pos, bucket=bucket,
-                    final=final)
+        trc.end("chunk_prefill")
         return logits
 
     def finish_chunk_prefill(self, slot: int, state: Dict) -> None:
@@ -374,12 +385,10 @@ class DecodeEngine:
         (:func:`~apex_tpu.serving.sampling.finite_rows`) must catch
         it."""
         trc = self.tracer
-        if trc.enabled:
-            trc.begin("exec")
+        trc.begin("exec", kind="decode")
         self.cache, logits = self._decode(self.params, self.cache,
                                           tokens, active)
-        if trc.enabled:
-            trc.end("exec", kind="decode")
+        trc.end("exec")
         fired, payload = self.injector.draw("decode_exec")
         if fired:
             victim = int(payload % logits.shape[0])
@@ -473,12 +482,10 @@ class DecodeEngine:
         site covers this step too (the victim row goes NaN across all
         positions, post-jit)."""
         trc = self.tracer
-        if trc.enabled:
-            trc.begin("exec")
+        trc.begin("exec", kind="verify", k1=int(tokens.shape[1]))
         self.cache, logits = self._verify(self.params, self.cache,
                                           tokens)
-        if trc.enabled:
-            trc.end("exec", kind="verify", k1=int(tokens.shape[1]))
+        trc.end("exec")
         fired, payload = self.injector.draw("decode_exec")
         if fired:
             victim = int(payload % logits.shape[0])
@@ -495,12 +502,10 @@ class DecodeEngine:
         logits; commits stay host-side (:meth:`commit`). Shares the
         ``decode_exec`` fault site with the other step kinds."""
         trc = self.tracer
-        if trc.enabled:
-            trc.begin("exec")
+        trc.begin("exec", kind="tree_verify", k1=int(tokens.shape[1]))
         self.cache, logits = self._tree_verify(self.params, self.cache,
                                                tokens, depth, anc)
-        if trc.enabled:
-            trc.end("exec", kind="tree_verify", k1=int(tokens.shape[1]))
+        trc.end("exec")
         fired, payload = self.injector.draw("decode_exec")
         if fired:
             victim = int(payload % logits.shape[0])
@@ -513,13 +518,11 @@ class DecodeEngine:
         beyond ``lengths + count`` were written but are never admitted
         by any mask before the next step re-writes them."""
         trc = self.tracer
-        if trc.enabled:
-            trc.begin("commit")
+        trc.begin("commit")
         self.cache = self.cache._replace(
             lengths=self.cache.lengths
             + jnp.asarray(counts, jnp.int32))
-        if trc.enabled:
-            trc.end("commit", rows=int(sum(int(c) for c in counts)))
+        trc.end("commit")
 
     def sample_grid(self, logits, keys, temperature) -> jax.Array:
         """Sample every (slot, position) of a verify step's logits with
@@ -642,7 +645,7 @@ class PagedDecodeEngine(DecodeEngine):
         self.tree_spec = tree_spec
         self.adaptive_spec = adaptive_spec
         self.injector = injector or FaultInjector()
-        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
+        self.tracer = _on_profiler_clock(tracer)
         self.stats = ServingStats(registry=self.tracer.registry)
         # both quantization levers are independent: weight-only int8 is
         # detected from the tree (dequant-fused dense/logits kernels),
@@ -801,8 +804,10 @@ class PagedDecodeEngine(DecodeEngine):
             skip = min(covered, max(n_pages - 1, 0))
         start = skip * self.page_size
         trc = self.tracer
-        if trc.enabled:
-            trc.begin("prefill")
+        trc.begin("prefill", request_id=trc.admitting, slot=slot,
+                  bucket=bucket_for(len(toks) - start, self.buckets),
+                  prompt_tokens=len(toks), shared_pages=covered,
+                  page_size=self.page_size)
         if skip:
             ids = np.asarray(toks[start:], np.int32)[None, :]
             ids, mask = pad_to_bucket(ids, ids.shape[1],
@@ -827,9 +832,7 @@ class PagedDecodeEngine(DecodeEngine):
             self.cache, logits = self._prefill(
                 self.params, self.cache, ids, mask, jnp.int32(slot),
                 jnp.asarray(write), jnp.asarray(row))
-        if trc.enabled:
-            trc.end("prefill", slot=slot, bucket=int(ids.shape[1]),
-                    shared_pages=covered)
+        trc.end("prefill")
         if self.prefix_sharing:
             self.pool.register_prefix(keys, pages)
         if self.host_tier is not None:
@@ -931,15 +934,13 @@ class PagedDecodeEngine(DecodeEngine):
         else:
             store = np.full((self.max_pages,), SCRATCH_PAGE, np.int32)
         trc = self.tracer
-        if trc.enabled:
-            trc.begin("chunk_prefill")
+        trc.begin("chunk_prefill", slot=slot, pos=pos, bucket=bucket,
+                  final=final, shared_pages=state["shared"])
         self.cache, logits = self._chunk_prefill(
             self.params, self.cache, ids, mask, jnp.int32(slot),
             jnp.int32(pos), jnp.asarray(write),
             jnp.asarray(state["row"]), jnp.asarray(store))
-        if trc.enabled:
-            trc.end("chunk_prefill", slot=slot, pos=pos, bucket=bucket,
-                    final=final, shared_pages=state["shared"])
+        trc.end("chunk_prefill")
         return logits
 
     def finish_chunk_prefill(self, slot: int, state: Dict) -> None:
@@ -1682,6 +1683,7 @@ class ContinuousBatchingScheduler:
             # it had produced EXCEPT its last sampled token, which the
             # next decode tick feeds (the normal teacher-forcing shape)
             tokens = tuple(req.prompt) + tuple(resume[:-1])
+            self.tracer.admitting = rid
             try:
                 logits = eng.prefill(i, tokens)
             except PoolExhausted as e:
@@ -2088,12 +2090,9 @@ class ContinuousBatchingScheduler:
             # narrow to 1 + the widest draft actually proposed, so the
             # per-tick page charge below tracks the controller.
             if eng.spec_k > 0 and positions:
-                if trc.enabled:
-                    trc.begin("draft")
+                trc.begin("draft")
                 drafts = self._draft_all(self._spec_ks(positions))
-                if trc.enabled:
-                    trc.end("draft",
-                            proposed=sum(len(d) for d in drafts))
+                trc.end("draft")
             else:
                 drafts = None
             k1 = eng.spec_k + 1
@@ -2107,12 +2106,10 @@ class ContinuousBatchingScheduler:
         # requeue in submission order: appendleft of the newest request
         # first leaves the oldest at the queue front (slot-index order
         # would let a later request resume before an earlier one)
-        if trc.enabled:
-            trc.begin("prepare_decode")
+        trc.begin("prepare_decode")
         preempted = eng.prepare_decode(
             positions, n_new=k1 if spec else 1)
-        if trc.enabled:
-            trc.end("prepare_decode", preempted=len(preempted))
+        trc.end("prepare_decode")
         for i in sorted(preempted,
                         key=lambda j: self._slots[j].request_id,
                         reverse=True):
@@ -2130,6 +2127,7 @@ class ContinuousBatchingScheduler:
             self._spec_tick(drafts, k1)
             return k1 * len(occupied)
         self.stats.plain_ticks += 1
+        trc.begin("build_inputs")
         tokens = jnp.asarray(
             [s.generated[-1] if self._decoding(s) else 0
              for s in self._slots], jnp.int32)
@@ -2140,14 +2138,13 @@ class ContinuousBatchingScheduler:
         keys = jnp.stack(
             [self._slot_key(s) if self._decoding(s)
              else jax.random.PRNGKey(0) for s in self._slots])
+        trc.end("build_inputs")
         logits = eng.decode(tokens, active)
-        if trc.enabled:
-            trc.begin("accept")
+        trc.begin("accept")
         finite = np.asarray(eng.finite(logits))
         next_tokens = np.asarray(eng.sample(logits, keys, temps))
-        if trc.enabled:
-            trc.end("accept")
-            trc.begin("commit")
+        trc.end("accept")
+        trc.begin("commit")
         vocab = eng.cfg.vocab_size
         quarantined: List[Tuple[int, NonFiniteLogits]] = []
         for i, slot in enumerate(self._slots):
@@ -2171,8 +2168,7 @@ class ContinuousBatchingScheduler:
             self._tokens_emitted += 1
             self._note_token(slot.request_id, i)
             self._maybe_evict(i)
-        if trc.enabled:
-            trc.end("commit")
+        trc.end("commit")
         # quarantine AFTER the healthy slots commit, requeueing at the
         # front in submission order (same rule as preemption)
         for i, err in sorted(
@@ -2196,6 +2192,7 @@ class ContinuousBatchingScheduler:
         eng = self.engine
         trc = self.tracer
         self.stats.spec_ticks += 1
+        trc.begin("build_inputs")
         rows = []
         for i, s in enumerate(self._slots):
             d = drafts[i][:k1 - 1]
@@ -2212,9 +2209,9 @@ class ContinuousBatchingScheduler:
             [[(len(s.generated) if self._decoding(s) else 0) + j
               for j in range(k1)] for s in self._slots], jnp.int32)
         keys = self._fold_grid(base, offs)
+        trc.end("build_inputs")
         logits = eng.verify(tokens)
-        if trc.enabled:
-            trc.begin("accept")
+        trc.begin("accept")
         finite = np.asarray(eng.finite(logits))            # (B, k1)
         grid = np.asarray(eng.sample_grid(logits, keys, temps))
         vocab = eng.cfg.vocab_size
@@ -2267,8 +2264,7 @@ class ContinuousBatchingScheduler:
             if eng.adaptive_spec and draft:
                 self._accept_ewma[i] = 0.5 * self._accept_ewma[i] \
                     + 0.5 * accepted / len(draft)
-        if trc.enabled:
-            trc.end("accept", committed=sum(counts))
+        trc.end("accept")
         eng.commit(counts)
         # a tick that commits m tokens counts m toward deadlines: the
         # scheduler clock stays in decode-step equivalents across modes
@@ -2306,13 +2302,9 @@ class ContinuousBatchingScheduler:
         eng = self.engine
         trc = self.tracer
         ks = self._spec_ks(positions)
-        if trc.enabled:
-            trc.begin("draft")
+        trc.begin("draft")
         trees = self._draft_trees(ks)
-        if trc.enabled:
-            trc.end("draft",
-                    proposed=sum(len(t[0]) for t in trees
-                                 if t is not None))
+        trc.end("draft")
         forced: Dict[int, List[int]] = {}
         for i, s in enumerate(self._slots):
             if self._decoding(s):
@@ -2330,11 +2322,9 @@ class ContinuousBatchingScheduler:
                  + (len(trees[i][0]) if trees[i] is not None else 0)
                  for i in positions)
         k1 = max(1, min(k1, avail))
-        if trc.enabled:
-            trc.begin("prepare_decode")
+        trc.begin("prepare_decode")
         preempted = eng.prepare_decode(positions, n_new=k1)
-        if trc.enabled:
-            trc.end("prepare_decode", preempted=len(preempted))
+        trc.end("prepare_decode")
         for i in sorted(preempted,
                         key=lambda j: self._slots[j].request_id,
                         reverse=True):
@@ -2348,6 +2338,7 @@ class ContinuousBatchingScheduler:
             forced.pop(i, None)
         if not forced:
             return 0
+        trc.begin("build_inputs")
         f_chain: List[List[int]] = []
         g_trees: List[Optional[Tuple[List[int], List[int]]]] = []
         for i, s in enumerate(self._slots):
@@ -2385,11 +2376,11 @@ class ContinuousBatchingScheduler:
                 offs[i] = (len(s.generated) - len(f_chain[i]) + 1
                            + dep_np[i])
         keys = self._fold_grid(base, jnp.asarray(offs))
-        logits = eng.tree_verify(jnp.asarray(tok_np),
-                                 jnp.asarray(dep_np),
-                                 jnp.asarray(anc_np))
-        if trc.enabled:
-            trc.begin("accept")
+        tok_d, dep_d, anc_d = (jnp.asarray(tok_np), jnp.asarray(dep_np),
+                               jnp.asarray(anc_np))
+        trc.end("build_inputs")
+        logits = eng.tree_verify(tok_d, dep_d, anc_d)
+        trc.begin("accept")
         finite = np.asarray(eng.finite(logits))            # (B, k1)
         grid = np.asarray(eng.sample_grid(logits, keys, temps))
         cnts, path = self._tree_accept(
@@ -2457,8 +2448,7 @@ class ContinuousBatchingScheduler:
                     + 0.5 * accepted / nodes
             if bad is not None:
                 quarantined.append((i, bad))
-        if trc.enabled:
-            trc.end("accept", committed=sum(counts))
+        trc.end("accept")
         eng.commit(counts)
         self.stats.spec_ticks += 1
         # a tick that commits m tokens counts m toward deadlines: the
@@ -2512,23 +2502,13 @@ class ContinuousBatchingScheduler:
         self._tick_no += 1
         if trc.enabled:
             trc.set_tick(self._tick_no)
-        before = self._tokens_emitted
-        self._expire_deadlines()
-        self._admit()
-        self._tick()
-        if self.streams is not None:
-            # end-of-tick delivery: every stream gets exactly the
-            # tokens this tick committed for it (1..k+1 under
-            # speculation), one stream_emit draw per delivering stream
-            self.streams.flush()
-        if trc.enabled:
-            trc.tick_metrics(self._tokens_emitted - before,
-                             len(self._queue),
-                             self.engine.pool_gauges())
-            if self.tenancy is not None:
-                trc.tenant_gauges(self.tenancy.gauge_snapshot())
-        if self.audit:
-            self.engine.check_invariants()
+        trc.begin("step", tick=self._tick_no,
+                  decoding=sum(map(self._decoding, self._slots)),
+                  queued=len(self._queue))
+        try:
+            self._phases()
+        finally:
+            trc.end("step")
         snap = (self._tokens_emitted, len(self.outcomes),
                 self.stats.retries, self.stats.prefill_chunks)
         if snap == self._watch_snap:
@@ -2537,6 +2517,34 @@ class ContinuousBatchingScheduler:
                 self._raise_livelock(self._stalled)
         else:
             self._stalled, self._watch_snap = 0, snap
+
+    def _phases(self) -> None:
+        """The tick's phases, each under its span, inside ``step``."""
+        trc = self.tracer
+        before = self._tokens_emitted
+        trc.begin("expire")
+        self._expire_deadlines()
+        trc.end("expire")
+        trc.begin("admit")
+        self._admit()
+        trc.admitting = -1
+        trc.end("admit")
+        self._tick()
+        if self.streams is not None:
+            # end-of-tick delivery: every stream gets exactly the
+            # tokens this tick committed for it (1..k+1 under
+            # speculation), one stream_emit draw per delivering stream
+            trc.begin("flush")
+            self.streams.flush()
+            trc.end("flush")
+        if trc.enabled:
+            trc.tick_metrics(self._tokens_emitted - before,
+                             len(self._queue),
+                             self.engine.pool_gauges())
+            if self.tenancy is not None:
+                trc.tenant_gauges(self.tenancy.gauge_snapshot())
+        if self.audit:
+            self.engine.check_invariants()
 
     def run(self) -> List[List[int]]:
         """Drain the queue; returns generated tokens (EOS included when

@@ -361,8 +361,7 @@ class PageTransfer:
         chain_key = prefix_page_keys(
             [int(t) for t in tokens], src_engine.page_size)[-1]
         n_pages = len(src_pages)
-        if trc.enabled:
-            trc.begin(self.span)
+        trc.begin(self.span, pages=n_pages, replica=replica)
         corrupt_last = False
         for attempt in range(self.max_retries + 1):
             if attempt:
@@ -404,16 +403,11 @@ class PageTransfer:
                 c_bytes.inc(int(k_tile.nbytes) + int(v_tile.nbytes))
             if health is not None:
                 health.probe(True)
-            if trc.enabled:
-                trc.end(self.span, pages=n_pages,
-                        attempts=attempt + 1, replica=replica)
+            trc.end(self.span, attempts=attempt + 1)
             return k_tile, v_tile, attempt + 1
         self._bump("failures")
         c_failures.inc()
-        if trc.enabled:
-            trc.end(self.span, pages=n_pages,
-                    attempts=self.max_retries + 1, replica=replica,
-                    failed=True)
+        trc.end(self.span, attempts=self.max_retries + 1, failed=True)
         err = self._budget_error(replica, self.max_retries + 1, n_pages,
                                  corrupt_last)
         raise self.tracer.attach(err) if trc.enabled else err

@@ -551,21 +551,23 @@ def _fwd_call(q, k, v, mask, *, causal, scale, rate, seed, interpret):
                              memory_space=pltpu.VMEM)
     lse_spec = pl.BlockSpec((1, 1, bq), lambda i, qt, kt: (i, 0, qt),
                             memory_space=pltpu.VMEM)
-    o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, sk=sk, causal=causal, rate=rate,
-                          has_mask=mask is not None, pad=sk != sk_p),
-        grid=grid,
-        in_specs=[_smem(), _qkv_spec(bq, d_p), kv_spec, kv_spec,
-                  mask_spec],
-        out_specs=(_qkv_spec(bq, d_p), lse_spec),
-        out_shape=(jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, 1, sq_p), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32)],
-        compiler_params=_cparams(),
-        interpret=pallas_interpret(interpret),
-    )(sd, q3, k3, v3, m3)
+    with jax.named_scope("apex_flash_fwd"):
+        o, lse = pl.pallas_call(
+            functools.partial(_fwd_kernel, sk=sk, causal=causal, rate=rate,
+                              has_mask=mask is not None, pad=sk != sk_p),
+            grid=grid,
+            in_specs=[_smem(), _qkv_spec(bq, d_p), kv_spec, kv_spec,
+                      mask_spec],
+            out_specs=(_qkv_spec(bq, d_p), lse_spec),
+            out_shape=(jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
+                       jax.ShapeDtypeStruct((b * h, 1, sq_p), jnp.float32)),
+            scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32),
+                            pltpu.VMEM((bq, 128), jnp.float32),
+                            pltpu.VMEM((bq, 128), jnp.float32)],
+            compiler_params=_cparams(),
+            interpret=pallas_interpret(interpret),
+            name="apex_flash_fwd",
+        )(sd, q3, k3, v3, m3)
     out = o[:, :sq, :d].reshape(b, h, sq, d)
     return out, lse  # lse stays padded (b*h, 1, sq_p)
 
@@ -593,18 +595,20 @@ def _bwd_call(q, k, v, mask, out, lse_p, do, *, causal, scale, rate, seed,
     mask_spec = pl.BlockSpec((1, 1, bk),
                              lambda i, qt, kt: (i // h, 0, ckt(kt, qt)),
                              memory_space=pltpu.VMEM)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sk=sk, causal=causal, rate=rate,
-                          has_mask=mask is not None, pad=sk != sk_p),
-        grid=(b * h, sq_p // bq, sk_p // bk),
-        in_specs=[_smem(), _qkv_spec(bq, d_p), kv_spec, kv_spec,
-                  mask_spec, _qkv_spec(bq, d_p), row_spec, row_spec],
-        out_specs=_qkv_spec(bq, d_p),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32)],
-        compiler_params=_cparams(),
-        interpret=pallas_interpret(interpret),
-    )(sd, q3, k3, v3, m3, do3, lse_p, delta)
+    with jax.named_scope("apex_flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, sk=sk, causal=causal, rate=rate,
+                              has_mask=mask is not None, pad=sk != sk_p),
+            grid=(b * h, sq_p // bq, sk_p // bk),
+            in_specs=[_smem(), _qkv_spec(bq, d_p), kv_spec, kv_spec,
+                      mask_spec, _qkv_spec(bq, d_p), row_spec, row_spec],
+            out_specs=_qkv_spec(bq, d_p),
+            out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d_p), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d_p), jnp.float32)],
+            compiler_params=_cparams(),
+            interpret=pallas_interpret(interpret),
+            name="apex_flash_bwd_dq",
+        )(sd, q3, k3, v3, m3, do3, lse_p, delta)
 
     # dkv: k outer / q inner — index maps swap roles; causal clamp
     # mirrors _clamp_kt (q tiles strictly above the diagonal are dead)
@@ -622,20 +626,22 @@ def _bwd_call(q, k, v, mask, out, lse_p, do, *, causal, scale, rate, seed,
                               memory_space=pltpu.VMEM)
     row_spec2 = pl.BlockSpec((1, 1, sq_p), lambda i, kt, qt: (i, 0, 0),
                              memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, sk=sk, causal=causal, rate=rate,
-                          has_mask=mask is not None, pad=sk != sk_p),
-        grid=(b * h, sk_p // bk, sq_p // bq),
-        in_specs=[_smem(), q_spec2, kv_spec2, kv_spec2, mask_spec2,
-                  q_spec2, row_spec2, row_spec2],
-        out_specs=(kv_spec2, kv_spec2),
-        out_shape=(jax.ShapeDtypeStruct((b * h, sk_p, d_p), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk_p, d_p), v.dtype)),
-        scratch_shapes=[pltpu.VMEM((bk, d_p), jnp.float32),
-                        pltpu.VMEM((bk, d_p), jnp.float32)],
-        compiler_params=_cparams(),
-        interpret=pallas_interpret(interpret),
-    )(sd, q3, k3, v3, m3, do3, lse_p, delta)
+    with jax.named_scope("apex_flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, sk=sk, causal=causal, rate=rate,
+                              has_mask=mask is not None, pad=sk != sk_p),
+            grid=(b * h, sk_p // bk, sq_p // bq),
+            in_specs=[_smem(), q_spec2, kv_spec2, kv_spec2, mask_spec2,
+                      q_spec2, row_spec2, row_spec2],
+            out_specs=(kv_spec2, kv_spec2),
+            out_shape=(jax.ShapeDtypeStruct((b * h, sk_p, d_p), k.dtype),
+                       jax.ShapeDtypeStruct((b * h, sk_p, d_p), v.dtype)),
+            scratch_shapes=[pltpu.VMEM((bk, d_p), jnp.float32),
+                            pltpu.VMEM((bk, d_p), jnp.float32)],
+            compiler_params=_cparams(),
+            interpret=pallas_interpret(interpret),
+            name="apex_flash_bwd_dkv",
+        )(sd, q3, k3, v3, m3, do3, lse_p, delta)
 
     # dq kernel produced d(scale*q); one fused XLA multiply finishes it
     dq = (dq[:, :sq, :d].astype(jnp.float32) * jnp.float32(scale)

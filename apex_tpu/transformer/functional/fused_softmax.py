@@ -84,15 +84,17 @@ def _bwd_call(y3, dy3, scale, interpret):
     yp, dyp = _pad_q(y3, tile), _pad_q(dy3, tile)
     grid = (batches, yp.shape[1] // tile)
     sc = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    dx = pl.pallas_call(
-        _bwd_kernel,
-        grid=grid,
-        in_specs=[_smem(), _row_specs(tile, sk), _row_specs(tile, sk)],
-        out_specs=_row_specs(tile, sk),
-        out_shape=jax.ShapeDtypeStruct(yp.shape, y3.dtype),
-        compiler_params=_dimsem("parallel", "parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, yp, dyp)
+    with jax.named_scope("apex_softmax_bwd"):
+        dx = pl.pallas_call(
+            _bwd_kernel,
+            grid=grid,
+            in_specs=[_smem(), _row_specs(tile, sk), _row_specs(tile, sk)],
+            out_specs=_row_specs(tile, sk),
+            out_shape=jax.ShapeDtypeStruct(yp.shape, y3.dtype),
+            compiler_params=_dimsem("parallel", "parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_softmax_bwd",
+        )(sc, yp, dyp)
     return dx[:, :q]
 
 
@@ -111,15 +113,17 @@ def _sms_fwd(x, mask, scale, interpret):
     mask_spec = pl.BlockSpec((1, tile, sk), lambda i, j: (i // np_, j, 0),
                              memory_space=pltpu.VMEM)
     sc = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    y = pl.pallas_call(
-        _masked_fwd_kernel,
-        grid=grid,
-        in_specs=[_smem(), _row_specs(tile, sk), mask_spec],
-        out_specs=_row_specs(tile, sk),
-        out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
-        compiler_params=_dimsem("parallel", "parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, xp, mp)
+    with jax.named_scope("apex_softmax_masked_fwd"):
+        y = pl.pallas_call(
+            _masked_fwd_kernel,
+            grid=grid,
+            in_specs=[_smem(), _row_specs(tile, sk), mask_spec],
+            out_specs=_row_specs(tile, sk),
+            out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
+            compiler_params=_dimsem("parallel", "parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_softmax_masked_fwd",
+        )(sc, xp, mp)
     return y[:, :sq].reshape(b, np_, sq, sk)
 
 
@@ -158,15 +162,17 @@ def _sut_fwd(x3, scale, interpret):
     xp = _pad_q(x3, tile)
     grid = (batches, xp.shape[1] // tile)
     sc = jnp.asarray(scale, jnp.float32).reshape(1, 1)
-    y = pl.pallas_call(
-        _causal_fwd_kernel,
-        grid=grid,
-        in_specs=[_smem(), _row_specs(tile, sk)],
-        out_specs=_row_specs(tile, sk),
-        out_shape=jax.ShapeDtypeStruct(xp.shape, x3.dtype),
-        compiler_params=_dimsem("parallel", "parallel"),
-        interpret=pallas_interpret(interpret),
-    )(sc, xp)
+    with jax.named_scope("apex_softmax_causal_fwd"):
+        y = pl.pallas_call(
+            _causal_fwd_kernel,
+            grid=grid,
+            in_specs=[_smem(), _row_specs(tile, sk)],
+            out_specs=_row_specs(tile, sk),
+            out_shape=jax.ShapeDtypeStruct(xp.shape, x3.dtype),
+            compiler_params=_dimsem("parallel", "parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_softmax_causal_fwd",
+        )(sc, xp)
     return y[:, :sq]
 
 

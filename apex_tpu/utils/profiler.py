@@ -11,7 +11,13 @@ The reference leans on ``pyprof``/nvprof markers (removed upstream) and
   regions named here appear on the trace's Python/HLO-metadata rows, and
   the scope names survive into HLO op metadata so device kernels
   attribute back to model regions. The in-tree models and fused
-  optimizers are pre-annotated (attention / mlp / optimizer scopes).
+  optimizers are pre-annotated (attention / mlp / optimizer scopes), and
+  every Pallas kernel carries a stable ``apex_<kernel>_<fwd|bwd>`` name
+  and scope: a Mosaic ``custom-call`` is named in the trace after the
+  innermost scope around it.
+- :func:`span` is the HOST side: a ``jax.profiler.TraceAnnotation``
+  named ``apex:<name>`` on the same timeline as the device planes. The
+  serving scheduler opens one per phase of its tick.
 
 Typical use::
 
@@ -33,11 +39,30 @@ import jax
 annotate = jax.named_scope
 
 
+def span(name: str, **counts):
+    """A HOST span on the profiler's clock: ``apex:<name>`` on the host
+    plane of the trace, beside the device planes, with ``counts`` as the
+    event's stats. The serving scheduler opens one per phase of its tick
+    (``apex:sched/<phase>``, ``serving.observe.PHASES``). With no
+    profiler session it costs about a microsecond and records nothing."""
+    return jax.profiler.TraceAnnotation("apex:" + name, **counts)
+
+
 @contextlib.contextmanager
-def trace(log_dir: str, *, create_perfetto_link: bool = False):
-    """Capture a device+host profile under ``log_dir``."""
+def trace(log_dir: str, *, create_perfetto_link: bool = False,
+          python_tracer: bool = True):
+    """Capture a device+host profile under ``log_dir``.
+
+    ``python_tracer=False`` switches the profiler's Python function
+    tracer off: the host plane then holds the program's own spans
+    (:func:`span`) and not every Python call, and the host is not slowed
+    by being watched (a serving tick ran a fifth slower under it)."""
+    options = jax.profiler.ProfileOptions()
+    if not python_tracer:
+        options.python_tracer_level = 0
     jax.profiler.start_trace(log_dir,
-                             create_perfetto_link=create_perfetto_link)
+                             create_perfetto_link=create_perfetto_link,
+                             profiler_options=options)
     try:
         yield
     finally:
