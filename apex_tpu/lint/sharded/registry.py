@@ -130,29 +130,24 @@ def _gpt_trees():
     import jax
 
     from apex_tpu.models.gpt import gpt_tiny, init_gpt
-    from apex_tpu.serving.cache import init_cache, init_paged_cache
+    from apex_tpu.serving.cache import init_cache
 
     cfg = gpt_tiny()
     params = jax.eval_shape(
         lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0))
+    # the paged pool has its own k/v rule (heads merged into the last
+    # axis): the quant entry's table and trees cover it
     cache = jax.eval_shape(ft.partial(init_cache, cfg, 2, 32))
-    # the paged layout keeps heads on axis 2 (same k/v rule) and adds
-    # the replicated block tables — registering it keeps the
-    # block_tables rule live for APX701 and its spec APX702-checked
-    paged = jax.eval_shape(ft.partial(init_paged_cache, cfg, 2, 32, 6, 16))
-    return {"params": params, "kv_cache": cache, "paged_kv_cache": paged}
+    return {"params": params, "kv_cache": cache}
 
 
 def _gpt_reference():
     from apex_tpu.models.gpt import gpt_partition_specs, gpt_tiny
     from apex_tpu.partition import kv_cache_rules
-    from apex_tpu.serving.cache import (
-        cache_partition_specs, paged_cache_partition_specs,
-    )
+    from apex_tpu.serving.cache import cache_partition_specs
 
     return {"params": gpt_partition_specs(gpt_tiny()),
-            "kv_cache": cache_partition_specs(kv_cache_rules()),
-            "paged_kv_cache": paged_cache_partition_specs(kv_cache_rules())}
+            "kv_cache": cache_partition_specs(kv_cache_rules())}
 
 
 def _gpt_quant_trees():

@@ -756,8 +756,8 @@ def _page_handoff_medium_entry():
         cache = jax.eval_shape(ft.partial(
             init_paged_cache, cfg, slots, s_max, num_pages, page))
         n = s_max // page  # one max-length prompt's page tile
-        tile = _sds((cfg.num_layers, n, cfg.num_heads, page,
-                     cfg.head_dim), "bfloat16")
+        tile = _sds((cfg.num_layers, n, page,
+                     cfg.num_heads * cfg.head_dim), "bfloat16")
         fn = make_insert_pages_fn()
         return fn, (cache, _sds((n,), "int32"), tile, tile)
 
@@ -861,8 +861,8 @@ def _page_promote_insert_quant_medium_entry():
             init_paged_cache, cfg, slots, s_max, num_pages, page,
             jnp.int8))
         n = s_max // page
-        tile = _sds((cfg.num_layers, n, cfg.num_heads, page,
-                     cfg.head_dim), "int8")
+        tile = _sds((cfg.num_layers, n, page,
+                     cfg.num_heads * cfg.head_dim), "int8")
         scale = _sds((cfg.num_layers, n, cfg.num_heads), "float32")
         fn = make_insert_pages_quant_fn()
         return fn, (cache, _sds((n,), "int32"), tile, tile, scale,
@@ -1505,8 +1505,10 @@ def repo_entries() -> List[TraceEntry]:
         # ragged-length paged pool at the same model shape — its
         # budgets.json row demonstrates the K/V-read cut vs the dense
         # slots x S_max charge above
+        # (the step whose attention is the paged-attention kernel: this
+        # entry is that kernel family's registration)
         TraceEntry("gpt_paged_decode_step_medium_ragged",
-                   "apex_tpu.serving.decode",
+                   "apex_tpu.transformer.functional.paged_attention",
                    _paged_decode_step_medium_ragged_entry(), checks=()),
         # the verify step at the same ragged shape — one parameter
         # read priced over k+1 candidate positions; budgets.json pins

@@ -75,8 +75,18 @@ def _kernel_name(kernel) -> str:
     return getattr(kernel, "__name__", repr(kernel))
 
 
+def _space(spec) -> str:
+    return str(getattr(spec, "memory_space", "")).lower()
+
+
 def _is_smem(spec) -> bool:
-    return "smem" in str(getattr(spec, "memory_space", "")).lower()
+    return "smem" in _space(spec)
+
+
+def _off_chip(spec) -> bool:
+    """An operand left in HBM (``ANY`` / ``HBM``: the kernel brings what
+    it wants by DMA into scratch, which is priced) or a semaphore."""
+    return _space(spec) in ("any", "hbm") or "semaphore" in _space(spec)
 
 
 def _block_bytes(spec, operand) -> int:
@@ -104,15 +114,28 @@ def capture_calls(records: List[CallRecord]):
     real = pl.pallas_call
 
     def fake(kernel, *, out_shape, grid=None, in_specs=None,
-             out_specs=None, scratch_shapes=None, **_kw):
+             out_specs=None, scratch_shapes=None, grid_spec=None, **_kw):
+        prefetch = 0
+        if grid_spec is not None:
+            grid, in_specs, out_specs, scratch_shapes = (
+                grid_spec.grid, grid_spec.in_specs, grid_spec.out_specs,
+                grid_spec.scratch_shapes)
+            prefetch = getattr(grid_spec, "num_scalar_prefetch", 0)
+
         def runner(*operands):
             import jax.numpy as jnp
 
             rec = CallRecord(_kernel_name(kernel),
                              grid if isinstance(grid, tuple) else (grid,))
+            # scalar-prefetch operands live whole in SMEM
+            for op in operands[:prefetch]:
+                rec.smem_bytes += _block_bytes(None, op)
+            operands = operands[prefetch:]
             specs = in_specs if in_specs is not None else [None] * len(
                 operands)
             for spec, op in zip(specs, operands):
+                if _off_chip(spec):
+                    continue
                 b = _block_bytes(spec, op)
                 if _is_smem(spec):
                     rec.smem_bytes += b
@@ -127,7 +150,8 @@ def capture_calls(records: List[CallRecord]):
             for spec, leaf in zip(ospecs, out_leaves):
                 rec.out_bytes += _block_bytes(spec, leaf)
             for s in scratch_shapes or []:
-                rec.scratch_bytes += _block_bytes(None, s)
+                if not _off_chip(s):
+                    rec.scratch_bytes += _block_bytes(None, s)
             records.append(rec)
             outs = [jnp.zeros(l.shape, l.dtype) for l in out_leaves]
             if isinstance(out_shape, (list, tuple)):
@@ -390,10 +414,11 @@ def _w8_matmul_cfg():
 
 def _paged_serving_cfg(which):
     """Paged serving steps under the recorder: prefill runs flash
-    attention over the prompt bucket (its pallas blocks are what the
-    budget prices); decode's gather/scatter is XLA math today, so — as
-    with the bottleneck config — registering it pins the trace and
-    covers any Pallas paged-attention kernel that lands later."""
+    attention over the prompt bucket and decode runs the paged-attention
+    kernel ``apex_paged_decode_fwd`` (their pallas blocks and the
+    kernel's K/V page buffers are what the budget prices); the verify,
+    tree-verify and chunk steps keep the XLA gather path, registered to
+    pin their trace."""
     def build():
         import dataclasses
         import functools as ft
@@ -438,6 +463,27 @@ def _paged_serving_cfg(which):
         fn = make_paged_decode_fn(cfg)
         return fn, (params, cache, _sds((2,), "int32"),
                     _sds((2,), "bool"))
+
+    return build
+
+
+def _paged_attention_cfg():
+    """The paged decode-attention kernel at the served width: 56 slots,
+    16 heads of 64, pages of 16, a bfloat16 pool left in HBM. What is
+    resident is the query / new-row / output blocks and the two K and two
+    V buffers of 128 positions the kernel fetches pages into."""
+    def build():
+        import functools as ft
+
+        from apex_tpu.transformer.functional.paged_attention import (
+            paged_decode_attention,
+        )
+
+        row = _sds((56, 1, 1024), "bfloat16")
+        pool = _sds((24, 3586, 16, 1024), "bfloat16")
+        return ft.partial(paged_decode_attention, heads=16), (
+            row, row, row, pool, pool, _sds((56, 64), "int32"),
+            _sds((56,), "int32"), _sds((), "int32"))
 
     return build
 
@@ -501,6 +547,10 @@ def repo_configs() -> List[Config]:
                        _paged_serving_cfg("chunk_prefill")))
     cfgs.append(Config("gpt_paged_decode_step", "apex_tpu.serving.decode",
                        _paged_serving_cfg("decode")))
+    cfgs.append(Config(
+        "paged_decode_attention_medium",
+        "apex_tpu.transformer.functional.paged_attention",
+        _paged_attention_cfg()))
     cfgs.append(Config("gpt_spec_verify_step", "apex_tpu.serving.decode",
                        _paged_serving_cfg("verify")))
     cfgs.append(Config("gpt_tree_verify_step", "apex_tpu.serving.decode",

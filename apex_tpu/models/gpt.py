@@ -397,76 +397,101 @@ def _block_decode(lp, x, k_cache, v_cache, pos, cfg, rope_freqs,
     return x + mlp, k_cache, v_cache
 
 
-def _paged_decode_attention(q_k_v: jax.Array, k_pages: jax.Array,
-                            v_pages: jax.Array, block_tables: jax.Array,
-                            pos: jax.Array, cfg: GPTConfig,
+def _page_rows(t: jax.Array) -> jax.Array:
+    """One position's (b, nh_local, hd) heads as the (b, nh_local * hd)
+    row a stored page holds: heads side by side."""
+    return t.reshape(t.shape[0], -1)
+
+
+def _pages_to_tiles(pages: jax.Array, hd: int) -> jax.Array:
+    """Stored pages (..., page_size, nh_local * hd) as head-major tiles
+    (..., nh_local, page_size, hd)."""
+    *lead, page_size, width = pages.shape
+    return jnp.moveaxis(
+        pages.reshape(*lead, page_size, width // hd, hd), -2, -3)
+
+
+def _tiles_to_pages(tiles: jax.Array) -> jax.Array:
+    """Head-major tiles (..., nh_local, page_size, hd) as stored pages
+    (..., page_size, nh_local * hd)."""
+    *lead, nh, page_size, hd = tiles.shape
+    return jnp.moveaxis(tiles, -3, -2).reshape(*lead, page_size, nh * hd)
+
+
+def _gather_pages(pages: jax.Array, table: jax.Array, hd: int) -> jax.Array:
+    """Every table row's pages of one layer's pool (num_pages, page_size,
+    nh_local * hd) as (b, nh_local, S, hd), S = max_pages * page_size
+    logical positions: the gather path of verify / tree verify / chunked
+    prefill (decode reads the mapped pages in place instead)."""
+    g = pages[table]
+    b, max_pages, page_size, width = g.shape
+    return g.reshape(b, max_pages * page_size, width // hd,
+                     hd).transpose(0, 2, 1, 3)
+
+
+def _paged_decode_attention(q_k_v: jax.Array, k_pool: jax.Array,
+                            v_pool: jax.Array, layer: jax.Array,
+                            block_tables: jax.Array, pos: jax.Array,
+                            cfg: GPTConfig,
                             rope_freqs: Optional[jax.Array]):
-    """Single-query attention against a PAGED KV pool.
+    """Single-query attention against the PAGED KV pool, read in place.
 
-    ``q_k_v`` is (b, 1, 3*h_local); ``k_pages``/``v_pages`` are
-    (num_pages, nh_local, page_size, hd) — one layer's slice of the
-    shared physical pool; ``block_tables`` (b, max_pages) int32 maps
-    each slot's logical page index to a physical page; ``pos`` (b,)
-    int32 is each slot's current length. The paged analogue of
-    :func:`_decode_attention`'s write-new-row-then-attend contract: the
-    new row is scattered into physical page ``block_tables[b, pos //
-    page_size]`` at row ``pos % page_size`` BEFORE attending, then the
-    slot's whole table row is gathered back and masked to ``s <= pos``.
+    ``q_k_v`` is (b, 1, 3*h_local); ``k_pool``/``v_pool`` are the WHOLE
+    stacked pool (L, num_pages, page_size, nh_local * hd) and ``layer``
+    the scalar index of the layer attending; ``block_tables`` (b,
+    max_pages) int32 maps each slot's logical page index to a physical
+    page; ``pos`` (b,) int32 is each slot's current length. The Pallas
+    kernel ``apex_paged_decode_fwd``
+    (:mod:`apex_tpu.transformer.functional.paged_attention`) brings only
+    the pages at or below ``pos`` from HBM and runs the float32 online
+    softmax over them; the pool is read, never written here.
 
-    Placement invariance: masked scores are set to ``finfo(f32).min``,
-    so their softmax probabilities are EXACTLY zero and garbage beyond
-    ``pos`` — stale rows, other requests' pages reached through the
-    gather, the scratch page — contributes exactly ``0 * v`` to the
-    context. Active-slot logits are therefore bit-identical for any
+    The paged analogue of :func:`_decode_attention`'s
+    write-new-row-then-attend contract: the new token's K/V row is rounded
+    to the pool's dtype and attended to AT position ``pos`` as an operand
+    of the kernel, and returned ((b, nh_local * hd) each) for the caller to
+    write into page ``block_tables[b, pos // page_size]`` at row ``pos %
+    page_size`` — one scatter for all layers after the layer scan
+    (``serving.decode._paged_decode_core``), so the pool is no per-layer
+    carry of the scan.
+
+    Placement invariance: rows at or past ``pos`` are masked in the scores
+    and zeroed in the values, and pages past ``pos`` are never fetched, so
+    garbage beyond ``pos`` — stale rows, NaN, other requests' pages —
+    cannot reach the context. Active-slot logits are bit-identical for any
     physical page assignment of the same logical contents (the serving
-    contract ``tests/L0/run_serving`` pins).
+    contract ``tests/L0/run_serving`` pins). Returns (ctx (b, 1, h_local),
+    k_row, v_row).
     """
-    b = q_k_v.shape[0]
+    from apex_tpu.transformer.functional.paged_attention import (
+        paged_decode_attention,
+    )
+
     hd = cfg.head_dim
-    page_size = k_pages.shape[2]
     q, k, v = _split_qkv(q_k_v, hd)            # (b, nh_local, 1, hd)
     if rope_freqs is not None:
         q = fused_apply_rotary_pos_emb_bhsd(q, rope_freqs, positions=pos)
         k = fused_apply_rotary_pos_emb_bhsd(k, rope_freqs, positions=pos)
-    logical = jnp.clip(pos // page_size, 0, block_tables.shape[1] - 1)
-    pages = jnp.take_along_axis(block_tables, logical[:, None], 1)[:, 0]
-    rows = pos % page_size
-    # (pages, :, rows) pairs advanced indices around a slice, so the
-    # scatter value is (b, nh_local, hd): the new row for every slot in
-    # one in-place update of the donated pool (APX512's contract)
-    k_pages = k_pages.at[pages, :, rows].set(
-        k[:, :, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[pages, :, rows].set(
-        v[:, :, 0].astype(v_pages.dtype))
-    # gather each slot's table row: (b, max_pages, nh, page, hd) ->
-    # (b, nh, S, hd) with S = max_pages * page_size logical positions
-    kg = k_pages[block_tables].transpose(0, 2, 1, 3, 4)
-    vg = v_pages[block_tables].transpose(0, 2, 1, 3, 4)
-    s_max = kg.shape[2] * kg.shape[3]
-    kg = kg.reshape(b, kg.shape[1], s_max, hd)
-    vg = vg.reshape(b, vg.shape[1], s_max, hd)
-    scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32),
-                        kg.astype(jnp.float32)) / math.sqrt(hd)
-    valid = jnp.arange(s_max)[None, None, None, :] \
-        <= pos[:, None, None, None]
-    scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhqs,bhsd->bhqd", probs,
-                     vg.astype(jnp.float32)).astype(q_k_v.dtype)
-    return ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1), k_pages, v_pages
+    k_row = _page_rows(k).astype(k_pool.dtype)
+    v_row = _page_rows(v).astype(v_pool.dtype)
+    ctx = paged_decode_attention(
+        _page_rows(q)[:, None], k_row[:, None], v_row[:, None], k_pool,
+        v_pool, block_tables, pos, layer, heads=q.shape[1])
+    return ctx.astype(q_k_v.dtype), k_row, v_row
 
 
-def _block_decode_paged(lp, x, k_pages, v_pages, block_tables, pos, cfg,
-                        rope_freqs, qkv_fn, out_fn, fc1_fn, fc2_fn):
+def _block_decode_paged(lp, x, k_pool, v_pool, layer, block_tables, pos,
+                        cfg, rope_freqs, qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block_decode` over the paged pool (block-table
-    indirection instead of a per-slot cache row)."""
-    att, k_pages, v_pages = _paged_decode_attention(
+    indirection instead of a per-slot cache row); returns (x', k_row,
+    v_row), the layer's new rows for the caller to write."""
+    att, k_row, v_row = _paged_decode_attention(
         qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_pages, v_pages, block_tables, pos, cfg, rope_freqs)
+        k_pool, v_pool, layer, block_tables, pos, cfg, rope_freqs)
     x = x + out_fn(lp["out"], att)
     mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
         fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_pages, v_pages
+    return x + mlp, k_row, v_row
 
 
 def _verify_attention(q_k_v: jax.Array, k_cache: jax.Array,
@@ -547,7 +572,7 @@ def _paged_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
     """
     b, k1, _ = q_k_v.shape
     hd = cfg.head_dim
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[1]
     q, k, v = _split_qkv(q_k_v, hd)            # (b, nh_local, k1, hd)
     if rope_freqs is not None:
         q = fused_apply_rotary_pos_emb_bhsd(q, rope_freqs, positions=pos)
@@ -558,15 +583,13 @@ def _paged_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
         pages = jnp.take_along_axis(
             block_tables, logical[:, None], 1)[:, 0]
         rows = p % page_size
-        k_pages = k_pages.at[pages, :, rows].set(
-            k[:, :, j].astype(k_pages.dtype))
-        v_pages = v_pages.at[pages, :, rows].set(
-            v[:, :, j].astype(v_pages.dtype))
-    kg = k_pages[block_tables].transpose(0, 2, 1, 3, 4)
-    vg = v_pages[block_tables].transpose(0, 2, 1, 3, 4)
-    s_max = kg.shape[2] * kg.shape[3]
-    kg = kg.reshape(b, kg.shape[1], s_max, hd)
-    vg = vg.reshape(b, vg.shape[1], s_max, hd)
+        k_pages = k_pages.at[pages, rows].set(
+            _page_rows(k[:, :, j]).astype(k_pages.dtype))
+        v_pages = v_pages.at[pages, rows].set(
+            _page_rows(v[:, :, j]).astype(v_pages.dtype))
+    kg = _gather_pages(k_pages, block_tables, hd)
+    vg = _gather_pages(v_pages, block_tables, hd)
+    s_max = kg.shape[2]
     scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32),
                         kg.astype(jnp.float32)) / math.sqrt(hd)
     qpos = pos[:, None] + jnp.arange(k1)[None, :]        # (b, k1)
@@ -675,7 +698,7 @@ def _paged_chunk_prefill_attention(q_k_v: jax.Array, k_pages: jax.Array,
     invariant, as in :func:`_paged_decode_attention`."""
     _, sc, _ = q_k_v.shape
     hd = cfg.head_dim
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[1]
     n_chunk_pages = sc // page_size
     q, k, v = _split_qkv(q_k_v, hd)            # (1, nh_local, sc, hd)
     p1 = pos[None]
@@ -685,19 +708,16 @@ def _paged_chunk_prefill_attention(q_k_v: jax.Array, k_pages: jax.Array,
     mz = key_mask.astype(k.dtype)[:, None, :, None]
 
     def tiles(t, dtype):
-        # (1, nh, sc, hd) -> page tiles (n_chunk_pages, nh, page, hd),
+        # (1, nh, sc, hd) -> pages (n_chunk_pages, page, nh * hd),
         # zero-masked pad rows included (scratch eats redirected pages)
-        t = (t * mz)[0]
-        t = t.reshape(t.shape[0], n_chunk_pages, page_size, hd)
-        return t.transpose(1, 0, 2, 3).astype(dtype)
+        t = (t * mz)[0].transpose(1, 0, 2)
+        return t.reshape(n_chunk_pages, page_size, -1).astype(dtype)
 
     k_pages = k_pages.at[write_pages].set(tiles(k, k_pages.dtype))
     v_pages = v_pages.at[write_pages].set(tiles(v, v_pages.dtype))
-    kg = k_pages[gather_row][None].transpose(0, 2, 1, 3, 4)
-    vg = v_pages[gather_row][None].transpose(0, 2, 1, 3, 4)
-    s_max = kg.shape[2] * kg.shape[3]
-    kg = kg.reshape(1, kg.shape[1], s_max, hd)
-    vg = vg.reshape(1, vg.shape[1], s_max, hd)
+    kg = _gather_pages(k_pages, gather_row[None], hd)
+    vg = _gather_pages(v_pages, gather_row[None], hd)
+    s_max = kg.shape[2]
     scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32),
                         kg.astype(jnp.float32)) / math.sqrt(hd)
     qpos = p1[:, None] + jnp.arange(sc)[None, :]         # (1, sc)
@@ -825,7 +845,7 @@ def _paged_tree_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
     the engine pins linear spec for kv8 instead."""
     b, k1, _ = q_k_v.shape
     hd = cfg.head_dim
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[1]
     q, k, v = _split_qkv(q_k_v, hd)            # (b, nh_local, k1, hd)
     if rope_freqs is not None:
         tpos = pos[:, None] + depth                      # (b, k1)
@@ -837,15 +857,13 @@ def _paged_tree_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
         pages = jnp.take_along_axis(
             block_tables, logical[:, None], 1)[:, 0]
         rows = p % page_size
-        k_pages = k_pages.at[pages, :, rows].set(
-            k[:, :, j].astype(k_pages.dtype))
-        v_pages = v_pages.at[pages, :, rows].set(
-            v[:, :, j].astype(v_pages.dtype))
-    kg = k_pages[block_tables].transpose(0, 2, 1, 3, 4)
-    vg = v_pages[block_tables].transpose(0, 2, 1, 3, 4)
-    s_max = kg.shape[2] * kg.shape[3]
-    kg = kg.reshape(b, kg.shape[1], s_max, hd)
-    vg = vg.reshape(b, vg.shape[1], s_max, hd)
+        k_pages = k_pages.at[pages, rows].set(
+            _page_rows(k[:, :, j]).astype(k_pages.dtype))
+        v_pages = v_pages.at[pages, rows].set(
+            _page_rows(v[:, :, j]).astype(v_pages.dtype))
+    kg = _gather_pages(k_pages, block_tables, hd)
+    vg = _gather_pages(v_pages, block_tables, hd)
+    s_max = kg.shape[2]
     scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32),
                         kg.astype(jnp.float32)) / math.sqrt(hd)
     valid = _tree_score_mask(pos, anc, s_max)
@@ -919,7 +937,8 @@ def _q8_page_insert(pool, scale, pages, rows, new_row, rescale=True,
 
     b = pages.shape[0]
     old = scale[pages]                                 # (b, nh)
-    tile = kv_dequantize(pool[pages], old)             # (b, nh, page, hd)
+    tile = kv_dequantize(_pages_to_tiles(pool[pages], new_row.shape[-1]),
+                         old)                          # (b, nh, page, hd)
     tile = tile.at[jnp.arange(b), :, rows].set(new_row)
     if zero_dead:
         ridx = jnp.arange(tile.shape[2])
@@ -933,14 +952,16 @@ def _q8_page_insert(pool, scale, pages, rows, new_row, rescale=True,
         qk = jnp.clip(jnp.round(tile / safe), -127, 127).astype(pool.dtype)
         nq = jnp.where(keep[..., None, None], qk, nq)
         ns = sel
-    return pool.at[pages].set(nq), scale.at[pages].set(ns)
+    return (pool.at[pages].set(_tiles_to_pages(nq)),
+            scale.at[pages].set(ns))
 
 
 def _q8_gather(pool, scale, block_tables, b, hd):
     """Dequantized (b, nh, S, hd) fp32 view of each slot's table row."""
     from apex_tpu.quant.kernels import kv_dequantize
 
-    g = kv_dequantize(pool[block_tables], scale[block_tables])
+    g = kv_dequantize(_pages_to_tiles(pool[block_tables], hd),
+                      scale[block_tables])
     g = g.transpose(0, 2, 1, 3, 4)
     return g.reshape(b, g.shape[1], g.shape[2] * g.shape[3], hd)
 
@@ -958,7 +979,7 @@ def _paged_decode_attention_q8(q_k_v, k_pages, v_pages, k_scale, v_scale,
     zero."""
     b = q_k_v.shape[0]
     hd = cfg.head_dim
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[1]
     q, k, v = _split_qkv(q_k_v, hd)            # (b, nh_local, 1, hd)
     if rope_freqs is not None:
         q = fused_apply_rotary_pos_emb_bhsd(q, rope_freqs, positions=pos)
@@ -1018,7 +1039,7 @@ def _paged_verify_attention_q8(q_k_v, k_pages, v_pages, k_scale, v_scale,
     """
     b, k1, _ = q_k_v.shape
     hd = cfg.head_dim
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[1]
     q, k, v = _split_qkv(q_k_v, hd)            # (b, nh_local, k1, hd)
     if rope_freqs is not None:
         q = fused_apply_rotary_pos_emb_bhsd(q, rope_freqs, positions=pos)

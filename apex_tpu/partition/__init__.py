@@ -28,6 +28,7 @@ from apex_tpu.partition.tables import (
     gpt_rules,
     kv_cache_quant_rules,
     kv_cache_rules,
+    paged_kv_cache_rules,
 )
 
 __all__ = [
@@ -36,6 +37,7 @@ __all__ = [
     "gpt_rules",
     "kv_cache_quant_rules",
     "kv_cache_rules",
+    "paged_kv_cache_rules",
     "make_mesh",
     "make_shard_and_gather_fns",
     "match_partition_rules",

@@ -21,39 +21,50 @@ Layout recap (Megatron over the ``model`` mesh axis):
 - layer norms replicated;
 - GPT layer leaves carry a leading stacked-``num_layers`` dim (the
   ``lax.scan`` depth loop), hence the extra leading ``None``;
-- KV cache: heads (axis 2 of ``(L, slots, heads, S, d)``) shard over
-  ``model`` — each rank caches exactly the heads its head-major qkv
-  column shard produces; slot lengths are replicated.
+- KV cache: heads (axis 2 of the dense ``(L, slots, heads, S, d)``, the
+  last axis of the paged ``(L, pages, page, heads * d)``, whose rows hold
+  whole heads side by side) shard over ``model`` — each rank caches
+  exactly the heads its head-major qkv column shard produces; slot
+  lengths and block tables are replicated.
 """
 
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.transformer import parallel_state as ps
 
-# KV-cache rules, shared by both model tables: the paths are the
-# ``KVCache``/``PagedKVCache`` namedtuple fields, matched at
-# end-of-path so a model param ending differently can never collide.
-# The k/v rule covers BOTH layouts — dense ``(L, slots, heads, S, d)``
-# and paged ``(L, pages, heads, page, d)`` keep heads on axis 2; block
-# tables (paged only) replicate, every rank indexes the same mapping.
+# KV-cache rules: the paths are the ``KVCache``/``PagedKVCache``
+# namedtuple fields, matched at end-of-path so a model param ending
+# differently can never collide. The two layouts name their leaves alike
+# and keep heads on different axes, so each has its own table: dense
+# ``(L, slots, heads, S, d)``; paged ``(L, pages, page, heads * d)`` plus
+# the block tables, which replicate (every rank indexes the same mapping).
 _KV_CACHE_RULES = (
     (r"(^|/)(k|v)$", P(None, None, ps.TENSOR_AXIS, None, None)),
+    (r"(^|/)lengths$", P()),
+)
+_PAGED_KV_CACHE_RULES = (
+    (r"(^|/)(k|v)$", P(None, None, None, ps.TENSOR_AXIS)),
     (r"(^|/)lengths$", P()),
     (r"(^|/)block_tables$", P()),
 )
 
 
 def kv_cache_rules():
-    """The serving-cache slice of the default tables."""
+    """The dense serving-cache slice of the default tables."""
     return _KV_CACHE_RULES
 
 
+def paged_kv_cache_rules():
+    """The paged serving-cache rules (``PagedKVCache``)."""
+    return _PAGED_KV_CACHE_RULES
+
+
 def kv_cache_quant_rules():
-    """KV-cache rules for the INT8 paged pool: the base rules plus the
-    per-page-per-head fp32 scales ``(L, pages, heads)`` — heads (axis
-    2, same as the pool's) shard over ``model``, so each rank's scale
-    shard dequantizes exactly its local heads' pages."""
-    return _KV_CACHE_RULES + (
+    """KV-cache rules for the INT8 paged pool: the paged rules plus the
+    per-page-per-head fp32 scales ``(L, pages, heads)`` — heads (axis 2)
+    shard over ``model`` like the pool's, so each rank's scale shard
+    dequantizes exactly its local heads' pages."""
+    return _PAGED_KV_CACHE_RULES + (
         (r"(^|/)(k|v)_scale$", P(None, None, ps.TENSOR_AXIS)),
     )
 
@@ -88,7 +99,7 @@ def gpt_quant_rules():
 
 def gpt_rules():
     """Rule table for the GPT param tree (``models.gpt.init_gpt``) plus
-    the serving KV cache. First match wins; table is overlap-free."""
+    the dense serving KV cache. First match wins; table is overlap-free."""
     t = ps.TENSOR_AXIS
     return (
         ("embedding/word/embedding", P(t, None)),
@@ -112,8 +123,8 @@ def draft_gpt_rules():
     is a GPT sharded on the SAME mesh as the target, so the layout is
     :func:`gpt_rules` minus the rows that can never match a draft tree:
     draft configs (``models.gpt.draft_gpt_tiny``/``draft_gpt_medium``)
-    are RoPE-only — no ``embedding/position`` leaf — and the lockstep
-    draft cache is DENSE (``KVCache``: k/v/lengths, no block tables).
-    A rule that can never match would be an APX701 dead-rule finding."""
-    dead = ("embedding/position/embedding", r"(^|/)block_tables$")
-    return tuple(rule for rule in gpt_rules() if rule[0] not in dead)
+    are RoPE-only — no ``embedding/position`` leaf (the lockstep draft
+    cache is the dense ``KVCache`` the table already covers). A rule
+    that can never match would be an APX701 dead-rule finding."""
+    return tuple(rule for rule in gpt_rules()
+                 if rule[0] != "embedding/position/embedding")

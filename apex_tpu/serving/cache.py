@@ -90,14 +90,18 @@ RESERVED_PAGES = 2
 
 class PagedKVCache(NamedTuple):
     """Paged layout: ``k``/``v`` hold a POOL of fixed-size pages shared
-    by every slot — ``(L, num_pages, num_heads, page_size, head_dim)``
-    — and ``block_tables`` (``(num_slots, max_pages)`` int32) maps each
-    slot's logical page index to a physical page. HBM for K/V history
+    by every slot — ``(L, num_pages, page_size, num_heads * head_dim)``,
+    a page's rows holding all heads side by side (head-major), so one
+    page of one layer is one contiguous run of whole (sublane, 128-lane)
+    tiles that the decode kernel fetches by DMA — and ``block_tables``
+    (``(num_slots, max_pages)`` int32) maps each slot's logical page
+    index to a physical page. HBM for K/V history
     scales with pages actually allocated (Σ ceil(len/page_size)), not
     ``slots x S_max``; the host-side allocator
     (:class:`apex_tpu.serving.paging.PagePool`) owns which pages are
-    live, shared (prefix caching) or free. Heads (axis 2) still shard
-    over ``model`` under TP; lengths and block tables are replicated.
+    live, shared (prefix caching) or free. Heads (the last axis, in
+    whole heads) shard over ``model`` under TP; lengths and block tables
+    are replicated.
 
     ``kv_dtype=int8`` mode: the pool stores round-to-nearest symmetric
     int8 with PER-PAGE-PER-HEAD fp32 scales in the trailing
@@ -111,8 +115,8 @@ class PagedKVCache(NamedTuple):
     pytree, so every existing 4-leaf construction and donation site is
     unchanged.
     """
-    k: jax.Array             # (L, num_pages, num_heads, page_size, hd)
-    v: jax.Array             # (L, num_pages, num_heads, page_size, hd)
+    k: jax.Array             # (L, num_pages, page_size, num_heads * hd)
+    v: jax.Array             # (L, num_pages, page_size, num_heads * hd)
     lengths: jax.Array       # (num_slots,) int32, valid positions
     block_tables: jax.Array  # (num_slots, max_pages) int32 page ids
     k_scale: Optional[jax.Array] = None  # (L, num_pages, num_heads) f32
@@ -141,8 +145,8 @@ def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
             f"max_len {max_len} exceeds the learned position table "
             f"({cfg.max_position_embeddings}); raise "
             "max_position_embeddings or use rope")
-    shape = (cfg.num_layers, num_pages, cfg.num_heads, page_size,
-             cfg.head_dim)
+    shape = (cfg.num_layers, num_pages, page_size,
+             cfg.num_heads * cfg.head_dim)
     bt = jnp.full((num_slots, max_pages_per_slot(max_len, page_size)),
                   SCRATCH_PAGE, jnp.int32)
     if jnp.dtype(dtype) == jnp.int8:
@@ -200,14 +204,17 @@ def audit_block_tables(block_tables, slot_pages) -> bool:
 
 def paged_cache_partition_specs(rules=None,
                                 quantized: bool = False) -> PagedKVCache:
-    """Same table-derived TP layout as :func:`cache_partition_specs`:
-    the pool's head axis (still axis 2) shards over ``model``; lengths
+    """Same table-derived TP layout as :func:`cache_partition_specs`,
+    from the paged table (``partition.paged_kv_cache_rules``): the pool's
+    last axis (whole heads side by side) shards over ``model``; lengths
     AND block tables are replicated — every rank walks the same
     logical-to-physical mapping over its local heads. With
     ``quantized`` the template grows the ``k_scale``/``v_scale``
-    leaves, matched against ``kv_cache_quant_rules()`` (head axis — now
+    leaves, matched against ``kv_cache_quant_rules()`` (head axis —
     axis 2 of the 3-d scales — sharded over ``model`` like the pool's)."""
-    from apex_tpu.partition import kv_cache_rules, match_partition_rules
+    from apex_tpu.partition import (
+        match_partition_rules, paged_kv_cache_rules,
+    )
 
     if rules is None:
         if quantized:
@@ -215,10 +222,10 @@ def paged_cache_partition_specs(rules=None,
 
             rules = kv_cache_quant_rules()
         else:
-            rules = kv_cache_rules()
+            rules = paged_kv_cache_rules()
     template = PagedKVCache(
-        k=jax.ShapeDtypeStruct((1,) * 5, "bfloat16"),
-        v=jax.ShapeDtypeStruct((1,) * 5, "bfloat16"),
+        k=jax.ShapeDtypeStruct((1,) * 4, "bfloat16"),
+        v=jax.ShapeDtypeStruct((1,) * 4, "bfloat16"),
         lengths=jax.ShapeDtypeStruct((1,), "int32"),
         block_tables=jax.ShapeDtypeStruct((1, 1), "int32"))
     if quantized:

@@ -21,7 +21,11 @@ Contracts:
   ``pos = lengths`` and attends with an ``s <= pos`` mask. Its logits
   must match a full-sequence forward at the same positions to fp32
   tolerance (the headline serving contract; see
-  ``tests/L0/run_serving``).
+  ``tests/L0/run_serving``). Over the paged bf16/f32 pool the attention
+  is the Pallas kernel ``apex_paged_decode_fwd``, which reads the
+  mapped pages of the whole pool in place and takes the new row as an
+  operand; the layers' rows are written by one scatter after the layer
+  scan (:func:`_paged_decode_core`).
 - **verify** (speculative decoding) advances every slot over k+1
   candidate positions at once — the last committed token plus k
   drafted candidates — returning exact per-position logits
@@ -77,7 +81,7 @@ from apex_tpu.models.gpt import (
     _block_decode, _block_decode_paged, _block_decode_paged_q8,
     _block_prefill, _block_tree_verify, _block_tree_verify_paged,
     _block_verify, _block_verify_paged, _block_verify_paged_q8, _ln,
-    _rope_or_none, _tied_lm_logits,
+    _pages_to_tiles, _rope_or_none, _tied_lm_logits, _tiles_to_pages,
 )
 from apex_tpu.serving.cache import (
     KVCache, PagedKVCache, cache_partition_specs,
@@ -276,7 +280,7 @@ def _paged_prefill_core(params, cfg: GPTConfig, cache: PagedKVCache, ids,
         raise ValueError(f"prefill takes one slot's (1, s) ids, got "
                          f"{ids.shape}")
     s = ids.shape[1]
-    page_size = cache.k.shape[3]
+    page_size = cache.k.shape[2]
     if s % page_size:
         raise ValueError(f"prompt bucket {s} is not a multiple of "
                          f"page_size {page_size}")
@@ -302,13 +306,12 @@ def _paged_prefill_core(params, cfg: GPTConfig, cache: PagedKVCache, ids,
     logits = logits_fn(params, h_last)
     mz = mask.astype(k.dtype)[None, None, None, :, None]
 
-    def tiles(t):
-        # (L, 1, nh, s, hd) -> page tiles (L, n_bucket_pages, nh,
-        # page_size, hd), zero-padded tail included (scratch eats it)
+    def pages(t):
+        # (L, 1, nh, s, hd) -> pages (L, n_bucket_pages, page_size,
+        # nh * hd), zero-padded tail included (scratch eats it)
         lyr, _, nh, _, hd = t.shape
-        t = (t * mz)[:, 0]
-        t = t.reshape(lyr, nh, n_bucket_pages, page_size, hd)
-        return t.transpose(0, 2, 1, 3, 4)
+        t = (t * mz)[:, 0].transpose(0, 2, 1, 3)
+        return t.reshape(lyr, n_bucket_pages, page_size, nh * hd)
 
     lengths = lax.dynamic_update_slice(cache.lengths, length[None],
                                        (slot,))
@@ -320,18 +323,18 @@ def _paged_prefill_core(params, cfg: GPTConfig, cache: PagedKVCache, ids,
         # scatter tiles + scales together — 6 alias pairs
         from apex_tpu.quant.kernels import kv_quantize
 
-        kq, ks = kv_quantize(tiles(k))
-        vq, vs = kv_quantize(tiles(v))
+        kq, ks = kv_quantize(_pages_to_tiles(pages(k), cfg.head_dim))
+        vq, vs = kv_quantize(_pages_to_tiles(pages(v), cfg.head_dim))
         new = PagedKVCache(
-            k=cache.k.at[:, write_pages].set(kq),
-            v=cache.v.at[:, write_pages].set(vq),
+            k=cache.k.at[:, write_pages].set(_tiles_to_pages(kq)),
+            v=cache.v.at[:, write_pages].set(_tiles_to_pages(vq)),
             lengths=lengths, block_tables=block_tables,
             k_scale=cache.k_scale.at[:, write_pages].set(ks),
             v_scale=cache.v_scale.at[:, write_pages].set(vs))
         return new, logits
     new = PagedKVCache(
-        k=cache.k.at[:, write_pages].set(tiles(k).astype(cache.k.dtype)),
-        v=cache.v.at[:, write_pages].set(tiles(v).astype(cache.v.dtype)),
+        k=cache.k.at[:, write_pages].set(pages(k).astype(cache.k.dtype)),
+        v=cache.v.at[:, write_pages].set(pages(v).astype(cache.v.dtype)),
         lengths=lengths, block_tables=block_tables)
     return new, logits
 
@@ -342,7 +345,11 @@ def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
     """One token for every slot against the page pool; the host has
     already made every slot's write target exclusive (page-boundary
     allocation + copy-on-write happen in
-    ``PagedDecodeEngine.prepare_decode`` BEFORE this runs). Block
+    ``PagedDecodeEngine.prepare_decode`` BEFORE this runs). Over the
+    bf16/f32 pool each layer's attention is the paged-attention kernel
+    on the WHOLE pool and the layer's index
+    (``models.gpt._paged_decode_attention``); the int8 pool keeps the
+    per-layer write + gather, with the pool as xs/ys of the scan. Block
     tables are host-owned state riding the donated cache tuple; they
     come back numerically unchanged, but through a self-row rewrite
     rather than an invar passthrough — an output that IS the invar
@@ -351,7 +358,7 @@ def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
     pos = cache.lengths
     bt = cache.block_tables
     x = embed_fn(params, tokens[:, None], pos=pos)
-    freqs = _rope_or_none(cfg, bt.shape[1] * cache.k.shape[3])
+    freqs = _rope_or_none(cfg, bt.shape[1] * cache.k.shape[2])
 
     if cache.k_scale is not None:
         def body(x, layer_slice):
@@ -369,15 +376,35 @@ def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
         return PagedKVCache(k, v, jnp.where(active, pos + 1, pos), bt,
                             ks, vs), logits
 
+    # the pool is closed over and only read: it is no xs/ys of the scan
+    # (a scan would copy each layer's slice out and back), and the layers'
+    # new rows go into the donated pool in ONE in-place scatter after it
     def body(x, layer_slice):
-        lp, kp, vp = layer_slice
-        x, kp, vp = _block_decode_paged(lp, x, kp, vp, bt, pos, cfg,
-                                        freqs, *dense_fns)
-        return x, (kp, vp)
+        lp, layer = layer_slice
+        x, k_row, v_row = _block_decode_paged(
+            lp, x, cache.k, cache.v, layer, bt, pos, cfg, freqs,
+            *dense_fns)
+        return x, (k_row, v_row)
 
-    x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
+    layers = cache.k.shape[0]
+    x, (k_rows, v_rows) = lax.scan(
+        body, x, (params["layers"], jnp.arange(layers, dtype=jnp.int32)))
     hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
     logits = logits_fn(params, hidden[:, 0])
+    _, num_pages, page_size, width = cache.k.shape
+    logical = jnp.clip(pos // page_size, 0, bt.shape[1] - 1)
+    pages = jnp.take_along_axis(bt, logical[:, None], 1)[:, 0]
+    # inactive slots write to the page their NULL/scratch row names.
+    # The pool as a list of rows (a view: its layout is row-major) takes
+    # the layers * slots new rows in one in-place row scatter
+    at = ((jnp.arange(layers)[:, None] * num_pages + pages[None, :])
+          * page_size + pos[None, :] % page_size).reshape(-1)
+
+    def write(pool, rows):
+        flat = pool.reshape(-1, width).at[at].set(rows.reshape(-1, width))
+        return flat.reshape(pool.shape)
+
+    k, v = write(cache.k, k_rows), write(cache.v, v_rows)
     bt = _self_rewrite(bt)
     return PagedKVCache(k, v, jnp.where(active, pos + 1, pos), bt), logits
 
@@ -393,7 +420,7 @@ def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
     pos = cache.lengths
     bt = cache.block_tables
     x = embed_fn(params, tokens, pos=pos)
-    freqs = _rope_or_none(cfg, bt.shape[1] * cache.k.shape[3])
+    freqs = _rope_or_none(cfg, bt.shape[1] * cache.k.shape[2])
 
     if cache.k_scale is not None:
         def body(x, layer_slice):
@@ -438,7 +465,7 @@ def _paged_tree_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
     pos = cache.lengths
     bt = cache.block_tables
     x = embed_fn(params, tokens, pos=pos[:, None] + depth)
-    freqs = _rope_or_none(cfg, bt.shape[1] * cache.k.shape[3])
+    freqs = _rope_or_none(cfg, bt.shape[1] * cache.k.shape[2])
 
     def body(x, layer_slice):
         lp, kp, vp = layer_slice
@@ -476,7 +503,7 @@ def _paged_chunk_prefill_core(params, cfg: GPTConfig,
         raise ValueError(f"chunk prefill takes one slot's (1, sc) ids, "
                          f"got {ids.shape}")
     sc = ids.shape[1]
-    page_size = cache.k.shape[3]
+    page_size = cache.k.shape[2]
     if sc % page_size:
         raise ValueError(f"chunk bucket {sc} is not a multiple of "
                          f"page_size {page_size}")

@@ -1,7 +1,7 @@
 """Host-side page allocator: free list, refcounts, prefix cache.
 
 The device half of paging (``serving.cache.PagedKVCache``) is dumb
-storage — a fixed pool of ``(heads, page_size, head_dim)`` pages per
+storage — a fixed pool of ``(page_size, heads * head_dim)`` pages per
 layer plus per-slot block tables. Everything that decides WHICH page a
 logical position lives in happens here, on the host, in plain Python:
 
@@ -177,7 +177,7 @@ def spill_checksum(header: bytes, k, v, k_scale=None,
 
 class SpillRecord(NamedTuple):
     """One spilled page in host memory: the versioned header, the
-    page's K/V tiles as host arrays ``(layers, 1, heads, page_size,
+    page's K/V tiles as host arrays ``(layers, 1, page_size, heads *
     head_dim)``, the int8 pool's per-page-per-head scale planes
     ``(layers, 1, heads)`` (``None`` for float pools — they must
     travel together or the page dequantizes wrong), and the

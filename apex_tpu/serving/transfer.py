@@ -2,7 +2,7 @@
 
 The disaggregated tier (``serving.router``) runs prefill and decode on
 separate engines; what moves between them is the prompt's completed KV
-pages — page-sized ``(layers, heads, page_size, head_dim)`` tiles
+pages — page-sized ``(layers, page_size, heads * head_dim)`` tiles
 gathered from the prefill replica's pool and scattered into pages the
 decode replica's :class:`~apex_tpu.serving.paging.PagePool` allocated.
 This module owns that channel, and its design goal is the robustness
@@ -94,7 +94,7 @@ TRANSFER_TICK_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0,
 def make_extract_pages_fn() -> Callable:
     """Jitted ``(cache, page_ids) -> (k_tile, v_tile)``: gather the
     identified pages out of a paged cache's pool — the sender half of
-    the handoff. Tiles are ``(layers, n_pages, heads, page_size,
+    the handoff. Tiles are ``(layers, n_pages, page_size, heads *
     head_dim)`` in the pool dtype. Read-only (no donation): the source
     cache keeps serving its own slots."""
 
@@ -163,7 +163,7 @@ def make_tile_transfer_fns(mesh=None, rules=None) -> Tuple[Callable,
     host tiles under the pool's TP spec (heads over ``model``) on the
     destination sub-mesh — the ``make_shard_and_gather_fns`` device_put
     /device_get pair from the partition engine, applied to the tile's
-    head axis (axis 2, same as the pool's). Build one pair per sub-mesh
+    head axis (the last, same as the pool's). Build one pair per sub-mesh
     of ``partition.mesh.make_mesh`` and hand them to
     :class:`PageTransfer`; without them the transfer stages through
     ``np.asarray`` — correct on any topology, optimal on one device."""
@@ -172,7 +172,7 @@ def make_tile_transfer_fns(mesh=None, rules=None) -> Tuple[Callable,
     from apex_tpu.partition.rules import make_shard_and_gather_fns
 
     del rules  # the tile layout is fixed by the pool's: heads sharded
-    spec = PartitionSpec(None, None, "model")
+    spec = PartitionSpec(None, None, None, "model")
     shard_fns, gather_fns = make_shard_and_gather_fns(
         {"k": spec, "v": spec}, mesh)
 
@@ -208,9 +208,9 @@ def make_reshard_extract_fn(mesh=None) -> Callable:
     cspecs = paged_cache_partition_specs()
 
     def extract(cache, page_ids):
-        k = jax.lax.all_gather(cache.k[:, page_ids], "model", axis=2,
+        k = jax.lax.all_gather(cache.k[:, page_ids], "model", axis=3,
                                tiled=True)
-        v = jax.lax.all_gather(cache.v[:, page_ids], "model", axis=2,
+        v = jax.lax.all_gather(cache.v[:, page_ids], "model", axis=3,
                                tiled=True)
         return k, v
 

@@ -346,6 +346,9 @@ def phase_server(smoke: Smoke) -> None:
                   for k in census[f"prefill_{longest}"]),
               f"prefill at bucket {longest} holds no flash kernel: "
               f"{census}")
+    check(census["decode"].get("paged_attention.apex_paged_decode_fwd") == 1,
+          f"decode holds no paged-attention kernel in its layer scan: "
+          f"{census}")
 
     smoke.emit(
         "server", since, model=sz.gpt, slots=sz.slots,
@@ -403,9 +406,10 @@ def phase_trainer(smoke: Smoke) -> None:
     # every LayerNorm (embeddings, two per layer, the MLM head) and the
     # loss run as kernels, forward and backward
     norms = 2 * cfg.num_layers + 2
-    expect = {"fused_layer_norm._fwd_kernel": norms,
-              "fused_layer_norm._bwd_kernel": norms,
-              "xentropy._fwd_kernel": 1, "xentropy._bwd_kernel": 1}
+    expect = {"fused_layer_norm.apex_ln_fwd": norms,
+              "fused_layer_norm.apex_ln_bwd": norms,
+              "xentropy.apex_xentropy_fwd": 1,
+              "xentropy.apex_xentropy_bwd": 1}
     flash = {k: v for k, v in census.items()
              if k.startswith("flash_attention.")}
     check({k: v for k, v in census.items() if k not in flash} == expect,

@@ -652,8 +652,8 @@ def test_init_paged_cache_validates():
         init_paged_cache(cfg, 1, 8, RESERVED_PAGES, 4)
     c = init_paged_cache(cfg, 2, 16, 6, 4)
     assert isinstance(c, PagedKVCache) and c.k.dtype == jnp.bfloat16
-    assert c.k.shape == (cfg.num_layers, 6, cfg.num_heads, 4,
-                         cfg.head_dim)
+    assert c.k.shape == (cfg.num_layers, 6, 4,
+                         cfg.num_heads * cfg.head_dim)
     assert c.block_tables.shape == (2, 4)  # ceil(16 / 4) per slot
     assert int(c.block_tables.min()) == SCRATCH_PAGE  # parked on scratch
     assert int(c.block_tables.max()) == SCRATCH_PAGE

@@ -1,0 +1,200 @@
+"""The paged decode-attention kernel (``apex_paged_decode_fwd``) against the
+plain gather path, which stays: ``_paged_verify_attention`` at ``k1 = 1``
+writes the new row into one layer's pages, gathers the slot's whole table
+row and runs the masked float32 softmax over it. The kernel reads the same
+pool in place, takes the new row as an operand, and leaves the write to
+its caller; outputs agree to float32 rounding (the online softmax sums in
+another order), the pool and the tables come back bit for bit alike."""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu.models.gpt import (
+    _paged_decode_attention, _paged_verify_attention, _rope_or_none,
+    gpt_tiny, init_gpt,
+)
+from apex_tpu.serving.cache import NULL_PAGE, RESERVED_PAGES, init_paged_cache
+from apex_tpu.serving.decode import make_paged_decode_fn, make_paged_verify_fn
+
+LAYERS, LAYER, MAX_PAGES = 3, 1, 5
+# float32 rounding of a 20-term softmax in another summation order
+TOL = dict(rtol=2e-5, atol=2e-6)
+
+
+def _positions(page_size):
+    """One slot per case of ``pos``: nothing cached yet; the middle of a
+    page; the last row of a page; the last row of the whole table."""
+    return {"pos0": 0, "mid_page": page_size + page_size // 2,
+            "page_last_row": 2 * page_size - 1,
+            "full_table": MAX_PAGES * page_size - 1}
+
+
+def _cfg(heads, rope):
+    base = gpt_tiny()
+    return dataclasses.replace(
+        base, num_heads=heads, hidden_size=heads * base.head_dim,
+        use_rope=rope)
+
+
+def _problem(page_size, dtype, heads, rope, seed=0, order=None, fill=None):
+    """A pool whose pages hold random rows, a table that maps each slot's
+    logical pages to shuffled physical ones (``order`` picks the shuffle),
+    NULL past what ``pos`` needs, and the new token's fused projection.
+    ``fill`` overwrites everything no slot may read: pages no table row
+    names (NULL among them) and the rows at or past ``pos``."""
+    cfg = _cfg(heads, rope)
+    pos = np.asarray(list(_positions(page_size).values()), np.int32)
+    slots = len(pos)
+    width = heads * cfg.head_dim
+    num_pages = RESERVED_PAGES + slots * MAX_PAGES + 3
+    rng = np.random.RandomState(seed)
+    logical = rng.standard_normal(
+        (2, LAYERS, slots, MAX_PAGES, page_size, width)).astype(np.float32)
+    physical = np.arange(RESERVED_PAGES, num_pages)
+    np.random.RandomState(1 if order is None else order).shuffle(physical)
+    table = np.full((slots, MAX_PAGES), NULL_PAGE, np.int32)
+    pools = rng.standard_normal(
+        (2, LAYERS, num_pages, page_size, width)).astype(np.float32)
+    if fill is not None:
+        pools[:] = fill
+    for s in range(slots):
+        for j in range(pos[s] // page_size + 1):
+            page = physical[s * MAX_PAGES + j]
+            table[s, j] = page
+            rows = min(page_size, pos[s] - j * page_size)
+            pools[:, :, page, :rows] = logical[:, :, s, j, :rows]
+    qkv = rng.standard_normal((slots, 1, 3 * width)).astype(np.float32)
+    freqs = _rope_or_none(cfg, MAX_PAGES * page_size)
+    k_pool, v_pool = (jnp.asarray(p).astype(dtype) for p in pools)
+    return (cfg, freqs, jnp.asarray(qkv), k_pool, v_pool,
+            jnp.asarray(table), jnp.asarray(pos))
+
+
+def _kernel(cfg, freqs, qkv, k_pool, v_pool, table, pos):
+    """(context, pools after the caller's write of the new rows)."""
+    ctx, k_row, v_row = _paged_decode_attention(
+        qkv, k_pool, v_pool, jnp.int32(LAYER), table, pos, cfg, freqs)
+    page_size = k_pool.shape[2]
+    pages = table[jnp.arange(len(pos)), pos // page_size]
+    return (ctx, k_pool.at[LAYER, pages, pos % page_size].set(k_row),
+            v_pool.at[LAYER, pages, pos % page_size].set(v_row))
+
+
+def _gather(cfg, freqs, qkv, k_pool, v_pool, table, pos):
+    ctx, k_pages, v_pages = _paged_verify_attention(
+        qkv, k_pool[LAYER], v_pool[LAYER], table, pos, cfg, freqs)
+    return (ctx, k_pool.at[LAYER].set(k_pages),
+            v_pool.at[LAYER].set(v_pages))
+
+
+@functools.lru_cache(maxsize=None)
+def _both(page_size, dtype, heads, rope):
+    problem = _problem(page_size, jnp.dtype(dtype), heads, rope)
+    return (jax.tree.map(np.asarray, _kernel(*problem)),
+            jax.tree.map(np.asarray, _gather(*problem)))
+
+
+@pytest.mark.parametrize("rope", [False, True], ids=["learned_pos", "rope"])
+@pytest.mark.parametrize("heads", [1, 8], ids=["one_head", "all_heads"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", list(_positions(4)))
+@pytest.mark.parametrize("page_size", [4, 16])
+def test_kernel_matches_the_gather_path(page_size, case, dtype, heads, rope):
+    (got, k_got, v_got), (want, k_want, v_want) = _both(
+        page_size, dtype, heads, rope)
+    slot = list(_positions(page_size)).index(case)
+    assert got.dtype == want.dtype == np.float32
+    np.testing.assert_allclose(got[slot], want[slot], **TOL)
+    # the pool comes back as the gather path leaves it, bit for bit
+    np.testing.assert_array_equal(k_got, k_want)
+    np.testing.assert_array_equal(v_got, v_want)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_output_is_bit_identical_across_page_placements(dtype):
+    outs = [np.asarray(_kernel(*_problem(4, jnp.dtype(dtype), 8, True,
+                                         order=order))[0])
+            for order in (1, 2, 3)]
+    np.testing.assert_array_equal(outs[0], outs[1])
+    np.testing.assert_array_equal(outs[0], outs[2])
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_nan_in_unmapped_pages_and_dead_rows_cannot_reach_the_output(dtype):
+    """Stronger than the gather path's ``0 * v``: NULL, unmapped pages and
+    the rows at or past ``pos`` hold NaN and every slot's context is finite
+    and bit for bit what a pool of zeros there gives."""
+    clean = _kernel(*_problem(4, jnp.dtype(dtype), 8, True, fill=0.0))[0]
+    dirty = _kernel(*_problem(4, jnp.dtype(dtype), 8, True, fill=np.nan))[0]
+    assert np.isfinite(np.asarray(dirty)).all()
+    np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+
+
+def test_decode_program_scans_the_kernel_and_never_carries_the_pool():
+    cfg = gpt_tiny()
+    params = jax.eval_shape(lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(
+        init_paged_cache, cfg, 2, 32, 6, 16))
+    jaxpr = jax.make_jaxpr(make_paged_decode_fn(cfg))(
+        params, cache, jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.bool_))
+    (jit,) = jaxpr.eqns
+    scans = [e for e in jit.params["jaxpr"].eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    (scan,) = scans
+    assert scan.params["length"] == cfg.num_layers
+    kernels = [e for e in scan.params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in kernels] == ["apex_paged_decode_fwd"]
+    # the pool goes in whole, as a constant of the loop, and is no output
+    consts = scan.invars[:scan.params["num_consts"]]
+    assert [v.aval.shape for v in consts].count(cache.k.shape) == 2
+    assert all(v.aval.ndim < 4 for v in scan.outvars)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_decode_step_leaves_pool_and_tables_as_the_verify_step_does(dtype):
+    """The whole step: ``jit_decode`` (kernel + one scatter after the layer
+    scan) against the verify step at ``k1 = 1`` (per-layer write + gather)
+    from the same cache: logits agree, the pools hold the same rows in the
+    same places, the block tables are equal bit for bit, and only decode
+    advances the active slots' lengths."""
+    cfg = dataclasses.replace(gpt_tiny(), hidden_dropout=0.0)
+    params = init_gpt(jax.random.PRNGKey(0), cfg)
+    slots, page_size, max_len = 3, 4, 16
+    cache = init_paged_cache(cfg, slots, max_len, 14, page_size,
+                             jnp.dtype(dtype))
+    rng = np.random.RandomState(0)
+    lengths = np.asarray([0, 6, 11], np.int32)
+    table = np.full(cache.block_tables.shape, NULL_PAGE, np.int32)
+    free = iter(rng.permutation(np.arange(RESERVED_PAGES, 14)))
+    for s in range(slots):
+        for j in range(lengths[s] // page_size + 1):
+            table[s, j] = next(free)
+    cache = cache._replace(
+        k=jnp.asarray(rng.standard_normal(cache.k.shape), cache.k.dtype),
+        v=jnp.asarray(rng.standard_normal(cache.v.shape), cache.v.dtype),
+        lengths=jnp.asarray(lengths), block_tables=jnp.asarray(table))
+    clone = jax.tree.map(jnp.copy, cache)
+    tokens = jnp.asarray([5, 7, 11], jnp.int32)
+    active = jnp.asarray([True, False, True])
+    got, logits = make_paged_decode_fn(cfg)(params, cache, tokens, active)
+    want, ref = make_paged_verify_fn(cfg)(params, clone, tokens[:, None])
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref[:, 0]),
+                               rtol=2e-4, atol=2e-4)
+    for ours, theirs in ((got.k, want.k), (got.v, want.v)):
+        ours, theirs = (np.asarray(t, np.float32) for t in (ours, theirs))
+        # layer 0 sees the same input on both paths; deeper layers differ
+        # by the rounding of the attention before them (one bfloat16 ulp)
+        np.testing.assert_array_equal(ours[0], theirs[0])
+        np.testing.assert_allclose(ours, theirs, rtol=1e-2, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got.block_tables), table)
+    np.testing.assert_array_equal(np.asarray(want.block_tables), table)
+    np.testing.assert_array_equal(np.asarray(got.lengths),
+                                  lengths + np.asarray([1, 0, 1]))
+    np.testing.assert_array_equal(np.asarray(want.lengths), lengths)

@@ -51,7 +51,7 @@ def _sites():
 
 def test_every_pallas_call_has_a_unique_literal_apex_name_and_scope():
     sites = _sites()
-    assert len(sites) >= 23
+    assert len(sites) >= 24
     for path, line, name, scope in sites:
         where = f"{path}:{line}"
         assert isinstance(name, str) and name.startswith("apex_"), where
@@ -60,7 +60,8 @@ def test_every_pallas_call_has_a_unique_literal_apex_name_and_scope():
     assert len(set(names)) == len(names), sorted(names)
     # the names the trace readers and PERF.md lean on
     assert {"apex_ln_fwd", "apex_ln_bwd", "apex_xentropy_fwd",
-            "apex_xentropy_bwd", "apex_flash_fwd"} <= set(names)
+            "apex_xentropy_bwd", "apex_flash_fwd",
+            "apex_paged_decode_fwd"} <= set(names)
 
 
 def test_kernel_name_reaches_the_lowered_program():

@@ -139,6 +139,24 @@ def test_w8_matmul_nk(v5e, m):
                       ((50304,), jnp.float32)) == 1
 
 
+@pytest.mark.parametrize("q_dtype,pool_dtype", [
+    (jnp.bfloat16, jnp.bfloat16), (jnp.float32, jnp.bfloat16),
+    (jnp.float32, jnp.float32)])
+def test_paged_decode_attention(v5e, q_dtype, pool_dtype):
+    """The served shape: 56 slots, 16 heads of 64, pages of 16, the whole
+    24-layer pool of 3,586 pages handed over in HBM."""
+    from apex_tpu.transformer.functional.paged_attention import (
+        paged_decode_attention,
+    )
+
+    row = ((56, 1, 1024), q_dtype)
+    pool = ((24, 3586, 16, 1024), pool_dtype)
+    assert compile_on(
+        v5e, functools.partial(paged_decode_attention, heads=16),
+        row, row, row, pool, pool, ((56, 64), jnp.int32),
+        ((56,), jnp.int32), ((), jnp.int32)) == 1
+
+
 def test_flat_adam(v5e):
     from apex_tpu.optimizers import FusedAdam
 
