@@ -17,9 +17,10 @@ For every ``pallas_call`` equation in the entry's jaxpr, each declared
   (an alias between mismatched buffers is rejected by XLA at compile
   time on hardware — on the interpret-mode CPU rig it is ignored);
 - the operand's provenance chain, followed through layout-preserving
-  equations only (``reshape``/``squeeze``/``expand_dims``), terminates
-  at an *invar* of the jaxpr the call sits in — i.e. the caller's
-  buffer, not a fresh intermediate.
+  equations (``reshape``/``squeeze``/``expand_dims``) and through an
+  earlier ``pallas_call``'s own aliased output (kernels chained in place
+  on one buffer), terminates at an *invar* of the jaxpr the call sits
+  in — i.e. the caller's buffer, not a fresh intermediate.
 
 The same contract covers jit DONATIONS (``donate_argnums``): a traced
 ``pjit`` equation carries ``donated_invars``, and the serving KV cache
@@ -66,9 +67,20 @@ def _trace_to_invar(var, producers, invars) -> str:
         eqn = producers.get(var)
         if eqn is None:
             return "constvar"  # a closed-over constant, not a live buffer
-        if eqn.primitive.name not in _LAYOUT_PRESERVING:
+        if eqn.primitive.name == "pallas_call":
+            # a chain of in-place kernels (the recurrent state stepped by
+            # one layer's call after another): an output that aliases an
+            # operand IS that operand's buffer, so follow it
+            at = next(i for i, ov in enumerate(eqn.outvars) if ov is var)
+            src = [i for i, o in _normalize_pairs(
+                eqn.params.get("input_output_aliases")) if o == at]
+            if not src:
+                return "pallas_call"
+            var = eqn.invars[src[0]]
+        elif eqn.primitive.name not in _LAYOUT_PRESERVING:
             return eqn.primitive.name
-        var = eqn.invars[0]
+        else:
+            var = eqn.invars[0]
         seen += 1
         if seen > 32:
             return "cycle"

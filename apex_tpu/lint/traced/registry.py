@@ -595,7 +595,7 @@ def _decode_step_entry(tp=None):
 
 def _prefill_step_bucketed_entry():
     """The ContinuousBatchingScheduler prefill path: a prompt padded up
-    to the 32-token bucket rung, 4-slot pool (scheduler.pad_to_bucket
+    to the 32-token bucket rung, 4-slot pool (scheduler._pad_on_host
     + DecodeEngine per-bucket jitted step)."""
     def build():
         from apex_tpu.serving.decode import make_prefill_fn
@@ -889,6 +889,40 @@ def _paged_decode_step_entry(tp=None):
 
             fn = make_tp_paged_decode_fn(GPTModel(cfg, tp_size=tp))
         return fn, (params, cache, _sds((2,), "int32"), _sds((2,), "bool"))
+
+    return build
+
+
+def _hybrid_step_entry(which):
+    """The server's two programs for a model with recurrent layers
+    (``models.hybrid`` at its tiny preset): prefill runs the chunked Gated
+    DeltaNet kernel and flash attention and overwrites one slot's state;
+    decode steps every slot's state through ``apex_gdn_decode_fwd``. Both
+    donate the 6-leaf cache (pool k/v, lengths, block tables, recurrent
+    state, convolution tails): this entry is the gated_delta kernel
+    family's registration."""
+    def build():
+        import functools as ft
+
+        import jax
+
+        from apex_tpu.models.hybrid import hybrid_tiny, init_hybrid
+        from apex_tpu.serving.cache import init_hybrid_cache
+        from apex_tpu.serving.decode import (
+            make_hybrid_decode_fn, make_hybrid_prefill_fn,
+        )
+
+        cfg = hybrid_tiny()
+        params = jax.eval_shape(
+            lambda k: init_hybrid(k, cfg), jax.random.PRNGKey(0))
+        cache = jax.eval_shape(ft.partial(
+            init_hybrid_cache, cfg, 2, 32, 6, 16))
+        if which == "prefill":
+            return make_hybrid_prefill_fn(cfg), (
+                params, cache, _sds((1, 16), "int32"), _sds((16,), "int32"),
+                _sds((), "int32"), _sds((1,), "int32"), _sds((2,), "int32"))
+        return make_hybrid_decode_fn(cfg), (
+            params, cache, _sds((2,), "int32"), _sds((2,), "bool"))
 
     return build
 
@@ -1472,6 +1506,16 @@ def repo_entries() -> List[TraceEntry]:
                    _paged_decode_step_entry(),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=4),
+        TraceEntry("hybrid_prefill_step",
+                   "apex_tpu.transformer.functional.gated_delta",
+                   _hybrid_step_entry("prefill"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=6),
+        TraceEntry("hybrid_decode_step",
+                   "apex_tpu.transformer.functional.gated_delta",
+                   _hybrid_step_entry("decode"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=6),
         TraceEntry("gpt_paged_decode_step_tp2", "apex_tpu.serving.decode",
                    _paged_decode_step_entry(tp=2),
                    checks=("precision", "memory", "schedule", "aliases"),

@@ -488,6 +488,32 @@ def _paged_attention_cfg():
     return build
 
 
+def _gated_delta_cfg(which):
+    """The two Gated DeltaNet kernels at the published widths (30 heads,
+    d_k 96, d_v 192). ``chunk``: the largest bucket; resident are five
+    heads' blocks of one chunk (W_v, W_k, Q, K^T, A, the decay row, the
+    output) and their state. ``step``: the stacked state of 12 layers x 16
+    slots stays where it is; resident are ten heads of one slot, in and
+    out."""
+    def build():
+        from apex_tpu.transformer.functional import gated_delta as gd
+
+        if which == "chunk":
+            return gd.gated_delta_chunked, (
+                _sds((30, 4096, 96), "float32"),
+                _sds((30, 4096, 96), "float32"),
+                _sds((30, 4096, 192), "float32"),
+                _sds((30, 4096), "float32"), _sds((30, 4096), "float32"))
+        return gd.gated_delta_step, (
+            _sds((16, 30, 96), "float32"), _sds((16, 30, 96), "float32"),
+            _sds((16, 30, 192), "float32"), _sds((16, 30), "float32"),
+            _sds((16, 30), "float32"),
+            _sds((12, 16, 30, 96, 192), "float32"), _sds((), "int32"),
+            _sds((16,), "bool"))
+
+    return build
+
+
 def _draft_forward_cfg():
     """The model drafter's per-token forward (``draft_gpt_tiny`` over
     its dense lockstep cache): XLA math today, so — like the paged
@@ -551,6 +577,11 @@ def repo_configs() -> List[Config]:
         "paged_decode_attention_medium",
         "apex_tpu.transformer.functional.paged_attention",
         _paged_attention_cfg()))
+    for which in ("chunk", "step"):
+        cfgs.append(Config(
+            f"gated_delta_{which}_7b",
+            "apex_tpu.transformer.functional.gated_delta",
+            _gated_delta_cfg(which)))
     cfgs.append(Config("gpt_spec_verify_step", "apex_tpu.serving.decode",
                        _paged_serving_cfg("verify")))
     cfgs.append(Config("gpt_tree_verify_step", "apex_tpu.serving.decode",

@@ -93,6 +93,9 @@ class GPTConfig:
     # with no bf16 round-trip, so microbatch accumulation keeps low bits
     gradient_accumulation_fusion: bool = False
 
+    #: no layer keeps a recurrent state (``models.hybrid`` has some that do)
+    recurrent = False
+
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads

@@ -123,15 +123,35 @@ class PagedKVCache(NamedTuple):
     v_scale: Optional[jax.Array] = None  # (L, num_pages, num_heads) f32
 
 
+class HybridKVCache(NamedTuple):
+    """The cache of a model with recurrent layers (``cfg.recurrent``:
+    ``apex_tpu.models.hybrid``): two kinds of state in one donated tuple.
+    ``k`` / ``v`` / ``lengths`` / ``block_tables`` are :class:`PagedKVCache`'s,
+    the pool's ``L`` counting the full-attention layers only, and the host
+    side (``PagePool``, block tables, the page copy) treats them alike.
+    ``state`` (one matrix per linear layer, slot and head) and ``conv``
+    (the last ``w - 1`` inputs of each linear layer's convolution) are PER
+    SLOT and outside the pages: whole at every moment, they cannot be
+    paged, shared by prefix or rolled back by rewriting rows; a slot's
+    prefill overwrites them and nothing else resets them."""
+    k: jax.Array             # (L_full, num_pages, page_size, heads * hd)
+    v: jax.Array
+    lengths: jax.Array       # (num_slots,) int32
+    block_tables: jax.Array  # (num_slots, max_pages) int32
+    state: jax.Array         # (L_lin, slots, H, d_k, d_v) float32
+    conv: jax.Array          # (L_lin, slots, w - 1, channels) float32
+
+    # no quantized pool beside recurrent state (the engine refuses it)
+    k_scale = None
+    v_scale = None
+
+
 def max_pages_per_slot(max_len: int, page_size: int) -> int:
     return -(-max_len // page_size)
 
 
-def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
-                     num_pages: int, page_size: int,
-                     dtype=jnp.bfloat16) -> PagedKVCache:
-    """Zero page pool + block tables parked on ``SCRATCH_PAGE`` (writes
-    of unoccupied slots land in scratch, reads of it are masked)."""
+def _check_pool_sizes(num_slots: int, max_len: int, num_pages: int,
+                      page_size: int) -> None:
     if max_len < 1 or num_slots < 1 or page_size < 1:
         raise ValueError(
             f"need positive num_slots/max_len/page_size, got "
@@ -140,6 +160,14 @@ def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
         raise ValueError(
             f"num_pages {num_pages} must exceed the {RESERVED_PAGES} "
             f"reserved pages (null + scratch)")
+
+
+def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
+                     num_pages: int, page_size: int,
+                     dtype=jnp.bfloat16) -> PagedKVCache:
+    """Zero page pool + block tables parked on ``SCRATCH_PAGE`` (writes
+    of unoccupied slots land in scratch, reads of it are masked)."""
+    _check_pool_sizes(num_slots, max_len, num_pages, page_size)
     if not cfg.use_rope and max_len > cfg.max_position_embeddings:
         raise ValueError(
             f"max_len {max_len} exceeds the learned position table "
@@ -164,6 +192,28 @@ def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
                         v=jnp.zeros(shape, dtype),
                         lengths=jnp.zeros((num_slots,), jnp.int32),
                         block_tables=bt)
+
+
+def init_hybrid_cache(cfg, num_slots: int, max_len: int, num_pages: int,
+                      page_size: int, dtype=jnp.bfloat16) -> HybridKVCache:
+    """The two kinds of state of a ``models.hybrid.HybridConfig``: a page
+    pool over the full-attention layers only, and zeroed per-slot recurrent
+    state and convolution tails (float32 both, whatever the pool's
+    ``dtype``) for the linear layers."""
+    _check_pool_sizes(num_slots, max_len, num_pages, page_size)
+    if jnp.dtype(dtype) == jnp.int8:
+        raise ValueError("no int8 pool beside recurrent state")
+    shape = (cfg.num_full_layers, num_pages, page_size,
+             cfg.num_heads * cfg.head_dim)
+    state, conv = cfg.state_shapes(num_slots)
+    return HybridKVCache(
+        k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+        lengths=jnp.zeros((num_slots,), jnp.int32),
+        block_tables=jnp.full(
+            (num_slots, max_pages_per_slot(max_len, page_size)),
+            SCRATCH_PAGE, jnp.int32),
+        state=jnp.zeros(state, jnp.float32),
+        conv=jnp.zeros(conv, jnp.float32))
 
 
 def audit_block_tables(block_tables, slot_pages) -> bool:

@@ -84,7 +84,7 @@ from apex_tpu.models.gpt import (
     _pages_to_tiles, _rope_or_none, _tied_lm_logits, _tiles_to_pages,
 )
 from apex_tpu.serving.cache import (
-    KVCache, PagedKVCache, cache_partition_specs,
+    HybridKVCache, KVCache, PagedKVCache, cache_partition_specs,
     paged_cache_partition_specs,
 )
 
@@ -391,12 +391,22 @@ def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
         body, x, (params["layers"], jnp.arange(layers, dtype=jnp.int32)))
     hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
     logits = logits_fn(params, hidden[:, 0])
-    _, num_pages, page_size, width = cache.k.shape
+    k, v = _write_new_rows(cache, k_rows, v_rows)
+    bt = _self_rewrite(bt)
+    return PagedKVCache(k, v, jnp.where(active, pos + 1, pos), bt), logits
+
+
+def _write_new_rows(cache, k_rows, v_rows):
+    """Every pool layer's new row of every slot (``k_rows`` / ``v_rows``
+    ``(L, slots, width)``) written at ``cache.lengths`` through the block
+    tables: the pool as a list of rows (a view: its layout is row-major)
+    takes the layers * slots rows in ONE in-place row scatter. Inactive
+    slots write to the page their NULL/scratch row names. Returns the
+    pool's ``(k, v)``."""
+    pos, bt = cache.lengths, cache.block_tables
+    layers, num_pages, page_size, width = cache.k.shape
     logical = jnp.clip(pos // page_size, 0, bt.shape[1] - 1)
     pages = jnp.take_along_axis(bt, logical[:, None], 1)[:, 0]
-    # inactive slots write to the page their NULL/scratch row names.
-    # The pool as a list of rows (a view: its layout is row-major) takes
-    # the layers * slots new rows in one in-place row scatter
     at = ((jnp.arange(layers)[:, None] * num_pages + pages[None, :])
           * page_size + pos[None, :] % page_size).reshape(-1)
 
@@ -404,9 +414,7 @@ def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
         flat = pool.reshape(-1, width).at[at].set(rows.reshape(-1, width))
         return flat.reshape(pool.shape)
 
-    k, v = write(cache.k, k_rows), write(cache.v, v_rows)
-    bt = _self_rewrite(bt)
-    return PagedKVCache(k, v, jnp.where(active, pos + 1, pos), bt), logits
+    return write(cache.k, k_rows), write(cache.v, v_rows)
 
 
 def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
@@ -797,6 +805,114 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
             logits_fn=logits_fn)
 
     return jax.jit(chunk_prefill, donate_argnums=1)
+
+
+# ---------------------------------------------------------------------------
+# a model with recurrent layers (models.hybrid): the same two programs
+# ---------------------------------------------------------------------------
+
+def _hybrid_prefill_core(params, cfg, cache: HybridKVCache, ids, mask, slot,
+                         write_pages, table_row):
+    """:func:`_paged_prefill_core` for a ``HybridConfig``: the
+    full-attention layers' K/V rows go to ``write_pages`` exactly as there
+    (the pool's layers are the full layers), and every linear layer's
+    recurrent state and convolution tail, as the prompt's last real token
+    left them, overwrite row ``slot`` of ``cache.state`` / ``cache.conv``:
+    that write is the slot's only reset."""
+    from apex_tpu.models import hybrid
+
+    if ids.ndim != 2 or ids.shape[0] != 1:
+        raise ValueError(f"prefill takes one slot's (1, s) ids, got "
+                         f"{ids.shape}")
+    s = ids.shape[1]
+    page_size = cache.k.shape[2]
+    if s % page_size:
+        raise ValueError(f"prompt bucket {s} is not a multiple of "
+                         f"page_size {page_size}")
+    if write_pages.shape != (s // page_size,):
+        raise ValueError(f"write_pages {write_pages.shape} != one page "
+                         f"per bucket page ({s // page_size},)")
+    x, states, tails, k, v = hybrid.prefill_layers(
+        params, cfg, hybrid.embed(params, ids[0]), mask, cache.k.dtype)
+    length = jnp.sum(mask).astype(jnp.int32)
+    logits = hybrid.logits_of(
+        params, cfg, lax.dynamic_slice_in_dim(x, length - 1, 1, 0))
+
+    def pages(t):
+        # (L_full, s, width) -> whole pages, the pad tail zeroed
+        t = t * mask.astype(t.dtype)[None, :, None]
+        return t.reshape(t.shape[0], -1, page_size, t.shape[-1])
+
+    return cache._replace(
+        k=cache.k.at[:, write_pages].set(pages(k)),
+        v=cache.v.at[:, write_pages].set(pages(v)),
+        lengths=lax.dynamic_update_slice(cache.lengths, length[None],
+                                         (slot,)),
+        block_tables=lax.dynamic_update_slice(
+            cache.block_tables, table_row[None, :], (slot, 0)),
+        state=lax.dynamic_update_slice(
+            cache.state, states[:, None], (0, slot, 0, 0, 0)),
+        conv=lax.dynamic_update_slice(
+            cache.conv, tails[:, None], (0, slot, 0, 0))), logits
+
+
+def _hybrid_decode_core(params, cfg, cache: HybridKVCache, tokens, active):
+    """:func:`_paged_decode_core` for a ``HybridConfig``, scanned by
+    period: each linear layer steps its layer of the stacked recurrent
+    state in place (``apex_gdn_decode_fwd``; the state and the convolution
+    tails are carries of the scan, never copied), each full layer attends
+    over the pool in place, and the full layers' new rows go into the pool
+    in one scatter after the scan. Slots that are not ``active`` keep their
+    recurrent state and their length."""
+    from apex_tpu.models import hybrid
+
+    pos = cache.lengths
+    bt = cache.block_tables
+    x = hybrid.embed(params, tokens)
+    n = cfg.linear_per_period
+
+    def period(carry, pp_at):
+        x, state, conv = carry
+        pp, at = pp_at
+        for j, lp in enumerate(pp["linear"]):
+            x, state, conv = hybrid.linear_block_decode(
+                lp, x, cfg, state, conv, at * n + j, active)
+        x, k_row, v_row = hybrid.full_block_decode(
+            pp["full"], x, cfg, cache.k, cache.v, at, bt, pos)
+        return (x, state, conv), (k_row, v_row)
+
+    (x, state, conv), (k_rows, v_rows) = lax.scan(
+        period, (x, cache.state, cache.conv),
+        (params["periods"],
+         jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
+    logits = hybrid.logits_of(params, cfg, x)
+    k, v = _write_new_rows(cache, k_rows, v_rows)
+    return cache._replace(
+        k=k, v=v, lengths=jnp.where(active, pos + 1, pos),
+        block_tables=_self_rewrite(bt), state=state, conv=conv), logits
+
+
+def make_hybrid_prefill_fn(cfg):
+    """jit(prefill) for a model with recurrent layers, cache DONATED (6
+    alias pairs: pool k/v, lengths, block tables, recurrent state,
+    convolution tails); one executable per bucket, and the same program
+    name as every other prefill (``jit_prefill``)."""
+
+    def prefill(params, cache, ids, mask, slot, write_pages, table_row):
+        return _hybrid_prefill_core(params, cfg, cache, ids, mask, slot,
+                                    write_pages, table_row)
+
+    return jax.jit(prefill, donate_argnums=1)
+
+
+def make_hybrid_decode_fn(cfg):
+    """jit(decode) for a model with recurrent layers, cache DONATED; one
+    executable per cache shape (``jit_decode``)."""
+
+    def decode(params, cache, tokens, active):
+        return _hybrid_decode_core(params, cfg, cache, tokens, active)
+
+    return jax.jit(decode, donate_argnums=1)
 
 
 def make_copy_page_fn():

@@ -172,6 +172,13 @@ def _validate_replicas(prefill_engines, decode_engines) -> None:
             raise ValueError(
                 f"the {role} replica must be a paged engine: the "
                 "handoff ships page tiles keyed by prefix_page_keys")
+        if getattr(eng.cfg, "recurrent", False):
+            raise ValueError(
+                "page transfer is not offered for a model with recurrent "
+                f"layers ({type(eng.cfg).__name__}): the handoff ships K/V "
+                "pages, and the slot's recurrent state and convolution "
+                "tails would have to travel with them; such a model "
+                "stays colocated")
         if getattr(eng.cache, "k_scale", None) is not None:
             raise ValueError(
                 "disaggregated serving is not offered over the int8 "

@@ -131,10 +131,11 @@ import numpy as np
 from apex_tpu.models.gpt import GPTConfig
 from apex_tpu.serving.cache import (
     NULL_PAGE, RESERVED_PAGES, SCRATCH_PAGE, audit_block_tables,
-    init_cache, init_paged_cache, max_pages_per_slot,
+    init_cache, init_hybrid_cache, init_paged_cache, max_pages_per_slot,
 )
 from apex_tpu.serving.decode import (
     make_chunk_prefill_fn, make_copy_page_fn, make_decode_fn,
+    make_hybrid_decode_fn, make_hybrid_prefill_fn,
     make_paged_chunk_prefill_fn, make_paged_decode_fn,
     make_paged_prefill_fn, make_paged_tree_verify_fn,
     make_paged_verify_fn, make_prefill_fn, make_tree_verify_fn,
@@ -163,7 +164,7 @@ from apex_tpu.serving.sampling import (
     tree_speculative_accept,
 )
 from apex_tpu.utils.profiler import span as profiler_span
-from apex_tpu.utils.seqlen import bucket_for, default_buckets, pad_to_bucket
+from apex_tpu.utils.seqlen import bucket_for, default_buckets
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,6 +209,35 @@ def _on_profiler_clock(tracer: Optional[Tracer]) -> Tracer:
     return tracer
 
 
+def _refuse_for_recurrent(cfg, **features) -> None:
+    """A model with recurrent layers (``cfg.recurrent``) is served by
+    monolithic prefill and plain decode only. Its per-slot state is whole at
+    every moment: each feature below would need a SNAPSHOT of it (kept,
+    rolled back, shipped or re-rounded along with the pages), which no
+    program takes yet (ROADMAP, Queue 2). So the feature is refused by name
+    where it is asked for, at construction, and never falls back in
+    silence. ``features``: name -> (asked for, what it would need)."""
+    if not getattr(cfg, "recurrent", False):
+        return
+    for name, (asked, needs) in features.items():
+        if asked:
+            raise ValueError(
+                f"{name} is not offered for a model with recurrent layers "
+                f"({type(cfg).__name__}): {needs}")
+
+
+def _pad_on_host(tokens: Sequence[int], buckets: Sequence[int]):
+    """``tokens`` as the ``(1, bucket)`` int32 ids and ``(bucket,)`` int32
+    mask (1 = real token) a prefill program takes, padded with numpy: what
+    ``utils.seqlen.pad_to_bucket`` returns, without the two tiny device
+    programs per distinct prompt length that its ``jnp.pad`` and mask
+    compile (minutes of a cold start over a few hundred lengths)."""
+    n = len(tokens)
+    ids = np.zeros((1, bucket_for(n, buckets)), np.int32)
+    ids[0, :n] = tokens
+    return ids, (np.arange(ids.shape[1]) < n).astype(np.int32)
+
+
 @dataclasses.dataclass
 class _Slot:
     request_id: int
@@ -248,6 +278,9 @@ class DecodeEngine:
                  draft_model=None, tree_spec: bool = False,
                  adaptive_spec: bool = False,
                  tracer: Optional[Tracer] = None):
+        _refuse_for_recurrent(cfg, **{"the dense cache": (
+            True, "the recurrent state lives beside the paged pool; use "
+            "PagedDecodeEngine")})
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
@@ -322,12 +355,11 @@ class DecodeEngine:
         if fired:
             raise InjectedFault("prefill_exec",
                                 self.injector.calls("prefill_exec") - 1)
-        ids = np.asarray(prompt, np.int32)[None, :]
         trc = self.tracer
         trc.begin("prefill", request_id=trc.admitting, slot=slot,
-                  bucket=bucket_for(ids.shape[1], self.buckets),
-                  prompt_tokens=ids.shape[1])
-        ids, mask = pad_to_bucket(ids, ids.shape[1], buckets=self.buckets)
+                  bucket=bucket_for(len(prompt), self.buckets),
+                  prompt_tokens=len(prompt))
+        ids, mask = _pad_on_host(prompt, self.buckets)
         self.cache, logits = self._prefill(
             self.params, self.cache, ids, mask, jnp.int32(slot))
         trc.end("prefill")
@@ -361,8 +393,7 @@ class DecodeEngine:
             raise InjectedFault(
                 "chunk_prefill_exec",
                 self.injector.calls("chunk_prefill_exec") - 1)
-        ids = np.asarray(chunk, np.int32)[None, :]
-        ids, mask = pad_to_bucket(ids, ids.shape[1], buckets=(bucket,))
+        ids, mask = _pad_on_host(chunk, (bucket,))
         trc = self.tracer
         trc.begin("chunk_prefill", slot=slot, pos=pos, bucket=bucket,
                   final=final)
@@ -385,7 +416,7 @@ class DecodeEngine:
         (:func:`~apex_tpu.serving.sampling.finite_rows`) must catch
         it."""
         trc = self.tracer
-        trc.begin("exec", kind="decode")
+        trc.begin("exec", kind="decode", **self._exec_stats())
         self.cache, logits = self._decode(self.params, self.cache,
                                           tokens, active)
         trc.end("exec")
@@ -394,6 +425,10 @@ class DecodeEngine:
             victim = int(payload % logits.shape[0])
             logits = logits.at[victim].set(jnp.nan)
         return logits
+
+    def _exec_stats(self) -> Dict[str, int]:
+        """What the ``exec`` span says beyond its kind (nothing here)."""
+        return {}
 
     def sample(self, logits, keys, temperature) -> jax.Array:
         toks = self._sample(logits, keys, temperature, top_k=self.top_k,
@@ -641,6 +676,34 @@ class PagedDecodeEngine(DecodeEngine):
                 "tree verify is not offered over the int8 page pool: a "
                 "branch commit would re-round committed history at "
                 "branch-dependent scales; kv8 keeps linear speculation")
+        self.recurrent = bool(getattr(cfg, "recurrent", False))
+        _refuse_for_recurrent(
+            cfg,
+            prefix_sharing=(prefix_sharing, "a shared page stands for "
+                            "tokens whose recurrent state was never kept; "
+                            "build the engine with prefix_sharing=False"),
+            draft_model=(draft_model is not None, "speculation is refused, "
+                         "so a drafter has no use"),
+            tree_spec=(tree_spec, "tree verify scores branches the "
+                       "recurrent state cannot fork over"),
+            spec_k=(spec_k > 0, "a rejected draft would have to roll the "
+                    "recurrent state back, and verify advances k+1 tokens "
+                    "with no state kept in between"),
+            **{"the int8 pool (cache_dtype=int8)": (
+                jnp.dtype(cache_dtype) == jnp.int8, "the recurrent layers' "
+                "programs are not built over the quantized pool's "
+                "per-layer write and gather"),
+               "the host tier (host_tier=)": (
+                   host_tier is not None, "a promoted prefix skips "
+                   "computing its tokens, which needs the recurrent state "
+                   "at the page boundary"),
+               "weight-only int8 (a quantized tree)": (
+                   is_quantized_tree(params), "the recurrent layers have "
+                   "no dequant-fused projections"),
+               "compute_dtype": (
+                   compute_dtype is not None, "its programs fix their "
+                   "precision: each product's inputs in the weights' "
+                   "dtype, float32 between two products")})
         self.draft_model = draft_model
         self.tree_spec = tree_spec
         self.adaptive_spec = adaptive_spec
@@ -653,8 +716,10 @@ class PagedDecodeEngine(DecodeEngine):
         # leaves the int8 pool carries) — the host side (PagePool, COW,
         # block tables) is dtype-agnostic throughout
         quantized = is_quantized_tree(params)
-        self.cache = init_paged_cache(cfg, num_slots, max_len, num_pages,
-                                      page_size, cache_dtype)
+        self.cache = (init_hybrid_cache if self.recurrent
+                      else init_paged_cache)(cfg, num_slots, max_len,
+                                             num_pages, page_size,
+                                             cache_dtype)
         self.pool = PagePool(num_pages, page_size, free_order,
                              injector=self.injector,
                              host_tier=host_tier)
@@ -688,14 +753,24 @@ class PagedDecodeEngine(DecodeEngine):
         # parked on scratch (see begin_chunk_prefill), so the audit
         # must not expect it to mirror _slot_pages yet
         self._prefill_parked: set = set()
-        self._prefill = make_paged_prefill_fn(cfg, compute_dtype,
-                                              quantized)
-        self._chunk_prefill = make_paged_chunk_prefill_fn(
-            cfg, compute_dtype, quantized)
-        self._decode = make_paged_decode_fn(cfg, compute_dtype, quantized)
-        self._verify = make_paged_verify_fn(cfg, compute_dtype, quantized)
-        self._tree_verify = make_paged_tree_verify_fn(
-            cfg, compute_dtype, quantized) if tree_spec else None
+        if self.recurrent:
+            # the two programs above and no other: what needs more was
+            # refused. Bytes a prefill writes besides its pages:
+            self._state_bytes = cfg.state_bytes_per_slot()
+            self._prefill = make_hybrid_prefill_fn(cfg)
+            self._decode = make_hybrid_decode_fn(cfg)
+            self._chunk_prefill = self._verify = self._tree_verify = None
+        else:
+            self._prefill = make_paged_prefill_fn(cfg, compute_dtype,
+                                                  quantized)
+            self._chunk_prefill = make_paged_chunk_prefill_fn(
+                cfg, compute_dtype, quantized)
+            self._decode = make_paged_decode_fn(cfg, compute_dtype,
+                                                quantized)
+            self._verify = make_paged_verify_fn(cfg, compute_dtype,
+                                                quantized)
+            self._tree_verify = make_paged_tree_verify_fn(
+                cfg, compute_dtype, quantized) if tree_spec else None
         self._copy = make_copy_page_fn()
         self._init_samplers()
 
@@ -727,6 +802,13 @@ class PagedDecodeEngine(DecodeEngine):
                 self.params, self.cache, i32(self.num_slots),
                 jax.ShapeDtypeStruct((self.num_slots,), jnp.bool_)),
         }
+
+    def _exec_stats(self) -> Dict[str, int]:
+        """A model with recurrent layers says how many slots' state the
+        step updates: the slots that hold a request (each maps pages)."""
+        if not self.recurrent:
+            return {}
+        return {"state_slots": sum(1 for p in self._slot_pages if p)}
 
     def page_demand(self, total_len: int) -> None:
         need = max_pages_per_slot(min(total_len, self.max_len),
@@ -807,11 +889,11 @@ class PagedDecodeEngine(DecodeEngine):
         trc.begin("prefill", request_id=trc.admitting, slot=slot,
                   bucket=bucket_for(len(toks) - start, self.buckets),
                   prompt_tokens=len(toks), shared_pages=covered,
-                  page_size=self.page_size)
+                  page_size=self.page_size,
+                  **({"state_bytes": self._state_bytes}
+                     if self.recurrent else {}))
         if skip:
-            ids = np.asarray(toks[start:], np.int32)[None, :]
-            ids, mask = pad_to_bucket(ids, ids.shape[1],
-                                      buckets=self.buckets)
+            ids, mask = _pad_on_host(toks[start:], self.buckets)
             write = np.full((ids.shape[1] // self.page_size,),
                             SCRATCH_PAGE, np.int32)
             for j in range(write.shape[0]):
@@ -823,9 +905,7 @@ class PagedDecodeEngine(DecodeEngine):
                 jnp.int32(start), jnp.asarray(write), jnp.asarray(row),
                 jnp.asarray(row))
         else:
-            ids = np.asarray(toks, np.int32)[None, :]
-            ids, mask = pad_to_bucket(ids, ids.shape[1],
-                                      buckets=self.buckets)
+            ids, mask = _pad_on_host(toks, self.buckets)
             write = np.full((ids.shape[1] // self.page_size,),
                             SCRATCH_PAGE, np.int32)
             write[covered:n_pages] = private
@@ -919,8 +999,7 @@ class PagedDecodeEngine(DecodeEngine):
             raise InjectedFault(
                 "chunk_prefill_exec",
                 self.injector.calls("chunk_prefill_exec") - 1)
-        ids = np.asarray(chunk, np.int32)[None, :]
-        ids, mask = pad_to_bucket(ids, ids.shape[1], buckets=(bucket,))
+        ids, mask = _pad_on_host(chunk, (bucket,))
         first_page = pos // self.page_size
         write = np.full((bucket // self.page_size,), SCRATCH_PAGE,
                         np.int32)
@@ -1205,6 +1284,12 @@ class ContinuousBatchingScheduler:
         # chunk_tokens-sized pieces run BETWEEN decode ticks under a
         # per-tick token budget (see _prefill_phase). None keeps the
         # classic monolithic admission prefill.
+        _refuse_for_recurrent(
+            getattr(engine, "cfg", None),
+            **{"chunked prefill (chunk_tokens=)": (
+                chunk_tokens is not None, "a chunk would have to start "
+                "from the recurrent state the chunk before it left, which "
+                "no program carries")})
         if chunk_tokens is not None:
             chunk_tokens = int(chunk_tokens)
             if chunk_tokens < 1:

@@ -1,0 +1,330 @@
+"""The cell ``olmo_hybrid_7b.long_prompt_decode`` (PR 27): its count files
+by hand, its readers on a trace without and with the new kernels, its
+manifest entries, and the two runs that have to come out not correct."""
+
+import json
+import os
+import sys
+import types
+
+import pytest
+
+from benchmark import harness, spans, trace
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+BENCH = os.path.join(REPO, "benchmark")
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+CELL = "olmo_hybrid_7b.long_prompt_decode"
+NEW = ("gdn_decode_kernel_ms_per_decode", "gdn_decode_roofline_pct",
+       "gdn_chunk_kernel_ms_per_ktok", "gdn_chunk_roofline_pct",
+       "hybrid_decode_hbm_pct", "hybrid_paged_attn_kernel_ms_per_decode",
+       "hybrid_flash_kernel_ms_per_prefill")
+ATTENTION = NEW[-2:]      # GPT's kernels too, one call per FULL layer here
+PEAKS = harness.load_json(BENCH, "peaks.json")["TPU v5 lite"]
+
+sys.path.insert(0, BENCH)
+try:
+    import run as bench_run      # benchmark/run.py
+finally:
+    sys.path.remove(BENCH)
+
+
+def sizes(rehearsal=False):
+    config = harness.load_json(BENCH, "configs", "olmo_hybrid_7b.json")
+    if rehearsal:
+        config = harness.rehearsal_view(config)
+    return harness.load_module("reference", "olmo_hybrid_7b",
+                               BENCH).sizes_of(config)
+
+
+def reader(name):
+    return harness.load_module("metrics", name, BENCH).read
+
+
+# -- the count files, by hand -------------------------------------------------
+
+def test_gated_delta_counts_by_hand():
+    gd = harness.load_module("kernels", "gated_delta", BENCH)
+    sz = sizes()
+    # per slot and head: the 96 x 192 state in and out, rows q, k, beta k
+    # (96 each), beta v, alpha and the output (192 each); float32
+    assert gd.decode_bytes(sz, 16) == 4 * 16 * 30 * (
+        2 * 96 * 192 + 3 * 96 + 3 * 192) == 72_437_760
+    # per token and head: three products with the state and one of 64 x 64
+    assert gd.chunk_flops(sz, 4096) == 30 * 4096 * (
+        3 * 2 * 96 * 192 + 2 * 64 * 192) == 16_609_443_840
+    # per token and head: W_v and the output (192 each), W_k, Q, K^T (96
+    # each), a row of A (64); per chunk the decay row; per call the state
+    assert gd.chunk_bytes(sz, 4096, calls=1) == 4 * 30 * (
+        4096 * (2 * 192 + 3 * 96 + 64) + 64 * 192 + 96 * 192) == 365_445_120
+    assert gd.chunk_bytes(sz, 8192, calls=2) == 2 * 365_445_120
+
+
+def test_hybrid_decode_step_counts_by_hand():
+    step = harness.load_module("kernels", "hybrid_decode_step", BENCH)
+    sz = sizes()
+    mlp = 3 * 3840 * 11008
+    linear = 3840 * (11520 + 5760) + 3840 * 60 + 4 * 11520 \
+        + 5760 * 3840 + mlp
+    full = 4 * 3840 * 3840 + mlp
+    assert (linear, full) == (215_562_240, 185_794_560)    # the issue's
+    matrices = 12 * linear + 4 * full + 3840 * 100352
+    small = 12 * (2 * 3840 + 192 + 2 * 30) + 4 * 4 * 3840 + 3840
+    assert step.weight_bytes(sz) == 2 * matrices + 4 * small \
+        == 7_431_195_456
+    assert step.state_bytes(sz, 16) == 12 * 16 * 2 * 4 * (
+        30 * 96 * 192 + 3 * 11520) == 902_430_720
+    assert step.kv_bytes(sz, 25_600) == 2 * 4 * 3840 * 2 * 25_600
+    assert step.bytes_needed(sz, 25_600, 16) == 9_906_490_176
+
+
+# -- the readers ----------------------------------------------------------------
+
+def test_new_readers_give_nothing_on_the_recorded_gpt_trace():
+    """A trace of a program without recurrent layers, with GPT's counts and
+    with the hybrid's: no reader raises, every one returns ``None`` (the
+    trace is PR 24's: its decode attention is no kernel yet, its prompts are
+    under the flash kernel's threshold)."""
+    path = os.path.join(DATA, "small_gpt_serve.xplane.pb.gz")
+    cell = types.SimpleNamespace(bench_dir=BENCH)
+    for counts in ({"sizes": {"layers": 2, "hidden": 64}, "slots": 3,
+                    "mapped_positions": 40},
+                   {"sizes": sizes(True), "mapped_positions": 40}, {}):
+        run = {"trace": trace.reduce_file(path),
+               "apex_spans": spans.load(path), "counts": counts,
+               "peaks": PEAKS, "cell": cell}
+        got = {name: reader(name)(run) for name in NEW}
+        assert all(v is None for v in got.values()), got
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """The small trace of the new cell, recorded on the chip (PR 27):
+    ``run.py --workload olmo_hybrid_7b.long_prompt_decode --rehearsal-on-chip
+    --trace 1 --option trace_seconds=0.25 --option keep_trace=...``: the
+    rehearsal sizes (one period, hidden 128, 3 slots), 0.25 s from t = 0.5 s
+    of a backlog of 40."""
+    path = os.path.join(DATA, "small_hybrid_serve.xplane.pb.gz")
+    return {"trace": trace.reduce_file(path), "apex_spans": spans.load(path),
+            "counts": {"sizes": sizes(True), "mapped_positions": 3 * 300},
+            "peaks": PEAKS, "cell": types.SimpleNamespace(bench_dir=BENCH)}
+
+
+def test_recorded_hybrid_trace_is_small_and_names_both_kernels(recorded):
+    path = os.path.join(DATA, "small_hybrid_serve.xplane.pb.gz")
+    assert os.path.getsize(path) < 1 << 20
+    reduced = recorded["trace"]
+    decodes = len(reduced.program_times("jit_decode"))
+    prefills = len(reduced.program_times("jit_prefill"))
+    assert decodes >= 10 and prefills >= 1
+    gdn = harness.load_module("metrics", "gdn_decode_kernel_ms_per_decode",
+                              BENCH).GDN_DECODE_FWD
+    chunk = harness.load_module("metrics", "gdn_chunk_kernel_ms_per_ktok",
+                                BENCH).GDN_CHUNK_FWD
+    # the engagement counters: one call per linear layer per execution
+    assert reduced.kernel_time(gdn.match)[1] == 3 * decodes
+    assert reduced.kernel_time(chunk.match)[1] == 3 * prefills
+    stats = [s.stats for s in recorded["apex_spans"] if s.phase == "prefill"]
+    assert stats and all(s["state_bytes"] == 4 * 3 * (2 * 16 * 32 + 3 * 128)
+                         and s["shared_pages"] == 0 for s in stats)
+    execs = [s.stats for s in recorded["apex_spans"] if s.phase == "exec"]
+    assert execs and all(1 <= s["state_slots"] <= 3 for s in execs)
+
+
+def test_new_readers_give_numbers_on_the_recorded_hybrid_trace(recorded):
+    got = {name: reader(name)(recorded) for name in NEW}
+    assert all(v is not None and v > 0 for v in got.values()), got
+    kernel = harness.load_module("metrics", "gdn_decode_kernel_ms_per_decode",
+                                 BENCH)
+    assert kernel.state_slots(recorded) == 3       # the exec spans say so
+    # tiny shapes: microseconds, and nowhere near a roofline
+    assert got["gdn_decode_kernel_ms_per_decode"] < 1.0
+    assert all(got[n] < 100.0 for n in NEW if n.endswith("_pct"))
+    # the shared readers work on it unchanged
+    for name in ("decode_device_ms", "device_idle_pct.serve",
+                 "tick_idle_ms.admit", "tick_idle_ms.build_inputs",
+                 "tick_idle_ms.dispatch", "prefill_shared_pct"):
+        assert reader(name)(recorded) is not None, name
+    assert reader("prefill_shared_pct")(recorded) == 0.0
+
+
+@pytest.mark.parametrize("calls, want", [(36, 1e3 * 0.012 / 3), (0, None),
+                                         (35, None)])
+def test_gdn_decode_kernel_ms_wants_whole_executions(calls, want):
+    name = ("%apex_gdn_decode_fwd.19 = (f32[16,3,10,192], f32[12,16,30,96,"
+            "192]) custom-call(s32[1] %mul.6, s32[16] %convert.1)")
+
+    def kernel_time(match):
+        return (0.012, calls) if match(name) and calls else (0.0, 0)
+
+    run = {"trace": types.SimpleNamespace(kernel_time=kernel_time),
+           "counts": {"sizes": {"linear_layers": 12}}}
+    got = reader("gdn_decode_kernel_ms_per_decode")(run)
+    assert got == (pytest.approx(want) if want else None)
+
+
+@pytest.mark.parametrize("name, kernel", zip(ATTENTION, (
+    "%apex_paged_decode_fwd.7 = bf16[16,1,3840]{2,1,0} custom-call(s32[16,"
+    "256] %block_tables.1, s32[16] %pos.1)",
+    "%apex_flash_fwd.3 = bf16[1,30,4096,128] custom-call(bf16[8] %a)")))
+@pytest.mark.parametrize("calls, want", [(40, 1e3 * 0.02 / 10), (4, 20.0),
+                                         (0, None), (39, None)])
+def test_attention_kernels_are_read_per_full_layer(name, kernel, calls, want):
+    def kernel_time(match):
+        return (0.02, calls) if match(kernel) and calls else (0.0, 0)
+
+    run = {"trace": types.SimpleNamespace(kernel_time=kernel_time),
+           "counts": {"sizes": {"layers": 16, "full_layers": 4}}}
+    got = reader(name)(run)
+    assert got == (pytest.approx(want) if want else None)
+    run["counts"]["sizes"].pop("full_layers")       # GPT's sizes: nothing
+    assert reader(name)(run) is None
+
+
+# -- the manifest ---------------------------------------------------------------
+
+def test_manifest_gains_the_cell_and_only_appends_its_name():
+    m = harness.load_json(REPO, "BENCHMARK.json")
+    assert m["configs"][-1]["name"] == "olmo_hybrid_7b"
+    assert m["configs"][-1]["reduced"] == ["num_hidden_layers", "layer_types"]
+    assert m["workloads"][-1] == {**m["workloads"][-1], "name": CELL,
+                                  "config": "olmo_hybrid_7b",
+                                  "traffic": "long_prompt_decode", "chips": 1}
+    at = {e["name"]: i for i, e in enumerate(m["per_layer"])}
+    new = [m["per_layer"][at[name]] for name in NEW]
+    assert all(e["workloads"] == [CELL] and e["moves"] ==
+               "serve_tokens_per_s" and e["layer"] == "Kernels" for e in new)
+    # appended: every entry the benchmark had stands before the first of them
+    assert sorted(at[name] for name in NEW) == list(range(
+        len(at) - len(NEW), len(at)))
+    cell = harness.Cell(CELL)
+    assert [e["name"] for e in cell.end_to_end] == ["serve_tokens_per_s",
+                                                    "setup_s"]
+    shared = {e["name"] for e in cell.per_layer} - set(NEW)
+    assert shared == {
+        "sched_step_ms.serve", "decode_device_ms", "device_idle_pct.serve",
+        "prefill_device_ms_per_ktok", "tick_idle_ms.admit",
+        "tick_idle_ms.build_inputs", "tick_idle_ms.dispatch",
+        "tick_idle_ms.accept", "tick_idle_ms.commit_flush",
+        "tick_idle_ms.unspanned"}
+    # wherever the cell was appended it stands last, after GPT's cells
+    for e in m["end_to_end"] + m["per_layer"]:
+        if CELL in e.get("workloads", []):
+            assert e["workloads"][-1] == CELL
+
+
+def test_what_the_two_pinned_tests_check_besides():
+    """``conftest.py`` marks two tests of the benchmark's own as strict
+    expected failures, for ONE assertion each (``reduced == []``;
+    ``per_layer[-1]``). What else they check holds, and is checked here:
+    the configuration's entry and files as ``test_manifest.py::
+    test_config_entry_and_its_files`` has them, and PR 25's metric entry
+    word for word, the last of those the benchmark had."""
+    m = harness.load_json(REPO, "BENCHMARK.json")
+    config = m["configs"][-1]
+    assert set(config) == {"name", "source", "file", "reduced", "why"}
+    assert config["file"] == "benchmark/configs/olmo_hybrid_7b.json"
+    body = harness.load_json(REPO, config["file"])
+    assert body["source"] == config["source"] == (
+        "https://huggingface.co/allenai/Olmo-Hybrid-7B/blob/main/config.json")
+    assert body["reduced"] == config["reduced"]
+    for kind in ("runners/" + body["runner"], "reference/" + config["name"]):
+        assert os.path.exists(os.path.join(BENCH, kind + ".py")), kind
+    assert any(w["config"] == config["name"] for w in m["workloads"])
+
+    entry = m["per_layer"][-len(NEW) - 1]
+    assert entry == {
+        "name": "paged_attn_kernel_ms_per_decode", "unit": "ms",
+        "better": "lower", "source": "device_trace", "layer": "Kernels",
+        "moves": "serve_tokens_per_s",
+        "workloads": ["gpt2_medium.offline_decode",
+                      "gpt2_medium.prompt_backlog"]}
+
+
+def test_configuration_file_holds_the_published_keys_and_its_cut():
+    row = {"vocab_size": 100352, "hidden_size": 3840,
+           "intermediate_size": 11008, "num_attention_heads": 30,
+           "num_key_value_heads": 30, "max_position_embeddings": 65536,
+           "rms_norm_eps": 1e-06, "linear_num_key_heads": 30,
+           "linear_num_value_heads": 30, "linear_key_head_dim": 96,
+           "linear_value_head_dim": 192, "linear_conv_kernel_dim": 4}
+    config = harness.load_json(BENCH, "configs", "olmo_hybrid_7b.json")
+    assert {k: config[k] for k in row} == row
+    assert config["num_hidden_layers"] == 16 == len(config["layer_types"])
+    assert config["layer_types"] == (["linear_attention"] * 3
+                                     + ["full_attention"]) * 4
+    assert config["reduced"] == ["num_hidden_layers", "layer_types"]
+    assert config["rope_parameters"] == {"rope_theta": None}
+    mix = harness.load_json(BENCH, "traffic", "long_prompt_decode.json")
+    assert mix["arrivals"] == {"process": "backlog", "requests": 256}
+    assert mix["prompt_tokens"] == {"dist": "loguniform", "lo": 512,
+                                    "hi": 3072}
+    assert mix["max_new_tokens"] == {"dist": "uniform", "lo": 128, "hi": 512}
+    assert "shared_prefix" not in mix and mix["temperatures"] == [0.0, 0.8]
+    assert (mix["trace_start_s"], mix["trace_seconds"]) == (8.0, 6.0)
+    sz = sizes()
+    assert (sz["layers"], sz["linear_layers"], sz["full_layers"]) == (16, 12,
+                                                                      4)
+
+
+@pytest.mark.parametrize("rehearsal", [False, True])
+def test_every_seed_offers_the_same_sizes_in_the_same_order(rehearsal):
+    """The window never drains this backlog, so it is a sample of the ORDER:
+    the runner fixes the order of the sizes, and ``--seed`` gives contents."""
+    from benchmark import traffic
+    runner = harness.load_module("runners", "hybrid_serve", BENCH)
+    mix = harness.load_json(BENCH, "traffic", "long_prompt_decode.json")
+    if rehearsal:
+        mix = harness.rehearsal_view(mix)
+    made = [traffic.requests(mix, seed, 30.0, 1000, 4096)
+            for seed in (11, 2 ** 31 + 7)]
+    a, b = (runner.same_work_every_seed(m, mix) for m in made)
+    work = lambda rs: [(len(r.prompt), r.max_new_tokens, r.temperature)
+                       for r in rs]
+    assert work(a) == work(b) and work(made[0]) != work(made[1])
+    assert [r.temperature for r in a[:4]] == [0.0, 0.8, 0.0, 0.8]
+    # the generator's own multiset of sizes, and each seed's own contents
+    assert sorted(w[:2] for w in work(a)) == sorted(
+        w[:2] for w in work(made[0]))
+    assert sorted(r.prompt for r in a) == sorted(r.prompt for r in made[0])
+    assert {r.prompt for r in a}.isdisjoint(r.prompt for r in b)
+    assert len({r.seed for r in a}) > len(a) // 2
+    # no sorted order: the head of the list is a sample of the whole mix
+    head = [w[0] for w in work(a)[:len(a) // 4]]
+    assert min(head) < sum(w[0] for w in work(a)) / len(a) < max(head)
+
+
+# -- runs that have to come out not correct ------------------------------------
+
+def rehearse(capsys, *extra):
+    rc = bench_run.main(["--workload", CELL, "--seed", str(2 ** 31 + 7),
+                         "--seconds", "2", "--trace", "0", "--cpu-rehearsal",
+                         *extra])
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith("{")]
+    assert rc == 0 and lines[-1]["stage"] == "rehearsal_result"
+    return json.loads(lines[-1]["would_be"]), lines
+
+
+def bad_rows(lines, stage):
+    return {n["number"] for l in lines if l.get("stage") == stage
+            for n in l["numbers"] if not n["ok"]}
+
+
+def test_served_tokens_altered_where_they_are_staged_are_not_correct(capsys):
+    result, lines = rehearse(capsys, "--option", "break_tokens=1")
+    assert result["correct"] is False
+    assert "served_logit_gap_max" in bad_rows(lines, "correct")
+
+
+def test_the_bfloat16_state_control_is_refused_and_the_served_run_is_not(
+        capsys):
+    result, lines = rehearse(capsys, "--control", "1")
+    assert result["correct"] is True and result["failed"] == 0
+    assert bad_rows(lines, "correct") == set()
+    # the reference with its recurrent state in bfloat16, by the same limits
+    assert bad_rows(lines, "control") == {"served_logit_gap_max",
+                                          "served_logit_gap_mean"}
+    counts = [l for l in lines if l.get("stage") == "built"][0]
+    assert counts["state_bytes_per_slot"] == 4 * 3 * (2 * 16 * 32 + 3 * 128)

@@ -157,6 +157,48 @@ def test_paged_decode_attention(v5e, q_dtype, pool_dtype):
         ((56,), jnp.int32), ((), jnp.int32)) == 1
 
 
+def test_paged_decode_attention_at_a_row_of_3840_lanes(v5e):
+    """The hybrid configuration's full layers: 16 slots, 30 heads of 128 (a
+    page row of 3,840 lanes, float32 queries), 4 layers of 4,098 pages."""
+    from apex_tpu.transformer.functional.paged_attention import (
+        paged_decode_attention,
+    )
+
+    pool = ((4, 4098, 16, 3840), jnp.bfloat16)
+    new_row = ((16, 1, 3840), jnp.bfloat16)
+    assert compile_on(
+        v5e, functools.partial(paged_decode_attention, heads=30),
+        ((16, 1, 3840), jnp.float32), new_row, new_row, pool, pool,
+        ((16, 256), jnp.int32), ((16,), jnp.int32), ((), jnp.int32)) == 1
+
+
+def test_gated_delta_kernels(v5e):
+    """Both Gated DeltaNet kernels at the published widths (30 heads, d_k
+    96, d_v 192): the chunked form over the largest bucket, and the step
+    over the whole stacked state of 12 layers x 16 slots, aliased."""
+    from apex_tpu.transformer.functional import gated_delta as gd
+
+    f32 = jnp.float32
+    assert compile_on(
+        v5e, gd.gated_delta_chunked, ((30, 4096, 96), f32),
+        ((30, 4096, 96), f32), ((30, 4096, 192), f32), ((30, 4096), f32),
+        ((30, 4096), f32)) == 1
+    sharding = SingleDeviceSharding(v5e)
+    shapes = [((16, 30, 96), f32), ((16, 30, 96), f32), ((16, 30, 192), f32),
+              ((16, 30), f32), ((16, 30), f32), ((12, 16, 30, 96, 192), f32),
+              ((), jnp.int32), ((16,), jnp.bool_)]
+    compiled = jax.jit(gd.gated_delta_step, donate_argnums=5).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    ).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    # the state comes back in the buffer it came in: nothing its size is
+    # allocated beside it
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 12 * 16 * 30 * 96 * 192 * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 def test_flat_adam(v5e):
     from apex_tpu.optimizers import FusedAdam
 
