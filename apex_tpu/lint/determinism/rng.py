@@ -8,8 +8,14 @@ key the original slot would have used. The repo's idiom is
 
     key = jax.random.fold_in(jax.random.PRNGKey(req.seed), step)
 
-and batched variants that ``jnp.stack`` per-slot keys. Two statically
-detectable ways to break it:
+in two batched spellings: a ``jnp.stack`` of per-slot keys, and — what
+the scheduler does since the decode tick builds its keys in one program
+— the seed root read back to the host once per request,
+``np.asarray(jax.random.PRNGKey(req.seed))``, kept in a ``[slots, 2]``
+array and folded with the ``[slots]`` counters inside the sampler's
+program (``jax.vmap(jax.random.fold_in)(base, counts)``,
+``serving.sampling.stream_keys``). Two statically detectable ways to
+break it:
 
 **Raw PRNGKey on the tick path.** A ``PRNGKey(...)`` whose result is
 consumed directly (not folded, not an element of a batched key stack)
@@ -20,7 +26,9 @@ expression is ``fold_in`` (it is the seed root of a fold chain), or
 (b) it is an element of a list/tuple/comprehension that feeds a
 ``stack`` / ``concatenate`` / ``array`` / ``asarray`` call (the
 batched-slot idiom — the fold already happened upstream or the slot
-is inert/padding).
+is inert/padding), or (c) it is itself the operand of ``asarray`` /
+``array``: the seed root read back as plain words, which no sampler
+takes as a key — the batched fold consumes it.
 
 **Key reuse.** A local name bound to a ``fold_in`` / ``PRNGKey``
 result and then passed as an argument to two or more distinct calls:
@@ -68,8 +76,9 @@ def _random_split_names(tree: ast.Module) -> Set[str]:
 
 
 def _key_ok(call: ast.Call, parents: Dict[int, ast.AST]) -> bool:
-    """Is this PRNGKey(...) call blessed — under a fold_in, or an
-    element of a batched key stack?"""
+    """Is this PRNGKey(...) call blessed — under a fold_in, an element
+    of a batched key stack, or read back as the seed root of a batched
+    fold (``np.asarray(PRNGKey(seed))``)?"""
     node: ast.AST = call
     while id(node) in parents:
         parent = parents[id(node)]

@@ -1,10 +1,20 @@
 """Token sampling under explicit PRNG keys.
 
 Serving needs reproducible sampling: every stochastic draw threads an
-explicit ``jax.random`` key (the scheduler derives per-slot keys as
-``fold_in(PRNGKey(request.seed), step)``), so a replayed request stream
+explicit ``jax.random`` key, and token ``n`` of a request draws with
+``fold_in(PRNGKey(request.seed), n)``, so a replayed request stream
 regenerates byte-identical outputs — the determinism contract the
 training side already holds (see ``tests/L0/run_serving``).
+
+Where the keys are derived: INSIDE the sampler programs
+(:func:`stream_keys`). The scheduler takes ``PRNGKey(request.seed)``
+once, when the request gets its slot, and keeps its two words on the
+host; each tick it uploads one ``[num_slots, 2]`` array of those base
+keys and one array of counts, and ``sample_stream`` /
+``sample_stream_grid`` fold the count into the base for every slot in
+the program that samples. The schedule is the one above; only the
+number of device programs changed, which no longer grows with the
+slots.
 
 One fused entry point handles the whole batch: per-slot temperature
 (``<= 0`` selects greedy) so mixed greedy/sampled slots decode in one
@@ -86,6 +96,36 @@ def sample_token_grid(logits: jax.Array, keys: jax.Array,
                          keys.reshape(b * k1, 2),
                          jnp.repeat(temperature, k1), top_k, top_p)
     return toks.reshape(b, k1)
+
+
+def stream_keys(base: jax.Array, counts: jax.Array) -> jax.Array:
+    """The replay contract's key schedule over arrays: ``base`` (B, 2)
+    uint32 holds each slot's ``PRNGKey(request.seed)``, ``counts`` int32
+    is (B,) — token numbers — or (B, k1) — one per verify position.
+    Returns the (B, 2) or (B, k1, 2) keys ``fold_in(base[b],
+    counts[b, ...])``, word for word what the eager call gives."""
+    fold = jax.random.fold_in
+    if counts.ndim == 2:
+        fold = jax.vmap(fold, (None, 0))
+    return jax.vmap(fold)(base, counts)
+
+
+def sample_stream(logits: jax.Array, base: jax.Array, counts: jax.Array,
+                  temperature: jax.Array, top_k: int = 0,
+                  top_p: float = 0.0) -> jax.Array:
+    """:func:`sample_tokens` with slot b's key derived in the same
+    program: ``fold_in(base[b], counts[b])``."""
+    return sample_tokens(logits, stream_keys(base, counts), temperature,
+                         top_k, top_p)
+
+
+def sample_stream_grid(logits: jax.Array, base: jax.Array,
+                       counts: jax.Array, temperature: jax.Array,
+                       top_k: int = 0, top_p: float = 0.0) -> jax.Array:
+    """:func:`sample_token_grid` with position (b, j)'s key derived in
+    the same program: ``fold_in(base[b], counts[b, j])``."""
+    return sample_token_grid(logits, stream_keys(base, counts),
+                             temperature, top_k, top_p)
 
 
 def speculative_accept(tokens: jax.Array, drafts: jax.Array,

@@ -13,7 +13,11 @@ decode program plus one prefill program per touched bucket.
 Determinism: every sampled token draws from
 ``fold_in(PRNGKey(request.seed), n_generated)`` — replaying the same
 request stream regenerates identical outputs regardless of how requests
-interleave across slots.
+interleave across slots. The key is derived in the program that samples
+(``sampling.stream_keys``) from two host-built arrays over the slots:
+each request's base key, ``PRNGKey(request.seed)`` read once when it is
+admitted or resumed (``_base_key``), and the number of the token. A tick
+dispatches and uploads the same whatever the number of slots.
 
 Chunked prefill (``chunk_tokens=``, the Sarathi-Serve move): a
 monolithic prompt forward stalls every co-tenant decode for the whole
@@ -40,7 +44,8 @@ host-side n-gram drafter (``serving.draft``) for up to ``spec_k``
 candidate tokens per slot, then runs ONE verify step over the k+1
 candidate positions (``serving.decode``), samples every position with
 the key the plain stream would have used there
-(``fold_in(seed, n_generated + j)``), and commits the longest prefix
+(``fold_in(seed, n_generated + j)``, folded from the same base keys in
+the grid sampler's program), and commits the longest prefix
 where the samples reproduce the drafts, plus the first non-matching
 sample — 1..k+1 tokens per slot per tick. Because the keys are the
 plain stream's keys, the committed tokens are BIT-IDENTICAL to plain
@@ -160,7 +165,7 @@ from apex_tpu.serving.transfer import (
     make_insert_pages_fn, make_insert_pages_quant_fn,
 )
 from apex_tpu.serving.sampling import (
-    finite_rows, sample_token_grid, sample_tokens,
+    finite_rows, sample_stream, sample_stream_grid,
     tree_speculative_accept,
 )
 from apex_tpu.utils.profiler import span as profiler_span
@@ -238,6 +243,16 @@ def _pad_on_host(tokens: Sequence[int], buckets: Sequence[int]):
     return ids, (np.arange(ids.shape[1]) < n).astype(np.int32)
 
 
+def _base_key(seed: int) -> np.ndarray:
+    """The two uint32 words of ``PRNGKey(seed)``, on the host: the root
+    of a request's key schedule, which the sampler programs fold the
+    token number into (``sampling.stream_keys``). Taken from ``PRNGKey``
+    itself, which has its own rule for a seed outside int32, and once
+    per admission: one small program and one read-back, where the tick
+    used to run two programs per slot."""
+    return np.asarray(jax.random.PRNGKey(seed))
+
+
 @dataclasses.dataclass
 class _Slot:
     request_id: int
@@ -245,6 +260,7 @@ class _Slot:
     prompt_len: int
     generated: List[int]
     pos: int            # cache rows written (prompt + decode steps)
+    base_key: np.ndarray    # _base_key(request.seed)
     prefill: Optional[_PrefillProgress] = None
 
 
@@ -337,9 +353,9 @@ class DecodeEngine:
                     f"{self.cfg.vocab_size})")
 
     def _init_samplers(self) -> None:
-        self._sample = jax.jit(sample_tokens,
+        self._sample = jax.jit(sample_stream,
                                static_argnames=("top_k", "top_p"))
-        self._sample_grid = jax.jit(sample_token_grid,
+        self._sample_grid = jax.jit(sample_stream_grid,
                                     static_argnames=("top_k", "top_p"))
         self._finite = jax.jit(finite_rows)
 
@@ -430,9 +446,13 @@ class DecodeEngine:
         """What the ``exec`` span says beyond its kind (nothing here)."""
         return {}
 
-    def sample(self, logits, keys, temperature) -> jax.Array:
-        toks = self._sample(logits, keys, temperature, top_k=self.top_k,
-                            top_p=self.top_p)
+    def sample(self, logits, base, counts, temperature) -> jax.Array:
+        """One token per row of ``logits`` (B, V): row b draws with
+        ``fold_in(base[b], counts[b])``, derived inside the sampler
+        program (:func:`~apex_tpu.serving.sampling.stream_keys`) from
+        the request's base key and the number of the token."""
+        toks = self._sample(logits, base, counts, temperature,
+                            top_k=self.top_k, top_p=self.top_p)
         fired, payload = self.injector.draw("sample")
         if fired:
             # out-of-vocabulary id: negative, so it can never collide
@@ -559,12 +579,13 @@ class DecodeEngine:
             + jnp.asarray(counts, jnp.int32))
         trc.end("commit")
 
-    def sample_grid(self, logits, keys, temperature) -> jax.Array:
+    def sample_grid(self, logits, base, counts, temperature) -> jax.Array:
         """Sample every (slot, position) of a verify step's logits with
-        its own key; the ``sample`` fault site corrupts the victim
+        its own key, ``fold_in(base[b], counts[b, j])``, derived in the
+        same program; the ``sample`` fault site corrupts the victim
         slot's FIRST position (the one a plain tick would have drawn),
         so the scheduler's range gate quarantines before any commit."""
-        toks = self._sample_grid(logits, keys, temperature,
+        toks = self._sample_grid(logits, base, counts, temperature,
                                  top_k=self.top_k, top_p=self.top_p)
         fired, payload = self.injector.draw("sample")
         if fired:
@@ -1342,11 +1363,6 @@ class ContinuousBatchingScheduler:
         # can call step() directly, e.g. the Poisson scenario bench)
         self._stalled = 0
         self._watch_snap = None
-        # (B,) base keys × (B, k1) offsets -> (B, k1, 2) per-position
-        # sampling keys for verify ticks: position j of slot b folds in
-        # n_generated[b] + j — the plain stream's key for that token
-        self._fold_grid = jax.jit(jax.vmap(
-            jax.vmap(jax.random.fold_in, (None, 0)), (0, 0)))
         self._tree_accept = jax.jit(tree_speculative_accept)
         # adaptive controller state: per-slot EWMA of the measured
         # draft acceptance rate (reset to optimistic 1.0 at admission);
@@ -1469,9 +1485,38 @@ class ContinuousBatchingScheduler:
         self._queue.append((rid, request, []))
         return rid
 
-    def _slot_key(self, slot: _Slot) -> jax.Array:
-        return jax.random.fold_in(
-            jax.random.PRNGKey(slot.request.seed), len(slot.generated))
+    def _sampler_inputs(self):
+        """What a decode tick's programs read of the slots, one
+        host-built array each whatever the number of slots: the pending
+        token, the decoding flag, the temperature, the request's base
+        key and the number of the token to sample (``len(generated)``).
+        The sampler folds the last into the base key on the device
+        (``sampling.stream_keys``): token n of a request draws with
+        ``fold_in(PRNGKey(seed), n)``. A slot that is not decoding
+        reads zeros, and nothing reads its sample."""
+        n = len(self._slots)
+        last = np.zeros((n,), np.int32)
+        active = np.zeros((n,), bool)
+        temps = np.zeros((n,), np.float32)
+        base = np.zeros((n, 2), np.uint32)
+        counts = np.zeros((n,), np.int32)
+        for i, s in enumerate(self._slots):
+            if self._decoding(s):
+                last[i] = s.generated[-1]
+                active[i] = True
+                temps[i] = s.request.temperature
+                base[i] = s.base_key
+                counts[i] = len(s.generated)
+        return last, active, temps, base, counts
+
+    def _first_token(self, logits, base_key: np.ndarray,
+                     temperature: float) -> int:
+        """Sample a request's token 0 from its prefill logits (1, V)
+        with ``fold_in(PRNGKey(seed), 0)``: the tick's sampler at a
+        batch of one."""
+        return int(self.engine.sample(
+            logits, base_key[None, :], np.zeros((1,), np.int32),
+            np.asarray([temperature], np.float32))[0])
 
     # -- typed termination ------------------------------------------------
 
@@ -1801,6 +1846,7 @@ class ContinuousBatchingScheduler:
             self._prefill_ticks[rid] = \
                 self._prefill_ticks.get(rid, 0) + 1
             self._charge_work(len(tokens))
+            base_key = _base_key(req.seed)
             first_tok = None
             if not resume:
                 # the FIRST generated token comes from the prefill
@@ -1814,10 +1860,8 @@ class ContinuousBatchingScheduler:
                             "logits")):
                         continue
                     break
-                key = jax.random.fold_in(jax.random.PRNGKey(req.seed), 0)
-                first_tok = int(eng.sample(
-                    logits, key[None, :],
-                    jnp.asarray([req.temperature], jnp.float32))[0])
+                first_tok = self._first_token(logits, base_key,
+                                              req.temperature)
                 if not 0 <= first_tok < eng.cfg.vocab_size:
                     self.stats.bad_samples += 1
                     if self._fail_admission(i, rid, NonFiniteLogits(
@@ -1828,7 +1872,7 @@ class ContinuousBatchingScheduler:
                     break
             self._queue.popleft()
             slot = _Slot(rid, req, len(req.prompt), list(resume),
-                         len(tokens))
+                         len(tokens), base_key)
             trc = self.tracer
             if trc.enabled:
                 trc.instant("admitted", request_id=rid, slot=i,
@@ -1889,7 +1933,7 @@ class ContinuousBatchingScheduler:
                 break
             self._queue.popleft()
             slot = _Slot(rid, req, len(req.prompt), list(resume),
-                         len(tokens))
+                         len(tokens), _base_key(req.seed))
             slot.prefill = _PrefillProgress(
                 tokens=tokens, next=int(state.get("start", 0)),
                 state=state)
@@ -1936,11 +1980,8 @@ class ContinuousBatchingScheduler:
                 self._fail_prefill(i, NonFiniteLogits(
                     f"request {rid}: non-finite prefill logits"))
                 return
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(s.request.seed), 0)
-            first_tok = int(eng.sample(
-                logits, key[None, :],
-                jnp.asarray([s.request.temperature], jnp.float32))[0])
+            first_tok = self._first_token(logits, s.base_key,
+                                          s.request.temperature)
             if not 0 <= first_tok < eng.cfg.vocab_size:
                 self.stats.bad_samples += 1
                 self._fail_prefill(i, NonFiniteLogits(
@@ -2212,22 +2253,13 @@ class ContinuousBatchingScheduler:
             self._spec_tick(drafts, k1)
             return k1 * len(occupied)
         self.stats.plain_ticks += 1
-        trc.begin("build_inputs")
-        tokens = jnp.asarray(
-            [s.generated[-1] if self._decoding(s) else 0
-             for s in self._slots], jnp.int32)
-        active = jnp.asarray([self._decoding(s) for s in self._slots])
-        temps = jnp.asarray(
-            [s.request.temperature if self._decoding(s) else 0.0
-             for s in self._slots], jnp.float32)
-        keys = jnp.stack(
-            [self._slot_key(s) if self._decoding(s)
-             else jax.random.PRNGKey(0) for s in self._slots])
+        trc.begin("build_inputs", slots=len(occupied))
+        tokens, active, temps, base, counts = self._sampler_inputs()
         trc.end("build_inputs")
         logits = eng.decode(tokens, active)
         trc.begin("accept")
         finite = np.asarray(eng.finite(logits))
-        next_tokens = np.asarray(eng.sample(logits, keys, temps))
+        next_tokens = np.asarray(eng.sample(logits, base, counts, temps))
         trc.end("accept")
         trc.begin("commit")
         vocab = eng.cfg.vocab_size
@@ -2277,28 +2309,22 @@ class ContinuousBatchingScheduler:
         eng = self.engine
         trc = self.tracer
         self.stats.spec_ticks += 1
-        trc.begin("build_inputs")
-        rows = []
-        for i, s in enumerate(self._slots):
-            d = drafts[i][:k1 - 1]
-            rows.append(([s.generated[-1] if self._decoding(s) else 0]
-                         + d + [0] * (k1 - 1 - len(d))))
-        tokens = jnp.asarray(rows, jnp.int32)
-        temps = jnp.asarray(
-            [s.request.temperature if self._decoding(s) else 0.0
-             for s in self._slots], jnp.float32)
-        base = jnp.stack(
-            [jax.random.PRNGKey(s.request.seed) if self._decoding(s)
-             else jax.random.PRNGKey(0) for s in self._slots])
-        offs = jnp.asarray(
-            [[(len(s.generated) if self._decoding(s) else 0) + j
-              for j in range(k1)] for s in self._slots], jnp.int32)
-        keys = self._fold_grid(base, offs)
+        trc.begin("build_inputs",
+                  slots=sum(map(self._decoding, self._slots)))
+        last, _, temps, base, n_gen = self._sampler_inputs()
+        tokens = np.zeros((eng.num_slots, k1), np.int32)
+        tokens[:, 0] = last
+        for i, d in enumerate(drafts):
+            d = d[:k1 - 1]
+            tokens[i, 1:1 + len(d)] = d
+        # grid position j of a slot samples its (n_generated + j)-th
+        # token: the plain stream's key for it
+        offs = n_gen[:, None] + np.arange(k1, dtype=np.int32)
         trc.end("build_inputs")
         logits = eng.verify(tokens)
         trc.begin("accept")
         finite = np.asarray(eng.finite(logits))            # (B, k1)
-        grid = np.asarray(eng.sample_grid(logits, keys, temps))
+        grid = np.asarray(eng.sample_grid(logits, base, offs, temps))
         vocab = eng.cfg.vocab_size
         counts = [0] * eng.num_slots
         quarantined: List[Tuple[int, NonFiniteLogits]] = []
@@ -2423,7 +2449,8 @@ class ContinuousBatchingScheduler:
             forced.pop(i, None)
         if not forced:
             return 0
-        trc.begin("build_inputs")
+        trc.begin("build_inputs", slots=len(forced))
+        _, active, temps, base, n_gen = self._sampler_inputs()
         f_chain: List[List[int]] = []
         g_trees: List[Optional[Tuple[List[int], List[int]]]] = []
         for i, s in enumerate(self._slots):
@@ -2445,32 +2472,21 @@ class ContinuousBatchingScheduler:
             f_chain.append(chain)
         tok_np, dep_np, anc_np, val_np, par_np, start_np = tree_arrays(
             f_chain, g_trees, k1)
-        temps = jnp.asarray(
-            [s.request.temperature if self._decoding(s) else 0.0
-             for s in self._slots], jnp.float32)
-        base = jnp.stack(
-            [jax.random.PRNGKey(s.request.seed) if self._decoding(s)
-             else jax.random.PRNGKey(0) for s in self._slots])
         # column j samples the (n_generated - f + 1 + depth[j])-th
         # generated token — exactly the plain stream's key offset for
         # that position (forced columns before the walk root land on
         # already-committed offsets; their samples are never read)
-        offs = np.zeros((eng.num_slots, k1), np.int32)
-        for i, s in enumerate(self._slots):
-            if self._decoding(s):
-                offs[i] = (len(s.generated) - len(f_chain[i]) + 1
-                           + dep_np[i])
-        keys = self._fold_grid(base, jnp.asarray(offs))
-        tok_d, dep_d, anc_d = (jnp.asarray(tok_np), jnp.asarray(dep_np),
-                               jnp.asarray(anc_np))
+        chain_len = np.asarray([len(f) for f in f_chain], np.int32)
+        offs = np.where(active[:, None],
+                        (n_gen - chain_len + 1)[:, None] + dep_np,
+                        0).astype(np.int32)
         trc.end("build_inputs")
-        logits = eng.tree_verify(tok_d, dep_d, anc_d)
+        logits = eng.tree_verify(tok_np, dep_np, anc_np)
         trc.begin("accept")
         finite = np.asarray(eng.finite(logits))            # (B, k1)
-        grid = np.asarray(eng.sample_grid(logits, keys, temps))
-        cnts, path = self._tree_accept(
-            jnp.asarray(grid), jnp.asarray(tok_np), jnp.asarray(par_np),
-            jnp.asarray(val_np), jnp.asarray(start_np))
+        grid = np.asarray(eng.sample_grid(logits, base, offs, temps))
+        cnts, path = self._tree_accept(grid, tok_np, par_np, val_np,
+                                       start_np)
         cnts, path = np.asarray(cnts), np.asarray(path)
         vocab = eng.cfg.vocab_size
         counts = [0] * eng.num_slots          # cache ROWS to commit

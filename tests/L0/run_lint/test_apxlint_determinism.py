@@ -56,6 +56,24 @@ def test_apx805_rng_discipline():
     assert _codes(os.path.join("serving", "apx805_clean.py")) == []
 
 
+def test_apx805_batched_fold_is_clean(tmp_path):
+    """The seed root read back once per request and folded for all
+    slots in one program is the idiom, not a raw key; the same root
+    handed to a sampler unfolded still is one."""
+    name = os.path.join("serving", "apx805_batched_clean.py")
+    assert _codes(name) == []
+    src = open(os.path.join(FIXTURES, name)).read()
+    serving = tmp_path / "serving"
+    serving.mkdir()
+    bad = serving / "engine.py"
+    bad.write_text(src.replace(
+        "self.base[slot] = np.asarray(jax.random.PRNGKey(seed))",
+        "self.base[slot] = jax.random.PRNGKey(seed)"))
+    findings, _ = lint_paths([str(bad)], trace=False, determinism=True,
+                             include_fixtures=True)
+    assert [f.code for f in findings] == ["APX805"]
+
+
 def test_apx803_raise_closure():
     assert _codes(os.path.join("serving", "apx803_bad.py")) \
         == ["APX803"]

@@ -1,0 +1,214 @@
+"""The replay contract's key schedule, derived in one program.
+
+Token ``n`` of a request samples with ``fold_in(PRNGKey(seed), n)``. The
+scheduler used to run those two programs per slot per tick; now it takes
+``PRNGKey(seed)`` once per admission (``scheduler._base_key``) and the
+sampler programs fold the token number in for all slots at once
+(``sampling.stream_keys``). Held here:
+
+- the keys are the eager ones word for word, also for seeds outside int32,
+  where ``PRNGKey(python int)`` has its own rule for the high word;
+- the scheduler's streams are those of a loop that samples one request at a
+  time with eagerly derived keys (the contract as it was first written);
+- a steady decode tick of any kind derives no key eagerly and uploads the
+  same number of host arrays at 2 slots as at 8;
+- the ``build_inputs`` span says how many slots it built for.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu.models.gpt import gpt_tiny, init_gpt
+from apex_tpu.serving import (ContinuousBatchingScheduler, DecodeEngine,
+                              PagedDecodeEngine, Request, Tracer,
+                              sample_tokens, stream_keys)
+from apex_tpu.serving.draft_model import DraftModel
+from apex_tpu.serving.scheduler import _base_key
+
+EOS = -1
+MAX_LEN = 64
+SEEDS = (0, 1, 2 ** 31 - 2, 2 ** 31, 2 ** 32 - 1, 2 ** 40, 2 ** 40 + 5, -7)
+COUNTS = (0, 1, 255, 1023)
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = dataclasses.replace(gpt_tiny(), use_rope=True, hidden_dropout=0.0)
+    return cfg, init_gpt(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.fixture(scope="module")
+def derive():
+    return jax.jit(stream_keys)
+
+
+def _eager_key(seed, n):
+    return np.asarray(jax.random.fold_in(jax.random.PRNGKey(seed), n))
+
+
+@pytest.mark.parametrize("count", COUNTS)
+@pytest.mark.parametrize("seed", SEEDS)
+def test_stream_keys_equal_eager_fold_in(derive, seed, count):
+    """One row of the batch is this seed; its neighbours are other seeds, so
+    a row that read its neighbour's base would show."""
+    seeds = [seed, 3, seed + 1]
+    base = np.stack([_base_key(s) for s in seeds])
+    assert base.dtype == np.uint32 and base.shape == (3, 2)
+    counts = np.asarray([count, 5, count], np.int32)
+    keys = np.asarray(derive(base, counts))
+    assert keys.dtype == np.uint32
+    for row, (s, n) in enumerate(zip(seeds, counts)):
+        np.testing.assert_array_equal(keys[row], _eager_key(s, int(n)))
+    # the verify grids: position j folds count + j
+    offs = counts[:, None] + np.arange(3, dtype=np.int32)
+    grid = np.asarray(derive(base, offs))
+    assert grid.shape == (3, 3, 2)
+    for j in range(3):
+        np.testing.assert_array_equal(grid[0, j],
+                                      _eager_key(seed, count + j))
+
+
+def _engine(model, kind, num_slots, **kw):
+    cfg, params = model
+    if kind == "dense":
+        return DecodeEngine(params, cfg, num_slots=num_slots,
+                            max_len=MAX_LEN, **kw)
+    return PagedDecodeEngine(params, cfg, num_slots=num_slots,
+                             max_len=MAX_LEN, num_pages=4 * num_slots + 8,
+                             page_size=16, **kw)
+
+
+def _reference_stream(model, req):
+    """The contract as a loop: one request alone, its keys derived eagerly,
+    the sampler given the keys themselves."""
+    eng = _engine(model, "dense", 1)
+    temps = jnp.asarray([req.temperature], jnp.float32)
+    logits = eng.prefill(0, req.prompt)
+    out = []
+    for n in range(req.max_new_tokens):
+        key = jax.random.fold_in(jax.random.PRNGKey(req.seed), n)
+        out.append(int(sample_tokens(logits, key[None, :], temps)[0]))
+        logits = eng.decode(jnp.asarray([out[-1]], jnp.int32),
+                            jnp.asarray([True]))
+    return out
+
+
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_scheduler_streams_equal_eager_key_reference(model, kind):
+    reqs = [Request(prompt=(5 + i, 7, 11 + i), max_new_tokens=6,
+                    temperature=0.9, seed=s)
+            for i, s in enumerate(SEEDS)]
+    sched = ContinuousBatchingScheduler(_engine(model, kind, 3), eos_id=EOS)
+    for r in reqs:
+        sched.submit(r)
+    outs = sched.run()
+    for r, got in zip(reqs, outs):
+        assert got == _reference_stream(model, r), r.seed
+    # streams of different seeds differ: the sampler did read the keys
+    assert len({tuple(o) for o in outs}) > len(outs) // 2
+
+
+class _Counted:
+    """A jitted program of the engine, counting its calls and the host
+    arrays among its arguments: each is one upload."""
+
+    def __init__(self, fn, tally):
+        self.fn, self.tally = fn, tally
+
+    def __call__(self, *args, **kw):
+        self.tally["programs"] += 1
+        self.tally["uploads"] += sum(
+            isinstance(a, np.ndarray)
+            for a in jax.tree_util.tree_leaves((args, kw)))
+        return self.fn(*args, **kw)
+
+
+def _steady_tick_counts(model, monkeypatch, num_slots, mode):
+    """Programs dispatched, arrays uploaded and eager key derivations in
+    three decode ticks with every slot decoding and no admission."""
+    cfg, params = model
+    kw = {}
+    if mode != "plain":
+        kw["spec_k"] = 2
+    if mode == "tree":
+        kw.update(tree_spec=True, draft_model=DraftModel(
+            params, cfg, num_slots=num_slots, max_len=MAX_LEN))
+    eng = _engine(model, "paged", num_slots, **kw)
+    sched = ContinuousBatchingScheduler(eng, eos_id=EOS)
+    for i in range(num_slots):
+        # a repeating prompt, so that the n-gram drafter proposes
+        sched.submit(Request(prompt=(7, 11) * 4, max_new_tokens=40,
+                             temperature=0.0 if i % 2 else 0.8, seed=i))
+    for _ in range(3):      # admission, and every program compiled
+        sched.step()
+    assert all(sched._decoding(s) for s in sched._slots)
+    tally = {"programs": 0, "uploads": 0, "eager_keys": 0}
+    for name in ("_decode", "_verify", "_tree_verify", "_sample",
+                 "_sample_grid", "_finite"):
+        if getattr(eng, name, None) is not None:
+            monkeypatch.setattr(eng, name, _Counted(getattr(eng, name),
+                                                    tally))
+    monkeypatch.setattr(sched, "_tree_accept",
+                        _Counted(sched._tree_accept, tally))
+
+    def counting(real, key):
+        def call(*args, **kw):
+            # a call on tracers is a program being compiled (the tree
+            # grid's width follows the drafts), not a program run
+            if not any(isinstance(a, jax.core.Tracer) for a in args):
+                tally[key] += 1
+            return real(*args, **kw)
+        return call
+
+    for name in ("PRNGKey", "fold_in", "key"):
+        monkeypatch.setattr(jax.random, name,
+                            counting(getattr(jax.random, name),
+                                     "eager_keys"))
+    # every explicit upload the scheduler could make
+    for name in ("asarray", "array", "stack"):
+        monkeypatch.setattr(jnp, name,
+                            counting(getattr(jnp, name), "uploads"))
+    monkeypatch.setattr(jax, "device_put",
+                        counting(jax.device_put, "uploads"))
+    before = (sched.stats.plain_ticks, sched.stats.spec_ticks)
+    for _ in range(3):
+        sched.step()
+    monkeypatch.undo()
+    ticks = (sched.stats.plain_ticks - before[0],
+             sched.stats.spec_ticks - before[1])
+    assert all(sched._decoding(s) for s in sched._slots)
+    return tally, ticks
+
+
+@pytest.mark.parametrize("mode", ["plain", "spec", "tree"])
+def test_tick_programs_and_uploads_do_not_grow_with_slots(
+        model, monkeypatch, mode):
+    few, ticks_few = _steady_tick_counts(model, monkeypatch, 2, mode)
+    many, ticks_many = _steady_tick_counts(model, monkeypatch, 8, mode)
+    assert few["eager_keys"] == 0 and many["eager_keys"] == 0
+    assert ticks_few == ticks_many
+    assert few == many
+    if mode == "plain":
+        assert ticks_few == (3, 0)
+        # a tick: decode(tokens, active), finite, sample(base, counts, temps)
+        assert few == {"programs": 9, "uploads": 15, "eager_keys": 0}
+    else:
+        assert ticks_few[1] > 0
+
+
+def test_build_inputs_span_counts_decoding_slots(model):
+    trc = Tracer()
+    eng = _engine(model, "paged", 3, tracer=trc)
+    sched = ContinuousBatchingScheduler(eng, eos_id=EOS)
+    for i, n in enumerate((2, 5, 5)):
+        sched.submit(Request(prompt=(3, 5 + i), max_new_tokens=n))
+    sched.run()
+    slots = [dict(e.args)["slots"] for e in trc.events
+             if e.name == "build_inputs"]
+    # the first request ends after its second token: three slots decode in
+    # the first tick, two in the rest
+    assert slots == [3, 2, 2, 2]
