@@ -2,7 +2,7 @@
 
 The observe layer's names are a contract surface twice over: the
 deterministic replay tests compare ``tick_stream()`` tuples whose
-first element is the event NAME, and the bench/export layer reads
+first element is the event NAME, and the export layer reads
 metrics back by name (``registry.get`` / ``quantiles``). Both go
 quietly wrong when an emit site drifts from the declared vocabulary —
 a span opened under a name missing from ``PHASES`` still records, the

@@ -15,8 +15,8 @@ countable; ``*refs``-style kernels are skipped, never guessed at.
 
 **APX103** — flash attention keeps its online-softmax statistics
 (running max ``m``, normalizer ``l``, logsumexp ``lse``) and layer norm
-its ``mean``/``rstd`` in fp32 even when ``_P_BF16`` casts the
-probability tiles to bf16: the normalizer sums the fp32 tile *before*
+its ``mean``/``rstd`` in fp32 even though the probability tiles are
+cast to bf16: the normalizer sums the fp32 tile *before*
 the cast, and a half-precision ``l`` or ``lse`` corrupts every row that
 spans more than one k tile. The check flags (a) stores into a
 stats-named ref that round through ``astype(bf16/f16)``, (b) stats
@@ -217,5 +217,5 @@ def _check_stats_stores(tree: ast.Module, path: str,
                     "APX103", path, node.lineno,
                     f"store into stats ref '{name}' rounds through a "
                     "reduced-precision astype — m/l/lse/mean/rstd must "
-                    "stay fp32 (even under _P_BF16)"))
+                    "stay fp32 (only the probability tiles go to bf16)"))
     return findings

@@ -988,7 +988,7 @@ def _spec_verify_step_medium_ragged_entry():
     """The verify step at the ragged medium shape (32 slots, bf16
     params, uniform 32..512 ladder), k+1 = 4 positions per slot —
     cost-tier only. Its budgets.json row divided by the expected
-    committed tokens per slot at the bench acceptance rate is the
+    committed tokens per slot at the measured acceptance rate is the
     bytes per accepted token, to set against the plain-decode
     ``model_bytes_per_token``."""
     def build():

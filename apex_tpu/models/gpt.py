@@ -1521,71 +1521,3 @@ def gpt_pipeline_model(model: GPTModel) -> "PipelineModel":
         return vocab_parallel_cross_entropy(logits, mb["labels"]).mean()
 
     return PipelineModel(embed_fn, stage_fn, loss_fn)
-
-
-# ---------------------------------------------------------------------------
-# bench hook (BASELINE config #5)
-# ---------------------------------------------------------------------------
-
-def gpt_tp_bench(on_tpu: bool, n_devices: int, *,
-                 batch: Optional[int] = None, remat: bool = False
-                 ) -> Tuple[Any, Any, Any, int]:
-    """Returns (body, make_init, fetch, global_batch) for bench.py:
-    a full TP train step (loss, grads inside shard_map; FusedAdam update)
-    on a tp=n mesh. ``make_init`` is a zero-arg factory building the
-    (params, opt_state) train state on device, so bench.py's donating
-    timer keeps exactly ONE copy in HBM. ``batch``/``remat`` let
-    bench.py sweep configs the way the BERT headline does."""
-    import dataclasses
-
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from apex_tpu.optimizers import FusedAdam
-
-    cfg = gpt_medium() if on_tpu else gpt_tiny()
-    # gpt_medium() defaults remat=True — OVERRIDE both ways, or every
-    # "remat=False" bench config silently pays the fwd recompute. A
-    # string names a jax.checkpoint policy (selective recompute).
-    if isinstance(remat, str):
-        cfg = dataclasses.replace(cfg, remat=True, remat_policy=remat)
-    else:
-        cfg = dataclasses.replace(cfg, remat=bool(remat))
-    default_b, seq = (8, 1024) if on_tpu else (2, 32)
-    batch = default_b if batch is None else batch
-    ps.destroy_model_parallel()
-    mesh = ps.initialize_model_parallel(
-        tensor_model_parallel_size_=n_devices)
-    model = GPTModel(cfg, tp_size=n_devices)
-    opt = FusedAdam(lr=1e-4, weight_decay=0.01)
-    specs = model.partition_specs()
-    shard = lambda tree, sp: jax.tree.map(  # noqa: E731
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, sp)
-
-    def make_init():
-        # opt.init's zeros_like inherits the params' NamedSharding, so
-        # m/v come out sharded without a second device_put pass
-        params = shard(model.init(jax.random.PRNGKey(0)), specs)
-        return params, opt.init(params)
-
-    ids = jnp.zeros((batch, seq), jnp.int32)
-    labels = jnp.zeros((batch, seq), jnp.int32)
-
-    # bf16 compute over fp32 params (O2-style: optimizer math fp32)
-    loss_grad = ps.shard_map(
-        jax.value_and_grad(
-            lambda p, i, t: model.loss(p, i, t,
-                                       compute_dtype=jnp.bfloat16),
-            argnums=0), mesh=mesh,
-        in_specs=(specs, P(), P()),
-        out_specs=(P(), specs))
-
-    def body(state):
-        p, o = state
-        loss, grads = loss_grad(p, ids, labels)
-        p, o = opt.step(grads, p, o)
-        return (p, o)
-
-    def fetch(state):
-        return jnp.sum(state[0]["final_ln"]["weight"])
-
-    return body, make_init, fetch, batch

@@ -11,7 +11,6 @@ gates, and the budgets workflow.
 """
 
 from apex_tpu.quant.kernels import (
-    kernel_variant,
     kv_dequantize,
     kv_quantize,
     w8_matmul,
@@ -28,7 +27,6 @@ from apex_tpu.quant.params import (
 __all__ = [
     "dequantize_tensor",
     "is_quantized_tree",
-    "kernel_variant",
     "kv_dequantize",
     "kv_quantize",
     "quant_partition_specs",

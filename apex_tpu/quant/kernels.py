@@ -28,13 +28,8 @@ Two weight layouts, one contract:
 
 The grid runs over N tiles only (whole-M, whole-K blocks): decode M is
 the slot count and K the hidden size, both comfortably VMEM-resident,
-while N (ffn width, vocab) is what scales. ``kernel_variant(...)``
-(same machinery as the flash-attention toggles) flips ``w8_fused`` to
-the plain-jnp reference for same-process A/B pricing and parity tests.
+while N (ffn width, vocab) is what scales.
 """
-
-import contextlib
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -42,37 +37,11 @@ from jax.experimental import pallas as pl
 
 from apex_tpu.utils.platform import pallas_interpret
 
-# Trace-time toggle (the flash_attention kernel_variant contract): True
-# runs the Pallas dequant-fused kernel, False the jnp reference — the
-# cost tier charges the same int8 invar bytes either way (reads are
-# priced at the jit boundary), so the budgets.json byte claims survive
-# the toggle; only the fusion (no dequantized HBM round-trip) differs.
-_W8_FUSED = True
-
 # N-tile candidates, largest first. 384 = 3 x 128 keeps the lane dim a
 # multiple of the int8 min tile (32, 128) and divides the GPT-2 padded
 # vocab (50304 = 131 x 384); a non-dividing N falls back to one whole
 # tile (tiny configs — their widths are VMEM-trivial).
 _BLOCK_N = (512, 384, 256, 128)
-
-
-@contextlib.contextmanager
-def kernel_variant(**toggles):
-    """Temporarily override module toggles (``w8_fused``). Trace-time
-    only — jit inside the context; already-compiled programs are
-    unaffected. Same contract as
-    :func:`apex_tpu.transformer.functional.flash_attention.kernel_variant`."""
-    mapping = {k: f"_{k.upper()}" for k in toggles}
-    saved = {}
-    for k, attr in mapping.items():
-        if attr not in globals():
-            raise ValueError(f"unknown kernel_variant toggle {k!r}")
-        saved[attr] = globals()[attr]
-        globals()[attr] = toggles[k]
-    try:
-        yield
-    finally:
-        globals().update(saved)
 
 
 def _block_n(n: int) -> int:
@@ -113,23 +82,6 @@ def _w8_matmul_nk_kernel(x_ref, wq_ref, scale_ref, out_ref):
         preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
-def _w8_ref(x2, wq, scale, bias, out_dtype, nk):
-    """jnp reference path (``w8_fused=False``): same fp32 dequant +
-    fp32 accumulator, no fusion — the A/B baseline and the CPU-cheap
-    variant for golden tests."""
-    w = wq.astype(jnp.float32) * (scale[:, None] if nk else scale[None, :])
-    if nk:
-        y = jax.lax.dot_general(x2.astype(jnp.float32), w,
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    else:
-        y = jnp.dot(x2.astype(jnp.float32), w,
-                    preferred_element_type=jnp.float32)
-    if bias is not None:
-        y = y + bias.astype(jnp.float32)
-    return y.astype(out_dtype)
-
-
 def _check_operands(x, wq, scale, k, n):
     if wq.dtype != jnp.int8:
         raise ValueError(f"wq must be int8, got {wq.dtype}")
@@ -155,9 +107,6 @@ def w8_matmul(x, wq, scale, bias=None, out_dtype=None, interpret=None):
     for d in lead:
         m *= d
     x2 = x.reshape(m, k)
-    if not _W8_FUSED:
-        return _w8_ref(x2, wq, scale, bias, out_dtype, False).reshape(
-            lead + (n,))
     bn = _block_n(n)
     scale2 = scale.reshape(1, n)
     if bias is None:
@@ -207,9 +156,6 @@ def w8_matmul_nk(x, wq, scale, out_dtype=jnp.float32, interpret=None):
     for d in lead:
         m *= d
     x2 = x.reshape(m, k)
-    if not _W8_FUSED:
-        return _w8_ref(x2, wq, scale, None, out_dtype, True).reshape(
-            lead + (n,))
     bn = _block_n(n)
     with jax.named_scope("apex_w8_matmul_nk"):
         out = pl.pallas_call(

@@ -15,7 +15,7 @@ the suffix never recurred); the scheduler pads the verify bucket and
 bounds acceptance by the true draft length.
 
 ``tree_arrays`` is the grid packer shared by the tree-speculation
-paths (scheduler, bench, tests): it lowers per-slot draft trees —
+paths (scheduler, tests): it lowers per-slot draft trees —
 ``(tokens, parents)`` lists, parent ``-1`` = child of the walk root —
 plus each slot's FORCED token chain (committed tokens whose cache rows
 must be re-sent; at least the pending token) into the padded

@@ -470,8 +470,7 @@ STAT_FIELDS = {
 class ServingStats:
     """Degradation counters, shared by an engine and its scheduler.
     Pure host-side ints (never read these inside a traced function —
-    APX401). ``bench.py gpt_decode`` emits the non-zero subset so the
-    driver tracks degradation behavior across rounds.
+    APX401).
 
     Since the observability PR this is a *view* over a
     :class:`~apex_tpu.serving.observe.MetricsRegistry`: every field in
@@ -578,7 +577,7 @@ class RequestOutcome:
 
 def snapshot(obj: Any) -> Dict:
     """Best-effort plain-dict view of a stats/outcome object for error
-    payloads and bench ``extra`` blocks."""
+    payloads and reports."""
     if hasattr(obj, "as_dict"):
         return obj.as_dict()
     if dataclasses.is_dataclass(obj):

@@ -251,7 +251,7 @@ class MetricsRegistry:
                   labels: Optional[Dict[str, Any]] = None,
                   ) -> Optional[Dict[str, float]]:
         """``{"p50": ..., "p95": ..., "p99": ...}`` for a histogram, or
-        ``None`` if absent/empty — the bench ``extra`` helper."""
+        ``None`` if absent/empty."""
         h = self.get(name, labels)
         if h is None or not isinstance(h, Histogram) or not h.count:
             return None
@@ -553,8 +553,8 @@ class Tracer:
 
     def tenant_latency_summary(self, tenant: str) -> Dict[str, float]:
         """Per-tenant ``{ttft_p50: ..., itl_p99: ...}`` quantile dict —
-        the bench helper behind the noisy-neighbor contract; silently
-        omits empty histograms."""
+        the reading behind the noisy-neighbor contract; silently omits
+        empty histograms."""
         out: Dict[str, float] = {}
         for short, name in (("ttft", "serving_tenant_ttft_ticks"),
                             ("itl", "serving_tenant_itl_ticks")):
@@ -629,7 +629,7 @@ class Tracer:
 
     def latency_summary(self) -> Dict[str, float]:
         """``{ttft_p50: ..., itl_p99: ...}`` — flat quantile dict for
-        bench ``extra`` blocks; silently omits empty histograms."""
+        reports; silently omits empty histograms."""
         out: Dict[str, float] = {}
         for short, name in (("ttft", "serving_ttft_ticks"),
                             ("itl", "serving_itl_ticks")):

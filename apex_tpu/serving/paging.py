@@ -641,7 +641,7 @@ class PagePool:
         return True
 
     def stats(self) -> Dict:
-        """Per-tier breakdown for gauges and bench ``extra`` blocks:
+        """Per-tier breakdown for gauges and reports:
         the HBM side (usable/free/cached/used pages, occupancy) plus,
         when a host tier is attached, its ``host_*``-prefixed stats
         (:meth:`PrefixRegistry.stats`)."""

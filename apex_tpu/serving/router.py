@@ -53,9 +53,9 @@ depth to the work-charged tick clock the way colocated admission does
 — it charges the deterministic handoff cost instead
 (``handoff_ticks_per_page`` per shipped page, plus one backoff tick
 per retry attempt, observed in the ``serving_transfer_ticks``
-histogram). That unblocked-decode gap is exactly the p99 ITL win the
-``serving_disagg_vs_colocated`` A/B pair measures; sampling keys never
-see the clock, so streams are unaffected.
+histogram). That unblocked-decode gap is a p99 ITL win on the tick clock (not
+measured on the chip: no benchmark cell runs a router); sampling keys
+never see the clock, so streams are unaffected.
 
 Scope: both replicas must be PAGED engines with identical model
 config/geometry and SHARED injector+tracer (one deterministic fault
@@ -82,8 +82,8 @@ host-staged channel on
 uses a link-overlap model: handoffs routed to distinct prefill
 replicas within one pass are charged the busy-horizon increase, not
 the sum — with one prefill replica this reduces exactly to the pair's
-serial charge, and with several it is the goodput win the
-``serving_pool_scaling`` bench measures. The validation contract
+serial charge, and with several it is a goodput win on the tick clock (not measured
+on the chip). The validation contract
 (``_validate_replicas``) applies pairwise across ALL N+M replicas,
 and the shared-``PrefixRegistry``-or-none rule is pool-wide.
 

@@ -1360,7 +1360,7 @@ class ContinuousBatchingScheduler:
         self._tick_no = 0
         self._tokens_emitted = 0
         # progress-watchdog state (instance-held so external drivers
-        # can call step() directly, e.g. the Poisson scenario bench)
+        # can call step() directly, as benchmark/runners/gpt_serve.py does)
         self._stalled = 0
         self._watch_snap = None
         self._tree_accept = jax.jit(tree_speculative_accept)
@@ -2593,8 +2593,8 @@ class ContinuousBatchingScheduler:
     def step(self) -> None:
         """One scheduler tick: expire deadlines, admit, decode (and,
         when chunked prefill is on, run prompt chunks with the budget
-        the decode phase left). Public so external load generators —
-        the Poisson scenario bench — can interleave ``submit`` calls
+        the decode phase left). Public so external load generators
+        (``benchmark/runners``) can interleave ``submit`` calls
         with ticks; :meth:`run` is just the drain loop over this. The
         progress watchdog spans steps: a chunk forward counts as
         progress (a long prompt prefilling is converging), so its

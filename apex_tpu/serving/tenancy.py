@@ -32,8 +32,8 @@ a :class:`TenancyPolicy` the scheduler consults at three points —
 
 The policy reorders WHEN work happens, never WHAT commits: sampling
 keys depend only on ``(seed, n_generated)``, so committed streams are
-integer-identical to the untenanted scheduler — the invariant the
-``serving_tenancy_vs_untenanted`` A/B bench asserts.
+integer-identical to the untenanted scheduler — the invariant
+``tests/L0/run_serving/test_tenancy.py`` asserts.
 
 Host state (APX401): vtimes, ledgers and reservation maps — never
 read them inside a traced function.

@@ -29,7 +29,7 @@ VPU diet (the d=64 lever — BERT-Large's own head shape ran at 18% of
 peak while d=128 hit 38% at identical FLOPs, so the cost is per score
 ELEMENT, not MXU occupancy):
 
-- **base-2 online softmax** (``_EXP2``): ``log2(e)`` is folded into the
+- **base-2 online softmax**: ``log2(e)`` is folded into the
   q prescale that already exists, so every ``exp`` in the three kernels
   becomes the cheaper ``exp2`` (the hardware primitive ``exp`` lowers
   to — one fewer VPU multiply per score element per exponential) and
@@ -39,20 +39,23 @@ ELEMENT, not MXU occupancy):
   ONE ln(2) multiply on the final dk tile (see ``_bwd_call`` — dk is
   ``ds^T @ (scale*log2e*q)``, i.e. log2e too big, and the fixup is
   d-sized, not s²-sized).
-- **bf16 probability tiles** (``_P_BF16``): p / ds are consumed only by
-  MXU ``dot_general``s, so they are cast to bf16 immediately after the
-  fp32 (m, l) statistics are updated, and the dropout keep/scale ops run
-  on the bf16 tile. m, l, lse, acc stay fp32. With the toggle off the
-  tiles stay fp32 and the other operand is upcast — the measurement
-  variant ``bench.py ab flash_d64_p32`` uses to price the bf16 path.
-  fp32 inputs always keep fp32 tiles (golden-test tolerances are tight).
+- **probability tiles in the operands' dtype**: p / ds are consumed only
+  by MXU ``dot_general``s, so with bf16 operands they are cast to bf16
+  immediately after the fp32 (m, l) statistics are updated, and the
+  dropout keep/scale ops run on the bf16 tile. m, l, lse, acc stay fp32.
+  fp32 inputs keep fp32 tiles (golden-test tolerances are tight).
+
+Both, and the choices below them (no causal tile-skipping, tiles of up
+to 512 at every head dim, ``dimension_semantics`` always given), were
+settled by same-process A/B runs on a v5e whose records were deleted
+(PR 21); no record of those measurements remains, and no benchmark cell
+runs the other side of any of them, so the kernels are built one way.
 
 Dropout masks are position-hashed (``_hash_keep``) and therefore
-bit-identical between forward and backward and across every variant
-toggle — the toggles change arithmetic cost, never randomness.
+bit-identical between forward and backward, and independent of the
+tile size.
 """
 
-import contextlib
 import functools
 from typing import Optional
 
@@ -69,104 +72,28 @@ from apex_tpu.utils.pallas import (
 )
 from apex_tpu.utils.platform import pallas_interpret
 
-def _block(s_padded: int, max_block: int = 512) -> int:
-    """Largest of 512/256/128 (capped at ``max_block``) that divides the
-    padded length — bigger blocks amortize grid overhead and feed the MXU
-    larger matmuls. Causal kernels cap lower: the tile-skipping win grows
-    as the diagonal gets thinner relative to the tile (at seq 2048,
-    512-tiles keep 10/16 of the work, 256-tiles only 36/64)."""
+
+def _block(s_padded: int) -> int:
+    """Largest of 512/256/128 that divides the padded length — bigger
+    blocks amortize grid overhead and feed the MXU larger matmuls.
+    Causal tiles above the diagonal are NOT skipped: gating whole tiles
+    behind ``pl.when`` cost more than the skipped matmuls saved (the
+    kernels are VPU-bound, and per-tile control flow defeats Mosaic's
+    copy/compute overlap); the win that landed is the mask-free
+    interior-tile path (``_needs_mask``)."""
     for cand in (512, 256, 128):
-        if cand <= max_block and s_padded % cand == 0:
+        if s_padded % cand == 0:
             return cand
     return 128
 
-
-def _causal_live(qt, kt, bq, bk):
-    """True iff tile (qt, kt) contains any unmasked position under the
-    causal mask: its smallest k position <= its largest q position."""
-    return kt * bk <= (qt + 1) * bq - 1
-
-
-# Causal tile-skipping toggles. Measured on v5e (seq 2048, d 64, fwd+bwd,
-# several same-process A/B sweeps): gating whole tiles behind pl.when
-# costs MORE than the
-# skipped matmuls save (the kernels are VPU-bound, and the per-tile
-# control flow defeats Mosaic's copy/compute overlap), and index-map
-# clamping adds further cost. The win that did land is the mask-free
-# interior-tile path (_needs_mask). Defaults reflect the measurements;
-# the toggles remain for re-tuning on other TPU generations.
-_CAUSAL_MAX_BLOCK = 512
-_CAUSAL_SKIP = False
-_CAUSAL_CLAMP = False
-_DIM_SEMANTICS = True
-
-# VPU-diet toggles (see module docstring). Same contract as the causal
-# toggles above: module-level so `bench.py ab` can trace a legacy-variant
-# callable against the default one IN THE SAME PROCESS, where both sides
-# share whatever drifts between processes. Flip via
-# `kernel_variant(...)`; the toggles are read at TRACE time, so a
-# callable must be traced (first call / warmup) inside the context.
-_EXP2 = True    # base-2 online softmax, log2e folded into the q prescale
-_P_BF16 = True  # bf16 p/ds tiles into the MXU (bf16 operands only)
-
-# Block cap for small head dims. The exp2/bf16-p diet shifts the VPU:MXU
-# ratio at d<128 (the matmuls stay narrow while the per-score VPU cost
-# drops), so the measured-best 512 tile of the pre-exp2 kernels may no
-# longer be optimal — `bench.py ab flash_d64_block256` re-tunes this
-# without a code edit. 512 (= no change) until the driver's A/B says
-# otherwise; _SMALL_D gates which head dims the cap applies to.
-_SMALL_D_MAX_BLOCK = 512
-_SMALL_D = 128
 
 _LOG2E = 1.4426950408889634  # log2(e): folded into the q prescale
 _LN2 = 0.6931471805599453    # 1/log2(e): the one dk fixup multiply
 
 
-@contextlib.contextmanager
-def kernel_variant(**toggles):
-    """Temporarily override module toggles (``exp2``, ``p_bf16``,
-    ``small_d_max_block``, ``causal_skip``, ...). Trace-time only: jit a
-    callable INSIDE the context (fwd and bwd together — e.g. warm a
-    ``jax.grad`` under jit) and the variant is baked into the compiled
-    program; already-compiled programs are unaffected. Used by the
-    same-process A/B harness (``bench.py ab``) and the kernel-parity
-    pinning checks."""
-    mapping = {k: f"_{k.upper()}" for k in toggles}
-    saved = {}
-    for k, attr in mapping.items():
-        if attr not in globals():
-            raise ValueError(f"unknown kernel_variant toggle {k!r}")
-        saved[attr] = globals()[attr]
-        globals()[attr] = toggles[k]
-    try:
-        yield
-    finally:
-        globals().update(saved)
-
-
-def _exp(x):
-    return jnp.exp2(x) if _EXP2 else jnp.exp(x)
-
-
-def _log(x):
-    return jnp.log2(x) if _EXP2 else jnp.log(x)
-
-
-def _mxu_dtype(operand_dtype):
-    """dtype the probability/ds tiles take into an MXU dot against an
-    operand of ``operand_dtype``. bf16 operands: bf16 (default) or fp32
-    (the ``_P_BF16=False`` measurement variant, which upcasts the
-    operand instead). fp32 operands always fp32 — golden-test parity."""
-    if operand_dtype == jnp.bfloat16 and not _P_BF16:
-        return jnp.dtype(jnp.float32)
-    return jnp.dtype(operand_dtype)
-
-
 def _cparams():
     """(batch*heads, outer, inner-reduction) -> the first two grid dims
     are parallel, the innermost accumulates into scratch."""
-    if not _DIM_SEMANTICS:
-        return None
     return _dimsem("parallel", "parallel", "arbitrary")
 
 
@@ -225,7 +152,7 @@ def _needs_mask(causal, pad, qt, kt, bq, bk, nk):
     crossing the causal diagonal and (under k-padding) the last k tile do;
     interior tiles take a mask-free path with roughly half the VPU work —
     which is the bound that matters (measured on v5e: causal tile-skipping
-    alone moved the seq-2048 fwd+bwd bench <5%, because the kernels are
+    alone moved a seq-2048 fwd+bwd timing <5%, because the kernels are
     VPU-bound on mask construction + softmax, not MXU-bound)."""
     needs = None
     if causal:
@@ -249,18 +176,11 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Causal tile-skipping: tiles entirely above the diagonal contribute
-    # nothing — gate ALL their compute (the index maps also clamp their
-    # k/v fetches to an already-resident block, so a skipped tile costs
-    # one grid tick and nothing else).
-    run = _causal_live(qt, kt, bq, bk) if (causal and _CAUSAL_SKIP) \
-        else True
-
     def tile(masked):
         def go():
-            # q arrives PRE-SCALED by softmax_scale (*log2e under _EXP2)
-            # — folded outside the kernel, so no per-score-element scale
-            # op; scores are base-2 logits and every exp below is exp2
+            # q arrives PRE-SCALED by softmax_scale * log2e — folded
+            # outside the kernel, so no per-score-element scale op;
+            # scores are base-2 logits and every exp below is exp2
             q, k, v = q_ref[0], k_ref[0], v_ref[0]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
@@ -271,8 +191,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
                 s = jnp.where(valid, s, _NEG)
             m_prev = m_ref[:, 0:1]
             m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = _exp(m_prev - m_cur)
-            p = _exp(s - m_cur)
+            alpha = jnp.exp2(m_prev - m_cur)
+            p = jnp.exp2(s - m_cur)
             if masked:
                 p = jnp.where(valid, p, 0.0)
             # (m, l) statistics stay fp32: l sums the fp32 tile BEFORE
@@ -281,30 +201,28 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
                                                             keepdims=True)
             m_ref[:, 0:1] = m_cur
             # p is consumed only by the PV matmul from here on — cast to
-            # the MXU dtype now so the dropout keep/scale ops below run
-            # on the narrow tile too (precision loss bounded by the fp32
+            # v's dtype now so the dropout keep/scale ops below run on
+            # the narrow tile too (precision loss bounded by the fp32
             # matmul accumulate)
-            p = p.astype(_mxu_dtype(v.dtype))
+            p = p.astype(v.dtype)
             if rate > 0.0:
                 keep = _keep_mask(seed_ref, i, qt * bq, kt * bk,
                                   p.shape, rate)
                 p = jnp.where(keep, p * p.dtype.type(1.0 / (1.0 - rate)),
                               p.dtype.type(0.0))
             acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-                p, v.astype(p.dtype), (((1,), (0,)), ((), ())),
+                p, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         return go
 
-    @pl.when(run)
-    def _():
-        if has_mask:
-            tile(True)()
+    if has_mask:
+        tile(True)()
+    else:
+        needs = _needs_mask(causal, pad, qt, kt, bq, bk, nk)
+        if needs is None:
+            tile(False)()
         else:
-            needs = _needs_mask(causal, pad, qt, kt, bq, bk, nk)
-            if needs is None:
-                tile(False)()
-            else:
-                jax.lax.cond(needs, tile(True), tile(False))
+            jax.lax.cond(needs, tile(True), tile(False))
 
     @pl.when(kt == nk - 1)
     def _():
@@ -318,11 +236,11 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
         # shared full-row block (a (1,1,sq_p) block indexed (i,0,0) is
         # revisited across qt; on v4/v5p each TensorCore's private copy
         # would lose the other core's rows on write-back).
-        # Under _EXP2 the stored value is the BASE-2 logsumexp
-        # (m2 + log2 l); the backward kernels consume it as-is — no
-        # base conversion ever happens on an s²-sized tile.
+        # The stored value is the BASE-2 logsumexp (m2 + log2 l); the
+        # backward kernels consume it as-is — no base conversion ever
+        # happens on an s²-sized tile.
         lse_ref[0, 0, :] = jnp.where(
-            l[:, 0] > 0, m_ref[:, 0] + _log(l[:, 0]), jnp.inf)
+            l[:, 0] > 0, m_ref[:, 0] + jnp.log2(l[:, 0]), jnp.inf)
 
 
 # -- backward: dq -----------------------------------------------------------
@@ -338,24 +256,21 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, do_ref,
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = _causal_live(qt, kt, bq, bk) if (causal and _CAUSAL_SKIP) \
-        else True
-
     def tile(masked):
         def go():
             q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
             lse_row = lse_ref[0, 0, pl.ds(qt * bq, bq)]
             delta_row = delta_ref[0, 0, pl.ds(qt * bq, bq)]
             # q pre-scaled; the kernel emits d(q*scale) and the caller
-            # multiplies the final dq by softmax_scale once. Under _EXP2
-            # s and lse_row are both base-2, so exp2(s - lse2) is the
+            # multiplies the final dq by softmax_scale once. s and
+            # lse_row are both base-2, so exp2(s - lse2) is the
             # base-e probability and ds needs NO base fixup here (dL/ds
             # is taken w.r.t. the base-e logit, whose gradient path the
             # caller's single scale multiply completes).
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            p = _exp(s - lse_row[:, None])
+            p = jnp.exp2(s - lse_row[:, None])
             if masked:
                 valid = _score_mask(
                     s, qt, kt, mask_ref[0, 0, :] if has_mask else None,
@@ -368,22 +283,19 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, do_ref,
                                   p.shape, rate)
                 dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
             ds = p * (dp - delta_row[:, None])
-            dsd = _mxu_dtype(k.dtype)
             dq_acc[:] += jax.lax.dot_general(
-                ds.astype(dsd), k.astype(dsd), (((1,), (0,)), ((), ())),
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         return go
 
-    @pl.when(run)
-    def _():
-        if has_mask:
-            tile(True)()
+    if has_mask:
+        tile(True)()
+    else:
+        needs = _needs_mask(causal, pad, qt, kt, bq, bk, nk)
+        if needs is None:
+            tile(False)()
         else:
-            needs = _needs_mask(causal, pad, qt, kt, bq, bk, nk)
-            if needs is None:
-                tile(False)()
-            else:
-                jax.lax.cond(needs, tile(True), tile(False))
+            jax.lax.cond(needs, tile(True), tile(False))
 
     @pl.when(kt == nk - 1)
     def _():
@@ -404,29 +316,26 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, do_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = _causal_live(qt, kt, bq, bk) if (causal and _CAUSAL_SKIP) \
-        else True
-
     def tile(masked):
         def go():
             q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
             lse_row = lse_ref[0, 0, pl.ds(qt * bq, bq)]
             delta_row = delta_ref[0, 0, pl.ds(qt * bq, bq)]
-            # q pre-scaled: dk = ds^T @ (scale*q); under _EXP2 the
-            # prescale carries an extra log2e, so the caller multiplies
-            # the FINAL dk tile by ln2 once (d-sized, not s²-sized)
+            # q pre-scaled: dk = ds^T @ (scale*log2e*q); the caller
+            # multiplies the FINAL dk tile by ln2 once (d-sized, not
+            # s²-sized)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            p = _exp(s - lse_row[:, None])
+            p = jnp.exp2(s - lse_row[:, None])
             if masked:
                 valid = _score_mask(
                     s, qt, kt, mask_ref[0, 0, :] if has_mask else None,
                     sk if pad else None, causal)
                 p = jnp.where(valid, p, 0.0)
             # p feeds only the dv matmul past this point (ds re-derives
-            # from the fp32 copy below) — bf16 tile for keep/scale + MXU
-            pd = _mxu_dtype(do.dtype)
+            # from the fp32 copy below) — narrow tile for keep/scale + MXU
+            pd = do.dtype
             if rate > 0.0:
                 keep = _keep_mask(seed_ref, i, qt * bq, kt * bk,
                                   p.shape, rate)
@@ -437,30 +346,27 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, do_ref,
                 p_drop = p.astype(pd)
             # dv += p_drop^T @ do
             dv_acc[:] += jax.lax.dot_general(
-                p_drop, do.astype(pd), (((0,), (0,)), ((), ())),
+                p_drop, do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
             if rate > 0.0:
                 dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
             ds = p * (dp - delta_row[:, None])
-            dsd = _mxu_dtype(q.dtype)
             dk_acc[:] += jax.lax.dot_general(
-                ds.astype(dsd), q.astype(dsd), (((0,), (0,)), ((), ())),
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         return go
 
-    @pl.when(run)
-    def _():
-        if has_mask:
-            tile(True)()
+    if has_mask:
+        tile(True)()
+    else:
+        needs = _needs_mask(causal, pad, qt, kt, bq, bk,
+                            pl.num_programs(1))
+        if needs is None:
+            tile(False)()
         else:
-            needs = _needs_mask(causal, pad, qt, kt, bq, bk,
-                                pl.num_programs(1))
-            if needs is None:
-                tile(False)()
-            else:
-                jax.lax.cond(needs, tile(True), tile(False))
+            jax.lax.cond(needs, tile(True), tile(False))
 
     @pl.when(qt == nq - 1)
     def _():
@@ -504,33 +410,13 @@ def _prep(q, k, v, mask, b, h):
     return q3, k3, v3, m3, sq_p, sk_p, d_p
 
 
-def _clamp_kt(causal, bq, bk):
-    """k-tile index clamp for (i, qt, kt)-ordered causal grids: a tile
-    above the diagonal re-requests the last live k-block instead of
-    fetching one it will never read (the kernel's `run` gate skips the
-    compute; this skips the copy)."""
-    if not (causal and _CAUSAL_SKIP and _CAUSAL_CLAMP):
-        return lambda kt, qt: kt
-    return lambda kt, qt: jnp.minimum(kt, ((qt + 1) * bq - 1) // bk)
-
-
 def _prescale_q(q3, scale):
     """Fold softmax_scale into q (fp32 multiply, one rounding back to
     the storage dtype) so no kernel pays a per-score-element scale op.
-    Under _EXP2 the SAME multiply also carries log2(e): the kernels'
-    score tiles come out as base-2 logits for free."""
-    if _EXP2:
-        scale = scale * _LOG2E
-    return (q3.astype(jnp.float32) * jnp.float32(scale)).astype(q3.dtype)
-
-
-def _maxb(causal, d):
-    """Block-size cap: the causal-skip cap when tile skipping is on, the
-    small-head-dim cap below _SMALL_D (see the toggle comments)."""
-    maxb = _CAUSAL_MAX_BLOCK if (causal and _CAUSAL_SKIP) else 512
-    if d < _SMALL_D:
-        maxb = min(maxb, _SMALL_D_MAX_BLOCK)
-    return maxb
+    The SAME multiply also carries log2(e): the kernels' score tiles
+    come out as base-2 logits for free."""
+    return (q3.astype(jnp.float32)
+            * jnp.float32(scale * _LOG2E)).astype(q3.dtype)
 
 
 def _fwd_call(q, k, v, mask, *, causal, scale, rate, seed, interpret):
@@ -538,16 +424,13 @@ def _fwd_call(q, k, v, mask, *, causal, scale, rate, seed, interpret):
     sk = k.shape[2]
     q3, k3, v3, m3, sq_p, sk_p, d_p = _prep(q, k, v, mask, b, h)
     q3 = _prescale_q(q3, scale)
-    maxb = _maxb(causal, d)
-    bq, bk = _block(sq_p, maxb), _block(sk_p, maxb)
+    bq, bk = _block(sq_p), _block(sk_p)
     grid = (b * h, sq_p // bq, sk_p // bk)
     sd = jnp.asarray(seed, jnp.uint32).reshape(1, 2)
-    ckt = _clamp_kt(causal, bq, bk)
-    kv_spec = pl.BlockSpec((1, bk, d_p),
-                           lambda i, qt, kt: (i, ckt(kt, qt), 0),
+    kv_spec = pl.BlockSpec((1, bk, d_p), lambda i, qt, kt: (i, kt, 0),
                            memory_space=pltpu.VMEM)
     mask_spec = pl.BlockSpec((1, 1, bk),
-                             lambda i, qt, kt: (i // h, 0, ckt(kt, qt)),
+                             lambda i, qt, kt: (i // h, 0, kt),
                              memory_space=pltpu.VMEM)
     lse_spec = pl.BlockSpec((1, 1, bq), lambda i, qt, kt: (i, 0, qt),
                             memory_space=pltpu.VMEM)
@@ -584,16 +467,13 @@ def _bwd_call(q, k, v, mask, out, lse_p, do, *, causal, scale, rate, seed,
                     -1)[:, None, :]  # (bh, 1, sq_p) like lse
     sd = jnp.asarray(seed, jnp.uint32).reshape(1, 2)
 
-    maxb = _maxb(causal, d)
-    bq, bk = _block(sq_p, maxb), _block(sk_p, maxb)
-    ckt = _clamp_kt(causal, bq, bk)
+    bq, bk = _block(sq_p), _block(sk_p)
     row_spec = pl.BlockSpec((1, 1, sq_p), lambda i, qt, kt: (i, 0, 0),
                             memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, bk, d_p),
-                           lambda i, qt, kt: (i, ckt(kt, qt), 0),
+    kv_spec = pl.BlockSpec((1, bk, d_p), lambda i, qt, kt: (i, kt, 0),
                            memory_space=pltpu.VMEM)
     mask_spec = pl.BlockSpec((1, 1, bk),
-                             lambda i, qt, kt: (i // h, 0, ckt(kt, qt)),
+                             lambda i, qt, kt: (i // h, 0, kt),
                              memory_space=pltpu.VMEM)
     with jax.named_scope("apex_flash_bwd_dq"):
         dq = pl.pallas_call(
@@ -610,14 +490,8 @@ def _bwd_call(q, k, v, mask, out, lse_p, do, *, causal, scale, rate, seed,
             name="apex_flash_bwd_dq",
         )(sd, q3, k3, v3, m3, do3, lse_p, delta)
 
-    # dkv: k outer / q inner — index maps swap roles; causal clamp
-    # mirrors _clamp_kt (q tiles strictly above the diagonal are dead)
-    if causal and _CAUSAL_SKIP and _CAUSAL_CLAMP:
-        cqt = lambda qt, kt: jnp.maximum(qt, (kt * bk) // bq)
-    else:
-        cqt = lambda qt, kt: qt
-    q_spec2 = pl.BlockSpec((1, bq, d_p),
-                           lambda i, kt, qt: (i, cqt(qt, kt), 0),
+    # dkv: k outer / q inner — index maps swap roles
+    q_spec2 = pl.BlockSpec((1, bq, d_p), lambda i, kt, qt: (i, qt, 0),
                            memory_space=pltpu.VMEM)
     kv_spec2 = pl.BlockSpec((1, bk, d_p), lambda i, kt, qt: (i, kt, 0),
                             memory_space=pltpu.VMEM)
@@ -646,14 +520,11 @@ def _bwd_call(q, k, v, mask, out, lse_p, do, *, causal, scale, rate, seed,
     # dq kernel produced d(scale*q); one fused XLA multiply finishes it
     dq = (dq[:, :sq, :d].astype(jnp.float32) * jnp.float32(scale)
           ).astype(q.dtype).reshape(b, h, sq, d)
-    dk = dk[:, :sk, :d]
-    if _EXP2:
-        # the dkv kernel's dk = ds^T @ (scale*log2e*q) — one ln(2)
-        # multiply on the final (s, d) tile undoes the log2e (the ONLY
-        # base-conversion cost of the base-2 softmax; it fuses with the
-        # slice above)
-        dk = (dk.astype(jnp.float32) * jnp.float32(_LN2)).astype(k.dtype)
-    dk = dk.reshape(b, h, sk, d)
+    # the dkv kernel's dk = ds^T @ (scale*log2e*q) — one ln(2) multiply
+    # on the final (s, d) tile undoes the log2e (the ONLY base-conversion
+    # cost of the base-2 softmax; it fuses with the slice)
+    dk = (dk[:, :sk, :d].astype(jnp.float32)
+          * jnp.float32(_LN2)).astype(k.dtype).reshape(b, h, sk, d)
     dv = dv[:, :sk, :d].reshape(b, h, sk, d)
     return dq, dk, dv
 

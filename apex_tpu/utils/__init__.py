@@ -1,5 +1,4 @@
 from apex_tpu.utils.platform import (  # noqa: F401
-    has_tpu,
     pallas_interpret,
 )
 from apex_tpu.utils.math import (  # noqa: F401

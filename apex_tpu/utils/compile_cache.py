@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-Every entry point (``chip_smoke.py``, ``bench.py``, ``__graft_entry__.py``,
-the examples, ``tests/conftest.py``) calls :func:`enable_compile_cache`
+Every entry point (``benchmark/run.py``, ``chip_smoke.py``,
+``__graft_entry__.py``, the examples, ``tests/conftest.py``) calls :func:`enable_compile_cache`
 before its first compile. The directory is part of the cache key, so it is
 a fixed path: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it
 (jax reads that variable itself; no directory is set in code), otherwise

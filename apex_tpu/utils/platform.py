@@ -20,11 +20,6 @@ def _platform() -> str:
     return jax.devices()[0].platform
 
 
-def has_tpu() -> bool:
-    """True when the default backend's devices are TPUs."""
-    return _platform() == "tpu"
-
-
 def pallas_interpret(interpret=None) -> bool:
     """Resolve a user-supplied ``interpret`` flag (None → interpret on the
     CPU backend, compile everywhere else)."""

@@ -44,7 +44,6 @@ import collections
 import dataclasses
 import json
 import math
-import os
 import sys
 import time
 
@@ -140,20 +139,25 @@ class Smoke:
 
     def compile(self, what: str, traced):
         """Compile and inspect one traced program (``jitted.trace(...)``):
-        returns (compiled, ``{"<module>.<kernel>": calls}`` over its
-        ``pallas_call`` equations, seconds the compile took). On the chip
+        returns (compiled, ``{"<kernel>": calls}`` over its ``pallas_call``
+        equations, seconds the compile took). Every kernel is one of the
+        package's list (``KERNEL_NAMES``), which is also where the names
+        the phases expect are held to
+        (``tests/L0/run_utils/test_kernel_names.py``). On the chip
         no call may be in interpret mode, and the compiled text must hold
         one Mosaic custom call per ``pallas_call`` traced — a kernel that
         silently became something else would not. In the rehearsal every
         call is in interpret mode and lowers to plain HLO."""
+        from apex_tpu.utils.pallas import KERNEL_NAMES
+
         census = collections.Counter()
         for eqn in _pallas_calls(traced.jaxpr):
-            info = eqn.params["jaxpr"].debug_info
-            module = os.path.basename(
-                info.func_src_info.split(" at ")[-1]).split(".py")[0]
-            census[f"{module}.{info.func_name}"] += 1
+            name = eqn.params["jaxpr"].debug_info.func_name
+            check(name in KERNEL_NAMES,
+                  f"{what}: pallas_call {name} is not in KERNEL_NAMES")
+            census[name] += 1
             check(bool(eqn.params["interpret"]) == self.rehearsal,
-                  f"{what}: pallas_call {module}.{info.func_name} has "
+                  f"{what}: pallas_call {name} has "
                   f"interpret={eqn.params['interpret']} on platform "
                   f"{self.device['platform']}")
         t0 = time.perf_counter()
@@ -342,11 +346,10 @@ def phase_server(smoke: Smoke) -> None:
         compiled, census[name], _ = smoke.compile(name, traced)
         programs[name] = compiled_bytes(compiled)
     if longest >= 512:
-        check(any(k.startswith("flash_attention.")
-                  for k in census[f"prefill_{longest}"]),
+        check("apex_flash_fwd" in census[f"prefill_{longest}"],
               f"prefill at bucket {longest} holds no flash kernel: "
               f"{census}")
-    check(census["decode"].get("paged_attention.apex_paged_decode_fwd") == 1,
+    check(census["decode"].get("apex_paged_decode_fwd") == 1,
           f"decode holds no paged-attention kernel in its layer scan: "
           f"{census}")
 
@@ -406,12 +409,9 @@ def phase_trainer(smoke: Smoke) -> None:
     # every LayerNorm (embeddings, two per layer, the MLM head) and the
     # loss run as kernels, forward and backward
     norms = 2 * cfg.num_layers + 2
-    expect = {"fused_layer_norm.apex_ln_fwd": norms,
-              "fused_layer_norm.apex_ln_bwd": norms,
-              "xentropy.apex_xentropy_fwd": 1,
-              "xentropy.apex_xentropy_bwd": 1}
-    flash = {k: v for k, v in census.items()
-             if k.startswith("flash_attention.")}
+    expect = {"apex_ln_fwd": norms, "apex_ln_bwd": norms,
+              "apex_xentropy_fwd": 1, "apex_xentropy_bwd": 1}
+    flash = {"apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv"}
     check({k: v for k, v in census.items() if k not in flash} == expect,
           f"Pallas calls {census} != expected {expect}")
 

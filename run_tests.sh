@@ -52,17 +52,7 @@ case "$tier" in
   L0)    exec python -m pytest tests/L0 -q "$@" ;;
   L1)    exec python -m pytest tests/L1 -q "$@" ;;
   all)   exec python -m pytest tests -q "$@" ;;
-  quick) # the -m quick subset, then a few-arrival smoke of the
-         # seeded-Poisson serving bench (tiny model, chat mix only via
-         # APEX_BENCH_SCENARIOS) plus the multi-tenant adversarial
-         # mix, so scheduler-policy regressions surface in the inner
-         # loop, not first in CI
-         python -m pytest tests -q -m quick "$@"
-         echo "quick: Poisson serving-bench smoke (chat mix)" >&2
-         env APEX_BENCH_SCENARIOS=chat python bench.py \
-             gpt_serving_scenarios
-         echo "quick: multi-tenant serving smoke (adversarial mix)" >&2
-         exec python bench.py serving_multitenant ;;
+  quick) exec python -m pytest tests -q -m quick "$@" ;;
   chaos) # per-seed trace dumps land next to this path (a tag + seed
          # suffix is spliced in before the extension); set it empty to
          # disable the dump
@@ -87,6 +77,6 @@ case "$tier" in
                 "budget is ${budget}s" >&2
            exit 1
          fi ;;
-  *)     echo "usage: $0 [L0|L1|all|quick|gate|lint] [pytest args...]" >&2
+  *)     echo "usage: $0 [L0|L1|all|quick|chaos|gate|lint] [pytest args...]" >&2
          exit 2 ;;
 esac

@@ -265,13 +265,3 @@ def test_remat_policy_selective_matches_and_validates():
                            "remat_policy": "save_only_these_names"})
     with pytest.raises(ValueError, match="save_only_these_names"):
         gpt_loss_unsharded(params, factory, ids, labels)
-
-
-def test_bench_hook_smoke():
-    from apex_tpu.models.gpt import gpt_tp_bench
-
-    # tp=2 keeps the hook-contract check ~4x cheaper than tp=8 on the
-    # 1-core host; the tp=8 math itself is covered by the tp8 tests
-    body, make_init, fetch, batch = gpt_tp_bench(False, 2)
-    state = body(make_init())
-    assert np.isfinite(float(fetch(state)))

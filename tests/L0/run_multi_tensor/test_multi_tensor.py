@@ -189,8 +189,17 @@ def test_applier_callable_reference_arity():
 
 def test_flat_adam_kernel_bf16_moment_and_castout():
     """Kernel-level reduced-precision contract: bf16 m in/out with fp32
-    accumulate (== round-to-nearest of the fp32 m), and the optional 4th
-    output == the updated params cast to the emit dtype, bit for bit."""
+    accumulate, rounded ONCE to bf16 on the store, and the optional 4th
+    output == the updated params cast to the emit dtype, bit for bit.
+
+    The stored m is the rounding of the kernel's own fp32 accumulator,
+    which need not be the fp32 path's m bit for bit: the two programs
+    fuse differently, and a contracted multiply-add moves
+    ``b1 * m + (1 - b1) * g`` by one fp32 ulp. That shows only where the
+    fp32 value sits on a bf16 rounding tie (element 1325 of this draw:
+    0x3ec68000 against 0x3ec68001), and there either neighbour is a
+    correct single rounding. So: one bf16 ulp at most, only at such
+    ties, and in at most 1e-4 of the elements."""
     n = 5000
     g = jax.random.normal(jax.random.PRNGKey(3), (n,), jnp.float32)
     p = jax.random.normal(jax.random.PRNGKey(4), (n,), jnp.float32)
@@ -208,11 +217,16 @@ def test_flat_adam_kernel_bf16_moment_and_castout():
     assert len(outs) == 4
     p_bf, m_bf, v_bf, pc = outs
     assert m_bf.dtype == jnp.bfloat16 and v_bf.dtype == jnp.float32
-    # m32 is bf16-exact, so the fp32-accumulated m must round to exactly
-    # the fp32 path's m, and v must match bit for bit
-    np.testing.assert_array_equal(
-        np.asarray(m_bf, np.float32),
-        np.asarray(m_ref.astype(jnp.bfloat16), np.float32))
+    # m32 is bf16-exact, so the bf16 path's accumulator is the fp32
+    # path's m up to the order of operations; v must match bit for bit
+    got = np.asarray(m_bf).view(np.uint16).astype(np.int32)
+    want = np.asarray(m_ref.astype(jnp.bfloat16)).view(
+        np.uint16).astype(np.int32)
+    off = got != want
+    assert np.abs(got - want).max() <= 1
+    low = np.asarray(m_ref).view(np.uint32)[off] & 0xFFFF
+    assert np.isin(low, (0x7FFF, 0x8000, 0x8001)).all(), low
+    assert off.mean() <= 1e-4
     np.testing.assert_array_equal(np.asarray(v_bf), np.asarray(v_ref))
     np.testing.assert_allclose(np.asarray(p_bf), np.asarray(p_ref),
                                rtol=1e-5, atol=1e-6)

@@ -673,9 +673,19 @@ def test_chunked_spec_streams_match_plain_sync():
 
 
 def test_chunked_final_logits_match_one_shot_paged():
-    """Engine-level contract: the final chunk's last-token logits are
-    BITWISE equal to a one-shot prefill of the same prompt — same
-    jitted executable family, same padded math, no chunk-count drift."""
+    """Engine-level contract: the final chunk's last-token logits are a
+    one-shot prefill's of the same prompt to float32 rounding: the same
+    argmax, and no logit further off than 32 ulps of the largest one
+    (5.5 measured here, 9 with XLA's fusion off). They are NOT bitwise
+    equal and never were on this installation: the two are different
+    programs. The one-shot prefill attends over its 16-token bucket
+    through ``flash_attention`` (scores times ``1/sqrt(hd)``, its own
+    softmax); a chunk attends over the ``max_len`` gathered positions
+    (scores divided by ``sqrt(hd)``, ``jax.nn.softmax``); and XLA fuses
+    the two differently, so even layer 0's K rows, equal stage by
+    stage, land a float32 ulp apart. What the scheduler promises on top
+    of this is the neighbouring tests': the committed token streams are
+    identical."""
     import numpy as np
 
     import jax.numpy as jnp
@@ -685,8 +695,8 @@ def test_chunked_final_logits_match_one_shot_paged():
     prompt = tuple(range(2, 2 + 13))    # 13 tokens -> 4 chunks of 4
 
     def engine():
-        # fp32 cache for the same reason as _run_chunked: bitwise is
-        # only promised where the cache itself doesn't round
+        # fp32 cache for the same reason as _run_chunked: the bound is
+        # float32's only where the cache itself doesn't round
         return PagedDecodeEngine(params, cfg, num_slots=1,
                                  max_len=MAX_LEN, num_pages=24,
                                  page_size=4, buckets=(16, 32),
@@ -705,7 +715,10 @@ def test_chunked_final_logits_match_one_shot_paged():
         pos += ct
     eng.finish_chunk_prefill(0, state)
     eng.check_invariants()
-    assert np.array_equal(np.asarray(logits), one_shot)
+    chunked = np.asarray(logits)
+    assert chunked.argmax() == one_shot.argmax()
+    ulp = np.spacing(np.abs(one_shot).max())
+    assert np.abs(chunked - one_shot).max() <= 32 * ulp
 
 
 def test_chunked_bounds_cotenant_itl_tail_on_the_tick_clock():

@@ -184,98 +184,25 @@ def test_auto_dispatch_threshold():
     assert "pallas_call" in jaxpr2
 
 
-# -- VPU-diet variants (exp2 online softmax, bf16 p-tiles) ------------------
-
-def _fam():
-    """The flash_attention MODULE (the package __init__ rebinds the name
-    to the function; importlib addresses the module, where the variant
-    toggles and ``kernel_variant`` live)."""
-    import importlib
-    return importlib.import_module(
-        "apex_tpu.transformer.functional.flash_attention")
-
-
-def _fwdbwd(q, k, v, rate=0.0, rng=None, **kw):
-    def loss(q, k, v):
-        return jnp.sum(flash_attention(
-            q, k, v, causal=True, use_kernel=True, dropout_rate=rate,
-            dropout_rng=rng, **kw).astype(jnp.float32) ** 2)
-    l, grads = jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
-    return (l, *grads)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_kernel_variants_agree(dtype):
-    """The shipped kernels (exp2 + bf16 p-tiles) vs the legacy toggles:
-    pure arithmetic re-expression, so fwd AND all grads must agree to
-    the golden tolerances. Variants are baked at TRACE time, so each
-    side jits inside its context."""
-    fam = _fam()
-    q, k, v = _qkv(jax.random.PRNGKey(20), 1, 2, 512, 64, dtype)
-    new = jax.jit(_fwdbwd)(q, k, v)
-    with fam.kernel_variant(exp2=False, p_bf16=False):
-        old = jax.jit(_fwdbwd)(q, k, v)
-    for a, b in zip(new, old):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   **{kk: 3 * t for kk, t in
-                                      TOL[dtype].items()})
-
-
-def test_small_d_block_cap_variant_matches():
-    """``small_d_max_block`` only retiles the grid — the math is
-    identical, so a 256 cap must reproduce the default to fp32
-    tolerance, dropout included (the counter-hash mask is addressed by
-    GLOBAL (q,k) position, so retiling must not move any mask bit)."""
-    fam = _fam()
-    q, k, v = _qkv(jax.random.PRNGKey(21), 1, 2, 512, 64, jnp.float32)
+@pytest.mark.parametrize("s", [384, 1024])
+def test_dropout_mask_independent_of_tiling(s):
+    """The keep mask is addressed by GLOBAL (head, q, k) position, so it
+    cannot depend on how the kernel tiles the scores: a 3x3 grid of
+    128-tiles (s=384) and a 2x2 grid of 512-tiles (s=1024) must drop
+    what the untiled XLA path drops for the same rng, forward and in
+    all three gradients (causal, so tiles on, under and over the
+    diagonal all run)."""
+    q, k, v = _qkv(jax.random.PRNGKey(21), 1, 2, s, 16, jnp.float32)
     rng = jax.random.PRNGKey(22)
-    base = jax.jit(lambda q, k, v: _fwdbwd(q, k, v, 0.3, rng))(q, k, v)
-    with fam.kernel_variant(small_d_max_block=256):
-        capped = jax.jit(lambda q, k, v: _fwdbwd(q, k, v, 0.3, rng))(
-            q, k, v)
-    for a, b in zip(base, capped):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
+
+    def fwdbwd(use_kernel):
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, causal=True, use_kernel=use_kernel,
+                dropout_rate=0.3, dropout_rng=rng) ** 2)
+        l, grads = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(q, k, v)
+        return (l, *grads)
+
+    for a, b in zip(fwdbwd(True), fwdbwd(False)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4)
-
-
-def test_dropout_mask_invariant_across_variants():
-    """The keep mask is a pure function of (rng, global position) — the
-    exp2/bf16 toggles must not move a single mask bit. Recover each
-    variant's mask from a rate-r run against its own no-dropout output
-    (dropped entries of p are exact zeros, so out_drop == 0 exactly
-    where whole rows drop is too coarse — compare elementwise scaling
-    instead on V = identity-ish basis): with v = identity basis columns,
-    out[q, i] directly exposes p[q, i]'s keep bit."""
-    fam = _fam()
-    s, d = 256, 64
-    q = jax.random.normal(jax.random.PRNGKey(23), (1, 1, s, d),
-                          jnp.float32)
-    k = jax.random.normal(jax.random.PRNGKey(24), (1, 1, s, d),
-                          jnp.float32)
-    # v = one-hot rows: out[:, :, i, j] = sum_k p[i, k] * v[k, j] with
-    # v[k, j] = (k % d == j) exposes p column-sums per residue class;
-    # enough to catch any mask shift while keeping d < s workable
-    v = (jnp.arange(s)[:, None] % d == jnp.arange(d)[None, :]).astype(
-        jnp.float32)[None, None]
-    rng = jax.random.PRNGKey(25)
-
-    def dropped(toggles):
-        if toggles:
-            with fam.kernel_variant(**toggles):
-                return jax.jit(lambda q, k, v: flash_attention(
-                    q, k, v, causal=True, use_kernel=True,
-                    dropout_rate=0.3, dropout_rng=rng))(q, k, v)
-        return jax.jit(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, use_kernel=True,
-            dropout_rate=0.3, dropout_rng=rng))(q, k, v)
-
-    base = dropped(None)
-    for toggles in ({"exp2": False}, {"p_bf16": False},
-                    {"exp2": False, "p_bf16": False},
-                    {"small_d_max_block": 128}):
-        other = dropped(toggles)
-        np.testing.assert_allclose(np.asarray(base), np.asarray(other),
-                                   atol=5e-5, rtol=5e-5,
-                                   err_msg=f"mask moved under {toggles}")

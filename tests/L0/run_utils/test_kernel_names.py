@@ -50,18 +50,34 @@ def _sites():
 
 
 def test_every_pallas_call_has_a_unique_literal_apex_name_and_scope():
+    from apex_tpu.utils.pallas import KERNEL_NAMES
+
     sites = _sites()
-    assert len(sites) >= 24
     for path, line, name, scope in sites:
         where = f"{path}:{line}"
         assert isinstance(name, str) and name.startswith("apex_"), where
         assert scope == name, (where, name, scope)
     names = [name for _, _, name, _ in sites]
     assert len(set(names)) == len(names), sorted(names)
+    # the package's one list says what the sources say
+    assert sorted(KERNEL_NAMES) == sorted(names)
     # the names the trace readers and PERF.md lean on
     assert {"apex_ln_fwd", "apex_ln_bwd", "apex_xentropy_fwd",
             "apex_xentropy_bwd", "apex_flash_fwd",
             "apex_paged_decode_fwd"} <= set(names)
+
+
+def test_chip_smoke_expects_only_kernels_of_the_list():
+    """``chip_smoke.py`` kept names of its own once and broke in silence
+    when the kernels were renamed (PR 24)."""
+    import re
+
+    from apex_tpu.utils.pallas import KERNEL_NAMES
+
+    source = open(os.path.join(REPO, "chip_smoke.py")).read()
+    expected = set(re.findall(r'"(apex_\w+)"', source))
+    assert {"apex_ln_fwd", "apex_paged_decode_fwd"} <= expected
+    assert expected <= set(KERNEL_NAMES), expected - set(KERNEL_NAMES)
 
 
 def test_kernel_name_reaches_the_lowered_program():

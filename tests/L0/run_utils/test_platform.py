@@ -24,7 +24,6 @@ def fresh_platform():
 
 
 def test_cpu_backend_selects_interpret(fresh_platform):
-    assert platform.has_tpu() is False
     assert platform.pallas_interpret() is True
     assert platform.pallas_interpret(False) is False
 
@@ -36,8 +35,6 @@ def test_backend_error_propagates(fresh_platform, monkeypatch):
         raise RuntimeError("Unable to initialize backend 'tpu'")
 
     monkeypatch.setattr(jax, "devices", boom)
-    with pytest.raises(RuntimeError, match="Unable to initialize"):
-        platform.has_tpu()
     with pytest.raises(RuntimeError, match="Unable to initialize"):
         platform.pallas_interpret()
 

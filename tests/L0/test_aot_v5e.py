@@ -219,8 +219,8 @@ def test_flat_adam(v5e):
 
 def test_ddp_bert_step_lowers_for_four_chips(v5e_chips):
     """The multi-chip route: the data-parallel BERT amp-O2 step of
-    ``__graft_entry__`` phase 1 and ``bench.py ddp_bert`` lowers for four
-    chips through ``shard_map``, kernels and all. The same step as a plain
+    ``__graft_entry__`` phase 1 and the ``bert_large.pretrain_s128_dp4``
+    cell lowers for four chips through ``shard_map``, kernels and all. The same step as a plain
     ``jit`` over batch-sharded inputs does not — XLA does not partition a
     Mosaic kernel — which the CPU mesh (interpret-mode kernels) cannot
     show. The day the second half fails, the reason ``shard_map`` is the

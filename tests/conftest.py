@@ -20,7 +20,7 @@ jax.config.update("jax_num_cpu_devices", 8)
 
 # Persistent compilation cache: the suite's wall time is dominated by XLA
 # compiles, and most test programs are identical run to run. The
-# subprocess-driving tests (examples, bench) land in the same directory
+# subprocess-driving tests (examples) land in the same directory
 # because every entry point calls the same helper.
 enable_compile_cache()
 
@@ -31,7 +31,6 @@ enable_compile_cache()
 # `-m 'not slow'` tier remains the gate; quick only ADDS a marker, it
 # never hides a test from the default run.
 _HEAVY_MODULES = {
-    "test_bench_parent.py",     # bench.py subprocesses
     "test_resume.py",           # kill-and-resume subprocess
     "test_graft_entry.py",      # in-process dryrun (all mesh shapes)
     "test_gpt.py",              # tp8/pp/cp shard_map compiles
