@@ -17,6 +17,19 @@ Semantics (matching the reference kernel):
   (label smoothing spreads eps uniformly over the vocab);
 - rows with ``label < 0`` are ignored (zero loss, zero grad) — the
   functional analogue of the reference's padding handling.
+
+Shapes: any ``n``, any ``v``, float32 or bfloat16. The kernels read the
+logits, and write ``dx``, at the shape the caller hands over, in blocks of
+256 rows x 2,048 vocabulary lanes (fewer lanes for a vocabulary under
+2,048). Nothing is padded in HBM: where ``n`` or ``v`` is no multiple of
+the block, the last block is ragged. Pallas fills the out-of-bounds part
+of an input block with unspecified values (NaN in interpret mode) and
+drops out-of-bounds writes, so every use of ``x`` sits behind the
+``col < v`` mask. Rows need none: every reduction runs along the
+vocabulary, so what a ragged row block holds out of bounds stays in rows
+whose loss is zeroed (``row >= n``) and whose ``dx`` is never written.
+Only the four 1-D vectors (labels, loss, lse, dloss) are padded, to a
+multiple of 256 rows.
 """
 
 import functools
@@ -28,7 +41,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.utils.math import round_up_to_multiple
-from apex_tpu.utils.pallas import dimsem as _dimsem, NEG_INF as _NEG, pad2 as _pad2
+from apex_tpu.utils.pallas import (
+    dimsem as _dimsem,
+    NEG_INF as _NEG,
+    pad_axis as _pad_axis,
+)
 from apex_tpu.utils.platform import pallas_interpret
 
 _BR = 256     # rows per block (sublane dim)
@@ -52,6 +69,8 @@ def _fwd_kernel(x_ref, lab_ref, loss_ref, lse_ref,
     bv = x.shape[1]
     col = vt * bv + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     in_vocab = col < v
+    # the one read of the block: lanes out of bounds (a ragged last block
+    # holds unspecified values there) are replaced before any use
     x = jnp.where(in_vocab, x, _NEG)
 
     m_prev = m_ref[:, 0:1]
@@ -94,6 +113,9 @@ def _bwd_kernel(x_ref, lab_ref, lse_ref, dl_ref, dx_ref, *, n, v, eps):
     dloss = dl_ref[0, pl.ds(rt * br, br)][:, None]
     row = rt * br + jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
     live = jnp.logical_not((labels < 0) | (row >= n))
+    # x is unspecified out of bounds (ragged last block) and soft may be
+    # anything there: the select below keeps it out of the block, and the
+    # block's out-of-bounds part is never written to dx
     soft = jnp.exp(x - lse)
     target = (1.0 - eps) * (col == labels).astype(jnp.float32)
     if eps > 0.0:
@@ -107,17 +129,21 @@ def _row_spec(n_p):
                         memory_space=pltpu.VMEM)
 
 
-def _fwd_call(logits, labels, eps, interpret):
-    n, v = logits.shape
+def _tiling(n, v):
+    """``(n_p, grid, x_spec)`` for (n, v) logits: the logits' blocks cover
+    the operand as it is (ragged last blocks), the row vectors are n_p
+    long."""
     n_p = round_up_to_multiple(n, _BR)
     bv = min(_BV, round_up_to_multiple(v, 128))
-    v_p = round_up_to_multiple(v, bv)
-    xp = _pad2(logits, n_p, v_p)
-    lab = jnp.pad(labels.astype(jnp.int32), (0, n_p - n),
-                  constant_values=-1)[None, :]
-    grid = (n_p // _BR, v_p // bv)
     x_spec = pl.BlockSpec((_BR, bv), lambda rt, vt: (rt, vt),
                           memory_space=pltpu.VMEM)
+    return n_p, (n_p // _BR, pl.cdiv(v, bv)), x_spec
+
+
+def _fwd_call(logits, labels, eps, interpret):
+    n, v = logits.shape
+    n_p, grid, x_spec = _tiling(n, v)
+    lab = _pad_axis(labels.astype(jnp.int32), n_p, 0, -1)[None, :]
     with jax.named_scope("apex_xentropy_fwd"):
         loss, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, n=n, v=v, eps=eps),
@@ -134,34 +160,26 @@ def _fwd_call(logits, labels, eps, interpret):
             compiler_params=_dimsem("arbitrary", "arbitrary"),
             interpret=pallas_interpret(interpret),
             name="apex_xentropy_fwd",
-        )(xp, lab)
+        )(logits, lab)
     return loss[0, :n], lse  # lse stays padded (1, n_p)
 
 
 def _bwd_call(logits, labels, lse_p, dloss, eps, interpret):
     n, v = logits.shape
-    n_p = round_up_to_multiple(n, _BR)
-    bv = min(_BV, round_up_to_multiple(v, 128))
-    v_p = round_up_to_multiple(v, bv)
-    xp = _pad2(logits, n_p, v_p)
-    lab = jnp.pad(labels.astype(jnp.int32), (0, n_p - n),
-                  constant_values=-1)[None, :]
-    dl = jnp.pad(dloss.astype(jnp.float32), (0, n_p - n))[None, :]
-    grid = (n_p // _BR, v_p // bv)
-    x_spec = pl.BlockSpec((_BR, bv), lambda rt, vt: (rt, vt),
-                          memory_space=pltpu.VMEM)
+    n_p, grid, x_spec = _tiling(n, v)
+    lab = _pad_axis(labels.astype(jnp.int32), n_p, 0, -1)[None, :]
+    dl = _pad_axis(dloss.astype(jnp.float32), n_p, 0)[None, :]
     with jax.named_scope("apex_xentropy_bwd"):
-        dx = pl.pallas_call(
+        return pl.pallas_call(
             functools.partial(_bwd_kernel, n=n, v=v, eps=eps),
             grid=grid,
             in_specs=[x_spec, _row_spec(n_p), _row_spec(n_p), _row_spec(n_p)],
             out_specs=x_spec,
-            out_shape=jax.ShapeDtypeStruct((n_p, v_p), logits.dtype),
+            out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
             compiler_params=_dimsem("parallel", "parallel"),
             interpret=pallas_interpret(interpret),
             name="apex_xentropy_bwd",
-        )(xp, lab, lse_p, dl)
-    return dx[:n, :v]
+        )(logits, lab, lse_p, dl)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

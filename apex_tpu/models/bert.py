@@ -199,8 +199,14 @@ def apply_bert(params: Dict[str, Any], cfg: BertConfig,
     t = jax.nn.gelu(L.dense(head["transform"], x))
     t = _ln(head["layernorm"], t, cfg.layer_norm_eps)
     word_table = emb["word"]["embedding"].astype(t.dtype)
-    mlm_logits = (jnp.dot(t, word_table.T).astype(jnp.float32)
-                  + head["bias"].astype(jnp.float32))
+    # rows flattened BEFORE the product, contracted on the table's hidden
+    # axis: the logits are born (b*s, vocab) row-major, which is what the
+    # loss kernel reads; as a (b, s, vocab) product XLA lays them out
+    # vocab-major and copies the gigabyte in front of the kernel
+    flat = jax.lax.dot_general(t.reshape(b * s, -1), word_table,
+                               (((1,), (1,)), ((), ())))
+    mlm_logits = (flat.astype(jnp.float32)
+                  + head["bias"].astype(jnp.float32)).reshape(b, s, -1)
     pooled = jnp.tanh(L.dense(params["pooler"], x[:, 0]))
     return {"hidden": x, "mlm_logits": mlm_logits, "pooled": pooled}
 

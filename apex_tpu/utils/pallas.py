@@ -37,11 +37,6 @@ def pad_axis(x, size: int, axis: int, value=0.0):
     return jnp.pad(x, pads, constant_values=value)
 
 
-def pad2(x, rows: int, cols: int, value=0.0):
-    """Pad a 2-D array to (rows, cols)."""
-    return pad_axis(pad_axis(x, rows, 0, value), cols, 1, value)
-
-
 def dimsem(*sem):
     """``pltpu.CompilerParams`` with grid dimension semantics:
     ``"parallel"`` = revisit-free tiles Mosaic may pipeline/partition

@@ -46,13 +46,20 @@ def compile_for_tpu(monkeypatch):
     monkeypatch.setattr(platform, "_platform", lambda: "tpu")
 
 
-def compile_on(device, fn, *shapes):
+MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+def compile_text(device, fn, *shapes):
     """Compile ``fn`` for ``device`` at ``shapes`` ((shape, dtype) pairs);
-    returns the number of Mosaic custom calls in the compiled program."""
+    returns the compiled program's text."""
     sharding = SingleDeviceSharding(device)
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    return text.count('custom_call_target="tpu_custom_call"')
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def compile_on(device, fn, *shapes):
+    """The number of Mosaic custom calls in ``compile_text``'s program."""
+    return compile_text(device, fn, *shapes).count(MOSAIC_CALL)
 
 
 def _sum32(x):
@@ -114,10 +121,18 @@ def test_fused_softmax(v5e):
                                          (50304, jnp.bfloat16)])
 def test_xentropy_fwd_bwd(v5e, vocab, dtype):
     from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
+    from apex_tpu.utils.math import round_up_to_multiple
 
-    assert compile_on(
+    text = compile_text(
         v5e, jax.grad(lambda x, y: jnp.sum(softmax_cross_entropy_loss(x, y))),
-        ((8192, vocab), dtype), ((8192,), jnp.int32)) == 2
+        ((8192, vocab), dtype), ((8192,), jnp.int32))
+    assert text.count(MOSAIC_CALL) == 2
+    # neither vocabulary is a multiple of the 2,048-lane block (30,720 and
+    # 51,200 are the next ones): the kernels read and write the operand
+    # itself, and nothing of the padded shape is in the program
+    padded = round_up_to_multiple(vocab, 2048)
+    assert padded != vocab and f"[8192,{vocab}]" in text
+    assert f"[8192,{padded}]" not in text
 
 
 @pytest.mark.parametrize("m,k,n", [
