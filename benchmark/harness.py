@@ -15,7 +15,8 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
-#: exactly the keys of the result line, and of ``device`` inside it
+#: the keys of the result line the driver reads (``breakdown`` and the
+#: benchmark's own ``compared`` follow them), and of ``device`` inside it
 RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device")
 DEVICE_KEYS = ("platform", "kind", "count", "memory_peak_bytes")
 
@@ -96,8 +97,7 @@ def enable_compile_cache(root: str = ROOT) -> str:
     ``JAX_COMPILATION_CACHE_DIR`` names (JAX reads it itself), else
     ``.jax_cache`` in the checkout, which is also where the program's own
     ``enable_compile_cache`` puts it. Every program is kept, however quick
-    its compile: a server pads each new prompt length with a tiny program of
-    its own, hundreds of them, and a second run has to find them all."""
+    its compile: a second run has to find them all, the tiny ones too."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -189,8 +189,10 @@ def program_bytes(compiled) -> dict:
 
 
 def result_line(*, correct: bool, attempted: int, failed: int, metrics: dict,
-                device: dict, breakdown=None) -> str:
-    """The last line of a run: exactly the contract's keys."""
+                device: dict, breakdown=None, numbers=()) -> str:
+    """The last line of a run: the contract's keys and, last of all,
+    ``compared``: each number that decided ``correct`` beside its limit
+    (``numbers``: the rows ``comparison`` gives)."""
     for key in DEVICE_KEYS:
         if key not in device:
             raise BenchmarkError(f"device lacks {key!r}")
@@ -205,7 +207,18 @@ def result_line(*, correct: bool, attempted: int, failed: int, metrics: dict,
                            breakdown["device_ops"][:10]],
             "idle_gaps": [[str(n), float(s)] for n, s in
                           breakdown["idle_gaps"][:10]]}
+    out["compared"] = {n["number"]: {"value": n["value"], "limit": n["limit"]}
+                       for n in numbers}
     return json.dumps(out)
+
+
+def say_compared(numbers) -> None:
+    """Each number compared beside its limit, one to a line: the last lines
+    of a run's standard error."""
+    for n in numbers:
+        print(f"compared {n['number']} = {n['value']!r} (limit "
+              f"{n['limit']!r}){'' if n['ok'] else ' NOT CORRECT'}",
+              file=sys.stderr, flush=True)
 
 
 def say(label: dict, **fields) -> None:

@@ -1,8 +1,9 @@
 """The decode program's share of its bandwidth bound: the bytes one step
 needs (``kernels/decode_step.py``: every weight once and the K and V of the
 cache positions mapped at the middle of the traced span) over the HBM bandwidth, over
-the median device time of a decode step. Decode attention is a gather and an
-einsum inside the program, not a kernel, so this is the whole program's."""
+the median device time of a decode step. The whole program's share: decode
+attention is the kernel ``apex_paged_decode_fwd`` inside it since PR 25, and
+``paged_attn_kernel_ms_per_decode`` gives that kernel's own time."""
 
 from benchmark.harness import load_module, median
 

@@ -3,7 +3,9 @@ traced span per thousand prompt tokens computed there: each prompt counts as
 its bucket (padding is computed; so are cached-prefix tokens today, the
 monolithic prefill runs the whole bucket and only redirects the writes). The
 prompts counted are those whose first token was delivered inside the traced
-span."""
+span, which the traffic file places (``trace_start_s``, ``trace_seconds``)
+where admissions run beside decode: in ``prompt_backlog`` 4 s from t = 2 s of
+a backlog that outlasts the window, about a hundred prefills."""
 
 
 def bucket_for(n: int, buckets) -> int:

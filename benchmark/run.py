@@ -6,8 +6,10 @@
 A new process per run. Without a TPU, or with fewer chips than the cell asks
 for, it exits non-zero and prints no result: there is no CPU fallback. The
 last line of standard output is the result, one JSON object with the keys
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` (and
-``breakdown`` with ``--trace 1``); everything else goes on earlier lines.
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, with
+``--trace 1`` ``breakdown``, and last ``compared`` (each number that decided
+``correct`` beside its limit, which are also the last lines of standard
+error); everything else goes on earlier lines.
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics, read from a profiler trace of the first seconds of the
 window by the readers under ``metrics/``.
@@ -18,6 +20,7 @@ labelled a rehearsal, and no result line, because a rehearsal is not one.
 """
 
 import argparse
+import collections
 import contextlib
 import os
 import shutil
@@ -57,6 +60,7 @@ class Ctx:
         # a fixed place inside the checkout, emptied by each traced run
         self.trace_dir = os.path.join(cell.root, ".bench_trace", cell.name)
         self.traced = None          # (t_open, t_close) of the traced span
+        self.trace_stop_s = None    # what stopping the profiler cost after
         self._tracing = False
         self._t_trace = None
 
@@ -78,6 +82,7 @@ class Ctx:
         if self._tracing:
             self.traced = (self._t_trace, time.perf_counter())
             jax.profiler.stop_trace()
+            self.trace_stop_s = time.perf_counter() - self.traced[1]
             self._tracing = False
         return False
 
@@ -151,7 +156,19 @@ def main(argv=None) -> int:
         metrics = {}
     elif ctx.trace:
         from benchmark import trace
-        reduced = trace.reduce_dir(ctx.trace_dir)
+        # a server may have nothing to do in its traced span (a backlog the
+        # window drained): that is a reading, idle 100%. A training run
+        # whose trace holds no device operation is a broken run.
+        reduced = trace.reduce_dir(
+            ctx.trace_dir, may_be_empty=cell.traffic["kind"] == "requests")
+        if not reduced.chips:
+            ctx.say(stage="empty_traced_window",
+                    what="no operation ran on a device in the traced span: "
+                         "the readers that need device work are left out",
+                    traced_from_s=out["counts"]["traced_from_s"],
+                    traced_s=ctx.traced[1] - ctx.traced[0],
+                    backlog_left=out["counts"]["backlog_left"],
+                    drained_at_s=out["counts"]["drained_at_s"])
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
         breakdown = reduced.breakdown()
@@ -175,6 +192,9 @@ def main(argv=None) -> int:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         ctx.say(stage="trace", busy_s=reduced.busy_s,
                 window_s=reduced.window_s, end_to_end_while_traced=values,
+                stop_trace_s=ctx.trace_stop_s,
+                bench_spans=collections.Counter(
+                    name for name, _, _ in reduced.host.bench),
                 programs=reduced.programs_summary(),
                 custom_calls=reduced.custom_calls()
                 if ctx.options.get("list_kernels") else None)
@@ -184,10 +204,11 @@ def main(argv=None) -> int:
     line = harness.result_line(
         correct=out["correct"], attempted=out["attempted"],
         failed=out["failed"], metrics=metrics, device=device,
-        breakdown=breakdown)
+        breakdown=breakdown, numbers=out["numbers"])
     if ctx.rehearsal:
         ctx.say(stage="rehearsal_result", would_be=line)
         return 0
+    harness.say_compared(out["numbers"])
     print(line, flush=True)
     return 0
 

@@ -246,8 +246,8 @@ def run(ctx):
                              low["update_norms"]), want, limits)
         ctx.say(stage="control", what="the reference in bfloat16 throughout",
                 numbers=harness.comparison(c_rows)[1])
-    return {"correct": ok, "attempted": steps, "failed": failed,
-            "values": values, "memory_peak_bytes": peak,
+    return {"correct": ok, "numbers": numbers, "attempted": steps,
+            "failed": failed, "values": values, "memory_peak_bytes": peak,
             "counts": {"steps": steps, "tokens": tokens,
                        "tokens_per_step_per_chip": feed.rows * feed.seq
                        // ctx.chips, "window_s": window,

@@ -10,6 +10,8 @@ shows in its time to first token, and how late the generator submitted is
 reported beside it.
 """
 
+import gc
+import sys
 import time
 
 import numpy as np
@@ -64,21 +66,15 @@ def _request(a):
                    temperature=a.temperature, seed=a.seed)
 
 
-def warm_up(ctx, engine, sched, mix, sz, arrivals):
-    """Run every program the mix can reach once: each prefill bucket its
-    prompt lengths can hit, decode, both samplers, the page copy (two
-    identical prompts share a partial last page, and the second to decode
-    copies it), and the tiny program with which the engine pads each
-    distinct prompt length to its bucket (``utils.seqlen.pad_to_bucket``
-    pads with ``jnp.pad``, one compile per length: PERF.md, Open
-    questions), as a server that has been up for a while has."""
-    from apex_tpu.utils.seqlen import pad_to_bucket
-
+def warm_up(ctx, engine, sched, mix, sz):
+    """Run every program the mix can reach once, as a server that has been
+    up for a while has: each prefill bucket its prompt lengths can hit,
+    decode, both samplers, and the page copy (two identical prompts share a
+    partial last page, and the second to decode copies it). Nothing here
+    depends on how many requests the mix offers: the engine pads a prompt to
+    its bucket on the host, and ``compiles_in_window`` 0 is part of
+    ``correct``."""
     from benchmark import traffic
-
-    lengths = sorted({len(a.prompt) for a in arrivals})
-    for n in lengths:
-        pad_to_bucket(np.zeros((1, n), np.int32), n, buckets=engine.buckets)
 
     lo = mix["prompt_tokens"].get("lo", mix["prompt_tokens"].get("value"))
     hi = mix["prompt_tokens"].get("hi", mix["prompt_tokens"].get("value"))
@@ -101,16 +97,32 @@ def warm_up(ctx, engine, sched, mix, sz, arrivals):
     while sched.busy:
         sched.step()
         steps += 1
-    return {"buckets": buckets, "requests": len(warm), "steps": steps,
-            "prompt_lengths_padded": len(lengths)}
+    return {"buckets": buckets, "requests": len(warm), "steps": steps}
 
 
 def drive(ctx, sched, arrivals, mix, deliveries):
-    """The window. Returns the clock readings it took."""
+    """The window. Returns the clock readings it took.
+
+    What set-up left alive stays out of the window's garbage collections, as
+    in a server that froze its heap once it was up: a full collection of
+    this process takes 0.15-0.2 s, several ticks, and whether one or three
+    of them fell into the window moved ``serve_tokens_per_s`` by 1.5%
+    between runs whose median tick was the same (PERF.md, section 6, PR 27).
+    """
+    gc.collect()
+    gc.freeze()
+    try:
+        return _drive(ctx, sched, arrivals, mix, deliveries)
+    finally:
+        gc.unfreeze()
+
+
+def _drive(ctx, sched, arrivals, mix, deliveries):
     grace = float(mix.get("grace_s", 0.0))
     backlog = mix["arrivals"]["process"] == "backlog"
     rid_of, submitted_at, step_walls, depth = {}, {}, [], []
     nxt, n = 0, len(arrivals)
+    drained_at = None
     trace_from = float(mix.get("trace_start_s", 0.0))
     tracing, traced = False, not ctx.trace
     t0 = time.perf_counter()
@@ -138,6 +150,9 @@ def drive(ctx, sched, arrivals, mix, deliveries):
             t_after = time.perf_counter()
             step_walls.append((t, t_after - t))
             depth.append(len(sched._queue))
+            if backlog and drained_at is None and nxt == n \
+                    and not depth[-1]:
+                drained_at = t_after - t0    # the last request has a slot
         else:
             wake = min(end, t0 + arrivals[nxt].due_s) if nxt < n else end
             with ctx.span("idle_no_request"):
@@ -147,7 +162,9 @@ def drive(ctx, sched, arrivals, mix, deliveries):
         ctx.stop_trace()
     return {"t0": t0, "t1": t1, "end": end, "rid_of": rid_of,
             "submitted_at": submitted_at, "step_walls": step_walls,
-            "queue_depth": depth, "submitted": nxt}
+            "queue_depth": depth, "submitted": nxt,
+            "backlog_left": n - nxt + len(sched._queue),
+            "drained_at_s": drained_at}
 
 
 def measures(ctx, arrivals, clock, deliveries, sched, mix):
@@ -203,8 +220,60 @@ def measures(ctx, arrivals, clock, deliveries, sched, mix):
         "step_walls": clock["step_walls"],
         "queue_depth_first": clock["queue_depth"][:1],
         "queue_depth_last": clock["queue_depth"][-1:],
+        "backlog_left": clock["backlog_left"],
+        "drained_at_s": clock["drained_at_s"],
     }
     return values, counts, failed, finished
+
+
+def say_window(ctx, engine, clock, counts, deliveries, arrivals, sz, values,
+               failed, compiles_in_window, **more):
+    """The ``window`` line, and what the metric readers take from the window
+    into ``counts``. A backlog that the window drained says so, here and on
+    standard error: from ``drained_at_s`` on slots stood empty, and
+    ``serve_tokens_per_s`` reads what the traffic file offered and not what
+    the server can do."""
+    from benchmark import harness
+
+    walls = [w for _, w in clock["step_walls"]]
+    left, drained = counts["backlog_left"], counts["drained_at_s"]
+    drained = {} if left or drained is None else {"drained_at_s": drained}
+    ctx.say(stage="window", window_s=counts["window_s"],
+            steps=len(walls), step_ms_p50=1e3 * harness.median(walls),
+            tokens_delivered=counts["tokens_delivered"],
+            requests_submitted=counts["requests_submitted"],
+            requests_finished=counts["requests_finished"], failed=failed,
+            backlog_left=left, **drained,
+            itl_ms_p50=counts["itl_ms_p50"], ttft_ms_p50=counts["ttft_ms_p50"],
+            gen_late_ms_p95=harness.quantile(counts["gen_late_ms"], 0.95),
+            queue_depth_first=counts["queue_depth_first"],
+            queue_depth_last=counts["queue_depth_last"],
+            slowest_steps_at_s_ms=[
+                [round(t - clock["t0"], 2), round(1e3 * w, 1)] for t, w in
+                sorted(clock["step_walls"], key=lambda r: -r[1])[:3]],
+            values=values, compiles_in_window=compiles_in_window,
+            peak_bytes_in_use=(ctx.devices[0].memory_stats() or {}).get(
+                "peak_bytes_in_use"), **more)
+    if drained:
+        print(f"benchmark: {ctx.cell.name} drained its backlog "
+              f"{drained['drained_at_s']:.1f} s into a window of "
+              f"{ctx.seconds:g} s; benchmark/traffic/"
+              f"{ctx.cell.traffic_name}.json needs more than "
+              f"{len(arrivals)} arrivals.requests", file=sys.stderr)
+    counts["buckets"] = list(engine.buckets)
+    counts["slots"] = engine.num_slots
+    counts["page_size"] = engine.page_size
+    counts["first_delivery"] = {
+        i: deliveries[clock["rid_of"][i]][0][0]
+        for i in range(clock["submitted"])
+        if clock["rid_of"][i] in deliveries}
+    counts["prompt_tokens"] = [len(a.prompt) for a in arrivals]
+    counts["traced"] = ctx.traced
+    counts["traced_from_s"] = ctx.traced and ctx.traced[0] - clock["t0"]
+    counts["sizes"] = sz
+    span = ctx.traced or (clock["t0"], clock["t1"])
+    counts["mapped_positions"] = mapped_positions(
+        arrivals, clock, deliveries, 0.5 * (span[0] + span[1]))
 
 
 def check_outputs(ctx, config, ref, sz, arrivals, clock, finished,
@@ -283,7 +352,7 @@ def run(ctx):
             num_pages=engine.pool.num_pages, slots=engine.num_slots)
     arrivals = traffic.requests(mix, ctx.seed, ctx.seconds, sz["vocab"],
                                 engine.max_len)
-    warm = warm_up(ctx, engine, sched, mix, sz, arrivals)
+    warm = warm_up(ctx, engine, sched, mix, sz)
     mem = {name: harness.program_bytes(traced.lower().compile())
            for name, traced in engine.trace_programs().items()
            if name == "decode"}
@@ -302,39 +371,11 @@ def run(ctx):
     invariants = bool(engine.check_invariants())
     program = mem["decode"]["arguments"] + mem["decode"]["temp"]
     peak = harness.memory_peak_bytes(ctx.devices[:1], program)
-    walls = [w for _, w in clock["step_walls"]]
-    ctx.say(stage="window", window_s=counts["window_s"],
-            steps=len(walls), step_ms_p50=1e3 * harness.median(walls),
-            tokens_delivered=counts["tokens_delivered"],
-            requests_submitted=counts["requests_submitted"],
-            requests_finished=counts["requests_finished"], failed=failed,
-            itl_ms_p50=counts["itl_ms_p50"], ttft_ms_p50=counts["ttft_ms_p50"],
-            gen_late_ms_p95=harness.quantile(counts["gen_late_ms"], 0.95),
-            queue_depth_first=counts["queue_depth_first"],
-            queue_depth_last=counts["queue_depth_last"],
-            slowest_steps_at_s_ms=[
-                [round(t - clock["t0"], 2), round(1e3 * w, 1)] for t, w in
-                sorted(clock["step_walls"], key=lambda r: -r[1])[:3]],
-            pages_cached=engine.pool.num_cached, values=values,
-            compiles_in_window=compiles_in_window,
-            peak_bytes_in_use=(ctx.devices[0].memory_stats() or {}).get(
-                "peak_bytes_in_use"))
-    counts["buckets"] = list(engine.buckets)
-    counts["slots"] = engine.num_slots
-    counts["page_size"] = engine.page_size
-    counts["first_delivery"] = {
-        i: deliveries[clock["rid_of"][i]][0][0]
-        for i in range(clock["submitted"])
-        if clock["rid_of"][i] in deliveries}
-    counts["prompt_tokens"] = [len(a.prompt) for a in arrivals]
-    counts["traced"] = ctx.traced
-    counts["sizes"] = sz
+    say_window(ctx, engine, clock, counts, deliveries, arrivals, sz, values,
+               failed, compiles_in_window, pages_cached=engine.pool.num_cached)
     # tokens as the client got them (the stream), not the outcome's copy
     delivered_tokens = {rid: list(st.delivered)
                         for rid, st in sched.streams.streams.items()}
-    span = ctx.traced or (clock["t0"], clock["t1"])
-    counts["mapped_positions"] = mapped_positions(
-        arrivals, clock, deliveries, 0.5 * (span[0] + span[1]))
 
     # -- free the server, then the reference judges what it served ----------
     del engine, sched
@@ -353,9 +394,9 @@ def run(ctx):
                      "position of the same prompts and served tokens, "
                      "judged by the float32 reference",
                 numbers=harness.comparison(c_rows)[1])
-    return {"correct": ok, "attempted": counts["requests_attempted"],
-            "failed": failed, "values": values, "memory_peak_bytes": peak,
-            "counts": counts}
+    return {"correct": ok, "numbers": numbers,
+            "attempted": counts["requests_attempted"], "failed": failed,
+            "values": values, "memory_peak_bytes": peak, "counts": counts}
 
 
 def mapped_positions(arrivals, clock, deliveries, at: float) -> int:

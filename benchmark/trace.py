@@ -171,17 +171,23 @@ class Host:
 
 
 class Reduced:
-    def __init__(self, chips, host):
+    """``chips``: the device planes on which something ran. With
+    ``may_be_empty`` (a cell that serves requests) there may be none: the
+    window is then what the benchmark's own host spans cover, the device
+    idled all through it, and whatever asks for device work finds none."""
+
+    def __init__(self, chips, host, may_be_empty: bool = False):
         self.chips = chips
         self.host = host
         spans = [c.span for c in chips if c.span]
-        if not spans:
+        if not spans and not (may_be_empty and host.bench):
             raise ValueError("no operation ran on a device in this trace")
         lo = min([s for s, _ in spans] + [b[1] for b in host.bench])
         hi = max([e for _, e in spans] + [b[2] for b in host.bench])
         self.window = (lo, hi)
         self.window_s = hi - lo
-        self.busy_s = float(np.mean([_measure(c.busy) for c in chips]))
+        self.busy_s = float(np.mean([_measure(c.busy) for c in chips])
+                            ) if chips else 0.0
 
     # -- what the metric readers ask ------------------------------------
     def idle_pct(self) -> float:
@@ -203,7 +209,7 @@ class Reduced:
 
     def kernel_time(self, match) -> tuple:
         """(seconds, calls), averaged over the chips."""
-        rows = [c.kernel_time(match) for c in self.chips]
+        rows = [c.kernel_time(match) for c in self.chips] or [(0.0, 0)]
         return (float(np.mean([r[0] for r in rows])),
                 int(round(np.mean([r[1] for r in rows]))))
 
@@ -231,6 +237,8 @@ class Reduced:
     def custom_calls(self) -> dict:
         """Every ``custom-call`` (a Pallas kernel, on this chip) with its
         calls and seconds on the first chip: for looking at by hand."""
+        if not self.chips:
+            return {}
         c = self.chips[0]
         return {short(n, 160): [c.op_count[n], c.op_time[n]]
                 for n in c.op_time if " custom-call(" in _LAYOUT.sub("", n)}
@@ -240,9 +248,8 @@ class Reduced:
 
     def idle_gaps(self):
         """(start, end) of the first chip's idle gaps inside the window."""
-        c = self.chips[0]
         whole = np.asarray([self.window], float)
-        gaps = _subtract(whole, c.busy)
+        gaps = _subtract(whole, self.chips[0].busy) if self.chips else whole
         return gaps[(gaps[:, 1] - gaps[:, 0]) >= MIN_GAP_S]
 
     def breakdown(self) -> dict:
@@ -269,7 +276,7 @@ def find(trace_dir: str) -> str:
     return paths[-1]
 
 
-def reduce_file(path: str) -> Reduced:
+def reduce_file(path: str, may_be_empty: bool = False) -> Reduced:
     """Reduce one ``.xplane.pb`` (or ``.xplane.pb.gz``: the recorded trace
     the tests read is kept compressed)."""
     from jax.profiler import ProfileData
@@ -283,8 +290,8 @@ def reduce_file(path: str) -> Reduced:
     planes = list(data.planes)
     chips = [Chip(p) for p in planes if p.name.startswith("/device:TPU:")]
     chips = [c for c in chips if c.span]
-    return Reduced(chips, Host(planes))
+    return Reduced(chips, Host(planes), may_be_empty)
 
 
-def reduce_dir(trace_dir: str) -> Reduced:
-    return reduce_file(find(trace_dir))
+def reduce_dir(trace_dir: str, may_be_empty: bool = False) -> Reduced:
+    return reduce_file(find(trace_dir), may_be_empty)

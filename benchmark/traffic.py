@@ -7,10 +7,13 @@ Two kinds of mix:
     token ids uniform over the vocabulary, every row full length.
 ``"kind": "requests"`` generation requests with the times they are due.
 
-Every seed gets the same multiset of sizes and arrival gaps, in another
-order, and other token contents: the sizes and gaps are drawn from the
-mix's own ``sizes_seed`` and only permuted by ``--seed``, so that two seeds
-differ in placement and not in the amount of work.
+Every seed gets the same multiset of sizes and arrival gaps and other token
+contents: the sizes and gaps are drawn from the mix's own ``sizes_seed``, so
+that two seeds never differ in the amount of work. An open loop (``poisson``)
+gets them in another order on each ``--seed``. A ``backlog`` gets them in ONE
+order on every seed: a backlog has to outlast the window, so the window holds
+only the head of the list, and a head that ``--seed`` chose moved
+``serve_tokens_per_s`` by 2-5% from seed to seed (PERF.md, section 6, PR 27).
 """
 
 import dataclasses
@@ -85,6 +88,19 @@ def _draw(spec: dict, rng, n: int) -> np.ndarray:
     return np.rint(x).astype(np.int64)
 
 
+def backlog_order(prompt_len, new_tokens, opens, sizes_seed: int):
+    """The one order a backlog's sizes come in, whatever ``--seed`` is: the
+    requests sorted by (prompt length, ``max_new_tokens``, shared prefix),
+    which forgets the order they were drawn in, then permuted from the mix's
+    own ``sizes_seed``, so that any head of the list is a sample of the
+    whole mix. Request ``i`` has the same sizes, the same shared prefix and
+    (the temperatures alternate by position) the same temperature on every
+    seed; ``--seed`` gives the token contents and the samplers' seeds."""
+    n = len(prompt_len)
+    by_size = np.lexsort((opens, new_tokens, prompt_len))
+    return by_size[seeded(n, sizes_seed, 5).permutation(n)]
+
+
 def how_many(mix: dict, seconds: float) -> int:
     arr = mix["arrivals"]
     if arr["process"] == "backlog":
@@ -115,21 +131,20 @@ def requests(mix: dict, seed: int, seconds: float, vocab_size: int,
     arr = mix["arrivals"]
     if arr["process"] == "backlog":
         due = np.zeros(n)
+        perm = backlog_order(prompt_len, new_tokens, opens,
+                             int(mix["sizes_seed"]))
     elif arr["process"] == "poisson":
         # n exponential gaps scaled to fill the window exactly: the same
-        # gaps for every seed, permuted below
+        # gaps for every seed, permuted like the sizes
         gaps = sizes.exponential(1.0, size=n)
         gaps *= seconds / gaps.sum() * n / (n + 1)
-        due = None
+        order = seeded(seed, 1)
+        perm = order.permutation(n)
+        due = np.cumsum(gaps[order.permutation(n)])
     else:
         raise ValueError(f"unknown arrival process {arr['process']!r}")
-
-    order = seeded(seed, 1)
-    perm = order.permutation(n)
     prompt_len, new_tokens, opens = (prompt_len[perm], new_tokens[perm],
                                      opens[perm])
-    if due is None:
-        due = np.cumsum(gaps[order.permutation(n)])
 
     content = seeded(seed, 2)
     lo = int(mix.get("token_lo", 2))

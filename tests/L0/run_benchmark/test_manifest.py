@@ -67,7 +67,10 @@ def test_config_entry_and_its_files(config):
     assert config["file"].startswith("benchmark/configs/")
     body = json.load(open(os.path.join(REPO, config["file"])))
     assert body["source"] == config["source"]
-    assert body["reduced"] == config["reduced"] == []
+    # a configuration cut in depth says so in both places, in the same words
+    assert body["reduced"] == config["reduced"]
+    assert len(config["reduced"]) <= 16
+    assert all(NAME.match(key) and key in body for key in config["reduced"])
     for kind in ("runners/" + body["runner"], "reference/" + config["name"]):
         assert os.path.exists(os.path.join(REPO, "benchmark", kind + ".py"))
     assert any(w["config"] == config["name"] for w in MANIFEST["workloads"])
@@ -114,14 +117,38 @@ def test_result_line_holds_exactly_the_contract_keys():
         device=device,
         breakdown={"device_ops": [["a", 1.0]] * 12, "idle_gaps": []}))
     assert list(line) == ["correct", "attempted", "failed", "metrics",
-                          "device", "breakdown"]
+                          "device", "breakdown", "compared"]
+    assert line["compared"] == {}
     assert line["metrics"] == {"setup_s": {"value": 1.0, "unit": "s"}}
     assert line["device"] == device
     assert len(line["breakdown"]["device_ops"]) == 10
     plain = json.loads(harness.result_line(
         correct=False, attempted=1, failed=1, metrics={},
         device={k: device[k] for k in harness.DEVICE_KEYS}))
-    assert list(plain) == list(harness.RESULT_KEYS)
+    assert list(plain) == [*harness.RESULT_KEYS, "compared"]
     with pytest.raises(harness.BenchmarkError):
         harness.result_line(correct=True, attempted=1, failed=0, metrics={},
                             device={"platform": "tpu"})
+
+
+def test_each_number_compared_stands_beside_its_limit(capsys):
+    """In the result line under a key of its own that comes last, and as the
+    last lines of standard error."""
+    from benchmark import harness
+
+    ok, numbers = harness.comparison([("gap_max", 0.25, 0.1),
+                                      ("compiles_in_window", 0, 0),
+                                      ("gap_mean", float("nan"), 1.0)])
+    assert not ok and [n["ok"] for n in numbers] == [False, True, False]
+    line = harness.result_line(
+        correct=ok, attempted=3, failed=0, metrics={}, numbers=numbers[:2],
+        device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                "memory_peak_bytes": 1})
+    assert list(json.loads(line))[-1] == "compared"
+    assert json.loads(line)["compared"] == {
+        "gap_max": {"value": 0.25, "limit": 0.1},
+        "compiles_in_window": {"value": 0, "limit": 0}}
+    harness.say_compared(numbers[:2])
+    assert capsys.readouterr().err.splitlines() == [
+        "compared gap_max = 0.25 (limit 0.1) NOT CORRECT",
+        "compared compiles_in_window = 0 (limit 0)"]

@@ -21,11 +21,14 @@ CELLS = [w["name"] for w in json.load(open(
     os.path.join(REPO, "BENCHMARK.json")))["workloads"] if w["chips"] == 1]
 
 
-def rehearse(capsys, workload, *extra):
+def rehearse(capsys, workload, *extra, stderr=None):
     rc = bench_run.main(["--workload", workload, "--seed", str(2 ** 31 + 7),
                          "--seconds", "2", "--trace", "0", "--cpu-rehearsal",
                          *extra])
-    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+    captured = capsys.readouterr()
+    if stderr is not None:
+        stderr.append(captured.err)
+    lines = [json.loads(l) for l in captured.out.splitlines()
              if l.startswith("{")]
     assert rc == 0
     # every line says it is a rehearsal on the CPU, and none is a result
@@ -46,9 +49,44 @@ def test_cell_rehearses_end_to_end(capsys, workload):
     assert set(result["metrics"]) == {m["name"] for m in cell.end_to_end}
     assert all(m["value"] > 0 for m in result["metrics"].values())
     numbers = [l for l in lines if l.get("stage") == "correct"][0]["numbers"]
-    # each number compared is printed beside its limit
+    # each number compared is printed beside its limit, and stands in the
+    # result under a key of its own that comes last
     assert all({"number", "value", "limit", "ok"} <= set(n) for n in numbers)
     assert {"compiles_in_window"} <= {n["number"] for n in numbers}
+    assert list(result)[-1] == "compared" and result["compared"] == {
+        n["number"]: {"value": n["value"], "limit": n["limit"]}
+        for n in numbers}
+
+
+SERVING = [w for w in CELLS if bench_run.harness.Cell(w).traffic["kind"]
+           == "requests"]
+
+
+@pytest.mark.parametrize("workload", SERVING)
+def test_window_line_says_what_is_left_of_the_backlog(capsys, workload):
+    """``backlog_left`` always; where the window drained the backlog (the
+    tiny rehearsal backlogs of the two GPT cells; the real ones outlast the
+    window twice over) also ``drained_at_s``, and a line on standard error
+    that names the traffic file to give more requests."""
+    said = []
+    result, lines = rehearse(capsys, workload, stderr=said)
+    assert SERVING == ["gpt2_medium.offline_decode",
+                       "gpt2_medium.prompt_backlog",
+                       "olmo_hybrid_7b.long_prompt_decode"]
+    [window] = [l for l in lines if l.get("stage") == "window"]
+    cell = bench_run.harness.Cell(workload)
+    offered = cell.traffic["rehearsal"]["arrivals"]["requests"]
+    assert window["requests_submitted"] == offered
+    assert 0 <= window["backlog_left"] <= offered - window[
+        "requests_finished"]
+    [err] = said
+    if window["backlog_left"]:
+        assert "drained_at_s" not in window and "drained" not in err
+    else:
+        assert 0.0 < window["drained_at_s"] <= 2.0
+        assert (f"benchmark/traffic/{cell.traffic_name}.json needs more "
+                f"than {offered} arrivals.requests") in err
+    assert result["correct"] is True and window["compiles_in_window"] == 0
 
 
 def test_training_step_that_returns_its_state_unchanged_is_not_correct(

@@ -54,6 +54,51 @@ def test_collectives_are_told_from_other_ops():
     assert not trace._COLLECTIVE.search("%fusion.7 = bf16[8] fusion(%a)")
 
 
+def host_only(spans):
+    """A ``trace.Host`` that holds the benchmark's spans and nothing else."""
+    host = trace.Host([])
+    host.bench = list(spans)
+    return host
+
+
+@pytest.mark.parametrize("kind", ["requests", "batches"])
+def test_a_trace_without_device_work_is_a_reading_only_for_a_server(kind):
+    """A server whose backlog drained before the traced span idled all
+    through it: idle 100%, every reader of device work finds nothing, and
+    the gap carries the name of what the host did. A training run without a
+    device operation is a broken run."""
+    host = host_only([("idle_no_request", 10.0, 10.05),
+                      ("idle_no_request", 10.05, 14.0)])
+    if kind == "batches":
+        with pytest.raises(ValueError, match="no operation ran on a device"):
+            trace.Reduced([], host, may_be_empty=False)
+        return
+    reduced = trace.Reduced([], host, may_be_empty=True)
+    assert reduced.idle_pct() == 100.0 and reduced.busy_s == 0.0
+    assert reduced.window == (10.0, 14.0) and reduced.window_s == 4.0
+    assert reduced.program_times("jit_decode") == []
+    assert reduced.kernel_time(lambda n: True) == (0.0, 0)
+    assert reduced.programs_summary() == {} == reduced.custom_calls()
+    assert reduced.idle_gaps().tolist() == [[10.0, 14.0]]
+    assert reduced.breakdown() == {
+        "device_ops": [], "idle_gaps": [("idle_no_request", 4.0)]}
+    bench = os.path.join(REPO, "benchmark")
+    run = {"trace": reduced, "apex_spans": [], "peaks": {}, "cell": None,
+           "counts": {"sizes": {"layers": 24}, "traced": (10.0, 14.0),
+                      "first_delivery": {}, "prompt_tokens": [],
+                      "buckets": [128], "mapped_positions": 0}}
+    manifest = harness.load_json(REPO, "BENCHMARK.json")
+    got = {m["name"]: harness.load_module("metrics", m["name"], bench).read(
+        run) for m in manifest["per_layer"]
+        if "gpt2_medium.prompt_backlog" in m["workloads"]
+        and m["name"] != "sched_step_ms.serve"}
+    assert got.pop("device_idle_pct.serve") == 100.0
+    assert got and all(v is None for v in got.values()), got
+    # not even a span of the benchmark's own: nothing was traced at all
+    with pytest.raises(ValueError, match="no operation ran on a device"):
+        trace.Reduced([], host_only([]), may_be_empty=True)
+
+
 @pytest.fixture(scope="module")
 def recorded():
     return trace.reduce_file(RECORDED)

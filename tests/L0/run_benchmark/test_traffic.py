@@ -1,5 +1,6 @@
 """The traffic generator: seeded, and the same amount of work for every
-seed; latencies of the open loop count from when a request was due."""
+seed (a backlog: the same work in the same order); latencies of the open
+loop count from when a request was due."""
 
 import collections
 import json
@@ -31,15 +32,74 @@ def test_requests_are_deterministic_in_the_seed(name):
 
 @pytest.mark.parametrize("name", ["offline_decode", "serve_prompts"])
 def test_every_seed_gets_the_same_sizes_in_another_order(name):
+    """... where the window takes every request (the open loop). A backlog
+    outlasts its window, and gets them in the same order."""
     a = traffic.requests(mix(name), 1, 30.0, 50257, 1024)
     b = traffic.requests(mix(name), BIG, 30.0, 50257, 1024)
     sizes = lambda rs: sorted((len(r.prompt), r.max_new_tokens,
                                r.shared_prefix is not None) for r in rs)
     assert sizes(a) == sizes(b)
-    assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
+    same_order = [len(r.prompt) for r in a] == [len(r.prompt) for r in b]
+    assert same_order == (mix(name)["arrivals"]["process"] == "backlog")
     gaps = lambda rs: sorted(np.round(np.diff([0.0] + [r.due_s for r in rs]),
                                       9))
     assert gaps(a) == gaps(b)
+
+
+BACKLOGS = sorted(
+    f[:-5] for f in os.listdir(os.path.join(REPO, "benchmark", "traffic"))
+    if f.endswith(".json") and mix(f[:-5]).get("arrivals", {}).get(
+        "process") == "backlog")
+
+
+def test_the_backlog_mixes_are_the_three_serving_cells():
+    assert BACKLOGS == ["long_prompt_decode", "offline_decode",
+                        "prompt_backlog"]
+
+
+@pytest.mark.parametrize("rehearsal", [False, True])
+@pytest.mark.parametrize("name", BACKLOGS)
+def test_a_backlog_offers_one_order_of_work_on_every_seed(name, rehearsal):
+    """A window samples the HEAD of a backlog it cannot drain: request ``i``
+    has the same prompt length, shared prefix, ``max_new_tokens`` and
+    temperature on every seed, and other token contents."""
+    from benchmark.harness import rehearsal_view
+
+    m = rehearsal_view(mix(name)) if rehearsal else mix(name)
+    a = traffic.requests(m, 11, 30.0, 50257, 4096)
+    b = traffic.requests(m, BIG, 30.0, 50257, 4096)
+    work = lambda rs: [(len(r.prompt), r.max_new_tokens, r.temperature,
+                        r.shared_prefix) for r in rs]
+    assert work(a) == work(b) and len(a) == m["arrivals"]["requests"]
+    assert {r.prompt for r in a}.isdisjoint(r.prompt for r in b)
+    assert len({r.seed for r in a} | {r.seed for r in b}) > len(a)
+    temps = m["temperatures"]
+    assert [r.temperature for r in a[:4]] == (temps * 4)[:4]
+    # no sorted order: any head of the list is a sample of the whole mix
+    head = [len(r.prompt) for r in a[:max(len(a) // 4, 4)]]
+    assert min(head) < sum(len(r.prompt) for r in a) / len(a) < max(head)
+    if m.get("shared_prefix"):
+        every = m["shared_prefix"]["one_request_in"]
+        shared = [r.shared_prefix is not None for r in a]
+        assert abs(sum(shared) - len(a) / every) <= 1
+        first = max(len(a) // 4, 2 * every)
+        assert 0 < sum(shared[:first]) < first
+        heads = {r.shared_prefix: r.prompt[:m["shared_prefix"]["tokens"]]
+                 for r in a if r.shared_prefix is not None}
+        assert all(r.prompt[:m["shared_prefix"]["tokens"]]
+                   == heads[r.shared_prefix]
+                   for r in a if r.shared_prefix is not None)
+
+
+def test_the_two_gpt_backlogs_outlast_a_server_twice_as_fast():
+    """What the server finishes in a window today (PERF.md, section 5: about
+    30 requests a second of ``prompt_backlog``, about 95,000 tokens of
+    ``offline_decode``), twice over."""
+    assert mix("prompt_backlog")["arrivals"]["requests"] >= 2 * 30 * 30
+    offline = traffic.requests(mix("offline_decode"), 1, 30.0, 50257, 1024)
+    assert sum(r.max_new_tokens for r in offline) >= 2 * 95_000
+    assert mix("prompt_backlog")["trace_start_s"] == 2.0
+    assert mix("prompt_backlog")["trace_seconds"] == 4.0
 
 
 def test_offline_backlog_is_all_due_at_once_and_fits_the_cache():
@@ -98,7 +158,8 @@ def test_open_loop_latency_counts_from_the_due_time():
     clock = {"t0": t0, "t1": t0 + 10.5, "end": t0 + 10.0,
              "rid_of": {0: 0, 1: 1}, "submitted": 2,
              "submitted_at": {0: t0 + 1.4, 1: t0 + 2.0},
-             "step_walls": [(t0, 0.5)], "queue_depth": [1]}
+             "step_walls": [(t0, 0.5)], "queue_depth": [1],
+             "backlog_left": 0, "drained_at_s": None}
     deliveries = {0: [(t0 + 2.0, 1), (t0 + 2.5, 1), (t0 + 3.5, 1)]}
     Out = collections.namedtuple("Out", "tokens error")
     sched = type("S", (), {"outcomes": {0: Out((1, 2, 3), None)}})
@@ -114,3 +175,4 @@ def test_open_loop_latency_counts_from_the_due_time():
         1e3 * (0.5 + 0.95 * 0.5))
     assert values["serve_tokens_per_s"] == pytest.approx(3 / 10.0)
     assert values["setup_s"] == pytest.approx(10.0)
+    assert (counts["backlog_left"], counts["drained_at_s"]) == (0, None)
