@@ -514,6 +514,32 @@ def _gated_delta_cfg(which):
     return build
 
 
+def _nemotron_kernel_cfg(which):
+    """The two kernels of the ``nemotron_h`` family at the published widths.
+    ``ssd_step``: the stacked Mamba-2 state of 5 layers x 128 slots (128
+    heads of 64 x 128) stays where it is; resident are 64 heads of one slot,
+    in and out. ``gmm``: the grouped expert product over 128 held experts of
+    1024 x 2688, one decode tick's 128 x 22 assignments; resident are a row
+    tile, a column tile of one expert's matrix and the output tile."""
+    def build():
+        import functools as ft
+
+        if which == "ssd_step":
+            from apex_tpu.transformer.functional.ssd import ssd_step
+            return ssd_step, (
+                _sds((128, 128, 64), "float32"), _sds((128, 128), "float32"),
+                _sds((128,), "float32"), _sds((128, 8, 128), "float32"),
+                _sds((128, 8, 128), "float32"),
+                _sds((5, 128, 128, 64, 128), "float32"), _sds((), "int32"),
+                _sds((128,), "bool"))
+        from apex_tpu.transformer.functional.moe import grouped_matmul
+        return ft.partial(grouped_matmul, activation="relu2"), (
+            _sds((2816, 1024), "bfloat16"),
+            _sds((128, 1024, 2688), "bfloat16"), _sds((128,), "int32"))
+
+    return build
+
+
 def _draft_forward_cfg():
     """The model drafter's per-token forward (``draft_gpt_tiny`` over
     its dense lockstep cache): XLA math today, so — like the paged
@@ -582,6 +608,12 @@ def repo_configs() -> List[Config]:
             f"gated_delta_{which}_7b",
             "apex_tpu.transformer.functional.gated_delta",
             _gated_delta_cfg(which)))
+    cfgs.append(Config("ssd_step_120b",
+                       "apex_tpu.transformer.functional.ssd",
+                       _nemotron_kernel_cfg("ssd_step")))
+    cfgs.append(Config("moe_gmm_120b",
+                       "apex_tpu.transformer.functional.moe",
+                       _nemotron_kernel_cfg("gmm")))
     cfgs.append(Config("gpt_spec_verify_step", "apex_tpu.serving.decode",
                        _paged_serving_cfg("verify")))
     cfgs.append(Config("gpt_tree_verify_step", "apex_tpu.serving.decode",

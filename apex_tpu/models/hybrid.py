@@ -29,9 +29,11 @@ and the static index into that slice is a second slice, which the compiler
 materialises, 1.3 GB a period at 7 B widths.)
 
 This file holds the blocks, once for a whole (bucket-padded) prompt and once
-for one token per slot against the serving cache; ``serving.decode`` builds
-the server's two programs from them, and :func:`apply_hybrid` is the whole
-forward with no cache (the tests' middle term between the two).
+for one token per slot against the serving cache, and the two halves the
+serving engine builds its programs from (:meth:`HybridConfig.prefill_core`,
+:meth:`HybridConfig.decode_core`: the seam ``models.nemotron_h`` stands on
+too); :func:`apply_hybrid` is the whole forward with no cache (the tests'
+middle term between the two).
 """
 
 import dataclasses
@@ -106,6 +108,28 @@ class HybridConfig:
     def conv_channels(self) -> int:
         return self.linear_heads * (2 * self.linear_key_dim
                                     + self.linear_value_dim)
+
+    # -- what the serving engine asks (the seam, with models.nemotron_h) ----
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers of the page pool: the full-attention layers."""
+        return self.num_full_layers
+
+    @property
+    def kv_row_width(self) -> int:
+        """Width of one cached K (or V) row."""
+        return self.num_heads * self.head_dim
+
+    def prefill_core(self, params, ids, mask, kv_dtype):
+        return prefill_layers(params, self, embed(params, ids), mask,
+                              kv_dtype)
+
+    def decode_core(self, params, cache, tokens, active):
+        return decode_layers(params, self, cache, tokens, active)
+
+    def logits_of(self, params, x):
+        return logits_of(params, self, x)
 
     def state_shapes(self, num_slots: int) -> Tuple[Tuple[int, ...], ...]:
         """(recurrent state, convolution tail) of ``num_slots`` slots."""
@@ -391,6 +415,37 @@ def prefill_layers(params, cfg: HybridConfig, x, mask,
     x, (states, tails, k, v) = lax.scan(period, x, params["periods"])
     return (x, states.reshape(-1, *states.shape[2:]),
             tails.reshape(-1, *tails.shape[2:]), k, v)
+
+
+def decode_layers(params, cfg: HybridConfig, cache, tokens, active):
+    """One token for every slot against the serving cache
+    (``serving.cache.HybridKVCache``), scanned by period: each linear layer
+    steps its layer of the stacked recurrent state in place
+    (``apex_gdn_decode_fwd``; the state and the convolution tails are carries
+    of the scan, never copied), each full layer attends over the pool in
+    place. Returns ``(x (slots, hidden), state', conv', counters' (none
+    here), k_rows, v_rows (full layers, slots, heads * head_dim))`` for the
+    engine to write."""
+    pos = cache.lengths
+    bt = cache.block_tables
+    x = embed(params, tokens)
+    n = cfg.linear_per_period
+
+    def period(carry, pp_at):
+        x, state, conv = carry
+        pp, at = pp_at
+        for j, lp in enumerate(pp["linear"]):
+            x, state, conv = linear_block_decode(
+                lp, x, cfg, state, conv, at * n + j, active)
+        x, k_row, v_row = full_block_decode(
+            pp["full"], x, cfg, cache.k, cache.v, at, bt, pos)
+        return (x, state, conv), (k_row, v_row)
+
+    (x, state, conv), (k_rows, v_rows) = lax.scan(
+        period, (x, cache.state, cache.conv),
+        (params["periods"],
+         jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
+    return x, state, conv, None, k_rows, v_rows
 
 
 def apply_hybrid(params, cfg: HybridConfig, ids):

@@ -90,8 +90,9 @@ RESERVED_PAGES = 2
 
 class PagedKVCache(NamedTuple):
     """Paged layout: ``k``/``v`` hold a POOL of fixed-size pages shared
-    by every slot — ``(L, num_pages, page_size, num_heads * head_dim)``,
-    a page's rows holding all heads side by side (head-major), so one
+    by every slot — ``(L, num_pages, page_size, kv_heads * head_dim)``
+    (``kv_heads`` = ``num_heads`` for the GPT family),
+    a page's rows holding all K/V heads side by side (head-major), so one
     page of one layer is one contiguous run of whole (sublane, 128-lane)
     tiles that the decode kernel fetches by DMA — and ``block_tables``
     (``(num_slots, max_pages)`` int32) maps each slot's logical page
@@ -125,7 +126,8 @@ class PagedKVCache(NamedTuple):
 
 class HybridKVCache(NamedTuple):
     """The cache of a model with recurrent layers (``cfg.recurrent``:
-    ``apex_tpu.models.hybrid``): two kinds of state in one donated tuple.
+    ``apex_tpu.models.hybrid``, ``apex_tpu.models.nemotron_h``): two kinds of
+    state in one donated tuple.
     ``k`` / ``v`` / ``lengths`` / ``block_tables`` are :class:`PagedKVCache`'s,
     the pool's ``L`` counting the full-attention layers only, and the host
     side (``PagePool``, block tables, the page copy) treats them alike.
@@ -133,13 +135,18 @@ class HybridKVCache(NamedTuple):
     (the last ``w - 1`` inputs of each linear layer's convolution) are PER
     SLOT and outside the pages: whole at every moment, they cannot be
     paged, shared by prefix or rolled back by rewriting rows; a slot's
-    prefill overwrites them and nothing else resets them."""
-    k: jax.Array             # (L_full, num_pages, page_size, heads * hd)
+    prefill overwrites them and nothing else resets them. ``counters`` (a
+    dict of int32 arrays, or nothing) are what a model's decode program
+    counts on the device (``cfg.counter_shapes``): they ride the donated
+    tuple so that no tick gains a read-back, and are read on request
+    (``PagedDecodeEngine.read_counters``)."""
+    k: jax.Array             # (L_attn, num_pages, page_size, kv_heads * hd)
     v: jax.Array
     lengths: jax.Array       # (num_slots,) int32
     block_tables: jax.Array  # (num_slots, max_pages) int32
-    state: jax.Array         # (L_lin, slots, H, d_k, d_v) float32
-    conv: jax.Array          # (L_lin, slots, w - 1, channels) float32
+    state: jax.Array         # (L_rec, slots, heads, ...) float32
+    conv: jax.Array          # (L_rec, slots, w - 1, channels) float32
+    counters: Optional[dict] = None
 
     # no quantized pool beside recurrent state (the engine refuses it)
     k_scale = None
@@ -196,16 +203,18 @@ def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
 
 def init_hybrid_cache(cfg, num_slots: int, max_len: int, num_pages: int,
                       page_size: int, dtype=jnp.bfloat16) -> HybridKVCache:
-    """The two kinds of state of a ``models.hybrid.HybridConfig``: a page
-    pool over the full-attention layers only, and zeroed per-slot recurrent
-    state and convolution tails (float32 both, whatever the pool's
-    ``dtype``) for the linear layers."""
+    """The two kinds of state of a model with recurrent layers (what
+    ``cfg`` states: ``serving.decode``, "the seam"): a page pool over the
+    attention layers only, rows of ``kv_row_width``, and zeroed per-slot
+    recurrent state and convolution tails (float32 both, whatever the
+    pool's ``dtype``) for the recurrent layers; zeroed counters where the
+    model keeps any."""
     _check_pool_sizes(num_slots, max_len, num_pages, page_size)
     if jnp.dtype(dtype) == jnp.int8:
         raise ValueError("no int8 pool beside recurrent state")
-    shape = (cfg.num_full_layers, num_pages, page_size,
-             cfg.num_heads * cfg.head_dim)
+    shape = (cfg.kv_layers, num_pages, page_size, cfg.kv_row_width)
     state, conv = cfg.state_shapes(num_slots)
+    counters = getattr(cfg, "counter_shapes", lambda: None)()
     return HybridKVCache(
         k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
         lengths=jnp.zeros((num_slots,), jnp.int32),
@@ -213,7 +222,9 @@ def init_hybrid_cache(cfg, num_slots: int, max_len: int, num_pages: int,
             (num_slots, max_pages_per_slot(max_len, page_size)),
             SCRATCH_PAGE, jnp.int32),
         state=jnp.zeros(state, jnp.float32),
-        conv=jnp.zeros(conv, jnp.float32))
+        conv=jnp.zeros(conv, jnp.float32),
+        counters=counters and {name: jnp.zeros(shape, jnp.int32)
+                               for name, shape in counters.items()})
 
 
 def audit_block_tables(block_tables, slot_pages) -> bool:

@@ -808,19 +808,32 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
 
 
 # ---------------------------------------------------------------------------
-# a model with recurrent layers (models.hybrid): the same two programs
+# a model with recurrent layers: the same two programs, for every family
 # ---------------------------------------------------------------------------
+#
+# The seam (ROADMAP D11). A config whose ``recurrent`` is true states, and
+# the engine takes here and nowhere else:
+#   ``kv_layers``, ``kv_row_width``        the page pool's leading axis and
+#                                          row (the attention layers only)
+#   ``state_shapes(slots)``                (recurrent state, convolution
+#                                          tail) shapes, float32, per slot
+#   ``state_bytes_per_slot()``             what a prefill writes besides pages
+#   ``counter_shapes()`` (optional)        int32 counters kept in the cache
+#   ``prefill_core(params, ids, mask, kv_dtype)`` -> (x (s, hidden), states,
+#       tails (recurrent layers, ...), k, v (kv_layers, s, kv_row_width))
+#   ``decode_core(params, cache, tokens, active)`` -> (x (slots, hidden),
+#       state', conv', counters', k_rows, v_rows (kv_layers, slots, width))
+#   ``logits_of(params, x)``               final norm and head
+# ``models.hybrid`` and ``models.nemotron_h`` stand on it.
 
-def _hybrid_prefill_core(params, cfg, cache: HybridKVCache, ids, mask, slot,
-                         write_pages, table_row):
-    """:func:`_paged_prefill_core` for a ``HybridConfig``: the
-    full-attention layers' K/V rows go to ``write_pages`` exactly as there
-    (the pool's layers are the full layers), and every linear layer's
-    recurrent state and convolution tail, as the prompt's last real token
-    left them, overwrite row ``slot`` of ``cache.state`` / ``cache.conv``:
-    that write is the slot's only reset."""
-    from apex_tpu.models import hybrid
-
+def _recurrent_prefill_core(params, cfg, cache: HybridKVCache, ids, mask,
+                            slot, write_pages, table_row):
+    """:func:`_paged_prefill_core` for a model with recurrent layers: the
+    attention layers' K/V rows go to ``write_pages`` exactly as there (the
+    pool's layers are the attention layers), and every recurrent layer's
+    state and convolution tail, as the prompt's last real token left them,
+    overwrite row ``slot`` of ``cache.state`` / ``cache.conv``: that write
+    is the slot's only reset."""
     if ids.ndim != 2 or ids.shape[0] != 1:
         raise ValueError(f"prefill takes one slot's (1, s) ids, got "
                          f"{ids.shape}")
@@ -832,11 +845,11 @@ def _hybrid_prefill_core(params, cfg, cache: HybridKVCache, ids, mask, slot,
     if write_pages.shape != (s // page_size,):
         raise ValueError(f"write_pages {write_pages.shape} != one page "
                          f"per bucket page ({s // page_size},)")
-    x, states, tails, k, v = hybrid.prefill_layers(
-        params, cfg, hybrid.embed(params, ids[0]), mask, cache.k.dtype)
+    x, states, tails, k, v = cfg.prefill_core(params, ids[0], mask,
+                                              cache.k.dtype)
     length = jnp.sum(mask).astype(jnp.int32)
-    logits = hybrid.logits_of(
-        params, cfg, lax.dynamic_slice_in_dim(x, length - 1, 1, 0))
+    logits = cfg.logits_of(
+        params, lax.dynamic_slice_in_dim(x, length - 1, 1, 0))
 
     def pages(t):
         # (L_full, s, width) -> whole pages, the pad tail zeroed
@@ -853,64 +866,48 @@ def _hybrid_prefill_core(params, cfg, cache: HybridKVCache, ids, mask, slot,
         state=lax.dynamic_update_slice(
             cache.state, states[:, None], (0, slot, 0, 0, 0)),
         conv=lax.dynamic_update_slice(
-            cache.conv, tails[:, None], (0, slot, 0, 0))), logits
+            cache.conv, tails[:, None], (0, slot, 0, 0)),
+        # a prefill counts nothing; the donated leaves still need a write
+        counters=jax.tree.map(_self_rewrite, cache.counters)), logits
 
 
-def _hybrid_decode_core(params, cfg, cache: HybridKVCache, tokens, active):
-    """:func:`_paged_decode_core` for a ``HybridConfig``, scanned by
-    period: each linear layer steps its layer of the stacked recurrent
-    state in place (``apex_gdn_decode_fwd``; the state and the convolution
-    tails are carries of the scan, never copied), each full layer attends
-    over the pool in place, and the full layers' new rows go into the pool
-    in one scatter after the scan. Slots that are not ``active`` keep their
-    recurrent state and their length."""
-    from apex_tpu.models import hybrid
-
-    pos = cache.lengths
-    bt = cache.block_tables
-    x = hybrid.embed(params, tokens)
-    n = cfg.linear_per_period
-
-    def period(carry, pp_at):
-        x, state, conv = carry
-        pp, at = pp_at
-        for j, lp in enumerate(pp["linear"]):
-            x, state, conv = hybrid.linear_block_decode(
-                lp, x, cfg, state, conv, at * n + j, active)
-        x, k_row, v_row = hybrid.full_block_decode(
-            pp["full"], x, cfg, cache.k, cache.v, at, bt, pos)
-        return (x, state, conv), (k_row, v_row)
-
-    (x, state, conv), (k_rows, v_rows) = lax.scan(
-        period, (x, cache.state, cache.conv),
-        (params["periods"],
-         jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
-    logits = hybrid.logits_of(params, cfg, x)
+def _recurrent_decode_core(params, cfg, cache: HybridKVCache, tokens, active):
+    """:func:`_paged_decode_core` for a model with recurrent layers: the
+    model steps every recurrent layer's slice of the stacked state in place
+    and attends over the pool in place (``cfg.decode_core``), and the
+    attention layers' new rows go into the pool in one scatter after it.
+    Slots that are not ``active`` keep their recurrent state and their
+    length."""
+    x, state, conv, counters, k_rows, v_rows = cfg.decode_core(
+        params, cache, tokens, active)
+    logits = cfg.logits_of(params, x)
     k, v = _write_new_rows(cache, k_rows, v_rows)
+    pos = cache.lengths
     return cache._replace(
         k=k, v=v, lengths=jnp.where(active, pos + 1, pos),
-        block_tables=_self_rewrite(bt), state=state, conv=conv), logits
+        block_tables=_self_rewrite(cache.block_tables), state=state,
+        conv=conv, counters=counters), logits
 
 
-def make_hybrid_prefill_fn(cfg):
+def make_recurrent_prefill_fn(cfg):
     """jit(prefill) for a model with recurrent layers, cache DONATED (6
     alias pairs: pool k/v, lengths, block tables, recurrent state,
-    convolution tails); one executable per bucket, and the same program
-    name as every other prefill (``jit_prefill``)."""
+    convolution tails; and one per counter); one executable per bucket, and
+    the same program name as every other prefill (``jit_prefill``)."""
 
     def prefill(params, cache, ids, mask, slot, write_pages, table_row):
-        return _hybrid_prefill_core(params, cfg, cache, ids, mask, slot,
-                                    write_pages, table_row)
+        return _recurrent_prefill_core(params, cfg, cache, ids, mask, slot,
+                                       write_pages, table_row)
 
     return jax.jit(prefill, donate_argnums=1)
 
 
-def make_hybrid_decode_fn(cfg):
+def make_recurrent_decode_fn(cfg):
     """jit(decode) for a model with recurrent layers, cache DONATED; one
     executable per cache shape (``jit_decode``)."""
 
     def decode(params, cache, tokens, active):
-        return _hybrid_decode_core(params, cfg, cache, tokens, active)
+        return _recurrent_decode_core(params, cfg, cache, tokens, active)
 
     return jax.jit(decode, donate_argnums=1)
 

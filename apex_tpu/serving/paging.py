@@ -1,7 +1,7 @@
 """Host-side page allocator: free list, refcounts, prefix cache.
 
 The device half of paging (``serving.cache.PagedKVCache``) is dumb
-storage — a fixed pool of ``(page_size, heads * head_dim)`` pages per
+storage — a fixed pool of ``(page_size, kv_heads * head_dim)`` pages per
 layer plus per-slot block tables. Everything that decides WHICH page a
 logical position lives in happens here, on the host, in plain Python:
 

@@ -140,7 +140,7 @@ from apex_tpu.serving.cache import (
 )
 from apex_tpu.serving.decode import (
     make_chunk_prefill_fn, make_copy_page_fn, make_decode_fn,
-    make_hybrid_decode_fn, make_hybrid_prefill_fn,
+    make_recurrent_decode_fn, make_recurrent_prefill_fn,
     make_paged_chunk_prefill_fn, make_paged_decode_fn,
     make_paged_prefill_fn, make_paged_tree_verify_fn,
     make_paged_verify_fn, make_prefill_fn, make_tree_verify_fn,
@@ -775,11 +775,13 @@ class PagedDecodeEngine(DecodeEngine):
         # must not expect it to mirror _slot_pages yet
         self._prefill_parked: set = set()
         if self.recurrent:
-            # the two programs above and no other: what needs more was
-            # refused. Bytes a prefill writes besides its pages:
+            # the ONE recurrent path, whatever the family: the model states
+            # its two cores, state shapes and row width (serving.decode,
+            # "the seam"), and what needs more programs was refused above.
+            # Bytes a prefill writes besides its pages:
             self._state_bytes = cfg.state_bytes_per_slot()
-            self._prefill = make_hybrid_prefill_fn(cfg)
-            self._decode = make_hybrid_decode_fn(cfg)
+            self._prefill = make_recurrent_prefill_fn(cfg)
+            self._decode = make_recurrent_decode_fn(cfg)
             self._chunk_prefill = self._verify = self._tree_verify = None
         else:
             self._prefill = make_paged_prefill_fn(cfg, compute_dtype,
@@ -1263,6 +1265,17 @@ class PagedDecodeEngine(DecodeEngine):
                   for i, p in enumerate(self._slot_pages)]
         audit_block_tables(self.cache.block_tables, expect)
         return True
+
+    def read_counters(self) -> Optional[Dict[str, np.ndarray]]:
+        """What the model's decode program has counted on the device since
+        the engine was built (``cfg.counter_shapes``: int32, so a caller
+        takes differences), fetched now: one read-back, on request, which no
+        tick makes. ``None`` for a model that counts nothing."""
+        counters = getattr(self.cache, "counters", None)
+        if counters is None:
+            return None
+        return {name: np.asarray(value)
+                for name, value in jax.device_get(counters).items()}
 
     def pool_snapshot(self) -> Dict:
         snap = self.pool.snapshot()

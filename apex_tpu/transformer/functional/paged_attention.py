@@ -8,12 +8,14 @@ gathered, transposed or upcast in HBM, and it is only read: the new
 token's K/V row comes in as an operand standing at position ``pos``, and
 the caller writes it into the (donated) pool afterwards.
 
-A page is ``(page_size, heads * head_dim)``: rows of all local heads side
-by side, so one page is one contiguous run of whole (sublane, 128-lane)
+A page is ``(page_size, kv_heads * head_dim)``: rows of all local K/V heads
+side by side, so one page is one contiguous run of whole (sublane, 128-lane)
 tiles and the MXU does the per-head work. Scores are ``Q_blk @ K^T`` with
-``Q_blk`` ``(heads, heads * head_dim)`` holding head ``h``'s query in its
-own columns and zeros elsewhere; the context is the matching diagonal
-blocks of ``P @ V``.
+``Q_blk`` ``(heads, kv_heads * head_dim)`` holding query head ``h`` in the
+columns of ITS K/V head (``h // (heads / kv_heads)``: its own when the counts
+are equal, one block shared by ``heads / kv_heads`` query rows when K/V heads
+are fewer) and zeros elsewhere; the context is the matching blocks of ``P @
+V``.
 
 Placement invariance: a slot's output depends on the pages its table names
 below ``pos`` and on nothing else. Pages past ``cdiv(pos, page_size)`` are
@@ -66,9 +68,10 @@ def _dot_f32(a, b, dims):
 
 def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
                    k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *, heads,
-                   page_size):
+                   kv_heads, page_size):
     span, width = kbuf.shape[1:]
-    hd = width // heads
+    hd = width // kv_heads
+    per = heads // kv_heads             # query heads that share a K/V head
     chunk = span // page_size
     rows = -(-heads // _ROW_TILE) * _ROW_TILE
     slot = pl.program_id(0)
@@ -96,10 +99,21 @@ def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
     def _():
         dma(0, 0, lambda d: d.start())
 
-    # head h's query in columns [h * hd, (h + 1) * hd) of row h
-    own = (lax.broadcasted_iota(jnp.int32, (rows, width), 1) // hd
-           == lax.broadcasted_iota(jnp.int32, (rows, width), 0))
-    q_blk = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0)
+    if per == 1:
+        # head h's query in columns [h * hd, (h + 1) * hd) of row h
+        own = (lax.broadcasted_iota(jnp.int32, (rows, width), 1) // hd
+               == lax.broadcasted_iota(jnp.int32, (rows, width), 0))
+        q_blk = jnp.where(own, q_ref[0].astype(jnp.float32), 0.0)
+    else:
+        # fewer K/V heads: q_ref holds (heads, hd), row h's query goes to the
+        # columns of K/V head h // per
+        own = (lax.broadcasted_iota(jnp.int32, (rows, width), 1) // hd
+               == lax.broadcasted_iota(jnp.int32, (rows, width), 0) // per)
+        q_rows = q_ref[0].astype(jnp.float32)
+        if rows > heads:
+            q_rows = jnp.concatenate(
+                [q_rows, jnp.zeros((rows - heads, hd), jnp.float32)], 0)
+        q_blk = jnp.where(own, jnp.concatenate([q_rows] * kv_heads, 1), 0.0)
     norm = math.sqrt(hd)
     neg = jnp.finfo(jnp.float32).min
     at_lane = lax.broadcasted_iota(jnp.int32, (rows, span), 1)
@@ -138,49 +152,65 @@ def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
     p_new = jnp.exp(s_new - m_new)
     acc = alpha * acc + p_new * vn_ref[0].astype(jnp.float32)
     ctx = jnp.where(own, acc / (alpha * l + p_new), 0.0)
-    o_ref[0] = jnp.sum(ctx, axis=0, keepdims=True).astype(o_ref.dtype)
+    if per == 1:
+        o_ref[0] = jnp.sum(ctx, axis=0, keepdims=True).astype(o_ref.dtype)
+    else:       # row h's context stands in the columns of its K/V head
+        o_ref[0] = sum(ctx[:heads, j * hd:(j + 1) * hd]
+                       for j in range(kv_heads)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
-                           pos, layer, *, heads, interpret=None):
+                           pos, layer, *, heads, kv_heads=None,
+                           interpret=None):
     """Attention of one query row per slot over the slot's mapped pages.
 
     ``q`` ``(b, 1, heads * hd)``, heads side by side (the query-rows axis
     is static 1: verify's k1 rows are a later kernel); ``k_new`` /
-    ``v_new`` of the same shape: the new token's row, attended to at
+    ``v_new`` ``(b, 1, kv_heads * hd)``: the new token's row, attended to at
     position ``pos`` as if it were already written (it is rounded to the
     pool's dtype first, as a written row would be); ``k_pool`` / ``v_pool``
-    ``[L, pages, page_size, heads * hd]``, the whole stacked pool, left in
+    ``[L, pages, page_size, kv_heads * hd]``, the whole stacked pool, left in
     HBM and only read; ``block_tables`` ``(b, max_pages)`` int32; ``pos``
     ``(b,)`` int32; ``layer`` a scalar int32 (traced under the layer
-    scan). Scores, softmax and the context accumulate in float32 with the
-    mask ``s <= pos``; returns the context ``(b, 1, heads * hd)`` in
-    ``q``'s dtype.
+    scan). ``kv_heads`` (``heads`` when not given) divides ``heads``: query
+    head ``h`` reads K/V head ``h // (heads / kv_heads)``. Scores, softmax
+    and the context accumulate in float32 with the mask ``s <= pos``; returns
+    the context ``(b, 1, heads * hd)`` in ``q``'s dtype.
     """
-    b, k1, width = q.shape
+    b, k1, q_width = q.shape
+    kv_heads = heads if kv_heads is None else kv_heads
     if k1 != 1:
         raise ValueError(f"paged decode attention takes one query row per "
                          f"slot, got {k1}")
+    if heads % kv_heads or q_width % heads:
+        raise ValueError(f"{heads} query heads of a {q_width} row over "
+                         f"{kv_heads} K/V heads")
+    width = q_width // heads * kv_heads
     if k_pool.shape != v_pool.shape or k_pool.ndim != 4 \
-            or k_pool.shape[3] != width or width % heads:
+            or k_pool.shape[3] != width:
         raise ValueError(f"pool {k_pool.shape} / {v_pool.shape} does not "
                          f"hold [L, pages, page_size, {width}] rows of "
-                         f"{heads} heads")
+                         f"{kv_heads} heads")
     page_size = k_pool.shape[2]
     chunk = max(1, min(_CHUNK_POSITIONS // page_size,
                        block_tables.shape[1]))
     row = pl.BlockSpec((1, 1, width), lambda i, *_: (i, 0, 0),
                        memory_space=pltpu.VMEM)
+    q_row = row
+    if kv_heads != heads:       # a query head a row: (b, heads, hd)
+        q = q.reshape(b, heads, q_width // heads)
+        q_row = pl.BlockSpec((1,) + q.shape[1:], lambda i, *_: (i, 0, 0),
+                             memory_space=pltpu.VMEM)
     pool = pl.BlockSpec(memory_space=pl.ANY)
     buf = pltpu.VMEM((2, chunk * page_size, width), k_pool.dtype)
     with jax.named_scope("apex_paged_decode_fwd"):
         return pl.pallas_call(
             functools.partial(_decode_kernel, heads=heads,
-                              page_size=page_size),
+                              kv_heads=kv_heads, page_size=page_size),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3, grid=(b,),
-                in_specs=[row, row, row, pool, pool],
-                out_specs=row,
+                in_specs=[q_row, row, row, pool, pool],
+                out_specs=q_row,
                 scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             interpret=pallas_interpret(interpret),
@@ -188,4 +218,4 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
         )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),
           jnp.reshape(layer, (1,)).astype(jnp.int32), q,
           k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
-          k_pool, v_pool)
+          k_pool, v_pool).reshape(b, 1, q_width)

@@ -198,3 +198,76 @@ def test_decode_step_leaves_pool_and_tables_as_the_verify_step_does(dtype):
     np.testing.assert_array_equal(np.asarray(got.lengths),
                                   lengths + np.asarray([1, 0, 1]))
     np.testing.assert_array_equal(np.asarray(want.lengths), lengths)
+
+
+# -- fewer K/V heads than query heads ------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("heads, kv_heads", [(8, 2), (4, 1), (2, 2)],
+                         ids=["4_per_kv_head", "one_kv_head", "1_per_kv_head"])
+@pytest.mark.parametrize("page_size", [4, 16])
+def test_grouped_kv_heads_match_gather_and_einsum(page_size, heads, kv_heads,
+                                                  dtype):
+    """Query head ``h`` reads K/V head ``h // (heads / kv_heads)``; the pool
+    row is ``kv_heads * head_dim``. Against the slot's pages gathered, K and V
+    repeated to the query heads and a masked float32 softmax."""
+    from apex_tpu.transformer.functional.paged_attention import (
+        paged_decode_attention,
+    )
+
+    hd = 16
+    pos = np.asarray(list(_positions(page_size).values()), np.int32)
+    slots, width = len(pos), kv_heads * hd
+    num_pages = RESERVED_PAGES + slots * MAX_PAGES + 3
+    rng = np.random.RandomState(heads + page_size)
+    pools = rng.standard_normal(
+        (2, LAYERS, num_pages, page_size, width)).astype(np.float32)
+    physical = np.arange(RESERVED_PAGES, num_pages)
+    rng.shuffle(physical)
+    table = np.full((slots, MAX_PAGES), NULL_PAGE, np.int32)
+    for s in range(slots):
+        for j in range(pos[s] // page_size + 1):
+            table[s, j] = physical[s * MAX_PAGES + j]
+    k_pool, v_pool = (jnp.asarray(p).astype(dtype) for p in pools)
+    q = jnp.asarray(rng.standard_normal((slots, 1, heads * hd)), jnp.float32)
+    k_new, v_new = (jnp.asarray(rng.standard_normal((slots, 1, width)),
+                                jnp.float32).astype(dtype) for _ in range(2))
+    got = paged_decode_attention(
+        q, k_new, v_new, k_pool, v_pool, jnp.asarray(table),
+        jnp.asarray(pos), jnp.int32(LAYER), heads=heads, kv_heads=kv_heads)
+    assert got.shape == (slots, 1, heads * hd) and got.dtype == jnp.float32
+
+    span = MAX_PAGES * page_size
+    for s in range(slots):
+        def rows(pool, new):
+            t = np.asarray(pool[LAYER].astype(jnp.float32))[
+                np.maximum(table[s], 0)].reshape(span, kv_heads, hd).copy()
+            t[pos[s]] = np.asarray(new[s, 0].astype(jnp.float32)).reshape(
+                kv_heads, hd)
+            return np.repeat(t, heads // kv_heads, axis=1)   # (span, heads, hd)
+        k, v = rows(k_pool, k_new), rows(v_pool, v_new)
+        qs = np.asarray(q[s, 0]).reshape(heads, hd)
+        scores = np.einsum("hd,shd->hs", qs, k) / np.sqrt(hd)
+        scores[:, pos[s] + 1:] = -np.inf
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        want = np.einsum("hs,shd->hd", p / p.sum(-1, keepdims=True), v)
+        np.testing.assert_allclose(np.asarray(got[s, 0]).reshape(heads, hd),
+                                   want, rtol=2e-5, atol=2e-5)
+
+
+def test_heads_that_are_no_multiple_of_the_kv_heads_are_refused():
+    from apex_tpu.transformer.functional.paged_attention import (
+        paged_decode_attention,
+    )
+
+    pool = jnp.zeros((1, 6, 4, 32))
+    with pytest.raises(ValueError, match="query heads"):
+        paged_decode_attention(
+            jnp.zeros((2, 1, 48)), jnp.zeros((2, 1, 32)), jnp.zeros((2, 1, 32)),
+            pool, pool, jnp.zeros((2, 3), jnp.int32), jnp.zeros((2,), jnp.int32),
+            jnp.int32(0), heads=3, kv_heads=2)
+    with pytest.raises(ValueError, match="does not hold"):
+        paged_decode_attention(
+            jnp.zeros((2, 1, 64)), jnp.zeros((2, 1, 16)), jnp.zeros((2, 1, 16)),
+            pool, pool, jnp.zeros((2, 3), jnp.int32), jnp.zeros((2,), jnp.int32),
+            jnp.int32(0), heads=4, kv_heads=1)

@@ -214,6 +214,106 @@ def test_gated_delta_kernels(v5e):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
+def test_nemotron_kernels(v5e):
+    """The two kernels of the ``nemotron_h`` family at the published widths:
+    the Mamba-2 step over the whole stacked state of 5 layers x 128 slots
+    (128 heads of 64 x 128), aliased; the grouped expert product over 128
+    held experts, both products, at a decode tick's rows and at the largest
+    bucket's; and the paged kernel at 32 query heads over 2 K/V heads."""
+    from apex_tpu.transformer.functional import moe
+    from apex_tpu.transformer.functional.paged_attention import (
+        paged_decode_attention,
+    )
+    from apex_tpu.transformer.functional.ssd import ssd_step
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    sharding = SingleDeviceSharding(v5e)
+    shapes = [((128, 128, 64), f32), ((128, 128), f32), ((128,), f32),
+              ((128, 8, 128), f32), ((128, 8, 128), f32),
+              ((5, 128, 128, 64, 128), f32), ((), jnp.int32),
+              ((128,), jnp.bool_)]
+    compiled = jax.jit(ssd_step, donate_argnums=5).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    ).compile()
+    assert compiled.as_text().count(MOSAIC_CALL) == 1
+    # the 2.7 GB state comes back in the buffer it came in
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * 128 * 128 * 64 * 128 * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+    for rows in (128 * 22, 1024 * 22):
+        assert compile_on(
+            v5e, functools.partial(moe.grouped_matmul, activation="relu2",
+                                   out_dtype=bf16),
+            ((rows, 1024), bf16), ((128, 1024, 2688), bf16),
+            ((128,), jnp.int32)) == 1
+        assert compile_on(
+            v5e, moe.grouped_matmul, ((rows, 2688), bf16),
+            ((128, 2688, 1024), bf16), ((128,), jnp.int32)) == 1
+    pool = ((1, 8194, 16, 256), bf16)
+    new_row = ((128, 1, 256), bf16)
+    assert compile_on(
+        v5e, functools.partial(paged_decode_attention, heads=32, kv_heads=2),
+        ((128, 1, 4096), f32), new_row, new_row, pool, pool,
+        ((128, 64), jnp.int32), ((128,), jnp.int32), ((), jnp.int32)) == 1
+
+
+def test_nemotron_full_size_programs(v5e):
+    """The two programs of ``nemotron3_super_120b_a12b.many_slot_decode`` at
+    full size (one period ``MEMEMEMEM*E``, 128 of 512 experts, a quarter of
+    the vocabulary, 128 slots, the full pool): both compile for a v5e with no
+    chip; ``memory_analysis`` gives what the configuration file says (9.30 GB
+    of weights, 2.90 GB of cache, all of it aliased) and fits the chip; the
+    kernel names are the engagement counters the trace readers count."""
+    import re
+
+    from apex_tpu.models import nemotron_h as nh
+    from apex_tpu.serving.cache import init_hybrid_cache
+    from apex_tpu.serving.decode import (
+        make_recurrent_decode_fn, make_recurrent_prefill_fn,
+    )
+
+    slots, bucket = 128, 512
+    cfg = nh.NemotronHConfig(vocab_size=32768, pattern="MEMEMEMEM*E",
+                             experts_held=128, max_position_embeddings=1024)
+    sharding = SingleDeviceSharding(v5e)
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+    params = on(jax.eval_shape(lambda k: nh.init(k, cfg, jnp.bfloat16),
+                               jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(functools.partial(
+        init_hybrid_cache, cfg, slots, 1024, slots * 64 + 2, 16,
+        jnp.bfloat16)))
+    size = lambda tree: sum(a.size * a.dtype.itemsize
+                            for a in jax.tree.leaves(tree))
+    assert round(size(params) / 1e9, 2) == 9.30
+    assert round(size(cache) / 1e9, 2) == 2.90
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=sharding)
+    i32 = jnp.int32
+    programs = {
+        "decode": make_recurrent_decode_fn(cfg).lower(
+            params, cache, sds((slots,), i32), sds((slots,), jnp.bool_)),
+        "prefill": make_recurrent_prefill_fn(cfg).lower(
+            params, cache, sds((1, bucket), i32), sds((bucket,), i32),
+            sds((), i32), sds((bucket // 16,), i32), sds((64,), i32))}
+    want = {"decode": {"apex_ssd_decode_fwd": 5, "apex_moe_gmm_fwd": 10,
+                       "apex_paged_decode_fwd": 1, "apex_flash_fwd": 0},
+            "prefill": {"apex_ssd_decode_fwd": 0, "apex_moe_gmm_fwd": 10,
+                        "apex_paged_decode_fwd": 0, "apex_flash_fwd": 1}}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        got = {k: len(re.findall(rf"%{k}(\.\d+)? = ", text))
+               for k in want[name]}
+        assert got == want[name], name
+        mem = compiled.memory_analysis()
+        # the donated cache comes back in place: nothing its size beside it
+        # (padded to tiles: a few KB over the arrays' own bytes)
+        assert 0 <= mem.alias_size_in_bytes - size(cache) < 1 << 16, name
+        assert mem.temp_size_in_bytes < 0.5e9, name
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 0.85 * 16 * 2 ** 30, name
+
+
 def test_flat_adam(v5e):
     from apex_tpu.optimizers import FusedAdam
 
