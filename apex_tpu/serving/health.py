@@ -464,6 +464,8 @@ STAT_FIELDS = {
     "chunk_deferrals": "prefill chunks deferred on fair-share overrun",
     "tenant_preemptions": "slots requeued for a higher-priority tenant",
     "slo_violations": "finished requests that broke their tenant SLO",
+    "page_boundaries": "fresh pages mapped as a slot crossed a boundary",
+    "block_table_uploads": "host block table sent to the device",
 }
 
 

@@ -96,7 +96,7 @@ from typing import Dict, List, Mapping, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.serving.cache import NULL_PAGE, max_pages_per_slot
+from apex_tpu.serving.cache import max_pages_per_slot
 from apex_tpu.serving.faults import FaultInjector, InjectedFault
 from apex_tpu.serving.health import (HEALTH_STATES, PoolExhausted,
                                      ReplicaHealth, ReplicaUnavailable,
@@ -391,22 +391,17 @@ class _DisaggEngine:
             rem.free_slot(_STAGING_SLOT)
             raise
         pages = shared + promoted + private
-        row = np.full((act.max_pages,), NULL_PAGE, np.int32)
-        row[:n_pages] = pages
-        # install: block-table row + true prompt length (exactly what
-        # the jitted colocated prefill writes), then scatter the
-        # verified tiles into the private pages
-        act.cache = act.cache._replace(
-            block_tables=act.cache.block_tables.at[slot].set(
-                jnp.asarray(row)),
-            lengths=act.cache.lengths.at[slot].set(
-                jnp.int32(len(toks))))
+        # install: page list, block-table row + true prompt length
+        # (exactly what the jitted colocated prefill leaves; the row
+        # through the engine, which owns the table and uploads it before
+        # the next step), then scatter the verified tiles into the
+        # private pages
+        act.install_slot(slot, pages, len(toks))
         if private:
             k_dev, v_dev = tier.shard_fn(k_tile, v_tile)
             act.cache = self._insert(
                 act.cache, jnp.asarray(private, jnp.int32), k_dev,
                 v_dev)
-        act._slot_pages[slot] = list(pages)
         if act.prefix_sharing:
             act.pool.register_prefix(keys, pages)
         rem.free_slot(_STAGING_SLOT)

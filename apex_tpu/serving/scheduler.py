@@ -433,6 +433,7 @@ class DecodeEngine:
         it."""
         trc = self.tracer
         trc.begin("exec", kind="decode", **self._exec_stats())
+        self.sync_table()
         self.cache, logits = self._decode(self.params, self.cache,
                                           tokens, active)
         trc.end("exec")
@@ -445,6 +446,11 @@ class DecodeEngine:
     def _exec_stats(self) -> Dict[str, int]:
         """What the ``exec`` span says beyond its kind (nothing here)."""
         return {}
+
+    def sync_table(self) -> None:
+        """Called before a decode, verify or tree-verify program is
+        launched: the paged engine sends its block table if the host
+        changed it (nothing here: a dense cache row is its own map)."""
 
     def sample(self, logits, base, counts, temperature) -> jax.Array:
         """One token per row of ``logits`` (B, V): row b draws with
@@ -538,6 +544,7 @@ class DecodeEngine:
         positions, post-jit)."""
         trc = self.tracer
         trc.begin("exec", kind="verify", k1=int(tokens.shape[1]))
+        self.sync_table()
         self.cache, logits = self._verify(self.params, self.cache,
                                           tokens)
         trc.end("exec")
@@ -558,6 +565,7 @@ class DecodeEngine:
         ``decode_exec`` fault site with the other step kinds."""
         trc = self.tracer
         trc.begin("exec", kind="tree_verify", k1=int(tokens.shape[1]))
+        self.sync_table()
         self.cache, logits = self._tree_verify(self.params, self.cache,
                                                tokens, depth, anc)
         trc.end("exec")
@@ -650,6 +658,22 @@ class PagedDecodeEngine(DecodeEngine):
     page-boundary pages and clone any shared page a slot is about to
     append into, so the jitted decode step only ever writes
     exclusively-owned (or scratch) pages.
+
+    **The block table is the host's.** ``_slot_pages`` decides what a
+    slot maps, and ``_table`` (numpy, ``[num_slots, max_pages]`` int32)
+    is that decision in the layout of the device leaf
+    ``cache.block_tables``, which is only a copy. A page boundary, a
+    COW retarget and the parking of a freed or preempted slot's row on
+    scratch are host writes and mark the table dirty; a prefill program
+    writes its slot's row on the device itself and the host mirrors it.
+    The invariant: *the device table equals the host table whenever a
+    program that goes through it is launched*. Those programs are the
+    decode, verify and tree-verify steps (they write a row for EVERY
+    slot, active or not, through the table), and each is preceded by
+    :meth:`sync_table`: one transfer if the table is dirty, whatever
+    the number of slots, boundaries, clones and freed slots behind it,
+    and none otherwise. The prefill programs take their pages and rows
+    as arguments and never read the leaf, so they need no upload.
 
     ``free_order`` permutes the initial free list — physical placement
     is an allocator detail the logits provably don't depend on (the
@@ -770,9 +794,14 @@ class PagedDecodeEngine(DecodeEngine):
                                  if quant else make_insert_pages_fn())
             self.pool.spill_hook = self._spill_page
         self._slot_pages: List[List[int]] = [[] for _ in range(num_slots)]
-        # slots mid-chunked-prefill: their device block-table row is
-        # parked on scratch (see begin_chunk_prefill), so the audit
-        # must not expect it to mirror _slot_pages yet
+        # the block table (class docstring): every row parked on scratch,
+        # as init_*_cache fills the device leaf
+        self._table = np.full((num_slots, self.max_pages), SCRATCH_PAGE,
+                              np.int32)
+        self._table_dirty = False
+        # slots mid-chunked-prefill: their block-table row is parked on
+        # scratch (see begin_chunk_prefill), so the audit must not
+        # expect it to mirror _slot_pages yet
         self._prefill_parked: set = set()
         if self.recurrent:
             # the ONE recurrent path, whatever the family: the model states
@@ -896,6 +925,9 @@ class PagedDecodeEngine(DecodeEngine):
 
         row = np.full((self.max_pages,), NULL_PAGE, np.int32)
         row[:n_pages] = pages
+        # either program below stores ``row`` as the slot's row on the
+        # device: the host mirrors it and nothing is left to upload
+        self._table[slot] = row
         # a host-tier engine skips fully-covered leading pages the way
         # chunked prefill does: the suffix runs as one final "chunk"
         # whose attention gathers the covered pages through the real
@@ -952,8 +984,9 @@ class PagedDecodeEngine(DecodeEngine):
         """Stage a chunked prefill: share the longest cached prefix
         run and allocate the private pages UP FRONT (all-or-nothing,
         with the same rollback as :meth:`prefill`), but run no forward
-        yet. While chunks are in flight the slot's device block-table
-        row stays parked on scratch: co-tenant decode/verify ticks
+        yet. While chunks are in flight the slot's block-table row
+        stays parked on scratch, on the host and on the device, as
+        :meth:`free_slot` left it: co-tenant decode/verify ticks
         write a garbage row for EVERY slot, and a mid-prefill slot's
         write target could be a SHARED page — parking routes those
         writes to the scratch page until the final chunk atomically
@@ -1042,6 +1075,7 @@ class PagedDecodeEngine(DecodeEngine):
             self.params, self.cache, ids, mask, jnp.int32(slot),
             jnp.int32(pos), jnp.asarray(write),
             jnp.asarray(state["row"]), jnp.asarray(store))
+        self._table[slot] = store   # what the program stored: mirrored
         trc.end("chunk_prefill")
         return logits
 
@@ -1188,8 +1222,15 @@ class PagedDecodeEngine(DecodeEngine):
         slot the pool genuinely cannot serve (or whose ``cow_clone``
         fault site fired) is preempted — its pages are released (often
         unblocking the rest of the batch) and the caller requeues the
-        request."""
+        request.
+
+        Everything here but the clone's page copy is host work: a new
+        page and a retarget are written into the host table
+        (``_table``), which the step's launch uploads once
+        (:meth:`sync_table`); ``stats.page_boundaries`` and
+        ``stats.cow_copies`` count what was done."""
         preempted: List[int] = []
+        boundaries = 0
         for i, pos in sorted(positions.items()):
             pages = self._slot_pages[i]
             first = pos // self.page_size
@@ -1201,9 +1242,9 @@ class PagedDecodeEngine(DecodeEngine):
                         self._preempt(i, preempted)
                         break
                     pages.append(p)
-                    self.cache = self.cache._replace(
-                        block_tables=self.cache.block_tables.at[
-                            i, idx].set(p))
+                    self._table[i, idx] = p
+                    self._table_dirty = True
+                    boundaries += 1
                 elif self.pool.needs_copy(pages[idx]):  # COW
                     dst = None if self.injector.fire("cow_clone") \
                         else self.pool.alloc()
@@ -1222,13 +1263,14 @@ class PagedDecodeEngine(DecodeEngine):
                         break
                     self.stats.cow_copies += 1
                     self.cache = self._copy(self.cache,
-                                            jnp.int32(pages[idx]),
-                                            jnp.int32(dst))
-                    self.cache = self.cache._replace(
-                        block_tables=self.cache.block_tables.at[
-                            i, idx].set(dst))
+                                            np.int32(pages[idx]),
+                                            np.int32(dst))
+                    self._table[i, idx] = dst
+                    self._table_dirty = True
                     self.pool.release(pages[idx])
                     pages[idx] = dst
+        if boundaries:
+            self.stats.page_boundaries += boundaries
         return preempted
 
     def _preempt(self, slot: int, preempted: List[int]) -> None:
@@ -1239,25 +1281,66 @@ class PagedDecodeEngine(DecodeEngine):
     def free_slot(self, slot: int) -> None:
         """Release the slot's page references and park its block-table
         row on scratch (a freed slot's parked decode writes must never
-        land in a page the allocator may hand to someone else)."""
+        land in a page the allocator may hand to someone else). The
+        parking is a host write: the decode program writes a row for
+        every slot, active or not, and :meth:`sync_table` uploads the
+        table before that program is launched, so the device never
+        steps through the freed row. No device program runs here."""
         for p in self._slot_pages[slot]:
             self.pool.release(p)
         self._slot_pages[slot] = []
         self._prefill_parked.discard(slot)
-        self.cache = self.cache._replace(
-            block_tables=self.cache.block_tables.at[slot].set(
-                jnp.full((self.max_pages,), SCRATCH_PAGE, jnp.int32)))
+        row = self._table[slot]
+        if (row != SCRATCH_PAGE).any():     # a staged slot is parked already
+            row[:] = SCRATCH_PAGE
+            self._table_dirty = True
         if self.draft_model is not None:
             self.draft_model.free_slot(slot)
+
+    def install_slot(self, slot: int, pages: Sequence[int],
+                     length: int) -> None:
+        """What a prefill leaves behind for ``slot``, for a prompt whose
+        rows were computed elsewhere (the disaggregated handoff,
+        ``serving.router``): the slot's page list, its NULL-padded row
+        in the host table (uploaded before the next step, like any host
+        write) and the prompt's length on the device."""
+        self._slot_pages[slot] = list(pages)
+        self._table[slot] = NULL_PAGE
+        self._table[slot, :len(pages)] = pages
+        self._table_dirty = True
+        self.cache = self.cache._replace(
+            lengths=self.cache.lengths.at[slot].set(jnp.int32(length)))
+
+    def sync_table(self) -> None:
+        """Hold the invariant of the class docstring: if a host write has
+        not reached the device, replace the leaf by ONE transfer of the
+        whole table (a copy of it: the host goes on writing while the
+        transfer is in flight). No program runs and none compiles; the
+        new leaf is placed as the old one was (its sharding if it was
+        committed to one, the default device otherwise, so the step
+        programs see the arguments they were compiled for). Whoever
+        takes ``engine.cache`` to launch a program of their own calls
+        this first."""
+        if not self._table_dirty:
+            return
+        leaf = self.cache.block_tables
+        self.cache = self.cache._replace(block_tables=jax.device_put(
+            self._table.copy(), leaf.sharding if leaf.committed else None))
+        self._table_dirty = False
+        self.stats.block_table_uploads += 1
 
     def check_invariants(self) -> bool:
         """Full pool audit: host-side refcount/free-list/registry
         accounting against the per-slot page lists
         (:meth:`PagePool.check_invariants`), then the device block
         tables against those same lists
-        (:func:`~apex_tpu.serving.cache.audit_block_tables`). Raises
+        (:func:`~apex_tpu.serving.cache.audit_block_tables`): what the
+        next step would go through, so a pending host write is uploaded
+        first (:meth:`sync_table`) and the audit holds the device leaf,
+        and with it the host table, against ``_slot_pages``. Raises
         :class:`~apex_tpu.serving.health.PoolInvariantError`."""
         self.pool.check_invariants(self._slot_pages)
+        self.sync_table()
         # mid-chunked-prefill slots hold pages but park their device
         # row on scratch until the final chunk installs it — audit
         # those rows as empty (all scratch/null) instead
@@ -2192,6 +2275,21 @@ class ContinuousBatchingScheduler:
                          if d else None)
         return trees
 
+    def _prepare_decode(self, positions: Dict[int, int],
+                        n_new: int) -> List[int]:
+        """The engine's ``prepare_decode`` under its span, which closes
+        with what the call did: page boundaries crossed, shared pages
+        cloned, slots preempted (the recorded event's; the profiler's
+        span takes arguments only when it opens)."""
+        trc, stats = self.tracer, self.stats
+        boundaries, cow = stats.page_boundaries, stats.cow_copies
+        trc.begin("prepare_decode")
+        preempted = self.engine.prepare_decode(positions, n_new=n_new)
+        trc.end("prepare_decode",
+                boundaries=stats.page_boundaries - boundaries,
+                cow=stats.cow_copies - cow, preempted=len(preempted))
+        return preempted
+
     def _tick(self) -> None:
         spent = self._decode_phase()
         if self.chunk_tokens is not None:
@@ -2245,10 +2343,7 @@ class ContinuousBatchingScheduler:
         # requeue in submission order: appendleft of the newest request
         # first leaves the oldest at the queue front (slot-index order
         # would let a later request resume before an earlier one)
-        trc.begin("prepare_decode")
-        preempted = eng.prepare_decode(
-            positions, n_new=k1 if spec else 1)
-        trc.end("prepare_decode")
+        preempted = self._prepare_decode(positions, k1 if spec else 1)
         for i in sorted(preempted,
                         key=lambda j: self._slots[j].request_id,
                         reverse=True):
@@ -2446,9 +2541,7 @@ class ContinuousBatchingScheduler:
                  + (len(trees[i][0]) if trees[i] is not None else 0)
                  for i in positions)
         k1 = max(1, min(k1, avail))
-        trc.begin("prepare_decode")
-        preempted = eng.prepare_decode(positions, n_new=k1)
-        trc.end("prepare_decode")
+        preempted = self._prepare_decode(positions, k1)
         for i in sorted(preempted,
                         key=lambda j: self._slots[j].request_id,
                         reverse=True):

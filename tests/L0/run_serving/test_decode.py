@@ -446,6 +446,7 @@ def test_tp_verify_matches_unsharded():
                             buckets=(8, 16, 32), spec_k=k)
     eng.prefill(0, [int(t) for t in np.asarray(seq[0, :PROMPT])])
     eng.prepare_decode({0: PROMPT}, n_new=k + 1)
+    eng.sync_table()    # the clone is launched by hand: upload first
     clone = jax.tree.map(jnp.copy, eng.cache)
     want = eng.verify(tokens)
     _, got = make_tp_paged_verify_fn(model)(params, clone, tokens)
@@ -627,6 +628,7 @@ def test_tp_tree_verify_matches_unsharded():
     for slot in (0, 1):
         eng.prefill(slot, [int(t) for t in np.asarray(seq[0, :PROMPT])])
     eng.prepare_decode({0: PROMPT, 1: PROMPT}, n_new=4)
+    eng.sync_table()    # the clone is launched by hand: upload first
     clone = jax.tree.map(jnp.copy, eng.cache)
     want = eng.tree_verify(toks, depth, anc)
     _, got = make_tp_paged_tree_verify_fn(model)(params, clone, toks,

@@ -224,6 +224,35 @@ def test_event_taxonomy_and_metrics_after_run(model):
         == sched.stats.spec_ticks
 
 
+def test_prepare_decode_span_and_block_table_counters(model):
+    """The ``prepare_decode`` event closes with what the call did, and the
+    two counters of the host-owned block table say the same: pages mapped
+    at a boundary, and whole-table uploads, at most one a tick."""
+    trc = Tracer()
+    sched = ContinuousBatchingScheduler(_engine(model, tracer=trc),
+                                        eos_id=EOS)
+    for s in range(3):      # one prompt three times: its partial page is
+        sched.submit(Request(prompt=(7, 11, 13, 17, 19, 23),    # cloned
+                             max_new_tokens=5, temperature=0.7, seed=s))
+    sched.run()
+    spans = [dict(e.args) for e in trc.events if e.name == "prepare_decode"]
+    assert spans and all(set(a) == {"boundaries", "cow", "preempted"}
+                         for a in spans)
+    st = sched.stats
+    assert sum(a["boundaries"] for a in spans) == st.page_boundaries > 0
+    assert sum(a["cow"] for a in spans) == st.cow_copies > 0
+    assert sum(a["preempted"] for a in spans) == st.preemptions == 0
+    assert 0 < st.block_table_uploads <= st.plain_ticks
+    reg = trc.registry
+    assert reg.counter("serving_page_boundaries_total").value \
+        == st.page_boundaries
+    assert reg.counter("serving_block_table_uploads_total").value \
+        == st.block_table_uploads
+    text = reg.to_prometheus()
+    assert "serving_page_boundaries_total" in text
+    assert "serving_block_table_uploads_total" in text
+
+
 @pytest_chaos
 def test_pool_gauges_track_the_pool(model):
     trc = Tracer()

@@ -37,6 +37,7 @@ from apex_tpu.serving import (
     PagedDecodeEngine, PageTransfer, Request, Tracer, TransferCorrupt,
     TransferFailed, FINISH_REASONS, transfer_checksum,
 )
+from apex_tpu.serving.cache import audit_block_tables
 from apex_tpu.serving.paging import prefix_page_keys
 
 pytestmark = pytest.mark.chaos
@@ -120,6 +121,32 @@ def test_fault_free_streams_match_colocated(model, spec_k):
     assert router.stats.failovers == 0
     assert all(h.state == "healthy" for h in router.health.values())
     _assert_all_ok_golden(router, golden)
+
+
+def test_remote_install_survives_the_next_upload(model):
+    """The decode replica's block table is its engine's to write
+    (``PagedDecodeEngine.install_slot``): the router's install goes into
+    the host table, so the upload in front of the next decode step
+    carries it. No audit here: its own upload would hide a row that the
+    decode launch had lost. Prompts of 5 clone their partial page on the
+    first append, so the first tick's launch does upload."""
+    golden = _golden(model)
+    router = _router(model)
+    router.audit = False
+    for r in _REQS:
+        router.submit(r)
+    router.step()           # admit two by remote prefill, decode once
+    act = router.engine.active
+    assert router.stats.remote_prefills == 2
+    assert act.stats.block_table_uploads == 1 and not act._table_dirty
+    assert all(act._slot_pages)
+    audit_block_tables(act.cache.block_tables, act._slot_pages)
+    while router.busy:
+        router.step()
+    assert [list(router.outcomes[i].tokens) for i in range(len(_REQS))] \
+        == golden
+    assert router.stats.remote_prefills == len(_REQS)
+    router.engine.check_invariants()
 
 
 def test_cross_replica_prefix_dedup(model):

@@ -11,7 +11,10 @@ sampler programs fold the token number in for all slots at once
 - the scheduler's streams are those of a loop that samples one request at a
   time with eagerly derived keys (the contract as it was first written);
 - a steady decode tick of any kind derives no key eagerly and uploads the
-  same number of host arrays at 2 slots as at 8;
+  same number of host arrays at 2 slots as at 8; the block table is the
+  host's (``PagedDecodeEngine``) and costs a tick one more upload when a slot
+  crossed a page boundary, however many did, and none otherwise; a plain
+  tick leaves no leaf of ``engine.cache`` to an eager operation;
 - the ``build_inputs`` span says how many slots it built for.
 """
 
@@ -72,14 +75,16 @@ def test_stream_keys_equal_eager_fold_in(derive, seed, count):
                                       _eager_key(seed, count + j))
 
 
-def _engine(model, kind, num_slots, **kw):
+def _engine(model, kind, num_slots, page_size=16, **kw):
     cfg, params = model
     if kind == "dense":
         return DecodeEngine(params, cfg, num_slots=num_slots,
                             max_len=MAX_LEN, **kw)
-    return PagedDecodeEngine(params, cfg, num_slots=num_slots,
-                             max_len=MAX_LEN, num_pages=4 * num_slots + 8,
-                             page_size=16, **kw)
+    return PagedDecodeEngine(
+        params, cfg, num_slots=num_slots, max_len=MAX_LEN,
+        num_pages=PagedDecodeEngine.full_pool_pages(num_slots, MAX_LEN,
+                                                    page_size),
+        page_size=page_size, **kw)
 
 
 def _reference_stream(model, req):
@@ -127,9 +132,30 @@ class _Counted:
         return self.fn(*args, **kw)
 
 
-def _steady_tick_counts(model, monkeypatch, num_slots, mode):
-    """Programs dispatched, arrays uploaded and eager key derivations in
-    three decode ticks with every slot decoding and no admission."""
+class _CountedStep(_Counted):
+    """The step program, which takes the cache and returns the next one. A
+    leaf of the cache it is handed that is neither a leaf it returned last
+    time nor the table the engine uploaded was made in between by an eager
+    operation on a leaf of ``engine.cache``: counted."""
+
+    def __init__(self, fn, tally, known):
+        super().__init__(fn, tally)
+        self.known = known      # arrays, kept alive so that no id comes back
+
+    def __call__(self, params, cache, *args):
+        ids = {id(a) for a in self.known}
+        self.tally["eager_cache_ops"] += sum(
+            id(leaf) not in ids for leaf in jax.tree_util.tree_leaves(cache))
+        new_cache, logits = super().__call__(params, cache, *args)
+        self.known[:] = jax.tree_util.tree_leaves(new_cache)
+        return new_cache, logits
+
+
+def _steady_tick_counts(model, monkeypatch, num_slots, mode, page_size):
+    """Programs dispatched, arrays uploaded, eager key derivations and eager
+    operations on the cache in three decode ticks with every slot decoding
+    and no admission; and per tick, what ``prepare_decode`` did to the block
+    table beside the uploads the table cost."""
     cfg, params = model
     kw = {}
     if mode != "plain":
@@ -137,7 +163,7 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode):
     if mode == "tree":
         kw.update(tree_spec=True, draft_model=DraftModel(
             params, cfg, num_slots=num_slots, max_len=MAX_LEN))
-    eng = _engine(model, "paged", num_slots, **kw)
+    eng = _engine(model, "paged", num_slots, page_size, **kw)
     sched = ContinuousBatchingScheduler(eng, eos_id=EOS)
     for i in range(num_slots):
         # a repeating prompt, so that the n-gram drafter proposes
@@ -146,9 +172,13 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode):
     for _ in range(3):      # admission, and every program compiled
         sched.step()
     assert all(sched._decoding(s) for s in sched._slots)
-    tally = {"programs": 0, "uploads": 0, "eager_keys": 0}
-    for name in ("_decode", "_verify", "_tree_verify", "_sample",
-                 "_sample_grid", "_finite"):
+    tally = {"programs": 0, "uploads": 0, "eager_keys": 0,
+             "table_uploads": 0, "eager_cache_ops": 0}
+    known = jax.tree_util.tree_leaves(eng.cache)
+    monkeypatch.setattr(eng, "_decode",
+                        _CountedStep(eng._decode, tally, known))
+    for name in ("_verify", "_tree_verify", "_sample", "_sample_grid",
+                 "_finite"):
         if getattr(eng, name, None) is not None:
             monkeypatch.setattr(eng, name, _Counted(getattr(eng, name),
                                                     tally))
@@ -172,30 +202,65 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode):
     for name in ("asarray", "array", "stack"):
         monkeypatch.setattr(jnp, name,
                             counting(getattr(jnp, name), "uploads"))
-    monkeypatch.setattr(jax, "device_put",
-                        counting(jax.device_put, "uploads"))
-    before = (sched.stats.plain_ticks, sched.stats.spec_ticks)
+    real_put = jax.device_put
+
+    def table_put(x, *args, **kw):
+        # the one transfer the engine makes itself: its block table
+        assert x.shape == eng._table.shape and x.dtype == np.int32
+        tally["table_uploads"] += 1
+        out = real_put(x, *args, **kw)
+        known.append(out)
+        return out
+
+    monkeypatch.setattr(jax, "device_put", table_put)
+    stats = sched.stats
+    before = (stats.plain_ticks, stats.spec_ticks)
+    per_tick = []
     for _ in range(3):
+        was = (stats.page_boundaries + stats.cow_copies,
+               stats.block_table_uploads)
         sched.step()
+        per_tick.append((stats.page_boundaries + stats.cow_copies - was[0],
+                         stats.block_table_uploads - was[1]))
     monkeypatch.undo()
-    ticks = (sched.stats.plain_ticks - before[0],
-             sched.stats.spec_ticks - before[1])
+    ticks = (stats.plain_ticks - before[0], stats.spec_ticks - before[1])
     assert all(sched._decoding(s) for s in sched._slots)
-    return tally, ticks
+    assert tally["table_uploads"] == sum(up for _, up in per_tick)
+    return tally, ticks, per_tick
 
 
+@pytest.mark.parametrize("page_size", [16, 4])
 @pytest.mark.parametrize("mode", ["plain", "spec", "tree"])
 def test_tick_programs_and_uploads_do_not_grow_with_slots(
-        model, monkeypatch, mode):
-    few, ticks_few = _steady_tick_counts(model, monkeypatch, 2, mode)
-    many, ticks_many = _steady_tick_counts(model, monkeypatch, 8, mode)
+        model, monkeypatch, mode, page_size):
+    """With pages of 4 rows the counted ticks cross page boundaries (every
+    slot holds the 8-token prompt: rows 8 and 12 open a page), with pages of
+    16 the plain ones cross none."""
+    few, ticks_few, table_few = _steady_tick_counts(
+        model, monkeypatch, 2, mode, page_size)
+    many, ticks_many, table_many = _steady_tick_counts(
+        model, monkeypatch, 8, mode, page_size)
     assert few["eager_keys"] == 0 and many["eager_keys"] == 0
     assert ticks_few == ticks_many
-    assert few == many
+    # the table: one upload on a tick that mapped or retargeted any number
+    # of pages, none on a tick that did not
+    for mapped, uploads in table_few + table_many:
+        assert uploads == (1 if mapped else 0), (table_few, table_many)
+    # slots that speculate cross their boundaries on different ticks, so the
+    # table's uploads are compared by that law and the rest by count
+    rest = ("programs", "uploads", "eager_keys")
+    assert {k: few[k] for k in rest} == {k: many[k] for k in rest}
     if mode == "plain":
         assert ticks_few == (3, 0)
-        # a tick: decode(tokens, active), finite, sample(base, counts, temps)
-        assert few == {"programs": 9, "uploads": 15, "eager_keys": 0}
+        crossing = [(2, 1), (8, 1)] if page_size == 4 else [(0, 0), (0, 0)]
+        assert [max(table_few), max(table_many)] == crossing
+        # a tick: decode(tokens, active), finite, sample(base, counts, temps),
+        # and in the one tick of the three that opens a page of 4 rows for
+        # every slot, the table; no eager operation on a leaf of the cache
+        assert few == many == {
+            "programs": 9, "uploads": 15, "eager_keys": 0,
+            "table_uploads": 1 if page_size == 4 else 0,
+            "eager_cache_ops": 0}
     else:
         assert ticks_few[1] > 0
 
