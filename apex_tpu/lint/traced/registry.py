@@ -893,9 +893,10 @@ def _paged_decode_step_entry(tp=None):
     return build
 
 
-def _recurrent_step_entry(family, which):
-    """The server's two programs for a model with recurrent layers, at the
-    family's tiny preset. ``hybrid`` (``models.hybrid``): prefill runs the
+def _model_step_entry(family, which):
+    """The server's two programs for a model that brings its own cores
+    (``serving.decode``, "the seam"), at the family's tiny preset.
+    ``hybrid`` (``models.hybrid``): prefill runs the
     chunked Gated DeltaNet kernel and flash attention and overwrites one
     slot's state; decode steps every slot's state through
     ``apex_gdn_decode_fwd``. ``nemotron_h`` (``models.nemotron_h``): prefill
@@ -904,32 +905,42 @@ def _recurrent_step_entry(family, which):
     ``apex_ssd_decode_fwd``, attends over two K/V heads and counts what its
     held experts got. Both donate the cache (pool k/v, lengths, block tables,
     recurrent state, convolution tails; and the second family's three
-    counters): these entries are the kernel families' registration."""
+    counters). ``deepseek`` (``models.deepseek``): prefill expands keys and
+    values from the latent rows it writes and runs flash attention and the
+    grouped expert product; decode attends over ONE pool of latent rows
+    through ``apex_mla_decode_fwd`` and donates it (the pool, lengths, block
+    tables and three counters: 6 pairs). These entries are the kernel
+    families' registration."""
     def build():
         import functools as ft
 
         import jax
 
-        from apex_tpu.serving.cache import init_hybrid_cache
+        from apex_tpu.serving.cache import (
+            init_hybrid_cache, init_latent_cache,
+        )
         from apex_tpu.serving.decode import (
-            make_recurrent_decode_fn, make_recurrent_prefill_fn,
+            make_model_decode_fn, make_model_prefill_fn,
         )
 
+        init_cache = init_hybrid_cache
         if family == "hybrid":
             from apex_tpu.models.hybrid import hybrid_tiny, init_hybrid
             cfg, init = hybrid_tiny(), init_hybrid
-        else:
+        elif family == "nemotron_h":
             from apex_tpu.models.nemotron_h import init, nemotron_h_tiny
             cfg = nemotron_h_tiny()
+        else:
+            from apex_tpu.models.deepseek import deepseek_tiny, init
+            cfg, init_cache = deepseek_tiny(), init_latent_cache
         params = jax.eval_shape(
             lambda k: init(k, cfg), jax.random.PRNGKey(0))
-        cache = jax.eval_shape(ft.partial(
-            init_hybrid_cache, cfg, 2, 32, 6, 16))
+        cache = jax.eval_shape(ft.partial(init_cache, cfg, 2, 32, 6, 16))
         if which == "prefill":
-            return make_recurrent_prefill_fn(cfg), (
+            return make_model_prefill_fn(cfg), (
                 params, cache, _sds((1, 16), "int32"), _sds((16,), "int32"),
                 _sds((), "int32"), _sds((1,), "int32"), _sds((2,), "int32"))
-        return make_recurrent_decode_fn(cfg), (
+        return make_model_decode_fn(cfg), (
             params, cache, _sds((2,), "int32"), _sds((2,), "bool"))
 
     return build
@@ -1516,24 +1527,34 @@ def repo_entries() -> List[TraceEntry]:
                    min_alias_pairs=4),
         TraceEntry("hybrid_prefill_step",
                    "apex_tpu.transformer.functional.gated_delta",
-                   _recurrent_step_entry("hybrid", "prefill"),
+                   _model_step_entry("hybrid", "prefill"),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=6),
         TraceEntry("hybrid_decode_step",
                    "apex_tpu.transformer.functional.gated_delta",
-                   _recurrent_step_entry("hybrid", "decode"),
+                   _model_step_entry("hybrid", "decode"),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=6),
         TraceEntry("nemotron_h_prefill_step",
                    "apex_tpu.transformer.functional.moe",
-                   _recurrent_step_entry("nemotron_h", "prefill"),
+                   _model_step_entry("nemotron_h", "prefill"),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=9),
         TraceEntry("nemotron_h_decode_step",
                    "apex_tpu.transformer.functional.ssd",
-                   _recurrent_step_entry("nemotron_h", "decode"),
+                   _model_step_entry("nemotron_h", "decode"),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=9),
+        TraceEntry("deepseek_prefill_step",
+                   "apex_tpu.models.deepseek",
+                   _model_step_entry("deepseek", "prefill"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=6),
+        TraceEntry("deepseek_decode_step",
+                   "apex_tpu.transformer.functional.mla_attention",
+                   _model_step_entry("deepseek", "decode"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=6),
         TraceEntry("gpt_paged_decode_step_tp2", "apex_tpu.serving.decode",
                    _paged_decode_step_entry(tp=2),
                    checks=("precision", "memory", "schedule", "aliases"),

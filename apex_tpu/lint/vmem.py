@@ -540,6 +540,41 @@ def _nemotron_kernel_cfg(which):
     return build
 
 
+def _deepseek_kernel_cfg(which):
+    """The kernels of the ``deepseek_v3`` family at the published widths.
+    ``mla``: 128 absorbed queries of 640 a slot over the ONE latent pool of
+    5 layers x 25602 pages of 16 rows x 640, which stays where it is;
+    resident are two chunks of 256 rows, the queries and the latent context.
+    ``gate_up`` / ``down``: the grouped product over the SwiGLU expert's two
+    matrices at hidden 7168 (16 held experts of 4 layers, read in place out
+    of the stack), one decode tick's 64 x 8 assignments; resident are a row
+    tile, a column tile of one expert's matrix (256 wide at k = 7168) and the
+    output tile."""
+    def build():
+        import functools as ft
+
+        if which == "mla":
+            from apex_tpu.transformer.functional.mla_attention import (
+                mla_decode_attention,
+            )
+            return ft.partial(mla_decode_attention, value_width=512), (
+                _sds((64, 128, 640), "float32"), _sds((64, 640), "float32"),
+                _sds((5, 25602, 16, 640), "bfloat16"),
+                _sds((64, 400), "int32"), _sds((64,), "int32"),
+                _sds((), "int32"))
+        from apex_tpu.transformer.functional.moe import grouped_matmul
+        k, n = (7168, 4096) if which == "gate_up" else (2048, 7168)
+
+        def product(lhs, rhs, sizes, first):
+            return grouped_matmul(lhs, rhs, sizes, first_group=first)
+
+        return product, (
+            _sds((512, k), "float32"), _sds((64, k, n), "bfloat16"),
+            _sds((16,), "int32"), _sds((), "int32"))
+
+    return build
+
+
 def _draft_forward_cfg():
     """The model drafter's per-token forward (``draft_gpt_tiny`` over
     its dense lockstep cache): XLA math today, so — like the paged
@@ -614,6 +649,13 @@ def repo_configs() -> List[Config]:
     cfgs.append(Config("moe_gmm_120b",
                        "apex_tpu.transformer.functional.moe",
                        _nemotron_kernel_cfg("gmm")))
+    cfgs.append(Config("mla_decode_671b",
+                       "apex_tpu.transformer.functional.mla_attention",
+                       _deepseek_kernel_cfg("mla")))
+    for which in ("gate_up", "down"):
+        cfgs.append(Config(f"moe_gmm_{which}_671b",
+                           "apex_tpu.transformer.functional.moe",
+                           _deepseek_kernel_cfg(which)))
     cfgs.append(Config("gpt_spec_verify_step", "apex_tpu.serving.decode",
                        _paged_serving_cfg("verify")))
     cfgs.append(Config("gpt_tree_verify_step", "apex_tpu.serving.decode",

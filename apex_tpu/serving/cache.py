@@ -153,6 +153,30 @@ class HybridKVCache(NamedTuple):
     v_scale = None
 
 
+class LatentKVCache(NamedTuple):
+    """The cache of a model whose attention reads ONE row per token and layer
+    (``cfg.latent``: ``apex_tpu.models.deepseek``, multi-head latent
+    attention): ``k`` is the only pool, ``[L, num_pages, page_size,
+    kv_row_width]``, a row holding the normed compressed latent and the roped
+    shared key side by side (padded with zeros to whole 128-lane tiles); key
+    and value of every head are read out of that one row, so there is no
+    ``v``. ``lengths`` / ``block_tables`` are :class:`PagedKVCache`'s, and the
+    host side (``PagePool``, block tables, prefix sharing, copy-on-write,
+    preemption, page transfer) treats a latent page as any page: there is no
+    per-slot state beside the pool. ``counters`` as in
+    :class:`HybridKVCache`."""
+    k: jax.Array             # (L, num_pages, page_size, kv_row_width)
+    lengths: jax.Array       # (num_slots,) int32
+    block_tables: jax.Array  # (num_slots, max_pages) int32
+    counters: Optional[dict] = None
+
+    # one pool: what reads ``cache.v`` finds nothing to copy, write or ship
+    v = None
+    # no quantized latent pool (the engine refuses it)
+    k_scale = None
+    v_scale = None
+
+
 def max_pages_per_slot(max_len: int, page_size: int) -> int:
     return -(-max_len // page_size)
 
@@ -182,8 +206,7 @@ def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
             "max_position_embeddings or use rope")
     shape = (cfg.num_layers, num_pages, page_size,
              cfg.num_heads * cfg.head_dim)
-    bt = jnp.full((num_slots, max_pages_per_slot(max_len, page_size)),
-                  SCRATCH_PAGE, jnp.int32)
+    bt = _parked_tables(num_slots, max_len, page_size)
     if jnp.dtype(dtype) == jnp.int8:
         # quantized pool: zero int8 pages + zero fp32 scales (a
         # 0-scale page dequantizes to exact zeros, so NULL stays
@@ -201,6 +224,20 @@ def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
                         block_tables=bt)
 
 
+def _parked_tables(num_slots: int, max_len: int, page_size: int):
+    """Block tables with every row parked on ``SCRATCH_PAGE``."""
+    return jnp.full((num_slots, max_pages_per_slot(max_len, page_size)),
+                    SCRATCH_PAGE, jnp.int32)
+
+
+def _zero_counters(cfg):
+    """The zeroed int32 counters a model's decode program keeps in its cache
+    (``cfg.counter_shapes``), or nothing."""
+    shapes = getattr(cfg, "counter_shapes", lambda: None)()
+    return shapes and {name: jnp.zeros(shape, jnp.int32)
+                       for name, shape in shapes.items()}
+
+
 def init_hybrid_cache(cfg, num_slots: int, max_len: int, num_pages: int,
                       page_size: int, dtype=jnp.bfloat16) -> HybridKVCache:
     """The two kinds of state of a model with recurrent layers (what
@@ -214,17 +251,29 @@ def init_hybrid_cache(cfg, num_slots: int, max_len: int, num_pages: int,
         raise ValueError("no int8 pool beside recurrent state")
     shape = (cfg.kv_layers, num_pages, page_size, cfg.kv_row_width)
     state, conv = cfg.state_shapes(num_slots)
-    counters = getattr(cfg, "counter_shapes", lambda: None)()
     return HybridKVCache(
         k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
         lengths=jnp.zeros((num_slots,), jnp.int32),
-        block_tables=jnp.full(
-            (num_slots, max_pages_per_slot(max_len, page_size)),
-            SCRATCH_PAGE, jnp.int32),
+        block_tables=_parked_tables(num_slots, max_len, page_size),
         state=jnp.zeros(state, jnp.float32),
         conv=jnp.zeros(conv, jnp.float32),
-        counters=counters and {name: jnp.zeros(shape, jnp.int32)
-                               for name, shape in counters.items()})
+        counters=_zero_counters(cfg))
+
+
+def init_latent_cache(cfg, num_slots: int, max_len: int, num_pages: int,
+                      page_size: int, dtype=jnp.bfloat16) -> LatentKVCache:
+    """The one pool of a model with latent attention (what ``cfg`` states:
+    ``serving.decode``, "the seam"), rows of ``kv_row_width``, block tables
+    parked on ``SCRATCH_PAGE``; zeroed counters where the model keeps any."""
+    _check_pool_sizes(num_slots, max_len, num_pages, page_size)
+    if jnp.dtype(dtype) == jnp.int8:
+        raise ValueError("no int8 latent pool")
+    return LatentKVCache(
+        k=jnp.zeros((cfg.kv_layers, num_pages, page_size, cfg.kv_row_width),
+                    dtype),
+        lengths=jnp.zeros((num_slots,), jnp.int32),
+        block_tables=_parked_tables(num_slots, max_len, page_size),
+        counters=_zero_counters(cfg))
 
 
 def audit_block_tables(block_tables, slot_pages) -> bool:

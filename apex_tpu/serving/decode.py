@@ -84,7 +84,7 @@ from apex_tpu.models.gpt import (
     _pages_to_tiles, _rope_or_none, _tied_lm_logits, _tiles_to_pages,
 )
 from apex_tpu.serving.cache import (
-    HybridKVCache, KVCache, PagedKVCache, cache_partition_specs,
+    KVCache, PagedKVCache, cache_partition_specs,
     paged_cache_partition_specs,
 )
 
@@ -402,7 +402,8 @@ def _write_new_rows(cache, k_rows, v_rows):
     tables: the pool as a list of rows (a view: its layout is row-major)
     takes the layers * slots rows in ONE in-place row scatter. Inactive
     slots write to the page their NULL/scratch row names. Returns the
-    pool's ``(k, v)``."""
+    pool's ``(k, v)``; a cache with one pool (``cache.v`` is ``None``: a
+    latent pool) takes ``v_rows`` ``None`` and gives ``v`` ``None``."""
     pos, bt = cache.lengths, cache.block_tables
     layers, num_pages, page_size, width = cache.k.shape
     logical = jnp.clip(pos // page_size, 0, bt.shape[1] - 1)
@@ -414,7 +415,8 @@ def _write_new_rows(cache, k_rows, v_rows):
         flat = pool.reshape(-1, width).at[at].set(rows.reshape(-1, width))
         return flat.reshape(pool.shape)
 
-    return write(cache.k, k_rows), write(cache.v, v_rows)
+    return write(cache.k, k_rows), \
+        None if cache.v is None else write(cache.v, v_rows)
 
 
 def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
@@ -808,32 +810,49 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
 
 
 # ---------------------------------------------------------------------------
-# a model with recurrent layers: the same two programs, for every family
+# a model that brings its own cores: the same two programs, for every family
 # ---------------------------------------------------------------------------
 #
-# The seam (ROADMAP D11). A config whose ``recurrent`` is true states, and
-# the engine takes here and nowhere else:
+# The seam (ROADMAP D11). A config with a ``decode_core`` (:func:`model_cores`)
+# states, and the engine takes here and nowhere else:
 #   ``kv_layers``, ``kv_row_width``        the page pool's leading axis and
-#                                          row (the attention layers only)
-#   ``state_shapes(slots)``                (recurrent state, convolution
-#                                          tail) shapes, float32, per slot
-#   ``state_bytes_per_slot()``             what a prefill writes besides pages
+#                                          row
 #   ``counter_shapes()`` (optional)        int32 counters kept in the cache
 #   ``prefill_core(params, ids, mask, kv_dtype)`` -> (x (s, hidden), states,
-#       tails (recurrent layers, ...), k, v (kv_layers, s, kv_row_width))
+#       tails, k, v (kv_layers, s, kv_row_width))
 #   ``decode_core(params, cache, tokens, active)`` -> (x (slots, hidden),
 #       state', conv', counters', k_rows, v_rows (kv_layers, slots, width))
 #   ``logits_of(params, x)``               final norm and head
-# ``models.hybrid`` and ``models.nemotron_h`` stand on it.
+# and TWO independent facts about its cache:
+#   ``recurrent``   it keeps per-slot state beside the pool, whole at every
+#                   moment (``state_shapes(slots)``: recurrent state and
+#                   convolution tail, float32; ``state_bytes_per_slot()``:
+#                   what a prefill writes besides pages). The pool then
+#                   counts the attention layers only, and whatever would need
+#                   a snapshot of the state is refused
+#                   (``scheduler._refuse_for_recurrent``).
+#   ``latent``      its pool is ONE pool of rows that are key and value at
+#                   once (``serving.cache.LatentKVCache``): both cores give
+#                   ``v`` ``None``, and with no state ``states`` / ``tails`` /
+#                   ``state'`` / ``conv'`` ``None`` too. A latent page is
+#                   shared, copied, preempted and shipped as any page; what
+#                   needs a program the model does not bring is refused
+#                   (``scheduler._refuse_without_a_core``).
+# ``models.hybrid`` and ``models.nemotron_h`` (recurrent) and
+# ``models.deepseek`` (latent) stand on it.
 
-def _recurrent_prefill_core(params, cfg, cache: HybridKVCache, ids, mask,
-                            slot, write_pages, table_row):
-    """:func:`_paged_prefill_core` for a model with recurrent layers: the
-    attention layers' K/V rows go to ``write_pages`` exactly as there (the
-    pool's layers are the attention layers), and every recurrent layer's
-    state and convolution tail, as the prompt's last real token left them,
-    overwrite row ``slot`` of ``cache.state`` / ``cache.conv``: that write
-    is the slot's only reset."""
+def model_cores(cfg) -> bool:
+    """Does ``cfg`` bring its own prefill and decode cores (the seam)?"""
+    return callable(getattr(cfg, "decode_core", None))
+
+
+def _model_prefill_core(params, cfg, cache, ids, mask, slot, write_pages,
+                        table_row):
+    """:func:`_paged_prefill_core` for a model that brings its cores: the
+    pool layers' rows go to ``write_pages`` exactly as there, and every
+    recurrent layer's state and convolution tail (where the model has any),
+    as the prompt's last real token left them, overwrite row ``slot`` of
+    ``cache.state`` / ``cache.conv``: that write is the slot's only reset."""
     if ids.ndim != 2 or ids.shape[0] != 1:
         raise ValueError(f"prefill takes one slot's (1, s) ids, got "
                          f"{ids.shape}")
@@ -856,58 +875,65 @@ def _recurrent_prefill_core(params, cfg, cache: HybridKVCache, ids, mask,
         t = t * mask.astype(t.dtype)[None, :, None]
         return t.reshape(t.shape[0], -1, page_size, t.shape[-1])
 
-    return cache._replace(
-        k=cache.k.at[:, write_pages].set(pages(k)),
-        v=cache.v.at[:, write_pages].set(pages(v)),
-        lengths=lax.dynamic_update_slice(cache.lengths, length[None],
-                                         (slot,)),
-        block_tables=lax.dynamic_update_slice(
-            cache.block_tables, table_row[None, :], (slot, 0)),
-        state=lax.dynamic_update_slice(
-            cache.state, states[:, None], (0, slot, 0, 0, 0)),
-        conv=lax.dynamic_update_slice(
-            cache.conv, tails[:, None], (0, slot, 0, 0)),
-        # a prefill counts nothing; the donated leaves still need a write
-        counters=jax.tree.map(_self_rewrite, cache.counters)), logits
+    new = {"k": cache.k.at[:, write_pages].set(pages(k))}
+    if v is not None:
+        new["v"] = cache.v.at[:, write_pages].set(pages(v))
+    new["lengths"] = lax.dynamic_update_slice(cache.lengths, length[None],
+                                              (slot,))
+    new["block_tables"] = lax.dynamic_update_slice(
+        cache.block_tables, table_row[None, :], (slot, 0))
+    if states is not None:
+        new["state"] = lax.dynamic_update_slice(
+            cache.state, states[:, None], (0, slot, 0, 0, 0))
+        new["conv"] = lax.dynamic_update_slice(
+            cache.conv, tails[:, None], (0, slot, 0, 0))
+    # a prefill counts nothing; the donated leaves still need a write
+    new["counters"] = jax.tree.map(_self_rewrite, cache.counters)
+    return cache._replace(**new), logits
 
 
-def _recurrent_decode_core(params, cfg, cache: HybridKVCache, tokens, active):
-    """:func:`_paged_decode_core` for a model with recurrent layers: the
-    model steps every recurrent layer's slice of the stacked state in place
-    and attends over the pool in place (``cfg.decode_core``), and the
-    attention layers' new rows go into the pool in one scatter after it.
-    Slots that are not ``active`` keep their recurrent state and their
-    length."""
+def _model_decode_core(params, cfg, cache, tokens, active):
+    """:func:`_paged_decode_core` for a model that brings its cores: the
+    model attends over the pool in place and steps every recurrent layer's
+    slice of the stacked state in place (``cfg.decode_core``), and the pool
+    layers' new rows go into the pool in one scatter after it. Slots that
+    are not ``active`` keep their recurrent state and their length."""
     x, state, conv, counters, k_rows, v_rows = cfg.decode_core(
         params, cache, tokens, active)
     logits = cfg.logits_of(params, x)
     k, v = _write_new_rows(cache, k_rows, v_rows)
     pos = cache.lengths
-    return cache._replace(
-        k=k, v=v, lengths=jnp.where(active, pos + 1, pos),
-        block_tables=_self_rewrite(cache.block_tables), state=state,
-        conv=conv, counters=counters), logits
+    new = {"k": k}
+    if v is not None:
+        new["v"] = v
+    new["lengths"] = jnp.where(active, pos + 1, pos)
+    new["block_tables"] = _self_rewrite(cache.block_tables)
+    if state is not None:
+        new["state"], new["conv"] = state, conv
+    new["counters"] = counters
+    return cache._replace(**new), logits
 
 
-def make_recurrent_prefill_fn(cfg):
-    """jit(prefill) for a model with recurrent layers, cache DONATED (6
-    alias pairs: pool k/v, lengths, block tables, recurrent state,
-    convolution tails; and one per counter); one executable per bucket, and
-    the same program name as every other prefill (``jit_prefill``)."""
+def make_model_prefill_fn(cfg):
+    """jit(prefill) for a model that brings its cores, cache DONATED (with
+    recurrent layers 6 alias pairs: pool k/v, lengths, block tables,
+    recurrent state, convolution tails; with a latent pool 3: the pool,
+    lengths, block tables; and one per counter); one executable per bucket,
+    and the same program name as every other prefill (``jit_prefill``)."""
 
     def prefill(params, cache, ids, mask, slot, write_pages, table_row):
-        return _recurrent_prefill_core(params, cfg, cache, ids, mask, slot,
-                                       write_pages, table_row)
+        return _model_prefill_core(params, cfg, cache, ids, mask, slot,
+                                   write_pages, table_row)
 
     return jax.jit(prefill, donate_argnums=1)
 
 
-def make_recurrent_decode_fn(cfg):
-    """jit(decode) for a model with recurrent layers, cache DONATED; one
+def make_model_decode_fn(cfg):
+    """jit(decode) for a model that brings its cores, cache DONATED; one
     executable per cache shape (``jit_decode``)."""
 
     def decode(params, cache, tokens, active):
-        return _recurrent_decode_core(params, cfg, cache, tokens, active)
+        return _model_decode_core(params, cfg, cache, tokens, active)
 
     return jax.jit(decode, donate_argnums=1)
 
@@ -927,7 +953,9 @@ def make_copy_page_fn():
             return lax.dynamic_update_slice_in_dim(pool, page, dst,
                                                    axis=1)
 
-        new = cache._replace(k=clone(cache.k), v=clone(cache.v))
+        new = cache._replace(k=clone(cache.k))
+        if cache.v is not None:         # a latent pool is the one pool
+            new = new._replace(v=clone(cache.v))
         if cache.k_scale is not None:
             new = new._replace(k_scale=clone(cache.k_scale),
                                v_scale=clone(cache.v_scale))

@@ -136,15 +136,16 @@ import numpy as np
 from apex_tpu.models.gpt import GPTConfig
 from apex_tpu.serving.cache import (
     NULL_PAGE, RESERVED_PAGES, SCRATCH_PAGE, audit_block_tables,
-    init_cache, init_hybrid_cache, init_paged_cache, max_pages_per_slot,
+    init_cache, init_hybrid_cache, init_latent_cache, init_paged_cache,
+    max_pages_per_slot,
 )
 from apex_tpu.serving.decode import (
     make_chunk_prefill_fn, make_copy_page_fn, make_decode_fn,
-    make_recurrent_decode_fn, make_recurrent_prefill_fn,
+    make_model_decode_fn, make_model_prefill_fn,
     make_paged_chunk_prefill_fn, make_paged_decode_fn,
     make_paged_prefill_fn, make_paged_tree_verify_fn,
     make_paged_verify_fn, make_prefill_fn, make_tree_verify_fn,
-    make_verify_fn,
+    make_verify_fn, model_cores,
 )
 from apex_tpu.serving.draft import ngram_draft, tree_arrays
 from apex_tpu.serving.faults import FaultInjector, InjectedFault
@@ -222,13 +223,31 @@ def _refuse_for_recurrent(cfg, **features) -> None:
     program takes yet (ROADMAP, Queue 2). So the feature is refused by name
     where it is asked for, at construction, and never falls back in
     silence. ``features``: name -> (asked for, what it would need)."""
-    if not getattr(cfg, "recurrent", False):
-        return
+    if getattr(cfg, "recurrent", False):
+        _refuse(cfg, "for a model with recurrent layers", features)
+
+
+def _refuse(cfg, where: str, features) -> None:
     for name, (asked, needs) in features.items():
         if asked:
-            raise ValueError(
-                f"{name} is not offered for a model with recurrent layers "
-                f"({type(cfg).__name__}): {needs}")
+            raise ValueError(f"{name} is not offered {where} "
+                             f"({type(cfg).__name__}): {needs}")
+
+
+def _refuse_without_a_core(cfg, **features) -> None:
+    """A model that brings its own cores (``serving.decode``, "the seam")
+    and keeps no per-slot state brings exactly two, monolithic prefill and
+    plain decode, over a pool only they can read (``cfg.latent``: one row a
+    token that is key and value at once). On the HOST a latent page is a
+    page like any other: prefix sharing, copy-on-write, preemption by
+    requeue and page transfer are offered. What needs a third program over
+    that pool (a verify step, a chunk's write-then-attend, the quantized
+    pool's write and gather, dequant-fused projections) is refused by name
+    where it is asked for, at construction, and never falls back in
+    silence (ROADMAP, M3). ``features``: name -> (asked for, what it would
+    need)."""
+    if model_cores(cfg) and not getattr(cfg, "recurrent", False):
+        _refuse(cfg, "over a latent pool", features)
 
 
 def _pad_on_host(tokens: Sequence[int], buckets: Sequence[int]):
@@ -297,6 +316,8 @@ class DecodeEngine:
         _refuse_for_recurrent(cfg, **{"the dense cache": (
             True, "the recurrent state lives beside the paged pool; use "
             "PagedDecodeEngine")})
+        _refuse_without_a_core(cfg, **{"the dense cache": (
+            True, "its decode core reads pages; use PagedDecodeEngine")})
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
@@ -749,6 +770,30 @@ class PagedDecodeEngine(DecodeEngine):
                    compute_dtype is not None, "its programs fix their "
                    "precision: each product's inputs in the weights' "
                    "dtype, float32 between two products")})
+        _refuse_without_a_core(
+            cfg,
+            draft_model=(draft_model is not None, "speculation is refused, "
+                         "so a drafter has no use"),
+            tree_spec=(tree_spec, "tree verify needs a core that scores "
+                       "k+1 absorbed queries a slot under an ancestor mask"),
+            spec_k=(spec_k > 0, "verify needs a core that writes and "
+                    "attends k+1 latent rows a slot"),
+            **{"the int8 pool (cache_dtype=int8)": (
+                jnp.dtype(cache_dtype) == jnp.int8, "a latent row has no "
+                "heads to scale by, and its decode kernel reads the pool's "
+                "own dtype"),
+               "the host tier (host_tier=)": (
+                   host_tier is not None, "a promoted prefix is attended by "
+                   "the suffix as a chunk, which needs a chunked-prefill "
+                   "core"),
+               "weight-only int8 (a quantized tree)": (
+                   is_quantized_tree(params), "its cores have no "
+                   "dequant-fused projections"),
+               "compute_dtype": (
+                   compute_dtype is not None, "its programs fix their "
+                   "precision: each product's inputs in the weights' "
+                   "dtype, float32 between two products")})
+        self.model_cores = model_cores(cfg)
         self.draft_model = draft_model
         self.tree_spec = tree_spec
         self.adaptive_spec = adaptive_spec
@@ -762,6 +807,7 @@ class PagedDecodeEngine(DecodeEngine):
         # block tables) is dtype-agnostic throughout
         quantized = is_quantized_tree(params)
         self.cache = (init_hybrid_cache if self.recurrent
+                      else init_latent_cache if self.model_cores
                       else init_paged_cache)(cfg, num_slots, max_len,
                                              num_pages, page_size,
                                              cache_dtype)
@@ -803,14 +849,19 @@ class PagedDecodeEngine(DecodeEngine):
         # scratch (see begin_chunk_prefill), so the audit must not
         # expect it to mirror _slot_pages yet
         self._prefill_parked: set = set()
-        if self.recurrent:
-            # the ONE recurrent path, whatever the family: the model states
-            # its two cores, state shapes and row width (serving.decode,
-            # "the seam"), and what needs more programs was refused above.
-            # Bytes a prefill writes besides its pages:
-            self._state_bytes = cfg.state_bytes_per_slot()
-            self._prefill = make_recurrent_prefill_fn(cfg)
-            self._decode = make_recurrent_decode_fn(cfg)
+        if self.model_cores:
+            # the ONE path of a model that brings its cores, whatever the
+            # family: the model states them, its row width and whether it
+            # keeps state beside the pool (serving.decode, "the seam"), and
+            # what needs more programs was refused above. Bytes a prefill
+            # writes besides its pages (recurrent state), or in a page (a
+            # latent pool):
+            self._state_bytes = cfg.state_bytes_per_slot() \
+                if self.recurrent else None
+            self._page_bytes = cfg.kv_layers * page_size * cfg.kv_row_width \
+                * jnp.dtype(cache_dtype).itemsize
+            self._prefill = make_model_prefill_fn(cfg)
+            self._decode = make_model_decode_fn(cfg)
             self._chunk_prefill = self._verify = self._tree_verify = None
         else:
             self._prefill = make_paged_prefill_fn(cfg, compute_dtype,
@@ -945,8 +996,9 @@ class PagedDecodeEngine(DecodeEngine):
                   bucket=bucket_for(len(toks) - start, self.buckets),
                   prompt_tokens=len(toks), shared_pages=covered,
                   page_size=self.page_size,
-                  **({"state_bytes": self._state_bytes}
-                     if self.recurrent else {}))
+                  **({"state_bytes": self._state_bytes} if self.recurrent
+                     else {"latent_bytes": len(private) * self._page_bytes}
+                     if self.model_cores else {}))
         if skip:
             ids, mask = _pad_on_host(toks[start:], self.buckets)
             write = np.full((ids.shape[1] // self.page_size,),
@@ -1407,6 +1459,12 @@ class ContinuousBatchingScheduler:
                 chunk_tokens is not None, "a chunk would have to start "
                 "from the recurrent state the chunk before it left, which "
                 "no program carries")})
+        _refuse_without_a_core(
+            getattr(engine, "cfg", None),
+            **{"chunked prefill (chunk_tokens=)": (
+                chunk_tokens is not None, "a chunk attends the latent rows "
+                "of the chunks before it, which needs a core that expands "
+                "or absorbs over mapped pages at prompt length")})
         if chunk_tokens is not None:
             chunk_tokens = int(chunk_tokens)
             if chunk_tokens < 1:

@@ -96,10 +96,15 @@ def make_extract_pages_fn() -> Callable:
     identified pages out of a paged cache's pool — the sender half of
     the handoff. Tiles are ``(layers, n_pages, page_size, heads *
     head_dim)`` in the pool dtype. Read-only (no donation): the source
-    cache keeps serving its own slots."""
+    cache keeps serving its own slots. A latent pool
+    (``serving.cache.LatentKVCache``) has no ``v``: its pages travel as the
+    same pair with a ``v_tile`` of width 0, so the wire format, the checksum
+    and the receiver stay as they are."""
 
     def extract(cache, page_ids):
-        return cache.k[:, page_ids], cache.v[:, page_ids]
+        k_tile = cache.k[:, page_ids]
+        return k_tile, (k_tile[..., :0] if cache.v is None
+                        else cache.v[:, page_ids])
 
     return jax.jit(extract)
 
@@ -113,8 +118,10 @@ def make_insert_pages_fn() -> Callable:
     decode step's row append."""
 
     def insert(cache, page_ids, k_tile, v_tile):
-        return cache._replace(k=cache.k.at[:, page_ids].set(k_tile),
-                              v=cache.v.at[:, page_ids].set(v_tile))
+        new = cache._replace(k=cache.k.at[:, page_ids].set(k_tile))
+        if cache.v is None:         # a latent pool: the width-0 tile is no
+            return new              # pool's
+        return new._replace(v=cache.v.at[:, page_ids].set(v_tile))
 
     return jax.jit(insert, donate_argnums=(0,))
 

@@ -6,8 +6,8 @@ add is simply not there.
 
 ``route``: sigmoid scores in float32, the ``k`` experts with the largest
 ``score + bias`` (the bias moves the choice and not the weight; ties go to the
-lower index, ``lax.top_k``'s rule), weights ``scale * s / (sum of the k chosen
-+ 1e-20)``.
+lower index, ``lax.top_k``'s rule), optionally among the best groups of
+experts only, weights ``scale * s / (sum of the k chosen + 1e-20)``.
 
 ``dispatch``: the ``rows * k`` assignments sorted by expert, stably, those
 that fall on an expert not held here (or on a row that is padding) last. Every
@@ -45,11 +45,35 @@ _ROW_TILE = 16
 _RHS_TILE_BYTES = 3 << 20
 
 
-def route(logits, bias, top_k: int, scale: float):
+def route(logits, bias, top_k: int, scale: float, n_group: int = 1,
+          topk_group: int = 1):
     """``logits`` (rows, experts) float32, ``bias`` (experts,). Returns
-    ``(experts (rows, k) int32, weights (rows, k) float32)``."""
+    ``(experts (rows, k) int32, weights (rows, k) float32)``.
+
+    With ``n_group`` > 1 the choice is limited to groups (the experts of one
+    node, in the deployment the rule was made for): the experts stand in
+    ``n_group`` groups of equal size, side by side; a group's score is the
+    sum of its two largest ``score + bias``; only the ``topk_group`` best
+    groups (ties to the lower index) stay eligible, and the ``k`` largest
+    ``score + bias`` are taken inside them. One group is no limit and adds
+    no operation."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, chosen = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        rows, experts = choice.shape
+        if experts % n_group or not 0 < topk_group <= n_group \
+                or topk_group * (experts // n_group) < top_k:
+            raise ValueError(
+                f"{experts} experts in {n_group} groups of which "
+                f"{topk_group} stay cannot give {top_k} a token")
+        grouped = choice.reshape(rows, n_group, experts // n_group)
+        group_score = jnp.sum(lax.top_k(grouped, 2)[0], -1)
+        _, kept = lax.top_k(group_score, topk_group)
+        eligible = jnp.zeros((rows, n_group), bool).at[
+            jnp.arange(rows)[:, None], kept].set(True)
+        choice = jnp.where(eligible[:, :, None], grouped,
+                           -jnp.inf).reshape(rows, experts)
+    _, chosen = lax.top_k(choice, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     return chosen.astype(jnp.int32), scale * picked / (
         jnp.sum(picked, -1, keepdims=True) + 1e-20)
@@ -116,8 +140,10 @@ def _visits(sizes, m: int, tm: int):
     return group, tile.astype(jnp.int32), offsets, n.astype(jnp.int32)[None]
 
 
-def _gmm_kernel(group_ref, tile_ref, offsets_ref, n_ref, lhs_ref, rhs_ref,
-                out_ref, *, activation):
+def _gmm_kernel(group_ref, tile_ref, offsets_ref, n_ref, *refs, activation):
+    # after the four scalars every call has: the matrices' own indices where
+    # ``rhs`` holds more than this call's groups, then rows, matrices, result
+    lhs_ref, rhs_ref, out_ref = refs[-3:]
     v = pl.program_id(1)
 
     @pl.when(v < n_ref[0])
@@ -147,17 +173,20 @@ def _gmm_kernel(group_ref, tile_ref, offsets_ref, n_ref, lhs_ref, rhs_ref,
 
 def _column_tile(k: int, n: int, itemsize: int) -> int:
     """The widest divisor of ``n`` in whole 128-lane tiles whose ``(k, tile)``
-    block stays under ``_RHS_TILE_BYTES``; ``n`` itself when it is small or
-    no multiple of 128."""
-    if n % 128 or k * n * itemsize <= _RHS_TILE_BYTES:
+    block stays under ``_RHS_TILE_BYTES``, or under two lane tiles' worth
+    where ``k`` is so long that this is more (hidden 7168 in bfloat16: 3.7
+    MB, so that a tile is 256 columns and a matrix's grid steps half as
+    many); ``n`` itself when it is small or no multiple of 128."""
+    most = max(_RHS_TILE_BYTES, k * 256 * itemsize)
+    if n % 128 or k * n * itemsize <= most:
         return n
     fits = [t for t in range(128, n, 128)
-            if n % t == 0 and k * t * itemsize <= _RHS_TILE_BYTES]
+            if n % t == 0 and k * t * itemsize <= most]
     return max(fits) if fits else 128
 
 
 def grouped_matmul(lhs, rhs, sizes, *, activation=None, out_dtype=None,
-                   interpret=None):
+                   first_group=None, interpret=None):
     """``lhs`` (m, k), its rows sorted by group: the first ``sizes[0]`` rows
     belong to group 0, the next ``sizes[1]`` to group 1, ...; ``rhs``
     (groups, k, n); ``sizes`` (groups,) int32 with ``sum(sizes) <= m``.
@@ -167,10 +196,18 @@ def grouped_matmul(lhs, rhs, sizes, *, activation=None, out_dtype=None,
     bfloat16 ``rhs`` go in as two bfloat16 terms, ``hi + lo`` (two MXU passes
     over a matrix fetched once: the product is bound by reading the matrices,
     and the router downstream is why: ``models.nemotron_h._dense``); rows
-    already in ``rhs``'s dtype go in as they are."""
+    already in ``rhs``'s dtype go in as they are.
+
+    ``first_group`` (a scalar int32, traced or not): ``rhs`` holds MORE
+    matrices than this call's groups, ``(all groups, k, n)``, and group ``g``
+    multiplies by ``rhs[first_group + g]``. That is how a layer under
+    ``lax.scan`` reads its experts out of the stacked weights of all layers
+    in place: a layer's slice of them, handed to a kernel, is a copy of every
+    expert's matrix, hit or not, each step."""
     m, k = lhs.shape
-    groups, k2, n = rhs.shape
-    if k != k2 or sizes.shape != (groups,):
+    all_groups, k2, n = rhs.shape
+    groups = all_groups if first_group is None else sizes.shape[0]
+    if k != k2 or sizes.shape != (groups,) or groups > all_groups:
         raise ValueError(f"lhs {lhs.shape}, rhs {rhs.shape}, sizes "
                          f"{sizes.shape} do not fit")
     if activation not in (None, "relu2"):
@@ -182,16 +219,21 @@ def grouped_matmul(lhs, rhs, sizes, *, activation=None, out_dtype=None,
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     tn = _column_tile(k, n, rhs.dtype.itemsize)
     group, tile, offsets, real = _visits(sizes.astype(jnp.int32), m + pad, tm)
+    scalars = (group, tile, offsets, real)
+    if first_group is not None:
+        scalars += (group + jnp.asarray(first_group, jnp.int32),)
+    matrix = len(scalars) - 1 if first_group is not None else 0
     with jax.named_scope("apex_moe_gmm_fwd"):
         out = pl.pallas_call(
             functools.partial(_gmm_kernel, activation=activation),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=4, grid=(n // tn, group.shape[0]),
+                num_scalar_prefetch=len(scalars),
+                grid=(n // tn, group.shape[0]),
                 in_specs=[
                     pl.BlockSpec((tm, k), lambda j, v, g, t, *_: (t[v], 0),
                                  memory_space=pltpu.VMEM),
                     pl.BlockSpec((1, k, tn),
-                                 lambda j, v, g, t, *_: (g[v], 0, j),
+                                 lambda j, v, *s: (s[matrix][v], 0, j),
                                  memory_space=pltpu.VMEM)],
                 out_specs=pl.BlockSpec(
                     (tm, tn), lambda j, v, g, t, *_: (t[v], j),
@@ -201,5 +243,5 @@ def grouped_matmul(lhs, rhs, sizes, *, activation=None, out_dtype=None,
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=pallas_interpret(interpret),
             name="apex_moe_gmm_fwd",
-        )(group, tile, offsets, real, lhs, rhs)
+        )(*scalars, lhs, rhs)
     return out[:m] if pad else out

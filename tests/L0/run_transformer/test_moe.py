@@ -162,3 +162,115 @@ def test_float32_rows_into_bfloat16_matrices_go_in_as_two_terms():
                                         jnp.asarray(sizes)))
     err = lambda got: float(np.abs(got[:24] - want[:24]).max())
     assert err(two) < 2e-3 < 0.02 < err(one)
+
+
+# -- the group limit (deepseek_v3: n_group 8, topk_group 4) ---------------------
+
+def by_the_rule(logits, bias, k, scale, n_group, topk_group):
+    """The published rule, row by row in numpy."""
+    scores = 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
+    choice = scores + np.asarray(bias, np.float64)
+    rows, experts = choice.shape
+    per = experts // n_group
+    chosen, weights = [], []
+    for r in range(rows):
+        groups = choice[r].reshape(n_group, per)
+        score = np.sort(groups, -1)[:, -2:].sum(-1)
+        kept = np.argsort(-score, kind="stable")[:topk_group]
+        eligible = np.full(experts, -np.inf)
+        for g in kept:
+            eligible[g * per:(g + 1) * per] = choice[r, g * per:(g + 1) * per]
+        picked = np.argsort(-eligible, kind="stable")[:k]
+        chosen.append(picked)
+        weights.append(scale * scores[r, picked] / scores[r, picked].sum())
+    return np.stack(chosen), np.stack(weights)
+
+
+def test_one_group_lowers_to_what_no_group_limit_lowers_to():
+    args = (jax.ShapeDtypeStruct((6, 16), jnp.float32),
+            jax.ShapeDtypeStruct((16,), jnp.float32))
+    plain = jax.jit(lambda l, b: moe.route(l, b, 4, 2.5)).lower(*args)
+    one = jax.jit(lambda l, b: moe.route(l, b, 4, 2.5, 1, 1)).lower(*args)
+    assert plain.as_text() == one.as_text()
+
+
+def test_group_limited_route_is_the_published_rule():
+    rng = np.random.RandomState(11)
+    logits = jnp.asarray(rng.standard_normal((64, 32)), jnp.float32)
+    bias = jnp.asarray(0.05 * rng.standard_normal((32,)), jnp.float32)
+    chosen, weights = moe.route(logits, bias, 6, 2.5, n_group=8, topk_group=3)
+    want, want_w = by_the_rule(logits, bias, 6, 2.5, 8, 3)
+    np.testing.assert_array_equal(chosen, want)
+    np.testing.assert_allclose(weights, want_w, rtol=1e-5)
+    # the limit does something here: without it other experts are chosen
+    free, _ = moe.route(logits, bias, 6, 2.5)
+    assert (np.sort(np.asarray(free), -1)
+            != np.sort(np.asarray(chosen), -1)).any()
+
+
+def test_a_group_outside_the_best_is_never_chosen_whatever_its_top_expert():
+    """Group 2 holds the single largest score and one dud: its two best sum
+    to less than groups 0 and 1, so with two groups kept it is out, and its
+    top expert with it."""
+    logits = jnp.asarray([[2.0, 1.9, 1.8, 1.7, 5.0, -9.0]])
+    chosen, weights = moe.route(logits, jnp.zeros((6,)), 3, 1.0, n_group=3,
+                                topk_group=2)
+    assert sorted(np.asarray(chosen[0]).tolist()) == [0, 1, 2]
+    np.testing.assert_allclose(np.asarray(weights).sum(), 1.0, rtol=1e-6)
+    # all three groups kept: the largest score is back
+    chosen, _ = moe.route(logits, jnp.zeros((6,)), 3, 1.0, n_group=3,
+                          topk_group=3)
+    assert 4 in np.asarray(chosen[0]).tolist()
+
+
+def test_the_bias_moves_groups_and_choices_and_never_the_weights():
+    logits = jnp.asarray([[1.0, 0.9, 0.2, 0.1, 0.8, 0.7, 0.0, -0.1]])
+    none = jnp.zeros((8,))
+    chosen, _ = moe.route(logits, none, 2, 2.5, n_group=4, topk_group=1)
+    assert sorted(np.asarray(chosen[0]).tolist()) == [0, 1]
+    # a bias on group 2's experts makes it the best group ...
+    bias = none.at[4].set(0.3).at[5].set(0.3)
+    chosen, weights = moe.route(logits, bias, 2, 2.5, n_group=4,
+                                topk_group=1)
+    assert sorted(np.asarray(chosen[0]).tolist()) == [4, 5]
+    # ... and the weights are the unbiased scores', normalised and scaled
+    s = np.asarray(jax.nn.sigmoid(logits[0]))[np.asarray(chosen[0])]
+    np.testing.assert_allclose(weights[0], 2.5 * s / s.sum(), rtol=1e-6)
+
+
+def test_group_limits_that_cannot_give_k_are_refused():
+    with pytest.raises(ValueError, match="cannot give"):
+        moe.route(jnp.zeros((2, 16)), jnp.zeros((16,)), 6, 1.0, n_group=4,
+                  topk_group=1)
+    with pytest.raises(ValueError, match="cannot give"):
+        moe.route(jnp.zeros((2, 10)), jnp.zeros((10,)), 2, 1.0, n_group=4,
+                  topk_group=2)
+
+
+def test_a_long_k_widens_the_column_tile_and_short_ones_keep_theirs():
+    # the published latent experts (k 1024 and 2688): as before
+    assert moe._column_tile(1024, 2688, 2) == 896
+    assert moe._column_tile(2688, 1024, 2) == 512
+    # hidden 7168 in bfloat16: 128 columns fit 3 MB, two lane tiles are taken
+    assert moe._column_tile(7168, 4096, 2) == 256
+    assert moe._column_tile(2048, 7168, 2) == 512
+    assert moe._column_tile(64, 200, 4) == 200        # no multiple of 128
+
+
+def test_a_layers_experts_are_read_out_of_the_stack_in_place():
+    """``first_group``: ``rhs`` holds three layers' experts; a call names
+    its layer by the first of them, traced or not, and gives what the
+    layer's own slice gives."""
+    sizes = jnp.asarray([5, 0, 9, 2], jnp.int32)
+    rng = np.random.RandomState(8)
+    lhs = jnp.asarray(rng.standard_normal((16, 64)), jnp.float32)
+    stack = jnp.asarray(rng.standard_normal((3, 4, 64, 256)), jnp.float32)
+    flat = stack.reshape(12, 64, 256)
+    for layer in range(3):
+        want = moe.grouped_matmul(lhs, stack[layer], sizes)
+        got = jax.jit(lambda first: moe.grouped_matmul(
+            lhs, flat, sizes, first_group=first))(jnp.int32(4 * layer))
+        np.testing.assert_array_equal(np.asarray(got)[:16],
+                                      np.asarray(want)[:16])
+    with pytest.raises(ValueError, match="do not fit"):
+        moe.grouped_matmul(lhs, flat[:3], sizes, first_group=0)

@@ -269,7 +269,7 @@ def test_nemotron_full_size_programs(v5e):
     from apex_tpu.models import nemotron_h as nh
     from apex_tpu.serving.cache import init_hybrid_cache
     from apex_tpu.serving.decode import (
-        make_recurrent_decode_fn, make_recurrent_prefill_fn,
+        make_model_decode_fn, make_model_prefill_fn,
     )
 
     slots, bucket = 128, 512
@@ -290,9 +290,9 @@ def test_nemotron_full_size_programs(v5e):
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=sharding)
     i32 = jnp.int32
     programs = {
-        "decode": make_recurrent_decode_fn(cfg).lower(
+        "decode": make_model_decode_fn(cfg).lower(
             params, cache, sds((slots,), i32), sds((slots,), jnp.bool_)),
-        "prefill": make_recurrent_prefill_fn(cfg).lower(
+        "prefill": make_model_prefill_fn(cfg).lower(
             params, cache, sds((1, bucket), i32), sds((bucket,), i32),
             sds((), i32), sds((bucket // 16,), i32), sds((64,), i32))}
     want = {"decode": {"apex_ssd_decode_fwd": 5, "apex_moe_gmm_fwd": 10,
@@ -312,6 +312,100 @@ def test_nemotron_full_size_programs(v5e):
         assert mem.temp_size_in_bytes < 0.5e9, name
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < 0.85 * 16 * 2 ** 30, name
+
+
+def test_deepseek_kernels(v5e):
+    """``apex_mla_decode_fwd`` at the cell's shapes (128 absorbed queries of
+    640 a slot, 64 slots, the full pool of 25602 pages of 16 rows over 5
+    layers, 400 pages a slot), and the grouped product at the SwiGLU expert's
+    two shapes (the fused gate and up matrix at hidden 7168, where a column
+    tile is 256 wide), for a decode tick's rows and a prompt block's."""
+    from apex_tpu.transformer.functional import moe
+    from apex_tpu.transformer.functional.mla_attention import (
+        mla_decode_attention,
+    )
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert compile_on(
+        v5e, functools.partial(mla_decode_attention, value_width=512),
+        ((64, 128, 640), f32), ((64, 640), f32),
+        ((5, 25602, 16, 640), bf16), ((64, 400), jnp.int32),
+        ((64,), jnp.int32), ((), jnp.int32)) == 1
+    assert moe._column_tile(7168, 4096, 2) == 256       # 3.7 MB a tile
+    assert moe._column_tile(2048, 7168, 2) == 512
+    # a scanned layer's experts, read in place out of the four layers' stack
+    gmm = lambda lhs, rhs, sizes, first: moe.grouped_matmul(
+        lhs, rhs, sizes, first_group=first)
+    for rows in (64 * 8, 1024 * 8):
+        assert compile_on(
+            v5e, gmm, ((rows, 7168), f32), ((64, 7168, 4096), bf16),
+            ((16,), jnp.int32), ((), jnp.int32)) == 1
+        assert compile_on(
+            v5e, gmm, ((rows, 2048), f32), ((64, 2048, 7168), bf16),
+            ((16,), jnp.int32), ((), jnp.int32)) == 1
+
+
+def test_deepseek_full_size_programs(v5e):
+    """The programs of ``deepseek_v3.resident_context_decode`` at full size
+    (one leading dense layer and 4 expert layers, 16 of 256 experts, an
+    eighth of the vocabulary, 64 slots, the full pool of 6400 positions a
+    slot): decode and every prefill bucket compile for a v5e with no chip;
+    ``memory_analysis`` gives what the configuration file says (9.13 GB of
+    weights, 2.62 GB of cache in ONE pool, all of it aliased) and fits the
+    chip; the kernel names are the engagement counters the trace readers
+    count (``apex_mla_decode_fwd`` once a layer in decode and never in a
+    prefill, which expands)."""
+    import re
+
+    from apex_tpu.models import deepseek
+    from apex_tpu.serving.cache import init_latent_cache
+    from apex_tpu.serving.decode import (
+        make_model_decode_fn, make_model_prefill_fn,
+    )
+
+    slots, max_len, page = 64, 6400, 16
+    cfg = deepseek.DeepseekConfig(vocab_size=16160, num_layers=5,
+                                  first_k_dense=1, experts_held=16)
+    sharding = SingleDeviceSharding(v5e)
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+    params = on(jax.eval_shape(lambda k: deepseek.init(k, cfg, jnp.bfloat16),
+                               jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(functools.partial(
+        init_latent_cache, cfg, slots, max_len,
+        slots * (max_len // page) + 2, page, jnp.bfloat16)))
+    size = lambda tree: sum(a.size * a.dtype.itemsize
+                            for a in jax.tree.leaves(tree))
+    assert round(size(params) / 1e9, 2) == 9.13
+    assert round(size(cache) / 1e9, 2) == 2.62
+    assert cache.k.shape == (5, 25602, 16, 640) and cache.v is None
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=sharding)
+    i32 = jnp.int32
+    programs = {"decode": make_model_decode_fn(cfg).lower(
+        params, cache, sds((slots,), i32), sds((slots,), jnp.bool_))}
+    for bucket in (1024, 2048, 4096):
+        programs[f"prefill_{bucket}"] = make_model_prefill_fn(cfg).lower(
+            params, cache, sds((1, bucket), i32), sds((bucket,), i32),
+            sds((), i32), sds((bucket // page,), i32),
+            sds((max_len // page,), i32))
+    # a scanned layer's kernels stand once in the program's text
+    want = {"decode": {"apex_mla_decode_fwd": 2, "apex_moe_gmm_fwd": 2,
+                       "apex_flash_fwd": 0},
+            "prefill": {"apex_mla_decode_fwd": 0, "apex_moe_gmm_fwd": 2,
+                        "apex_flash_fwd": 2}}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        kind = name.split("_")[0]
+        got = {k: len(re.findall(rf"%{k}(\.\d+)? = ", text))
+               for k in want[kind]}
+        assert got == want[kind], name
+        mem = compiled.memory_analysis()
+        assert 0 <= mem.alias_size_in_bytes - size(cache) < 1 << 16, name
+        print(name, mem.temp_size_in_bytes / 1e9,
+              (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30)
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 0.96 * 16 * 2 ** 30, name
 
 
 def test_flat_adam(v5e):

@@ -40,11 +40,62 @@ _HEAVY_MODULES = {
 }
 
 
+# Seven assertions of ``tests/L0/run_benchmark/test_nemotron_cell.py`` pin
+# the benchmark as PR 33 left it: its configuration, cell and readers as the
+# LAST entries of their lists, the serving cells as four and the backlog
+# mixes as four. That file lies under the benchmark's own ``paths``, which
+# only a ``benchmark`` PR may edit, and a ``model_config`` PR has to APPEND a
+# configuration, a cell and readers (PR 36: deepseek_v3), so each of these
+# has to fail. Exactly these ids are expected failures, STRICT: one that
+# starts to pass again fails the run until its line here is deleted. All they
+# check besides their pins is asserted again, by name, in
+# ``test_deepseek_cell.py`` (the window line of all five serving cells
+# included; the four pinned runs of it fail on their first line, before any
+# rehearsal). The next ``benchmark`` PR should relax the pins and delete this
+# list and the one in ``tests/L0/run_benchmark/conftest.py`` (ROADMAP B1 (n)).
+_NEMOTRON = "tests/L0/run_benchmark/test_nemotron_cell.py::"
+PINNED_BY_PR_33 = {
+    _NEMOTRON + "test_manifest_gains_the_cell_and_only_appends":
+        "asserts nemotron3_super_120b_a12b's configuration, cell and readers "
+        "are the LAST entries: new entries have to be appended after them",
+    _NEMOTRON + "test_what_the_pinned_tests_of_pr_27_check_besides":
+        "asserts olmo_hybrid_7b is configs[-2] and its readers stand right "
+        "before the last six per_layer entries",
+    _NEMOTRON + "test_the_backlog_mixes_are_the_four_serving_cells":
+        "asserts the backlog mixes are four; resident_context_decode is a "
+        "fifth",
+    **{_NEMOTRON + f"test_window_line_of_every_serving_cell[{cell}]":
+       "asserts SERVING == the four serving cells PR 33 knew; the benchmark "
+       "has five since PR 36" for cell in (
+           "gpt2_medium.offline_decode", "gpt2_medium.prompt_backlog",
+           "olmo_hybrid_7b.long_prompt_decode",
+           "nemotron3_super_120b_a12b.many_slot_decode")},
+}
+# An eighth id did not exist before PR 36: ``test_rehearsal.py`` makes one
+# case of its pinned test per serving cell of the manifest, so the new cell
+# makes a new case of it (the four others are in the benchmark's own list).
+# Not run: it would rehearse the cell only to fail on SERVING, and
+# ``test_deepseek_cell.py::test_window_line_of_every_serving_cell`` rehearses
+# it and asserts the same lines.
+NEW_CASE_OF_A_PINNED_TEST = {
+    "tests/L0/run_benchmark/test_rehearsal.py::"
+    "test_window_line_says_what_is_left_of_the_backlog"
+    "[deepseek_v3.resident_context_decode]":
+        "asserts SERVING == the three serving cells PR 32 knew",
+}
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         p = item.path
         if p.name not in _HEAVY_MODULES and "L1" not in p.parts:
             item.add_marker(pytest.mark.quick)
+        if item.nodeid in PINNED_BY_PR_33:
+            item.add_marker(pytest.mark.xfail(
+                reason=PINNED_BY_PR_33[item.nodeid], strict=True))
+        elif item.nodeid in NEW_CASE_OF_A_PINNED_TEST:
+            item.add_marker(pytest.mark.xfail(
+                reason=NEW_CASE_OF_A_PINNED_TEST[item.nodeid], run=False))
 
 
 @pytest.fixture(autouse=True)
