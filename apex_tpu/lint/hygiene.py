@@ -23,9 +23,10 @@ Host-module references (``time``, ``random``, ``numpy``/``np.random``,
 ``datetime``) are matched against the module's actual imports, so
 ``from jax import random`` never false-positives.
 
-Roots also propagate *across modules*: ``jax.jit(sample_stream)`` in
-``serving/scheduler.py`` makes ``sample_stream`` — defined in
-``serving/sampling.py`` — a traced body, even though sampling.py itself
+Roots also propagate *across modules*: ``jax.jit(sample_stream_checked)``
+in ``serving/scheduler.py`` makes ``sample_stream_checked`` — defined in
+``serving/sampling.py`` — a traced body, and with it what it calls there
+(``sample_stream``, ``finite_rows``), even though sampling.py itself
 never mentions jit. :func:`check_files` collects such imported-name
 roots per file (via the importing module's ``from apex_tpu.x import
 name`` statements), maps each dotted module back to its file in the

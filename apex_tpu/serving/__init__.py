@@ -130,9 +130,9 @@ from apex_tpu.serving.router import (  # noqa: F401
     DisaggregatedRouter, PoolRouter,
 )
 from apex_tpu.serving.sampling import (  # noqa: F401
-    finite_rows, sample_stream, sample_stream_grid, sample_token_grid,
-    sample_tokens, speculative_accept, stream_keys,
-    tree_speculative_accept,
+    finite_rows, sample_stream, sample_stream_checked, sample_stream_grid,
+    sample_stream_grid_checked, sample_token_grid, sample_tokens,
+    speculative_accept, stream_keys, tree_speculative_accept,
 )
 from apex_tpu.serving.scheduler import (  # noqa: F401
     ContinuousBatchingScheduler, DecodeEngine, PagedDecodeEngine, Request,

@@ -466,6 +466,7 @@ STAT_FIELDS = {
     "slo_violations": "finished requests that broke their tenant SLO",
     "page_boundaries": "fresh pages mapped as a slot crossed a boundary",
     "block_table_uploads": "host block table sent to the device",
+    "sampler_waits": "blocking read-backs of a checked sampler's result",
 }
 
 

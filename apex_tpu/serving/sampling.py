@@ -16,6 +16,16 @@ the program that samples. The schedule is the one above; only the
 number of device programs changed, which no longer grows with the
 slots.
 
+The finiteness gate rides in the same program. What the scheduler
+launches behind a step or a prefill is ``sample_stream_checked`` /
+``sample_stream_grid_checked``: the sampler's tokens and
+``finite_rows(logits)`` stacked into ONE int32 array (row 0 the tokens,
+row 1 the flags), so the host waits once and reads back once per tick
+or admission. The flag has a row of its own because no token value can
+stand for "not finite": an out-of-range token is a different fault
+(``stats.bad_samples``) from a non-finite row (``stats.nan_events``).
+A row that is not finite is sampled like any other and never committed.
+
 One fused entry point handles the whole batch: per-slot temperature
 (``<= 0`` selects greedy) so mixed greedy/sampled slots decode in one
 jitted step instead of recompiling per request mix. ``top_k`` / ``top_p``
@@ -128,6 +138,34 @@ def sample_stream_grid(logits: jax.Array, base: jax.Array,
                              temperature, top_k, top_p)
 
 
+def _checked(tokens: jax.Array, logits: jax.Array) -> jax.Array:
+    """``tokens`` (…) int32 and ``finite_rows(logits)`` as ONE int32
+    array (2, …): ``[0]`` the tokens, ``[1]`` 1 where the row they were
+    drawn from is entirely finite."""
+    return jnp.stack([tokens, finite_rows(logits).astype(jnp.int32)])
+
+
+def sample_stream_checked(logits: jax.Array, base: jax.Array,
+                          counts: jax.Array, temperature: jax.Array,
+                          top_k: int = 0, top_p: float = 0.0) -> jax.Array:
+    """:func:`sample_stream` and the finiteness gate in one program with
+    one result: (2, B) int32, ``[0]`` the tokens :func:`sample_stream`
+    gives bit for bit, ``[1]`` :func:`finite_rows` of the same logits."""
+    return _checked(sample_stream(logits, base, counts, temperature,
+                                  top_k, top_p), logits)
+
+
+def sample_stream_grid_checked(logits: jax.Array, base: jax.Array,
+                               counts: jax.Array, temperature: jax.Array,
+                               top_k: int = 0,
+                               top_p: float = 0.0) -> jax.Array:
+    """:func:`sample_stream_grid` and the finiteness gate over a verify
+    step's (B, k1, V) logits: (2, B, k1) int32, laid out as
+    :func:`sample_stream_checked`."""
+    return _checked(sample_stream_grid(logits, base, counts, temperature,
+                                       top_k, top_p), logits)
+
+
 def speculative_accept(tokens: jax.Array, drafts: jax.Array,
                        draft_lens: jax.Array) -> jax.Array:
     """Vectorized accept rule: ``tokens`` (B, k1) are the grid-sampled
@@ -200,8 +238,9 @@ def tree_speculative_accept(samples: jax.Array, tokens: jax.Array,
 def finite_rows(logits: jax.Array) -> jax.Array:
     """(…, V) -> (…,) bool — True where a row of ``logits`` is entirely
     finite. The scheduler's always-on NaN/Inf quarantine gate: a
-    device-side reduction so each tick ships B (or B×k1) bools to the
-    host instead of the logits matrix. A False row is never sampled
-    into a stream — the slot is quarantined and the request retried
-    (``serving.health.NonFiniteLogits``)."""
+    device-side reduction, so each tick ships B (or B×k1) flags to the
+    host instead of the logits matrix, in the sampler's own result
+    (:func:`sample_stream_checked`). The token of a False row is never
+    committed to a stream — the slot is quarantined and the request
+    retried (``serving.health.NonFiniteLogits``)."""
     return jnp.all(jnp.isfinite(logits), axis=-1)

@@ -19,6 +19,16 @@ each request's base key, ``PRNGKey(request.seed)`` read once when it is
 admitted or resumed (``_base_key``), and the number of the token. A tick
 dispatches and uploads the same whatever the number of slots.
 
+One wait a tick: everywhere logits become tokens (the plain, verify and
+tree-verify ticks, a request's first token after a prefill or a final
+chunk) the scheduler launches ONE program behind the step that made
+them, the sampler with the finiteness gate in it
+(``sampling.sample_stream_checked``; ``engine.sample`` / ``sample_grid``
+return its result on the device, the copy down started), and reads ONE
+array back (``_await_sampler``, inside the ``accept`` span): the tokens
+and the finite flags. A steady plain tick is two device programs and one
+read-back; ``stats.sampler_waits`` counts the waits.
+
 Chunked prefill (``chunk_tokens=``, the Sarathi-Serve move): a
 monolithic prompt forward stalls every co-tenant decode for the whole
 prompt length, which is exactly what blows up p99 inter-token latency
@@ -89,9 +99,10 @@ instead of crashing or spinning:
   ``None``; every request ends in a
   :class:`~apex_tpu.serving.health.RequestOutcome` with a typed
   reason, in ``scheduler.outcomes``.
-- **quarantine + retry budget** — non-finite logits or an
-  out-of-vocabulary sampled token quarantines the slot: the corrupt
-  token is never committed, the slot is freed and the request requeued
+- **quarantine + retry budget** — non-finite logits (the gate's flag,
+  read back beside the tokens) or an out-of-vocabulary sampled token
+  quarantines the slot: the corrupt token is never committed, the
+  slot is freed and the request requeued
   at the queue FRONT with its progress. Because resume re-prefills the
   committed tokens and keys depend only on ``(seed, n_generated)``,
   the recovered stream is bit-identical to the fault-free one — and
@@ -166,7 +177,7 @@ from apex_tpu.serving.transfer import (
     make_insert_pages_fn, make_insert_pages_quant_fn,
 )
 from apex_tpu.serving.sampling import (
-    finite_rows, sample_stream, sample_stream_grid,
+    sample_stream_checked, sample_stream_grid_checked,
     tree_speculative_accept,
 )
 from apex_tpu.utils.profiler import span as profiler_span
@@ -374,11 +385,10 @@ class DecodeEngine:
                     f"{self.cfg.vocab_size})")
 
     def _init_samplers(self) -> None:
-        self._sample = jax.jit(sample_stream,
+        self._sample = jax.jit(sample_stream_checked,
                                static_argnames=("top_k", "top_p"))
-        self._sample_grid = jax.jit(sample_stream_grid,
+        self._sample_grid = jax.jit(sample_stream_grid_checked,
                                     static_argnames=("top_k", "top_p"))
-        self._finite = jax.jit(finite_rows)
 
     def prefill(self, slot: int, prompt: Sequence[int]) -> jax.Array:
         """Run the full forward over ``prompt`` into cache row ``slot``;
@@ -449,9 +459,9 @@ class DecodeEngine:
         Returns (num_slots, V) fp32 logits. An armed ``decode_exec``
         fault site overwrites one deterministic victim row with NaN
         AFTER the jitted step — the compiled program and the other
-        rows stay bit-exact, and the scheduler's finiteness gate
-        (:func:`~apex_tpu.serving.sampling.finite_rows`) must catch
-        it."""
+        rows stay bit-exact, and the finiteness gate in the sampler's
+        program (:func:`~apex_tpu.serving.sampling.finite_rows`) must
+        catch it."""
         trc = self.tracer
         trc.begin("exec", kind="decode", **self._exec_stats())
         self.sync_table()
@@ -474,24 +484,35 @@ class DecodeEngine:
         changed it (nothing here: a dense cache row is its own map)."""
 
     def sample(self, logits, base, counts, temperature) -> jax.Array:
-        """One token per row of ``logits`` (B, V): row b draws with
-        ``fold_in(base[b], counts[b])``, derived inside the sampler
-        program (:func:`~apex_tpu.serving.sampling.stream_keys`) from
-        the request's base key and the number of the token."""
-        toks = self._sample(logits, base, counts, temperature,
-                            top_k=self.top_k, top_p=self.top_p)
+        """LAUNCH the checked sampler on ``logits`` (B, V) and return its
+        result on the device, not waited for: (2, B) int32, ``[0]`` one
+        token per row — row b draws with ``fold_in(base[b], counts[b])``,
+        derived inside the program
+        (:func:`~apex_tpu.serving.sampling.stream_keys`) from the
+        request's base key and the number of the token — and ``[1]``
+        which rows are finite, i.e. safe to commit
+        (:func:`~apex_tpu.serving.sampling.finite_rows`). Called right
+        behind the step or prefill that made ``logits``, with host
+        arrays for the rest: they go up while the chip still runs the
+        step, the program queues behind it, and the copy down starts
+        when it ends. The caller's ``np.asarray`` is the one wait."""
+        return self._launched(self._sample(
+            logits, base, counts, temperature, top_k=self.top_k,
+            top_p=self.top_p))
+
+    def _launched(self, out: jax.Array) -> jax.Array:
+        """A checked sampler's result (2, B[, k1]) on its way down: the
+        ``sample`` fault site drawn (it writes into the victim slot's
+        token, on a grid its FIRST position), the copy to the host
+        started, nothing waited for."""
         fired, payload = self.injector.draw("sample")
         if fired:
             # out-of-vocabulary id: negative, so it can never collide
             # with a real token — the scheduler's range check quarantines
-            victim = int(payload % toks.shape[0])
-            toks = toks.at[victim].set(jnp.int32(-1 - payload % 7))
-        return toks
-
-    def finite(self, logits) -> jax.Array:
-        """(B,) bool device reduction: which logits rows are safe to
-        sample (see :func:`~apex_tpu.serving.sampling.finite_rows`)."""
-        return self._finite(logits)
+            at = (0, int(payload % out.shape[1])) + (0,) * (out.ndim - 2)
+            out = out.at[at].set(jnp.int32(-1 - payload % 7))
+        out.copy_to_host_async()
+        return out
 
     # -- speculative decoding -------------------------------------------
 
@@ -609,18 +630,16 @@ class DecodeEngine:
         trc.end("commit")
 
     def sample_grid(self, logits, base, counts, temperature) -> jax.Array:
-        """Sample every (slot, position) of a verify step's logits with
-        its own key, ``fold_in(base[b], counts[b, j])``, derived in the
-        same program; the ``sample`` fault site corrupts the victim
-        slot's FIRST position (the one a plain tick would have drawn),
-        so the scheduler's range gate quarantines before any commit."""
-        toks = self._sample_grid(logits, base, counts, temperature,
-                                 top_k=self.top_k, top_p=self.top_p)
-        fired, payload = self.injector.draw("sample")
-        if fired:
-            victim = int(payload % toks.shape[0])
-            toks = toks.at[victim, 0].set(jnp.int32(-1 - payload % 7))
-        return toks
+        """:meth:`sample` over a verify step's (B, k1, V) logits: launches
+        the checked grid sampler and returns (2, B, k1) int32 on the
+        device, every (slot, position) drawn with its own key,
+        ``fold_in(base[b], counts[b, j])``, derived in the same program;
+        the ``sample`` fault site corrupts the victim slot's FIRST
+        position (the one a plain tick would have drawn), so the
+        scheduler's range gate quarantines before any commit."""
+        return self._launched(self._sample_grid(
+            logits, base, counts, temperature, top_k=self.top_k,
+            top_p=self.top_p))
 
     # scheduler hooks, no-ops for the dense engine: a cache row needs
     # no per-token capacity and frees by being overwritten
@@ -1663,14 +1682,26 @@ class ContinuousBatchingScheduler:
                 counts[i] = len(s.generated)
         return last, active, temps, base, counts
 
+    def _await_sampler(self, launched) -> Tuple[np.ndarray, np.ndarray]:
+        """The ONE blocking read-back of a tick or an admission: the
+        result of ``engine.sample`` / ``sample_grid``, launched behind
+        the step, as (tokens, finite) on the host.
+        ``stats.sampler_waits`` counts these waits: 1 per decode tick of
+        any kind and 1 per first token sampled."""
+        out = np.asarray(launched)
+        self.stats.sampler_waits += 1
+        return out[0], out[1].astype(bool)
+
     def _first_token(self, logits, base_key: np.ndarray,
-                     temperature: float) -> int:
+                     temperature: float) -> Tuple[int, bool]:
         """Sample a request's token 0 from its prefill logits (1, V)
-        with ``fold_in(PRNGKey(seed), 0)``: the tick's sampler at a
-        batch of one."""
-        return int(self.engine.sample(
+        with ``fold_in(PRNGKey(seed), 0)``: the tick's checked sampler at
+        a batch of one, launched behind the prefill and waited for once.
+        Returns the token and whether the logits were finite."""
+        toks, finite = self._await_sampler(self.engine.sample(
             logits, base_key[None, :], np.zeros((1,), np.int32),
-            np.asarray([temperature], np.float32))[0])
+            np.asarray([temperature], np.float32)))
+        return int(toks[0]), bool(finite[0])
 
     # -- typed termination ------------------------------------------------
 
@@ -2007,15 +2038,15 @@ class ContinuousBatchingScheduler:
                 # logits; on resume it already exists. Both gates below
                 # are the always-on production checks the decode tick
                 # also applies.
-                if not bool(np.asarray(eng.finite(logits)).all()):
+                first_tok, finite = self._first_token(logits, base_key,
+                                                      req.temperature)
+                if not finite:
                     self.stats.nan_events += 1
                     if self._fail_admission(i, rid, NonFiniteLogits(
                             f"request {rid}: non-finite prefill "
                             "logits")):
                         continue
                     break
-                first_tok = self._first_token(logits, base_key,
-                                              req.temperature)
                 if not 0 <= first_tok < eng.cfg.vocab_size:
                     self.stats.bad_samples += 1
                     if self._fail_admission(i, rid, NonFiniteLogits(
@@ -2129,13 +2160,13 @@ class ContinuousBatchingScheduler:
         eng.finish_chunk_prefill(i, s.prefill.state)
         s.prefill = None
         if not s.generated:
-            if not bool(np.asarray(eng.finite(logits)).all()):
+            first_tok, finite = self._first_token(logits, s.base_key,
+                                                  s.request.temperature)
+            if not finite:
                 self.stats.nan_events += 1
                 self._fail_prefill(i, NonFiniteLogits(
                     f"request {rid}: non-finite prefill logits"))
                 return
-            first_tok = self._first_token(logits, s.base_key,
-                                          s.request.temperature)
             if not 0 <= first_tok < eng.cfg.vocab_size:
                 self.stats.bad_samples += 1
                 self._fail_prefill(i, NonFiniteLogits(
@@ -2424,8 +2455,8 @@ class ContinuousBatchingScheduler:
         trc.end("build_inputs")
         logits = eng.decode(tokens, active)
         trc.begin("accept")
-        finite = np.asarray(eng.finite(logits))
-        next_tokens = np.asarray(eng.sample(logits, base, counts, temps))
+        next_tokens, finite = self._await_sampler(
+            eng.sample(logits, base, counts, temps))
         trc.end("accept")
         trc.begin("commit")
         vocab = eng.cfg.vocab_size
@@ -2489,8 +2520,8 @@ class ContinuousBatchingScheduler:
         trc.end("build_inputs")
         logits = eng.verify(tokens)
         trc.begin("accept")
-        finite = np.asarray(eng.finite(logits))            # (B, k1)
-        grid = np.asarray(eng.sample_grid(logits, base, offs, temps))
+        grid, finite = self._await_sampler(                # (B, k1) each
+            eng.sample_grid(logits, base, offs, temps))
         vocab = eng.cfg.vocab_size
         counts = [0] * eng.num_slots
         quarantined: List[Tuple[int, NonFiniteLogits]] = []
@@ -2647,8 +2678,8 @@ class ContinuousBatchingScheduler:
         trc.end("build_inputs")
         logits = eng.tree_verify(tok_np, dep_np, anc_np)
         trc.begin("accept")
-        finite = np.asarray(eng.finite(logits))            # (B, k1)
-        grid = np.asarray(eng.sample_grid(logits, base, offs, temps))
+        grid, finite = self._await_sampler(                # (B, k1) each
+            eng.sample_grid(logits, base, offs, temps))
         cnts, path = self._tree_accept(grid, tok_np, par_np, val_np,
                                        start_np)
         cnts, path = np.asarray(cnts), np.asarray(path)

@@ -342,6 +342,111 @@ def test_spec_multi_fault_chaos_is_typed_prefixed_and_replayable(
     assert replay.engine.injector.counts == sched.engine.injector.counts
 
 
+# -- the gate and the sampler are one program, read back once ------------------
+
+#: (nan_events, bad_samples, retries, faults fired, sites consulted) of the
+#: run below as the tree BEFORE the two were one program gave them (commit
+#: ef5603f: ``finite`` and ``sample`` two programs, each waited for), by
+#: (mode, injector seed). ``decode_exec`` is drawn by the step, ``sample``
+#: behind the sampler's launch: one order of draws, then as now.
+_GATE_CHAOS = {
+    ("plain", 0): (2, 3, 5, (2, 3), (13, 18)),
+    ("plain", 1): (1, 2, 3, (2, 2), (13, 18)),
+    ("spec", 0): (1, 3, 4, (2, 3), (13, 17)),
+    ("spec", 1): (1, 2, 3, (1, 2), (11, 15)),
+    ("tree", 0): (1, 1, 2, (1, 3), (11, 16)),
+    ("tree", 1): (1, 2, 3, (2, 2), (12, 16)),
+    ("chunked", 0): (2, 2, 4, (2, 4), (18, 22)),
+    ("chunked", 1): (3, 2, 5, (3, 4), (17, 22)),
+}
+
+
+@pytest.mark.parametrize("mode,seed", sorted(_GATE_CHAOS))
+def test_decode_and_sample_faults_on_one_schedule_keep_their_counts(
+        model, mode, seed):
+    """``decode_exec`` and ``sample`` armed together: a NaN row and an
+    out-of-range token are told apart although both now come back in one
+    array (``nan_events`` against ``bad_samples``), every site is consulted
+    as often and fires as often as before, as many retries are charged, and
+    every stream recovers to the fault-free golden one."""
+    reqs = [Request(prompt=(7, 11, 7, 11, 7), max_new_tokens=6),
+            Request(prompt=(17, 19, 17, 19), max_new_tokens=6,
+                    temperature=0.8, seed=3),
+            Request(prompt=(7, 11, 13, 29), max_new_tokens=5),
+            Request(prompt=(5, 3) * 5, max_new_tokens=6,
+                    temperature=0.7, seed=9)]
+    golden = _golden(model, reqs)
+    inj = FaultInjector(seed=seed, rates={"decode_exec": 0.2,
+                                          "sample": 0.2})
+    if mode in ("spec", "tree"):
+        eng = _model_spec_engine(model, inj, tree=True) if mode == "tree" \
+            else _spec_engine(model, inj)
+    else:
+        eng = _engine(model, inj)
+    sched, outs = _drive(eng, reqs, audit=True,
+                         **({"chunk_tokens": 4} if mode == "chunked"
+                            else {}))
+    assert outs == golden
+    assert all(o.ok for o in sched.outcomes.values())
+    st = sched.stats
+    sites = ("decode_exec", "sample")
+    assert (st.nan_events, st.bad_samples, st.retries,
+            tuple(inj.counts[s] for s in sites),
+            tuple(inj.calls(s) for s in sites)) == _GATE_CHAOS[mode, seed]
+
+
+def _nan_once(engine, method, when=lambda *a: True):
+    """``engine.<method>`` returns NaN logits the first time ``when`` holds
+    of its arguments: a prefill whose output is not finite, which no fault
+    site makes (``prefill_exec`` raises before the program runs)."""
+    real, done = getattr(engine, method), []
+
+    def spoiled(*args, **kw):
+        logits = real(*args, **kw)
+        if not done and when(*args):
+            done.append(True)
+            return logits * float("nan")
+        return logits
+
+    setattr(engine, method, spoiled)
+
+
+@pytest.mark.parametrize("fault", ["non_finite", "out_of_range"])
+@pytest.mark.parametrize("path", ["admit", "finish_prefill"])
+def test_first_token_gates_fail_the_admission_and_free_the_slot(
+        model, path, fault):
+    """Both gates on a request's FIRST token, read from one result of one
+    program: a prefill that is not finite and a first token outside the
+    vocabulary each fail the admission, monolithic (``_admit``) and after
+    the final chunk (``_finish_prefill``) alike: the slot and its pages are
+    freed, one retry is charged, the fault is counted under its own name,
+    nothing is committed, and the retried request gives the golden stream."""
+    reqs = [Request(prompt=(7, 11, 13), max_new_tokens=4,
+                    temperature=0.8, seed=5)]
+    golden = _golden(model, reqs)
+    chunked = path == "finish_prefill"
+    eng = _engine(model, FaultInjector(schedule={"sample": (0,)})
+                  if fault == "out_of_range" else None)
+    if fault == "non_finite":
+        _nan_once(eng, *(("chunk_prefill", lambda *a: a[5]) if chunked
+                         else ("prefill",)))
+    sched = ContinuousBatchingScheduler(
+        eng, eos_id=EOS, audit=True,
+        **({"chunk_tokens": 4} if chunked else {}))
+    sched.submit(reqs[0])
+    sched.step()
+    st = sched.stats
+    counted = (1, 0) if fault == "non_finite" else (0, 1)
+    assert (st.nan_events, st.bad_samples) == counted
+    assert st.retries == 1 and sched._tokens_emitted == 0
+    assert all(s is None for s in sched._slots)
+    assert all(not pages for pages in eng._slot_pages)
+    assert [rid for rid, _, _ in sched._queue] == [0]
+    assert sched.run() == golden
+    assert sched.outcomes[0].ok and sched.outcomes[0].retries == 1
+    assert (st.nan_events, st.bad_samples) == counted      # and no more
+
+
 # -- typed terminations ------------------------------------------------------
 
 def test_retry_budget_exhausted_surfaces_typed(model):

@@ -5,9 +5,12 @@ speculative grid/accept helpers that reuse the same sampler."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from apex_tpu.serving import (sample_token_grid, sample_tokens,
-                              speculative_accept)
+from apex_tpu.serving import (finite_rows, sample_stream,
+                              sample_stream_checked, sample_stream_grid,
+                              sample_stream_grid_checked, sample_token_grid,
+                              sample_tokens, speculative_accept)
 
 V = 64
 
@@ -196,3 +199,50 @@ def test_speculative_accept_pad_positions_never_match():
     np.testing.assert_array_equal(
         np.asarray(speculative_accept(
             toks, drafts, jnp.asarray([0], jnp.int32))), [0])
+
+
+# -- the finiteness gate in the sampler's program -----------------------------
+
+_BAD = {1: jnp.nan, 3: jnp.inf, 4: -jnp.inf}      # row -> what spoils it
+
+
+@pytest.mark.parametrize("restrict", [{}, {"top_k": 5}, {"top_p": 0.6},
+                                      {"top_k": 9, "top_p": 0.8}],
+                         ids=["full", "top_k", "top_p", "top_k_top_p"])
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "T0.8"])
+@pytest.mark.parametrize("grid", [False, True], ids=["BV", "Bk1V"])
+def test_checked_sampler_is_the_sampler_and_the_gate(grid, temperature,
+                                                     restrict):
+    """One jitted program, one result: row 0 is what ``sample_stream`` /
+    ``sample_stream_grid`` gives for the same arguments bit for bit (the
+    spoiled rows too: a row's draw never reads another row), row 1 is
+    ``finite_rows`` of the same logits. NaN, +inf and -inf each spoil a row
+    in ONE element; on the grid they sit at different positions, so a flag
+    that read its slot and not its position would show."""
+    b, k1 = 6, 3
+    shape = (b, k1, V) if grid else (b, V)
+    logits = jax.random.normal(jax.random.PRNGKey(11), shape, jnp.float32)
+    for row, bad in _BAD.items():
+        at = (row, row % k1, 7 + row) if grid else (row, 7 + row)
+        logits = logits.at[at].set(bad)
+    base = np.asarray(jax.random.split(jax.random.PRNGKey(5), b), np.uint32)
+    counts = np.arange(b, dtype=np.int32) * 3
+    if grid:
+        counts = counts[:, None] + np.arange(k1, dtype=np.int32)
+    temps = np.full((b,), temperature, np.float32)
+    plain, checked = ((sample_stream_grid, sample_stream_grid_checked)
+                      if grid else (sample_stream, sample_stream_checked))
+    jit = lambda f: jax.jit(f, static_argnames=("top_k", "top_p"))
+    out = np.asarray(jit(checked)(logits, base, counts, temps, **restrict))
+    assert out.dtype == np.int32 and out.shape == (2,) + shape[:-1]
+    np.testing.assert_array_equal(
+        out[0], np.asarray(jit(plain)(logits, base, counts, temps,
+                                      **restrict)))
+    finite = np.asarray(jax.jit(finite_rows)(logits))
+    np.testing.assert_array_equal(out[1].astype(bool), finite)
+    assert set(out[1].ravel().tolist()) == {0, 1}
+    assert int((~finite).sum()) == len(_BAD)
+    for row in _BAD:        # and only the spoiled position of the row
+        assert not finite[(row, row % k1) if grid else row]
+    # a finite row's token is in range whatever its neighbours hold
+    assert ((out[0] >= 0) & (out[0] < V))[finite].all()

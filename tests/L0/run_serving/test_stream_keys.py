@@ -15,6 +15,10 @@ sampler programs fold the token number in for all slots at once
   host's (``PagedDecodeEngine``) and costs a tick one more upload when a slot
   crossed a page boundary, however many did, and none otherwise; a plain
   tick leaves no leaf of ``engine.cache`` to an eager operation;
+- the finiteness gate rides in the sampler's program: a steady tick of any
+  kind waits ONCE for a sampler's result (``stats.sampler_waits``), and a
+  plain one launches two programs, the step and the checked sampler, and
+  reads one device array back;
 - the ``build_inputs`` span says how many slots it built for.
 """
 
@@ -152,10 +156,11 @@ class _CountedStep(_Counted):
 
 
 def _steady_tick_counts(model, monkeypatch, num_slots, mode, page_size):
-    """Programs dispatched, arrays uploaded, eager key derivations and eager
-    operations on the cache in three decode ticks with every slot decoding
-    and no admission; and per tick, what ``prepare_decode`` did to the block
-    table beside the uploads the table cost."""
+    """Programs dispatched, arrays uploaded, device arrays read back, waits
+    for a sampler, eager key derivations and eager operations on the cache
+    in three decode ticks with every slot decoding and no admission; and per
+    tick, what ``prepare_decode`` did to the block table beside the uploads
+    the table cost."""
     cfg, params = model
     kw = {}
     if mode != "plain":
@@ -172,14 +177,13 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode, page_size):
     for _ in range(3):      # admission, and every program compiled
         sched.step()
     assert all(sched._decoding(s) for s in sched._slots)
-    tally = {"programs": 0, "uploads": 0, "eager_keys": 0,
+    tally = {"programs": 0, "uploads": 0, "readbacks": 0, "eager_keys": 0,
              "table_uploads": 0, "eager_cache_ops": 0}
     known = jax.tree_util.tree_leaves(eng.cache)
     monkeypatch.setattr(eng, "_decode",
                         _CountedStep(eng._decode, tally, known))
-    for name in ("_verify", "_tree_verify", "_sample", "_sample_grid",
-                 "_finite"):
-        if getattr(eng, name, None) is not None:
+    for name in ("_verify", "_tree_verify", "_sample", "_sample_grid"):
+        if getattr(eng, name) is not None:
             monkeypatch.setattr(eng, name, _Counted(getattr(eng, name),
                                                     tally))
     monkeypatch.setattr(sched, "_tree_accept",
@@ -189,7 +193,8 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode, page_size):
         def call(*args, **kw):
             # a call on tracers is a program being compiled (the tree
             # grid's width follows the drafts), not a program run
-            if not any(isinstance(a, jax.core.Tracer) for a in args):
+            if not any(isinstance(a, jax.core.Tracer)
+                       for a in jax.tree_util.tree_leaves(args)):
                 tally[key] += 1
             return real(*args, **kw)
         return call
@@ -202,6 +207,14 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode, page_size):
     for name in ("asarray", "array", "stack"):
         monkeypatch.setattr(jnp, name,
                             counting(getattr(jnp, name), "uploads"))
+    # every blocking copy down: numpy asked for a device array's value
+    real_asarray = np.asarray
+
+    def read_back(x, *args, **kw):
+        tally["readbacks"] += isinstance(x, jax.Array)
+        return real_asarray(x, *args, **kw)
+
+    monkeypatch.setattr(np, "asarray", read_back)
     real_put = jax.device_put
 
     def table_put(x, *args, **kw):
@@ -214,7 +227,7 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode, page_size):
 
     monkeypatch.setattr(jax, "device_put", table_put)
     stats = sched.stats
-    before = (stats.plain_ticks, stats.spec_ticks)
+    before = (stats.plain_ticks, stats.spec_ticks, stats.sampler_waits)
     per_tick = []
     for _ in range(3):
         was = (stats.page_boundaries + stats.cow_copies,
@@ -224,6 +237,7 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode, page_size):
                          stats.block_table_uploads - was[1]))
     monkeypatch.undo()
     ticks = (stats.plain_ticks - before[0], stats.spec_ticks - before[1])
+    tally["sampler_waits"] = stats.sampler_waits - before[2]
     assert all(sched._decoding(s) for s in sched._slots)
     assert tally["table_uploads"] == sum(up for _, up in per_tick)
     return tally, ticks, per_tick
@@ -248,21 +262,90 @@ def test_tick_programs_and_uploads_do_not_grow_with_slots(
         assert uploads == (1 if mapped else 0), (table_few, table_many)
     # slots that speculate cross their boundaries on different ticks, so the
     # table's uploads are compared by that law and the rest by count
-    rest = ("programs", "uploads", "eager_keys")
+    rest = ("programs", "uploads", "readbacks", "eager_keys")
     assert {k: few[k] for k in rest} == {k: many[k] for k in rest}
+    # ONE wait for a sampler in a tick of any kind, with the finite flags in
+    # its result: no second program, no second read-back for the gate
+    assert few["sampler_waits"] == many["sampler_waits"] == 3 == sum(ticks_few)
+    if mode != "tree":      # the tree walk is a program of its own: 2 more
+        assert few["readbacks"] == 3
     if mode == "plain":
         assert ticks_few == (3, 0)
         crossing = [(2, 1), (8, 1)] if page_size == 4 else [(0, 0), (0, 0)]
         assert [max(table_few), max(table_many)] == crossing
-        # a tick: decode(tokens, active), finite, sample(base, counts, temps),
-        # and in the one tick of the three that opens a page of 4 rows for
-        # every slot, the table; no eager operation on a leaf of the cache
+        # a tick: TWO programs, decode(tokens, active) and the checked
+        # sampler (base, counts, temps) launched behind it, ONE read-back, and
+        # in the one tick of the three that opens a page of 4 rows for every
+        # slot, the table; no eager operation on a leaf of the cache
         assert few == many == {
-            "programs": 9, "uploads": 15, "eager_keys": 0,
+            "programs": 6, "uploads": 15, "readbacks": 3,
+            "sampler_waits": 3, "eager_keys": 0,
             "table_uploads": 1 if page_size == 4 else 0,
             "eager_cache_ops": 0}
     else:
         assert ticks_few[1] > 0
+
+
+@pytest.mark.parametrize("mode", ["plain", "spec", "chunked"])
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_sampler_waits_once_a_tick_and_once_an_admission(model, kind, mode):
+    """``stats.sampler_waits``, the counter that says the gate and the
+    sampler are one program read back once: +1 for every first token
+    sampled (an admission, monolithic or after the final chunk; a resumed
+    request samples none) and +1 for every decode tick of any kind,
+    whatever the number of slots. It rides the registry as the others do."""
+    trc = Tracer()
+    eng = _engine(model, kind, 3, page_size=4, tracer=trc,
+                  **({"spec_k": 2} if mode == "spec" else {}))
+    sched = ContinuousBatchingScheduler(
+        eng, eos_id=EOS, **({"chunk_tokens": 4} if mode == "chunked" else {}))
+    stats = sched.stats
+    for i in range(2):
+        sched.submit(Request(prompt=(7, 11) * 3, max_new_tokens=12,
+                             temperature=0.8 * i, seed=i))
+    # two first tokens; on the monolithic path the tick that admits both
+    # runs one decode step behind them, chunks take the ticks they take
+    sched.step()
+    if mode != "chunked":
+        assert stats.sampler_waits == 3
+    while not all(map(sched._decoding, sched._slots[:2])):
+        sched.step()
+    assert stats.sampler_waits == 2 + stats.plain_ticks + stats.spec_ticks
+    for _ in range(3):      # steady: both slots decode, nobody is admitted
+        was = stats.sampler_waits
+        sched.step()
+        assert stats.sampler_waits == was + 1
+    # one more admission beside two decoding slots
+    sched.submit(Request(prompt=(5, 3, 5), max_new_tokens=3))
+    was = stats.sampler_waits
+    sched.step()
+    assert stats.sampler_waits == was + 2
+    sched.run()
+    ticks = stats.plain_ticks + stats.spec_ticks
+    assert stats.sampler_waits == 3 + ticks
+    assert trc.registry.counter("serving_sampler_waits_total").value \
+        == stats.sampler_waits
+    assert "serving_sampler_waits_total" in trc.registry.to_prometheus()
+
+
+def test_sampler_waits_not_for_a_resumed_request(model):
+    """A preempted request re-admitted with its progress samples no first
+    token: its admission waits for no sampler."""
+    eng = _engine(model, "paged", 1)
+    sched = ContinuousBatchingScheduler(eng, eos_id=EOS)
+    sched.submit(Request(prompt=(7, 11, 13), max_new_tokens=6))
+    sched.step()
+    sched.step()
+    slot = sched._slots[0]
+    sched._queue.appendleft((slot.request_id, slot.request,
+                             list(slot.generated)))
+    sched._slots[0] = None
+    eng.free_slot(0)
+    was = sched.stats.sampler_waits
+    sched.step()                    # re-prefill, then one decode tick
+    assert sched.stats.sampler_waits == was + 1
+    sched.run()
+    assert sched.outcomes[0].ok and len(sched.outcomes[0].tokens) == 6
 
 
 def test_build_inputs_span_counts_decoding_slots(model):
