@@ -37,6 +37,7 @@ from apex_tpu.amp.scaler import (
     LossScalerState,
     apply_if_finite,  # noqa: F401  (re-exported)
 )
+from apex_tpu.utils.profiler import region
 
 
 class Amp:
@@ -53,6 +54,7 @@ class Amp:
         self.scaler = LossScaler(loss_scale=properties.loss_scale)
 
     # -- model / input casting -----------------------------------------
+    @region("amp")
     def cast_model(self, params: Any, precast: Any = None) -> Any:
         """O2/O3 model cast. ``precast`` is an optimizer-emitted compute
         tree (``FusedAdam(emit_compute_params=True)`` etc.): matching-
@@ -70,6 +72,7 @@ class Amp:
             precast=precast,
         )
 
+    @region("amp")
     def cast_input(self, batch: Any) -> Any:
         p = self.properties
         if p.cast_model_type is None:
@@ -95,12 +98,15 @@ class Amp:
         return tuple(self.scaler.init_state()
                      for _ in range(self.num_losses))
 
+    @region("amp")
     def scale_loss(self, loss, state: LossScalerState):
         return self.scaler.scale(loss, state)
 
+    @region("amp")
     def unscale(self, grads, state: LossScalerState):
         return self.scaler.unscale(grads, state)
 
+    @region("amp")
     def update_scale(self, state: LossScalerState, found_inf):
         return self.scaler.update_scale(state, found_inf)
 
@@ -128,15 +134,15 @@ class Amp:
                     loss, aux = out
                 else:
                     loss, aux = out, None
-                return self.scaler.scale(loss, state), (loss, aux)
+                return self.scale_loss(loss, state), (loss, aux)
 
             (_, (loss, aux)), grads = jax.value_and_grad(
                 scaled_loss_fn, has_aux=True, **grad_kwargs
             )(params, *args, **kw)
             if reduce_grads is not None:
                 grads = reduce_grads(grads)
-            grads, found_inf = self.scaler.unscale(grads, state)
-            new_state = self.scaler.update_scale(state, found_inf)
+            grads, found_inf = self.unscale(grads, state)
+            new_state = self.update_scale(state, found_inf)
             value = (loss, aux) if has_aux else loss
             return value, grads, found_inf, new_state
 

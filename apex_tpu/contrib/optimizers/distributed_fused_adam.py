@@ -39,6 +39,7 @@ from jax import lax
 from apex_tpu.multi_tensor_apply import flatten as _flatten
 from apex_tpu.optimizers._common import check_m_dtype, f32, select_finite
 from apex_tpu.transformer import parallel_state as ps
+from apex_tpu.utils.profiler import region
 
 
 class DistributedAdamState(NamedTuple):
@@ -122,6 +123,7 @@ class DistributedFusedAdam:
             row = P((tensor_axis, self.axis_name), None)
         return DistributedAdamState(step=P(), master=row, m=row, v=row)
 
+    @region("optimizer")
     def step(self, grads: Any, params: Any, state: DistributedAdamState,
              *, lr=None, grad_scale=1.0, weight_decay=None,
              found_inf: Optional[jax.Array] = None

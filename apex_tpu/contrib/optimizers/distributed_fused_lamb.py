@@ -29,6 +29,7 @@ from apex_tpu.contrib.optimizers.distributed_fused_adam import (
 from apex_tpu.multi_tensor_apply import flatten as _flatten
 from apex_tpu.optimizers._common import check_m_dtype, f32, select_finite
 from apex_tpu.transformer import parallel_state as ps
+from apex_tpu.utils.profiler import region
 
 
 class DistributedLambState(NamedTuple):
@@ -101,6 +102,7 @@ class DistributedFusedLAMB:
         return lax.dynamic_slice_in_dim(row_ids, d * local_rows,
                                         local_rows, 0)
 
+    @region("optimizer")
     def step(self, grads: Any, params: Any, state: DistributedLambState,
              *, lr=None, weight_decay=None, grad_scale=1.0,
              found_inf: Optional[jax.Array] = None

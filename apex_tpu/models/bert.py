@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from apex_tpu.amp.autocast import cast_args
 from apex_tpu.models import layers as L
 from apex_tpu.normalization import fused_layer_norm_affine
+from apex_tpu.utils.profiler import region
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,25 +164,28 @@ def apply_bert(params: Dict[str, Any], cfg: BertConfig,
     "pooled": (b,h)}."""
     b, s = input_ids.shape
     emb = params["embeddings"]
-    x = L.embedding(emb["word"], input_ids, compute_dtype)
-    x = x + L.embedding(emb["position"], jnp.arange(s), compute_dtype)[None]
-    if token_type_ids is None:
-        token_type_ids = jnp.zeros_like(input_ids)
-    x = x + L.embedding(emb["token_type"], token_type_ids, compute_dtype)
-    x = _ln(emb["layernorm"], x, cfg.layer_norm_eps)
+    with region("embed"):
+        x = L.embedding(emb["word"], input_ids, compute_dtype)
+        x = x + L.embedding(emb["position"], jnp.arange(s),
+                            compute_dtype)[None]
+        if token_type_ids is None:
+            token_type_ids = jnp.zeros_like(input_ids)
+        x = x + L.embedding(emb["token_type"], token_type_ids, compute_dtype)
+        x = _ln(emb["layernorm"], x, cfg.layer_norm_eps)
 
     rngs = (jax.random.split(dropout_rng, 2 * cfg.num_layers + 1)
             if dropout_rng is not None else [None] * (2 * cfg.num_layers + 1))
-    x = _maybe_dropout(x, cfg.hidden_dropout, rngs[0])
+    with region("embed"):   # apart from the lookups: the key split stays put
+        x = _maybe_dropout(x, cfg.hidden_dropout, rngs[0])
 
     def encoder_layer(layer, x, rng_a, rng_h):
-        with jax.named_scope("attention"):
+        with region("attention"):
             att = _attention(layer["attention"], cfg, x, attention_mask,
                              rng_a)
             att = _maybe_dropout(att, cfg.hidden_dropout, rng_h)
             x = _ln(layer["attention"]["layernorm"], x + att,
                     cfg.layer_norm_eps)
-        with jax.named_scope("mlp"):
+        with region("mlp"):
             mlp = L.dense(layer["mlp"]["fc2"],
                           jax.nn.gelu(L.dense(layer["mlp"]["fc1"], x)))
             x = _ln(layer["mlp"]["layernorm"], x + mlp, cfg.layer_norm_eps)
@@ -196,18 +200,19 @@ def apply_bert(params: Dict[str, Any], cfg: BertConfig,
                               rngs[2 * li + 2])
 
     head = params["mlm_head"]
-    t = jax.nn.gelu(L.dense(head["transform"], x))
-    t = _ln(head["layernorm"], t, cfg.layer_norm_eps)
-    word_table = emb["word"]["embedding"].astype(t.dtype)
-    # rows flattened BEFORE the product, contracted on the table's hidden
-    # axis: the logits are born (b*s, vocab) row-major, which is what the
-    # loss kernel reads; as a (b, s, vocab) product XLA lays them out
-    # vocab-major and copies the gigabyte in front of the kernel
-    flat = jax.lax.dot_general(t.reshape(b * s, -1), word_table,
-                               (((1,), (1,)), ((), ())))
-    mlm_logits = (flat.astype(jnp.float32)
-                  + head["bias"].astype(jnp.float32)).reshape(b, s, -1)
-    pooled = jnp.tanh(L.dense(params["pooler"], x[:, 0]))
+    with region("head"):
+        t = jax.nn.gelu(L.dense(head["transform"], x))
+        t = _ln(head["layernorm"], t, cfg.layer_norm_eps)
+        word_table = emb["word"]["embedding"].astype(t.dtype)
+        # rows flattened BEFORE the product, contracted on the table's
+        # hidden axis: the logits are born (b*s, vocab) row-major, which is
+        # what the loss kernel reads; as a (b, s, vocab) product XLA lays
+        # them out vocab-major and copies the gigabyte in front of the kernel
+        flat = jax.lax.dot_general(t.reshape(b * s, -1), word_table,
+                                   (((1,), (1,)), ((), ())))
+        mlm_logits = (flat.astype(jnp.float32)
+                      + head["bias"].astype(jnp.float32)).reshape(b, s, -1)
+        pooled = jnp.tanh(L.dense(params["pooler"], x[:, 0]))
     return {"hidden": x, "mlm_logits": mlm_logits, "pooled": pooled}
 
 
@@ -220,8 +225,9 @@ def mlm_loss(logits: jax.Array, labels: jax.Array,
     from apex_tpu.contrib.xentropy import softmax_cross_entropy_loss
 
     b, s, v = logits.shape
-    flat_labels = jnp.where(label_mask != 0, labels, -1).reshape(b * s)
-    losses = softmax_cross_entropy_loss(logits.reshape(b * s, v),
-                                        flat_labels)
-    m = label_mask.astype(jnp.float32)
-    return losses.sum() / jnp.maximum(m.sum(), 1.0)
+    with region("loss"):
+        flat_labels = jnp.where(label_mask != 0, labels, -1).reshape(b * s)
+        losses = softmax_cross_entropy_loss(logits.reshape(b * s, v),
+                                            flat_labels)
+        m = label_mask.astype(jnp.float32)
+        return losses.sum() / jnp.maximum(m.sum(), 1.0)

@@ -64,6 +64,7 @@ from jax import lax
 from apex_tpu.models.nemotron_h import _dense, _rms, _two_terms, embed
 from apex_tpu.transformer.functional import flash_attention, moe
 from apex_tpu.transformer.functional.mla_attention import mla_decode_attention
+from apex_tpu.utils.profiler import region
 
 # rows of a prompt that one pass of the expert layer takes: its sorted
 # assignments (rows * experts_per_token, most of them for experts held
@@ -321,6 +322,7 @@ def _by_head(spec, x, w, axis):
     return hi + lo
 
 
+@region("head")
 def logits_of(params, cfg, x):
     """Final norm and the untied head: (rows, hidden) -> float32 logits."""
     return _dense(params["head"],
@@ -361,6 +363,7 @@ def _queries(lp, c_q, cfg, pos):
 # attention: expanded over a prompt, absorbed for one token per slot
 # ---------------------------------------------------------------------------
 
+@region("attention")
 def attention_prefill(lp, x, cfg, mask, kv_dtype):
     """One layer's attention over a prompt: ``x`` (s, hidden). Returns
     ``(x', rows (s, kv_row_width))``, the rows in ``kv_dtype``, the cache's:
@@ -389,6 +392,7 @@ def attention_prefill(lp, x, cfg, mask, kv_dtype):
     return x + _dense(lp["out"], ctx), row
 
 
+@region("attention")
 def attention_decode(lp, x, cfg, pool, layer, block_tables, pos, active):
     """One token for every slot against the latent pool, read in place by
     ``apex_mla_decode_fwd``; ``layer`` indexes the pool's leading axis.
@@ -411,6 +415,7 @@ def attention_decode(lp, x, cfg, pool, layer, block_tables, pos, active):
 # the MLPs: dense, and the experts held here
 # ---------------------------------------------------------------------------
 
+@region("mlp")
 def dense_mlp(lp, x, cfg):
     u = _rms(lp["mlp_norm"], x, cfg.rms_norm_eps)
     return x + _dense(lp["down"], _swiglu(_dense(lp["gate_up"], u)))
@@ -436,18 +441,21 @@ def _experts(lp, u, cfg, real, held=None, first_group=None):
     ``held``: the matrices ``(w_gate_up, w_down)`` where they are not
     ``lp``'s own, this layer's from ``first_group`` on."""
     w_gate_up, w_down = held or (lp["w_gate_up"], lp["w_down"])
-    logits = jnp.dot(u, lp["router"]["kernel"].astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    chosen, weights = moe.route(logits, lp["router_bias"],
-                                cfg.experts_per_token,
-                                cfg.routed_scaling_factor, cfg.n_group,
-                                cfg.topk_group)
-    d = moe.dispatch(chosen, weights, cfg.expert_offset, cfg.experts_held,
-                     real)
-    mid = _swiglu(moe.grouped_matmul(u[d.token], w_gate_up, d.sizes,
-                                     first_group=first_group))
-    out = moe.grouped_matmul(mid, w_down, d.sizes, first_group=first_group)
-    return moe.combine(out, d, u.shape[0]), d.sizes, chosen
+    with region("router"):
+        logits = jnp.dot(u, lp["router"]["kernel"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        chosen, weights = moe.route(logits, lp["router_bias"],
+                                    cfg.experts_per_token,
+                                    cfg.routed_scaling_factor, cfg.n_group,
+                                    cfg.topk_group)
+    with region("experts"):
+        d = moe.dispatch(chosen, weights, cfg.expert_offset,
+                         cfg.experts_held, real)
+        mid = _swiglu(moe.grouped_matmul(u[d.token], w_gate_up, d.sizes,
+                                         first_group=first_group))
+        out = moe.grouped_matmul(mid, w_down, d.sizes,
+                                 first_group=first_group)
+        return moe.combine(out, d, u.shape[0]), d.sizes, chosen
 
 
 def expert_mlp(lp, x, cfg, real, held=None, first_group=None):
@@ -457,20 +465,24 @@ def expert_mlp(lp, x, cfg, real, held=None, first_group=None):
     experts that many rows at a time. Returns ``(x', sizes (experts_held,),
     chosen (rows, k))``."""
     rows = x.shape[0]
-    u = _rms(lp["mlp_norm"], x, cfg.rms_norm_eps)
+    with region("router"):
+        u = _rms(lp["mlp_norm"], x, cfg.rms_norm_eps)
     if rows > _MOE_ROWS and rows % _MOE_ROWS == 0:
         routed, sizes, chosen = lax.map(
             lambda block: _experts(lp, block[0], cfg, block[1], held,
                                    first_group),
             (u.reshape(-1, _MOE_ROWS, u.shape[1]),
              real.reshape(-1, _MOE_ROWS)))
-        routed, sizes = routed.reshape(rows, -1), jnp.sum(sizes, 0)
-        chosen = chosen.reshape(rows, -1)
+        with region("experts"):
+            routed, sizes = routed.reshape(rows, -1), jnp.sum(sizes, 0)
+            chosen = chosen.reshape(rows, -1)
     else:
         routed, sizes, chosen = _experts(lp, u, cfg, real, held, first_group)
-    shared = _dense(lp["shared_down"],
-                    _swiglu(_dense(lp["shared_gate_up"], u)))
-    return x + routed + shared, sizes, chosen
+    with region("mlp"):     # the shared expert
+        shared = _dense(lp["shared_down"],
+                        _swiglu(_dense(lp["shared_gate_up"], u)))
+    with region("experts"):
+        return x + routed + shared, sizes, chosen
 
 
 # ---------------------------------------------------------------------------
@@ -528,10 +540,12 @@ def decode_layers(params, cfg: DeepseekConfig, cache, tokens, active):
                                   cfg.first_k_dense + at, bt, pos, active)
         x, sizes, _ = expert_mlp(lp, x, cfg, active, held,
                                  at * cfg.experts_held)
-        counters = {
-            **counters,
-            "moe_load": counters["moe_load"].at[at].add(sizes),
-            "moe_hit": counters["moe_hit"].at[at].add(jnp.sum(sizes > 0))}
+        with region("experts"):
+            counters = {
+                **counters,
+                "moe_load": counters["moe_load"].at[at].add(sizes),
+                "moe_hit": counters["moe_hit"].at[at].add(
+                    jnp.sum(sizes > 0))}
         return (x, counters), row
 
     layers, held = _held(params["moe"])

@@ -44,6 +44,7 @@ from apex_tpu.transformer.functional import (
     fused_apply_rotary_pos_emb_bhsd,
     rope_frequencies,
 )
+from apex_tpu.utils.profiler import region
 
 
 @dataclasses.dataclass(frozen=True)
@@ -296,14 +297,14 @@ def _block(lp, x, cfg, rope_freqs, qkv_fn, out_fn, fc1_fn, fc2_fn,
     ``cfg.context_parallel_impl``."""
     attn = _CP_ATTN[cfg.context_parallel_impl] if ring \
         else _causal_attention
-    with jax.named_scope("attention"):
+    with region("attention"):
         att = attn(qkv_fn(lp["qkv"], _ln(lp["ln1"], x,
                                          cfg.layer_norm_eps)),
                    cfg, rope_freqs)
         att = out_fn(lp["out"], att)
         att = _maybe_dropout(att, cfg.hidden_dropout, dropout_rng, 0)
         x = x + att
-    with jax.named_scope("mlp"):
+    with region("mlp"):
         mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
             fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
         mlp = _maybe_dropout(mlp, cfg.hidden_dropout, dropout_rng, 1)
@@ -375,29 +376,35 @@ def _decode_attention(q_k_v: jax.Array, k_cache: jax.Array,
     return ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1), k_cache, v_cache
 
 
+def _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn):
+    """The MLP half of every serving block: x + MLP(LN(x))."""
+    with region("mlp"):
+        mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
+            fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
+        return x + mlp
+
+
 def _block_prefill(lp, x, cfg, rope_freqs, key_mask,
                    qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block` that also emits this layer's (k, v) cache tiles."""
-    att, k, v = _prefill_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        cfg, rope_freqs, key_mask)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k, v
+    with region("attention"):
+        att, k, v = _prefill_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            cfg, rope_freqs, key_mask)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k, v
 
 
 def _block_decode(lp, x, k_cache, v_cache, pos, cfg, rope_freqs,
                   qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block` against the cache: x is the (b, 1, h) new-token
     hidden; returns (x', k_cache', v_cache')."""
-    att, k_cache, v_cache = _decode_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_cache, v_cache, pos, cfg, rope_freqs)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_cache, v_cache
+    with region("attention"):
+        att, k_cache, v_cache = _decode_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_cache, v_cache, pos, cfg, rope_freqs)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_cache, v_cache
 
 
 def _page_rows(t: jax.Array) -> jax.Array:
@@ -488,13 +495,12 @@ def _block_decode_paged(lp, x, k_pool, v_pool, layer, block_tables, pos,
     """:func:`_block_decode` over the paged pool (block-table
     indirection instead of a per-slot cache row); returns (x', k_row,
     v_row), the layer's new rows for the caller to write."""
-    att, k_row, v_row = _paged_decode_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_pool, v_pool, layer, block_tables, pos, cfg, rope_freqs)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_row, v_row
+    with region("attention"):
+        att, k_row, v_row = _paged_decode_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_pool, v_pool, layer, block_tables, pos, cfg, rope_freqs)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_row, v_row
 
 
 def _verify_attention(q_k_v: jax.Array, k_cache: jax.Array,
@@ -550,13 +556,12 @@ def _verify_attention(q_k_v: jax.Array, k_cache: jax.Array,
 def _block_verify(lp, x, k_cache, v_cache, pos, cfg, rope_freqs,
                   qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block_decode` over k1 candidate positions at once."""
-    att, k_cache, v_cache = _verify_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_cache, v_cache, pos, cfg, rope_freqs)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_cache, v_cache
+    with region("attention"):
+        att, k_cache, v_cache = _verify_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_cache, v_cache, pos, cfg, rope_freqs)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_cache, v_cache
 
 
 def _paged_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
@@ -608,13 +613,12 @@ def _paged_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
 def _block_verify_paged(lp, x, k_pages, v_pages, block_tables, pos, cfg,
                         rope_freqs, qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block_verify` over the paged pool."""
-    att, k_pages, v_pages = _paged_verify_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_pages, v_pages, block_tables, pos, cfg, rope_freqs)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_pages, v_pages
+    with region("attention"):
+        att, k_pages, v_pages = _paged_verify_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_pages, v_pages, block_tables, pos, cfg, rope_freqs)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_pages, v_pages
 
 
 def _chunk_prefill_attention(q_k_v: jax.Array, k_cache: jax.Array,
@@ -670,13 +674,12 @@ def _block_chunk_prefill(lp, x, k_cache, v_cache, slot, pos, cfg,
                          rope_freqs, key_mask, qkv_fn, out_fn, fc1_fn,
                          fc2_fn):
     """:func:`_block_verify` for one slot's prompt chunk."""
-    att, k_cache, v_cache = _chunk_prefill_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_cache, v_cache, slot, pos, cfg, rope_freqs, key_mask)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_cache, v_cache
+    with region("attention"):
+        att, k_cache, v_cache = _chunk_prefill_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_cache, v_cache, slot, pos, cfg, rope_freqs, key_mask)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_cache, v_cache
 
 
 def _paged_chunk_prefill_attention(q_k_v: jax.Array, k_pages: jax.Array,
@@ -737,14 +740,13 @@ def _block_chunk_prefill_paged(lp, x, k_pages, v_pages, write_pages,
                                gather_row, pos, cfg, rope_freqs,
                                key_mask, qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block_chunk_prefill` over the paged pool."""
-    att, k_pages, v_pages = _paged_chunk_prefill_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_pages, v_pages, write_pages, gather_row, pos, cfg, rope_freqs,
-        key_mask)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_pages, v_pages
+    with region("attention"):
+        att, k_pages, v_pages = _paged_chunk_prefill_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_pages, v_pages, write_pages, gather_row, pos, cfg, rope_freqs,
+            key_mask)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_pages, v_pages
 
 
 # ---------------------------------------------------------------------------
@@ -824,13 +826,12 @@ def _tree_verify_attention(q_k_v: jax.Array, k_cache: jax.Array,
 def _block_tree_verify(lp, x, k_cache, v_cache, pos, depth, anc, cfg,
                        rope_freqs, qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block_verify` under the tree-attention mask."""
-    att, k_cache, v_cache = _tree_verify_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_cache, v_cache, pos, depth, anc, cfg, rope_freqs)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_cache, v_cache
+    with region("attention"):
+        att, k_cache, v_cache = _tree_verify_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_cache, v_cache, pos, depth, anc, cfg, rope_freqs)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_cache, v_cache
 
 
 def _paged_tree_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
@@ -881,13 +882,12 @@ def _block_tree_verify_paged(lp, x, k_pages, v_pages, block_tables, pos,
                              depth, anc, cfg, rope_freqs,
                              qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block_tree_verify` over the paged pool."""
-    att, k_pages, v_pages = _paged_tree_verify_attention(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_pages, v_pages, block_tables, pos, depth, anc, cfg, rope_freqs)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_pages, v_pages
+    with region("attention"):
+        att, k_pages, v_pages = _paged_tree_verify_attention(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_pages, v_pages, block_tables, pos, depth, anc, cfg, rope_freqs)
+        x = x + out_fn(lp["out"], att)
+    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_pages, v_pages
 
 
 # ---------------------------------------------------------------------------
@@ -1012,14 +1012,14 @@ def _block_decode_paged_q8(lp, x, k_pages, v_pages, k_scale, v_scale,
                            block_tables, pos, cfg, rope_freqs,
                            qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block_decode_paged` over the int8 pool + scales."""
-    att, k_pages, v_pages, k_scale, v_scale = _paged_decode_attention_q8(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_pages, v_pages, k_scale, v_scale, block_tables, pos, cfg,
-        rope_freqs)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_pages, v_pages, k_scale, v_scale
+    with region("attention"):
+        att, k_pages, v_pages, k_scale, v_scale = _paged_decode_attention_q8(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_pages, v_pages, k_scale, v_scale, block_tables, pos, cfg,
+            rope_freqs)
+        x = x + out_fn(lp["out"], att)
+    return (_mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_pages, v_pages,
+            k_scale, v_scale)
 
 
 def _paged_verify_attention_q8(q_k_v, k_pages, v_pages, k_scale, v_scale,
@@ -1080,14 +1080,14 @@ def _block_verify_paged_q8(lp, x, k_pages, v_pages, k_scale, v_scale,
                            block_tables, pos, cfg, rope_freqs,
                            qkv_fn, out_fn, fc1_fn, fc2_fn):
     """:func:`_block_verify_paged` over the int8 pool + scales."""
-    att, k_pages, v_pages, k_scale, v_scale = _paged_verify_attention_q8(
-        qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-        k_pages, v_pages, k_scale, v_scale, block_tables, pos, cfg,
-        rope_freqs)
-    x = x + out_fn(lp["out"], att)
-    mlp = fc2_fn(lp["fc2"], jax.nn.gelu(
-        fc1_fn(lp["fc1"], _ln(lp["ln2"], x, cfg.layer_norm_eps))))
-    return x + mlp, k_pages, v_pages, k_scale, v_scale
+    with region("attention"):
+        att, k_pages, v_pages, k_scale, v_scale = _paged_verify_attention_q8(
+            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
+            k_pages, v_pages, k_scale, v_scale, block_tables, pos, cfg,
+            rope_freqs)
+        x = x + out_fn(lp["out"], att)
+    return (_mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_pages, v_pages,
+            k_scale, v_scale)
 
 
 def _maybe_dropout(x, rate, rng, salt):
@@ -1232,18 +1232,20 @@ class GPTModel:
 
         cfg = self.cfg
         b, s = input_ids.shape
-        x = self.embed.apply(params["embedding"]["word"], input_ids)
-        if compute_dtype is not None:
-            x = x.astype(compute_dtype)
+        with region("embed"):
+            x = self.embed.apply(params["embedding"]["word"], input_ids)
+            if compute_dtype is not None:
+                x = x.astype(compute_dtype)
         if cfg.context_parallel:
             # ids arrived (b, s/cp): positions and rotary angles are the
             # GLOBAL ones for this rank's shard
             cp_rank = lax.axis_index(ps.CONTEXT_AXIS)
             if not cfg.use_rope:
-                pos = lax.dynamic_slice_in_dim(
-                    params["embedding"]["position"]["embedding"],
-                    cp_rank * s, s, 0)
-                x = x + pos.astype(x.dtype)[None]
+                with region("embed"):
+                    pos = lax.dynamic_slice_in_dim(
+                        params["embedding"]["position"]["embedding"],
+                        cp_rank * s, s, 0)
+                    x = x + pos.astype(x.dtype)[None]
             freqs = _rope_or_none(
                 cfg, s * lax.axis_size(ps.CONTEXT_AXIS))
             if freqs is not None:
@@ -1254,10 +1256,12 @@ class GPTModel:
                              self.qkv.apply, self.out.apply,
                              self.fc1.apply, self.fc2.apply, dropout_rng,
                              ring=True)
-            return _ln(params["final_ln"], x, cfg.layer_norm_eps)
+            with region("head"):
+                return _ln(params["final_ln"], x, cfg.layer_norm_eps)
         if not cfg.use_rope:
-            pos = params["embedding"]["position"]["embedding"][:s]
-            x = x + pos.astype(x.dtype)[None]
+            with region("embed"):
+                pos = params["embedding"]["position"]["embedding"][:s]
+                x = x + pos.astype(x.dtype)[None]
         freqs = _rope_or_none(cfg, s)
         if cfg.sequence_parallel:
             # enter the SP region: shard seq over the model axis; the
@@ -1271,14 +1275,16 @@ class GPTModel:
         x = _scan_layers(x, params["layers"], cfg, freqs,
                          self.qkv.apply, self.out.apply,
                          self.fc1.apply, self.fc2.apply, dropout_rng)
-        return _ln(params["final_ln"], x, cfg.layer_norm_eps)
+        with region("head"):
+            return _ln(params["final_ln"], x, cfg.layer_norm_eps)
 
     def logits_local(self, params: Dict[str, Any],
                      hidden: jax.Array) -> jax.Array:
         """Tied LM head: (b, s, h) -> vocab-SHARDED logits (b, s, V/tp),
         in rank order (the ``parallel_output=True`` convention)."""
         table = params["embedding"]["word"]["embedding"]
-        return _tied_lm_logits(hidden, table)
+        with region("head"):
+            return _tied_lm_logits(hidden, table)
 
     def allreduce_sequence_parallel_grads(self, grads: Dict[str, Any]
                                           ) -> Dict[str, Any]:
@@ -1319,22 +1325,24 @@ class GPTModel:
             # leave the SP region for the LM head; the gather's backward
             # reduce-scatters dhidden — the SP dual of copy_to_region, so
             # the head dots the gathered hidden directly
-            hidden = mappings.gather_from_sequence_parallel_region(
-                hidden, True, 1)
-            table = params["embedding"]["word"]["embedding"]
-            logits = jnp.dot(hidden,
-                             table.astype(hidden.dtype).T).astype(
-                jnp.float32)
+            with region("head"):
+                hidden = mappings.gather_from_sequence_parallel_region(
+                    hidden, True, 1)
+                table = params["embedding"]["word"]["embedding"]
+                logits = jnp.dot(hidden,
+                                 table.astype(hidden.dtype).T).astype(
+                    jnp.float32)
         else:
             logits = self.logits_local(params, hidden)
-        loss = vocab_parallel_cross_entropy(logits, labels).mean()
-        if self.cfg.context_parallel:
-            # per-token losses live on seq shards of equal size: the
-            # global mean is the mean of rank means. NOTE the trainer's
-            # closure: like DDP over the batch, each rank's AD yields
-            # d(local token mean)/dp — pmean the GRADS over the context
-            # axis after backward (see test_context_parallel_*).
-            loss = lax.pmean(loss, ps.CONTEXT_AXIS)
+        with region("loss"):
+            loss = vocab_parallel_cross_entropy(logits, labels).mean()
+            if self.cfg.context_parallel:
+                # per-token losses live on seq shards of equal size: the
+                # global mean is the mean of rank means. NOTE the trainer's
+                # closure: like DDP over the batch, each rank's AD yields
+                # d(local token mean)/dp — pmean the GRADS over the context
+                # axis after backward (see test_context_parallel_*).
+                loss = lax.pmean(loss, ps.CONTEXT_AXIS)
         return loss
 
 
@@ -1347,13 +1355,14 @@ def apply_gpt_unsharded(params: Dict[str, Any], cfg: GPTConfig,
                         *, dropout_rng: Optional[jax.Array] = None,
                         compute_dtype=None) -> jax.Array:
     b, s = input_ids.shape
-    table = params["embedding"]["word"]["embedding"]
-    if compute_dtype is not None:
-        table = table.astype(compute_dtype)
-    x = jnp.take(table, input_ids, axis=0)
-    if not cfg.use_rope:
-        pos = params["embedding"]["position"]["embedding"][:s]
-        x = x + pos.astype(x.dtype)[None]
+    with region("embed"):
+        table = params["embedding"]["word"]["embedding"]
+        if compute_dtype is not None:
+            table = table.astype(compute_dtype)
+        x = jnp.take(table, input_ids, axis=0)
+        if not cfg.use_rope:
+            pos = params["embedding"]["position"]["embedding"][:s]
+            x = x + pos.astype(x.dtype)[None]
     freqs = _rope_or_none(cfg, s)
 
     def dense(p, x):
@@ -1362,7 +1371,8 @@ def apply_gpt_unsharded(params: Dict[str, Any], cfg: GPTConfig,
 
     x = _scan_layers(x, params["layers"], cfg, freqs,
                      dense, dense, dense, dense, dropout_rng)
-    return _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    with region("head"):
+        return _ln(params["final_ln"], x, cfg.layer_norm_eps)
 
 
 def gpt_loss_unsharded(params: Dict[str, Any], cfg: GPTConfig,
@@ -1374,17 +1384,19 @@ def gpt_loss_unsharded(params: Dict[str, Any], cfg: GPTConfig,
     hidden = apply_gpt_unsharded(params, cfg, input_ids,
                                  dropout_rng=dropout_rng,
                                  compute_dtype=compute_dtype)
-    table = params["embedding"]["word"]["embedding"]
-    hidden, table_t = cast_args("matmul", hidden,
-                                table.astype(hidden.dtype).T)
-    logits = jnp.dot(hidden, table_t)
+    with region("head"):
+        table = params["embedding"]["word"]["embedding"]
+        hidden, table_t = cast_args("matmul", hidden,
+                                    table.astype(hidden.dtype).T)
+        logits = jnp.dot(hidden, table_t)
     # fused xentropy (ref apex/contrib/xentropy): fp32 logsumexp inside
     # the kernel, no (b, s, V) log-softmax ever materialized — at
     # V=50304 that tensor dominated the unsharded step's HBM footprint
-    v = logits.shape[-1]
-    nll = softmax_cross_entropy_loss(logits.reshape(-1, v),
-                                     labels.reshape(-1))
-    return nll.mean()
+    with region("loss"):
+        v = logits.shape[-1]
+        nll = softmax_cross_entropy_loss(logits.reshape(-1, v),
+                                         labels.reshape(-1))
+        return nll.mean()
 
 
 # ---------------------------------------------------------------------------

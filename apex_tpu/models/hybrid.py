@@ -49,6 +49,7 @@ from apex_tpu.transformer.functional import flash_attention
 from apex_tpu.transformer.functional.gated_delta import (
     CHUNK, causal_conv, conv_step, gated_delta_chunked, gated_delta_step,
 )
+from apex_tpu.utils.profiler import region
 
 LINEAR, FULL = "linear_attention", "full_attention"
 _L2_EPS = 1e-6
@@ -239,20 +240,25 @@ def _rms(p, x, eps):
 
 
 def _mlp(lp, x, cfg):
-    gate, up = jnp.split(_dense(lp["gate_up"], x), 2, axis=-1)
-    return _rms(lp["norm2"], _dense(lp["down"], jax.nn.silu(gate) * up),
-                cfg.rms_norm_eps)
+    """``x + norm2(SwiGLU(x))``: the MLP half of either kind of layer."""
+    with region("mlp"):
+        gate, up = jnp.split(_dense(lp["gate_up"], x), 2, axis=-1)
+        return x + _rms(lp["norm2"],
+                        _dense(lp["down"], jax.nn.silu(gate) * up),
+                        cfg.rms_norm_eps)
 
 
 def embed(params, ids):
-    return jnp.take(params["embedding"]["word"]["embedding"], ids,
-                    axis=0).astype(jnp.float32)
+    with region("embed"):
+        return jnp.take(params["embedding"]["word"]["embedding"], ids,
+                        axis=0).astype(jnp.float32)
 
 
 def logits_of(params, cfg, x):
     """Final norm and the untied head: (rows, hidden) -> float32 logits."""
-    return _dense(params["head"],
-                  _rms(params["final_norm"], x, cfg.rms_norm_eps))
+    with region("head"):
+        return _dense(params["head"],
+                      _rms(params["final_norm"], x, cfg.rms_norm_eps))
 
 
 # ---------------------------------------------------------------------------
@@ -297,29 +303,30 @@ def linear_block_prefill(lp, x, cfg, mask):
     convolution tail as the prompt's last real token leaves them: padded
     positions decay nothing (``log alpha = 0``) and write nothing (``beta =
     0``)."""
-    s = x.shape[0]
-    proj = _dense(lp["in_proj"], x)
-    chan = cfg.conv_channels
-    real = mask.astype(bool)
-    conv_out, tail = causal_conv(
-        proj[:, :chan], lp["conv"]["weight"].astype(jnp.float32),
-        jnp.sum(mask))
-    q, k, v = _heads(conv_out, cfg)
-    log_alpha, beta = _gates(lp, x, cfg)
-    log_alpha = jnp.where(real[:, None], log_alpha, 0.0)
-    beta = jnp.where(real[:, None], beta, 0.0)
-    pad = -s % CHUNK
+    with region("mixer"):
+        s = x.shape[0]
+        proj = _dense(lp["in_proj"], x)
+        chan = cfg.conv_channels
+        real = mask.astype(bool)
+        conv_out, tail = causal_conv(
+            proj[:, :chan], lp["conv"]["weight"].astype(jnp.float32),
+            jnp.sum(mask))
+        q, k, v = _heads(conv_out, cfg)
+        log_alpha, beta = _gates(lp, x, cfg)
+        log_alpha = jnp.where(real[:, None], log_alpha, 0.0)
+        beta = jnp.where(real[:, None], beta, 0.0)
+        pad = -s % CHUNK
 
-    def lead(t):        # (s, H, ...) -> (H, s padded to whole chunks, ...)
-        t = jnp.moveaxis(t, 1, 0)
-        return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        def lead(t):    # (s, H, ...) -> (H, s padded to whole chunks, ...)
+            t = jnp.moveaxis(t, 1, 0)
+            return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
 
-    o, state = gated_delta_chunked(lead(q), lead(k), lead(v),
-                                   lead(log_alpha), lead(beta))
-    o = jnp.moveaxis(o[:, :s], 0, 1)
-    y = _gated_out(lp, o, proj[:, chan:], cfg)
-    x = x + _rms(lp["norm1"], y, cfg.rms_norm_eps)
-    return x + _mlp(lp, x, cfg), state, tail
+        o, state = gated_delta_chunked(lead(q), lead(k), lead(v),
+                                       lead(log_alpha), lead(beta))
+        o = jnp.moveaxis(o[:, :s], 0, 1)
+        y = _gated_out(lp, o, proj[:, chan:], cfg)
+        x = x + _rms(lp["norm1"], y, cfg.rms_norm_eps)
+    return _mlp(lp, x, cfg), state, tail
 
 
 def linear_block_decode(lp, x, cfg, state, conv, layer, active):
@@ -327,20 +334,21 @@ def linear_block_decode(lp, x, cfg, state, conv, layer, active):
     the WHOLE stacked arrays (``HybridConfig.state_shapes``), of which layer
     ``layer`` (a traced scalar) is read and written. Returns ``(x', state',
     conv')``."""
-    proj = _dense(lp["in_proj"], x)
-    chan = cfg.conv_channels
-    tail = lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
-    conv_out, new_tail = conv_step(
-        proj[:, :chan], tail, lp["conv"]["weight"].astype(jnp.float32))
-    new_tail = jnp.where(active[:, None, None], new_tail, tail)
-    conv = lax.dynamic_update_index_in_dim(conv, new_tail, layer, 0)
-    q, k, v = _heads(conv_out, cfg)
-    log_alpha, beta = _gates(lp, x, cfg)
-    o, state = gated_delta_step(q, k, v, log_alpha, beta, state, layer,
-                                active)
-    y = _gated_out(lp, o, proj[:, chan:], cfg)
-    x = x + _rms(lp["norm1"], y, cfg.rms_norm_eps)
-    return x + _mlp(lp, x, cfg), state, conv
+    with region("mixer"):
+        proj = _dense(lp["in_proj"], x)
+        chan = cfg.conv_channels
+        tail = lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
+        conv_out, new_tail = conv_step(
+            proj[:, :chan], tail, lp["conv"]["weight"].astype(jnp.float32))
+        new_tail = jnp.where(active[:, None, None], new_tail, tail)
+        conv = lax.dynamic_update_index_in_dim(conv, new_tail, layer, 0)
+        q, k, v = _heads(conv_out, cfg)
+        log_alpha, beta = _gates(lp, x, cfg)
+        o, state = gated_delta_step(q, k, v, log_alpha, beta, state, layer,
+                                    active)
+        y = _gated_out(lp, o, proj[:, chan:], cfg)
+        x = x + _rms(lp["norm1"], y, cfg.rms_norm_eps)
+    return _mlp(lp, x, cfg), state, conv
 
 
 # ---------------------------------------------------------------------------
@@ -359,19 +367,20 @@ def full_block_prefill(lp, x, cfg, mask, kv_dtype):
     """One full-attention layer over a prompt. Returns ``(x', k, v)``, the
     (s, heads * head_dim) rows the cache keeps, in ``kv_dtype``, the cache's:
     the prompt attends to the rows decode will read."""
-    s = x.shape[0]
-    q, k, v = (t.astype(kv_dtype) for t in _qkv(lp, x, cfg))
+    with region("attention"):
+        s = x.shape[0]
+        q, k, v = (t.astype(kv_dtype) for t in _qkv(lp, x, cfg))
 
-    def heads(t):
-        return t.reshape(1, s, cfg.num_heads, cfg.head_dim).transpose(
-            0, 2, 1, 3)
+        def heads(t):
+            return t.reshape(1, s, cfg.num_heads, cfg.head_dim).transpose(
+                0, 2, 1, 3)
 
-    ctx = flash_attention(heads(q), heads(k), heads(v), mask[None, :],
-                          causal=True,
-                          softmax_scale=1.0 / math.sqrt(cfg.head_dim))
-    y = _dense(lp["out"], ctx.transpose(0, 2, 1, 3).reshape(s, -1))
-    x = x + _rms(lp["norm1"], y, cfg.rms_norm_eps)
-    return x + _mlp(lp, x, cfg), k, v
+        ctx = flash_attention(heads(q), heads(k), heads(v), mask[None, :],
+                              causal=True,
+                              softmax_scale=1.0 / math.sqrt(cfg.head_dim))
+        y = _dense(lp["out"], ctx.transpose(0, 2, 1, 3).reshape(s, -1))
+        x = x + _rms(lp["norm1"], y, cfg.rms_norm_eps)
+    return _mlp(lp, x, cfg), k, v
 
 
 def full_block_decode(lp, x, cfg, k_pool, v_pool, layer, block_tables, pos):
@@ -383,14 +392,15 @@ def full_block_decode(lp, x, cfg, k_pool, v_pool, layer, block_tables, pos):
         paged_decode_attention,
     )
 
-    q, k, v = _qkv(lp, x, cfg)
-    k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
-    ctx = paged_decode_attention(
-        q[:, None], k[:, None], v[:, None], k_pool, v_pool, block_tables,
-        pos, layer, heads=cfg.num_heads)[:, 0]
-    y = _dense(lp["out"], ctx)
-    x = x + _rms(lp["norm1"], y, cfg.rms_norm_eps)
-    return x + _mlp(lp, x, cfg), k, v
+    with region("attention"):
+        q, k, v = _qkv(lp, x, cfg)
+        k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
+        ctx = paged_decode_attention(
+            q[:, None], k[:, None], v[:, None], k_pool, v_pool, block_tables,
+            pos, layer, heads=cfg.num_heads)[:, 0]
+        y = _dense(lp["out"], ctx)
+        x = x + _rms(lp["norm1"], y, cfg.rms_norm_eps)
+    return _mlp(lp, x, cfg), k, v
 
 
 # ---------------------------------------------------------------------------

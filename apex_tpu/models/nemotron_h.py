@@ -51,6 +51,7 @@ from apex_tpu.normalization import fused_rms_norm_affine
 from apex_tpu.transformer.functional import flash_attention, moe
 from apex_tpu.transformer.functional.gated_delta import causal_conv, conv_step
 from apex_tpu.transformer.functional.ssd import CHUNK, ssd_chunked, ssd_step
+from apex_tpu.utils.profiler import region
 
 MAMBA, ATTENTION, EXPERTS = "M", "*", "E"
 
@@ -300,11 +301,13 @@ def _rms(p, x, eps):
     return fused_rms_norm_affine(x, p["weight"], x.shape[-1], eps)
 
 
+@region("embed")
 def embed(params, ids):
     return jnp.take(params["embedding"]["word"]["embedding"], ids,
                     axis=0).astype(jnp.float32)
 
 
+@region("head")
 def logits_of(params, cfg, x):
     """Final norm and the untied head: (rows, hidden) -> float32 logits."""
     return _dense(params["head"],
@@ -343,6 +346,7 @@ def _mamba_out(lp, y, xs, z, cfg):
     return _dense(lp["out"], y.reshape(rows, -1) * lp["y_norm"]["weight"])
 
 
+@region("mixer")
 def mamba_block_prefill(lp, x, cfg, mask):
     """One Mamba-2 layer over a prompt: ``x`` (s, hidden), ``mask`` (s,) with
     1 = real token and the padding at the end. Returns ``(x', state (H, P, N)
@@ -364,6 +368,7 @@ def mamba_block_prefill(lp, x, cfg, mask):
     return x + _mamba_out(lp, y[:s], xs, z, cfg), state, tail
 
 
+@region("mixer")
 def mamba_block_decode(lp, x, cfg, state, conv, layer, active):
     """One token for every slot: ``x`` (b, hidden); ``state`` and ``conv`` the
     WHOLE stacked arrays (``NemotronHConfig.state_shapes``), of which layer
@@ -394,6 +399,7 @@ def _qkv(lp, x, cfg):
         qkv[:, q + cfg.kv_row_width:]
 
 
+@region("attention")
 def attention_block_prefill(lp, x, cfg, mask, kv_dtype):
     """One attention layer over a prompt. Returns ``(x', k, v)``, the (s,
     kv_heads * head_dim) rows the cache keeps, in ``kv_dtype``, the cache's:
@@ -414,6 +420,7 @@ def attention_block_prefill(lp, x, cfg, mask, kv_dtype):
         k, v
 
 
+@region("attention")
 def attention_block_decode(lp, x, cfg, k_pool, v_pool, layer, block_tables,
                            pos):
     """One token for every slot against the paged pool, read in place by
@@ -442,21 +449,26 @@ def expert_block(lp, x, cfg, real):
     tokens. Returns ``(x', sizes (experts_held,), chosen (rows, k))``: the
     assignments each held expert got, and the router's choice."""
     rows = x.shape[0]
-    u = _rms(lp["norm"], x, cfg.rms_norm_eps)
-    logits = jnp.dot(u, lp["router"]["kernel"].astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    chosen, weights = moe.route(logits, lp["router_bias"],
-                                cfg.experts_per_token,
-                                cfg.routed_scaling_factor)
-    d = moe.dispatch(chosen, weights, cfg.expert_offset, cfg.experts_held,
-                     real)
-    latent = _dense(lp["down"], u)
-    mid = moe.grouped_matmul(latent[d.token], lp["w1"], d.sizes,
-                             activation="relu2")
-    routed = moe.combine(moe.grouped_matmul(mid, lp["w2"], d.sizes), d, rows)
-    shared = _dense(lp["shared_out"],
-                    jnp.square(jax.nn.relu(_dense(lp["shared_in"], u))))
-    return x + _dense(lp["up"], routed) + shared, d.sizes, chosen
+    with region("router"):
+        u = _rms(lp["norm"], x, cfg.rms_norm_eps)
+        logits = jnp.dot(u, lp["router"]["kernel"].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        chosen, weights = moe.route(logits, lp["router_bias"],
+                                    cfg.experts_per_token,
+                                    cfg.routed_scaling_factor)
+    with region("experts"):
+        d = moe.dispatch(chosen, weights, cfg.expert_offset,
+                         cfg.experts_held, real)
+        latent = _dense(lp["down"], u)
+        mid = moe.grouped_matmul(latent[d.token], lp["w1"], d.sizes,
+                                 activation="relu2")
+        routed = moe.combine(moe.grouped_matmul(mid, lp["w2"], d.sizes), d,
+                             rows)
+    with region("mlp"):     # the shared expert
+        shared = _dense(lp["shared_out"],
+                        jnp.square(jax.nn.relu(_dense(lp["shared_in"], u))))
+    with region("experts"):
+        return x + _dense(lp["up"], routed) + shared, d.sizes, chosen
 
 
 # ---------------------------------------------------------------------------
@@ -529,11 +541,13 @@ def decode_layers(params, cfg: NemotronHConfig, cache, tokens, active):
                 v_rows.append(v_row)
             else:
                 x, sizes, _ = expert_block(lp, x, cfg, active)
-                counters = {
-                    **counters,
-                    "moe_load": counters["moe_load"].at[layer].add(sizes),
-                    "moe_hit": counters["moe_hit"].at[layer].add(
-                        jnp.sum(sizes > 0))}
+                with region("experts"):
+                    counters = {
+                        **counters,
+                        "moe_load": counters["moe_load"].at[layer].add(
+                            sizes),
+                        "moe_hit": counters["moe_hit"].at[layer].add(
+                            jnp.sum(sizes > 0))}
         return (x, state, conv, counters), (jnp.stack(k_rows),
                                             jnp.stack(v_rows))
 

@@ -18,6 +18,7 @@ from apex_tpu.optimizers._common import (
     finish_compute_params, flat_layout,
     f32, select_finite, tree_unzip, tree_zeros_f32,
 )
+from apex_tpu.utils.profiler import region
 
 
 class AdagradState(NamedTuple):
@@ -49,6 +50,7 @@ class FusedAdagrad:
             return AdagradState(step=step, sum=jnp.zeros_like(buf))
         return AdagradState(step=step, sum=tree_zeros_f32(params))
 
+    @region("optimizer")
     def step(self, grads: Any, params: Any, state: AdagradState, *,
              lr=None, grad_scale=1.0, weight_decay=None,
              found_inf: Optional[jax.Array] = None,

@@ -25,6 +25,7 @@ from apex_tpu.optimizers._common import (
     check_m_dtype, finish_compute_params, flat_layout,
     f32, select_finite, tree_unzip, tree_zeros,
 )
+from apex_tpu.utils.profiler import region
 
 
 class AdamState(NamedTuple):
@@ -83,6 +84,7 @@ class FusedAdam:
 
         return AdamState(step=P(), m=param_specs, v=param_specs)
 
+    @region("optimizer")
     def step(self, grads: Any, params: Any, state: AdamState, *,
              lr=None, grad_scale=1.0, weight_decay=None,
              found_inf: Optional[jax.Array] = None,

@@ -20,6 +20,7 @@ from apex_tpu.optimizers._common import (
     check_m_dtype, f32, finish_compute_params, global_grad_norm,
     select_finite, tree_unzip, tree_zeros, tree_zeros_f32,
 )
+from apex_tpu.utils.profiler import region
 
 
 class LambState(NamedTuple):
@@ -72,6 +73,7 @@ class FusedLAMB:
                          m=tree_zeros(params, self.m_dtype),
                          v=tree_zeros_f32(params))
 
+    @region("optimizer")
     def step(self, grads: Any, params: Any, state: LambState, *,
              lr=None, weight_decay=None, grad_scale=1.0,
              grad_norm: Optional[jax.Array] = None,

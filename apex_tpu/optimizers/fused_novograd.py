@@ -24,6 +24,7 @@ from apex_tpu.optimizers._common import (
     check_m_dtype, finish_compute_params, flat_layout,
     f32, select_finite, tree_unzip, tree_zeros,
 )
+from apex_tpu.utils.profiler import region
 
 
 class NovoGradState(NamedTuple):
@@ -71,6 +72,7 @@ class FusedNovoGrad:
             m=tree_zeros(params, self.m_dtype),
             v=jax.tree.map(lambda p: jnp.zeros((), jnp.float32), params))
 
+    @region("optimizer")
     def step(self, grads: Any, params: Any, state: NovoGradState, *,
              lr=None, grad_scale=1.0, weight_decay=None,
              found_inf: Optional[jax.Array] = None,

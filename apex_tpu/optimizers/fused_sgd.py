@@ -14,6 +14,7 @@ from apex_tpu.optimizers._common import (
     check_m_dtype, f32, finish_compute_params, select_finite, tree_unzip,
     tree_zeros,
 )
+from apex_tpu.utils.profiler import region
 
 
 class SGDState(NamedTuple):
@@ -61,6 +62,7 @@ class FusedSGD:
         return SGDState(step=step,
                         momentum_buf=tree_zeros(params, self.m_dtype))
 
+    @region("optimizer")
     def step(self, grads: Any, params: Any, state: SGDState, *,
              lr=None, grad_scale=1.0, weight_decay=None,
              found_inf: Optional[jax.Array] = None,

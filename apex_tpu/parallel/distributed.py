@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.transformer import parallel_state as ps
+from apex_tpu.utils.profiler import region
 
 
 class DistributedDataParallel:
@@ -68,6 +69,7 @@ class DistributedDataParallel:
         return jax.tree.map(
             lambda p: lax.pcast(p, self.axis_name, to="varying"), params)
 
+    @region("grad_sync")
     def allreduce_grads(self, grads: Any) -> Any:
         """psum grads over the data axis (call inside shard_map/pmap).
 

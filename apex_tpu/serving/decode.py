@@ -87,11 +87,17 @@ from apex_tpu.serving.cache import (
     KVCache, PagedKVCache, cache_partition_specs,
     paged_cache_partition_specs,
 )
+from apex_tpu.utils.profiler import region
 
 
 # ---------------------------------------------------------------------------
 # shared cores (parameterized by the linear/embedding/logits impls)
 # ---------------------------------------------------------------------------
+
+def _final_ln(params, cfg: GPTConfig, x):
+    with region("head"):
+        return _ln(params["final_ln"], x, cfg.layer_norm_eps)
+
 
 def _prefill_core(params, cfg: GPTConfig, cache: KVCache, ids, mask,
                   slot, *, embed_fn, dense_fns, logits_fn):
@@ -114,7 +120,7 @@ def _prefill_core(params, cfg: GPTConfig, cache: KVCache, ids, mask,
         return x, (k, v)
 
     x, (k, v) = lax.scan(body, x, params["layers"])
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     length = jnp.sum(mask).astype(jnp.int32)
     h_last = lax.dynamic_slice_in_dim(hidden, length - 1, 1, 1)[:, 0]
     logits = logits_fn(params, h_last)
@@ -123,13 +129,14 @@ def _prefill_core(params, cfg: GPTConfig, cache: KVCache, ids, mask,
     # the cache contents independent of pad ids outright (and keep the
     # donation bit-identity tests deterministic)
     mz = mask.astype(k.dtype)[None, None, None, :, None]
-    new = KVCache(
-        k=lax.dynamic_update_slice(cache.k, (k * mz).astype(cache.k.dtype),
-                                   (0, slot, 0, 0, 0)),
-        v=lax.dynamic_update_slice(cache.v, (v * mz).astype(cache.v.dtype),
-                                   (0, slot, 0, 0, 0)),
-        lengths=lax.dynamic_update_slice(cache.lengths, length[None],
-                                         (slot,)))
+    with region("cache_write"):
+        new = KVCache(
+            k=lax.dynamic_update_slice(
+                cache.k, (k * mz).astype(cache.k.dtype), (0, slot, 0, 0, 0)),
+            v=lax.dynamic_update_slice(
+                cache.v, (v * mz).astype(cache.v.dtype), (0, slot, 0, 0, 0)),
+            lengths=lax.dynamic_update_slice(cache.lengths, length[None],
+                                             (slot,)))
     return new, logits
 
 
@@ -149,7 +156,7 @@ def _decode_core(params, cfg: GPTConfig, cache: KVCache, tokens, active,
         return x, (kc, vc)
 
     x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     logits = logits_fn(params, hidden[:, 0])
     return KVCache(k, v, jnp.where(active, pos + 1, pos)), logits
 
@@ -187,7 +194,7 @@ def _verify_core(params, cfg: GPTConfig, cache: KVCache, tokens, *,
         return x, (kc, vc)
 
     x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     logits = logits_fn(params, hidden)
     return KVCache(k, v, _self_rewrite(pos)), logits
 
@@ -215,7 +222,7 @@ def _tree_verify_core(params, cfg: GPTConfig, cache: KVCache, tokens,
         return x, (kc, vc)
 
     x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     logits = logits_fn(params, hidden)
     return KVCache(k, v, _self_rewrite(pos)), logits
 
@@ -251,7 +258,7 @@ def _chunk_prefill_core(params, cfg: GPTConfig, cache: KVCache, ids,
         return x, (kc, vc)
 
     x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     n_real = jnp.sum(mask).astype(jnp.int32)
     h_last = lax.dynamic_slice_in_dim(hidden, n_real - 1, 1, 1)[:, 0]
     logits = logits_fn(params, h_last)
@@ -300,7 +307,7 @@ def _paged_prefill_core(params, cfg: GPTConfig, cache: PagedKVCache, ids,
         return x, (k, v)
 
     x, (k, v) = lax.scan(body, x, params["layers"])
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     length = jnp.sum(mask).astype(jnp.int32)
     h_last = lax.dynamic_slice_in_dim(hidden, length - 1, 1, 1)[:, 0]
     logits = logits_fn(params, h_last)
@@ -313,30 +320,32 @@ def _paged_prefill_core(params, cfg: GPTConfig, cache: PagedKVCache, ids,
         t = (t * mz)[:, 0].transpose(0, 2, 1, 3)
         return t.reshape(lyr, n_bucket_pages, page_size, nh * hd)
 
-    lengths = lax.dynamic_update_slice(cache.lengths, length[None],
-                                       (slot,))
-    block_tables = lax.dynamic_update_slice(
-        cache.block_tables, table_row[None, :], (slot, 0))
-    if cache.k_scale is not None:
-        # int8 pool: quantize each freshly-written page per head (amax
-        # over the page, zeroed pad rows quantize to exact 0) and
-        # scatter tiles + scales together — 6 alias pairs
-        from apex_tpu.quant.kernels import kv_quantize
+    @region("cache_write")
+    def write():
+        lengths = lax.dynamic_update_slice(cache.lengths, length[None],
+                                           (slot,))
+        block_tables = lax.dynamic_update_slice(
+            cache.block_tables, table_row[None, :], (slot, 0))
+        if cache.k_scale is not None:
+            # int8 pool: quantize each freshly-written page per head (amax
+            # over the page, zeroed pad rows quantize to exact 0) and
+            # scatter tiles + scales together — 6 alias pairs
+            from apex_tpu.quant.kernels import kv_quantize
 
-        kq, ks = kv_quantize(_pages_to_tiles(pages(k), cfg.head_dim))
-        vq, vs = kv_quantize(_pages_to_tiles(pages(v), cfg.head_dim))
-        new = PagedKVCache(
-            k=cache.k.at[:, write_pages].set(_tiles_to_pages(kq)),
-            v=cache.v.at[:, write_pages].set(_tiles_to_pages(vq)),
-            lengths=lengths, block_tables=block_tables,
-            k_scale=cache.k_scale.at[:, write_pages].set(ks),
-            v_scale=cache.v_scale.at[:, write_pages].set(vs))
-        return new, logits
-    new = PagedKVCache(
-        k=cache.k.at[:, write_pages].set(pages(k).astype(cache.k.dtype)),
-        v=cache.v.at[:, write_pages].set(pages(v).astype(cache.v.dtype)),
-        lengths=lengths, block_tables=block_tables)
-    return new, logits
+            kq, ks = kv_quantize(_pages_to_tiles(pages(k), cfg.head_dim))
+            vq, vs = kv_quantize(_pages_to_tiles(pages(v), cfg.head_dim))
+            return PagedKVCache(
+                k=cache.k.at[:, write_pages].set(_tiles_to_pages(kq)),
+                v=cache.v.at[:, write_pages].set(_tiles_to_pages(vq)),
+                lengths=lengths, block_tables=block_tables,
+                k_scale=cache.k_scale.at[:, write_pages].set(ks),
+                v_scale=cache.v_scale.at[:, write_pages].set(vs))
+        return PagedKVCache(
+            k=cache.k.at[:, write_pages].set(pages(k).astype(cache.k.dtype)),
+            v=cache.v.at[:, write_pages].set(pages(v).astype(cache.v.dtype)),
+            lengths=lengths, block_tables=block_tables)
+
+    return write(), logits
 
 
 def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
@@ -370,7 +379,7 @@ def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
         x, (k, v, ks, vs) = lax.scan(
             body, x, (params["layers"], cache.k, cache.v,
                       cache.k_scale, cache.v_scale))
-        hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+        hidden = _final_ln(params, cfg, x)
         logits = logits_fn(params, hidden[:, 0])
         bt = _self_rewrite(bt)
         return PagedKVCache(k, v, jnp.where(active, pos + 1, pos), bt,
@@ -389,7 +398,7 @@ def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
     layers = cache.k.shape[0]
     x, (k_rows, v_rows) = lax.scan(
         body, x, (params["layers"], jnp.arange(layers, dtype=jnp.int32)))
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     logits = logits_fn(params, hidden[:, 0])
     k, v = _write_new_rows(cache, k_rows, v_rows)
     bt = _self_rewrite(bt)
@@ -406,17 +415,18 @@ def _write_new_rows(cache, k_rows, v_rows):
     latent pool) takes ``v_rows`` ``None`` and gives ``v`` ``None``."""
     pos, bt = cache.lengths, cache.block_tables
     layers, num_pages, page_size, width = cache.k.shape
-    logical = jnp.clip(pos // page_size, 0, bt.shape[1] - 1)
-    pages = jnp.take_along_axis(bt, logical[:, None], 1)[:, 0]
-    at = ((jnp.arange(layers)[:, None] * num_pages + pages[None, :])
-          * page_size + pos[None, :] % page_size).reshape(-1)
 
     def write(pool, rows):
         flat = pool.reshape(-1, width).at[at].set(rows.reshape(-1, width))
         return flat.reshape(pool.shape)
 
-    return write(cache.k, k_rows), \
-        None if cache.v is None else write(cache.v, v_rows)
+    with region("cache_write"):
+        logical = jnp.clip(pos // page_size, 0, bt.shape[1] - 1)
+        pages = jnp.take_along_axis(bt, logical[:, None], 1)[:, 0]
+        at = ((jnp.arange(layers)[:, None] * num_pages + pages[None, :])
+              * page_size + pos[None, :] % page_size).reshape(-1)
+        return write(cache.k, k_rows), \
+            None if cache.v is None else write(cache.v, v_rows)
 
 
 def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
@@ -442,7 +452,7 @@ def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
         x, (k, v, ks, vs) = lax.scan(
             body, x, (params["layers"], cache.k, cache.v,
                       cache.k_scale, cache.v_scale))
-        hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+        hidden = _final_ln(params, cfg, x)
         logits = logits_fn(params, hidden)
         return PagedKVCache(k, v, _self_rewrite(pos), _self_rewrite(bt),
                             ks, vs), logits
@@ -454,7 +464,7 @@ def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
         return x, (kp, vp)
 
     x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     logits = logits_fn(params, hidden)
     return PagedKVCache(k, v, _self_rewrite(pos), _self_rewrite(bt)), \
         logits
@@ -484,7 +494,7 @@ def _paged_tree_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
         return x, (kp, vp)
 
     x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     logits = logits_fn(params, hidden)
     return PagedKVCache(k, v, _self_rewrite(pos), _self_rewrite(bt)), \
         logits
@@ -539,7 +549,7 @@ def _paged_chunk_prefill_core(params, cfg: GPTConfig,
         return x, (kp, vp)
 
     x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _ln(params["final_ln"], x, cfg.layer_norm_eps)
+    hidden = _final_ln(params, cfg, x)
     n_real = jnp.sum(mask).astype(jnp.int32)
     h_last = lax.dynamic_slice_in_dim(hidden, n_real - 1, 1, 1)[:, 0]
     logits = logits_fn(params, h_last)
@@ -637,10 +647,10 @@ def _logits_w8(params, hidden):
 
 def _unsharded_fns(cfg: GPTConfig, compute_dtype, quantized):
     if quantized:
-        return (_embed_w8(cfg, compute_dtype), (_dense_w8,) * 4,
-                _logits_w8)
-    return (_embed_unsharded(cfg, compute_dtype), (_dense,) * 4,
-            _logits_unsharded)
+        return (region("embed")(_embed_w8(cfg, compute_dtype)),
+                (_dense_w8,) * 4, region("head")(_logits_w8))
+    return (region("embed")(_embed_unsharded(cfg, compute_dtype)),
+            (_dense,) * 4, region("head")(_logits_unsharded))
 
 
 def make_prefill_fn(cfg: GPTConfig, compute_dtype=None, quantized=False):
@@ -875,20 +885,21 @@ def _model_prefill_core(params, cfg, cache, ids, mask, slot, write_pages,
         t = t * mask.astype(t.dtype)[None, :, None]
         return t.reshape(t.shape[0], -1, page_size, t.shape[-1])
 
-    new = {"k": cache.k.at[:, write_pages].set(pages(k))}
-    if v is not None:
-        new["v"] = cache.v.at[:, write_pages].set(pages(v))
-    new["lengths"] = lax.dynamic_update_slice(cache.lengths, length[None],
-                                              (slot,))
-    new["block_tables"] = lax.dynamic_update_slice(
-        cache.block_tables, table_row[None, :], (slot, 0))
-    if states is not None:
-        new["state"] = lax.dynamic_update_slice(
-            cache.state, states[:, None], (0, slot, 0, 0, 0))
-        new["conv"] = lax.dynamic_update_slice(
-            cache.conv, tails[:, None], (0, slot, 0, 0))
-    # a prefill counts nothing; the donated leaves still need a write
-    new["counters"] = jax.tree.map(_self_rewrite, cache.counters)
+    with region("cache_write"):
+        new = {"k": cache.k.at[:, write_pages].set(pages(k))}
+        if v is not None:
+            new["v"] = cache.v.at[:, write_pages].set(pages(v))
+        new["lengths"] = lax.dynamic_update_slice(
+            cache.lengths, length[None], (slot,))
+        new["block_tables"] = lax.dynamic_update_slice(
+            cache.block_tables, table_row[None, :], (slot, 0))
+        if states is not None:
+            new["state"] = lax.dynamic_update_slice(
+                cache.state, states[:, None], (0, slot, 0, 0, 0))
+            new["conv"] = lax.dynamic_update_slice(
+                cache.conv, tails[:, None], (0, slot, 0, 0))
+        # a prefill counts nothing; the donated leaves still need a write
+        new["counters"] = jax.tree.map(_self_rewrite, cache.counters)
     return cache._replace(**new), logits
 
 
@@ -949,9 +960,10 @@ def make_copy_page_fn():
 
     def copy(cache, src, dst):
         def clone(pool):
-            page = lax.dynamic_slice_in_dim(pool, src, 1, axis=1)
-            return lax.dynamic_update_slice_in_dim(pool, page, dst,
-                                                   axis=1)
+            with region("cache_write"):
+                page = lax.dynamic_slice_in_dim(pool, src, 1, axis=1)
+                return lax.dynamic_update_slice_in_dim(pool, page, dst,
+                                                       axis=1)
 
         new = cache._replace(k=clone(cache.k))
         if cache.v is not None:         # a latent pool is the one pool
@@ -994,7 +1006,7 @@ def _tp_fns(model: GPTModel):
 
     dense_fns = (model.qkv.apply, model.out.apply, model.fc1.apply,
                  model.fc2.apply)
-    return embed, dense_fns, logits
+    return region("embed")(embed), dense_fns, region("head")(logits)
 
 
 def _tp_quant_fns(model: GPTModel):
@@ -1055,7 +1067,8 @@ def _tp_quant_fns(model: GPTModel):
         local = w8_matmul_nk(hidden, word["embedding"], word["scale"])
         return mappings.gather_from_tensor_model_parallel_region(local)
 
-    return embed, (column, row, column, row), logits
+    return (region("embed")(embed), (column, row, column, row),
+            region("head")(logits))
 
 
 def _tp_build(model: GPTModel, quantized: bool):

@@ -9,12 +9,26 @@ The reference leans on ``pyprof``/nvprof markers (removed upstream) and
   instrument.
 - :func:`annotate` (= ``jax.named_scope``) is the nvtx-range analogue:
   regions named here appear on the trace's Python/HLO-metadata rows, and
-  the scope names survive into HLO op metadata so device kernels
-  attribute back to model regions. The in-tree models and fused
-  optimizers are pre-annotated (attention / mlp / optimizer scopes), and
-  every Pallas kernel carries a stable ``apex_<kernel>_<fwd|bwd>`` name
-  and scope: a Mosaic ``custom-call`` is named in the trace after the
-  innermost scope around it.
+  the scope names survive into HLO op metadata (the ``tf_op`` stat of
+  every ``XLA Ops`` event) so device kernels attribute back to model
+  regions.
+- :func:`region` is ``annotate`` held to ONE vocabulary, :data:`REGIONS`,
+  the device-side counterpart of ``serving.observe.PHASES``. The library
+  opens them where the work is written, so a user's step composed from
+  ``h.cast_model`` / ``h.value_and_grad`` / a model / a loss / ``ddp`` /
+  an optimizer's ``step`` is named without touching user code: ``embed``,
+  ``attention``, ``mlp``, ``head`` (every in-tree model), ``mixer``
+  (Gated DeltaNet, Mamba-2), ``router`` and ``experts`` (the sparse
+  layers), ``loss`` (``mlm_loss``, the GPT losses), ``amp`` (the model
+  cast, loss scaling, unscale, the finiteness reduction), ``grad_sync``
+  (``DistributedDataParallel.allreduce_grads``), ``optimizer`` (every
+  fused optimizer's ``step``, around its own ``<Name>.step`` scope) and
+  ``cache_write`` (the row scatter into the serving page pool). An
+  operation counts under the FIRST region on its path; ``layer{i}`` and
+  the kernels' scopes are not regions. Every Pallas kernel carries a
+  stable ``apex_<kernel>_<fwd|bwd>`` name and scope: a Mosaic
+  ``custom-call`` is named in the trace after the innermost scope around
+  it, and its time counts under the region around its call.
 - :func:`span` is the HOST side: a ``jax.profiler.TraceAnnotation``
   named ``apex:<name>`` on the same timeline as the device planes. The
   serving scheduler opens one per phase of its tick.
@@ -37,6 +51,24 @@ from typing import Dict, List, Optional
 import jax
 
 annotate = jax.named_scope
+
+#: The device regions, one vocabulary for every model, the amp frontend, the
+#: data-parallel wrapper, the optimizers and the serving cache. A name here
+#: is a plain component of an operation's ``tf_op`` path in the device
+#: trace (``jit(train_step)/jvp(layer0)/attention/...``).
+REGIONS = ("embed", "attention", "mixer", "mlp", "router", "experts", "head",
+           "loss", "amp", "optimizer", "grad_sync", "cache_write")
+
+
+def region(name: str):
+    """``jax.named_scope(name)`` for a name in :data:`REGIONS`; any other
+    name raises, where the scope is opened (at trace time). A scope is a
+    name in the lowered module's locations: it costs nothing at run time
+    and leaves the compiled program as it was."""
+    if name not in REGIONS:
+        raise ValueError(f"{name!r} is not a device region; REGIONS = "
+                         f"{REGIONS}")
+    return jax.named_scope(name)
 
 
 def span(name: str, **counts):

@@ -1,0 +1,12 @@
+"""Device milliseconds of the ``experts`` group of regions per execution of
+``jit_decode``: ``experts`` and ``router`` (the scores and the choice, the
+sort, the grouped products, the combine). The MEAN over the traced span, so
+that the ``decode_ms.*`` groups add up to the program's summed ``XLA Ops`` time
+per execution (``benchmark/regions.py``); ``decode_device_ms`` stays the
+median. ``None`` where the program carries no region."""
+
+from benchmark import regions
+
+
+def read(run):
+    return regions.decode_ms(run, "experts")

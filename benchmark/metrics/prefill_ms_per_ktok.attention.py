@@ -1,0 +1,14 @@
+"""Device milliseconds of the ``attention`` group of regions in ``jit_prefill``
+(every bucket) per thousand bucket tokens, counted as
+``prefill_device_ms_per_ktok`` counts them: ``attention`` and ``cache_write``
+(norms, projections, the flash kernel, the output product, the pages scattered
+into the pool). The ``prefill_ms_per_ktok.*`` groups add up to the prefill
+programs' summed ``XLA Ops`` time per thousand tokens
+(``benchmark/regions.py``). ``None`` where the program carries no region or the
+traced span holds no prefill."""
+
+from benchmark import regions
+
+
+def read(run):
+    return regions.prefill_ms_per_ktok(run, "attention")

@@ -1,0 +1,12 @@
+"""Device milliseconds of the ``mlp`` group of regions per execution of
+``jit_train_step``: the two products, GELU and the LayerNorm of every layer,
+forward and backward. Summed over the traced span and divided by the
+executions; the ``train_step_ms.*`` groups add up to the program's summed ``XLA
+Ops`` time (``benchmark/regions.py``). ``None`` where the program carries no
+region."""
+
+from benchmark import regions
+
+
+def read(run):
+    return regions.train_step_ms(run, "mlp")

@@ -7,6 +7,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from apex_tpu import amp
 from apex_tpu.models import (
@@ -85,9 +86,184 @@ def _hlo_with_metadata(lowered):
         return lowered.compile().as_text()
 
 
-def test_named_scopes_reach_hlo_metadata():
-    """The profiler hooks are real: scope names survive into the lowered
-    HLO's metadata (what the trace viewer attributes kernels to)."""
+def _regions_in(lowered):
+    """The device regions (``utils.profiler.REGIONS``) on the operation
+    paths of a lowered computation, by the trace reader's own rule."""
+    import re
+
+    from benchmark.regions import region_of
+
+    # an operation's path has components and starts with none (a scan's
+    # body is a function of its own, its paths relative); a file's starts
+    # with "/", a stack frame's function name has no component
+    paths = re.findall(r'loc\("([^/"][^"]*/[^"]*)"',
+                       _hlo_with_metadata(lowered))
+    return {region_of(path) for path in paths} - {None}
+
+
+def _lower_bert_step(ddp: bool):
+    """The train step as ``benchmark/runners/bert_pretrain.py`` composes
+    it from the library: cast, value_and_grad over model and loss, the
+    optimizer; with ``ddp`` inside a one-device ``shard_map``."""
+    from jax.sharding import PartitionSpec as P
+
+    cfg = bert_tiny()
+    h = amp.initialize(opt_level="O2", loss_scale="dynamic", verbosity=0)
+    opt = FusedAdam(lr=1e-3)
+    master = init_bert(jax.random.PRNGKey(0), cfg)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    wrap = None
+    if ddp:
+        from apex_tpu.parallel import DistributedDataParallel
+        from apex_tpu.transformer import parallel_state as ps
+        ps.destroy_model_parallel()
+        mesh = ps.initialize_model_parallel(devices=jax.devices()[:1])
+        wrap = DistributedDataParallel()
+
+    def step(master, opt_state, scaler, ids, mask):
+        p = h.cast_model(wrap.local_replica(master) if wrap else master)
+        loss, grads, found_inf, scaler = h.value_and_grad(
+            lambda p: mlm_loss(apply_bert(p, cfg, ids, mask)["mlm_logits"],
+                               ids, mask),
+            reduce_grads=wrap.allreduce_grads if wrap else None)(p, scaler)
+        master, opt_state = opt.step(grads, master, opt_state,
+                                     found_inf=found_inf)
+        return master, opt_state, scaler, loss
+
+    if ddp:
+        rep, data = P(), P(ps.DATA_AXIS)
+        step = ps.shard_map(step, mesh=mesh,
+                            in_specs=(rep, rep, rep, data, data),
+                            out_specs=(rep, rep, rep, rep))
+    try:
+        return jax.jit(step).lower(master, opt.init(master), h.init_state(),
+                                   ids, jnp.ones_like(ids))
+    finally:
+        if ddp:
+            ps.destroy_model_parallel()
+
+
+def _lower_gpt(which: str):
+    """One of the GPT serving programs at ``gpt_tiny``."""
+    from apex_tpu.serving import cache as C
+    from apex_tpu.serving import decode as D
+
+    cfg = gpt_tiny()
+    params = init_gpt(jax.random.PRNGKey(0), cfg)
+    slots, max_len, page = 2, 32, 4
+    i32 = jnp.int32
+    tokens, active = jnp.zeros((slots,), i32), jnp.ones((slots,), bool)
+    ids, mask = jnp.zeros((1, 8), i32), jnp.ones((8,), i32)
+    if which in ("prefill", "decode", "verify", "chunk_prefill"):
+        cache = C.init_cache(cfg, slots, max_len, jnp.float32)
+        return {
+            "prefill": lambda: D.make_prefill_fn(cfg).lower(
+                params, cache, ids, mask, i32(0)),
+            "decode": lambda: D.make_decode_fn(cfg).lower(
+                params, cache, tokens, active),
+            "verify": lambda: D.make_verify_fn(cfg).lower(
+                params, cache, jnp.zeros((slots, 3), i32)),
+            "chunk_prefill": lambda: D.make_chunk_prefill_fn(cfg).lower(
+                params, cache, ids, mask, i32(0), i32(0)),
+        }[which]()
+    dtype = jnp.int8 if which.endswith("_q8") else jnp.float32
+    cache = C.init_paged_cache(cfg, slots, max_len, slots * 8 + 2, page,
+                               dtype)
+    return {
+        "paged_prefill": lambda: D.make_paged_prefill_fn(cfg).lower(
+            params, cache, ids, mask, i32(0), jnp.zeros((2,), i32),
+            jnp.zeros((8,), i32)),
+        "paged_decode": lambda: D.make_paged_decode_fn(cfg).lower(
+            params, cache, tokens, active),
+        "paged_decode_q8": lambda: D.make_paged_decode_fn(cfg).lower(
+            params, cache, tokens, active),
+        "paged_verify": lambda: D.make_paged_verify_fn(cfg).lower(
+            params, cache, jnp.zeros((slots, 3), i32)),
+        "paged_verify_q8": lambda: D.make_paged_verify_fn(cfg).lower(
+            params, cache, jnp.zeros((slots, 3), i32)),
+        "paged_tree_verify": lambda: D.make_paged_tree_verify_fn(cfg).lower(
+            params, cache, jnp.zeros((slots, 3), i32),
+            jnp.zeros((slots, 3), i32), jnp.ones((slots, 3, 3), bool)),
+    }[which]()
+
+
+def _lower_model(family: str, which: str):
+    """The prefill or decode program of a model that brings its cores, at
+    its tiny size."""
+    from apex_tpu.models import deepseek, hybrid, nemotron_h
+    from apex_tpu.serving import cache as C
+    from apex_tpu.serving import decode as D
+
+    cfg, init, init_cache = {
+        "hybrid": (hybrid.hybrid_tiny(), hybrid.init_hybrid,
+                   C.init_hybrid_cache),
+        "nemotron_h": (nemotron_h.nemotron_h_tiny(), nemotron_h.init,
+                       C.init_hybrid_cache),
+        "deepseek": (deepseek.deepseek_tiny(), deepseek.init,
+                     C.init_latent_cache)}[family]
+    params = init(jax.random.PRNGKey(0), cfg)
+    slots, max_len, page = 2, 32, 4
+    cache = init_cache(cfg, slots, max_len, slots * 8 + 2, page, jnp.float32)
+    i32 = jnp.int32
+    if which == "decode":
+        return D.make_model_decode_fn(cfg).lower(
+            params, cache, jnp.zeros((slots,), i32), jnp.ones((slots,), bool))
+    return D.make_model_prefill_fn(cfg).lower(
+        params, cache, jnp.zeros((1, 8), i32), jnp.ones((8,), i32), i32(0),
+        jnp.zeros((2,), i32), jnp.zeros((8,), i32))
+
+
+_TRAIN = {"embed", "attention", "mlp", "head", "loss", "amp", "optimizer"}
+_GPT = {"embed", "attention", "mlp", "head"}
+_SPARSE = _GPT | {"router", "experts", "cache_write"}
+_SCOPED = [
+    ("bert_train_step", lambda: _lower_bert_step(False), _TRAIN),
+    ("bert_train_step_ddp", lambda: _lower_bert_step(True),
+     _TRAIN | {"grad_sync"}),
+    ("gpt_prefill", lambda: _lower_gpt("prefill"), _GPT | {"cache_write"}),
+    ("gpt_decode", lambda: _lower_gpt("decode"), _GPT),
+    ("gpt_verify", lambda: _lower_gpt("verify"), _GPT),
+    ("gpt_chunk_prefill", lambda: _lower_gpt("chunk_prefill"), _GPT),
+    ("gpt_paged_prefill", lambda: _lower_gpt("paged_prefill"),
+     _GPT | {"cache_write"}),
+    ("gpt_paged_decode", lambda: _lower_gpt("paged_decode"),
+     _GPT | {"cache_write"}),
+    ("gpt_paged_decode_q8", lambda: _lower_gpt("paged_decode_q8"), _GPT),
+    ("gpt_paged_verify", lambda: _lower_gpt("paged_verify"), _GPT),
+    ("gpt_paged_verify_q8", lambda: _lower_gpt("paged_verify_q8"), _GPT),
+    ("gpt_paged_tree_verify", lambda: _lower_gpt("paged_tree_verify"), _GPT),
+    ("hybrid_prefill", lambda: _lower_model("hybrid", "prefill"),
+     _GPT | {"mixer", "cache_write"}),
+    ("hybrid_decode", lambda: _lower_model("hybrid", "decode"),
+     _GPT | {"mixer", "cache_write"}),
+    ("nemotron_h_prefill", lambda: _lower_model("nemotron_h", "prefill"),
+     _SPARSE | {"mixer"}),
+    ("nemotron_h_decode", lambda: _lower_model("nemotron_h", "decode"),
+     _SPARSE | {"mixer"}),
+    ("deepseek_prefill", lambda: _lower_model("deepseek", "prefill"),
+     _SPARSE),
+    ("deepseek_decode", lambda: _lower_model("deepseek", "decode"), _SPARSE),
+]
+
+
+@pytest.mark.parametrize("lower,regions", [c[1:] for c in _SCOPED],
+                         ids=[c[0] for c in _SCOPED])
+def test_named_scopes_reach_hlo_metadata(lower, regions):
+    """The profiler hooks are real: the device regions survive into the
+    lowered HLO's metadata (what the trace attributes operations to), in
+    the train step as the benchmark's runner composes it and in every
+    serving program, and each program holds exactly the regions its model
+    has."""
+    lowered = lower()
+    assert _regions_in(lowered) == regions
+    if "optimizer" in regions:      # the scopes PR 24's readers lean on
+        txt = _hlo_with_metadata(lowered)
+        assert "layer0)/attention" in txt or "layer0/attention" in txt
+        assert "layer0)/mlp" in txt or "layer0/mlp" in txt
+        assert "optimizer/FusedAdam.step" in txt
+
+
+def test_inference_forward_keeps_layer_scopes():
     cfg = bert_tiny()
     params = init_bert(jax.random.PRNGKey(0), cfg)
     ids = jnp.zeros((1, 16), jnp.int32)
@@ -97,12 +273,15 @@ def test_named_scopes_reach_hlo_metadata():
     assert "layer0/attention" in txt
     assert "layer0/mlp" in txt
 
-    opt = FusedAdam(lr=1e-3)
-    st = opt.init({"w": jnp.ones((4,))})
-    txt = _hlo_with_metadata(jax.jit(
-        lambda g, p, s: opt.step(g, p, s)
-    ).lower({"w": jnp.ones((4,))}, {"w": jnp.ones((4,))}, st))
-    assert "FusedAdam.step" in txt
+
+def test_region_refuses_a_name_outside_the_vocabulary():
+    from apex_tpu.utils.profiler import REGIONS, region
+
+    with pytest.raises(ValueError, match="not a device region"):
+        region("nonsense")
+    assert len(set(REGIONS)) == len(REGIONS) == 12
+    with region("attention"):       # and is a plain named scope otherwise
+        pass
 
 
 def test_profiler_trace_writes_files(tmp_path):

@@ -85,12 +85,35 @@ NEW_CASE_OF_A_PINNED_TEST = {
 }
 
 
+# Two assertions of ``tests/L0/run_benchmark/test_deepseek_cell.py`` pin the
+# SET of readers of two cells as PR 36 left it: ``{per_layer of the DeepSeek
+# cell} - NEW == SHARED`` and ``len(nemotron.per_layer) == 16``. A ``tracing``
+# PR that appends readers of those cells (PR 38: five ``decode_ms.*`` to the
+# first, twelve ``decode_ms.*`` / ``prefill_ms_per_ktok.*`` to the second) has
+# to fail both, and may not edit that file. STRICT, as above; all they check
+# besides the two pins is asserted again, by name, in
+# ``tests/L0/run_benchmark/test_regions.py::
+# test_what_the_pinned_tests_of_pr_36_check_besides``.
+_DEEPSEEK = "tests/L0/run_benchmark/test_deepseek_cell.py::"
+PINNED_BY_PR_36 = {
+    _DEEPSEEK
+    + "test_manifest_gains_the_cell_after_every_entry_that_was_there":
+        "asserts the DeepSeek cell's readers are NEW + SHARED and no other; "
+        "PR 38 appends five readers of its decode program",
+    _DEEPSEEK + "test_what_the_pinned_tests_of_pr_33_check_besides":
+        "asserts the Nemotron cell has sixteen readers; PR 38 appends twelve",
+}
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         p = item.path
         if p.name not in _HEAVY_MODULES and "L1" not in p.parts:
             item.add_marker(pytest.mark.quick)
-        if item.nodeid in PINNED_BY_PR_33:
+        if item.nodeid in PINNED_BY_PR_36:
+            item.add_marker(pytest.mark.xfail(
+                reason=PINNED_BY_PR_36[item.nodeid], strict=True))
+        elif item.nodeid in PINNED_BY_PR_33:
             item.add_marker(pytest.mark.xfail(
                 reason=PINNED_BY_PR_33[item.nodeid], strict=True))
         elif item.nodeid in NEW_CASE_OF_A_PINNED_TEST:
