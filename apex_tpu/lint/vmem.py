@@ -470,8 +470,9 @@ def _paged_serving_cfg(which):
 def _paged_attention_cfg():
     """The paged decode-attention kernel at the served width: 56 slots,
     16 heads of 64, pages of 16, a bfloat16 pool left in HBM. What is
-    resident is the query / new-row / output blocks and the two K and two
-    V buffers of 128 positions the kernel fetches pages into."""
+    resident is the query / new-row / output blocks, the two K and two V
+    buffers the kernel fetches pages into (1 MiB each: 512 positions of
+    this row) and the SMEM word that says which buffer a slot starts in."""
     def build():
         import functools as ft
 

@@ -3,7 +3,7 @@
 The decode step's attention reads only what a slot has mapped: the kernel
 walks the slot's block-table row up to ``pos``, brings those pages of the
 stacked pool from HBM to VMEM by DMA (double-buffered, several pages a
-step) and runs an online softmax over them in float32. The pool is never
+chunk) and runs an online softmax over them in float32. The pool is never
 gathered, transposed or upcast in HBM, and it is only read: the new
 token's K/V row comes in as an operand standing at position ``pos``, and
 the caller writes it into the (donated) pool afterwards.
@@ -17,11 +17,30 @@ are equal, one block shared by ``heads / kv_heads`` query rows when K/V heads
 are fewer) and zeros elsewhere; the context is the matching blocks of ``P @
 V``.
 
+The walk crosses slots. The grid runs the slots in order on one core, and
+the two buffers, their DMA semaphores and one SMEM word outlive a grid step.
+While slot ``i`` attends over its LAST chunk (or at once, when ``pos`` is 0
+and it has none) it starts the descriptors of slot ``i + 1``'s first chunk
+into the buffer that is free: the next table row and ``pos`` are
+scalar-prefetched, so it can name them. Slot ``i + 1`` begins by waiting for
+those pages, not by asking for them, and only slot 0 of a call pays a first
+fetch with nothing to hide it behind. The SMEM word says which buffer holds
+the running slot's first chunk; a slot without a chunk leaves it as it is
+and hands the turn on; the last slot starts nothing, so every descriptor of
+a call is waited for inside it. A chunk that is full is waited for once per
+pool (a DMA semaphore counts bytes); a slot's last chunk, which may be
+partly filled, in at most ``log2`` descriptors of whole pages.
+
 Placement invariance: a slot's output depends on the pages its table names
 below ``pos`` and on nothing else. Pages past ``cdiv(pos, page_size)`` are
-never fetched, so NULL table entries are never dereferenced, and rows of
-the last page at or past ``pos`` are masked in the scores AND zeroed in the
-values, so what they hold (stale rows, NaN) cannot reach the output.
+never fetched, so NULL table entries are never dereferenced. A slot reads
+only the buffer its own chunk was waited into, and of that buffer only rows
+below ``pos`` count: rows at or past ``pos`` can stand only in a slot's last
+chunk (the rest of a partly filled buffer with them: stale rows of an
+earlier chunk, of another slot), and there they are masked in the scores AND
+zeroed in the values, so what they hold (another slot's rows, NaN) cannot
+reach the output. The next slot's pages, on their way into the OTHER buffer
+meanwhile, are never read by this one.
 """
 
 import functools
@@ -35,9 +54,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.utils.platform import pallas_interpret
 
-# cache positions brought to VMEM per DMA step (one buffer of K and one of
-# V; two of each are resident)
-_CHUNK_POSITIONS = 128
+# a buffer (one of K and one of V; two of each are resident) holds as many
+# whole pages as fit in _BUFFER_BYTES: the chunk follows the row's width
+# (2 KB rows: 512 positions; 7.5 KB rows: 128)
+_BUFFER_BYTES = 1 << 20
+# a slot's last chunk is attended over its leading blocks of this many
+# positions that hold a row below ``pos``, not over the whole buffer
+_LIVE_POSITIONS = 128
 # head rows of the score matrix are padded to whole bfloat16 sublane tiles
 _ROW_TILE = 16
 
@@ -45,29 +68,36 @@ _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
 
 
-def _dot_f32(a, b, dims):
-    """``a`` (float32) contracted with ``b`` (a chunk in the pool's dtype),
-    products exact and accumulation in float32. A bfloat16 chunk goes to
-    the MXU as it is, against the three bfloat16 pieces whose sum is ``a``
-    (stacked, so the chunk is pushed once); a float32 chunk takes the
-    MXU's full-precision passes."""
-    if b.dtype != jnp.bfloat16:
-        return lax.dot_general(a, b.astype(jnp.float32), dims,
-                               precision=lax.Precision.HIGHEST,
-                               preferred_element_type=jnp.float32)
+def _operand(a, dtype):
+    """``a`` (float32) as ``_dot_f32`` takes it against a chunk of ``dtype``:
+    against bfloat16 the three bfloat16 pieces whose sum is ``a``, stacked
+    (so the chunk is pushed to the MXU once); else ``a`` itself."""
+    if dtype != jnp.bfloat16:
+        return a
     pieces = []
     for _ in range(3):
         piece = a.astype(jnp.bfloat16)
         pieces.append(piece)
         a = a - piece.astype(jnp.float32)
-    rows = pieces[0].shape[0]
-    out = lax.dot_general(jnp.concatenate(pieces, axis=0), b, dims,
-                          preferred_element_type=jnp.float32)
+    return jnp.concatenate(pieces, axis=0)
+
+
+def _dot_f32(a, b, dims):
+    """``_operand(a)`` contracted with ``b`` (a chunk in the pool's dtype),
+    products exact and accumulation in float32. A bfloat16 chunk goes to
+    the MXU as it is, against the stacked pieces; a float32 chunk takes the
+    MXU's full-precision passes."""
+    if b.dtype != jnp.bfloat16:
+        return lax.dot_general(a, b.astype(jnp.float32), dims,
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+    rows = a.shape[0] // 3
+    out = lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
     return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
 
 
 def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
-                   k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *, heads,
+                   k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, turn, *, heads,
                    kv_heads, page_size):
     span, width = kbuf.shape[1:]
     hd = width // kv_heads
@@ -75,29 +105,57 @@ def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
     chunk = span // page_size
     rows = -(-heads // _ROW_TILE) * _ROW_TILE
     slot = pl.program_id(0)
+    slots = pl.num_programs(0)
     pos = pos_ref[slot]
     layer = layer_ref[0]
-    # pages holding rows below pos: the new row itself is an operand
-    n_pages = (pos + page_size - 1) // page_size
+
+    def pages_of(s):
+        # pages holding rows below pos: the new row itself is an operand
+        return (pos_ref[s] + page_size - 1) // page_size
+
+    n_pages = pages_of(slot)
     n_chunks = (n_pages + chunk - 1) // chunk
 
-    def dma(c, buf, fn):
+    def fetch(s, c, buf):
+        """Start the descriptors of chunk ``c`` of slot ``s`` into ``buf``."""
         first = c * chunk
 
         def page(j, _):
-            src = bt_ref[slot, first + j]
+            src = bt_ref[s, first + j]
             dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
-            fn(pltpu.make_async_copy(k_hbm.at[layer, src],
-                                     kbuf.at[buf, dst], sem.at[0, buf]))
-            fn(pltpu.make_async_copy(v_hbm.at[layer, src],
-                                     vbuf.at[buf, dst], sem.at[1, buf]))
+            pltpu.make_async_copy(k_hbm.at[layer, src], kbuf.at[buf, dst],
+                                  sem.at[0, buf]).start()
+            pltpu.make_async_copy(v_hbm.at[layer, src], vbuf.at[buf, dst],
+                                  sem.at[1, buf]).start()
             return 0
 
-        lax.fori_loop(0, jnp.minimum(chunk, n_pages - first), page, 0)
+        lax.fori_loop(0, jnp.minimum(chunk, pages_of(s) - first), page, 0)
 
-    @pl.when(n_chunks > 0)
+    def wait(buf, pages):
+        """Wait for ``pages`` (static) pages' worth of bytes in ``buf``, K
+        and V: one descriptor each, which names the buffer's leading rows
+        only for their size."""
+        for i, ref in enumerate((kbuf, vbuf)):
+            at = ref.at[buf, pl.ds(0, pages * page_size)]
+            pltpu.make_async_copy(at, at, sem.at[i, buf]).wait()
+
+    def hand_on(buf):
+        """Start the next slot's first chunk into ``buf``, which is free."""
+        @pl.when(slot + 1 < slots)
+        def _():
+            fetch(slot + 1, 0, buf)
+
+    @pl.when(slot == 0)
     def _():
-        dma(0, 0, lambda d: d.start())
+        turn[0] = 0
+        fetch(slot, 0, 0)
+
+    base = turn[0]                      # the buffer of this slot's chunk 0
+    turn[0] = (base + n_chunks) % 2     # and of the next slot's
+
+    @pl.when(n_chunks == 0)
+    def _():
+        hand_on(base)
 
     if per == 1:
         # head h's query in columns [h * hd, (h + 1) * hd) of row h
@@ -114,36 +172,62 @@ def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
             q_rows = jnp.concatenate(
                 [q_rows, jnp.zeros((rows - heads, hd), jnp.float32)], 0)
         q_blk = jnp.where(own, jnp.concatenate([q_rows] * kv_heads, 1), 0.0)
+    q_op = _operand(q_blk, kbuf.dtype)  # once a slot, not once a chunk
     norm = math.sqrt(hd)
     neg = jnp.finfo(jnp.float32).min
-    at_lane = lax.broadcasted_iota(jnp.int32, (rows, span), 1)
-    at_row = lax.broadcasted_iota(jnp.int32, (span, 1), 0)
 
-    def step(c, carry):
-        m, l, acc = carry
-        buf = c % 2
-
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            dma(c + 1, 1 - buf, lambda d: d.start())
-
-        dma(c, buf, lambda d: d.wait())
+    def step(c, carry, last):
+        """Chunk ``c`` of this slot; ``last`` (static) says it is the slot's
+        last one: the only one that may be partly filled and hold rows at
+        or past ``pos``, and the one behind which the next slot's first
+        fetch hides."""
+        buf = (base + c) % 2
+        if last:
+            hand_on(1 - buf)
+            filled = n_pages - c * chunk
+            for bit in range(chunk.bit_length()):
+                pl.when((filled >> bit) & 1 == 1)(
+                    functools.partial(wait, buf, 1 << bit))
+        else:
+            fetch(slot, c + 1, 1 - buf)
+            wait(buf, chunk)
         left = pos - c * span                   # positions below pos here
-        valid = at_lane < left
-        s = jnp.where(valid, _dot_f32(q_blk, kbuf[buf], _NT) / norm, neg)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)      # (rows, span)
-        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-        v = vbuf[buf]
-        v = jnp.where(at_row < left, v, jnp.zeros_like(v))
-        return m_new, l, alpha * acc + _dot_f32(p, v, _NN)
 
-    m, l, acc = lax.fori_loop(
-        0, n_chunks, step,
+        def attend(carry, live):
+            """Over the buffer's first ``live`` (static) positions."""
+            m, l, acc = carry
+            valid = lax.broadcasted_iota(jnp.int32, (rows, live), 1) < left
+            k = kbuf[buf, pl.ds(0, live)]
+            s = jnp.where(valid, _dot_f32(q_op, k, _NT) / norm, neg)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)      # (rows, live)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            v = vbuf[buf, pl.ds(0, live)]
+            if last:
+                dead = lax.broadcasted_iota(jnp.int32, (live, 1), 0) >= left
+                v = jnp.where(dead, jnp.zeros_like(v), v)
+            return m_new, l, alpha * acc + _dot_f32(_operand(p, v.dtype), v,
+                                                    _NN)
+
+        if not last or span <= _LIVE_POSITIONS or span % _LIVE_POSITIONS:
+            return attend(carry, span)
+        # the blocks past the last live row hold nothing that counts:
+        # dropping them changes no bit of the sums
+        return lax.switch(
+            (left - 1) // _LIVE_POSITIONS,
+            [functools.partial(attend, live=(i + 1) * _LIVE_POSITIONS)
+             for i in range(span // _LIVE_POSITIONS)], carry)
+
+    carry = lax.fori_loop(
+        0, n_chunks - 1, functools.partial(step, last=False),
         (jnp.full((rows, 1), neg, jnp.float32),
          jnp.zeros((rows, 1), jnp.float32),
          jnp.zeros((rows, width), jnp.float32)))
+    m, l, acc = lax.cond(
+        n_chunks > 0,
+        lambda carry: step(n_chunks - 1, carry, last=True),
+        lambda carry: carry, carry)
     # the new token's own row, at position pos
     s_new = jnp.sum(q_blk * kn_ref[0].astype(jnp.float32), axis=1,
                     keepdims=True) / norm
@@ -192,8 +276,8 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
                          f"hold [L, pages, page_size, {width}] rows of "
                          f"{kv_heads} heads")
     page_size = k_pool.shape[2]
-    chunk = max(1, min(_CHUNK_POSITIONS // page_size,
-                       block_tables.shape[1]))
+    page_bytes = page_size * width * k_pool.dtype.itemsize
+    chunk = max(1, min(_BUFFER_BYTES // page_bytes, block_tables.shape[1]))
     row = pl.BlockSpec((1, 1, width), lambda i, *_: (i, 0, 0),
                        memory_space=pltpu.VMEM)
     q_row = row
@@ -211,8 +295,12 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
                 num_scalar_prefetch=3, grid=(b,),
                 in_specs=[q_row, row, row, pool, pool],
                 out_specs=q_row,
-                scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
+                scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                                pltpu.SMEM((1,), jnp.int32)]),
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            # the walk hands buffers from slot i to slot i + 1: in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=pallas_interpret(interpret),
             name="apex_paged_decode_fwd",
         )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),

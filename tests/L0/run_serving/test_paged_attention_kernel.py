@@ -21,6 +21,11 @@ from apex_tpu.models.gpt import (
 from apex_tpu.serving.cache import NULL_PAGE, RESERVED_PAGES, init_paged_cache
 from apex_tpu.serving.decode import make_paged_decode_fn, make_paged_verify_fn
 
+from apex_tpu.transformer.functional import paged_attention
+from apex_tpu.transformer.functional.paged_attention import (
+    paged_decode_attention,
+)
+
 LAYERS, LAYER, MAX_PAGES = 3, 1, 5
 # float32 rounding of a 20-term softmax in another summation order
 TOL = dict(rtol=2e-5, atol=2e-6)
@@ -115,24 +120,168 @@ def test_kernel_matches_the_gather_path(page_size, case, dtype, heads, rope):
     np.testing.assert_array_equal(v_got, v_want)
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_output_is_bit_identical_across_page_placements(dtype):
-    outs = [np.asarray(_kernel(*_problem(4, jnp.dtype(dtype), 8, True,
-                                         order=order))[0])
-            for order in (1, 2, 3)]
-    np.testing.assert_array_equal(outs[0], outs[1])
-    np.testing.assert_array_equal(outs[0], outs[2])
+# -- the walk across slots ------------------------------------------------------
+#
+# The kernel fetches slot i + 1's first pages while it finishes slot i, so
+# what one slot's program leaves in the buffers and on the semaphores is the
+# next one's start. These layouts put that hand-over at its edges, with a
+# chunk cut to TWO pages so that a table of seven holds up to four chunks.
+
+WALK_PAGES = 7
+
+
+def _walk_layouts(p):
+    """``pos`` per slot, by what the hand-over meets (``p``: page size; a
+    chunk is ``2 * p`` positions)."""
+    return {
+        "one_slot": [3 * p + 1],
+        "pos0_first": [0, 3 * p + 1, p],
+        "pos0_last": [3 * p + 1, p, 0],
+        "pos0_between": [3 * p + 1, 0, 0, p],
+        # chunk counts 3, 2, 0, 2, 1, 4, 1: odd / even / zero neighbours
+        "buffer_parity": [5 * p, 4 * p, 0, 2 * p + 1, p, 6 * p + 2, 2],
+        "last_chunk_full": [4 * p, 2 * p, 6 * p],
+        "one_live_row_in_last_chunk": [2 * p + 1, 4 * p + 1, 1],
+    }
+
+
+def _walk_problem(pos, page_size, heads, kv_heads, hd, dtype, max_pages,
+                  seed=0, order=0, keep=None, nan_slots=()):
+    """Operands for the kernel called directly. ``order`` reseeds the
+    physical placement of every slot but ``keep``; ``nan_slots`` get NaN in
+    every row of their mapped pages, both pools."""
+    pos = np.asarray(pos, np.int32)
+    slots, width = len(pos), kv_heads * hd
+    num_pages = RESERVED_PAGES + slots * max_pages + 3
+    rng = np.random.RandomState(seed)
+    logical = rng.standard_normal(
+        (2, LAYERS, slots, max_pages, page_size, width)).astype(np.float32)
+    q = rng.standard_normal((slots, 1, heads * hd)).astype(np.float32)
+    new = rng.standard_normal((2, slots, 1, width)).astype(np.float32)
+    physical = np.arange(RESERVED_PAGES, num_pages)
+    np.random.RandomState(100).shuffle(physical)
+    if order:
+        kept = set() if keep is None else set(
+            physical[keep * max_pages:(keep + 1) * max_pages])
+        rest = np.asarray([x for x in physical if x not in kept])
+        np.random.RandomState(100 + order).shuffle(rest)
+        rest = iter(rest)
+        physical = np.asarray([x if x in kept else next(rest)
+                               for x in physical])
+    table = np.full((slots, max_pages), NULL_PAGE, np.int32)
+    pools = np.zeros((2, LAYERS, num_pages, page_size, width), np.float32)
+    for s in range(slots):
+        for j in range(pos[s] // page_size + 1):
+            page = table[s, j] = physical[s * max_pages + j]
+            pools[:, :, page] = np.nan if s in nan_slots else \
+                logical[:, :, s, j]
+    k_pool, v_pool = (jnp.asarray(t).astype(dtype) for t in pools)
+    k_new, v_new = (jnp.asarray(t).astype(dtype) for t in new)
+    return (jnp.asarray(q), k_new, v_new, k_pool, v_pool,
+            jnp.asarray(table), jnp.asarray(pos), jnp.int32(LAYER))
+
+
+def _einsum_reference(q, k_new, v_new, k_pool, v_pool, table, pos, layer,
+                      heads, kv_heads):
+    """The slot's pages gathered, the new row set at ``pos``, K and V
+    repeated to the query heads, a masked float32 softmax."""
+    table, pos = np.asarray(table), np.asarray(pos)
+    page_size, width = k_pool.shape[2:]
+    hd = width // kv_heads
+    span = table.shape[1] * page_size
+    out = np.zeros((len(pos), heads, hd), np.float32)
+    for s in range(len(pos)):
+        def rows(pool, new):
+            t = np.asarray(pool[layer].astype(jnp.float32))[
+                np.maximum(table[s], 0)].reshape(span, kv_heads, hd).copy()
+            t[pos[s]] = np.asarray(new[s, 0].astype(jnp.float32)).reshape(
+                kv_heads, hd)
+            return np.repeat(t, heads // kv_heads, axis=1)   # (span, heads, hd)
+        k, v = rows(k_pool, k_new), rows(v_pool, v_new)
+        qs = np.asarray(q[s, 0]).reshape(heads, hd)
+        scores = np.einsum("hd,shd->hs", qs, k[:pos[s] + 1]) / np.sqrt(hd)
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        out[s] = np.einsum("hs,shd->hd", p / p.sum(-1, keepdims=True),
+                           v[:pos[s] + 1])
+    return out
+
+
+def _walk(monkeypatch, pos, dtype, page_size=4, heads=4, kv_heads=2, **kw):
+    """The kernel's output over ``pos`` with chunks of two pages, a slot's
+    last chunk attended over the one or two pages that hold a live row."""
+    problem = _walk_problem(pos, page_size, heads, kv_heads, 16,
+                            jnp.dtype(dtype), WALK_PAGES, **kw)
+    monkeypatch.setattr(paged_attention, "_BUFFER_BYTES",
+                        2 * problem[3][0, 0].nbytes)
+    monkeypatch.setattr(paged_attention, "_LIVE_POSITIONS", page_size)
+    return np.asarray(paged_decode_attention(
+        *problem, heads=heads, kv_heads=kv_heads)), problem
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_nan_in_unmapped_pages_and_dead_rows_cannot_reach_the_output(dtype):
+@pytest.mark.parametrize("heads, kv_heads", [(4, 4), (8, 2)],
+                         ids=["1_per_kv_head", "4_per_kv_head"])
+@pytest.mark.parametrize("layout", list(_walk_layouts(4)))
+def test_walk_across_slots_matches_einsum(monkeypatch, layout, heads,
+                                          kv_heads, dtype):
+    got, problem = _walk(monkeypatch, _walk_layouts(4)[layout], dtype,
+                         heads=heads, kv_heads=kv_heads)
+    want = _einsum_reference(*problem, heads, kv_heads)
+    np.testing.assert_allclose(got.reshape(want.shape), want,
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("moved", ["every_slot", "the_other_slots"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_output_is_bit_identical_across_page_placements(monkeypatch, dtype,
+                                                        moved):
+    if moved == "every_slot":
+        outs = [np.asarray(_kernel(*_problem(4, jnp.dtype(dtype), 8, True,
+                                             order=order))[0])
+                for order in (1, 2, 3)]
+        np.testing.assert_array_equal(outs[0], outs[1])
+        np.testing.assert_array_equal(outs[0], outs[2])
+        return
+    # slot ``keep`` stays where it is and every other slot's pages move:
+    # what its neighbours left in the buffers is not its business
+    pos = _walk_layouts(4)["buffer_parity"]
+    base, _ = _walk(monkeypatch, pos, dtype)
+    for keep in (1, 3, 5):
+        for order in (1, 2):
+            out, _ = _walk(monkeypatch, pos, dtype, order=order, keep=keep)
+            np.testing.assert_array_equal(out[keep], base[keep])
+            np.testing.assert_array_equal(out, base)
+
+
+@pytest.mark.parametrize("poison", ["unmapped_pages_and_dead_rows",
+                                    "the_next_slot", "the_slot_before"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_nan_in_unmapped_pages_and_dead_rows_cannot_reach_the_output(
+        monkeypatch, dtype, poison):
     """Stronger than the gather path's ``0 * v``: NULL, unmapped pages and
     the rows at or past ``pos`` hold NaN and every slot's context is finite
-    and bit for bit what a pool of zeros there gives."""
-    clean = _kernel(*_problem(4, jnp.dtype(dtype), 8, True, fill=0.0))[0]
-    dirty = _kernel(*_problem(4, jnp.dtype(dtype), 8, True, fill=np.nan))[0]
-    assert np.isfinite(np.asarray(dirty)).all()
-    np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+    and bit for bit what a pool of zeros there gives. And a slot's pages are
+    its own: NaN in every page of slot ``i + 1``, which slot ``i``'s program
+    fetches into the other buffer while it finishes, leaves slot ``i``'s
+    context bit for bit; so does NaN in the slot before, whose rows the
+    buffers still hold."""
+    if poison == "unmapped_pages_and_dead_rows":
+        clean = _kernel(*_problem(4, jnp.dtype(dtype), 8, True, fill=0.0))[0]
+        dirty = _kernel(*_problem(4, jnp.dtype(dtype), 8, True,
+                                  fill=np.nan))[0]
+        assert np.isfinite(np.asarray(dirty)).all()
+        np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
+        return
+    pos = _walk_layouts(4)["buffer_parity"]
+    clean, _ = _walk(monkeypatch, pos, dtype)
+    assert np.isfinite(clean).all()
+    for bad in (1, 3, 4, 5):        # 2, 1, 2 and 4 chunks; slot 2 has none
+        dirty, _ = _walk(monkeypatch, pos, dtype, nan_slots=(bad,))
+        assert np.isnan(dirty[bad]).any()
+        spared = bad - 1 if poison == "the_next_slot" else bad + 1
+        np.testing.assert_array_equal(dirty[spared], clean[spared])
+        others = [s for s in range(len(pos)) if s != bad]
+        np.testing.assert_array_equal(dirty[others], clean[others])
 
 
 def test_decode_program_scans_the_kernel_and_never_carries_the_pool():
@@ -203,63 +352,30 @@ def test_decode_step_leaves_pool_and_tables_as_the_verify_step_does(dtype):
 # -- fewer K/V heads than query heads ------------------------------------------
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("heads, kv_heads", [(8, 2), (4, 1), (2, 2)],
-                         ids=["4_per_kv_head", "one_kv_head", "1_per_kv_head"])
+@pytest.mark.parametrize(
+    "heads, kv_heads, hd", [(8, 2, 16), (4, 1, 16), (2, 2, 16), (30, 30, 128)],
+    ids=["4_per_kv_head", "one_kv_head", "1_per_kv_head", "row_of_3840_lanes"])
 @pytest.mark.parametrize("page_size", [4, 16])
 def test_grouped_kv_heads_match_gather_and_einsum(page_size, heads, kv_heads,
-                                                  dtype):
+                                                  hd, dtype):
     """Query head ``h`` reads K/V head ``h // (heads / kv_heads)``; the pool
     row is ``kv_heads * head_dim``. Against the slot's pages gathered, K and V
-    repeated to the query heads and a masked float32 softmax."""
-    from apex_tpu.transformer.functional.paged_attention import (
-        paged_decode_attention,
-    )
-
-    hd = 16
-    pos = np.asarray(list(_positions(page_size).values()), np.int32)
-    slots, width = len(pos), kv_heads * hd
-    num_pages = RESERVED_PAGES + slots * MAX_PAGES + 3
-    rng = np.random.RandomState(heads + page_size)
-    pools = rng.standard_normal(
-        (2, LAYERS, num_pages, page_size, width)).astype(np.float32)
-    physical = np.arange(RESERVED_PAGES, num_pages)
-    rng.shuffle(physical)
-    table = np.full((slots, MAX_PAGES), NULL_PAGE, np.int32)
-    for s in range(slots):
-        for j in range(pos[s] // page_size + 1):
-            table[s, j] = physical[s * MAX_PAGES + j]
-    k_pool, v_pool = (jnp.asarray(p).astype(dtype) for p in pools)
-    q = jnp.asarray(rng.standard_normal((slots, 1, heads * hd)), jnp.float32)
-    k_new, v_new = (jnp.asarray(rng.standard_normal((slots, 1, width)),
-                                jnp.float32).astype(dtype) for _ in range(2))
-    got = paged_decode_attention(
-        q, k_new, v_new, k_pool, v_pool, jnp.asarray(table),
-        jnp.asarray(pos), jnp.int32(LAYER), heads=heads, kv_heads=kv_heads)
-    assert got.shape == (slots, 1, heads * hd) and got.dtype == jnp.float32
-
-    span = MAX_PAGES * page_size
-    for s in range(slots):
-        def rows(pool, new):
-            t = np.asarray(pool[LAYER].astype(jnp.float32))[
-                np.maximum(table[s], 0)].reshape(span, kv_heads, hd).copy()
-            t[pos[s]] = np.asarray(new[s, 0].astype(jnp.float32)).reshape(
-                kv_heads, hd)
-            return np.repeat(t, heads // kv_heads, axis=1)   # (span, heads, hd)
-        k, v = rows(k_pool, k_new), rows(v_pool, v_new)
-        qs = np.asarray(q[s, 0]).reshape(heads, hd)
-        scores = np.einsum("hd,shd->hs", qs, k) / np.sqrt(hd)
-        scores[:, pos[s] + 1:] = -np.inf
-        p = np.exp(scores - scores.max(-1, keepdims=True))
-        want = np.einsum("hs,shd->hd", p / p.sum(-1, keepdims=True), v)
-        np.testing.assert_allclose(np.asarray(got[s, 0]).reshape(heads, hd),
-                                   want, rtol=2e-5, atol=2e-5)
+    repeated to the query heads and a masked float32 softmax. The row of
+    3,840 lanes is the hybrid configuration's; in float32 with pages of 16 a
+    buffer holds four of them, so the table's five are two chunks."""
+    pos = list(_positions(page_size).values())
+    problem = _walk_problem(pos, page_size, heads, kv_heads, hd,
+                            jnp.dtype(dtype), MAX_PAGES,
+                            seed=heads + page_size)
+    got = paged_decode_attention(*problem, heads=heads, kv_heads=kv_heads)
+    assert got.shape == (len(pos), 1, heads * hd)
+    assert got.dtype == jnp.float32
+    want = _einsum_reference(*problem, heads, kv_heads)
+    np.testing.assert_allclose(np.asarray(got).reshape(want.shape), want,
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_heads_that_are_no_multiple_of_the_kv_heads_are_refused():
-    from apex_tpu.transformer.functional.paged_attention import (
-        paged_decode_attention,
-    )
-
     pool = jnp.zeros((1, 6, 4, 32))
     with pytest.raises(ValueError, match="query heads"):
         paged_decode_attention(
