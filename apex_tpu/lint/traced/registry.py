@@ -909,15 +909,20 @@ def _model_step_entry(family, which):
     values from the latent rows it writes and runs flash attention and the
     grouped expert product; decode attends over ONE pool of latent rows
     through ``apex_mla_decode_fwd`` and donates it (the pool, lengths, block
-    tables and three counters: 6 pairs). These entries are the kernel
-    families' registration."""
+    tables and three counters: 6 pairs). ``exaone_moe``
+    (``models.exaone_moe``): prefill runs flash attention, banded in the
+    sliding layers, and writes a slot's cycle of window pages beside its
+    pages of the full pool; decode attends over both pools through the paged
+    decode kernel, the sliding layers' call bounded below, and donates them
+    (the two pools' k/v, lengths, block tables and three counters: 9 pairs).
+    These entries are the kernel families' registration."""
     def build():
         import functools as ft
 
         import jax
 
         from apex_tpu.serving.cache import (
-            init_hybrid_cache, init_latent_cache,
+            init_hybrid_cache, init_latent_cache, init_window_cache,
         )
         from apex_tpu.serving.decode import (
             make_model_decode_fn, make_model_prefill_fn,
@@ -930,9 +935,12 @@ def _model_step_entry(family, which):
         elif family == "nemotron_h":
             from apex_tpu.models.nemotron_h import init, nemotron_h_tiny
             cfg = nemotron_h_tiny()
-        else:
+        elif family == "deepseek":
             from apex_tpu.models.deepseek import deepseek_tiny, init
             cfg, init_cache = deepseek_tiny(), init_latent_cache
+        else:
+            from apex_tpu.models.exaone_moe import exaone_moe_tiny, init
+            cfg, init_cache = exaone_moe_tiny(), init_window_cache
         params = jax.eval_shape(
             lambda k: init(k, cfg), jax.random.PRNGKey(0))
         cache = jax.eval_shape(ft.partial(init_cache, cfg, 2, 32, 6, 16))
@@ -1555,6 +1563,16 @@ def repo_entries() -> List[TraceEntry]:
                    _model_step_entry("deepseek", "decode"),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=6),
+        TraceEntry("exaone_prefill_step",
+                   "apex_tpu.models.exaone_moe",
+                   _model_step_entry("exaone_moe", "prefill"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=9),
+        TraceEntry("exaone_decode_step",
+                   "apex_tpu.transformer.functional.paged_attention",
+                   _model_step_entry("exaone_moe", "decode"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=9),
         TraceEntry("gpt_paged_decode_step_tp2", "apex_tpu.serving.decode",
                    _paged_decode_step_entry(tp=2),
                    checks=("precision", "memory", "schedule", "aliases"),

@@ -576,6 +576,34 @@ def _deepseek_kernel_cfg(which):
     return build
 
 
+def _exaone_kernel_cfg():
+    """The paged decode kernel's BOUNDED call at the ``exaone_moe`` family's
+    published widths: 64 query heads over 8 K/V heads of 128 a slot, 64
+    slots, over the four sliding layers' pool of 578 pages of 16 rows x 1024
+    through the (64, 9) view of the cyclic table, which stays where it is;
+    resident are two buffers of 9 pages each of K and of V (a slot's whole
+    window is one partly filled chunk), the stacked queries and the
+    context."""
+    def build():
+        from apex_tpu.transformer.functional.paged_attention import (
+            paged_decode_attention,
+        )
+
+        def bounded(q, k, v, k_pool, v_pool, table, pos, layer, start):
+            return paged_decode_attention(q, k, v, k_pool, v_pool, table,
+                                          pos, layer, heads=64, kv_heads=8,
+                                          start=start)
+
+        row = _sds((64, 1, 1024), "bfloat16")
+        pool = _sds((4, 578, 16, 1024), "bfloat16")
+        return bounded, (
+            _sds((64, 1, 8192), "float32"), row, row, pool, pool,
+            _sds((64, 9), "int32"), _sds((64,), "int32"), _sds((), "int32"),
+            _sds((64,), "int32"))
+
+    return build
+
+
 def _draft_forward_cfg():
     """The model drafter's per-token forward (``draft_gpt_tiny`` over
     its dense lockstep cache): XLA math today, so — like the paged
@@ -657,6 +685,9 @@ def repo_configs() -> List[Config]:
         cfgs.append(Config(f"moe_gmm_{which}_671b",
                            "apex_tpu.transformer.functional.moe",
                            _deepseek_kernel_cfg(which)))
+    cfgs.append(Config("paged_window_decode_236b",
+                       "apex_tpu.transformer.functional.paged_attention",
+                       _exaone_kernel_cfg()))
     cfgs.append(Config("gpt_spec_verify_step", "apex_tpu.serving.decode",
                        _paged_serving_cfg("verify")))
     cfgs.append(Config("gpt_tree_verify_step", "apex_tpu.serving.decode",

@@ -108,6 +108,7 @@ class DeepseekConfig:
     #: the pool is one pool of rows that are key and value at once
     recurrent = False
     latent = True
+    pools = "latent"
 
     def __post_init__(self):
         if not 0 <= self.first_k_dense <= self.num_layers:
@@ -415,10 +416,15 @@ def attention_decode(lp, x, cfg, pool, layer, block_tables, pos, active):
 # the MLPs: dense, and the experts held here
 # ---------------------------------------------------------------------------
 
+def swiglu_mlp(lp, u):
+    """``W_down(silu(W_gate u) * W_up u)`` of rows ``u`` as the layer takes
+    them (normed, where the family norms in front)."""
+    return _dense(lp["down"], _swiglu(_dense(lp["gate_up"], u)))
+
+
 @region("mlp")
 def dense_mlp(lp, x, cfg):
-    u = _rms(lp["mlp_norm"], x, cfg.rms_norm_eps)
-    return x + _dense(lp["down"], _swiglu(_dense(lp["gate_up"], u)))
+    return x + swiglu_mlp(lp, _rms(lp["mlp_norm"], x, cfg.rms_norm_eps))
 
 
 def _held(moe_params):
@@ -458,15 +464,15 @@ def _experts(lp, u, cfg, real, held=None, first_group=None):
         return moe.combine(out, d, u.shape[0]), d.sizes, chosen
 
 
-def expert_mlp(lp, x, cfg, real, held=None, first_group=None):
-    """One expert layer's MLP over ``x`` (rows, hidden), a prompt's positions
-    or one token per slot alike; ``real`` (rows,) bool marks the rows that
-    are tokens. A prompt longer than ``_MOE_ROWS`` goes through the routed
-    experts that many rows at a time. Returns ``(x', sizes (experts_held,),
-    chosen (rows, k))``."""
-    rows = x.shape[0]
-    with region("router"):
-        u = _rms(lp["mlp_norm"], x, cfg.rms_norm_eps)
+def expert_parts(lp, u, cfg, real, held=None, first_group=None):
+    """One expert layer over rows ``u`` (rows, hidden) as the layer takes
+    them (normed, where the family norms in front), a prompt's positions or
+    one token per slot alike; ``real`` (rows,) bool marks the rows that are
+    tokens. A prompt longer than ``_MOE_ROWS`` goes through the routed
+    experts that many rows at a time. Returns ``(routed, shared, sizes
+    (experts_held,), chosen (rows, k))``: the held experts' part and the
+    shared expert's, for the caller to add."""
+    rows = u.shape[0]
     if rows > _MOE_ROWS and rows % _MOE_ROWS == 0:
         routed, sizes, chosen = lax.map(
             lambda block: _experts(lp, block[0], cfg, block[1], held,
@@ -481,6 +487,16 @@ def expert_mlp(lp, x, cfg, real, held=None, first_group=None):
     with region("mlp"):     # the shared expert
         shared = _dense(lp["shared_down"],
                         _swiglu(_dense(lp["shared_gate_up"], u)))
+    return routed, shared, sizes, chosen
+
+
+def expert_mlp(lp, x, cfg, real, held=None, first_group=None):
+    """``x + experts(RMSNorm(x))``: :func:`expert_parts` behind this family's
+    norm. Returns ``(x', sizes (experts_held,), chosen (rows, k))``."""
+    with region("router"):
+        u = _rms(lp["mlp_norm"], x, cfg.rms_norm_eps)
+    routed, shared, sizes, chosen = expert_parts(lp, u, cfg, real, held,
+                                                 first_group)
     with region("experts"):
         return x + routed + shared, sizes, chosen
 

@@ -90,9 +90,10 @@ this package is that path for ``apex_tpu.models.gpt``, TPU-first:
 """
 
 from apex_tpu.serving.cache import (  # noqa: F401
-    HybridKVCache, KVCache, LatentKVCache, PagedKVCache, audit_block_tables,
-    cache_partition_specs, init_cache, init_hybrid_cache, init_latent_cache,
-    init_paged_cache, paged_cache_partition_specs,
+    HybridKVCache, KVCache, LatentKVCache, PagedKVCache, WindowKVCache,
+    audit_block_tables, cache_partition_specs, init_cache, init_hybrid_cache,
+    init_latent_cache, init_paged_cache, init_window_cache,
+    paged_cache_partition_specs,
 )
 from apex_tpu.serving.decode import (  # noqa: F401
     make_chunk_prefill_fn, make_copy_page_fn, make_decode_fn,

@@ -177,6 +177,68 @@ class LatentKVCache(NamedTuple):
     v_scale = None
 
 
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages of a slot's cyclic table: the ``window`` positions ``pos -
+    window + 1 .. pos`` touch at most ``cdiv(window + page_size - 1,
+    page_size)`` logical pages (9 at a window of 128 and pages of 16), so
+    with that many physical pages no two of them share one."""
+    return -(-(window + page_size - 1) // page_size)
+
+
+class WindowKVCache(NamedTuple):
+    """The cache of a model some of whose attention layers see only the last
+    ``cfg.window`` positions (``apex_tpu.models.exaone_moe``): TWO pools in
+    one donated tuple. ``k`` / ``v`` / ``lengths`` / ``block_tables`` are
+    :class:`PagedKVCache`'s, the pool's ``L`` counting the FULL-attention
+    layers only, and the host side (``PagePool``, block tables, prefix
+    sharing, copy-on-write, preemption) treats them alike. ``wk`` / ``wv``
+    are the window layers' pool, ``[L_win, RESERVED_PAGES + slots * R,
+    page_size, width]`` with ``R`` = :func:`ring_pages`, whose table is
+    STATIC and a cycle: logical page ``j`` of slot ``i`` is physical page
+    ``RESERVED_PAGES + i * R + j % R`` (:func:`ring_page`). No array holds
+    that table: both programs compute what they need of it from ``lengths``
+    and the slot's number, so the host never writes or uploads it, a ring
+    page is private by construction (never shared, copied or freed), and a
+    window layer's bytes a slot are ``R * page_size`` rows at every context
+    length. ``counters`` as in :class:`HybridKVCache`."""
+    k: jax.Array             # (L_full, num_pages, page_size, width)
+    v: jax.Array
+    lengths: jax.Array       # (num_slots,) int32
+    block_tables: jax.Array  # (num_slots, max_pages) int32
+    wk: jax.Array            # (L_win, 2 + slots * R, page_size, width)
+    wv: jax.Array
+    counters: Optional[dict] = None
+
+    # no quantized pool beside the window pool (the engine refuses it)
+    k_scale = None
+    v_scale = None
+
+    @property
+    def ring(self) -> int:
+        return (self.wk.shape[1] - RESERVED_PAGES) // self.lengths.shape[0]
+
+    def window_view(self, window: int):
+        """What a window layer's decode attention walks, for every slot at
+        its length ``pos``: ``(table (slots, R), pos', start')``. The table names the slot's live pages in logical order
+        from the page that holds the window's first position ``max(pos -
+        window + 1, 0)``; ``pos'`` and ``start'`` count from that page's
+        first row, so ``start'`` lies in the first page and ``pos'`` within
+        the ``R`` pages."""
+        pos = self.lengths
+        page_size, r = self.wk.shape[2], self.ring
+        start = jnp.maximum(pos - (window - 1), 0)
+        first = start // page_size
+        slot = jnp.arange(pos.shape[0], dtype=jnp.int32)
+        table = ring_page(slot[:, None], first[:, None] + jnp.arange(r), r)
+        return table, pos - first * page_size, start - first * page_size
+
+
+def ring_page(slot, logical, ring: int):
+    """The physical page of logical page ``logical`` of ``slot`` in the
+    window pool: the static cyclic table."""
+    return RESERVED_PAGES + slot * ring + logical % ring
+
+
 def max_pages_per_slot(max_len: int, page_size: int) -> int:
     return -(-max_len // page_size)
 
@@ -274,6 +336,37 @@ def init_latent_cache(cfg, num_slots: int, max_len: int, num_pages: int,
         lengths=jnp.zeros((num_slots,), jnp.int32),
         block_tables=_parked_tables(num_slots, max_len, page_size),
         counters=_zero_counters(cfg))
+
+
+def init_window_cache(cfg, num_slots: int, max_len: int, num_pages: int,
+                      page_size: int, dtype=jnp.bfloat16) -> WindowKVCache:
+    """The two pools of a model with window layers (what ``cfg`` states:
+    ``serving.decode``, "the seam"): the full layers' pool as
+    :func:`init_latent_cache` makes one, with ``v``, and the window layers'
+    ``RESERVED_PAGES + num_slots * ring_pages(cfg.window, page_size)`` pages,
+    zeroed; zeroed counters where the model keeps any."""
+    _check_pool_sizes(num_slots, max_len, num_pages, page_size)
+    if jnp.dtype(dtype) == jnp.int8:
+        raise ValueError("no int8 pool beside a window pool")
+    full = (cfg.kv_layers, num_pages, page_size, cfg.kv_row_width)
+    ring = (cfg.window_layers, RESERVED_PAGES
+            + num_slots * ring_pages(cfg.window, page_size), page_size,
+            cfg.kv_row_width)
+    return WindowKVCache(
+        k=jnp.zeros(full, dtype), v=jnp.zeros(full, dtype),
+        lengths=jnp.zeros((num_slots,), jnp.int32),
+        block_tables=_parked_tables(num_slots, max_len, page_size),
+        wk=jnp.zeros(ring, dtype), wv=jnp.zeros(ring, dtype),
+        counters=_zero_counters(cfg))
+
+
+#: What the cores of a model that keeps no per-slot state read (``cfg.pools``:
+#: ``serving.decode``, "the seam"): the cache that holds it, and the words a
+#: refusal names it by. A further family of pools is one row here.
+MODEL_POOLS = {
+    "latent": (init_latent_cache, "a latent pool"),
+    "window": (init_window_cache, "a full pool and a window pool"),
+}
 
 
 def audit_block_tables(block_tables, slot_pages) -> bool:

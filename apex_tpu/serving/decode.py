@@ -85,7 +85,7 @@ from apex_tpu.models.gpt import (
 )
 from apex_tpu.serving.cache import (
     KVCache, PagedKVCache, cache_partition_specs,
-    paged_cache_partition_specs,
+    paged_cache_partition_specs, ring_page,
 )
 from apex_tpu.utils.profiler import region
 
@@ -414,19 +414,41 @@ def _write_new_rows(cache, k_rows, v_rows):
     pool's ``(k, v)``; a cache with one pool (``cache.v`` is ``None``: a
     latent pool) takes ``v_rows`` ``None`` and gives ``v`` ``None``."""
     pos, bt = cache.lengths, cache.block_tables
-    layers, num_pages, page_size, width = cache.k.shape
-
-    def write(pool, rows):
-        flat = pool.reshape(-1, width).at[at].set(rows.reshape(-1, width))
-        return flat.reshape(pool.shape)
-
     with region("cache_write"):
-        logical = jnp.clip(pos // page_size, 0, bt.shape[1] - 1)
+        logical = jnp.clip(pos // cache.k.shape[2], 0, bt.shape[1] - 1)
         pages = jnp.take_along_axis(bt, logical[:, None], 1)[:, 0]
-        at = ((jnp.arange(layers)[:, None] * num_pages + pages[None, :])
-              * page_size + pos[None, :] % page_size).reshape(-1)
-        return write(cache.k, k_rows), \
-            None if cache.v is None else write(cache.v, v_rows)
+        at = _row_numbers(cache.k, pages, pos)
+        return _put_rows(cache.k, k_rows, at), \
+            None if cache.v is None else _put_rows(cache.v, v_rows, at)
+
+
+def _row_numbers(pool, pages, pos):
+    """Where row ``pos`` of every slot's page ``pages`` (slots,) stands in
+    every layer of ``pool`` seen as a list of rows: (layers * slots,)."""
+    layers, num_pages, page_size, _ = pool.shape
+    return ((jnp.arange(layers)[:, None] * num_pages + pages[None, :])
+            * page_size + pos[None, :] % page_size).reshape(-1)
+
+
+def _put_rows(pool, rows, at):
+    width = pool.shape[-1]
+    flat = pool.reshape(-1, width).at[at].set(rows.reshape(-1, width))
+    return flat.reshape(pool.shape)
+
+
+def _write_window_rows(cache, k_rows, v_rows):
+    """:func:`_write_new_rows` for the window layers' pool
+    (``serving.cache.WindowKVCache``): every slot's new row goes to its own
+    place in its cycle, ``ring_page(slot, pos // page_size)``, whether the
+    slot is active or not (the row that stood there is ``ring * page_size``
+    positions old, outside any window, and a slot's ring is its own)."""
+    pos = cache.lengths
+    with region("cache_write"):
+        pages = ring_page(jnp.arange(pos.shape[0], dtype=jnp.int32),
+                          pos // cache.wk.shape[2], cache.ring)
+        at = _row_numbers(cache.wk, pages, pos)
+        return _put_rows(cache.wk, k_rows, at), \
+            _put_rows(cache.wv, v_rows, at)
 
 
 def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
@@ -848,8 +870,22 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
 #                   shared, copied, preempted and shipped as any page; what
 #                   needs a program the model does not bring is refused
 #                   (``scheduler._refuse_without_a_core``).
-# ``models.hybrid`` and ``models.nemotron_h`` (recurrent) and
-# ``models.deepseek`` (latent) stand on it.
+#   ``pools``       (a model with no state) a key of
+#                   ``serving.cache.MODEL_POOLS``: which cache holds what its
+#                   cores read, and the words a refusal names it by.
+#   ``window``      (positions; 0 or absent: none) some of its attention
+#                   layers see the last ``window`` positions only. The pool
+#                   then counts the FULL layers (``kv_layers``), and
+#                   ``window_layers`` more keep ``ring_pages`` pages a slot in
+#                   a second pool whose table is a static cycle
+#                   (``serving.cache.WindowKVCache``). Both cores give the
+#                   window layers' rows ``wk``, ``wv`` ((window_layers, s or
+#                   slots, width)) after ``v``. A prefill writes the pages
+#                   that hold the prompt's last ``ring_pages`` logical pages
+#                   into the slot's cycle; the host never sees that pool.
+# ``models.hybrid`` and ``models.nemotron_h`` (recurrent),
+# ``models.deepseek`` (latent) and ``models.exaone_moe`` (window) stand on
+# it.
 
 def model_cores(cfg) -> bool:
     """Does ``cfg`` bring its own prefill and decode cores (the seam)?"""
@@ -874,8 +910,8 @@ def _model_prefill_core(params, cfg, cache, ids, mask, slot, write_pages,
     if write_pages.shape != (s // page_size,):
         raise ValueError(f"write_pages {write_pages.shape} != one page "
                          f"per bucket page ({s // page_size},)")
-    x, states, tails, k, v = cfg.prefill_core(params, ids[0], mask,
-                                              cache.k.dtype)
+    x, states, tails, k, v, *windowed = cfg.prefill_core(
+        params, ids[0], mask, cache.k.dtype)
     length = jnp.sum(mask).astype(jnp.int32)
     logits = cfg.logits_of(
         params, lax.dynamic_slice_in_dim(x, length - 1, 1, 0))
@@ -898,6 +934,18 @@ def _model_prefill_core(params, cfg, cache, ids, mask, slot, write_pages,
                 cache.state, states[:, None], (0, slot, 0, 0, 0))
             new["conv"] = lax.dynamic_update_slice(
                 cache.conv, tails[:, None], (0, slot, 0, 0))
+        if windowed:
+            # the prompt's last ``ring`` logical pages (fewer in a bucket
+            # that has fewer), from the one that holds its last token back:
+            # earlier ones would alias them in the cycle. Rows the window has
+            # left behind, and the pad's zeros, ride along and are masked
+            ring = min(cache.ring, s // page_size)
+            first = jnp.maximum((length - 1) // page_size - (ring - 1), 0)
+            to = ring_page(slot, first + jnp.arange(ring), cache.ring)
+            for name, rows in zip(("wk", "wv"), windowed):
+                tail = lax.dynamic_slice_in_dim(
+                    pages(rows), first, ring, axis=1)
+                new[name] = getattr(cache, name).at[:, to].set(tail)
         # a prefill counts nothing; the donated leaves still need a write
         new["counters"] = jax.tree.map(_self_rewrite, cache.counters)
     return cache._replace(**new), logits
@@ -909,7 +957,7 @@ def _model_decode_core(params, cfg, cache, tokens, active):
     slice of the stacked state in place (``cfg.decode_core``), and the pool
     layers' new rows go into the pool in one scatter after it. Slots that
     are not ``active`` keep their recurrent state and their length."""
-    x, state, conv, counters, k_rows, v_rows = cfg.decode_core(
+    x, state, conv, counters, k_rows, v_rows, *windowed = cfg.decode_core(
         params, cache, tokens, active)
     logits = cfg.logits_of(params, x)
     k, v = _write_new_rows(cache, k_rows, v_rows)
@@ -917,6 +965,8 @@ def _model_decode_core(params, cfg, cache, tokens, active):
     new = {"k": k}
     if v is not None:
         new["v"] = v
+    if windowed:
+        new["wk"], new["wv"] = _write_window_rows(cache, *windowed)
     new["lengths"] = jnp.where(active, pos + 1, pos)
     new["block_tables"] = _self_rewrite(cache.block_tables)
     if state is not None:

@@ -96,7 +96,7 @@ from typing import Dict, List, Mapping, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.serving.cache import max_pages_per_slot
+from apex_tpu.serving.cache import MODEL_POOLS, max_pages_per_slot
 from apex_tpu.serving.faults import FaultInjector, InjectedFault
 from apex_tpu.serving.health import (HEALTH_STATES, PoolExhausted,
                                      ReplicaHealth, ReplicaUnavailable,
@@ -179,6 +179,14 @@ def _validate_replicas(prefill_engines, decode_engines) -> None:
                 "pages, and the slot's recurrent state and convolution "
                 "tails would have to travel with them; such a model "
                 "stays colocated")
+        if getattr(eng.cfg, "window", 0):
+            raise ValueError(
+                f"page transfer is not offered over "
+                f"{MODEL_POOLS[eng.cfg.pools][1]} "
+                f"({type(eng.cfg).__name__}): the handoff ships the "
+                "pages a block table names, and the slot's cycle of window "
+                "pages would have to travel with them; such a model stays "
+                "colocated")
         if getattr(eng.cache, "k_scale", None) is not None:
             raise ValueError(
                 "disaggregated serving is not offered over the int8 "

@@ -147,7 +147,7 @@ import numpy as np
 from apex_tpu.models.gpt import GPTConfig
 from apex_tpu.serving.cache import (
     NULL_PAGE, RESERVED_PAGES, SCRATCH_PAGE, audit_block_tables,
-    init_cache, init_hybrid_cache, init_latent_cache, init_paged_cache,
+    MODEL_POOLS, init_cache, init_hybrid_cache, init_paged_cache,
     max_pages_per_slot,
 )
 from apex_tpu.serving.decode import (
@@ -248,17 +248,21 @@ def _refuse(cfg, where: str, features) -> None:
 def _refuse_without_a_core(cfg, **features) -> None:
     """A model that brings its own cores (``serving.decode``, "the seam")
     and keeps no per-slot state brings exactly two, monolithic prefill and
-    plain decode, over a pool only they can read (``cfg.latent``: one row a
-    token that is key and value at once). On the HOST a latent page is a
-    page like any other: prefix sharing, copy-on-write, preemption by
-    requeue and page transfer are offered. What needs a third program over
-    that pool (a verify step, a chunk's write-then-attend, the quantized
-    pool's write and gather, dequant-fused projections) is refused by name
-    where it is asked for, at construction, and never falls back in
-    silence (ROADMAP, M3). ``features``: name -> (asked for, what it would
-    need)."""
+    plain decode, over pools only they can read, which the config names
+    (``cfg.pools``, a key of ``serving.cache.MODEL_POOLS``: ONE pool of rows
+    that are key and value at once, or the full layers' pool and the window
+    layers' cycle beside it). On the HOST a page of the pool the block table
+    walks is a page like any other: prefix sharing, copy-on-write and
+    preemption by requeue are offered (a window layer's cycle is rebuilt
+    from the whole prompt by every prefill and is never shared or copied).
+    What needs a third program over those pools (a verify step, a chunk's
+    write-then-attend, the quantized pool's write and gather, dequant-fused
+    projections) is refused by name, over THIS model's pools in the table's
+    words, where it is asked for, at construction, and never falls back in
+    silence (ROADMAP, M2 and M3). ``features``: name -> (asked for, what it
+    would need over its pools, whichever they are)."""
     if model_cores(cfg) and not getattr(cfg, "recurrent", False):
-        _refuse(cfg, "over a latent pool", features)
+        _refuse(cfg, "over " + MODEL_POOLS[cfg.pools][1], features)
 
 
 def _pad_on_host(tokens: Sequence[int], buckets: Sequence[int]):
@@ -794,13 +798,15 @@ class PagedDecodeEngine(DecodeEngine):
             draft_model=(draft_model is not None, "speculation is refused, "
                          "so a drafter has no use"),
             tree_spec=(tree_spec, "tree verify needs a core that scores "
-                       "k+1 absorbed queries a slot under an ancestor mask"),
+                       "k+1 queries a slot under an ancestor mask over its "
+                       "pools"),
             spec_k=(spec_k > 0, "verify needs a core that writes and "
-                    "attends k+1 latent rows a slot"),
+                    "attends k+1 rows a slot in each of its pools, and can "
+                    "roll a rejected draft back there"),
             **{"the int8 pool (cache_dtype=int8)": (
-                jnp.dtype(cache_dtype) == jnp.int8, "a latent row has no "
-                "heads to scale by, and its decode kernel reads the pool's "
-                "own dtype"),
+                jnp.dtype(cache_dtype) == jnp.int8, "its cores write and "
+                "read rows in the pool's own dtype and keep no per-page "
+                "scale"),
                "the host tier (host_tier=)": (
                    host_tier is not None, "a promoted prefix is attended by "
                    "the suffix as a chunk, which needs a chunked-prefill "
@@ -826,7 +832,7 @@ class PagedDecodeEngine(DecodeEngine):
         # block tables) is dtype-agnostic throughout
         quantized = is_quantized_tree(params)
         self.cache = (init_hybrid_cache if self.recurrent
-                      else init_latent_cache if self.model_cores
+                      else MODEL_POOLS[cfg.pools][0] if self.model_cores
                       else init_paged_cache)(cfg, num_slots, max_len,
                                              num_pages, page_size,
                                              cache_dtype)
@@ -874,11 +880,15 @@ class PagedDecodeEngine(DecodeEngine):
             # keeps state beside the pool (serving.decode, "the seam"), and
             # what needs more programs was refused above. Bytes a prefill
             # writes besides its pages (recurrent state), or in a page (a
-            # latent pool):
+            # latent pool), or into the slot's cycle (a window pool: its
+            # ring_pages pages of K and V in every window layer):
             self._state_bytes = cfg.state_bytes_per_slot() \
                 if self.recurrent else None
             self._page_bytes = cfg.kv_layers * page_size * cfg.kv_row_width \
                 * jnp.dtype(cache_dtype).itemsize
+            self._window_bytes = 2 * self.cache.ring * (
+                self.cache.wk.nbytes // self.cache.wk.shape[1]) \
+                if getattr(cfg, "window", 0) else None
             self._prefill = make_model_prefill_fn(cfg)
             self._decode = make_model_decode_fn(cfg)
             self._chunk_prefill = self._verify = self._tree_verify = None
@@ -1016,6 +1026,8 @@ class PagedDecodeEngine(DecodeEngine):
                   prompt_tokens=len(toks), shared_pages=covered,
                   page_size=self.page_size,
                   **({"state_bytes": self._state_bytes} if self.recurrent
+                     else {"window_bytes": self._window_bytes}
+                     if self.model_cores and self._window_bytes
                      else {"latent_bytes": len(private) * self._page_bytes}
                      if self.model_cores else {}))
         if skip:
@@ -1481,9 +1493,9 @@ class ContinuousBatchingScheduler:
         _refuse_without_a_core(
             getattr(engine, "cfg", None),
             **{"chunked prefill (chunk_tokens=)": (
-                chunk_tokens is not None, "a chunk attends the latent rows "
-                "of the chunks before it, which needs a core that expands "
-                "or absorbs over mapped pages at prompt length")})
+                chunk_tokens is not None, "a chunk attends the rows of the "
+                "chunks before it, which needs a core that reads them back "
+                "out of its pools at prompt length")})
         if chunk_tokens is not None:
             chunk_tokens = int(chunk_tokens)
             if chunk_tokens < 1:

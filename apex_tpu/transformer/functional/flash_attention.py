@@ -54,9 +54,28 @@ runs the other side of any of them, so the kernels are built one way.
 Dropout masks are position-hashed (``_hash_keep``) and therefore
 bit-identical between forward and backward, and independent of the
 tile size.
+
+A sliding window (``window``, forward only, with ``causal``): query ``i``
+attends key ``j`` iff ``0 <= i - j < window``. Here whole k tiles outside
+the band ARE left out, and not behind ``pl.when``: the grid's k extent is
+the number of tiles a q tile's band can touch (2 for a band of 128 under
+tiles of 512, whatever the sequence), and the index map names the band's
+tiles, so what is skipped is never fetched. That is a different trade from
+full causal's above: a band leaves out all but a constant number of tiles a
+q tile, where the diagonal leaves out half. A step whose tile would lie
+above the diagonal (the first q tiles, whose band is cut short by position
+0) does nothing.
+
+``precision`` (forward only, as ``window``): what the MXU does with the two
+products. ``None`` is what it does by itself, ONE bfloat16 pass whatever the
+operands' dtype; with float32 operands ``lax.Precision.HIGHEST`` takes its
+full-precision passes, and the probabilities, the accumulator and the output
+stay float32: a serving prompt path whose decode path is exact in q and p
+attends that way over the rows its cache keeps (``models.exaone_moe``).
 """
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -126,7 +145,7 @@ def _keep_mask(seed_ref, head, q0, k0, shape, rate):
                       rate)
 
 
-def _score_mask(s, qt, kt, mask_row, sk, causal):
+def _score_mask(s, qt, kt, mask_row, sk, causal, window=None):
     """Validity mask for a score tile; every component is optional so the
     callers only pay for the masking a tile actually needs (``sk=None``
     skips the padding check, ``mask_row=None`` the user mask)."""
@@ -141,13 +160,15 @@ def _score_mask(s, qt, kt, mask_row, sk, causal):
     if causal:
         qpos = qt * tq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         tri = kpos <= qpos
+        if window is not None:
+            tri &= qpos - kpos < window
         valid = tri if valid is None else valid & tri
     return valid
 
 
 # -- forward ----------------------------------------------------------------
 
-def _needs_mask(causal, pad, qt, kt, bq, bk, nk):
+def _needs_mask(causal, pad, qt, kt, bq, bk, nk, window=None):
     """Traced predicate: does tile (qt, kt) need any masking? Only tiles
     crossing the causal diagonal and (under k-padding) the last k tile do;
     interior tiles take a mask-free path with roughly half the VPU work —
@@ -157,6 +178,8 @@ def _needs_mask(causal, pad, qt, kt, bq, bk, nk):
     needs = None
     if causal:
         needs = (kt + 1) * bk - 1 > qt * bq
+    if window is not None:      # the tile's far corner lies below the band
+        needs |= (qt + 1) * bq - 1 - kt * bk >= window
     if pad:
         pad_t = kt == nk - 1
         needs = pad_t if needs is None else needs | pad_t
@@ -165,12 +188,18 @@ def _needs_mask(causal, pad, qt, kt, bq, bk, nk):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
                 o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, sk, causal, rate, has_mask, pad):
+                *, sk, causal, rate, has_mask, pad, window=None, nk=None,
+                precision=None):
     i, qt, kt = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    steps = pl.num_programs(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
+    step = kt
+    if window is None:
+        nk = steps
+    else:       # the grid walks the band: this step's k tile (_band_first)
+        kt = _band_first(qt, bq, bk, window) + step
 
-    @pl.when(kt == 0)
+    @pl.when(step == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG)
@@ -183,11 +212,12 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
             # scores are base-2 logits and every exp below is exp2
             q, k, v = q_ref[0], k_ref[0], v_ref[0]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    precision=precision,
                                     preferred_element_type=jnp.float32)
             if masked:
                 valid = _score_mask(
                     s, qt, kt, mask_ref[0, 0, :] if has_mask else None,
-                    sk if pad else None, causal)
+                    sk if pad else None, causal, window)
                 s = jnp.where(valid, s, _NEG)
             m_prev = m_ref[:, 0:1]
             m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -211,11 +241,20 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
                 p = jnp.where(keep, p * p.dtype.type(1.0 / (1.0 - rate)),
                               p.dtype.type(0.0))
             acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
+                p, v, (((1,), (0,)), ((), ())), precision=precision,
                 preferred_element_type=jnp.float32)
         return go
 
-    if has_mask:
+    if window is not None:
+        # a step past the q tile's last row names no tile of the band
+        in_band = kt * bk <= (qt + 1) * bq - 1
+        if has_mask:
+            pl.when(in_band)(tile(True))
+        else:
+            needs = _needs_mask(causal, pad, qt, kt, bq, bk, nk, window)
+            pl.when(in_band & needs)(tile(True))
+            pl.when(in_band & ~needs)(tile(False))
+    elif has_mask:
         tile(True)()
     else:
         needs = _needs_mask(causal, pad, qt, kt, bq, bk, nk)
@@ -224,7 +263,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref,
         else:
             jax.lax.cond(needs, tile(True), tile(False))
 
-    @pl.when(kt == nk - 1)
+    @pl.when(step == steps - 1)
     def _():
         l = l_ref[:, 0:1]
         safe = jnp.where(l > 0, l, 1.0)
@@ -419,7 +458,23 @@ def _prescale_q(q3, scale):
             * jnp.float32(scale * _LOG2E)).astype(q3.dtype)
 
 
-def _fwd_call(q, k, v, mask, *, causal, scale, rate, seed, interpret):
+def _band_first(qt, bq, bk, window):
+    """The first k tile that q tile ``qt``'s band touches: the tile of
+    position ``qt * bq - (window - 1)``, or tile 0."""
+    return jnp.maximum(qt * bq - (window - 1), 0) // bk
+
+
+def _band_tiles(bq, bk, window, nk):
+    """K tiles a q tile's band can touch, at most: its ``bq + window - 1``
+    positions begin at a multiple of ``gcd(bq, bk)`` less ``window - 1``."""
+    g = math.gcd(bq, bk)
+    worst = max((off - (window - 1)) % bk
+                for off in range(0, bk, g))     # the band's offset in a tile
+    return min(nk, (worst + bq + window - 2) // bk + 1)
+
+
+def _fwd_call(q, k, v, mask, *, causal, scale, rate, seed, interpret,
+              window=None, precision=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     q3, k3, v3, m3, sq_p, sk_p, d_p = _prep(q, k, v, mask, b, h)
@@ -427,17 +482,30 @@ def _fwd_call(q, k, v, mask, *, causal, scale, rate, seed, interpret):
     bq, bk = _block(sq_p), _block(sk_p)
     grid = (b * h, sq_p // bq, sk_p // bk)
     sd = jnp.asarray(seed, jnp.uint32).reshape(1, 2)
-    kv_spec = pl.BlockSpec((1, bk, d_p), lambda i, qt, kt: (i, kt, 0),
+    kernel = functools.partial(_fwd_kernel, sk=sk, causal=causal, rate=rate,
+                               has_mask=mask is not None, pad=sk != sk_p)
+    at = lambda qt, kt: kt
+    if precision is not None:
+        kernel = functools.partial(kernel, precision=precision)
+    if window is not None:
+        nk = grid[2]
+        grid = grid[:2] + (_band_tiles(bq, bk, window, nk),)
+        kernel = functools.partial(kernel, window=window, nk=nk)
+        # a step past the band's last tile (the q tile's own) does nothing:
+        # it names that tile again, which is no new fetch
+        at = lambda qt, kt: jnp.minimum(
+            _band_first(qt, bq, bk, window) + kt, ((qt + 1) * bq - 1) // bk)
+    kv_spec = pl.BlockSpec((1, bk, d_p),
+                           lambda i, qt, kt: (i, at(qt, kt), 0),
                            memory_space=pltpu.VMEM)
     mask_spec = pl.BlockSpec((1, 1, bk),
-                             lambda i, qt, kt: (i // h, 0, kt),
+                             lambda i, qt, kt: (i // h, 0, at(qt, kt)),
                              memory_space=pltpu.VMEM)
     lse_spec = pl.BlockSpec((1, 1, bq), lambda i, qt, kt: (i, 0, qt),
                             memory_space=pltpu.VMEM)
     with jax.named_scope("apex_flash_fwd"):
         o, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel, sk=sk, causal=causal, rate=rate,
-                              has_mask=mask is not None, pad=sk != sk_p),
+            kernel,
             grid=grid,
             in_specs=[_smem(), _qkv_spec(bq, d_p), kv_spec, kv_spec,
                       mask_spec],
@@ -566,7 +634,8 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 _UNFUSED_MAX_SEQ = 256
 
 
-def _unfused_attention(q, k, v, mask, seed, *, causal, scale, rate):
+def _unfused_attention(q, k, v, mask, seed, *, causal, scale, rate,
+                       window=None, precision=None):
     """Mathematically-identical XLA path for short sequences.
 
     Same masking convention (fully-masked rows return 0) and the SAME
@@ -576,7 +645,7 @@ def _unfused_attention(q, k, v, mask, seed, *, causal, scale, rate):
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision=precision,
                    preferred_element_type=jnp.float32) * scale
     if mask is None:
         valid = jnp.ones((1, 1, 1, sk), bool)
@@ -584,6 +653,8 @@ def _unfused_attention(q, k, v, mask, seed, *, causal, scale, rate):
         valid = (mask[:, None, None, :] != 0)
     if causal:
         tri = (jnp.arange(sk)[None, :] <= jnp.arange(sq)[:, None])
+        if window is not None:
+            tri &= jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :] < window
         valid = valid & tri[None, None]
     s = jnp.where(valid, s, _NEG)
     m = jnp.max(s, -1, keepdims=True)
@@ -598,6 +669,7 @@ def _unfused_attention(q, k, v, mask, seed, *, causal, scale, rate):
         keep = _hash_keep(qpos, kpos, bh, seed[0], seed[1], rate)
         p = jnp.where(keep, p / (1.0 - rate), 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                      precision=precision,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
@@ -608,7 +680,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     dropout_rate: float = 0.0,
                     dropout_rng: Optional[jax.Array] = None,
                     use_kernel: Optional[bool] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None,
+                    precision: Optional[jax.lax.Precision] = None
+                    ) -> jax.Array:
     """Fused scaled-dot-product attention.
 
     Args:
@@ -622,6 +697,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
       use_kernel: force the Pallas kernel (True) or the XLA path (False);
         None auto-dispatches on sequence length (kernel when the padded
         seq exceeds ``_UNFUSED_MAX_SEQ`` — the measured v5e crossover).
+      window: with ``causal``, query ``i`` attends key ``j`` iff ``0 <= i -
+        j < window`` (the token itself counts). Forward only: the kernel
+        visits the band's k tiles and no others; there is no backward.
+      precision: of the two products (the module's docstring). Forward
+        only.
 
     Returns (batch, heads, seq, head_dim) in q's dtype.
     """
@@ -634,6 +714,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         seed = jnp.zeros((2,), jnp.uint32)
     if use_kernel is None:
         use_kernel = max(q.shape[2], k.shape[2]) > _UNFUSED_MAX_SEQ
+    if window is not None and (not causal or window < 1
+                               or q.shape[2] != k.shape[2]):
+        raise ValueError(
+            f"a window ({window}) is a band below the diagonal of a "
+            "square causal attention")
+    if window is not None or precision is not None:     # forward only
+        window = None if window is None else int(window)
+        if not use_kernel:
+            return _unfused_attention(
+                q, k, v, mask, seed, causal=bool(causal),
+                scale=float(softmax_scale), rate=rate, window=window,
+                precision=precision)
+        return _fwd_call(q, k, v, mask, causal=bool(causal),
+                         scale=float(softmax_scale), rate=rate, seed=seed,
+                         interpret=interpret, window=window,
+                         precision=precision)[0]
     if not use_kernel:
         return _unfused_attention(q, k, v, mask, seed, causal=bool(causal),
                                   scale=float(softmax_scale), rate=rate)

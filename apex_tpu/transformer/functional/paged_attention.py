@@ -41,6 +41,16 @@ earlier chunk, of another slot), and there they are masked in the scores AND
 zeroed in the values, so what they hold (another slot's rows, NaN) cannot
 reach the output. The next slot's pages, on their way into the OTHER buffer
 meanwhile, are never read by this one.
+
+A lower bound (``start``, a sliding window's first position): rows below
+``start`` are masked in the scores and zeroed in the values exactly as rows
+at or past ``pos`` are, in every chunk. The caller hands a table whose FIRST
+page holds ``start`` (``serving.cache.WindowKVCache.window_view``: the live
+pages of a slot's cyclic table in logical order, ``pos`` and ``start``
+counted from the first of them), so no page is skipped and the walk is the
+one above. The bounded call runs under its own kernel name,
+``apex_paged_window_decode_fwd`` (one body, two names: the name says which
+kind of layer ran).
 """
 
 import functools
@@ -96,9 +106,12 @@ def _dot_f32(a, b, dims):
     return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
 
 
-def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
-                   k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, turn, *, heads,
-                   kv_heads, page_size):
+def _decode_kernel(bt_ref, pos_ref, layer_ref, *refs, heads, kv_heads,
+                   page_size, bounded=False):
+    # ``bounded``: one more scalar-prefetched row, the slots' lower bounds
+    start_ref = refs[0] if bounded else None
+    (q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
+     turn) = refs[int(bounded):]
     span, width = kbuf.shape[1:]
     hd = width // kv_heads
     per = heads // kv_heads             # query heads that share a K/V head
@@ -192,11 +205,16 @@ def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
             fetch(slot, c + 1, 1 - buf)
             wait(buf, chunk)
         left = pos - c * span                   # positions below pos here
+        if bounded:                             # and the first that counts
+            lower = start_ref[slot] - c * span
 
         def attend(carry, live):
             """Over the buffer's first ``live`` (static) positions."""
             m, l, acc = carry
-            valid = lax.broadcasted_iota(jnp.int32, (rows, live), 1) < left
+            at = lax.broadcasted_iota(jnp.int32, (rows, live), 1)
+            valid = at < left
+            if bounded:
+                valid &= at >= lower
             k = kbuf[buf, pl.ds(0, live)]
             s = jnp.where(valid, _dot_f32(q_op, k, _NT) / norm, neg)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -206,6 +224,9 @@ def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
             v = vbuf[buf, pl.ds(0, live)]
             if last:
                 dead = lax.broadcasted_iota(jnp.int32, (live, 1), 0) >= left
+                v = jnp.where(dead, jnp.zeros_like(v), v)
+            if bounded:
+                dead = lax.broadcasted_iota(jnp.int32, (live, 1), 0) < lower
                 v = jnp.where(dead, jnp.zeros_like(v), v)
             return m_new, l, alpha * acc + _dot_f32(_operand(p, v.dtype), v,
                                                     _NN)
@@ -244,7 +265,7 @@ def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
 
 
 def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
-                           pos, layer, *, heads, kv_heads=None,
+                           pos, layer, *, heads, kv_heads=None, start=None,
                            interpret=None):
     """Attention of one query row per slot over the slot's mapped pages.
 
@@ -259,7 +280,9 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
     scan). ``kv_heads`` (``heads`` when not given) divides ``heads``: query
     head ``h`` reads K/V head ``h // (heads / kv_heads)``. Scores, softmax
     and the context accumulate in float32 with the mask ``s <= pos``; returns
-    the context ``(b, 1, heads * hd)`` in ``q``'s dtype.
+    the context ``(b, 1, heads * hd)`` in ``q``'s dtype. ``start`` ``(b,)``
+    int32, where given: the mask is ``start <= s <= pos`` (``start`` lies in
+    the table's first page), and the call is ``apex_paged_window_decode_fwd``.
     """
     b, k1, q_width = q.shape
     kv_heads = heads if kv_heads is None else kv_heads
@@ -287,12 +310,17 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
                              memory_space=pltpu.VMEM)
     pool = pl.BlockSpec(memory_space=pl.ANY)
     buf = pltpu.VMEM((2, chunk * page_size, width), k_pool.dtype)
-    with jax.named_scope("apex_paged_decode_fwd"):
-        return pl.pallas_call(
-            functools.partial(_decode_kernel, heads=heads,
-                              kv_heads=kv_heads, page_size=page_size),
+    scalars = (block_tables.astype(jnp.int32), pos.astype(jnp.int32),
+               jnp.reshape(layer, (1,)).astype(jnp.int32))
+    operands = (q, k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
+                k_pool, v_pool)
+    kernel = functools.partial(_decode_kernel, heads=heads,
+                               kv_heads=kv_heads, page_size=page_size)
+
+    def call(scalar_rows):
+        return dict(
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3, grid=(b,),
+                num_scalar_prefetch=scalar_rows, grid=(b,),
                 in_specs=[q_row, row, row, pool, pool],
                 out_specs=q_row,
                 scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
@@ -301,9 +329,18 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
             # the walk hands buffers from slot i to slot i + 1: in order
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
-            interpret=pallas_interpret(interpret),
-            name="apex_paged_decode_fwd",
-        )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),
-          jnp.reshape(layer, (1,)).astype(jnp.int32), q,
-          k_new.astype(k_pool.dtype), v_new.astype(v_pool.dtype),
-          k_pool, v_pool).reshape(b, 1, q_width)
+            interpret=pallas_interpret(interpret))
+
+    # one body, two call sites: a kernel's name is a literal where it is
+    # called (tests/L0/run_utils/test_kernel_names.py)
+    if start is None:
+        with jax.named_scope("apex_paged_decode_fwd"):
+            out = pl.pallas_call(kernel, name="apex_paged_decode_fwd",
+                                 **call(3))(*scalars, *operands)
+    else:
+        with jax.named_scope("apex_paged_window_decode_fwd"):
+            out = pl.pallas_call(
+                functools.partial(kernel, bounded=True),
+                name="apex_paged_window_decode_fwd", **call(4))(
+                *scalars, start.astype(jnp.int32), *operands)
+    return out.reshape(b, 1, q_width)

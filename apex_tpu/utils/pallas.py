@@ -24,7 +24,8 @@ KERNEL_NAMES = (
     "apex_mla_decode_fwd", "apex_moe_gmm_fwd", "apex_mt_adagrad",
     "apex_mt_adam", "apex_mt_axpby", "apex_mt_l2norm", "apex_mt_lamb",
     "apex_mt_novograd", "apex_mt_scale", "apex_mt_sgd",
-    "apex_paged_decode_fwd", "apex_softmax_bwd",
+    "apex_paged_decode_fwd", "apex_paged_window_decode_fwd",
+    "apex_softmax_bwd",
     "apex_softmax_causal_fwd", "apex_softmax_masked_fwd",
     "apex_ssd_decode_fwd", "apex_w8_matmul", "apex_w8_matmul_bias",
     "apex_w8_matmul_nk", "apex_xentropy_bwd", "apex_xentropy_fwd")

@@ -387,3 +387,198 @@ def test_heads_that_are_no_multiple_of_the_kv_heads_are_refused():
             jnp.zeros((2, 1, 64)), jnp.zeros((2, 1, 16)), jnp.zeros((2, 1, 16)),
             pool, pool, jnp.zeros((2, 3), jnp.int32), jnp.zeros((2,), jnp.int32),
             jnp.int32(0), heads=4, kv_heads=1)
+
+
+# ---------------------------------------------------------------------------
+# the lower bound: a sliding layer's call over a slot's cycle of pages
+# ---------------------------------------------------------------------------
+
+W_HEADS, W_KV, W_HD = 4, 2, 16
+
+
+def _window_problem(page_size, window, pos, dtype, seed=0, poison=None):
+    """A window pool (``serving.cache.WindowKVCache``) holding, for every
+    slot, the rows of positions ``0 .. pos - 1`` of a random history laid
+    into the slot's cycle as decode would have written them (a later row
+    overwrites the one ``ring * page_size`` positions before it), and the
+    new token's rows. ``poison`` overwrites every row no slot may read: the
+    reserved pages and each slot's rows outside ``[start, pos)``."""
+    from apex_tpu.serving.cache import WindowKVCache, ring_page, ring_pages
+
+    pos = np.asarray(pos, np.int32)
+    slots, ring = len(pos), ring_pages(window, page_size)
+    width = W_KV * W_HD
+    rng = np.random.RandomState(seed)
+    history = rng.standard_normal(
+        (2, LAYERS, slots, int(pos.max()) + 1, width)).astype(np.float32)
+    pools = rng.standard_normal(
+        (2, LAYERS, RESERVED_PAGES + slots * ring, page_size,
+         width)).astype(np.float32)
+    if poison is not None:
+        pools[:] = poison
+    start = np.maximum(pos - (window - 1), 0)
+    for s in range(slots):
+        for p in range(0 if poison is None else start[s], pos[s]):
+            page = int(ring_page(s, p // page_size, ring))
+            pools[:, :, page, p % page_size] = history[:, :, s, p]
+    q = rng.standard_normal((slots, 1, W_HEADS * W_HD)).astype(np.float32)
+    new = rng.standard_normal((2, slots, 1, width)).astype(np.float32)
+    wk, wv = (jnp.asarray(p).astype(dtype) for p in pools)
+    cache = WindowKVCache(k=wk[:, :1], v=wv[:, :1], lengths=jnp.asarray(pos),
+                          block_tables=jnp.zeros((slots, 1), jnp.int32),
+                          wk=wk, wv=wv)
+    history = jnp.asarray(history).astype(dtype).astype(jnp.float32)
+    new = jnp.asarray(new).astype(dtype).astype(jnp.float32)
+    return cache, jnp.asarray(q), new, history, start
+
+
+def _band_reference(q, new, history, pos, start):
+    """Gather + einsum with the band mask, float32: each slot's query heads
+    over the history rows ``start .. pos - 1`` and the new row at ``pos``."""
+    out = []
+    per = W_HEADS // W_KV
+    for s in range(q.shape[0]):
+        k = jnp.concatenate([history[0, LAYER, s, start[s]:pos[s]],
+                             new[0, s]]).reshape(-1, W_KV, W_HD)
+        v = jnp.concatenate([history[1, LAYER, s, start[s]:pos[s]],
+                             new[1, s]]).reshape(-1, W_KV, W_HD)
+        qs = q[s, 0].reshape(W_KV, per, W_HD)
+        scores = jnp.einsum("gpd,sgd->gps", qs, k,
+                            precision="highest") / np.sqrt(W_HD)
+        out.append(jnp.einsum("gps,sgd->gpd", jax.nn.softmax(scores, -1), v,
+                              precision="highest").reshape(-1))
+    return jnp.stack(out)[:, None]
+
+
+def _bounded_call(cache, q, new, window):
+    table, pos, start = cache.window_view(window)
+    return paged_decode_attention(
+        q, new[0], new[1], cache.wk, cache.wv, table, pos, jnp.int32(LAYER),
+        heads=W_HEADS, kv_heads=W_KV, start=start)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("page_size, window, pos", [
+    (4, 8, (0, 3, 7, 8, 9, 100)),           # nothing cached .. far past it
+    (4, 8, (10, 11, 12, 13, 14, 15)),       # the window begins mid-page
+    (16, 128, (0, 127, 128, 129, 1000, 5000)),
+    (16, 24, (40, 41, 55, 56, 57, 2000)),   # a window of no whole pages
+], ids=["w8_ragged", "w8_mid_page", "w128_published", "w24_odd"])
+def test_bounded_call_matches_gather_and_einsum_under_the_band(
+        page_size, window, pos, dtype):
+    cache, q, new, history, start = _window_problem(page_size, window, pos,
+                                                    dtype)
+    got = _bounded_call(cache, q, new, window)
+    want = _band_reference(q, new, history, np.asarray(pos), start)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_nan_outside_the_window_and_in_unmapped_pages_changes_no_bit(dtype):
+    pos = (0, 3, 9, 14, 100, 37)
+    clean = _window_problem(4, 8, pos, dtype)
+    dirty = _window_problem(4, 8, pos, dtype, poison=np.nan)
+    got = np.asarray(_bounded_call(dirty[0], dirty[1], dirty[2], 8))
+    assert not np.isnan(got).any()
+    np.testing.assert_array_equal(
+        got, np.asarray(_bounded_call(clean[0], clean[1], clean[2], 8)))
+
+
+def test_cyclic_table_walked_over_three_wraps_matches_the_band():
+    """Decode by decode from position 0 past three turns of the cycle: the
+    new row is written into the slot's cycle where ``_write_window_rows``
+    puts it, and every step's bounded call equals the band over the whole
+    history. Two slots out of step with each other."""
+    from apex_tpu.serving.cache import WindowKVCache, ring_pages
+    from apex_tpu.serving.decode import _write_window_rows
+
+    page_size, window, slots = 4, 8, 2
+    ring = ring_pages(window, page_size)
+    steps = 3 * ring * page_size + 5
+    width = W_KV * W_HD
+    rng = np.random.RandomState(5)
+    rows = jnp.asarray(rng.standard_normal(
+        (2, LAYERS, slots, steps + 7, width)).astype(np.float32))
+    qs = jnp.asarray(rng.standard_normal(
+        (steps, slots, 1, W_HEADS * W_HD)).astype(np.float32))
+    pool = jnp.zeros((LAYERS, RESERVED_PAGES + slots * ring, page_size,
+                      width), jnp.float32)
+    cache = WindowKVCache(k=pool[:, :1], v=pool[:, :1],
+                          lengths=jnp.asarray([0, 7], jnp.int32),
+                          block_tables=jnp.zeros((slots, 1), jnp.int32),
+                          wk=pool, wv=pool)
+    # slot 1 is seven positions ahead: its first seven rows are history
+    for p in range(7):
+        ahead = cache._replace(lengths=jnp.asarray([0, p], jnp.int32))
+        wk, wv = _write_window_rows(ahead, rows[0, :, :, p], rows[1, :, :, p])
+        cache = cache._replace(wk=wk.at[:, RESERVED_PAGES:RESERVED_PAGES
+                                        + ring].set(0.0), wv=wv)
+    for t in range(steps):
+        pos = np.asarray(cache.lengths)
+        new = jnp.stack([jnp.stack([rows[i, LAYER, s, pos[s]]
+                                    for s in range(slots)])[:, None]
+                         for i in range(2)])
+        got = _bounded_call(cache, qs[t], new, window)
+        start = np.maximum(pos - (window - 1), 0)
+        want = _band_reference(qs[t], new, rows, pos, start)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+        now = jnp.stack([jnp.stack([rows[i, :, s, pos[s]]
+                                    for s in range(slots)], 1)
+                         for i in range(2)])
+        wk, wv = _write_window_rows(cache, now[0], now[1])
+        cache = cache._replace(wk=wk, wv=wv, lengths=cache.lengths + 1)
+
+
+def test_window_view_names_the_live_pages_of_the_cycle_in_order():
+    from apex_tpu.serving.cache import (WindowKVCache, ring_page,
+                                        ring_pages)
+
+    assert ring_pages(128, 16) == 9 and ring_pages(8, 4) == 3
+    pool = jnp.zeros((1, RESERVED_PAGES + 3 * 9, 16, 8))
+    cache = WindowKVCache(k=pool, v=pool, lengths=jnp.asarray([0, 130, 5000]),
+                          block_tables=jnp.zeros((3, 1), jnp.int32), wk=pool,
+                          wv=pool)
+    assert cache.ring == 9
+    table, pos, start = (np.asarray(t) for t in cache.window_view(128))
+    # slot 1 at 130: the window is 3 .. 130, which begins in logical page 0
+    assert pos.tolist() == [0, 130, 5000 - 304 * 16]
+    assert start.tolist() == [0, 3, (5000 - 127) % 16]
+    assert table[1].tolist() == [2 + 9 + j % 9 for j in range(9)]
+    assert table[2].tolist() == [2 + 18 + (304 + j) % 9 for j in range(9)]
+    # the pages pos - 127 .. pos touch never alias: nine logical pages, nine
+    # physical ones
+    assert all(len(set(row)) == 9 for row in table.tolist())
+    assert int(ring_page(2, 304, 9)) == table[2, 0]
+    assert (start < 16).all() and (pos <= 9 * 16).all()
+
+
+def test_without_a_bound_the_call_is_the_kernel_it_was():
+    """``start=None`` adds no operand and no operation: the program's text
+    is what the call without the argument lowers to, under the old name; the
+    bounded call has one more scalar row and its own name."""
+    cache, q, new, _, _ = _window_problem(4, 8, (3, 9), "float32")
+    table, pos, start = cache.window_view(8)
+    args = (q, new[0], new[1], cache.wk, cache.wv, table, pos,
+            jnp.int32(LAYER))
+    call = functools.partial(paged_decode_attention, heads=W_HEADS,
+                             kv_heads=W_KV)
+
+    def step(*a):
+        return call(*a)
+
+    def step_none(*a):
+        return call(*a, start=None)
+
+    def step_bounded(*a):
+        return call(*a[:-1], start=a[-1])
+
+    step_none.__name__ = step.__name__
+    plain, none = (jax.jit(f).lower(*args) for f in (step, step_none))
+    assert plain.as_text() == none.as_text()
+    bounded = jax.jit(step_bounded).lower(*args, start)
+    assert bounded.as_text() != plain.as_text()
+    plain, bounded = (t.as_text(debug_info=True) for t in (plain, bounded))
+    assert "apex_paged_decode_fwd" in plain
+    assert "apex_paged_window_decode_fwd" not in plain
+    assert "apex_paged_window_decode_fwd" in bounded
+    assert "apex_paged_decode_fwd" not in bounded

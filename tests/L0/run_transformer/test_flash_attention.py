@@ -206,3 +206,199 @@ def test_dropout_mask_independent_of_tiling(s):
     for a, b in zip(fwdbwd(True), fwdbwd(False)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# a sliding window: a band below the diagonal, its tiles and no others
+# ---------------------------------------------------------------------------
+
+def _band_reference(q, k, v, window, mask=None):
+    s = q.shape[2]
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") \
+        / np.sqrt(q.shape[-1])
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = (i - j >= 0) & (i - j < window)
+    if mask is not None:
+        seen = seen[None, None] & (mask[:, None, None, :] != 0)
+    p = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest")
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["whole", "key_mask"])
+@pytest.mark.parametrize("s, window", [
+    (512, 8), (1024, 128), (640, 128), (700, 128), (300, 8), (1536, 600)],
+    ids=lambda x: str(x))
+def test_window_kernel_matches_the_band_masked_reference(s, window, padded):
+    """Sequences that are and are not multiples of the tile (512 / 128), a
+    band narrower and wider than a tile, with and without a key mask (a
+    bucket's pad)."""
+    from apex_tpu.transformer.functional import flash_attention
+
+    q, k, v = (jax.random.normal(key, (1, 2, s, 32))
+               for key in jax.random.split(jax.random.PRNGKey(s), 3))
+    real = s - 37 if padded else s
+    mask = (jnp.arange(s) < real).astype(jnp.int32)[None] if padded else None
+    got = flash_attention(q, k, v, mask, causal=True, window=window,
+                          use_kernel=True)
+    want = _band_reference(q, k, v, window, mask)
+    np.testing.assert_allclose(np.asarray(got)[:, :, :real],
+                               np.asarray(want)[:, :, :real], atol=2e-6)
+    # short sequences take the plain path, under the same band
+    plain = flash_attention(q, k, v, mask, causal=True, window=window,
+                            use_kernel=False)
+    np.testing.assert_allclose(np.asarray(plain)[:, :, :real],
+                               np.asarray(want)[:, :, :real], atol=2e-6)
+
+
+@pytest.mark.parametrize("use_kernel", [True, False],
+                         ids=["kernel", "plain"])
+@pytest.mark.parametrize("s, window, key", [
+    (512, 8, 100), (640, 128, 127), (640, 128, 128), (1024, 128, 511),
+    (1024, 128, 512), (1024, 512, 3)], ids=lambda x: str(x))
+def test_window_mask_is_exact_at_its_edges(s, window, key, use_kernel):
+    """With equal scores everywhere a query's output is the mean of the
+    values it sees: a value that is 1 at ONE key and 0 elsewhere comes out
+    non-zero at exactly the queries that see that key, ``0 <= i - j <
+    window``: not at ``i - j = -1``, at ``0`` and ``window - 1``, not at
+    ``window``."""
+    from apex_tpu.transformer.functional import flash_attention
+
+    q = k = jnp.zeros((1, 1, s, 8))
+    v = jnp.zeros((1, 1, s, 8)).at[0, 0, key, 0].set(1.0)
+    got = np.asarray(flash_attention(q, k, v, causal=True, window=window,
+                                     use_kernel=use_kernel))[0, 0, :, 0]
+    sees = np.flatnonzero(got)
+    assert sees.tolist() == list(range(key, min(key + window, s)))
+    np.testing.assert_allclose(
+        got[sees], 1.0 / np.minimum(sees + 1, window), rtol=1e-6)
+
+
+def _k_extent(s, **kw):
+    """The k extent of the forward kernel's grid over a sequence of ``s``."""
+    from apex_tpu.transformer.functional import flash_attention
+
+    x = jax.ShapeDtypeStruct((1, 1, s, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, use_kernel=True, **kw))(x, x, x)
+    def calls(jaxpr):       # the kernel's call, inside custom_vjp or not
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from calls(sub)
+
+    call, = calls(jaxpr.jaxpr)
+    return call.params["grid_mapping"].grid[2]
+
+
+def test_visited_k_tiles_grow_with_the_band_and_not_with_the_sequence():
+    from apex_tpu.transformer.functional.flash_attention import _band_tiles
+
+    assert [_k_extent(s) for s in (1024, 2048, 8192)] == [2, 4, 16]
+    assert [_k_extent(s, window=128) for s in (1024, 2048, 8192)] == [2] * 3
+    assert [_k_extent(8192, window=w) for w in (1, 513, 514, 1025, 1026)] \
+        == [1, 2, 3, 3, 4]
+    # tiles of 128 (a sequence of 640): a band of 128 touches two of five
+    assert _k_extent(640) == 5 and _k_extent(640, window=128) == 2
+    assert _band_tiles(128, 512, 128, 4) == 2      # q tiles within a k tile
+    assert _band_tiles(512, 128, 128, 10) == 5     # a q tile over four
+
+
+def test_window_is_a_band_of_square_causal_attention_and_nothing_else():
+    from apex_tpu.transformer.functional import flash_attention
+
+    q = jnp.zeros((1, 1, 300, 8))
+    with pytest.raises(ValueError, match="band below the diagonal"):
+        flash_attention(q, q, q, window=8)
+    with pytest.raises(ValueError, match="band below the diagonal"):
+        flash_attention(q, q[:, :, :200], q[:, :, :200], causal=True,
+                        window=8)
+    with pytest.raises(ValueError, match="band below the diagonal"):
+        flash_attention(q, q, q, causal=True, window=0)
+
+
+def test_without_a_window_the_forward_lowers_to_what_it_did():
+    from apex_tpu.transformer.functional import flash_attention
+
+    x = jax.ShapeDtypeStruct((1, 2, 512, 16), jnp.float32)
+
+    def step(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def step_none(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=None)
+
+    step_none.__name__ = step.__name__
+    assert jax.jit(step).lower(x, x, x).as_text() \
+        == jax.jit(step_none).lower(x, x, x).as_text()
+
+
+# ---------------------------------------------------------------------------
+# precision: float32 operands at the MXU's full precision, forward only
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("use_kernel", [True, False], ids=["kernel", "xla"])
+@pytest.mark.parametrize("window", [None, 128], ids=["full", "band"])
+def test_precision_reaches_both_products_and_changes_nothing_else(window,
+                                                                  use_kernel):
+    """With ``precision`` the two products of the forward (the scores and
+    P.V) carry it, in the kernel and on the plain path, banded or not; the
+    probabilities and the output stay float32; the values are the reference's
+    (on the CPU every precision is exact: what the chip does with it is the
+    benchmark's comparison)."""
+    from apex_tpu.transformer.functional import flash_attention
+
+    s = 640
+    q, k, v = (jax.random.normal(key, (1, 2, s, 32))
+               for key in jax.random.split(jax.random.PRNGKey(7), 3))
+    attend = lambda precision: lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, use_kernel=use_kernel,
+        precision=precision)
+    got = attend(jax.lax.Precision.HIGHEST)(q, k, v)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        got, _band_reference(q, k, v, window or s), atol=2e-6)
+    dots = lambda precision: [
+        str(e.params.get("precision")) for e in _equations(
+            jax.make_jaxpr(attend(precision))(q, k, v).jaxpr)
+        if e.primitive.name == "dot_general"]
+    # two products a tile body (the kernel has a masked and a plain one)
+    exact = dots(jax.lax.Precision.HIGHEST)
+    assert len(dots(None)) == len(exact) >= 2 and not len(exact) % 2
+    assert "HIGHEST" not in "".join(dots(None))
+    assert all("HIGHEST" in d for d in exact)
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (a kernel's
+    body, a ``cond``'s branches)."""
+    for e in jaxpr.eqns:
+        yield e
+        for value in e.params.values():
+            for inner in value if isinstance(value, (list, tuple)) \
+                    else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_without_a_precision_the_forward_lowers_to_what_it_did():
+    from apex_tpu.transformer.functional import flash_attention
+
+    x = jax.ShapeDtypeStruct((1, 2, 512, 16), jnp.float32)
+
+    def step(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def step_none(q, k, v):
+        return flash_attention(q, k, v, causal=True, precision=None)
+
+    step_none.__name__ = step.__name__
+    assert jax.jit(step).lower(x, x, x).as_text() \
+        == jax.jit(step_none).lower(x, x, x).as_text()
+    # forward only, as a window: no gradient is defined through it
+    with pytest.raises(Exception):
+        jax.grad(lambda q: flash_attention(
+            q, q, q, causal=True, use_kernel=True,
+            precision=jax.lax.Precision.HIGHEST).sum())(
+                jnp.ones((1, 1, 512, 16)))

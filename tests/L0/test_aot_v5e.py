@@ -408,6 +408,128 @@ def test_deepseek_full_size_programs(v5e):
             < 0.96 * 16 * 2 ** 30, name
 
 
+def test_exaone_kernels(v5e):
+    """The paged decode kernel at the ``k_exaone_236b_a23b`` cell's two calls
+    (64 query heads over 8 K/V heads of 128, 64 slots): the full layer's over
+    the pool of 45570 pages and 712 a slot, and the sliding layers' BOUNDED
+    call (``start``) over the 578 pages of the window pool through the (64,
+    9) view of the cyclic table; the prompt path's flash attention (float32,
+    ``precision=HIGHEST``) at the three prefill buckets, banded (2 k tiles a
+    q tile, whatever the prompt) and full; the grouped
+    product at the SwiGLU expert's two shapes at hidden 6144."""
+    from apex_tpu.transformer.functional import flash_attention, moe
+    from apex_tpu.transformer.functional.flash_attention import _band_tiles
+    from apex_tpu.transformer.functional.paged_attention import (
+        paged_decode_attention,
+    )
+
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    paged = functools.partial(paged_decode_attention, heads=64, kv_heads=8)
+    row = ((64, 1, 1024), bf16)
+    assert compile_on(
+        v5e, paged, ((64, 1, 8192), f32), row, row,
+        ((1, 45570, 16, 1024), bf16), ((1, 45570, 16, 1024), bf16),
+        ((64, 712), i32), ((64,), i32), ((), i32)) == 1
+    bounded = lambda q, k, v, kp, vp, bt, pos, layer, start: paged(
+        q, k, v, kp, vp, bt, pos, layer, start=start)
+    assert compile_on(
+        v5e, bounded, ((64, 1, 8192), f32), row, row,
+        ((4, 578, 16, 1024), bf16), ((4, 578, 16, 1024), bf16),
+        ((64, 9), i32), ((64,), i32), ((), i32), ((64,), i32)) == 1
+    # the prompt path's two calls: float32 operands, both products at the
+    # MXU's full precision, banded (the sliding layers) and not (the full)
+    exact = functools.partial(flash_attention, causal=True,
+                              precision=jax.lax.Precision.HIGHEST)
+    for s in (2048, 4096, 8192):
+        assert _band_tiles(512, 512, 128, s // 512) == 2
+        qkv = ((1, 64, s, 128), f32)
+        for window in (128, None):
+            assert compile_on(v5e, functools.partial(exact, window=window),
+                              qkv, qkv, qkv, ((1, s), i32)) == 1
+    assert moe._column_tile(6144, 4096, 2) == 256       # 3.1 MB a tile
+    gmm = lambda lhs, rhs, sizes, first: moe.grouped_matmul(
+        lhs, rhs, sizes, first_group=first)
+    for rows in (64 * 8, 1024 * 8):
+        assert compile_on(
+            v5e, gmm, ((rows, 6144), f32), ((64, 6144, 4096), bf16),
+            ((16,), i32), ((), i32)) == 1
+        assert compile_on(
+            v5e, gmm, ((rows, 2048), f32), ((64, 2048, 6144), bf16),
+            ((16,), i32), ((), i32)) == 1
+
+
+def test_exaone_full_size_programs(v5e):
+    """The programs of ``k_exaone_236b_a23b.long_context_reasoning`` at full
+    size (one leading dense layer and 4 expert layers, 16 of 128 experts, an
+    eighth of the vocabulary, 64 slots, the full layer's pool of 11392
+    positions a slot and the four sliding layers' 9 pages a slot): decode and
+    every prefill bucket compile for a v5e with no chip; ``memory_analysis``
+    gives what the configuration file says (7.42 GB of weights, 2.99 GB in
+    the full pool and 0.15 GB in the window pool, all of it aliased) and fits
+    the chip; the kernel names are the engagement counters the trace readers
+    count (the full layer's call and the sliding layers' under their two
+    names, in decode only; the prompt path runs flash attention, banded in
+    the sliding layers)."""
+    import re
+
+    from apex_tpu.models import exaone_moe
+    from apex_tpu.serving.cache import init_window_cache
+    from apex_tpu.serving.decode import (
+        make_model_decode_fn, make_model_prefill_fn,
+    )
+
+    slots, max_len, page = 64, 11392, 16
+    kinds = exaone_moe.ExaoneMoeConfig().layer_types[:5]
+    cfg = exaone_moe.ExaoneMoeConfig(vocab_size=19200, num_layers=5,
+                                     layer_types=kinds, experts_held=16)
+    sharding = SingleDeviceSharding(v5e)
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+    params = on(jax.eval_shape(
+        lambda k: exaone_moe.init(k, cfg, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(functools.partial(
+        init_window_cache, cfg, slots, max_len,
+        slots * (max_len // page) + 2, page, jnp.bfloat16)))
+    size = lambda tree: sum(a.size * a.dtype.itemsize
+                            for a in jax.tree.leaves(tree))
+    assert round(size(params) / 1e9, 2) == 7.42
+    assert cache.k.shape == (1, 45570, 16, 1024)
+    assert cache.wk.shape == (4, 578, 16, 1024)
+    assert round(size((cache.k, cache.v)) / 1e9, 2) == 2.99
+    assert round(size((cache.wk, cache.wv)) / 1e9, 2) == 0.15
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=sharding)
+    i32 = jnp.int32
+    programs = {"decode": make_model_decode_fn(cfg).lower(
+        params, cache, sds((slots,), i32), sds((slots,), jnp.bool_))}
+    for bucket in (2048, 4096, 8192):
+        programs[f"prefill_{bucket}"] = make_model_prefill_fn(cfg).lower(
+            params, cache, sds((1, bucket), i32), sds((bucket,), i32),
+            sds((), i32), sds((bucket // page,), i32),
+            sds((max_len // page,), i32))
+    # the dense layer's kernels stand unrolled, the scanned period's once
+    # each call site: sliding, sliding, full, sliding
+    want = {"decode": {"apex_paged_decode_fwd": 1,
+                       "apex_paged_window_decode_fwd": 4,
+                       "apex_moe_gmm_fwd": 8, "apex_flash_fwd": 0},
+            "prefill": {"apex_paged_decode_fwd": 0,
+                        "apex_paged_window_decode_fwd": 0,
+                        "apex_moe_gmm_fwd": 8, "apex_flash_fwd": 5}}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        kind = name.split("_")[0]
+        got = {k: len(re.findall(rf"%{k}(\.\d+)? = ", text))
+               for k in want[kind]}
+        assert got == want[kind], (name, got)
+        mem = compiled.memory_analysis()
+        assert 0 <= mem.alias_size_in_bytes - size(cache) < 1 << 16, name
+        print(name, mem.temp_size_in_bytes / 1e9,
+              (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30)
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 0.96 * 16 * 2 ** 30, name
+
+
 def test_flat_adam(v5e):
     from apex_tpu.optimizers import FusedAdam
 
