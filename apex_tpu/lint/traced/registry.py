@@ -199,6 +199,27 @@ def _flash_entry(d, dtype, seq):
     return build
 
 
+def _fmha_entry():
+    """The whole-sequence pair on a packed projection at s128, four heads of
+    64, forward and backward (``models/bert.py``'s call)."""
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        from apex_tpu.transformer.functional.flash_attention import (
+            flash_attention_packed,
+        )
+
+        def loss(qkv):
+            out = flash_attention_packed(qkv)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        return jax.value_and_grad(loss), (
+            _sds((2, 128, 3, 4, 64), "bfloat16"),)
+
+    return build
+
+
 def _ln_entry(h, rms=False):
     def build():
         import importlib
@@ -1419,6 +1440,7 @@ def repo_entries() -> List[TraceEntry]:
                    _flash_entry(64, "bfloat16", 512)),
         TraceEntry("flash_d128_f32_s512_fwd_bwd", flash,
                    _flash_entry(128, "float32", 512)),
+        TraceEntry("fmha_d64_bf16_s128_fwd_bwd", flash, _fmha_entry()),
         TraceEntry("ln_h1024_fwd_bwd", ln, _ln_entry(1024)),
         TraceEntry("rms_h4096_fwd_bwd", ln, _ln_entry(4096, rms=True)),
         TraceEntry("xentropy_fwd_bwd", "apex_tpu.contrib.xentropy",

@@ -232,6 +232,25 @@ def _flash_cfg(d, dtype, seq):
     return build
 
 
+def _fmha_cfg(b, s, h, d, dtype):
+    """The whole-sequence pair (``apex_fmha_fwd`` / ``apex_fmha_bwd``) on a
+    packed projection at a real shape, forward and backward."""
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        from apex_tpu.transformer.functional.flash_attention import (
+            flash_attention_packed,
+        )
+
+        def loss(qkv):
+            return jnp.sum(flash_attention_packed(qkv).astype(jnp.float32))
+
+        return jax.value_and_grad(loss), (_sds((b, s, 3, h, d), dtype),)
+
+    return build
+
+
 def _ln_cfg(h, rms=False):
     def build():
         import importlib
@@ -639,6 +658,10 @@ def repo_configs() -> List[Config]:
                _flash_cfg(64, "bfloat16", 2048)),
         Config("flash_d128_f32_s2048", flash,
                _flash_cfg(128, "float32", 2048)),
+        Config("fmha_bert_large_s128", flash,
+               _fmha_cfg(64, 128, 16, 64, "bfloat16")),
+        Config("fmha_d128_f32_s256", flash,
+               _fmha_cfg(8, 256, 8, 128, "float32")),
         Config("ln_h1024_fwd_bwd", ln, _ln_cfg(1024)),
         Config("ln_h4096_fwd_bwd_colsplit", ln, _ln_cfg(4096)),
         Config("rms_h4096_fwd_bwd", ln, _ln_cfg(4096, rms=True)),

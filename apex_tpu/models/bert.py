@@ -6,8 +6,8 @@ is BERT-Large pretrain with amp O2 + FusedAdam + FusedLayerNorm. This is a
 functional BERT built on the package's own accelerants:
 
 - ``apex_tpu.normalization.fused_layer_norm_affine`` for every LayerNorm;
-- attention softmax routed through ``apex_tpu.transformer.functional``'s
-  fused kernel once built (plain jnp softmax until then);
+- attention through ``apex_tpu.transformer.functional``'s fused kernels
+  (``flash_attention_packed``: no score array reaches HBM at s128 / s256);
 - params are a nested dict so the AMP O2 cast (`keep_batchnorm_fp32` treats
   "layernorm" paths as norms) and TP sharding specs apply mechanically.
 
@@ -40,7 +40,9 @@ class BertConfig:
     layer_norm_eps: float = 1e-12
     hidden_dropout: float = 0.1     # applied only when rng given
     attention_dropout: float = 0.1
-    # fused flash-attention path (ref: apex/contrib multihead_attn/fmha);
+    # fused attention (ref: apex/contrib multihead_attn / fmha): at a
+    # sequence of 128 or 256 the whole-sequence fmha kernel pair on the
+    # packed projection, above 256 the tiled flash kernels, else XLA;
     # False falls back to materialized scores + fused softmax kernel
     fused_attention: bool = True
     # jax.checkpoint each encoder layer: one hidden state per layer of
@@ -119,18 +121,20 @@ def _ln(p, x, eps):
 
 def _attention(p, cfg: BertConfig, x, mask, dropout_rng=None):
     from apex_tpu.transformer.functional import (
-        flash_attention, scaled_masked_softmax)
+        flash_attention_packed, scaled_masked_softmax)
 
     b, s, h = x.shape
     nh, hd = cfg.num_heads, cfg.head_dim
     qkv = L.dense(p["qkv"], x).reshape(b, s, 3, nh, hd)
-    q, k, v = (qkv[:, :, j].transpose(0, 2, 1, 3) for j in range(3))
     if cfg.fused_attention:
-        ctx = flash_attention(
-            q, k, v, mask, softmax_scale=1.0 / math.sqrt(hd),
-            dropout_rate=cfg.attention_dropout, dropout_rng=dropout_rng)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, h)
-        return L.dense(p["out"], ctx)
+        # the projection's output as it lies: at a sequence of 128 or 256
+        # the fmha kernels index q, k and v in it and write the context as
+        # the output projection reads it; any other shape is transposed
+        # and takes flash_attention's own path
+        return L.dense(p["out"], flash_attention_packed(
+            qkv, mask, softmax_scale=1.0 / math.sqrt(hd),
+            dropout_rate=cfg.attention_dropout, dropout_rng=dropout_rng))
+    q, k, v = (qkv[:, :, j].transpose(0, 2, 1, 3) for j in range(3))
     scores = jnp.einsum("bnqd,bnkd->bnqk", *cast_args("einsum", q, k))
     if mask is not None:
         # mask: (b, s) with 1 = attend; the fused kernel masks nonzero

@@ -2,6 +2,7 @@
 
 from apex_tpu.transformer.functional.flash_attention import (  # noqa: F401
     flash_attention,
+    flash_attention_packed,
 )
 from apex_tpu.transformer.functional.fused_rope import (  # noqa: F401
     fused_apply_rotary_pos_emb,

@@ -3,8 +3,12 @@
 Reference: ``apex/contrib/csrc/multihead_attn/*`` (fused QKV-softmax-
 dropout-PV fwd/bwd, ~8k CUDA LoC) and ``apex/contrib/csrc/fmha/*``
 (short-seqlen fused MHA) — SURVEY.md §2b calls this the largest single
-kernel work item. Both are subsumed by one seqlen-generic flash-style
-kernel pair:
+kernel work item. The first is subsumed by one seqlen-generic flash-style
+kernel pair; ``fmha``'s own case, bidirectional attention over 128 or 256
+positions, is a whole-sequence pair of its own further down
+(``apex_fmha_fwd`` / ``apex_fmha_bwd``, "short sequences"), which
+``flash_attention_packed`` runs on a fused q / k / v projection as it lies.
+The tiled pair:
 
 - **forward**: grid ``(batch*heads, q_tiles, k_tiles)``; per q-tile a
   running (max, sum, acc) in VMEM scratch implements the online softmax
@@ -597,6 +601,286 @@ def _bwd_call(q, k, v, mask, out, lse_p, do, *, causal, scale, rate, seed,
     return dq, dk, dv
 
 
+# -- short sequences: the whole sequence is one tile (fmha) ------------------
+#
+# At a sequence of 128 or 256 the tiled kernels above degenerate to one
+# program a (batch, head) pair with a single k step each (24,576 programs a
+# BERT-Large forward at b64), and XLA's own path writes the scores and the
+# probabilities to HBM and keeps them for the backward. Here the whole
+# sequence is the tile: no online softmax, no k loop, no scratch, and the
+# backward is ONE program where the tiled kernels need two, because nothing
+# accumulates across grid steps.
+#
+# The operand is a fused projection as it lies, ``(b, s, 3 * h * d)``: q, k
+# and v are thirds of ONE array that three BlockSpecs index in place, the
+# context comes out ``(b, s, h * d)`` and the gradient as ONE array of the
+# operand's shape, so nothing is transposed, sliced or concatenated on
+# either side of a kernel. (On ``(b, h, s, d)`` operands the same bodies
+# lost to XLA, alone and inside BERT's step: rows of 64 columns are half a
+# lane tile, and the layout copies cost what the kernels saved; PERF.md,
+# PR 41. ``flash_attention`` therefore never takes this pair by itself.)
+#
+# A grid step takes a few batch rows. A row's heads are taken a lane block
+# (128 columns) at a time; where a block holds two heads of 64 each is
+# picked by zeroing the other's columns of k (and of do) in front of the
+# product, and each product's output keeps its own head's columns: every
+# product runs at the block's full width, which is what a [128, 64] operand
+# costs the MXU anyway, and no lane is ever shifted. The heads of a row are
+# independent chains laid side by side, which is what fills the units.
+
+_FMHA_HEAD_DIMS = (64, 128)     # the head widths the pair is built for
+_FMHA_BLOCK_BYTES = 512 * 1024  # of q's block in VMEM
+
+
+def _takes_fmha(s, h, d):
+    """Is a packed projection a shape the pair is built for: one tile of 128
+    or 256 positions, heads of 64 or 128 that fill whole lane blocks."""
+    return (s in (128, 256) and d in _FMHA_HEAD_DIMS
+            and (h * d) % 128 == 0)
+
+
+def _fmha_group(rows, s, cols, itemsize):
+    """Batch rows a grid step takes: as many as keep q's block at 512 KiB
+    (2 rows of BERT-Large's 16 x 64 columns at s128 in bfloat16), so that
+    the backward's blocks (q, k, v, do and the three gradients, double
+    buffered) stay at half the 16 MiB a kernel may use; one row where a
+    row alone is more. The last grid step may be ragged."""
+    return max(1, min(rows, _FMHA_BLOCK_BYTES // (s * cols * itemsize)))
+
+
+def _fmha_rows(g, heads, row):
+    """Run ``row(j)`` for the ``g`` rows of a grid step. One head's work is
+    a chain (scores, max, exp, sum, product) that leaves the units idle in
+    turn, and only independent heads fill them: rows of fewer than eight
+    heads are laid side by side until eight are (the loop's own ``unroll``
+    is all or nothing in a kernel)."""
+    side = math.gcd(g, max(1, 8 // heads))      # 8 // heads: a power of 2
+
+    def rows(i, carry):
+        for r in range(side):
+            row(i * side + r)
+        return carry
+
+    jax.lax.fori_loop(0, g // side, rows, 0)
+
+
+def _fmha_picks(d):
+    """For each head of a lane block, which columns are its own: ``None``
+    where the block is one head."""
+    if d == 128:
+        return [None]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    return [(lane >= t * d) & (lane < (t + 1) * d) for t in range(128 // d)]
+
+
+def _fmha_only(pick, x):
+    return x if pick is None else jnp.where(pick, x, jnp.zeros_like(x))
+
+
+def _fmha_merge(pick, new, old):
+    return new if pick is None or old is None else jnp.where(pick, new, old)
+
+
+def _fmha_key_bias(bias_ref, j, s):
+    """The bias of row ``j``'s keys, keys DOWN the sublanes and the same in
+    every lane, ``(s_k, s_q)``: one transpose a row, shared by its heads."""
+    return jnp.broadcast_to(bias_ref[j], (s, s)).T
+
+
+def _fmha_probs(q, k, bias, scale2, shift):
+    """``exp2(scale2 * k.qT + bias - shift)`` of one head, float32, keys
+    down the sublanes and queries along the lanes, so that a query's maximum
+    and sum are elementwise work over vregs and a statistic of the queries
+    is a ROW, as the log-sum-exp is stored; with the column maxima where
+    ``shift`` is None (the forward). Base 2: ``log2(e)`` rides in the one
+    multiply that applies the softmax scale; the key mask is one add."""
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale2
+    s = s + bias
+    m = jnp.max(s, axis=0, keepdims=True) if shift is None else shift
+    return jnp.exp2(s - m), m
+
+
+def _fmha_keep(seed_ref, head, shape, rate):
+    """The dropout keep mask of (head, q, k) for a tile that holds keys down
+    its sublanes: ``_keep_mask``'s hash at the same positions."""
+    kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 0).astype(jnp.uint32)
+    qpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1).astype(jnp.uint32)
+    return _hash_keep(qpos, kpos, head, seed_ref[0, 0], seed_ref[0, 1], rate)
+
+
+def _fmha_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
+                     lse_ref, *, d, scale2, rate):
+    g, s, cols = q_ref.shape
+    heads = cols // d
+    first = pl.program_id(0) * g
+    picks = _fmha_picks(d)
+
+    def row(j):
+        bias = _fmha_key_bias(bias_ref, j, s)
+        # every query sees the same keys: a query with none is a row with
+        # none, whose exp2(0) terms below mean nothing
+        live = jnp.max(bias_ref[j], axis=-1, keepdims=True) > _NEG / 2
+        for c in range(0, cols, 128):
+            at = (j, slice(None), pl.ds(c, 128))
+            q, k, v = q_ref[at], k_ref[at], v_ref[at]
+            out = None
+            for t, pick in enumerate(picks):
+                head = c // d + t
+                p, m = _fmha_probs(q, _fmha_only(pick, k), bias, scale2,
+                                   None)
+                l = jnp.sum(p, axis=0, keepdims=True)
+                # base 2, as the backward reads it
+                lse_ref[j, head, :] = jnp.where(
+                    live, m + jnp.log2(l), jnp.inf)[0]
+                # normalized in float32, then for the MXU only
+                p = (p * (1.0 / l)).astype(v.dtype)
+                if rate > 0.0:
+                    keep = _fmha_keep(seed_ref, (first + j) * heads + head,
+                                      p.shape, rate)
+                    p = jnp.where(keep, p * p.dtype.type(1.0 / (1.0 - rate)),
+                                  p.dtype.type(0.0))
+                out = _fmha_merge(pick, jax.lax.dot_general(
+                    p, v, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32), out)
+            o_ref[at] = jnp.where(live, out, 0.0).astype(o_ref.dtype)
+
+    _fmha_rows(g, heads, row)
+
+
+def _fmha_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
+                     lse_ref, dqkv_ref, *, d, scale, rate):
+    g, s, cols = q_ref.shape
+    heads = cols // d
+    first = pl.program_id(0) * g
+    picks = _fmha_picks(d)
+
+    def row(j):
+        bias = _fmha_key_bias(bias_ref, j, s)
+        for c in range(0, cols, 128):
+            at = (j, slice(None), pl.ds(c, 128))
+            q, k, v, do = q_ref[at], k_ref[at], v_ref[at], do_ref[at]
+            dq = dk = dv = None
+            for t, pick in enumerate(picks):
+                head = c // d + t
+                # an unseen row's lse is +inf: p, and every gradient, is 0
+                p, _ = _fmha_probs(q, _fmha_only(pick, k), bias,
+                                   scale * _LOG2E,
+                                   lse_ref[j, head, :][None, :])
+                dp = jax.lax.dot_general(
+                    v, _fmha_only(pick, do), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                p_drop = p
+                if rate > 0.0:
+                    keep = _fmha_keep(seed_ref, (first + j) * heads + head,
+                                      p.shape, rate)
+                    dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
+                    p_drop = jnp.where(keep, p / (1.0 - rate), 0.0)
+                # rowsum(do * o) is sum_k p * dp, all of it in this tile and
+                # in float32: the (rounded) output is not read again
+                delta = jnp.sum(p * dp, axis=0, keepdims=True)
+                # d / d(raw score): the softmax scale rides in ds, so dq
+                # and dk need no fixup
+                ds = (p * (dp - delta) * scale).astype(q.dtype)
+                dv = _fmha_merge(pick, jax.lax.dot_general(
+                    p_drop.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32), dv)
+                dk = _fmha_merge(pick, jax.lax.dot_general(
+                    ds, q, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32), dk)
+                dq = _fmha_merge(pick, jax.lax.dot_general(
+                    ds, k, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32), dq)
+            # the thirds of ONE array, as q, k and v are
+            for third, grad in enumerate((dq, dk, dv)):
+                dqkv_ref[j, :, pl.ds(third * cols + c, 128)] = grad.astype(
+                    dqkv_ref.dtype)
+
+    _fmha_rows(g, heads, row)
+
+
+def _fmha_operands(qkv, mask):
+    """``(b, s, 3, h, d)`` as the kernels take it: the array ``(b, s, 3 * h
+    * d)``, the key mask as ONE additive float32 row a batch row, the grid
+    and the BlockSpecs (q's / k's / v's thirds, a ``cols``-wide tile, the
+    whole width, ``n`` rows of ``s``)."""
+    b, s, _, h, d = qkv.shape
+    cols = h * d
+    x = qkv.reshape(b, s, 3 * cols)
+    bias = jnp.zeros((b, 1, s), jnp.float32) if mask is None else jnp.where(
+        mask != 0, 0.0, _NEG).astype(jnp.float32).reshape(b, 1, s)
+    g = _fmha_group(b, s, cols, qkv.dtype.itemsize)
+    block = lambda shape, at=0: pl.BlockSpec(           # noqa: E731
+        (g,) + shape, lambda i: (i, 0, at), memory_space=pltpu.VMEM)
+    thirds = [block((s, cols), t) for t in range(3)]
+    return (x, bias, (pl.cdiv(b, g),), thirds, block((s, cols)),
+            block((s, 3 * cols)), lambda n: block((n, s)))
+
+
+# Both launches are ``jit``s of their own: a model's layers call them with
+# one static configuration and one set of shapes, so the kernel is traced
+# and lowered ONCE a program and not once a layer (48 lowerings of a body
+# unrolled over 16 heads cost BERT-Large's step 11 s of set-up, compile
+# cache or not: PERF.md, PR 41).
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _fmha_forward(cfg, qkv, mask, seed):
+    scale, rate, interpret = cfg
+    b, s, _, h, d = qkv.shape
+    x, bias, grid, thirds, tile, _, rows = _fmha_operands(qkv, mask)
+    with jax.named_scope("apex_fmha_fwd"):
+        return pl.pallas_call(
+            functools.partial(_fmha_fwd_kernel, d=d, scale2=scale * _LOG2E,
+                              rate=rate),
+            grid=grid,
+            in_specs=[_smem(), *thirds, rows(1)],
+            out_specs=(tile, rows(h)),
+            out_shape=(jax.ShapeDtypeStruct((b, s, h * d), qkv.dtype),
+                       jax.ShapeDtypeStruct((b, h, s), jnp.float32)),
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_fmha_fwd",
+        )(jnp.asarray(seed, jnp.uint32).reshape(1, 2), x, x, x, bias)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _fmha_backward(cfg, qkv, mask, lse, seed, do):
+    scale, rate, interpret = cfg
+    d = qkv.shape[4]
+    x, bias, grid, thirds, tile, whole, rows = _fmha_operands(qkv, mask)
+    with jax.named_scope("apex_fmha_bwd"):
+        dqkv = pl.pallas_call(
+            functools.partial(_fmha_bwd_kernel, d=d, scale=scale, rate=rate),
+            grid=grid,
+            in_specs=[_smem(), *thirds, rows(1), tile, rows(lse.shape[1])],
+            out_specs=whole,
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            compiler_params=_dimsem("parallel"),
+            interpret=pallas_interpret(interpret),
+            name="apex_fmha_bwd",
+        )(jnp.asarray(seed, jnp.uint32).reshape(1, 2), x, x, x, bias, do,
+          lse)
+    return dqkv.reshape(qkv.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _fmha_core(cfg, qkv, mask, seed):
+    return _fmha_forward(cfg, qkv, mask, seed)[0]
+
+
+def _fmha_fwd(cfg, qkv, mask, seed):
+    out, lse = _fmha_forward(cfg, qkv, mask, seed)
+    return out, (qkv, mask, lse, seed)
+
+
+def _fmha_bwd(cfg, res, do):
+    qkv, mask, lse, seed = res
+    return _fmha_backward(cfg, qkv, mask, lse, seed, do), None, None
+
+
+_fmha_core.defvjp(_fmha_fwd, _fmha_bwd)
+
+
 # -- custom_vjp + public API ------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -626,11 +910,18 @@ def _flash_bwd(cfg, res, do):
 _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 
-# Measured crossover on TPU v5e (b=16, h=16, d=64, fwd+bwd): at padded
-# seq <= 256 XLA's single batched einsum+softmax beats the tiled kernel
-# (the kernel degenerates to b*h sequential one-tile programs), while at
-# >= 512 the kernel wins and at 2048 it is ~2x faster. Dispatch on size
-# so every caller gets the better path at its shape.
+# Two paths of ``flash_attention`` itself, and what decides between them
+# when ``use_kernel`` is None: above a padded sequence of 256 the tiled
+# kernels (``_flash_core``), at 256 and under plain XLA
+# (``_unfused_attention``). Measured crossover on TPU v5e (b=16, h=16, d=64,
+# fwd+bwd): at 512 the kernel wins and at 2048 it is ~2x faster, while at
+# 256 and under it degenerates to b*h sequential one-tile programs and loses
+# to XLA by 5x (PR 41: 2.35 ms against 0.46 at b64 h16 s128), and XLA's one
+# batched einsum + softmax is the right program for serving's short causal
+# buckets. The third path is not reached from here: the whole-sequence pair
+# (``_fmha_core``) takes a packed projection, through
+# ``flash_attention_packed``; on ``(b, h, s, d)`` operands it lost to XLA
+# too (0.78 ms against 0.46 alone, 141.1 ms against 135.1 a BERT step).
 _UNFUSED_MAX_SEQ = 256
 
 
@@ -673,6 +964,15 @@ def _unfused_attention(q, k, v, mask, seed, *, causal, scale, rate,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+def _dropout_seed(dropout_rate, dropout_rng):
+    """(rate, the two uint32 words of the dropout hash's seed): dropout is
+    active only when a key is given."""
+    rate = float(dropout_rate) if dropout_rng is not None else 0.0
+    if rate > 0.0:
+        return rate, jax.random.bits(dropout_rng, (2,), jnp.uint32)
+    return rate, jnp.zeros((2,), jnp.uint32)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     mask: Optional[jax.Array] = None, *,
                     causal: bool = False,
@@ -694,9 +994,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
       dropout_rate: attention-probability dropout (after normalization,
         reference semantics); active only when ``dropout_rng`` is given.
       dropout_rng: PRNG key; 64 bits folded into the dropout-hash seed.
-      use_kernel: force the Pallas kernel (True) or the XLA path (False);
-        None auto-dispatches on sequence length (kernel when the padded
-        seq exceeds ``_UNFUSED_MAX_SEQ`` — the measured v5e crossover).
+      use_kernel: force the tiled Pallas kernels (True) or the XLA path
+        (False); None auto-dispatches on sequence length (the kernels when
+        the padded seq exceeds ``_UNFUSED_MAX_SEQ`` — the measured v5e
+        crossover). A model that holds a packed q / k / v projection calls
+        ``flash_attention_packed`` instead, which at a sequence of 128 or
+        256 runs a third path, the whole-sequence pair ``apex_fmha_fwd`` /
+        ``apex_fmha_bwd`` (the comment at ``_UNFUSED_MAX_SEQ``).
       window: with ``causal``, query ``i`` attends key ``j`` iff ``0 <= i -
         j < window`` (the token itself counts). Forward only: the kernel
         visits the band's k tiles and no others; there is no backward.
@@ -707,11 +1011,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     if softmax_scale is None:
         softmax_scale = 1.0 / (q.shape[-1] ** 0.5)
-    rate = float(dropout_rate) if dropout_rng is not None else 0.0
-    if rate > 0.0:
-        seed = jax.random.bits(dropout_rng, (2,), jnp.uint32)
-    else:
-        seed = jnp.zeros((2,), jnp.uint32)
+    rate, seed = _dropout_seed(dropout_rate, dropout_rng)
     if use_kernel is None:
         use_kernel = max(q.shape[2], k.shape[2]) > _UNFUSED_MAX_SEQ
     if window is not None and (not causal or window < 1
@@ -735,3 +1035,37 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                   scale=float(softmax_scale), rate=rate)
     cfg = (bool(causal), float(softmax_scale), rate, interpret)
     return _flash_core(cfg, q, k, v, mask, seed)
+
+
+def flash_attention_packed(qkv: jax.Array, mask: Optional[jax.Array] = None,
+                           *, softmax_scale: Optional[float] = None,
+                           dropout_rate: float = 0.0,
+                           dropout_rng: Optional[jax.Array] = None,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """Bidirectional self-attention over a packed projection.
+
+    ``qkv`` is ``(batch, seq, 3, heads, head_dim)``, a fused q / k / v
+    projection's output as it lies in memory; returns ``(batch, seq, heads
+    * head_dim)``, what the output projection reads: ``flash_attention``
+    over the three transposed slices, and the same values. At a sequence of
+    128 or 256 and heads of 64 or 128 that fill whole lane blocks
+    (``_takes_fmha``) the whole-sequence kernel pair ``apex_fmha_fwd`` /
+    ``apex_fmha_bwd`` indexes the thirds of ``qkv`` in place, and no
+    score-shaped array reaches HBM, forward or backward; every other shape
+    is transposed and handed to ``flash_attention``, which picks the tiled
+    kernels or XLA as for any caller. ``mask``, ``softmax_scale`` and the
+    dropout arguments are ``flash_attention``'s.
+    """
+    b, s, three, h, d = qkv.shape
+    assert three == 3, qkv.shape
+    if _takes_fmha(s, h, d):
+        if softmax_scale is None:
+            softmax_scale = 1.0 / (d ** 0.5)
+        rate, seed = _dropout_seed(dropout_rate, dropout_rng)
+        return _fmha_core((float(softmax_scale), rate, interpret), qkv, mask,
+                          seed)
+    q, k, v = (qkv[:, :, j].transpose(0, 2, 1, 3) for j in range(3))
+    ctx = flash_attention(q, k, v, mask, softmax_scale=softmax_scale,
+                          dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+                          interpret=interpret)
+    return ctx.transpose(0, 2, 1, 3).reshape(b, s, h * d)

@@ -381,6 +381,7 @@ def phase_trainer(smoke: Smoke) -> None:
     from apex_tpu import models
     from apex_tpu.models import apply_bert, init_bert, mlm_loss
     from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.transformer.functional.flash_attention import _takes_fmha
 
     since, sz = smoke.cache_counts(), smoke.sizes
     cfg = getattr(models, sz.bert)()
@@ -407,13 +408,18 @@ def phase_trainer(smoke: Smoke) -> None:
         jax.jit(train_step, donate_argnums=(0, 1, 2)).trace(
             *state, ids, mask))
     # every LayerNorm (embeddings, two per layer, the MLM head) and the
-    # loss run as kernels, forward and backward
+    # loss run as kernels, forward and backward; at the full size (s128,
+    # heads of 64) so does each layer's attention, one whole-sequence
+    # forward and ONE backward kernel; the rehearsal's s64 heads of 32
+    # keep to plain XLA
     norms = 2 * cfg.num_layers + 2
     expect = {"apex_ln_fwd": norms, "apex_ln_bwd": norms,
               "apex_xentropy_fwd": 1, "apex_xentropy_bwd": 1}
-    flash = {"apex_flash_fwd", "apex_flash_bwd_dq", "apex_flash_bwd_dkv"}
-    check({k: v for k, v in census.items() if k not in flash} == expect,
-          f"Pallas calls {census} != expected {expect}")
+    fmha = _takes_fmha(sz.bert_seq, cfg.num_heads, cfg.head_dim)
+    if fmha:
+        expect.update(apex_fmha_fwd=cfg.num_layers,
+                      apex_fmha_bwd=cfg.num_layers)
+    check(census == expect, f"Pallas calls {census} != expected {expect}")
 
     *state, loss = compiled(*state, ids, mask)   # warm-up; also step 0
     losses = [float(loss)]
@@ -444,10 +450,11 @@ def phase_trainer(smoke: Smoke) -> None:
         seconds_per_step=round(step_s, 4),
         losses=[round(x, 5) for x in losses], ln_vocab=round(uniform, 4),
         loss_scale=float(scaler.loss_scale), pallas_calls=census,
-        attention=("flash kernel" if flash else
-                   f"XLA path, no flash kernel: at seq {sz.bert_seq} "
-                   "flash_attention keeps to plain XLA (the kernel starts "
-                   "above seq 256)"),
+        attention=("whole-sequence kernel pair (apex_fmha_fwd / "
+                   "apex_fmha_bwd) on the packed projection" if fmha else
+                   f"XLA path: seq {sz.bert_seq} x head width "
+                   f"{cfg.head_dim} is no shape the fmha pair is built for, "
+                   "and the tiled flash kernel starts above seq 256"),
         compiled_bytes=compiled_bytes(compiled))
 
 

@@ -171,17 +171,54 @@ def test_dispatch_paths_agree_with_dropout():
     np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
 
-def test_auto_dispatch_threshold():
-    """Below the crossover the XLA path runs (no pallas_call in the jaxpr);
-    above it the kernel runs."""
-    q, k, v = _qkv(jax.random.PRNGKey(16), 1, 1, 64, 8, jnp.float32)
-    jaxpr = str(jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v))(
-        q, k, v))
-    assert "pallas_call" not in jaxpr
-    q2, k2, v2 = _qkv(jax.random.PRNGKey(17), 1, 1, 512, 8, jnp.float32)
-    jaxpr2 = str(jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v))(
-        q2, k2, v2))
-    assert "pallas_call" in jaxpr2
+def _dispatched(f, *shapes):
+    """Which path a call takes, read off the kernels' names in its jaxpr."""
+    jaxpr = str(jax.make_jaxpr(f)(
+        *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes)))
+    assert ("pallas_call" in jaxpr) == ("apex_f" in jaxpr)
+    return ("fmha" if "apex_fmha_fwd" in jaxpr else
+            "tiled" if "apex_flash_fwd" in jaxpr else "xla")
+
+
+@pytest.mark.parametrize("path, s, d, kw", [
+    # at a padded sequence of 256 and under: plain XLA, whatever the call
+    ("xla", 64, 8, {}), ("xla", 128, 64, {}), ("xla", 256, 64, {}),
+    ("xla", 128, 128, {}), ("xla", 128, 64, dict(causal=True)),
+    ("xla", 256, 64, dict(causal=True)),
+    ("xla", 128, 64, dict(causal=True, window=16)),
+    ("xla", 128, 64, dict(precision=jax.lax.Precision.HIGHEST)),
+    # above it the tiled kernels, bidirectional or not
+    ("tiled", 512, 8, {}), ("tiled", 512, 64, {}),
+    ("tiled", 384, 64, dict(causal=True)),
+    # and a forced path stays forced
+    ("tiled", 128, 64, dict(use_kernel=True)),
+    ("xla", 512, 64, dict(use_kernel=False)),
+], ids=lambda x: x if isinstance(x, (str, int)) else "_".join(x) or "plain")
+def test_auto_dispatch_threshold(path, s, d, kw):
+    """``flash_attention``'s own two paths: below the crossover the XLA
+    path runs (no pallas_call in the jaxpr), above it the tiled kernels. It
+    never takes the whole-sequence pair ``apex_fmha_*`` by itself: on
+    ``(b, h, s, d)`` operands that pair lost to XLA on the chip (PERF.md,
+    PR 41)."""
+    shape = (1, 2, s, d)
+    assert _dispatched(lambda q, k, v: flash_attention(q, k, v, **kw),
+                       shape, shape, shape) == path
+
+
+@pytest.mark.parametrize("path, s, h, d", [
+    ("fmha", 128, 16, 64), ("fmha", 256, 2, 64), ("fmha", 128, 1, 128),
+    ("fmha", 256, 4, 128),
+    ("xla", 64, 2, 64), ("xla", 128, 4, 32), ("xla", 128, 8, 16),
+    ("xla", 128, 3, 64), ("xla", 200, 2, 64),
+    ("tiled", 512, 2, 64)])
+def test_packed_dispatch(path, s, h, d):
+    """``flash_attention_packed`` takes the whole-sequence pair for a
+    projection of one tile (128 or 256 positions) whose heads of 64 or 128
+    fill whole lane blocks: BERT at s128, bidirectional by construction.
+    Any other shape is ``flash_attention``'s."""
+    from apex_tpu.transformer.functional import flash_attention_packed
+
+    assert _dispatched(flash_attention_packed, (2, s, 3, h, d)) == path
 
 
 @pytest.mark.parametrize("s", [384, 1024])

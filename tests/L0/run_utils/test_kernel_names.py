@@ -63,8 +63,8 @@ def test_every_pallas_call_has_a_unique_literal_apex_name_and_scope():
     assert sorted(KERNEL_NAMES) == sorted(names)
     # the names the trace readers and PERF.md lean on
     assert {"apex_ln_fwd", "apex_ln_bwd", "apex_xentropy_fwd",
-            "apex_xentropy_bwd", "apex_flash_fwd",
-            "apex_paged_decode_fwd"} <= set(names)
+            "apex_xentropy_bwd", "apex_flash_fwd", "apex_fmha_fwd",
+            "apex_fmha_bwd", "apex_paged_decode_fwd"} <= set(names)
 
 
 def test_chip_smoke_expects_only_kernels_of_the_list():

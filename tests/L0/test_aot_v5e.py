@@ -103,6 +103,34 @@ def test_flash_attention_fwd_bwd(v5e, b, h, s, d, causal, dropout):
                       ((b, s), jnp.int32)) >= 2
 
 
+@pytest.mark.parametrize("b,s,h,d,dtype,dropout", [
+    (64, 128, 16, 64, jnp.bfloat16, False),     # BERT-Large's projection
+    (64, 128, 16, 64, jnp.bfloat16, True),
+    (16, 256, 16, 64, jnp.bfloat16, False),
+    (8, 256, 8, 128, jnp.float32, False),       # one row fills a step
+    (13, 128, 2, 64, jnp.float32, False),       # rows by fours, ragged
+])
+def test_fmha_fwd_bwd(v5e, b, s, h, d, dtype, dropout):
+    """The whole-sequence pair on the packed projection (``models/bert.py``'s
+    call): one forward and ONE backward kernel, q, k and v thirds of ONE
+    operand, the gradient ONE array of its shape, no score-shaped array and
+    nothing transposed, sliced or concatenated around them."""
+    from apex_tpu.transformer.functional import flash_attention_packed
+
+    def f(qkv, mask):
+        return _sum32(flash_attention_packed(
+            qkv, mask, dropout_rate=0.1,
+            dropout_rng=jax.random.PRNGKey(0) if dropout else None))
+
+    text = compile_text(v5e, jax.grad(f), ((b, s, 3, h, d), dtype),
+                        ((b, s), jnp.int32))
+    assert text.count(MOSAIC_CALL) == 2
+    assert "apex_fmha_fwd" in text and "apex_fmha_bwd" in text
+    assert f"[{b},{h},{s},{s}]" not in text
+    for op in (" transpose(", " concatenate(", " pad("):
+        assert op not in text, op
+
+
 def test_fused_softmax(v5e):
     from apex_tpu.transformer.functional import (
         scaled_masked_softmax, scaled_upper_triang_masked_softmax)
@@ -548,23 +576,31 @@ def test_flat_adam(v5e):
     assert text.count('custom_call_target="tpu_custom_call"') >= 1
 
 
-def test_ddp_bert_step_lowers_for_four_chips(v5e_chips):
+@pytest.mark.parametrize("preset, batch, seq, fmha", [
+    ("bert_tiny", 8, 64, 0), ("bert_large", 256, 128, 24)])
+def test_ddp_bert_step_lowers_for_four_chips(v5e_chips, preset, batch, seq,
+                                             fmha):
     """The multi-chip route: the data-parallel BERT amp-O2 step of
     ``__graft_entry__`` phase 1 and the ``bert_large.pretrain_s128_dp4``
-    cell lowers for four chips through ``shard_map``, kernels and all. The same step as a plain
+    cell (the second case: its own sizes, b64 s128 a chip) lowers for four
+    chips through ``shard_map``, kernels and all: at s128 each layer's
+    attention is ONE ``apex_fmha_fwd`` and ONE ``apex_fmha_bwd`` call and no
+    score-shaped array is in the program. The same step as a plain
     ``jit`` over batch-sharded inputs does not — XLA does not partition a
     Mosaic kernel — which the CPU mesh (interpret-mode kernels) cannot
     show. The day the second half fails, the reason ``shard_map`` is the
     only route is gone."""
-    from apex_tpu import amp
-    from apex_tpu.models import apply_bert, bert_tiny, init_bert, mlm_loss
+    import re
+
+    from apex_tpu import amp, models
+    from apex_tpu.models import apply_bert, init_bert, mlm_loss
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.parallel import DistributedDataParallel
     from apex_tpu.transformer import parallel_state as ps
 
     ps.destroy_model_parallel()
     mesh = ps.initialize_model_parallel(devices=v5e_chips)
-    cfg = bert_tiny()
+    cfg = getattr(models, preset)()
     h = amp.initialize(opt_level="O2", loss_scale="dynamic", verbosity=0)
     opt = FusedAdam(lr=1e-4)
 
@@ -589,7 +625,8 @@ def test_ddp_bert_step_lowers_for_four_chips(v5e_chips):
 
     rep, data = P(), P(ps.DATA_AXIS)
     args = (*placed(jax.eval_shape(make_state), rep),
-            *placed((jax.ShapeDtypeStruct((8, 64), jnp.int32),) * 2, data))
+            *placed((jax.ShapeDtypeStruct((batch, seq), jnp.int32),) * 2,
+                    data))
     try:
         mapped = ps.shard_map(
             functools.partial(step, ddp=DistributedDataParallel()),
@@ -598,6 +635,12 @@ def test_ddp_bert_step_lowers_for_four_chips(v5e_chips):
         text = jax.jit(mapped).lower(*args).compile().as_text()
         assert text.count('custom_call_target="tpu_custom_call"') >= 4
         assert " all-reduce(" in text
+        calls = lambda name: len(re.findall(       # noqa: E731
+            rf"%{name}[.\d]* = [^\n]* custom-call\(", text))
+        assert (calls("apex_fmha_fwd"), calls("apex_fmha_bwd")) \
+            == (fmha, fmha)
+        if fmha:
+            assert f"[{batch // 4},{cfg.num_heads},{seq},{seq}]" not in text
         with pytest.raises(NotImplementedError, match="Mosaic kernels"):
             jax.jit(step).lower(*args)
     finally:
