@@ -936,6 +936,13 @@ def _model_step_entry(family, which):
     pages of the full pool; decode attends over both pools through the paged
     decode kernel, the sliding layers' call bounded below, and donates them
     (the two pools' k/v, lengths, block tables and three counters: 9 pairs).
+    ``ling`` (``models.bailing_hybrid``): prefill runs the chunked delta rule
+    with a decay per key channel (``apex_kda_chunk_fwd``), flash attention
+    over keys and values expanded from the one MLA layer's latent rows, and
+    the grouped expert product; decode steps every slot's state through
+    ``apex_kda_decode_fwd`` and attends over the latent pool through
+    ``apex_mla_decode_fwd``; both donate state, tails and ONE pool (no
+    ``v``), lengths, block tables and three counters: 8 pairs.
     These entries are the kernel families' registration."""
     def build():
         import functools as ft
@@ -959,6 +966,11 @@ def _model_step_entry(family, which):
         elif family == "deepseek":
             from apex_tpu.models.deepseek import deepseek_tiny, init
             cfg, init_cache = deepseek_tiny(), init_latent_cache
+        elif family == "ling":
+            from apex_tpu.models.bailing_hybrid import (
+                bailing_hybrid_tiny, init,
+            )
+            cfg = bailing_hybrid_tiny()
         else:
             from apex_tpu.models.exaone_moe import exaone_moe_tiny, init
             cfg, init_cache = exaone_moe_tiny(), init_window_cache
@@ -1595,6 +1607,16 @@ def repo_entries() -> List[TraceEntry]:
                    _model_step_entry("exaone_moe", "decode"),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=9),
+        TraceEntry("ling_prefill_step",
+                   "apex_tpu.models.bailing_hybrid",
+                   _model_step_entry("ling", "prefill"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=8),
+        TraceEntry("ling_decode_step",
+                   "apex_tpu.transformer.functional.gated_delta",
+                   _model_step_entry("ling", "decode"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=8),
         TraceEntry("gpt_paged_decode_step_tp2", "apex_tpu.serving.decode",
                    _paged_decode_step_entry(tp=2),
                    checks=("precision", "memory", "schedule", "aliases"),

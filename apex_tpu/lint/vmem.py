@@ -534,6 +534,34 @@ def _gated_delta_cfg(which):
     return build
 
 
+def _kda_cfg(which):
+    """The two delta-rule kernels with a decay per key channel (Kimi Delta
+    Attention) at the ``bailing_hybrid`` family's published widths (32 heads
+    of 128 x 128). ``chunk``: the largest bucket; resident are four heads'
+    blocks of one chunk (W_v, W_k, Q, K^T, A, the decay's row of 128, the
+    output) and their state. ``step``: the stacked state of 6 layers x 256
+    slots stays where it is; resident are eight heads of one slot, in and
+    out, and their rows (q, k, beta k and the decay; beta v)."""
+    def build():
+        from apex_tpu.transformer.functional import gated_delta as gd
+
+        if which == "chunk":
+            return gd.gated_delta_chunked, (
+                _sds((32, 4096, 128), "float32"),
+                _sds((32, 4096, 128), "float32"),
+                _sds((32, 4096, 128), "float32"),
+                _sds((32, 4096, 128), "float32"),
+                _sds((32, 4096), "float32"))
+        return gd.gated_delta_step, (
+            _sds((256, 32, 128), "float32"), _sds((256, 32, 128), "float32"),
+            _sds((256, 32, 128), "float32"), _sds((256, 32, 128), "float32"),
+            _sds((256, 32), "float32"),
+            _sds((6, 256, 32, 128, 128), "float32"), _sds((), "int32"),
+            _sds((256,), "bool"))
+
+    return build
+
+
 def _nemotron_kernel_cfg(which):
     """The two kernels of the ``nemotron_h`` family at the published widths.
     ``ssd_step``: the stacked Mamba-2 state of 5 layers x 128 slots (128
@@ -711,6 +739,10 @@ def repo_configs() -> List[Config]:
     cfgs.append(Config("paged_window_decode_236b",
                        "apex_tpu.transformer.functional.paged_attention",
                        _exaone_kernel_cfg()))
+    for which in ("chunk", "step"):
+        cfgs.append(Config(f"kda_{which}_125b",
+                           "apex_tpu.transformer.functional.gated_delta",
+                           _kda_cfg(which)))
     cfgs.append(Config("gpt_spec_verify_step", "apex_tpu.serving.decode",
                        _paged_serving_cfg("verify")))
     cfgs.append(Config("gpt_tree_verify_step", "apex_tpu.serving.decode",

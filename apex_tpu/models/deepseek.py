@@ -158,6 +158,11 @@ class DeepseekConfig:
         stating the pad lets the kernel's DMA move whole tiles)."""
         return -(-self.latent_width // 128) * 128
 
+    def rope_angles(self, pos):
+        """(cos, sin) of the rotary pairs at ``pos``: what the latent
+        attention below asks of a config (YaRN here)."""
+        return _angles(self, pos)
+
     def counter_shapes(self) -> Dict[str, Tuple[int, ...]]:
         """int32 counters the decode program keeps on the device, in the
         donated cache: assignments per held expert and held experts with at
@@ -335,17 +340,43 @@ def _swiglu(gate_up):
     return jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
 
 
+# What follows up to the MLPs is the latent attention itself, for any config
+# that states ``num_heads``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+# ``qk_rope_head_dim``, ``v_head_dim``, ``latent_width``, ``kv_row_width``,
+# ``softmax_scale`` and ``rope_angles(pos)`` (``models.bailing_hybrid`` has no
+# query latent and gates each head's context: it brings its projections and
+# its gate, and everything between them is here).
+
+def latent_row_parts(lp, a, first, cfg, pos):
+    """What a cache row is made of, for the caller to lay side by side, out
+    of a projection ``a`` (rows, ...) that holds ``[c_kv | k_pe]`` from column
+    ``first``: ``c_kv`` after its norm, ``k_pe`` after its rotation at
+    ``pos``, and the zeros that pad the row to ``kv_row_width``; float32."""
+    mid, end = first + cfg.kv_lora_rank, first + cfg.latent_width
+    row = [_rms(lp["kv_norm"], a[:, first:mid], cfg.rms_norm_eps),
+           rope(a[:, mid:end], *cfg.rope_angles(pos))]
+    pad = cfg.kv_row_width - cfg.latent_width
+    if pad:
+        row.append(jnp.zeros((a.shape[0], pad), jnp.float32))
+    return row
+
+
+def split_queries(q, cfg, pos):
+    """``q`` (rows, heads * qk_head_dim) -> ``q_nope`` (rows, heads, nope),
+    roped ``q_pe`` (rows, heads, rope)."""
+    q = q.reshape(q.shape[0], cfg.num_heads, cfg.qk_head_dim)
+    cos, sin = cfg.rope_angles(pos)
+    return q[..., :cfg.qk_nope_head_dim], rope(
+        q[..., cfg.qk_nope_head_dim:], cos[:, None], sin[:, None])
+
+
 def _latents(lp, x, cfg, pos):
     """(rows, hidden) at positions ``pos`` (rows,) -> the normed query latent
     ``c_q`` (rows, q_lora_rank) and the cache row (rows, kv_row_width)
     float32: normed ``c_kv``, roped ``k_pe``, zeros."""
     a = _dense(lp["a_proj"], _rms(lp["norm"], x, cfg.rms_norm_eps))
-    qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
-    row = [_rms(lp["kv_norm"], a[:, qr:qr + kr], cfg.rms_norm_eps),
-           rope(a[:, qr + kr:], *_angles(cfg, pos))]
-    pad = cfg.kv_row_width - cfg.latent_width
-    if pad:
-        row.append(jnp.zeros((x.shape[0], pad), jnp.float32))
+    qr = cfg.q_lora_rank
+    row = latent_row_parts(lp, a, qr, cfg, pos)
     return _rms(lp["q_norm"], a[:, :qr], cfg.rms_norm_eps), \
         jnp.concatenate(row, -1)
 
@@ -353,29 +384,20 @@ def _latents(lp, x, cfg, pos):
 def _queries(lp, c_q, cfg, pos):
     """``c_q`` (rows, q_lora_rank) -> ``q_nope`` (rows, heads, nope), roped
     ``q_pe`` (rows, heads, rope)."""
-    q = _dense(lp["q_b"], c_q).reshape(c_q.shape[0], cfg.num_heads,
-                                       cfg.qk_head_dim)
-    cos, sin = _angles(cfg, pos)
-    return q[..., :cfg.qk_nope_head_dim], rope(
-        q[..., cfg.qk_nope_head_dim:], cos[:, None], sin[:, None])
+    return split_queries(_dense(lp["q_b"], c_q), cfg, pos)
 
 
 # ---------------------------------------------------------------------------
 # attention: expanded over a prompt, absorbed for one token per slot
 # ---------------------------------------------------------------------------
 
-@region("attention")
-def attention_prefill(lp, x, cfg, mask, kv_dtype):
-    """One layer's attention over a prompt: ``x`` (s, hidden). Returns
-    ``(x', rows (s, kv_row_width))``, the rows in ``kv_dtype``, the cache's:
-    keys and values are expanded from THOSE, so the prompt attends to what
-    decode will read. ``v`` is padded to the key's width for
+def expanded_attention(lp, q_nope, q_pe, row, cfg, mask, kv_dtype):
+    """A prompt's contexts ``(s, heads * v_head_dim)`` float32 from its
+    queries and its cache rows ``row`` (s, kv_row_width) in ``kv_dtype``:
+    keys and values are expanded from the ROWS, so the prompt attends to
+    what decode will read. ``v`` is padded to the key's width for
     ``flash_attention``, which takes one width."""
-    s, nh = x.shape[0], cfg.num_heads
-    pos = jnp.arange(s, dtype=jnp.int32)
-    c_q, row = _latents(lp, x, cfg, pos)
-    row = row.astype(kv_dtype)
-    q_nope, q_pe = _queries(lp, c_q, cfg, pos)
+    s, nh = row.shape[0], cfg.num_heads
     kr = cfg.kv_lora_rank
     c_kv, k_pe = row[:, :kr], row[:, kr:cfg.latent_width]
     # rounded to the cache's dtype the latent IS one term of it
@@ -389,26 +411,49 @@ def attention_prefill(lp, x, cfg, mask, kv_dtype):
         *(t.astype(kv_dtype)[None] for t in (q, k, v)), mask[None, :],
         causal=True, softmax_scale=cfg.softmax_scale)[0, :, :,
                                                       :cfg.v_head_dim]
-    ctx = ctx.transpose(1, 0, 2).reshape(s, -1).astype(jnp.float32)
-    return x + _dense(lp["out"], ctx), row
+    return ctx.transpose(1, 0, 2).reshape(s, -1).astype(jnp.float32)
 
 
-@region("attention")
-def attention_decode(lp, x, cfg, pool, layer, block_tables, pos, active):
-    """One token for every slot against the latent pool, read in place by
-    ``apex_mla_decode_fwd``; ``layer`` indexes the pool's leading axis.
-    Returns ``(x', row (slots, kv_row_width))`` for the caller to write at
-    ``pos``. A slot that is not ``active`` reads no page."""
-    c_q, row = _latents(lp, x, cfg, pos)
-    row = row.astype(pool.dtype)
-    q_nope, q_pe = _queries(lp, c_q, cfg, pos)
+def absorbed_attention(lp, q_nope, q_pe, row, cfg, pool, layer, block_tables,
+                       pos, active):
+    """One token's contexts ``(slots, heads, v_head_dim)`` for every slot
+    against the latent pool, read in place by ``apex_mla_decode_fwd``: the
+    queries absorb ``W_kvb``'s key half, the latent contexts pass its value
+    half. ``row`` (slots, kv_row_width) is the new token's, in the pool's
+    dtype. A slot that is not ``active`` reads no page."""
     q_lat = _by_head("bhd,hdc->bhc", q_nope, lp["kv_b_k"], 0)
     q = jnp.concatenate([q_lat, q_pe], -1) * cfg.softmax_scale
     q = jnp.pad(q, ((0, 0), (0, 0), (0, cfg.kv_row_width - q.shape[-1])))
     o_lat = mla_decode_attention(
         q, row, pool, block_tables, jnp.where(active, pos, 0), layer,
         value_width=cfg.kv_lora_rank)
-    ctx = _by_head("bhc,hcd->bhd", o_lat, lp["kv_b_v"], 0)
+    return _by_head("bhc,hcd->bhd", o_lat, lp["kv_b_v"], 0)
+
+
+@region("attention")
+def attention_prefill(lp, x, cfg, mask, kv_dtype):
+    """One layer's attention over a prompt: ``x`` (s, hidden). Returns
+    ``(x', rows (s, kv_row_width))``, the rows in ``kv_dtype``, the cache's
+    (:func:`expanded_attention`)."""
+    pos = jnp.arange(x.shape[0], dtype=jnp.int32)
+    c_q, row = _latents(lp, x, cfg, pos)
+    row = row.astype(kv_dtype)
+    q_nope, q_pe = _queries(lp, c_q, cfg, pos)
+    ctx = expanded_attention(lp, q_nope, q_pe, row, cfg, mask, kv_dtype)
+    return x + _dense(lp["out"], ctx), row
+
+
+@region("attention")
+def attention_decode(lp, x, cfg, pool, layer, block_tables, pos, active):
+    """One token for every slot against the latent pool
+    (:func:`absorbed_attention`); ``layer`` indexes the pool's leading axis.
+    Returns ``(x', row (slots, kv_row_width))`` for the caller to write at
+    ``pos``."""
+    c_q, row = _latents(lp, x, cfg, pos)
+    row = row.astype(pool.dtype)
+    q_nope, q_pe = _queries(lp, c_q, cfg, pos)
+    ctx = absorbed_attention(lp, q_nope, q_pe, row, cfg, pool, layer,
+                             block_tables, pos, active)
     return x + _dense(lp["out"], ctx.reshape(x.shape[0], -1)), row
 
 

@@ -139,9 +139,15 @@ class HybridKVCache(NamedTuple):
     dict of int32 arrays, or nothing) are what a model's decode program
     counts on the device (``cfg.counter_shapes``): they ride the donated
     tuple so that no tick gains a read-back, and are read on request
-    (``PagedDecodeEngine.read_counters``)."""
+    (``PagedDecodeEngine.read_counters``).
+
+    The pool beside the state may be a LATENT one (``cfg.recurrent`` and
+    ``cfg.latent`` both: ``apex_tpu.models.bailing_hybrid``): ``k`` is then
+    the only pool, rows that are key and value at once as in
+    :class:`LatentKVCache`, and ``v`` is ``None`` (a ``None`` leaf vanishes
+    from the pytree: 5 donated leaves and the counters instead of 6)."""
     k: jax.Array             # (L_attn, num_pages, page_size, kv_heads * hd)
-    v: jax.Array
+    v: Optional[jax.Array]   # the same, or None beside a latent pool
     lengths: jax.Array       # (num_slots,) int32
     block_tables: jax.Array  # (num_slots, max_pages) int32
     state: jax.Array         # (L_rec, slots, heads, ...) float32
@@ -304,17 +310,19 @@ def init_hybrid_cache(cfg, num_slots: int, max_len: int, num_pages: int,
                       page_size: int, dtype=jnp.bfloat16) -> HybridKVCache:
     """The two kinds of state of a model with recurrent layers (what
     ``cfg`` states: ``serving.decode``, "the seam"): a page pool over the
-    attention layers only, rows of ``kv_row_width``, and zeroed per-slot
-    recurrent state and convolution tails (float32 both, whatever the
-    pool's ``dtype``) for the recurrent layers; zeroed counters where the
-    model keeps any."""
+    attention layers only, rows of ``kv_row_width`` (ONE pool of them where
+    the model's attention is latent, ``cfg.latent``: ``v`` is left out), and
+    zeroed per-slot recurrent state and convolution tails (float32 both,
+    whatever the pool's ``dtype``) for the recurrent layers; zeroed counters
+    where the model keeps any."""
     _check_pool_sizes(num_slots, max_len, num_pages, page_size)
     if jnp.dtype(dtype) == jnp.int8:
         raise ValueError("no int8 pool beside recurrent state")
     shape = (cfg.kv_layers, num_pages, page_size, cfg.kv_row_width)
     state, conv = cfg.state_shapes(num_slots)
     return HybridKVCache(
-        k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+        k=jnp.zeros(shape, dtype),
+        v=None if getattr(cfg, "latent", False) else jnp.zeros(shape, dtype),
         lengths=jnp.zeros((num_slots,), jnp.int32),
         block_tables=_parked_tables(num_slots, max_len, page_size),
         state=jnp.zeros(state, jnp.float32),

@@ -855,7 +855,9 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
 #   ``decode_core(params, cache, tokens, active)`` -> (x (slots, hidden),
 #       state', conv', counters', k_rows, v_rows (kv_layers, slots, width))
 #   ``logits_of(params, x)``               final norm and head
-# and TWO independent facts about its cache:
+# and TWO independent facts about its cache (both may hold: recurrent state
+# beside ONE latent pool, ``serving.cache.HybridKVCache`` with ``v`` ``None``;
+# every refusal of either fact then applies):
 #   ``recurrent``   it keeps per-slot state beside the pool, whole at every
 #                   moment (``state_shapes(slots)``: recurrent state and
 #                   convolution tail, float32; ``state_bytes_per_slot()``:
@@ -884,8 +886,8 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
 #                   that hold the prompt's last ``ring_pages`` logical pages
 #                   into the slot's cycle; the host never sees that pool.
 # ``models.hybrid`` and ``models.nemotron_h`` (recurrent),
-# ``models.deepseek`` (latent) and ``models.exaone_moe`` (window) stand on
-# it.
+# ``models.deepseek`` (latent), ``models.exaone_moe`` (window) and
+# ``models.bailing_hybrid`` (recurrent AND latent) stand on it.
 
 def model_cores(cfg) -> bool:
     """Does ``cfg`` bring its own prefill and decode cores (the seam)?"""
@@ -979,7 +981,7 @@ def make_model_prefill_fn(cfg):
     """jit(prefill) for a model that brings its cores, cache DONATED (with
     recurrent layers 6 alias pairs: pool k/v, lengths, block tables,
     recurrent state, convolution tails; with a latent pool 3: the pool,
-    lengths, block tables; and one per counter); one executable per bucket,
+    lengths, block tables; with both 5; and one per counter); one executable per bucket,
     and the same program name as every other prefill (``jit_prefill``)."""
 
     def prefill(params, cache, ids, mask, slot, write_pages, table_row):

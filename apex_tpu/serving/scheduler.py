@@ -647,6 +647,22 @@ class DecodeEngine:
 
     # scheduler hooks, no-ops for the dense engine: a cache row needs
     # no per-token capacity and frees by being overwritten
+    def _prefill_bytes(self, private_pages: int) -> Dict[str, int]:
+        """What a prefill of a model that brings its cores writes, by the
+        facts its config states (``serving.decode``, "the seam"): the slot's
+        recurrent state, its cycle of window pages, its private pages of a
+        latent pool; state AND latent pages where both facts hold."""
+        if not self.model_cores:
+            return {}
+        said = {}
+        if self.recurrent:
+            said["state_bytes"] = self._state_bytes
+        if self._window_bytes:
+            said["window_bytes"] = self._window_bytes
+        elif self._latent:
+            said["latent_bytes"] = private_pages * self._page_bytes
+        return said
+
     def page_demand(self, total_len: int) -> None:
         """Validate a request's worst-case capacity need at submit."""
 
@@ -889,6 +905,7 @@ class PagedDecodeEngine(DecodeEngine):
             self._window_bytes = 2 * self.cache.ring * (
                 self.cache.wk.nbytes // self.cache.wk.shape[1]) \
                 if getattr(cfg, "window", 0) else None
+            self._latent = self.cache.v is None
             self._prefill = make_model_prefill_fn(cfg)
             self._decode = make_model_decode_fn(cfg)
             self._chunk_prefill = self._verify = self._tree_verify = None
@@ -1025,11 +1042,7 @@ class PagedDecodeEngine(DecodeEngine):
                   bucket=bucket_for(len(toks) - start, self.buckets),
                   prompt_tokens=len(toks), shared_pages=covered,
                   page_size=self.page_size,
-                  **({"state_bytes": self._state_bytes} if self.recurrent
-                     else {"window_bytes": self._window_bytes}
-                     if self.model_cores and self._window_bytes
-                     else {"latent_bytes": len(private) * self._page_bytes}
-                     if self.model_cores else {}))
+                  **self._prefill_bytes(len(private)))
         if skip:
             ids, mask = _pad_on_host(toks[start:], self.buckets)
             write = np.full((ids.shape[1] // self.page_size,),

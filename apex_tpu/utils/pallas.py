@@ -19,7 +19,8 @@ NEG_INF = -1e30
 # ``chip_smoke.py`` refuses a kernel it does not name.
 KERNEL_NAMES = (
     "apex_flash_bwd_dkv", "apex_flash_bwd_dq", "apex_flash_fwd",
-    "apex_fmha_bwd", "apex_fmha_fwd", "apex_gdn_chunk_fwd", "apex_gdn_decode_fwd", "apex_ln_bwd",
+    "apex_fmha_bwd", "apex_fmha_fwd", "apex_gdn_chunk_fwd", "apex_gdn_decode_fwd",
+    "apex_kda_chunk_fwd", "apex_kda_decode_fwd", "apex_ln_bwd",
     "apex_ln_bwd_coldx", "apex_ln_bwd_colsum", "apex_ln_fwd",
     "apex_mla_decode_fwd", "apex_moe_gmm_fwd", "apex_mt_adagrad",
     "apex_mt_adam", "apex_mt_axpby", "apex_mt_l2norm", "apex_mt_lamb",

@@ -9,6 +9,7 @@ time is spent on it. Seconds per kernel; ``slow``-marked so the unmarked
 tier stays compile-light.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -242,6 +243,37 @@ def test_gated_delta_kernels(v5e):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
+def test_hybrid_decode_runs_no_update_of_its_tails_twice(v5e):
+    """``olmo_hybrid_7b.long_prompt_decode``'s decode program (4 of the 8
+    periods, 16 slots, the full pool of 4096 positions a slot):
+    ``models.hybrid`` shifts each layer's convolution tail by a row and
+    writes it back over the donated buffer (``conv_step``), which is sound
+    as long as the compiler runs no such update a second time from the
+    buffer it has already written
+    (``test_ling_decode_moves_no_row_of_its_tails``): the compiled program
+    holds no rematerialised instruction."""
+    from apex_tpu.models import hybrid
+    from apex_tpu.serving.cache import init_hybrid_cache
+    from apex_tpu.serving.decode import make_model_decode_fn
+
+    slots, max_len, page = 16, 4096, 16
+    cfg = dataclasses.replace(hybrid.olmo_hybrid_7b(), num_periods=4)
+    sharding = SingleDeviceSharding(v5e)
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+    params = on(jax.eval_shape(
+        lambda k: hybrid.init_hybrid(k, cfg, jnp.bfloat16),
+        jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(functools.partial(
+        init_hybrid_cache, cfg, slots, max_len,
+        slots * (max_len // page) + 2, page, jnp.bfloat16)))
+    assert cache.conv.shape == (12, slots, 3, 11520)
+    text = make_model_decode_fn(cfg).lower(
+        params, cache, on(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        on(jax.ShapeDtypeStruct((slots,), jnp.bool_))).compile().as_text()
+    assert "apex_gdn_decode_fwd" in text and ".remat" not in text
+
+
 def test_nemotron_kernels(v5e):
     """The two kernels of the ``nemotron_h`` family at the published widths:
     the Mamba-2 step over the whole stacked state of 5 layers x 128 slots
@@ -333,6 +365,10 @@ def test_nemotron_full_size_programs(v5e):
         got = {k: len(re.findall(rf"%{k}(\.\d+)? = ", text))
                for k in want[name]}
         assert got == want[name], name
+        # the tails are shifted by a row and written back (``conv_step``):
+        # sound as long as no in-place update of them is run a second time
+        # (``test_ling_decode_moves_no_row_of_its_tails``)
+        assert ".remat" not in text, name
         mem = compiled.memory_analysis()
         # the donated cache comes back in place: nothing its size beside it
         # (padded to tiles: a few KB over the arrays' own bytes)
@@ -556,6 +592,218 @@ def test_exaone_full_size_programs(v5e):
               (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30)
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < 0.96 * 16 * 2 ** 30, name
+
+
+def test_ling_kernels(v5e):
+    """Both Kimi-Delta-Attention kernels at the ``ling3_flash_vl`` cell's
+    shapes (32 heads of 128, a decay per key channel): the chunked form over
+    the largest bucket and the step over the whole stacked state of 6 layers
+    x 256 slots, aliased; ``apex_mla_decode_fwd`` at 32 absorbed queries a
+    slot against the ONE layer's pool of 131074 pages, 512 a slot; and the
+    grouped product at the expert's two shapes (hidden 2560, experts of 768)
+    for a decode tick's rows and a prompt block's."""
+    from apex_tpu.transformer.functional import gated_delta as gd
+    from apex_tpu.transformer.functional import moe
+    from apex_tpu.transformer.functional.mla_attention import (
+        mla_decode_attention,
+    )
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    text = compile_text(
+        v5e, gd.gated_delta_chunked, ((32, 4096, 128), f32),
+        ((32, 4096, 128), f32), ((32, 4096, 128), f32),
+        ((32, 4096, 128), f32), ((32, 4096), f32))
+    assert text.count(MOSAIC_CALL) == 1 and "apex_kda_chunk_fwd" in text
+    sharding = SingleDeviceSharding(v5e)
+    shapes = [((256, 32, 128), f32), ((256, 32, 128), f32),
+              ((256, 32, 128), f32), ((256, 32, 128), f32), ((256, 32), f32),
+              ((6, 256, 32, 128, 128), f32), ((), jnp.int32),
+              ((256,), jnp.bool_)]
+    compiled = jax.jit(gd.gated_delta_step, donate_argnums=5).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(MOSAIC_CALL) == 1 and "apex_kda_decode_fwd" in text
+    # the state comes back in the buffer it came in: nothing its size is
+    # allocated beside it
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 6 * 256 * 32 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert compile_on(
+        v5e, functools.partial(mla_decode_attention, value_width=512),
+        ((256, 32, 640), f32), ((256, 640), f32),
+        ((1, 131074, 16, 640), bf16), ((256, 512), jnp.int32),
+        ((256,), jnp.int32), ((), jnp.int32)) == 1
+    assert moe._column_tile(2560, 1536, 2) == 512       # 2.6 MB a tile
+    assert moe._column_tile(768, 2560, 2) == 1280
+    for rows in (256 * 8, 1024 * 8):
+        assert compile_on(
+            v5e, moe.grouped_matmul, ((rows, 2560), f32),
+            ((64, 2560, 1536), bf16), ((64,), jnp.int32)) == 1
+        assert compile_on(
+            v5e, moe.grouped_matmul, ((rows, 768), f32),
+            ((64, 768, 2560), bf16), ((64,), jnp.int32)) == 1
+
+
+LING_SLOTS = 256
+
+
+def ling_full_size(v5e):
+    """``(cfg, params, cache, sds)`` of the ``ling3_flash_vl`` cell as shapes
+    on ``v5e``: layer 1 dense and layers 2-7 sparse, 64 of 512 experts, an
+    eighth of the vocabulary, 256 slots, the full pool."""
+    from apex_tpu.models import bailing_hybrid as bh
+    from apex_tpu.serving.cache import init_hybrid_cache
+
+    slots, max_len, page = LING_SLOTS, 8192, 16
+    cfg = bh.BailingHybridConfig(
+        vocab_size=19648, layer_types=bh.layer_types_of(1, 7, 6),
+        first_k_dense=1, experts_held=64)
+    sharding = SingleDeviceSharding(v5e)
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+    params = on(jax.eval_shape(lambda k: bh.init(k, cfg, jnp.bfloat16),
+                               jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(functools.partial(
+        init_hybrid_cache, cfg, slots, max_len,
+        slots * (max_len // page) + 2, page, jnp.bfloat16)))
+    return cfg, params, cache, lambda s, d: jax.ShapeDtypeStruct(
+        s, d, sharding=sharding)
+
+
+def test_ling_full_size_programs(v5e):
+    """The programs of ``ling3_flash_vl.many_stream_reasoning`` at full size
+    (layer 1 dense and layers 2-7 sparse, ``K | K K K M K K``; 64 of 512
+    experts, an eighth of the vocabulary, 256 slots, the full pool of 8192
+    positions a slot): decode and every prefill bucket compile for a v5e with
+    no chip; ``memory_analysis`` gives what the configuration file says (5.73
+    GB of weights; 3.22 GB of state, the tails and 2.68 GB of latents in ONE
+    pool, all of it aliased: 5 leaves and 3 counters) and fits the chip; the
+    kernel names are the engagement counters the trace readers count."""
+    import re
+
+    from apex_tpu.serving.decode import (
+        make_model_decode_fn, make_model_prefill_fn,
+    )
+
+    slots, max_len, page = LING_SLOTS, 8192, 16
+    cfg, params, cache, sds = ling_full_size(v5e)
+    size = lambda tree: sum(a.size * a.dtype.itemsize
+                            for a in jax.tree.leaves(tree))
+    matrices = sum(a.size for a in jax.tree.leaves(params)
+                   if a.dtype == jnp.bfloat16 and a.ndim >= 2) \
+        - 6 * 4 * 12288                     # the convolutions' taps
+    assert matrices == 2_865_905_664
+    assert round(size(params) / 1e9, 2) == 5.73
+    assert cache.v is None and cache.k.shape == (1, 131074, 16, 640)
+    assert cache.state.shape == (6, slots, 32, 128, 128)
+    assert cache.conv.shape == (6, slots, 3, 12288)
+    assert round(cache.state.size * 4 / 1e9, 2) == 3.22
+    assert round(cache.k.size * 2 / 1e9, 2) == 2.68
+    assert len(jax.tree.leaves(cache)) == 5 + 3
+    i32 = jnp.int32
+    programs = {"decode": make_model_decode_fn(cfg).lower(
+        params, cache, sds((slots,), i32), sds((slots,), jnp.bool_))}
+    for bucket in (1024, 2048, 4096):
+        programs[f"prefill_{bucket}"] = make_model_prefill_fn(cfg).lower(
+            params, cache, sds((1, bucket), i32), sds((bucket,), i32),
+            sds((), i32), sds((bucket // page,), i32),
+            sds((max_len // page,), i32))
+    # the layers are unrolled: every call stands in the program's text
+    want = {"decode": {"apex_kda_decode_fwd": 6, "apex_kda_chunk_fwd": 0,
+                       "apex_mla_decode_fwd": 1, "apex_moe_gmm_fwd": 12,
+                       "apex_flash_fwd": 0, "apex_gdn_decode_fwd": 0},
+            "prefill": {"apex_kda_decode_fwd": 0, "apex_kda_chunk_fwd": 6,
+                        "apex_mla_decode_fwd": 0, "apex_moe_gmm_fwd": 12,
+                        "apex_flash_fwd": 1, "apex_gdn_chunk_fwd": 0}}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        kind = name.split("_")[0]
+        got = {k: len(re.findall(rf"%{k}(\.\d+)? = ", text))
+               for k in want[kind]}
+        assert got == want[kind], name
+        mem = compiled.memory_analysis()
+        print(name, "args", mem.argument_size_in_bytes / 1e9, "temp",
+              mem.temp_size_in_bytes / 1e9, "alias",
+              mem.alias_size_in_bytes / 1e9, "cache", size(cache) / 1e9,
+              (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30)
+        assert mem.alias_size_in_bytes >= size(cache), name
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 0.96 * 16 * 2 ** 30, name
+
+
+def moved_rows(text, shape):
+    """Of the compiled program ``text``, the lines of every fusion that
+    writes a buffer of ``shape`` (its root a ``dynamic-update-slice`` of one)
+    which MOVE data within it: a pad, a concatenation, or a slice that does
+    not start at 0 and span the whole of every dimension after the first.
+    Such a fusion reads a row where it does not write it: written in place,
+    or run twice, it can read what it has already overwritten."""
+    import re
+
+    said = "f32[" + ",".join(map(str, shape)) + "]"
+    found, body = [], []
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            body = []           # a computation's header
+        body.append(line)
+        if line.startswith("}") and any(
+                l.lstrip().startswith("ROOT") and "dynamic-update-slice("
+                in l and f"= {said}" in l for l in body):
+            for l in body:
+                whole = all(
+                    (int(a), int(b)) == (0, n) for (a, b), n in zip(
+                        re.findall(r"\[(\d+):(\d+)\]", l)[1 - len(shape):],
+                        shape[1:]))
+                if " pad(" in l or " concatenate(" in l \
+                        or (" slice(" in l and not whole):
+                    found.append(l.strip()[:160])
+    return found
+
+
+def test_ling_decode_moves_no_row_of_its_tails(v5e, monkeypatch):
+    """The convolution tails are donated and updated in place, a layer at a
+    time, and at this size the compiler REMATERIALISES the first layer's
+    update: it runs it twice, the second time from the buffer the first has
+    already written. The ring update (``conv_ring_step``) is elementwise in
+    the tail, so neither the in-place write nor the second run can change
+    what it reads. The tail SHIFTED by a row and written back
+    (``conv_step``, as the first full-size run of the cell had it: NOT
+    ``correct``, the first KDA layer's tail shifted twice; my chip run, PR
+    42) is what this check refuses."""
+    from jax import lax
+
+    from apex_tpu.models import bailing_hybrid as bh
+    from apex_tpu.serving.decode import make_model_decode_fn
+    from apex_tpu.transformer.functional.gated_delta import (
+        conv_step, gated_delta_step,
+    )
+
+    cfg, params, cache, sds = ling_full_size(v5e)
+    args = (params, cache, sds((LING_SLOTS,), jnp.int32),
+            sds((LING_SLOTS,), jnp.bool_))
+    text = make_model_decode_fn(cfg).lower(*args).compile().as_text()
+    assert moved_rows(text, cache.conv.shape) == []
+
+    def shifted(lp, x, cfg, state, conv, layer, pos, active):
+        conv_in, log_decay, gate, beta = bh._kda_in(lp, x, cfg)
+        tail = lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
+        conv_out, new = conv_step(
+            conv_in, tail, lp["conv"]["weight"].astype(jnp.float32))
+        conv = lax.dynamic_update_index_in_dim(
+            conv, jnp.where(active[:, None, None], new, tail), layer, 0)
+        q, k, v = bh._kda_heads(conv_out, cfg)
+        o, state = gated_delta_step(q, k, v, log_decay, beta, state,
+                                    jnp.int32(layer), active)
+        return x + bh._kda_out(lp, o, gate, cfg), state, conv
+
+    monkeypatch.setattr(bh, "kda_decode", shifted)
+    text = make_model_decode_fn(cfg).lower(*args).compile().as_text()
+    assert len(moved_rows(text, cache.conv.shape)) >= 6
+    said = "f32[" + ",".join(map(str, cache.conv.shape)) + "]"
+    assert any(".remat" in l.split(" = ")[0] and f"= {said}" in l
+               for l in text.splitlines())
 
 
 def test_flat_adam(v5e):

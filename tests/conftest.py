@@ -146,6 +146,33 @@ NEW_CASE_OF_A_PINNED_TEST[
     "asserts SERVING == the three serving cells PR 32 knew"
 
 
+# PR 42 (Ling-3.0-flash-VL) appends a configuration, a cell, four readers,
+# and its cell to the lists of nineteen readers that read it unedited. Two
+# ids of ``tests/L0/run_benchmark/test_exaone_cell.py`` pin what that breaks:
+# PR 40's cell as the LAST name of every list that names it, and what may
+# stand behind PR 38's cells in its twenty lists as nothing or PR 40's cell.
+# STRICT, as above; all they check besides their pins is asserted again, by
+# name, in ``tests/L0/run_benchmark/test_ling_cell.py``
+# (``test_what_the_two_pinned_tests_of_test_exaone_cell_check_besides``,
+# ``test_manifest_holds_the_configuration_the_cell_and_its_readers_by_name``).
+# 29 expected failures in all since PR 42 (26 before).
+_EXAONE = "tests/L0/run_benchmark/test_exaone_cell.py::"
+PINNED_BY_PR_40 = {
+    _EXAONE
+    + "test_manifest_holds_the_configuration_the_cell_and_its_readers_by_name":
+        "asserts PR 40's cell is the LAST name of every list that names it; "
+        "PR 42 appends its cell to sixteen of those lists",
+    _EXAONE + "test_what_the_two_pinned_tests_of_test_regions_check_besides":
+        "asserts nothing but PR 40's cell stands behind PR 38's cells in its "
+        "twenty lists; PR 42's cell reads six decode_ms.* readers too",
+}
+NEW_CASE_OF_A_PINNED_TEST[
+    "tests/L0/run_benchmark/test_rehearsal.py::"
+    "test_window_line_says_what_is_left_of_the_backlog"
+    "[ling3_flash_vl.many_stream_reasoning]"] = \
+    "asserts SERVING == the three serving cells PR 32 knew"
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         p = item.path
@@ -157,6 +184,9 @@ def pytest_collection_modifyitems(config, items):
         elif item.nodeid in PINNED_BY_PR_38:
             item.add_marker(pytest.mark.xfail(
                 reason=PINNED_BY_PR_38[item.nodeid], strict=True))
+        elif item.nodeid in PINNED_BY_PR_40:
+            item.add_marker(pytest.mark.xfail(
+                reason=PINNED_BY_PR_40[item.nodeid], strict=True))
         elif item.nodeid in PINNED_BY_PR_33:
             item.add_marker(pytest.mark.xfail(
                 reason=PINNED_BY_PR_33[item.nodeid], strict=True))
