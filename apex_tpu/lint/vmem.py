@@ -592,7 +592,11 @@ def _deepseek_kernel_cfg(which):
     """The kernels of the ``deepseek_v3`` family at the published widths.
     ``mla``: 128 absorbed queries of 640 a slot over the ONE latent pool of
     5 layers x 25602 pages of 16 rows x 640, which stays where it is;
-    resident are two chunks of 256 rows, the queries and the latent context.
+    resident are a ring of eight blocks of 256 rows (16 pages, 320 KB each:
+    2.5 MiB), the queries and the latent context; beside them the compiler
+    keeps the stacked two-term query (256 x 640 bfloat16) and one block's
+    scores (256 x 256 float32, and the next block's beside them), 0.8 MiB
+    the estimator does not see.
     ``gate_up`` / ``down``: the grouped product over the SwiGLU expert's two
     matrices at hidden 7168 (16 held experts of 4 layers, read in place out
     of the stack), one decode tick's 64 x 8 assignments; resident are a row

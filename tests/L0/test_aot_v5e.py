@@ -63,6 +63,72 @@ def compile_on(device, fn, *shapes):
     return compile_text(device, fn, *shapes).count(MOSAIC_CALL)
 
 
+def mosaic_modules(lowered_text, kernel):
+    """``(bytes, tpu.matmul operations, tpu.enqueue_dma operations)`` of
+    each Mosaic module named ``kernel`` in a LOWERED program's text (the
+    module rides in the custom call's ``backend_config`` as base64 of MLIR
+    bytecode). Tracing a kernel and lowering Pallas to this module is work no
+    compile cache saves a server's start (the cache's key is the lowered
+    text), and every copy of a kernel's body, or of its descriptor code,
+    shows here again."""
+    import base64
+    import re
+
+    from jaxlib.mlir import ir
+
+    found = []
+    for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)', lowered_text):
+        raw = base64.b64decode(body)
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(raw).operation.get_asm()
+        if kernel in asm:
+            found.append((len(raw), asm.count("tpu.matmul"),
+                          asm.count("tpu.enqueue_dma")))
+    return found
+
+
+# ``apex_mla_decode_fwd``'s set-up budget, which a server's every start pays
+# in tracing, lowering and loading. Products: scores and values, in an
+# unmasked and a masked body, and not once more (a ``switch`` over live
+# lengths or an unrolled loop over blocks multiplies this number: PR 44's
+# form held 16). Descriptor starts: a block's 16 pages straight-line in the
+# loop and beside the last block, and one rolled up (PR 45's first form
+# started chunks at six places: 30, and +2 s of every start). Bytes: the
+# parent's module was 11 KB, this one is 21, PR 45's first form 33.
+MLA_DECODE_MATMULS = 2 * 2
+MLA_DECODE_STARTS = 2 * 16 + 1
+MLA_DECODE_MODULE_BYTES = 24 << 10
+
+
+def within_mla_budget(lowered_text):
+    """The lowered program holds the kernel's module ONCE, however often it
+    calls it (the call is a ``jit`` of its own: traced and lowered once a
+    program), and inside the set-up budget."""
+    modules = mosaic_modules(lowered_text, "apex_mla_decode_fwd")
+    assert len(modules) == 1, modules
+    ((size, matmuls, starts),) = modules
+    assert 2 <= matmuls <= MLA_DECODE_MATMULS, modules
+    assert starts <= MLA_DECODE_STARTS, modules
+    assert size <= MLA_DECODE_MODULE_BYTES, modules
+
+
+def check_mla_decode(device, *shapes):
+    """``mla_decode_attention`` at ``shapes`` compiles for ``device`` to one
+    Mosaic call whose lowered module keeps the set-up budget."""
+    from apex_tpu.transformer.functional.mla_attention import (
+        mla_decode_attention,
+    )
+
+    sharding = SingleDeviceSharding(device)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    lowered = jax.jit(functools.partial(
+        mla_decode_attention, value_width=512)).lower(*args)
+    assert lowered.compile().as_text().count(MOSAIC_CALL) == 1
+    within_mla_budget(lowered.as_text())
+
+
 def _sum32(x):
     return jnp.sum(x.astype(jnp.float32))
 
@@ -385,16 +451,12 @@ def test_deepseek_kernels(v5e):
     two shapes (the fused gate and up matrix at hidden 7168, where a column
     tile is 256 wide), for a decode tick's rows and a prompt block's."""
     from apex_tpu.transformer.functional import moe
-    from apex_tpu.transformer.functional.mla_attention import (
-        mla_decode_attention,
-    )
 
     f32, bf16 = jnp.float32, jnp.bfloat16
-    assert compile_on(
-        v5e, functools.partial(mla_decode_attention, value_width=512),
-        ((64, 128, 640), f32), ((64, 640), f32),
+    check_mla_decode(
+        v5e, ((64, 128, 640), f32), ((64, 640), f32),
         ((5, 25602, 16, 640), bf16), ((64, 400), jnp.int32),
-        ((64,), jnp.int32), ((), jnp.int32)) == 1
+        ((64,), jnp.int32), ((), jnp.int32))
     assert moe._column_tile(7168, 4096, 2) == 256       # 3.7 MB a tile
     assert moe._column_tile(2048, 7168, 2) == 512
     # a scanned layer's experts, read in place out of the four layers' stack
@@ -452,6 +514,9 @@ def test_deepseek_full_size_programs(v5e):
             params, cache, sds((1, bucket), i32), sds((bucket,), i32),
             sds((), i32), sds((bucket // page,), i32),
             sds((max_len // page,), i32))
+    # the set-up budget: the dense scan's call and the expert scan's are one
+    # function of the lowered module (the compiled program holds both: below)
+    within_mla_budget(programs["decode"].as_text())
     # a scanned layer's kernels stand once in the program's text
     want = {"decode": {"apex_mla_decode_fwd": 2, "apex_moe_gmm_fwd": 2,
                        "apex_flash_fwd": 0},
@@ -604,9 +669,6 @@ def test_ling_kernels(v5e):
     for a decode tick's rows and a prompt block's."""
     from apex_tpu.transformer.functional import gated_delta as gd
     from apex_tpu.transformer.functional import moe
-    from apex_tpu.transformer.functional.mla_attention import (
-        mla_decode_attention,
-    )
 
     f32, bf16 = jnp.float32, jnp.bfloat16
     text = compile_text(
@@ -629,11 +691,10 @@ def test_ling_kernels(v5e):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 6 * 256 * 32 * 128 * 128 * 4
     assert mem.temp_size_in_bytes < 64 << 20
-    assert compile_on(
-        v5e, functools.partial(mla_decode_attention, value_width=512),
-        ((256, 32, 640), f32), ((256, 640), f32),
+    check_mla_decode(
+        v5e, ((256, 32, 640), f32), ((256, 640), f32),
         ((1, 131074, 16, 640), bf16), ((256, 512), jnp.int32),
-        ((256,), jnp.int32), ((), jnp.int32)) == 1
+        ((256,), jnp.int32), ((), jnp.int32))
     assert moe._column_tile(2560, 1536, 2) == 512       # 2.6 MB a tile
     assert moe._column_tile(768, 2560, 2) == 1280
     for rows in (256 * 8, 1024 * 8):
@@ -709,6 +770,7 @@ def test_ling_full_size_programs(v5e):
             params, cache, sds((1, bucket), i32), sds((bucket,), i32),
             sds((), i32), sds((bucket // page,), i32),
             sds((max_len // page,), i32))
+    within_mla_budget(programs["decode"].as_text())
     # the layers are unrolled: every call stands in the program's text
     want = {"decode": {"apex_kda_decode_fwd": 6, "apex_kda_chunk_fwd": 0,
                        "apex_mla_decode_fwd": 1, "apex_moe_gmm_fwd": 12,
