@@ -296,12 +296,12 @@ def _draft_medium_trees():
     import jax
 
     from apex_tpu.models.gpt import draft_gpt_medium, init_gpt
-    from apex_tpu.serving.cache import init_cache
+    from apex_tpu.serving.draft_model import init_draft_cache
 
     cfg = draft_gpt_medium()
     params = jax.eval_shape(
         lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0))
-    cache = jax.eval_shape(ft.partial(init_cache, cfg, 2, 37))
+    cache = jax.eval_shape(ft.partial(init_draft_cache, cfg, 2, 37))
     return {"params": params, "kv_cache": cache}
 
 

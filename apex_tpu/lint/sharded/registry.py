@@ -130,24 +130,21 @@ def _gpt_trees():
     import jax
 
     from apex_tpu.models.gpt import gpt_tiny, init_gpt
-    from apex_tpu.serving.cache import init_cache
+    from apex_tpu.serving.cache import init_paged_cache
 
     cfg = gpt_tiny()
     params = jax.eval_shape(
         lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0))
-    # the paged pool has its own k/v rule (heads merged into the last
-    # axis): the quant entry's table and trees cover it
-    cache = jax.eval_shape(ft.partial(init_cache, cfg, 2, 32))
+    cache = jax.eval_shape(ft.partial(init_paged_cache, cfg, 2, 32, 6, 16))
     return {"params": params, "kv_cache": cache}
 
 
 def _gpt_reference():
     from apex_tpu.models.gpt import gpt_partition_specs, gpt_tiny
-    from apex_tpu.partition import kv_cache_rules
-    from apex_tpu.serving.cache import cache_partition_specs
+    from apex_tpu.serving.cache import paged_cache_partition_specs
 
     return {"params": gpt_partition_specs(gpt_tiny()),
-            "kv_cache": cache_partition_specs(kv_cache_rules())}
+            "kv_cache": paged_cache_partition_specs()}
 
 
 def _gpt_quant_trees():
@@ -186,30 +183,29 @@ def _gpt_quant_reference():
 
 def _draft_trees():
     """The speculative drafter's trees: a RoPE-only param tree (no
-    position leaf) and the DENSE lockstep cache (engine max_len 32 plus
-    DraftModel's catch-up chunk of 5) — exactly what draft_gpt_rules
-    must cover with no dead rows."""
+    position leaf) and the lockstep cache (engine max_len 32 plus
+    DraftModel's catch-up chunk of 5, under its identity table) —
+    exactly what draft_gpt_rules must cover with no dead rows."""
     import functools as ft
 
     import jax
 
     from apex_tpu.models.gpt import draft_gpt_tiny, init_gpt
-    from apex_tpu.serving.cache import init_cache
+    from apex_tpu.serving.draft_model import init_draft_cache
 
     cfg = draft_gpt_tiny()
     params = jax.eval_shape(
         lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0))
-    cache = jax.eval_shape(ft.partial(init_cache, cfg, 2, 37))
+    cache = jax.eval_shape(ft.partial(init_draft_cache, cfg, 2, 37))
     return {"params": params, "kv_cache": cache}
 
 
 def _draft_reference():
     from apex_tpu.models.gpt import draft_gpt_tiny, gpt_partition_specs
-    from apex_tpu.partition import kv_cache_rules
-    from apex_tpu.serving.cache import cache_partition_specs
+    from apex_tpu.serving.cache import paged_cache_partition_specs
 
     return {"params": gpt_partition_specs(draft_gpt_tiny()),
-            "kv_cache": cache_partition_specs(kv_cache_rules())}
+            "kv_cache": paged_cache_partition_specs()}
 
 
 def repo_entries() -> List[ShardedEntry]:
@@ -235,8 +231,8 @@ def repo_entries() -> List[ShardedEntry]:
             kv_cache_tree="paged_kv_cache",
             qkv_kernel_re=r"layers/qkv/kernel"),
         # the speculative drafter: same mesh and layout as the target
-        # minus the rows its trees can never match (position table,
-        # block tables); no optimizer families (inference-only). The kv
+        # minus the row its trees can never match (the position
+        # table); no optimizer families (inference-only). The kv
         # consistency check pins the lockstep cache's head axis to the
         # draft qkv column shard — the invariant that lets the drafter
         # run TP on the target's mesh without a resharding hop.

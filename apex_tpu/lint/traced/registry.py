@@ -570,81 +570,6 @@ def _serving_cfg():
     return dataclasses.replace(gpt_tiny(), use_rope=True)
 
 
-def _serving_args(cfg, num_slots=2, max_len=32):
-    import functools as ft
-
-    import jax
-
-    from apex_tpu.models.gpt import init_gpt
-    from apex_tpu.serving.cache import init_cache
-
-    params = jax.eval_shape(
-        lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0))
-    cache = jax.eval_shape(ft.partial(init_cache, cfg, num_slots, max_len))
-    return params, cache
-
-
-def _prefill_step_entry():
-    def build():
-        from apex_tpu.serving.decode import make_prefill_fn
-
-        cfg = _serving_cfg()
-        params, cache = _serving_args(cfg)
-        fn = make_prefill_fn(cfg)
-        return fn, (params, cache, _sds((1, 16), "int32"),
-                    _sds((16,), "int32"), _sds((), "int32"))
-
-    return build
-
-
-def _decode_step_entry(tp=None):
-    def build():
-        from apex_tpu.serving.decode import make_decode_fn, make_tp_decode_fn
-
-        cfg = _serving_cfg()
-        params, cache = _serving_args(cfg)
-        if tp is None:
-            fn = make_decode_fn(cfg)
-        else:
-            from apex_tpu.models.gpt import GPTModel
-
-            fn = make_tp_decode_fn(GPTModel(cfg, tp_size=tp))
-        return fn, (params, cache, _sds((2,), "int32"), _sds((2,), "bool"))
-
-    return build
-
-
-def _prefill_step_bucketed_entry():
-    """The ContinuousBatchingScheduler prefill path: a prompt padded up
-    to the 32-token bucket rung, 4-slot pool (scheduler._pad_on_host
-    + DecodeEngine per-bucket jitted step)."""
-    def build():
-        from apex_tpu.serving.decode import make_prefill_fn
-
-        cfg = _serving_cfg()
-        params, cache = _serving_args(cfg, num_slots=4, max_len=64)
-        fn = make_prefill_fn(cfg)
-        return fn, (params, cache, _sds((1, 32), "int32"),
-                    _sds((32,), "int32"), _sds((), "int32"))
-
-    return build
-
-
-def _decode_step_learned_pos_entry():
-    """Decode without RoPE — the learned-position-table gather variant
-    of _block_decode (gpt_tiny defaults to use_rope=False)."""
-    def build():
-        from apex_tpu.models.gpt import gpt_tiny
-        from apex_tpu.serving.decode import make_decode_fn
-
-        cfg = gpt_tiny()
-        params, cache = _serving_args(cfg)
-        fn = make_decode_fn(cfg)
-        return fn, (params, cache, _sds((2,), "int32"), _sds((2,), "bool"))
-
-    return build
-
-
 def _paged_serving_args(cfg, num_slots=2, max_len=32, num_pages=6,
                         page_size=16):
     import functools as ft
@@ -674,24 +599,6 @@ def _paged_prefill_step_entry():
         return fn, (params, cache, _sds((1, 16), "int32"),
                     _sds((16,), "int32"), _sds((), "int32"),
                     _sds((1,), "int32"), _sds((2,), "int32"))
-
-    return build
-
-
-def _chunk_prefill_step_entry():
-    """Dense chunked prefill: one 16-token prompt chunk written at a
-    dynamic start row (the scheduler's chunk_tokens bucket — exactly
-    one executable per chunk size). Same 3-leaf cache donation as the
-    monolithic prefill step."""
-    def build():
-        from apex_tpu.serving.decode import make_chunk_prefill_fn
-
-        cfg = _serving_cfg()
-        params, cache = _serving_args(cfg)
-        fn = make_chunk_prefill_fn(cfg)
-        return fn, (params, cache, _sds((1, 16), "int32"),
-                    _sds((16,), "int32"), _sds((), "int32"),
-                    _sds((), "int32"))
 
     return build
 
@@ -956,7 +863,7 @@ def _model_step_entry(family, which):
             make_model_decode_fn, make_model_prefill_fn,
         )
 
-        init_cache = init_hybrid_cache
+        init_pools = init_hybrid_cache
         if family == "hybrid":
             from apex_tpu.models.hybrid import hybrid_tiny, init_hybrid
             cfg, init = hybrid_tiny(), init_hybrid
@@ -965,7 +872,7 @@ def _model_step_entry(family, which):
             cfg = nemotron_h_tiny()
         elif family == "deepseek":
             from apex_tpu.models.deepseek import deepseek_tiny, init
-            cfg, init_cache = deepseek_tiny(), init_latent_cache
+            cfg, init_pools = deepseek_tiny(), init_latent_cache
         elif family == "ling":
             from apex_tpu.models.bailing_hybrid import (
                 bailing_hybrid_tiny, init,
@@ -973,10 +880,10 @@ def _model_step_entry(family, which):
             cfg = bailing_hybrid_tiny()
         else:
             from apex_tpu.models.exaone_moe import exaone_moe_tiny, init
-            cfg, init_cache = exaone_moe_tiny(), init_window_cache
+            cfg, init_pools = exaone_moe_tiny(), init_window_cache
         params = jax.eval_shape(
             lambda k: init(k, cfg), jax.random.PRNGKey(0))
-        cache = jax.eval_shape(ft.partial(init_cache, cfg, 2, 32, 6, 16))
+        cache = jax.eval_shape(ft.partial(init_pools, cfg, 2, 32, 6, 16))
         if which == "prefill":
             return make_model_prefill_fn(cfg), (
                 params, cache, _sds((1, 16), "int32"), _sds((16,), "int32"),
@@ -988,12 +895,12 @@ def _model_step_entry(family, which):
 
 
 def _paged_decode_step_medium_ragged_entry():
-    """The r10 paged counterpart of ``gpt_decode_step_medium``: same r8
-    model shape and 32 slots, but the pool is sized to a RAGGED length
-    ladder (uniform 32..512, page size 64) — Σ ceil(len/64) pages plus
-    the two reserved ones — so the cost tier's K/V read term is
-    proportional to tokens actually held instead of slots x S_max.
-    Cost-tier only, like the dense medium entry."""
+    """The decode roofline shape: gpt_medium-class decode, bf16 params,
+    32 slots, the pool sized to a RAGGED length ladder (uniform 32..512,
+    page size 64) — Σ ceil(len/64) pages plus the two reserved ones — so
+    the cost tier's K/V read term is proportional to tokens actually
+    held, not slots x S_max. Cost-tier only — APX5xx already runs on the
+    tiny-shape decode entries."""
     def build():
         import functools as ft
 
@@ -1103,9 +1010,9 @@ def _tree_verify_step_entry(tp=None):
 
 def _draft_forward_step_entry():
     """The draft-forward anchor: ``draft_gpt_medium`` decoding one
-    greedy token per slot through its dense lockstep cache — 32 slots
-    at the target's s_max = 512 plus DraftModel's chunk = 5 catch-up
-    headroom, bf16 params. Its budgets.json row is the ``draft_bytes``
+    greedy token per slot through its lockstep cache (the drafter's own
+    pool under its identity table) — 32 slots at the target's s_max =
+    512 plus DraftModel's chunk = 5 catch-up headroom, bf16 params. Its budgets.json row is the ``draft_bytes``
     numerator of the model-draft break-even condition; the ceiling is
     hand-tightened to < 3% of the target's per-step parameter read
     (the ``gpt_paged_decode_step_medium_ragged`` row)."""
@@ -1116,14 +1023,15 @@ def _draft_forward_step_entry():
         import jax.numpy as jnp
 
         from apex_tpu.models.gpt import draft_gpt_medium, init_gpt
-        from apex_tpu.serving.cache import init_cache
-        from apex_tpu.serving.decode import make_decode_fn
+        from apex_tpu.serving.decode import make_paged_decode_fn
+        from apex_tpu.serving.draft_model import init_draft_cache
 
         cfg = draft_gpt_medium()
         params = jax.eval_shape(
             lambda k: init_gpt(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0))
-        cache = jax.eval_shape(ft.partial(init_cache, cfg, 32, 512 + 5))
-        fn = make_decode_fn(cfg)
+        cache = jax.eval_shape(ft.partial(init_draft_cache, cfg, 32,
+                                          512 + 5))
+        fn = make_paged_decode_fn(cfg)
         return fn, (params, cache, _sds((32,), "int32"),
                     _sds((32,), "bool"))
 
@@ -1198,28 +1106,6 @@ def _quant_paged_step_entry(which):
     return build
 
 
-def _w8_decode_step_tp2_entry():
-    """Dense-cache decode under tp2 with int8 weights: the quantized
-    tree shards by ``quant_partition_specs`` (scale specs derived from
-    the bf16 table), the schedule check pins the collective order of
-    the dequant-fused column/row/logits applies."""
-    def build():
-        import jax
-
-        from apex_tpu.models.gpt import GPTModel, init_gpt
-        from apex_tpu.quant.params import quantize_params
-        from apex_tpu.serving.decode import make_tp_decode_fn
-
-        cfg = _serving_cfg()
-        params = quantize_params(jax.eval_shape(
-            lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0)))
-        _, cache = _serving_args(cfg)
-        fn = make_tp_decode_fn(GPTModel(cfg, tp_size=2), quantized=True)
-        return fn, (params, cache, _sds((2,), "int32"), _sds((2,), "bool"))
-
-    return build
-
-
 def _quant_paged_decode_medium_ragged_entry():
     """The quantized twin of the ragged medium paged decode: int8
     params (fp32 scales) + int8 page pool at the identical ladder —
@@ -1250,32 +1136,6 @@ def _quant_paged_decode_medium_ragged_entry():
         fn = make_paged_decode_fn(cfg, quantized=True)
         return fn, (params, cache, _sds((slots,), "int32"),
                     _sds((slots,), "bool"))
-
-    return build
-
-
-def _decode_step_medium_entry():
-    """The decode roofline shape: gpt_medium-class decode, bf16
-    params, 32 slots parked at depth 512 (steady-state mid-cache
-    occupancy). Cost-tier only — APX5xx
-    already runs on the tiny-shape decode entries."""
-    def build():
-        import functools as ft
-
-        import jax
-        import jax.numpy as jnp
-
-        from apex_tpu.models.gpt import GPTConfig, init_gpt
-        from apex_tpu.serving.cache import init_cache
-        from apex_tpu.serving.decode import make_decode_fn
-
-        cfg = GPTConfig(use_rope=True)
-        params = jax.eval_shape(
-            lambda k: init_gpt(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0))
-        cache = jax.eval_shape(ft.partial(init_cache, cfg, 32, 512))
-        fn = make_decode_fn(cfg)
-        return fn, (params, cache, _sds((32,), "int32"),
-                    _sds((32,), "bool"))
 
     return build
 
@@ -1520,44 +1380,17 @@ def repo_entries() -> List[TraceEntry]:
                    _bottleneck_entry(),
                    checks=("precision", "memory", "schedule"),
                    mesh=_mesh(cp=2, n_devices=2), min_devices=2),
-        # serving: the KV cache (k, v, lengths) is DONATED into both
-        # jitted steps — min_alias_pairs=3 pins the donation (APX512's
-        # pjit branch); a dropped donate_argnums re-allocates the whole
-        # cache every decoded token
-        TraceEntry("gpt_prefill_step", "apex_tpu.serving.decode",
-                   _prefill_step_entry(),
-                   checks=("precision", "memory", "aliases"),
-                   min_alias_pairs=3),
-        TraceEntry("gpt_decode_step", "apex_tpu.serving.decode",
-                   _decode_step_entry(),
-                   checks=("precision", "memory", "aliases"),
-                   min_alias_pairs=3),
-        TraceEntry("gpt_decode_step_tp2", "apex_tpu.serving.decode",
-                   _decode_step_entry(tp=2),
-                   checks=("precision", "memory", "schedule", "aliases"),
-                   mesh=_mesh(tp=2), min_devices=2, min_alias_pairs=3),
-        TraceEntry("gpt_prefill_step_bucketed", "apex_tpu.serving.decode",
-                   _prefill_step_bucketed_entry(),
-                   checks=("precision", "memory", "aliases"),
-                   min_alias_pairs=3),
-        TraceEntry("gpt_decode_step_learned_pos", "apex_tpu.serving.decode",
-                   _decode_step_learned_pos_entry(),
-                   checks=("precision", "memory", "aliases"),
-                   min_alias_pairs=3),
-        # paged serving: 4 donated leaves (pool k/v, lengths, block
-        # tables) — min_alias_pairs=4 pins the whole-cache donation
+        # serving: the KV cache (pool k/v, lengths, block tables) is
+        # DONATED into every jitted step — min_alias_pairs=4 pins the
+        # whole-cache donation (APX512's pjit branch); a dropped
+        # donate_argnums re-allocates the whole pool every decoded token
         TraceEntry("gpt_paged_prefill_step", "apex_tpu.serving.decode",
                    _paged_prefill_step_entry(),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=4),
-        # chunked prefill: the same donations as the monolithic steps
-        # (3 dense leaves / 4 paged leaves) — a dropped pair would
-        # re-allocate the whole cache EVERY CHUNK, multiplying the
-        # admission cost by the chunk count
-        TraceEntry("gpt_chunk_prefill_step", "apex_tpu.serving.decode",
-                   _chunk_prefill_step_entry(),
-                   checks=("precision", "memory", "aliases"),
-                   min_alias_pairs=3),
+        # chunked prefill: the same donation as the monolithic step — a
+        # dropped pair would re-allocate the whole cache EVERY CHUNK,
+        # multiplying the admission cost by the chunk count
         TraceEntry("gpt_paged_chunk_prefill_step",
                    "apex_tpu.serving.decode",
                    _paged_chunk_prefill_step_entry(),
@@ -1642,14 +1475,10 @@ def repo_entries() -> List[TraceEntry]:
                    _tree_verify_step_entry(tp=2),
                    checks=("precision", "memory", "schedule", "aliases"),
                    mesh=_mesh(tp=2), min_devices=2, min_alias_pairs=4),
-        # cost-tier anchor for the decode roofline; no
-        # APX5xx checks (the tiny-shape decode entries above carry them
-        # — this one exists so budgets.json pins the headline bytes)
-        TraceEntry("gpt_decode_step_medium", "apex_tpu.serving.decode",
-                   _decode_step_medium_entry(), checks=()),
-        # ragged-length paged pool at the same model shape — its
-        # budgets.json row demonstrates the K/V-read cut vs the dense
-        # slots x S_max charge above
+        # cost-tier anchor for the decode roofline, a ragged-length
+        # pool at the medium model shape; no APX5xx checks (the
+        # tiny-shape decode entries above carry them — this one exists so
+        # budgets.json pins the headline bytes)
         # (the step whose attention is the paged-attention kernel: this
         # entry is that kernel family's registration)
         TraceEntry("gpt_paged_decode_step_medium_ragged",
@@ -1698,18 +1527,17 @@ def repo_entries() -> List[TraceEntry]:
         # the model drafter's per-token forward at the medium
         # shape — the draft_bytes numerator of the break-even condition
         # (docs/source/serving.rst); its hand-tightened ceiling pins the draft
-        # under 3% of the target parameter read. The dense-cache
-        # donation (3 leaves) rides along.
+        # under 3% of the target parameter read. The drafter's cache
+        # donation (4 leaves) rides along.
         TraceEntry("gpt_draft_forward_step",
                    "apex_tpu.serving.draft_model",
                    _draft_forward_step_entry(),
                    checks=("precision", "memory", "aliases"),
-                   min_alias_pairs=3),
+                   min_alias_pairs=4),
         # int8 tier: the standalone dequant-fused matmuls, the w8+kv8
         # paged serving steps (6 donated cache leaves — pool k/v,
-        # lengths, block tables, k/v scales), a tp2 dense-decode with
-        # the quantized tree sharded by quant_partition_specs, and the
-        # r12 cost anchor at the ragged medium shape
+        # lengths, block tables, k/v scales), and the r12 cost anchor at
+        # the ragged medium shape
         TraceEntry("w8_matmul_fused", "apex_tpu.quant.kernels",
                    _w8_matmul_entry()),
         TraceEntry("gpt_paged_prefill_step_w8kv8",
@@ -1727,10 +1555,6 @@ def repo_entries() -> List[TraceEntry]:
                    _quant_paged_step_entry("verify"),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=6),
-        TraceEntry("gpt_decode_step_w8_tp2", "apex_tpu.serving.decode",
-                   _w8_decode_step_tp2_entry(),
-                   checks=("precision", "memory", "schedule", "aliases"),
-                   mesh=_mesh(tp=2), min_devices=2, min_alias_pairs=3),
         TraceEntry("gpt_paged_decode_step_medium_ragged_w8kv8",
                    "apex_tpu.serving.decode",
                    _quant_paged_decode_medium_ragged_entry(), checks=()),

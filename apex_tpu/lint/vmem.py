@@ -657,24 +657,24 @@ def _exaone_kernel_cfg():
 
 def _draft_forward_cfg():
     """The model drafter's per-token forward (``draft_gpt_tiny`` over
-    its dense lockstep cache): XLA math today, so — like the paged
-    steps — registering it pins the trace and budget-checks any Pallas
-    kernel that later lands in the draft path."""
+    its lockstep cache, the drafter's own pool under its identity
+    table): the paged decode step at the drafter's geometry, so its
+    attention is ``apex_paged_decode_fwd`` and is budget-checked here."""
     def build():
         import functools as ft
 
         import jax
 
         from apex_tpu.models.gpt import draft_gpt_tiny, init_gpt
-        from apex_tpu.serving.cache import init_cache
-        from apex_tpu.serving.decode import make_decode_fn
+        from apex_tpu.serving.decode import make_paged_decode_fn
+        from apex_tpu.serving.draft_model import init_draft_cache
 
         cfg = draft_gpt_tiny()
         params = jax.eval_shape(
             lambda k: init_gpt(k, cfg), jax.random.PRNGKey(0))
         # 32 + 5: the engine max_len plus DraftModel's catch-up chunk
-        cache = jax.eval_shape(ft.partial(init_cache, cfg, 2, 37))
-        fn = make_decode_fn(cfg)
+        cache = jax.eval_shape(ft.partial(init_draft_cache, cfg, 2, 37))
+        fn = make_paged_decode_fn(cfg)
         return fn, (params, cache, _sds((2,), "int32"),
                     _sds((2,), "bool"))
 

@@ -338,44 +338,6 @@ def _prefill_attention(q_k_v: jax.Array, cfg: GPTConfig,
     return ctx.transpose(0, 2, 1, 3).reshape(b, s, -1), k, v
 
 
-def _decode_attention(q_k_v: jax.Array, k_cache: jax.Array,
-                      v_cache: jax.Array, pos: jax.Array,
-                      cfg: GPTConfig, rope_freqs: Optional[jax.Array]):
-    """Single-query attention against a per-slot KV cache.
-
-    ``q_k_v`` is (b, 1, 3*h_local) — the new token's fused projection;
-    ``k_cache``/``v_cache`` are (b, nh_local, S_max, hd); ``pos`` (b,)
-    int32 is each slot's current length (= the new token's absolute
-    position). The new k/v row is written (``lax.dynamic_update_slice``)
-    BEFORE attending, so the ``s <= pos`` score mask only ever admits
-    rows that hold real tokens — cached pad/stale rows beyond ``pos``
-    are unreachable by construction. Scores/softmax run in fp32 (the
-    cache may be bf16); returns (ctx (b, 1, h_local), k_cache, v_cache).
-    """
-    b = q_k_v.shape[0]
-    hd = cfg.head_dim
-    q, k, v = _split_qkv(q_k_v, hd)            # (b, nh_local, 1, hd)
-    if rope_freqs is not None:
-        q = fused_apply_rotary_pos_emb_bhsd(q, rope_freqs, positions=pos)
-        k = fused_apply_rotary_pos_emb_bhsd(k, rope_freqs, positions=pos)
-
-    def write(cache, new, p):
-        return lax.dynamic_update_slice(cache, new, (0, p, 0))
-
-    k_cache = jax.vmap(write)(k_cache, k.astype(k_cache.dtype), pos)
-    v_cache = jax.vmap(write)(v_cache, v.astype(v_cache.dtype), pos)
-    s_max = k_cache.shape[2]
-    scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) / math.sqrt(hd)
-    valid = jnp.arange(s_max)[None, None, None, :] \
-        <= pos[:, None, None, None]
-    scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhqs,bhsd->bhqd", probs,
-                     v_cache.astype(jnp.float32)).astype(q_k_v.dtype)
-    return ctx.transpose(0, 2, 1, 3).reshape(b, 1, -1), k_cache, v_cache
-
-
 def _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn):
     """The MLP half of every serving block: x + MLP(LN(x))."""
     with region("mlp"):
@@ -393,18 +355,6 @@ def _block_prefill(lp, x, cfg, rope_freqs, key_mask,
             cfg, rope_freqs, key_mask)
         x = x + out_fn(lp["out"], att)
     return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k, v
-
-
-def _block_decode(lp, x, k_cache, v_cache, pos, cfg, rope_freqs,
-                  qkv_fn, out_fn, fc1_fn, fc2_fn):
-    """:func:`_block` against the cache: x is the (b, 1, h) new-token
-    hidden; returns (x', k_cache', v_cache')."""
-    with region("attention"):
-        att, k_cache, v_cache = _decode_attention(
-            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-            k_cache, v_cache, pos, cfg, rope_freqs)
-        x = x + out_fn(lp["out"], att)
-    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_cache, v_cache
 
 
 def _page_rows(t: jax.Array) -> jax.Array:
@@ -456,8 +406,7 @@ def _paged_decode_attention(q_k_v: jax.Array, k_pool: jax.Array,
     the pages at or below ``pos`` from HBM and runs the float32 online
     softmax over them; the pool is read, never written here.
 
-    The paged analogue of :func:`_decode_attention`'s
-    write-new-row-then-attend contract: the new token's K/V row is rounded
+    Write-new-row-then-attend: the new token's K/V row is rounded
     to the pool's dtype and attended to AT position ``pos`` as an operand
     of the kernel, and returned ((b, nh_local * hd) each) for the caller to
     write into page ``block_tables[b, pos // page_size]`` at row ``pos %
@@ -492,9 +441,9 @@ def _paged_decode_attention(q_k_v: jax.Array, k_pool: jax.Array,
 
 def _block_decode_paged(lp, x, k_pool, v_pool, layer, block_tables, pos,
                         cfg, rope_freqs, qkv_fn, out_fn, fc1_fn, fc2_fn):
-    """:func:`_block_decode` over the paged pool (block-table
-    indirection instead of a per-slot cache row); returns (x', k_row,
-    v_row), the layer's new rows for the caller to write."""
+    """:func:`_block` against the paged pool: x is the (b, 1, h)
+    new-token hidden; returns (x', k_row, v_row), the layer's new rows
+    for the caller to write."""
     with region("attention"):
         att, k_row, v_row = _paged_decode_attention(
             qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
@@ -503,80 +452,33 @@ def _block_decode_paged(lp, x, k_pool, v_pool, layer, block_tables, pos,
     return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_row, v_row
 
 
-def _verify_attention(q_k_v: jax.Array, k_cache: jax.Array,
-                      v_cache: jax.Array, pos: jax.Array,
-                      cfg: GPTConfig, rope_freqs: Optional[jax.Array]):
-    """Multi-query (speculative *verify*) attention against a per-slot
-    KV cache: the k+1 generalization of :func:`_decode_attention`.
+def _paged_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, block_tables: jax.Array,
+                            pos: jax.Array, cfg: GPTConfig,
+                            rope_freqs: Optional[jax.Array]):
+    """Multi-query (speculative *verify*) attention against the PAGED
+    pool — the k+1 generalization of :func:`_paged_decode_attention`.
 
     ``q_k_v`` is (b, k1, 3*h_local) — the last committed token plus k
     drafted candidates, projected together; ``pos`` (b,) int32 is each
     slot's committed length, so query j sits at absolute position
     ``pos + j`` (RoPE rotates consecutive positions from ``pos``, the
     same ``positions=`` contract the single-token path uses). All k1
-    new k/v rows are written (one ``lax.dynamic_update_slice`` block
-    per slot) BEFORE attending; the per-query mask ``s <= pos + j``
-    then admits exactly the committed history plus the candidate's own
-    prefix — write-then-attend, so every admitted row holds a real
-    value and logits row j equals a teacher-forced forward at position
-    ``pos + j`` bit-for-bit. Rows beyond the accepted prefix are never
+    new k/v rows are written BEFORE attending: k1 is static, so the
+    scatter is k1 unrolled single-row updates of the donated pool —
+    each position lands in page ``block_tables[b, (pos+j) //
+    page_size]`` at row ``(pos+j) % page_size``. The per-query mask
+    ``s <= pos + j`` then admits exactly the committed history plus the
+    candidate's own prefix — write-then-attend, so every admitted row
+    holds a real value and logits row j equals a teacher-forced forward
+    at position ``pos + j``. Rows beyond the accepted prefix are never
     admitted by any later mask before being re-written (positions are
     monotone), which is the whole cache-rollback contract: rejection
-    needs no cleanup pass. Callers must guarantee ``pos + k1 <=
-    S_max`` (``dynamic_update_slice`` clamps out-of-range starts,
-    which would silently shift the write onto committed rows).
-    Scores/softmax run in fp32; returns (ctx (b, k1, h_local),
-    k_cache, v_cache).
-    """
-    b, k1, _ = q_k_v.shape
-    hd = cfg.head_dim
-    q, k, v = _split_qkv(q_k_v, hd)            # (b, nh_local, k1, hd)
-    if rope_freqs is not None:
-        q = fused_apply_rotary_pos_emb_bhsd(q, rope_freqs, positions=pos)
-        k = fused_apply_rotary_pos_emb_bhsd(k, rope_freqs, positions=pos)
-
-    def write(cache, new, p):
-        return lax.dynamic_update_slice(cache, new, (0, p, 0))
-
-    k_cache = jax.vmap(write)(k_cache, k.astype(k_cache.dtype), pos)
-    v_cache = jax.vmap(write)(v_cache, v.astype(v_cache.dtype), pos)
-    s_max = k_cache.shape[2]
-    scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) / math.sqrt(hd)
-    qpos = pos[:, None] + jnp.arange(k1)[None, :]        # (b, k1)
-    valid = jnp.arange(s_max)[None, None, None, :] \
-        <= qpos[:, None, :, None]
-    scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhqs,bhsd->bhqd", probs,
-                     v_cache.astype(jnp.float32)).astype(q_k_v.dtype)
-    return ctx.transpose(0, 2, 1, 3).reshape(b, k1, -1), k_cache, v_cache
-
-
-def _block_verify(lp, x, k_cache, v_cache, pos, cfg, rope_freqs,
-                  qkv_fn, out_fn, fc1_fn, fc2_fn):
-    """:func:`_block_decode` over k1 candidate positions at once."""
-    with region("attention"):
-        att, k_cache, v_cache = _verify_attention(
-            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-            k_cache, v_cache, pos, cfg, rope_freqs)
-        x = x + out_fn(lp["out"], att)
-    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_cache, v_cache
-
-
-def _paged_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
-                            v_pages: jax.Array, block_tables: jax.Array,
-                            pos: jax.Array, cfg: GPTConfig,
-                            rope_freqs: Optional[jax.Array]):
-    """Multi-query verify attention against the PAGED pool — the k+1
-    generalization of :func:`_paged_decode_attention`, with the same
-    write-then-attend and exact-zero masking contracts as
-    :func:`_verify_attention` (see there for the rollback argument).
-    k1 is static, so the scatter is k1 unrolled single-row updates of
-    the donated pool — each position lands in page ``block_tables[b,
-    (pos+j) // page_size]`` at row ``(pos+j) % page_size``. Callers
-    must hold pages allocated for all k1 positions (the scheduler's
-    ``prepare_decode(..., n_new=k1)``).
+    needs no cleanup pass. Callers must hold pages allocated for all k1
+    positions (the scheduler's ``prepare_decode(..., n_new=k1)``; a
+    position past the table is clamped onto the row's last page).
+    Scores/softmax run in fp32; returns (ctx (b, k1, h_local), k_pages,
+    v_pages).
     """
     b, k1, _ = q_k_v.shape
     hd = cfg.head_dim
@@ -612,74 +514,14 @@ def _paged_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
 
 def _block_verify_paged(lp, x, k_pages, v_pages, block_tables, pos, cfg,
                         rope_freqs, qkv_fn, out_fn, fc1_fn, fc2_fn):
-    """:func:`_block_verify` over the paged pool."""
+    """:func:`_block_decode_paged` over k1 candidate positions at once
+    (one layer's pool in, the same pool with the k1 rows written out)."""
     with region("attention"):
         att, k_pages, v_pages = _paged_verify_attention(
             qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
             k_pages, v_pages, block_tables, pos, cfg, rope_freqs)
         x = x + out_fn(lp["out"], att)
     return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_pages, v_pages
-
-
-def _chunk_prefill_attention(q_k_v: jax.Array, k_cache: jax.Array,
-                             v_cache: jax.Array, slot: jax.Array,
-                             pos: jax.Array, cfg: GPTConfig,
-                             rope_freqs: Optional[jax.Array],
-                             key_mask: jax.Array):
-    """Chunked-prefill attention for ONE slot against the dense cache:
-    the prompt-sized generalization of :func:`_verify_attention`.
-
-    ``q_k_v`` is (1, sc, 3*h_local) — one chunk of one slot's prompt,
-    projected together; ``slot``/``pos`` are scalar int32 (cache row
-    and the chunk's absolute start position, so token j sits at
-    ``pos + j``); ``key_mask`` (1, sc) int32 marks real tokens (the
-    final chunk of a prompt is bucket-padded at the tail). The chunk's
-    k/v rows are zero-masked and written at ``pos`` BEFORE attending —
-    write-then-attend, so the per-query ``s <= pos + j`` mask admits
-    exactly the previously-written chunks plus the token's own prefix,
-    and logits at row j equal a teacher-forced forward at position
-    ``pos + j``. Pad queries (mask 0) attend only zeroed rows beyond
-    every real query's mask, so their garbage context is unreachable
-    from any real row's output. Scores/softmax run in fp32."""
-    _, sc, _ = q_k_v.shape
-    hd = cfg.head_dim
-    q, k, v = _split_qkv(q_k_v, hd)            # (1, nh_local, sc, hd)
-    p1 = pos[None]
-    if rope_freqs is not None:
-        q = fused_apply_rotary_pos_emb_bhsd(q, rope_freqs, positions=p1)
-        k = fused_apply_rotary_pos_emb_bhsd(k, rope_freqs, positions=p1)
-    mz = key_mask.astype(k.dtype)[:, None, :, None]
-    k_cache = lax.dynamic_update_slice(
-        k_cache, (k * mz).astype(k_cache.dtype), (slot, 0, pos, 0))
-    v_cache = lax.dynamic_update_slice(
-        v_cache, (v * mz).astype(v_cache.dtype), (slot, 0, pos, 0))
-    kg = lax.dynamic_slice(k_cache, (slot, 0, 0, 0),
-                           (1,) + k_cache.shape[1:])
-    vg = lax.dynamic_slice(v_cache, (slot, 0, 0, 0),
-                           (1,) + v_cache.shape[1:])
-    s_max = kg.shape[2]
-    scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32),
-                        kg.astype(jnp.float32)) / math.sqrt(hd)
-    qpos = p1[:, None] + jnp.arange(sc)[None, :]         # (1, sc)
-    valid = jnp.arange(s_max)[None, None, None, :] \
-        <= qpos[:, None, :, None]
-    scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhqs,bhsd->bhqd", probs,
-                     vg.astype(jnp.float32)).astype(q_k_v.dtype)
-    return ctx.transpose(0, 2, 1, 3).reshape(1, sc, -1), k_cache, v_cache
-
-
-def _block_chunk_prefill(lp, x, k_cache, v_cache, slot, pos, cfg,
-                         rope_freqs, key_mask, qkv_fn, out_fn, fc1_fn,
-                         fc2_fn):
-    """:func:`_block_verify` for one slot's prompt chunk."""
-    with region("attention"):
-        att, k_cache, v_cache = _chunk_prefill_attention(
-            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-            k_cache, v_cache, slot, pos, cfg, rope_freqs, key_mask)
-        x = x + out_fn(lp["out"], att)
-    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_cache, v_cache
 
 
 def _paged_chunk_prefill_attention(q_k_v: jax.Array, k_pages: jax.Array,
@@ -689,9 +531,24 @@ def _paged_chunk_prefill_attention(q_k_v: jax.Array, k_pages: jax.Array,
                                    cfg: GPTConfig,
                                    rope_freqs: Optional[jax.Array],
                                    key_mask: jax.Array):
-    """:func:`_chunk_prefill_attention` over the PAGED pool. Chunks are
-    whole pages (sc a multiple of page_size), so the write is the
-    monolithic paged prefill's page-granular scatter: the chunk's
+    """Chunked-prefill attention for ONE slot against the PAGED pool:
+    the prompt-sized generalization of :func:`_paged_verify_attention`.
+
+    ``q_k_v`` is (1, sc, 3*h_local) — one chunk of one slot's prompt,
+    projected together; ``pos`` is the chunk's absolute start position
+    (scalar int32), so token j sits at ``pos + j``; ``key_mask`` (1, sc)
+    int32 marks real tokens (the final chunk of a prompt is
+    bucket-padded at the tail). The chunk's k/v rows are zero-masked
+    and written BEFORE attending — write-then-attend, so the per-query
+    ``s <= pos + j`` mask admits exactly the previously-written chunks
+    plus the token's own prefix, and logits at row j equal a
+    teacher-forced forward at position ``pos + j``. Pad queries (mask
+    0) attend only zeroed rows beyond every real query's mask, so their
+    garbage context is unreachable from any real row's output.
+    Scores/softmax run in fp32.
+
+    Chunks are whole pages (sc a multiple of page_size), so the write is
+    the monolithic paged prefill's page-granular scatter: the chunk's
     zero-masked k/v rows are cut into page tiles and scattered to
     ``write_pages`` ((sc // page_size,) int32 — the host redirects
     prefix-shared pages to ``SCRATCH_PAGE``, so shared pages are never
@@ -739,7 +596,7 @@ def _paged_chunk_prefill_attention(q_k_v: jax.Array, k_pages: jax.Array,
 def _block_chunk_prefill_paged(lp, x, k_pages, v_pages, write_pages,
                                gather_row, pos, cfg, rope_freqs,
                                key_mask, qkv_fn, out_fn, fc1_fn, fc2_fn):
-    """:func:`_block_chunk_prefill` over the paged pool."""
+    """:func:`_block_verify_paged` for one slot's prompt chunk."""
     with region("attention"):
         att, k_pages, v_pages = _paged_chunk_prefill_attention(
             qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
@@ -778,72 +635,29 @@ def _tree_score_mask(pos, anc, s_max):
     return vis.transpose(0, 2, 1)[:, None]               # (b, 1, q, s)
 
 
-def _tree_verify_attention(q_k_v: jax.Array, k_cache: jax.Array,
-                           v_cache: jax.Array, pos: jax.Array,
-                           depth: jax.Array, anc: jax.Array,
-                           cfg: GPTConfig,
-                           rope_freqs: Optional[jax.Array]):
-    """Tree-mask verify attention against a per-slot KV cache.
-
-    ``q_k_v`` is (b, k1, 3*h_local) — the grid nodes' fused projection
-    in topological order (node 0 = the pending committed token, the
-    root every branch hangs off); ``depth`` (b, k1) int32 is each
-    node's depth below the committed history, so node j's ATTENTION /
-    RoPE position is ``pos + depth[j]`` while its PHYSICAL cache row
-    stays ``pos + j`` (distinct rows per node — siblings at one tree
-    depth share a position but must not share a row). ``anc`` (b, k1,
-    k1) bool is the ancestor-or-self matrix consumed by
-    :func:`_tree_score_mask`. Same write-then-attend rollback contract
-    as :func:`_verify_attention`: all k1 rows are written before any
-    mask admits them, and the host re-sends any committed token whose
-    row did not land contiguously (the forced-prefix rule in
-    ``scheduler._tree_tick``), so rejected branch rows are overwritten
-    before they are ever attended."""
-    b, k1, _ = q_k_v.shape
-    hd = cfg.head_dim
-    q, k, v = _split_qkv(q_k_v, hd)            # (b, nh_local, k1, hd)
-    if rope_freqs is not None:
-        tpos = pos[:, None] + depth                      # (b, k1)
-        q = fused_apply_rotary_pos_emb_bhsd(q, rope_freqs, positions=tpos)
-        k = fused_apply_rotary_pos_emb_bhsd(k, rope_freqs, positions=tpos)
-
-    def write(cache, new, p):
-        return lax.dynamic_update_slice(cache, new, (0, p, 0))
-
-    k_cache = jax.vmap(write)(k_cache, k.astype(k_cache.dtype), pos)
-    v_cache = jax.vmap(write)(v_cache, v.astype(v_cache.dtype), pos)
-    s_max = k_cache.shape[2]
-    scores = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) / math.sqrt(hd)
-    valid = _tree_score_mask(pos, anc, s_max)
-    scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhqs,bhsd->bhqd", probs,
-                     v_cache.astype(jnp.float32)).astype(q_k_v.dtype)
-    return ctx.transpose(0, 2, 1, 3).reshape(b, k1, -1), k_cache, v_cache
-
-
-def _block_tree_verify(lp, x, k_cache, v_cache, pos, depth, anc, cfg,
-                       rope_freqs, qkv_fn, out_fn, fc1_fn, fc2_fn):
-    """:func:`_block_verify` under the tree-attention mask."""
-    with region("attention"):
-        att, k_cache, v_cache = _tree_verify_attention(
-            qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),
-            k_cache, v_cache, pos, depth, anc, cfg, rope_freqs)
-        x = x + out_fn(lp["out"], att)
-    return _mlp_residual(lp, x, cfg, fc1_fn, fc2_fn), k_cache, v_cache
-
-
 def _paged_tree_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
                                  v_pages: jax.Array,
                                  block_tables: jax.Array, pos: jax.Array,
                                  depth: jax.Array, anc: jax.Array,
                                  cfg: GPTConfig,
                                  rope_freqs: Optional[jax.Array]):
-    """:func:`_tree_verify_attention` over the PAGED pool: the k1
-    unrolled row scatters of :func:`_paged_verify_attention` (node j at
-    physical position ``pos + j``) with the ancestor-matrix score mask
-    and depth-indexed RoPE. Not offered for the int8 pool: an accepted
+    """Tree-mask verify attention against the PAGED pool.
+
+    ``q_k_v`` is (b, k1, 3*h_local) — the grid nodes' fused projection
+    in topological order (node 0 = the pending committed token, the
+    root every branch hangs off); ``depth`` (b, k1) int32 is each
+    node's depth below the committed history, so node j's ATTENTION /
+    RoPE position is ``pos + depth[j]`` while its PHYSICAL row stays
+    ``pos + j`` (distinct rows per node — siblings at one tree depth
+    share a position but must not share a row): the k1 unrolled row
+    scatters of :func:`_paged_verify_attention`. ``anc`` (b, k1, k1)
+    bool is the ancestor-or-self matrix consumed by
+    :func:`_tree_score_mask`. Same write-then-attend rollback contract
+    as :func:`_paged_verify_attention`: all k1 rows are written before
+    any mask admits them, and the host re-sends any committed token
+    whose row did not land contiguously (the forced-prefix rule in
+    ``scheduler._tree_tick``), so rejected branch rows are overwritten
+    before they are ever attended. Not offered for the int8 pool: an accepted
     non-leftmost branch would require compacting quantized rows across
     pages, re-rounding committed history at branch-dependent scales —
     the engine pins linear spec for kv8 instead."""
@@ -881,7 +695,7 @@ def _paged_tree_verify_attention(q_k_v: jax.Array, k_pages: jax.Array,
 def _block_tree_verify_paged(lp, x, k_pages, v_pages, block_tables, pos,
                              depth, anc, cfg, rope_freqs,
                              qkv_fn, out_fn, fc1_fn, fc2_fn):
-    """:func:`_block_tree_verify` over the paged pool."""
+    """:func:`_block_verify_paged` under the tree-attention mask."""
     with region("attention"):
         att, k_pages, v_pages = _paged_tree_verify_attention(
             qkv_fn(lp["qkv"], _ln(lp["ln1"], x, cfg.layer_norm_eps)),

@@ -27,7 +27,6 @@ from apex_tpu.partition.tables import (
     gpt_quant_rules,
     gpt_rules,
     kv_cache_quant_rules,
-    kv_cache_rules,
     paged_kv_cache_rules,
 )
 
@@ -36,7 +35,6 @@ __all__ = [
     "gpt_quant_rules",
     "gpt_rules",
     "kv_cache_quant_rules",
-    "kv_cache_rules",
     "paged_kv_cache_rules",
     "make_mesh",
     "make_shard_and_gather_fns",

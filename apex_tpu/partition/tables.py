@@ -4,8 +4,8 @@ One table per model family covers everything that family shards: the
 parameter tree, the optimizer moments/master weights derived from it
 (see :func:`apex_tpu.partition.rules.optimizer_state_specs`), and — for
 GPT — the serving KV cache
-(:func:`apex_tpu.serving.cache.cache_partition_specs` matches its
-``KVCache`` template against the same table). The tables are written
+(:func:`apex_tpu.serving.cache.paged_cache_partition_specs` matches its
+``PagedKVCache`` template against the same table). The tables are written
 OVERLAP-FREE: every leaf matches exactly one rule, which APX701
 enforces for each registered tree, and the layouts reproduce the
 hand-maintained reference (``models.gpt.gpt_partition_specs``) that
@@ -21,9 +21,9 @@ Layout recap (Megatron over the ``model`` mesh axis):
 - layer norms replicated;
 - GPT layer leaves carry a leading stacked-``num_layers`` dim (the
   ``lax.scan`` depth loop), hence the extra leading ``None``;
-- KV cache: heads (axis 2 of the dense ``(L, slots, heads, S, d)``, the
-  last axis of the paged ``(L, pages, page, heads * d)``, whose rows hold
-  whole heads side by side) shard over ``model`` — each rank caches
+- KV cache: heads (the last axis of the pool ``(L, pages, page,
+  heads * d)``, whose rows hold whole heads side by side) shard over
+  ``model`` — each rank caches
   exactly the heads its head-major qkv column shard produces; slot
   lengths and block tables are replicated.
 """
@@ -32,26 +32,15 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu.transformer import parallel_state as ps
 
-# KV-cache rules: the paths are the ``KVCache``/``PagedKVCache``
-# namedtuple fields, matched at end-of-path so a model param ending
-# differently can never collide. The two layouts name their leaves alike
-# and keep heads on different axes, so each has its own table: dense
-# ``(L, slots, heads, S, d)``; paged ``(L, pages, page, heads * d)`` plus
-# the block tables, which replicate (every rank indexes the same mapping).
-_KV_CACHE_RULES = (
-    (r"(^|/)(k|v)$", P(None, None, ps.TENSOR_AXIS, None, None)),
-    (r"(^|/)lengths$", P()),
-)
+# KV-cache rules: the paths are the ``PagedKVCache`` namedtuple fields,
+# matched at end-of-path so a model param ending differently can never
+# collide: the pool ``(L, pages, page, heads * d)`` plus the block
+# tables, which replicate (every rank indexes the same mapping).
 _PAGED_KV_CACHE_RULES = (
     (r"(^|/)(k|v)$", P(None, None, None, ps.TENSOR_AXIS)),
     (r"(^|/)lengths$", P()),
     (r"(^|/)block_tables$", P()),
 )
-
-
-def kv_cache_rules():
-    """The dense serving-cache slice of the default tables."""
-    return _KV_CACHE_RULES
 
 
 def paged_kv_cache_rules():
@@ -99,7 +88,7 @@ def gpt_quant_rules():
 
 def gpt_rules():
     """Rule table for the GPT param tree (``models.gpt.init_gpt``) plus
-    the dense serving KV cache. First match wins; table is overlap-free."""
+    the serving KV cache. First match wins; table is overlap-free."""
     t = ps.TENSOR_AXIS
     return (
         ("embedding/word/embedding", P(t, None)),
@@ -114,7 +103,7 @@ def gpt_rules():
         ("layers/fc2/kernel", P(None, t, None)),
         ("layers/fc2/bias", P(None)),
         ("final_ln/(weight|bias)", P()),
-    ) + _KV_CACHE_RULES
+    ) + _PAGED_KV_CACHE_RULES
 
 
 def draft_gpt_rules():
@@ -124,7 +113,8 @@ def draft_gpt_rules():
     :func:`gpt_rules` minus the rows that can never match a draft tree:
     draft configs (``models.gpt.draft_gpt_tiny``/``draft_gpt_medium``)
     are RoPE-only — no ``embedding/position`` leaf (the lockstep draft
-    cache is the dense ``KVCache`` the table already covers). A rule
-    that can never match would be an APX701 dead-rule finding."""
+    cache is a ``PagedKVCache`` like the target's, which the table
+    already covers). A rule that can never match would be an APX701
+    dead-rule finding."""
     return tuple(rule for rule in gpt_rules()
                  if rule[0] != "embedding/position/embedding")

@@ -4,11 +4,10 @@ Reference anchor: the apex-fed Megatron stacks are served with
 KV-cached autoregressive generation (``megatron/text_generation``);
 this package is that path for ``apex_tpu.models.gpt``, TPU-first:
 
-- ``cache``     — two cache layouts updated in place via donated
+- ``cache``     — the cache layout, updated in place via donated
   buffers (apxlint APX512 pins the donation in the trace tier): the
-  dense per-slot ``KVCache`` and the paged ``PagedKVCache`` (fixed page
-  pool + per-slot block tables, K/V HBM proportional to allocated
-  pages instead of ``slots x S_max``);
+  paged ``PagedKVCache`` (fixed page pool + per-slot block tables, K/V
+  HBM proportional to allocated pages instead of ``slots x S_max``);
 - ``paging``    — host-side page allocator: free list, refcounts,
   prefix-hash cache with LRU eviction, copy-on-write bookkeeping, and
   the hierarchical KV-cache's host tier: a byte-budgeted
@@ -18,7 +17,7 @@ this package is that path for ``apex_tpu.models.gpt``, TPU-first:
   shareable across engines and replicas so any replica's prefill
   seeds everyone's cache;
 - ``decode``    — bucketed prefill + single-token decode + k+1-position
-  speculative *verify* steps over either layout, an unsharded path and
+  speculative *verify* steps over the page pool, an unsharded path and
   a TP-sharded path (heads over the ``model`` axis);
 - ``draft``     — host-side n-gram / prompt-lookup drafting for
   self-speculative decode (pure function of the token history — no
@@ -32,8 +31,8 @@ this package is that path for ``apex_tpu.models.gpt``, TPU-first:
   committed stream is bit-identical to plain decode;
 - ``scheduler`` — fixed-slot continuous batching (admit/evict on EOS or
   max-len; jit recompiles only per prompt bucket, never per request),
-  over either engine; the paged engine adds prefix sharing at admission
-  and preemption-by-requeue when the pool runs dry; ``spec_k > 0``
+  over the one engine (``PagedDecodeEngine``), with prefix sharing at
+  admission and preemption-by-requeue when the pool runs dry; ``spec_k > 0``
   turns ticks into draft → verify → accept steps committing 1..k+1
   tokens per slot, with optional model drafting (``draft_model=``),
   tree speculation (``tree_spec=True``) and per-stream adaptive depth
@@ -90,21 +89,16 @@ this package is that path for ``apex_tpu.models.gpt``, TPU-first:
 """
 
 from apex_tpu.serving.cache import (  # noqa: F401
-    HybridKVCache, KVCache, LatentKVCache, PagedKVCache, WindowKVCache,
-    audit_block_tables, cache_partition_specs, init_cache, init_hybrid_cache,
-    init_latent_cache, init_paged_cache, init_window_cache,
-    paged_cache_partition_specs,
+    HybridKVCache, LatentKVCache, PagedKVCache, WindowKVCache,
+    audit_block_tables, init_hybrid_cache, init_latent_cache,
+    init_paged_cache, init_window_cache, paged_cache_partition_specs,
 )
 from apex_tpu.serving.decode import (  # noqa: F401
-    make_chunk_prefill_fn, make_copy_page_fn, make_decode_fn,
-    make_paged_chunk_prefill_fn, make_paged_decode_fn,
+    make_copy_page_fn, make_paged_chunk_prefill_fn, make_paged_decode_fn,
     make_paged_prefill_fn, make_paged_tree_verify_fn,
-    make_paged_verify_fn, make_prefill_fn, make_tp_chunk_prefill_fn,
-    make_tp_decode_fn, make_tp_paged_chunk_prefill_fn,
+    make_paged_verify_fn, make_tp_paged_chunk_prefill_fn,
     make_tp_paged_decode_fn, make_tp_paged_prefill_fn,
     make_tp_paged_tree_verify_fn, make_tp_paged_verify_fn,
-    make_tp_prefill_fn, make_tp_tree_verify_fn, make_tp_verify_fn,
-    make_tree_verify_fn, make_verify_fn,
 )
 from apex_tpu.serving.draft import ngram_draft, tree_arrays  # noqa: F401
 from apex_tpu.serving.draft_model import DraftModel  # noqa: F401
@@ -136,7 +130,7 @@ from apex_tpu.serving.sampling import (  # noqa: F401
     speculative_accept, stream_keys, tree_speculative_accept,
 )
 from apex_tpu.serving.scheduler import (  # noqa: F401
-    ContinuousBatchingScheduler, DecodeEngine, PagedDecodeEngine, Request,
+    ContinuousBatchingScheduler, PagedDecodeEngine, Request,
 )
 from apex_tpu.serving.streaming import (  # noqa: F401
     StreamMux, TokenStream,

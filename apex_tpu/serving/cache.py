@@ -1,27 +1,28 @@
-"""KV cache: preallocated per-layer key/value buffers + slot lengths.
+"""KV cache: a pool of fixed-size pages, block tables, slot lengths.
 
-Layout: ``k``/``v`` are ``(num_layers, num_slots, num_heads, S_max,
-head_dim)`` — the per-layer ``[B, H, S, d]`` buffers of the design doc,
-stacked on a leading layer axis to match the model's stacked-layer
-``lax.scan`` (the depth loop slices one layer's cache per iteration with
-no re-plumbing). ``lengths`` is ``(num_slots,)`` int32 — how many
+ONE layout for every family: ``k``/``v`` are a pool of pages stacked on a
+leading layer axis to match the model's stacked-layer ``lax.scan``
+(:class:`PagedKVCache`; the families that bring their own cores keep
+further leaves beside it: :class:`HybridKVCache`, :class:`LatentKVCache`,
+:class:`WindowKVCache`), ``block_tables`` maps each slot's logical pages
+to physical ones, and ``lengths`` is ``(num_slots,)`` int32 — how many
 positions of each slot hold real tokens; it is simultaneously the next
-write offset and the attention-mask bound (decode masks scores to
-``s <= pos`` AFTER writing the new row, so stale rows past the length
-are unreachable).
+write offset and the attention-mask bound (decode attends ``s <= pos``
+with the new row at ``pos``, so stale rows past the length are
+unreachable).
 
-The cache is updated with ``lax.dynamic_update_slice`` inside a jit
-whose cache argument is DONATED: XLA reuses the input buffer for the
-output and a decode step is one in-place write per layer, not a fresh
-``O(L·B·H·S·d)`` allocation. The trace-tier linter (APX512) pins the
-donation — see ``apex_tpu/lint/traced/aliases.py`` and the
-``gpt_decode_step`` registry entries.
+The cache is updated inside a jit whose cache argument is DONATED: XLA
+reuses the input buffer for the output and a decode step is one in-place
+row scatter, not a fresh copy of the pool. The trace-tier linter (APX512)
+pins the donation — see ``apex_tpu/lint/traced/aliases.py`` and the
+``gpt_paged_decode_step`` registry entries.
 
 dtype: bf16 halves cache HBM and decode is score-bound, not
-precision-bound (scores/softmax stay fp32 in ``_decode_attention``);
-fp32 is for parity tests. Under TP the head axis (2) shards over the
-``model`` mesh axis — each rank holds its local heads' cache, matching
-the head-major qkv column shard.
+precision-bound (scores/softmax stay fp32 in the attention bodies of
+``models/gpt.py``); fp32 is for parity tests. Under TP the pool's last
+axis (whole heads side by side) shards over the ``model`` mesh axis —
+each rank holds its local heads' rows, matching the head-major qkv
+column shard.
 """
 
 from typing import NamedTuple, Optional
@@ -31,56 +32,6 @@ import jax.numpy as jnp
 
 from apex_tpu.models.gpt import GPTConfig
 
-
-class KVCache(NamedTuple):
-    k: jax.Array        # (L, num_slots, num_heads, S_max, head_dim)
-    v: jax.Array        # (L, num_slots, num_heads, S_max, head_dim)
-    lengths: jax.Array  # (num_slots,) int32, valid positions per slot
-
-
-def init_cache(cfg: GPTConfig, num_slots: int, max_len: int,
-               dtype=jnp.bfloat16) -> KVCache:
-    """Zero-filled cache for ``num_slots`` concurrent sequences of up to
-    ``max_len`` tokens each (prompt + generated)."""
-    if max_len < 1 or num_slots < 1:
-        raise ValueError(
-            f"need positive num_slots/max_len, got {num_slots}/{max_len}")
-    if not cfg.use_rope and max_len > cfg.max_position_embeddings:
-        raise ValueError(
-            f"max_len {max_len} exceeds the learned position table "
-            f"({cfg.max_position_embeddings}); raise "
-            "max_position_embeddings or use rope")
-    shape = (cfg.num_layers, num_slots, cfg.num_heads, max_len,
-             cfg.head_dim)
-    return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                   lengths=jnp.zeros((num_slots,), jnp.int32))
-
-
-def cache_partition_specs(rules=None) -> KVCache:
-    """TP layout: heads (axis 2) shard over the ``model`` mesh axis —
-    the cache shard each rank sees inside shard_map holds exactly the
-    heads its qkv column shard produces. Lengths are replicated.
-
-    Derived from the partition-rule table (``partition.kv_cache_rules``
-    by default, or any table covering the ``k``/``v``/``lengths``
-    paths), so serving stays consistent with whatever table shards the
-    model — APX702 checks the head axis against the qkv weights' ``tp``
-    axis."""
-    from apex_tpu.partition import kv_cache_rules, match_partition_rules
-
-    if rules is None:
-        rules = kv_cache_rules()
-    # Rank-faithful abstract template: matching only reads paths/ranks.
-    template = KVCache(
-        k=jax.ShapeDtypeStruct((1,) * 5, "bfloat16"),
-        v=jax.ShapeDtypeStruct((1,) * 5, "bfloat16"),
-        lengths=jax.ShapeDtypeStruct((1,), "int32"))
-    return match_partition_rules(rules, template)
-
-
-# ---------------------------------------------------------------------------
-# paged cache: fixed page pool + per-slot block tables
-# ---------------------------------------------------------------------------
 
 # Physical page ids below this are reserved and never allocated:
 NULL_PAGE = 0     # parks unmapped block-table entries; never written
@@ -274,22 +225,18 @@ def init_paged_cache(cfg: GPTConfig, num_slots: int, max_len: int,
             "max_position_embeddings or use rope")
     shape = (cfg.num_layers, num_pages, page_size,
              cfg.num_heads * cfg.head_dim)
-    bt = _parked_tables(num_slots, max_len, page_size)
+    scales = {}
     if jnp.dtype(dtype) == jnp.int8:
         # quantized pool: zero int8 pages + zero fp32 scales (a
         # 0-scale page dequantizes to exact zeros, so NULL stays
         # pristine before its first real write)
         sscale = (cfg.num_layers, num_pages, cfg.num_heads)
-        return PagedKVCache(k=jnp.zeros(shape, jnp.int8),
-                            v=jnp.zeros(shape, jnp.int8),
-                            lengths=jnp.zeros((num_slots,), jnp.int32),
-                            block_tables=bt,
-                            k_scale=jnp.zeros(sscale, jnp.float32),
-                            v_scale=jnp.zeros(sscale, jnp.float32))
-    return PagedKVCache(k=jnp.zeros(shape, dtype),
-                        v=jnp.zeros(shape, dtype),
-                        lengths=jnp.zeros((num_slots,), jnp.int32),
-                        block_tables=bt)
+        scales = dict(k_scale=jnp.zeros(sscale, jnp.float32),
+                      v_scale=jnp.zeros(sscale, jnp.float32))
+    return PagedKVCache(
+        k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+        lengths=jnp.zeros((num_slots,), jnp.int32),
+        block_tables=_parked_tables(num_slots, max_len, page_size), **scales)
 
 
 def _parked_tables(num_slots: int, max_len: int, page_size: int):
@@ -415,8 +362,11 @@ def audit_block_tables(block_tables, slot_pages) -> bool:
 
 def paged_cache_partition_specs(rules=None,
                                 quantized: bool = False) -> PagedKVCache:
-    """Same table-derived TP layout as :func:`cache_partition_specs`,
-    from the paged table (``partition.paged_kv_cache_rules``): the pool's
+    """TP layout, derived from the partition-rule table
+    (``partition.paged_kv_cache_rules`` by default, or any table covering
+    the cache's paths), so serving stays consistent with whatever table
+    shards the model — APX702 checks the head axis against the qkv
+    weights' ``tp`` axis: the pool's
     last axis (whole heads side by side) shards over ``model``; lengths
     AND block tables are replicated — every rank walks the same
     logical-to-physical mapping over its local heads. With

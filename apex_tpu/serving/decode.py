@@ -1,18 +1,20 @@
-"""Prefill + single-token decode steps over the KV cache.
+"""Prefill + single-token decode steps over the paged KV cache.
 
 Two execution paths from one body (the ``models/gpt.py`` discipline):
-``make_prefill_fn``/``make_decode_fn`` are plain-jnp on full params (the
-golden single-chip path); ``make_tp_prefill_fn``/``make_tp_decode_fn``
-run the same body inside ``parallel_state.shard_map`` with the Megatron
-TP layers — heads (and the cache's head axis) shard over the ``model``
-mesh axis, and logits leave through the existing ``_tied_lm_logits``
-vocab-sharded head followed by a rank-order gather, so every rank
-returns the full ``(b, V)`` row.
+``make_paged_prefill_fn``/``make_paged_decode_fn`` are plain-jnp on full
+params (the golden single-chip path);
+``make_tp_paged_prefill_fn``/``make_tp_paged_decode_fn`` run the same
+body inside ``parallel_state.shard_map`` with the Megatron TP layers —
+heads (and the pool's head axis) shard over the ``model`` mesh axis, and
+logits leave through the existing ``_tied_lm_logits`` vocab-sharded head
+followed by a rank-order gather, so every rank returns the full
+``(b, V)`` row.
 
 Contracts:
 
 - **prefill** runs the full forward ONCE over a (bucket-padded) prompt
-  for one slot, writes that slot's K/V rows (+ the slot length), and
+  for one slot, writes that slot's K/V pages (+ the slot length and its
+  block-table row), and
   returns the logits at the LAST REAL token — the first sampling input.
   The pad tail is masked out of attention (`key_mask`) and zeroed
   before entering the cache, so pad K/V can never be attended to, now
@@ -44,9 +46,9 @@ Contracts:
   logits row (at the last REAL token — the final chunk is the only
   padded one) is the first sampling input. One jitted, donated
   executable per (chunk bucket, cache shape) — every chunk pads to the
-  same ``chunk_tokens`` bucket. On the paged path chunks are whole
-  pages, so the write is the same page-granular scatter as monolithic
-  paged prefill; the attend gathers through a ``gather_row`` passed
+  same ``chunk_tokens`` bucket. Chunks are whole pages, so the write is
+  the same page-granular scatter as monolithic prefill; the attend
+  gathers through a ``gather_row`` passed
   separately from the ``store_row`` the core installs, because the
   scheduler keeps the stored row parked on ``SCRATCH_PAGE`` until the
   final chunk (co-tenant decode/verify steps write a row for EVERY
@@ -68,7 +70,7 @@ Contracts:
   whose row landed off the leftmost chain (the forced-prefix rule) —
   the same write-then-attend rollback, no compaction pass.
 - both jitted steps DONATE the cache: the update lowers to an in-place
-  buffer write instead of a fresh ``O(L·B·H·S·d)`` copy per token.
+  buffer write instead of a fresh copy of the pool per token.
   APX512 (trace tier) verifies the donation survives into the jaxpr.
 """
 
@@ -77,15 +79,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.models.gpt import (
-    GPTConfig, GPTModel, _block_chunk_prefill, _block_chunk_prefill_paged,
-    _block_decode, _block_decode_paged, _block_decode_paged_q8,
-    _block_prefill, _block_tree_verify, _block_tree_verify_paged,
-    _block_verify, _block_verify_paged, _block_verify_paged_q8, _ln,
-    _pages_to_tiles, _rope_or_none, _tied_lm_logits, _tiles_to_pages,
+    GPTConfig, GPTModel, _block_chunk_prefill_paged, _block_decode_paged,
+    _block_decode_paged_q8, _block_prefill, _block_tree_verify_paged,
+    _block_verify_paged, _block_verify_paged_q8, _ln, _pages_to_tiles,
+    _rope_or_none, _tied_lm_logits, _tiles_to_pages,
 )
 from apex_tpu.serving.cache import (
-    KVCache, PagedKVCache, cache_partition_specs,
-    paged_cache_partition_specs, ring_page,
+    PagedKVCache, paged_cache_partition_specs, ring_page,
 )
 from apex_tpu.utils.profiler import region
 
@@ -99,68 +99,6 @@ def _final_ln(params, cfg: GPTConfig, x):
         return _ln(params["final_ln"], x, cfg.layer_norm_eps)
 
 
-def _prefill_core(params, cfg: GPTConfig, cache: KVCache, ids, mask,
-                  slot, *, embed_fn, dense_fns, logits_fn):
-    """ids (1, s_bucket) already bucket-padded; mask (s_bucket,) int32
-    with 1 = real token (``utils.seqlen.pad_to_bucket``'s convention);
-    slot: scalar int32 cache row. Returns (cache', logits (1, V))."""
-    if ids.ndim != 2 or ids.shape[0] != 1:
-        raise ValueError(f"prefill takes one slot's (1, s) ids, got "
-                         f"{ids.shape}")
-    s = ids.shape[1]
-    if s > cache.k.shape[3]:
-        raise ValueError(f"prompt bucket {s} exceeds cache max_len "
-                         f"{cache.k.shape[3]}")
-    x = embed_fn(params, ids)
-    freqs = _rope_or_none(cfg, s)
-    key_mask = mask[None, :]
-
-    def body(x, lp):
-        x, k, v = _block_prefill(lp, x, cfg, freqs, key_mask, *dense_fns)
-        return x, (k, v)
-
-    x, (k, v) = lax.scan(body, x, params["layers"])
-    hidden = _final_ln(params, cfg, x)
-    length = jnp.sum(mask).astype(jnp.int32)
-    h_last = lax.dynamic_slice_in_dim(hidden, length - 1, 1, 1)[:, 0]
-    logits = logits_fn(params, h_last)
-    # zero the pad tail before it enters the cache: decode's s <= pos
-    # mask already can't reach rows past `length`, but zeroed rows make
-    # the cache contents independent of pad ids outright (and keep the
-    # donation bit-identity tests deterministic)
-    mz = mask.astype(k.dtype)[None, None, None, :, None]
-    with region("cache_write"):
-        new = KVCache(
-            k=lax.dynamic_update_slice(
-                cache.k, (k * mz).astype(cache.k.dtype), (0, slot, 0, 0, 0)),
-            v=lax.dynamic_update_slice(
-                cache.v, (v * mz).astype(cache.v.dtype), (0, slot, 0, 0, 0)),
-            lengths=lax.dynamic_update_slice(cache.lengths, length[None],
-                                             (slot,)))
-    return new, logits
-
-
-def _decode_core(params, cfg: GPTConfig, cache: KVCache, tokens, active,
-                 *, embed_fn, dense_fns, logits_fn):
-    """tokens (B,) int32 — each slot's previous token; active (B,) bool
-    gates the length advance (freed slots stay parked). Returns
-    (cache', logits (B, V) fp32)."""
-    pos = cache.lengths
-    x = embed_fn(params, tokens[:, None], pos=pos)
-    freqs = _rope_or_none(cfg, cache.k.shape[3])
-
-    def body(x, layer_slice):
-        lp, kc, vc = layer_slice
-        x, kc, vc = _block_decode(lp, x, kc, vc, pos, cfg, freqs,
-                                  *dense_fns)
-        return x, (kc, vc)
-
-    x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _final_ln(params, cfg, x)
-    logits = logits_fn(params, hidden[:, 0])
-    return KVCache(k, v, jnp.where(active, pos + 1, pos)), logits
-
-
 def _self_rewrite(x):
     """Rewrite row 0 of ``x`` with itself. Numerically a no-op, but it
     gives XLA an update op to land the donated buffer in — an output
@@ -171,102 +109,6 @@ def _self_rewrite(x):
     return lax.dynamic_update_slice(x, first, (0,) * x.ndim)
 
 
-def _verify_core(params, cfg: GPTConfig, cache: KVCache, tokens, *,
-                 embed_fn, dense_fns, logits_fn):
-    """Speculative *verify*: tokens (B, k1) int32 — column 0 is each
-    slot's last committed (pending) token, columns 1..k its drafted
-    candidates; row j attends at absolute position ``lengths + j``.
-    Returns (cache', logits (B, k1, V) fp32) where logits row j is
-    exactly the teacher-forced distribution for the token following
-    position ``lengths + j``. Lengths are NOT advanced — acceptance is
-    a host decision (the accepted count is only known after sampling),
-    committed via a tiny host-side ``_replace`` on the returned cache.
-    The caller guarantees ``lengths + k1 <= S_max`` for every slot
-    (the scheduler's headroom guard)."""
-    pos = cache.lengths
-    x = embed_fn(params, tokens, pos=pos)
-    freqs = _rope_or_none(cfg, cache.k.shape[3])
-
-    def body(x, layer_slice):
-        lp, kc, vc = layer_slice
-        x, kc, vc = _block_verify(lp, x, kc, vc, pos, cfg, freqs,
-                                  *dense_fns)
-        return x, (kc, vc)
-
-    x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _final_ln(params, cfg, x)
-    logits = logits_fn(params, hidden)
-    return KVCache(k, v, _self_rewrite(pos)), logits
-
-
-def _tree_verify_core(params, cfg: GPTConfig, cache: KVCache, tokens,
-                      depth, anc, *, embed_fn, dense_fns, logits_fn):
-    """Tree verify: tokens (B, k1) int32 in topological order (column 0
-    = each slot's pending token, the root every branch hangs off);
-    depth (B, k1) int32 node depths (depth[0] = 0); anc (B, k1, k1)
-    bool ancestor-or-self matrix (anc[i, j]: node i on j's root path,
-    anc[j, j] = True; a linear chain is anc[i, j] = i <= j with
-    depth[j] = j, which reduces this exactly to :func:`_verify_core`).
-    Node j's position embedding/RoPE angle is ``lengths + depth[j]``
-    and logits row j is the teacher-forced distribution following j's
-    root-to-node path. Lengths are NOT advanced — the host walks the
-    accepted path and commits the contiguous row prefix."""
-    pos = cache.lengths
-    x = embed_fn(params, tokens, pos=pos[:, None] + depth)
-    freqs = _rope_or_none(cfg, cache.k.shape[3])
-
-    def body(x, layer_slice):
-        lp, kc, vc = layer_slice
-        x, kc, vc = _block_tree_verify(lp, x, kc, vc, pos, depth, anc,
-                                       cfg, freqs, *dense_fns)
-        return x, (kc, vc)
-
-    x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _final_ln(params, cfg, x)
-    logits = logits_fn(params, hidden)
-    return KVCache(k, v, _self_rewrite(pos)), logits
-
-
-def _chunk_prefill_core(params, cfg: GPTConfig, cache: KVCache, ids,
-                        mask, slot, pos, *, embed_fn, dense_fns,
-                        logits_fn):
-    """Chunked prefill: ids (1, chunk_tokens) — one chunk of one slot's
-    prompt, already padded to the chunk bucket; mask (chunk_tokens,)
-    int32 with 1 = real token (all-ones except the final chunk); slot
-    and pos scalar int32 (cache row, absolute start position). Runs the
-    verify-style write-then-attend forward over the chunk, advances the
-    slot length to ``pos + sum(mask)`` (= the true prompt length after
-    the final chunk), and returns (cache', logits (1, V)) with the
-    logits taken at the chunk's last REAL token — only the final
-    chunk's row is a sampling input; earlier chunks' rows are
-    discarded by the caller."""
-    if ids.ndim != 2 or ids.shape[0] != 1:
-        raise ValueError(f"chunk prefill takes one slot's (1, sc) ids, "
-                         f"got {ids.shape}")
-    sc = ids.shape[1]
-    if sc > cache.k.shape[3]:
-        raise ValueError(f"chunk bucket {sc} exceeds cache max_len "
-                         f"{cache.k.shape[3]}")
-    x = embed_fn(params, ids, pos=pos[None])
-    freqs = _rope_or_none(cfg, cache.k.shape[3])
-    key_mask = mask[None, :]
-
-    def body(x, layer_slice):
-        lp, kc, vc = layer_slice
-        x, kc, vc = _block_chunk_prefill(lp, x, kc, vc, slot, pos, cfg,
-                                         freqs, key_mask, *dense_fns)
-        return x, (kc, vc)
-
-    x, (k, v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    hidden = _final_ln(params, cfg, x)
-    n_real = jnp.sum(mask).astype(jnp.int32)
-    h_last = lax.dynamic_slice_in_dim(hidden, n_real - 1, 1, 1)[:, 0]
-    logits = logits_fn(params, h_last)
-    lengths = lax.dynamic_update_slice(cache.lengths,
-                                       (pos + n_real)[None], (slot,))
-    return KVCache(k, v, lengths), logits
-
-
 # ---------------------------------------------------------------------------
 # paged cores — same forwards, block-table indirection into the pool
 # ---------------------------------------------------------------------------
@@ -274,15 +116,18 @@ def _chunk_prefill_core(params, cfg: GPTConfig, cache: KVCache, ids,
 def _paged_prefill_core(params, cfg: GPTConfig, cache: PagedKVCache, ids,
                         mask, slot, write_pages, table_row, *, embed_fn,
                         dense_fns, logits_fn):
-    """Bucketed prefill into the page pool. The forward is IDENTICAL to
-    :func:`_prefill_core` (flash attention over the padded prompt); only
-    the cache write differs: the stacked per-layer k/v tiles are cut
-    into whole pages and scattered to ``write_pages`` (one physical
-    page per bucket page — the host redirects prefix-shared pages and
-    the pad tail to ``SCRATCH_PAGE``, so shared pages are never
-    rewritten), and ``table_row`` ((max_pages,) int32, NULL-padded)
-    becomes the slot's block-table row. One compiled executable per
-    bucket, independent of how many pages are shared."""
+    """Bucketed prefill into the page pool: ids (1, s_bucket) already
+    bucket-padded; mask (s_bucket,) int32 with 1 = real token
+    (``utils.seqlen.pad_to_bucket``'s convention); slot: scalar int32. The
+    forward is flash attention over the padded prompt; the stacked per-layer
+    k/v tiles, their pad tail zeroed (the cache's contents are then
+    independent of pad ids outright), are cut into whole pages and scattered
+    to ``write_pages`` (one physical page per bucket page — the host
+    redirects prefix-shared pages and the pad tail to ``SCRATCH_PAGE``, so
+    shared pages are never rewritten), and ``table_row`` ((max_pages,)
+    int32, NULL-padded) becomes the slot's block-table row. One compiled
+    executable per bucket, independent of how many pages are shared. Returns
+    (cache', logits (1, V)) at the last real token."""
     if ids.ndim != 2 or ids.shape[0] != 1:
         raise ValueError(f"prefill takes one slot's (1, s) ids, got "
                          f"{ids.shape}")
@@ -351,19 +196,20 @@ def _paged_prefill_core(params, cfg: GPTConfig, cache: PagedKVCache, ids,
 def _paged_decode_core(params, cfg: GPTConfig, cache: PagedKVCache,
                        tokens, active, *, embed_fn, dense_fns,
                        logits_fn):
-    """One token for every slot against the page pool; the host has
-    already made every slot's write target exclusive (page-boundary
+    """One token for every slot against the page pool: tokens (B,) int32 —
+    each slot's previous token; active (B,) bool gates the length advance
+    (freed slots stay parked). Returns (cache', logits (B, V) fp32). The
+    host has already made every slot's write target exclusive (page-boundary
     allocation + copy-on-write happen in
     ``PagedDecodeEngine.prepare_decode`` BEFORE this runs). Over the
-    bf16/f32 pool each layer's attention is the paged-attention kernel
-    on the WHOLE pool and the layer's index
+    bf16/f32 pool each layer's attention is the paged-attention kernel on
+    the WHOLE pool and the layer's index
     (``models.gpt._paged_decode_attention``); the int8 pool keeps the
     per-layer write + gather, with the pool as xs/ys of the scan. Block
-    tables are host-owned state riding the donated cache tuple; they
-    come back numerically unchanged, but through a self-row rewrite
-    rather than an invar passthrough — an output that IS the invar
-    gives XLA nothing to land the donation in, and APX512 would flag
-    the dropped alias pair."""
+    tables are host-owned state riding the donated cache tuple; they come
+    back numerically unchanged, but through a self-row rewrite rather than
+    an invar passthrough — an output that IS the invar gives XLA nothing to
+    land the donation in, and APX512 would flag the dropped alias pair."""
     pos = cache.lengths
     bt = cache.block_tables
     x = embed_fn(params, tokens[:, None], pos=pos)
@@ -453,12 +299,20 @@ def _write_window_rows(cache, k_rows, v_rows):
 
 def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
                        tokens, *, embed_fn, dense_fns, logits_fn):
-    """:func:`_verify_core` over the page pool. The host has already
-    made every one of the k1 write targets exclusive
-    (``prepare_decode(..., n_new=k1)`` runs boundary allocation +
-    copy-on-write for every page the candidate positions touch), so
-    the unrolled scatters never land on a shared page. Lengths and
-    block tables ride the donated tuple through the self-row rewrite."""
+    """Speculative *verify*: tokens (B, k1) int32 — column 0 is each slot's
+    last committed (pending) token, columns 1..k its drafted candidates; row
+    j attends at absolute position ``lengths + j``
+    (``models.gpt._paged_verify_attention`` has the contract). Returns
+    (cache', logits (B, k1, V) fp32). Lengths are NOT advanced — acceptance
+    is a host decision (the accepted count is only known after sampling),
+    committed via a tiny host-side ``_replace`` on the returned cache. The
+    caller guarantees ``lengths + k1 <= max_len`` for every slot (the
+    scheduler's headroom guard). The host has already made every one of the
+    k1 write targets exclusive (``prepare_decode(..., n_new=k1)`` runs
+    boundary allocation + copy-on-write for every page the candidate
+    positions touch), so the unrolled scatters never land on a shared page.
+    Lengths and block tables ride the donated tuple through the self-row
+    rewrite."""
     pos = cache.lengths
     bt = cache.block_tables
     x = embed_fn(params, tokens, pos=pos)
@@ -495,12 +349,19 @@ def _paged_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
 def _paged_tree_verify_core(params, cfg: GPTConfig, cache: PagedKVCache,
                             tokens, depth, anc, *, embed_fn, dense_fns,
                             logits_fn):
-    """:func:`_tree_verify_core` over the page pool (same
-    ``prepare_decode(..., n_new=k1)`` exclusivity precondition as
-    :func:`_paged_verify_core`). Refused for the int8 pool: committing
-    a non-leftmost branch would re-round quantized history at
-    branch-dependent scales, breaking the kv8 rejected-tail
-    bit-identity contract — the engine pins linear spec there."""
+    """Tree verify: tokens (B, k1) int32 in topological order (column 0 =
+    each slot's pending token, the root every branch hangs off); depth (B,
+    k1) int32 node depths (depth[0] = 0); anc (B, k1, k1) bool
+    ancestor-or-self matrix (anc[i, j]: node i on j's root path, anc[j, j] =
+    True; a linear chain is anc[i, j] = i <= j with depth[j] = j, which
+    reduces this exactly to :func:`_paged_verify_core`, whose
+    ``prepare_decode(..., n_new=k1)`` precondition it shares). Logits row j
+    is the teacher-forced distribution following j's root-to-node path.
+    Lengths are NOT advanced — the host walks the accepted path and commits
+    the contiguous row prefix. Refused for the int8 pool: committing a
+    non-leftmost branch would re-round quantized history at branch-dependent
+    scales, breaking the kv8 rejected-tail bit-identity contract — the
+    engine pins linear spec there."""
     if cache.k_scale is not None:
         raise ValueError("tree verify is not offered over the int8 page "
                          "pool (kv8 keeps linear speculation)")
@@ -526,18 +387,23 @@ def _paged_chunk_prefill_core(params, cfg: GPTConfig,
                               cache: PagedKVCache, ids, mask, slot, pos,
                               write_pages, gather_row, store_row, *,
                               embed_fn, dense_fns, logits_fn):
-    """:func:`_chunk_prefill_core` over the page pool. Chunks are whole
-    pages, so the write is the monolithic paged prefill's page-granular
-    scatter to ``write_pages`` (prefix-shared pages redirected to
-    ``SCRATCH_PAGE`` by the host); the attend gathers through
-    ``gather_row`` (the slot's real NULL-padded row) while
-    ``store_row`` becomes the slot's block-table row — the scheduler
-    passes an all-scratch parked row until the final chunk, so
-    co-tenant decode/verify writes mid-prefill land on scratch (see the
-    module docstring). Refused for the int8 pool: chunk queries would
-    re-read earlier chunks dequantized where monolithic prefill attends
-    fresh bf16 values, drifting first-token logits off the synchronous
-    path."""
+    """Chunked prefill: ids (1, chunk_tokens) — one chunk of one slot's
+    prompt, already padded to the chunk bucket; mask (chunk_tokens,) int32
+    with 1 = real token (all-ones except the final chunk); slot and pos
+    scalar int32 (slot, absolute start position). Runs the verify-style
+    write-then-attend forward over the chunk, advances the slot length to
+    ``pos + sum(mask)``, and returns (cache', logits (1, V)) at the chunk's
+    last REAL token — only the final chunk's row is a sampling input. Chunks
+    are whole pages, so the write is the monolithic paged prefill's
+    page-granular scatter to ``write_pages`` (prefix-shared pages redirected
+    to ``SCRATCH_PAGE`` by the host); the attend gathers through
+    ``gather_row`` (the slot's real NULL-padded row) while ``store_row``
+    becomes the slot's block-table row — the scheduler passes an all-scratch
+    parked row until the final chunk, so co-tenant decode/verify writes
+    mid-prefill land on scratch (see the module docstring). Refused for the
+    int8 pool: chunk queries would re-read earlier chunks dequantized where
+    monolithic prefill attends fresh bf16 values, drifting first-token
+    logits off the synchronous path."""
     if cache.k_scale is not None:
         raise ValueError("chunked prefill is not offered over the int8 "
                          "page pool (kv8 keeps monolithic prefill)")
@@ -595,6 +461,20 @@ def _pos_idx(pos, s):
     return pos[:, None] + jnp.arange(s)[None, :]
 
 
+def _add_positions(cfg: GPTConfig, params, x, ids, pos):
+    """``x`` plus the learned position rows (nothing under RoPE): the
+    leading ``s`` rows for a prompt (``pos`` None), else slot b's s tokens
+    at absolute positions pos[b], pos[b]+1, ... (s = 1 for decode; tree
+    verify passes explicit (b, s) positions)."""
+    if cfg.use_rope:
+        return x
+    ptab = params["embedding"]["position"]["embedding"]
+    if pos is None:
+        return x + ptab[: ids.shape[1]].astype(x.dtype)[None]
+    idx = _pos_idx(pos, ids.shape[1])
+    return x + jnp.take(ptab, idx, axis=0).astype(x.dtype)
+
+
 def _dense(p, x):
     return jnp.dot(x, p["kernel"].astype(x.dtype)) \
         + p["bias"].astype(x.dtype)
@@ -605,18 +485,8 @@ def _embed_unsharded(cfg: GPTConfig, compute_dtype):
         table = params["embedding"]["word"]["embedding"]
         if compute_dtype is not None:
             table = table.astype(compute_dtype)
-        x = jnp.take(table, ids, axis=0)
-        if not cfg.use_rope:
-            ptab = params["embedding"]["position"]["embedding"]
-            if pos is None:
-                x = x + ptab[: ids.shape[1]].astype(x.dtype)[None]
-            else:
-                # decode/verify: slot b's s tokens sit at absolute
-                # positions pos[b], pos[b]+1, ... (s = 1 for decode);
-                # tree verify passes explicit (b, s) positions
-                idx = _pos_idx(pos, ids.shape[1])
-                x = x + jnp.take(ptab, idx, axis=0).astype(x.dtype)
-        return x
+        return _add_positions(cfg, params, jnp.take(table, ids, axis=0),
+                              ids, pos)
     return embed
 
 
@@ -646,14 +516,7 @@ def _embed_w8(cfg: GPTConfig, compute_dtype):
             * jnp.take(word["scale"], ids, axis=0)[..., None]
         x = x.astype(jnp.float32 if compute_dtype is None
                      else compute_dtype)
-        if not cfg.use_rope:
-            ptab = params["embedding"]["position"]["embedding"]
-            if pos is None:
-                x = x + ptab[: ids.shape[1]].astype(x.dtype)[None]
-            else:
-                idx = _pos_idx(pos, ids.shape[1])
-                x = x + jnp.take(ptab, idx, axis=0).astype(x.dtype)
-        return x
+        return _add_positions(cfg, params, x, ids, pos)
 
     return embed
 
@@ -675,42 +538,14 @@ def _unsharded_fns(cfg: GPTConfig, compute_dtype, quantized):
             (_dense,) * 4, region("head")(_logits_unsharded))
 
 
-def make_prefill_fn(cfg: GPTConfig, compute_dtype=None, quantized=False):
-    """jit(prefill) with the cache DONATED. One compiled executable per
-    (bucket length, cache shape) — call through a bucketing layer (the
-    scheduler does) so recompiles are per bucket, never per request.
-    ``quantized`` expects the weight-only int8 tree of
-    ``apex_tpu.quant.quantize_params`` (every builder here does)."""
-    embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
-                                                 quantized)
-
-    def prefill(params, cache, ids, mask, slot):
-        return _prefill_core(params, cfg, cache, ids, mask, slot,
-                             embed_fn=embed, dense_fns=dense_fns,
-                             logits_fn=logits_fn)
-
-    return jax.jit(prefill, donate_argnums=1)
-
-
-def make_decode_fn(cfg: GPTConfig, compute_dtype=None, quantized=False):
-    """jit(decode) with the cache DONATED; compiles once per cache
-    shape (batch of slots advances together)."""
-    embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
-                                                 quantized)
-
-    def decode(params, cache, tokens, active):
-        return _decode_core(params, cfg, cache, tokens, active,
-                            embed_fn=embed, dense_fns=dense_fns,
-                            logits_fn=logits_fn)
-
-    return jax.jit(decode, donate_argnums=1)
-
-
 def make_paged_prefill_fn(cfg: GPTConfig, compute_dtype=None,
                           quantized=False):
     """jit(paged prefill), cache DONATED (4 alias pairs: pool k/v,
     lengths, block tables; 6 with an int8 cache's scales). Compiles per
-    bucket, like the dense path."""
+    bucket — call through a bucketing layer (the scheduler does) so
+    recompiles are per bucket, never per request. ``quantized`` expects
+    the weight-only int8 tree of ``apex_tpu.quant.quantize_params``
+    (every builder here does)."""
     embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
                                                  quantized)
 
@@ -740,26 +575,13 @@ def make_paged_decode_fn(cfg: GPTConfig, compute_dtype=None,
     return jax.jit(decode, donate_argnums=1)
 
 
-def make_verify_fn(cfg: GPTConfig, compute_dtype=None, quantized=False):
-    """jit(speculative verify) with the cache DONATED; one executable
-    per (cache shape, k1) — the scheduler runs a single k1 = spec_k + 1
-    bucket (shorter drafts pad with token 0; the host bounds acceptance
-    by the true draft length), so this compiles once."""
-    embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
-                                                 quantized)
-
-    def verify(params, cache, tokens):
-        return _verify_core(params, cfg, cache, tokens,
-                            embed_fn=embed, dense_fns=dense_fns,
-                            logits_fn=logits_fn)
-
-    return jax.jit(verify, donate_argnums=1)
-
-
 def make_paged_verify_fn(cfg: GPTConfig, compute_dtype=None,
                          quantized=False):
     """jit(paged speculative verify), cache DONATED (4 alias pairs; 6
-    with an int8 cache's scales)."""
+    with an int8 cache's scales); one executable per (pool shape, k1) —
+    the scheduler runs a single k1 = spec_k + 1 bucket (shorter drafts
+    pad with token 0; the host bounds acceptance by the true draft
+    length), so this compiles once."""
     embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
                                                  quantized)
 
@@ -772,27 +594,13 @@ def make_paged_verify_fn(cfg: GPTConfig, compute_dtype=None,
     return jax.jit(verify, donate_argnums=1)
 
 
-def make_tree_verify_fn(cfg: GPTConfig, compute_dtype=None,
-                        quantized=False):
-    """jit(tree verify) with the cache DONATED; one executable per
-    (cache shape, k1). Takes (params, cache, tokens (B, k1), depth
-    (B, k1) int32, anc (B, k1, k1) bool) — see
-    :func:`_tree_verify_core` for the node contract."""
-    embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
-                                                 quantized)
-
-    def verify(params, cache, tokens, depth, anc):
-        return _tree_verify_core(params, cfg, cache, tokens, depth, anc,
-                                 embed_fn=embed, dense_fns=dense_fns,
-                                 logits_fn=logits_fn)
-
-    return jax.jit(verify, donate_argnums=1)
-
-
 def make_paged_tree_verify_fn(cfg: GPTConfig, compute_dtype=None,
                               quantized=False):
-    """jit(paged tree verify), cache DONATED (4 alias pairs). Int8
-    pools are refused — see :func:`_paged_tree_verify_core`."""
+    """jit(paged tree verify), cache DONATED (4 alias pairs); one
+    executable per (pool shape, k1). Takes (params, cache, tokens
+    (B, k1), depth (B, k1) int32, anc (B, k1, k1) bool) — see
+    :func:`_paged_tree_verify_core` for the node contract. Int8 pools
+    are refused there."""
     embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
                                                  quantized)
 
@@ -805,29 +613,13 @@ def make_paged_tree_verify_fn(cfg: GPTConfig, compute_dtype=None,
     return jax.jit(verify, donate_argnums=1)
 
 
-def make_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
-                          quantized=False):
-    """jit(chunked prefill) with the cache DONATED (3 alias pairs: k,
-    v, lengths). One compiled executable per (chunk bucket, cache
-    shape) — the scheduler pads every chunk to the same
-    ``chunk_tokens`` bucket, so this compiles once per engine."""
-    embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
-                                                 quantized)
-
-    def chunk_prefill(params, cache, ids, mask, slot, pos):
-        return _chunk_prefill_core(params, cfg, cache, ids, mask, slot,
-                                   pos, embed_fn=embed,
-                                   dense_fns=dense_fns,
-                                   logits_fn=logits_fn)
-
-    return jax.jit(chunk_prefill, donate_argnums=1)
-
-
 def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
                                 quantized=False):
     """jit(paged chunked prefill), cache DONATED (4 alias pairs: pool
-    k/v, lengths, block tables). Int8 pools are refused — see
-    :func:`_paged_chunk_prefill_core`."""
+    k/v, lengths, block tables). One compiled executable per (chunk
+    bucket, pool shape) — the scheduler pads every chunk to the same
+    ``chunk_tokens`` bucket, so this compiles once per engine. Int8
+    pools are refused — see :func:`_paged_chunk_prefill_core`."""
     embed, dense_fns, logits_fn = _unsharded_fns(cfg, compute_dtype,
                                                  quantized)
 
@@ -1039,14 +831,7 @@ def _tp_fns(model: GPTModel):
 
     def embed(params, ids, pos=None):
         x = model.embed.apply(params["embedding"]["word"], ids)
-        if not cfg.use_rope:
-            ptab = params["embedding"]["position"]["embedding"]
-            if pos is None:
-                x = x + ptab[: ids.shape[1]].astype(x.dtype)[None]
-            else:
-                idx = _pos_idx(pos, ids.shape[1])
-                x = x + jnp.take(ptab, idx, axis=0).astype(x.dtype)
-        return x
+        return _add_positions(cfg, params, x, ids, pos)
 
     def logits(params, hidden):
         local = _tied_lm_logits(hidden,
@@ -1092,14 +877,7 @@ def _tp_quant_fns(model: GPTModel):
             * jnp.take(word["scale"], safe, axis=0)[..., None]
         out = jnp.where(in_range[..., None], out, 0.0)
         x = mappings.reduce_from_tensor_model_parallel_region(out)
-        if not cfg.use_rope:
-            ptab = params["embedding"]["position"]["embedding"]
-            if pos is None:
-                x = x + ptab[: ids.shape[1]].astype(x.dtype)[None]
-            else:
-                idx = _pos_idx(pos, ids.shape[1])
-                x = x + jnp.take(ptab, idx, axis=0).astype(x.dtype)
-        return x
+        return _add_positions(cfg, params, x, ids, pos)
 
     def column(p, x):
         x = mappings.copy_to_tensor_model_parallel_region(x)
@@ -1132,89 +910,32 @@ def _tp_build(model: GPTModel, quantized: bool):
     return _tp_fns(model), model.partition_specs()
 
 
-def make_tp_prefill_fn(model: GPTModel, mesh=None, quantized=False):
-    """TP prefill: ``jit(shard_map(...))`` over the global mesh, cache
-    donated. Params use ``model.partition_specs()`` (or the quantized
-    tree's ``quant_partition_specs``); the cache uses
-    ``cache_partition_specs()`` (heads over ``model``)."""
+def _shard_jit(step, mesh, pspecs, cspecs, n_rest):
+    """``jit(shard_map(step))`` over the global mesh, cache donated: params
+    by ``pspecs``, the cache in and out by ``cspecs``, the ``n_rest`` host
+    arguments behind it and the logits replicated."""
     from jax.sharding import PartitionSpec as P
 
     from apex_tpu.transformer import parallel_state as ps
 
-    cfg = model.cfg
-    (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = cache_partition_specs()
-
-    def prefill(params, cache, ids, mask, slot):
-        return _prefill_core(params, cfg, cache, ids, mask, slot,
-                             embed_fn=embed, dense_fns=dense_fns,
-                             logits_fn=logits_fn)
-
     sharded = ps.shard_map(
-        prefill, mesh=mesh,
-        in_specs=(pspecs, cspecs, P(), P(), P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
-
-
-def make_tp_decode_fn(model: GPTModel, mesh=None, quantized=False):
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
-    cfg = model.cfg
-    (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = cache_partition_specs()
-
-    def decode(params, cache, tokens, active):
-        return _decode_core(params, cfg, cache, tokens, active,
-                            embed_fn=embed, dense_fns=dense_fns,
-                            logits_fn=logits_fn)
-
-    sharded = ps.shard_map(
-        decode, mesh=mesh,
-        in_specs=(pspecs, cspecs, P(), P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
-
-
-def make_tp_verify_fn(model: GPTModel, mesh=None, quantized=False):
-    """TP speculative verify: the (b, k1, V) logits leave through the
-    same vocab-sharded head + rank-order gather as decode's."""
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
-    cfg = model.cfg
-    (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = cache_partition_specs()
-
-    def verify(params, cache, tokens):
-        return _verify_core(params, cfg, cache, tokens,
-                            embed_fn=embed, dense_fns=dense_fns,
-                            logits_fn=logits_fn)
-
-    sharded = ps.shard_map(
-        verify, mesh=mesh,
-        in_specs=(pspecs, cspecs, P()),
+        step, mesh=mesh, in_specs=(pspecs, cspecs) + (P(),) * n_rest,
         out_specs=(cspecs, P()))
     return jax.jit(sharded, donate_argnums=1)
 
 
 def make_tp_paged_prefill_fn(model: GPTModel, mesh=None, quantized=False,
                              kv_quantized=False):
-    """TP paged prefill: the pool's head axis shards over ``model``;
-    block tables / page ids are replicated host decisions, so every
-    rank scatters its local heads' tiles to the same physical pages.
-    ``kv_quantized`` switches the cache specs to the int8 pool's (the
-    scales shard their head axis over ``model`` too)."""
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
+    """TP paged prefill: ``jit(shard_map(...))`` over the global mesh, cache
+    donated. Params use ``model.partition_specs()`` (or the quantized tree's
+    ``quant_partition_specs``); the cache uses
+    ``paged_cache_partition_specs()``: the pool's head axis shards over
+    ``model``; block tables / page ids are replicated host decisions, so
+    every rank scatters its local heads' tiles to the same physical pages.
+    ``kv_quantized`` switches the cache specs to the int8 pool's (the scales
+    shard their head axis over ``model`` too)."""
     cfg = model.cfg
     (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = paged_cache_partition_specs(quantized=kv_quantized)
 
     def prefill(params, cache, ids, mask, slot, write_pages, table_row):
         return _paged_prefill_core(params, cfg, cache, ids, mask, slot,
@@ -1222,107 +943,38 @@ def make_tp_paged_prefill_fn(model: GPTModel, mesh=None, quantized=False,
                                    embed_fn=embed, dense_fns=dense_fns,
                                    logits_fn=logits_fn)
 
-    sharded = ps.shard_map(
-        prefill, mesh=mesh,
-        in_specs=(pspecs, cspecs, P(), P(), P(), P(),
-                  P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
+    return _shard_jit(prefill, mesh, pspecs,
+                      paged_cache_partition_specs(quantized=kv_quantized), 5)
 
 
 def make_tp_paged_decode_fn(model: GPTModel, mesh=None, quantized=False,
                             kv_quantized=False):
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
     cfg = model.cfg
     (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = paged_cache_partition_specs(quantized=kv_quantized)
 
     def decode(params, cache, tokens, active):
         return _paged_decode_core(params, cfg, cache, tokens, active,
                                   embed_fn=embed, dense_fns=dense_fns,
                                   logits_fn=logits_fn)
 
-    sharded = ps.shard_map(
-        decode, mesh=mesh,
-        in_specs=(pspecs, cspecs, P(), P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
+    return _shard_jit(decode, mesh, pspecs,
+                      paged_cache_partition_specs(quantized=kv_quantized), 2)
 
 
 def make_tp_paged_verify_fn(model: GPTModel, mesh=None, quantized=False,
                             kv_quantized=False):
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
+    """TP speculative verify: the (b, k1, V) logits leave through the
+    same vocab-sharded head + rank-order gather as decode's."""
     cfg = model.cfg
     (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = paged_cache_partition_specs(quantized=kv_quantized)
 
     def verify(params, cache, tokens):
         return _paged_verify_core(params, cfg, cache, tokens,
                                   embed_fn=embed, dense_fns=dense_fns,
                                   logits_fn=logits_fn)
 
-    sharded = ps.shard_map(
-        verify, mesh=mesh,
-        in_specs=(pspecs, cspecs, P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
-
-
-def make_tp_tree_verify_fn(model: GPTModel, mesh=None, quantized=False):
-    """TP tree verify: the depth/anc tree descriptors are replicated
-    host decisions (like block tables); heads shard over ``model`` and
-    the (b, k1, V) logits leave through the vocab-sharded head +
-    rank-order gather, exactly as :func:`make_tp_verify_fn`."""
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
-    cfg = model.cfg
-    (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = cache_partition_specs()
-
-    def verify(params, cache, tokens, depth, anc):
-        return _tree_verify_core(params, cfg, cache, tokens, depth, anc,
-                                 embed_fn=embed, dense_fns=dense_fns,
-                                 logits_fn=logits_fn)
-
-    sharded = ps.shard_map(
-        verify, mesh=mesh,
-        in_specs=(pspecs, cspecs, P(), P(), P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
-
-
-def make_tp_chunk_prefill_fn(model: GPTModel, mesh=None, quantized=False):
-    """TP chunked prefill: heads (and the cache head axis) shard over
-    ``model``; slot/pos/mask are replicated host decisions, and the
-    final chunk's (1, V) logits leave through the vocab-sharded head +
-    rank-order gather, exactly as :func:`make_tp_prefill_fn`."""
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
-    cfg = model.cfg
-    (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = cache_partition_specs()
-
-    def chunk_prefill(params, cache, ids, mask, slot, pos):
-        return _chunk_prefill_core(params, cfg, cache, ids, mask, slot,
-                                   pos, embed_fn=embed,
-                                   dense_fns=dense_fns,
-                                   logits_fn=logits_fn)
-
-    sharded = ps.shard_map(
-        chunk_prefill, mesh=mesh,
-        in_specs=(pspecs, cspecs, P(), P(), P(), P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
+    return _shard_jit(verify, mesh, pspecs,
+                      paged_cache_partition_specs(quantized=kv_quantized), 1)
 
 
 def make_tp_paged_chunk_prefill_fn(model: GPTModel, mesh=None,
@@ -1331,13 +983,8 @@ def make_tp_paged_chunk_prefill_fn(model: GPTModel, mesh=None,
     replicated host decisions, so every rank scatters its local heads'
     tiles to the same physical pages (int8 pools refused — no
     ``kv_quantized`` switch, as with tree verify)."""
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
     cfg = model.cfg
     (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = paged_cache_partition_specs()
 
     def chunk_prefill(params, cache, ids, mask, slot, pos, write_pages,
                       gather_row, store_row):
@@ -1346,24 +993,19 @@ def make_tp_paged_chunk_prefill_fn(model: GPTModel, mesh=None,
             gather_row, store_row, embed_fn=embed, dense_fns=dense_fns,
             logits_fn=logits_fn)
 
-    sharded = ps.shard_map(
-        chunk_prefill, mesh=mesh,
-        in_specs=(pspecs, cspecs, P(), P(), P(), P(), P(), P(), P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
+    return _shard_jit(chunk_prefill, mesh, pspecs,
+                      paged_cache_partition_specs(), 7)
 
 
 def make_tp_paged_tree_verify_fn(model: GPTModel, mesh=None,
                                  quantized=False):
-    """TP paged tree verify (int8 pools refused — linear spec only
-    there, so no ``kv_quantized`` switch)."""
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.transformer import parallel_state as ps
-
+    """TP paged tree verify: the depth/anc tree descriptors are
+    replicated host decisions (like block tables); heads shard over
+    ``model`` and the (b, k1, V) logits leave as
+    :func:`make_tp_paged_verify_fn`'s (int8 pools refused — linear spec
+    only there, so no ``kv_quantized`` switch)."""
     cfg = model.cfg
     (embed, dense_fns, logits_fn), pspecs = _tp_build(model, quantized)
-    cspecs = paged_cache_partition_specs()
 
     def verify(params, cache, tokens, depth, anc):
         return _paged_tree_verify_core(params, cfg, cache, tokens,
@@ -1371,8 +1013,5 @@ def make_tp_paged_tree_verify_fn(model: GPTModel, mesh=None,
                                        dense_fns=dense_fns,
                                        logits_fn=logits_fn)
 
-    sharded = ps.shard_map(
-        verify, mesh=mesh,
-        in_specs=(pspecs, cspecs, P(), P(), P()),
-        out_specs=(cspecs, P()))
-    return jax.jit(sharded, donate_argnums=1)
+    return _shard_jit(verify, mesh, pspecs, paged_cache_partition_specs(),
+                      3)

@@ -20,8 +20,8 @@ paths (scheduler, tests): it lowers per-slot draft trees —
 plus each slot's FORCED token chain (committed tokens whose cache rows
 must be re-sent; at least the pending token) into the padded
 ``(tokens, depth, anc, valid, start)`` arrays
-``decode.make_tree_verify_fn`` and ``sampling.tree_speculative_accept``
-consume.
+``decode.make_paged_tree_verify_fn`` and
+``sampling.tree_speculative_accept`` consume.
 """
 
 from typing import List, Sequence, Tuple

@@ -11,9 +11,13 @@ m̄ > 1.017 + draft_bytes/target_bytes, from the computed byte budgets).
 
 Lockstep + resync contract
 --------------------------
-The draft keeps its OWN dense KV cache, one row stream per target
-slot. ``_tokens[slot]`` records exactly which tokens' K/V rows the
-draft cache holds (rows ``0..len-1``). Each ``draft()`` call re-syncs
+The draft keeps its OWN cache, one row stream per target slot, on the
+layout every cache has (``serving.cache.PagedKVCache``) under a block
+table that is the identity and never changes: logical page ``j`` of slot
+``i`` is physical page ``RESERVED_PAGES + i * P + j``, so there is no
+``PagePool``, nothing is shared and the host never touches the table
+after construction. ``_tokens[slot]`` records exactly which tokens' K/V
+rows the draft cache holds (rows ``0..len-1``). Each ``draft()`` call re-syncs
 every slot to the target's committed history by COMMON PREFIX: rows
 whose recorded token still matches the committed stream are kept;
 ``lengths`` is rolled back to the first divergence and the backlog
@@ -34,7 +38,7 @@ decode steps) and the draft tree (top-``branch`` root children,
 greedy-extended leftmost chain).
 
 TP: pass a ``GPTModel(draft_cfg, tp_size)`` — the drafter then builds
-``make_tp_verify_fn``/``make_tp_decode_fn`` over the same mesh the
+``make_tp_paged_verify_fn``/``make_tp_paged_decode_fn`` over the same mesh the
 target shards on (the draft partition table is
 ``partition.tables.draft_gpt_rules``).
 """
@@ -45,12 +49,33 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.models.gpt import GPTConfig
-from apex_tpu.serving.cache import init_cache
+from apex_tpu.serving.cache import (
+    RESERVED_PAGES, init_paged_cache, max_pages_per_slot,
+)
 from apex_tpu.serving.decode import (
-    make_decode_fn, make_tp_decode_fn, make_tp_verify_fn, make_verify_fn,
+    make_paged_decode_fn, make_paged_verify_fn, make_tp_paged_decode_fn,
+    make_tp_paged_verify_fn,
 )
 
 __all__ = ["DraftModel"]
+
+#: Rows a page of the drafter's private pool. Its table is the identity, so
+#: the size decides nothing but how many rows of slack a slot's last page has.
+PAGE_SIZE = 16
+
+
+def init_draft_cache(cfg: GPTConfig, num_slots: int, rows: int,
+                     dtype=jnp.bfloat16):
+    """The drafter's private cache: ``rows`` rows a slot in whole pages of
+    a pool no other slot maps, under the identity block table (module
+    docstring)."""
+    pages = max_pages_per_slot(rows, PAGE_SIZE)
+    cache = init_paged_cache(cfg, num_slots, rows,
+                             RESERVED_PAGES + num_slots * pages, PAGE_SIZE,
+                             dtype)
+    return cache._replace(block_tables=(
+        RESERVED_PAGES + jnp.arange(num_slots * pages, dtype=jnp.int32)
+    ).reshape(num_slots, pages))
 
 
 def _common_prefix(a: Sequence[int], b: Sequence[int]) -> int:
@@ -80,20 +105,22 @@ class DraftModel:
         self.params = params
         self.num_slots = num_slots
         self.chunk = chunk
-        self.cache = init_cache(cfg, num_slots, max_len + chunk,
-                                dtype=cache_dtype)
+        self.cache = init_draft_cache(cfg, num_slots, max_len + chunk,
+                                      cache_dtype)
         from apex_tpu.quant.params import is_quantized_tree
         quantized = is_quantized_tree(params)
         if model is not None:
             if model.cfg is not cfg and model.cfg != cfg:
                 raise ValueError("TP draft model config mismatch")
-            self._verify = make_tp_verify_fn(model, mesh,
-                                             quantized=quantized)
-            self._decode = make_tp_decode_fn(model, mesh,
-                                             quantized=quantized)
+            self._verify = make_tp_paged_verify_fn(model, mesh,
+                                                   quantized=quantized)
+            self._decode = make_tp_paged_decode_fn(model, mesh,
+                                                   quantized=quantized)
         else:
-            self._verify = make_verify_fn(cfg, compute_dtype, quantized)
-            self._decode = make_decode_fn(cfg, compute_dtype, quantized)
+            self._verify = make_paged_verify_fn(cfg, compute_dtype,
+                                                quantized)
+            self._decode = make_paged_decode_fn(cfg, compute_dtype,
+                                                quantized)
         # per-slot record of which tokens' K/V rows the cache holds
         self._tokens: List[List[int]] = [[] for _ in range(num_slots)]
 

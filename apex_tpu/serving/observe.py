@@ -575,9 +575,9 @@ class Tracer:
         g.set(rate)
 
     def tick_metrics(self, committed: int, queue_depth: int,
-                     pool: Optional[Dict[str, float]] = None) -> None:
+                     pool: Dict[str, float]) -> None:
         """End-of-tick rollup: committed-token histogram, queue-depth
-        gauge, and (paged engines) pool gauges."""
+        gauge, and the pool gauges."""
         hot = self._hot
         if "tick" not in hot:
             r = self.registry
@@ -591,41 +591,40 @@ class Tracer:
         h_commit, g_queue = hot["tick"]
         h_commit.observe(committed)
         g_queue.set(queue_depth)
-        if pool:
-            if "pool" not in hot:  # dense engines never create these
+        if "pool" not in hot:
+            r = self.registry
+            hot["pool"] = (
+                r.gauge("serving_pages_free",
+                        help="free pages in the pool"),
+                r.gauge("serving_pages_cached",
+                        help="pages held only by the prefix cache "
+                             "(evictable)"),
+                r.gauge("serving_page_pool_occupancy",
+                        help="fraction of usable pages referenced"))
+        g_free, g_cached, g_occ = hot["pool"]
+        g_free.set(pool["free"])
+        g_cached.set(pool["cached"])
+        g_occ.set(pool["occupancy"])
+        if "host_pages" in pool:  # host-tier engines only
+            if "host" not in hot:
                 r = self.registry
-                hot["pool"] = (
-                    r.gauge("serving_pages_free",
-                            help="free pages in the pool"),
-                    r.gauge("serving_pages_cached",
-                            help="pages held only by the prefix cache "
-                                 "(evictable)"),
-                    r.gauge("serving_page_pool_occupancy",
-                            help="fraction of usable pages referenced"))
-            g_free, g_cached, g_occ = hot["pool"]
-            g_free.set(pool["free"])
-            g_cached.set(pool["cached"])
-            g_occ.set(pool["occupancy"])
-            if "host_pages" in pool:  # host-tier engines only
-                if "host" not in hot:
-                    r = self.registry
-                    hot["host"] = (
-                        r.gauge("serving_page_pool_hbm_used",
-                                help="HBM pages currently referenced"),
-                        r.gauge("serving_page_pool_host_pages",
-                                help="pages resident in the host spill "
-                                     "tier"),
-                        r.gauge("serving_page_pool_host_bytes",
-                                help="bytes resident in the host spill "
-                                     "tier (headers + payload + scales)"),
-                        r.gauge("serving_page_pool_host_hit_rate",
-                                help="host-tier registry hit rate since "
-                                     "start"))
-                g_hbm, g_hp, g_hb, g_hr = hot["host"]
-                g_hbm.set(pool["hbm_used"])
-                g_hp.set(pool["host_pages"])
-                g_hb.set(pool["host_bytes"])
-                g_hr.set(pool["host_hit_rate"])
+                hot["host"] = (
+                    r.gauge("serving_page_pool_hbm_used",
+                            help="HBM pages currently referenced"),
+                    r.gauge("serving_page_pool_host_pages",
+                            help="pages resident in the host spill "
+                                 "tier"),
+                    r.gauge("serving_page_pool_host_bytes",
+                            help="bytes resident in the host spill "
+                                 "tier (headers + payload + scales)"),
+                    r.gauge("serving_page_pool_host_hit_rate",
+                            help="host-tier registry hit rate since "
+                                 "start"))
+            g_hbm, g_hp, g_hb, g_hr = hot["host"]
+            g_hbm.set(pool["hbm_used"])
+            g_hp.set(pool["host_pages"])
+            g_hb.set(pool["host_bytes"])
+            g_hr.set(pool["host_hit_rate"])
 
     def latency_summary(self) -> Dict[str, float]:
         """``{ttft_p50: ..., itl_p99: ...}`` — flat quantile dict for

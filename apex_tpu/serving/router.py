@@ -11,8 +11,8 @@ deduped by the chained content hashes of
 The design reuses the whole serving stack instead of forking it: the
 :class:`DisaggregatedRouter` IS a
 :class:`~apex_tpu.serving.scheduler.ContinuousBatchingScheduler` whose
-engine is a composite (:class:`_DisaggEngine`) presenting the standard
-``DecodeEngine`` interface. Every decode-path method delegates to the
+engine is a composite (:class:`_DisaggEngine`) presenting what the
+scheduler calls of ``PagedDecodeEngine``. Every decode-path method delegates to the
 ACTIVE replica (the one backing the slots); only ``prefill`` routes:
 
 1. remote replica ``routable`` → run the prompt forward there, ship
@@ -57,7 +57,7 @@ histogram). That unblocked-decode gap is a p99 ITL win on the tick clock (not
 measured on the chip: no benchmark cell runs a router); sampling keys
 never see the clock, so streams are unaffected.
 
-Scope: both replicas must be PAGED engines with identical model
+Scope: both replicas must be engines with identical model
 config/geometry and SHARED injector+tracer (one deterministic fault
 and event sequence). Chunked prefill, model drafters/tree speculation,
 and int8 page pools stay colocated-only for now — the constructor
@@ -148,7 +148,7 @@ def _pool_names(n_prefill: int, n_decode: int):
 def _validate_replicas(prefill_engines, decode_engines) -> None:
     """The pool pairing contract, applied pairwise across ALL N+M
     replicas (the 1x1 pair is the degenerate case): every replica is a
-    distinct paged engine, every geometry/sampling attribute matches
+    distinct engine, every geometry/sampling attribute matches
     the first replica's (transitively: pairwise), and the host tier /
     injector / tracer are each ONE shared instance pool-wide — a
     per-pair check would admit a 2x2 pool whose halves fork the prefix
@@ -167,11 +167,7 @@ def _validate_replicas(prefill_engines, decode_engines) -> None:
             "disaggregation needs two engine instances per pair: every "
             "pool replica must be a DISTINCT engine (a shared instance "
             "would alias slots and page pools)")
-    for role, eng in named:
-        if not getattr(eng, "paged", False):
-            raise ValueError(
-                f"the {role} replica must be a paged engine: the "
-                "handoff ships page tiles keyed by prefix_page_keys")
+    for _, eng in named:
         if getattr(eng.cfg, "recurrent", False):
             raise ValueError(
                 "page transfer is not offered for a model with recurrent "
@@ -228,13 +224,12 @@ def _validate_replicas(prefill_engines, decode_engines) -> None:
 
 class _DisaggEngine:
     """The composite engine behind :class:`DisaggregatedRouter`:
-    presents the ``DecodeEngine`` interface over two paged replicas.
+    presents what the scheduler calls of ``PagedDecodeEngine`` over two
+    replicas.
     Attribute/method access falls through to the ACTIVE replica (the
     one whose slots the scheduler drives); ``prefill`` routes per the
     module doc. Swappable: :meth:`switch_active` exchanges the roles
     on failover."""
-
-    paged = True
 
     def __init__(self, prefill_engine, decode_engine,
                  transfer: PageTransfer,
@@ -468,11 +463,6 @@ class _DisaggEngine:
                            **self.active.pool_snapshot()},
                 "remote": {"replica": self._remote_name,
                            **self.remote.pool_snapshot()}}
-
-    def pool_gauges(self) -> Dict[str, float]:
-        # the tick gauges track the pool the slots live in; the remote
-        # pool's story is told by the per-replica transfer metrics
-        return self.active.pool_gauges()
 
 
 class _PoolEngine(_DisaggEngine):

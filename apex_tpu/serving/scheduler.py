@@ -77,7 +77,7 @@ streams bit-for-bit:
   model draft → n-gram draft → plain tick, charging no retry budget.
 - **tree speculation** (``tree_spec=True``): drafts become small trees
   (chain + alternate root branch) verified in ONE tree-attention
-  forward (``decode.make_tree_verify_fn``); the accept walk
+  forward (``decode.make_paged_tree_verify_fn``); the accept walk
   (``sampling.tree_speculative_accept``) follows the sampled
   root-to-leaf path. Cache lengths only ever advance by the
   row-contiguous committed prefix; committed tokens stranded off the
@@ -132,7 +132,7 @@ through host-side hooks, so the jitted programs — and a replayed chaos
 run — stay bit-exact.
 
 The engine's cache is DONATED to each jitted step (see
-``serving.decode``); ``DecodeEngine`` immediately rebinds
+``serving.decode``); ``PagedDecodeEngine`` immediately rebinds
 ``self.cache``, so never hold a stale reference to it across a step.
 """
 
@@ -147,16 +147,13 @@ import numpy as np
 from apex_tpu.models.gpt import GPTConfig
 from apex_tpu.serving.cache import (
     NULL_PAGE, RESERVED_PAGES, SCRATCH_PAGE, audit_block_tables,
-    MODEL_POOLS, init_cache, init_hybrid_cache, init_paged_cache,
-    max_pages_per_slot,
+    MODEL_POOLS, init_hybrid_cache, init_paged_cache, max_pages_per_slot,
 )
 from apex_tpu.serving.decode import (
-    make_chunk_prefill_fn, make_copy_page_fn, make_decode_fn,
-    make_model_decode_fn, make_model_prefill_fn,
+    make_copy_page_fn, make_model_decode_fn, make_model_prefill_fn,
     make_paged_chunk_prefill_fn, make_paged_decode_fn,
     make_paged_prefill_fn, make_paged_tree_verify_fn,
-    make_paged_verify_fn, make_prefill_fn, make_tree_verify_fn,
-    make_verify_fn, model_cores,
+    make_paged_verify_fn, model_cores,
 )
 from apex_tpu.serving.draft import ngram_draft, tree_arrays
 from apex_tpu.serving.faults import FaultInjector, InjectedFault
@@ -298,422 +295,23 @@ class _Slot:
     prefill: Optional[_PrefillProgress] = None
 
 
-class DecodeEngine:
+class PagedDecodeEngine:
     """Owns the params, the cache, and the jitted programs (bucketed
-    prefill, batched decode, speculative verify, sampling). ``top_k``,
-    ``top_p`` and ``spec_k`` are static — engine settings, compiled
-    into the programs (``spec_k`` is the DRAFT DEPTH; 0 disables
-    speculation). ``injector`` hooks the fault sites (inert by
-    default); ``tracer`` hooks the observability sites the same way
-    (``serving.observe`` — disabled by default: it records nothing,
-    and its ``begin``/``end`` still open the phase's ``apex:sched/*``
-    span on the profiler's clock); ``stats`` is the
+    prefill, batched decode, speculative verify, sampling) over the paged
+    cache: a fixed page pool, per-slot block tables, and a host-side
+    :class:`PagePool` deciding placement. ``top_k``, ``top_p`` and
+    ``spec_k`` are static — engine settings, compiled into the programs
+    (``spec_k`` is the DRAFT DEPTH; 0 disables speculation). ``injector``
+    hooks the fault sites (inert by default); ``tracer`` hooks the
+    observability sites the same way (``serving.observe`` — disabled by
+    default: it records nothing, and its ``begin``/``end`` still open the
+    phase's ``apex:sched/*`` span on the profiler's clock); ``stats`` is the
     :class:`~apex_tpu.serving.health.ServingStats` counter block the
-    scheduler shares, a view over the tracer's metrics registry."""
+    scheduler shares, a view over the tracer's metrics registry.
 
-    paged = False
-    #: The tenant whose request the scheduler is currently admitting —
-    #: stamped (tenancy mode only) right before ``prefill`` /
-    #: ``begin_chunk_prefill`` so composite engines can thread it into
-    #: their routing observability and affinity tiebreaks
-    #: (``serving.router``). Host state, never read under trace.
-    admission_tenant: Optional[str] = None
-
-    def __init__(self, params, cfg: GPTConfig, num_slots: int,
-                 max_len: int, cache_dtype=jnp.bfloat16, top_k: int = 0,
-                 top_p: float = 0.0, spec_k: int = 0,
-                 buckets: Optional[Sequence[int]] = None,
-                 compute_dtype=None,
-                 injector: Optional[FaultInjector] = None,
-                 draft_model=None, tree_spec: bool = False,
-                 adaptive_spec: bool = False,
-                 tracer: Optional[Tracer] = None):
-        _refuse_for_recurrent(cfg, **{"the dense cache": (
-            True, "the recurrent state lives beside the paged pool; use "
-            "PagedDecodeEngine")})
-        _refuse_without_a_core(cfg, **{"the dense cache": (
-            True, "its decode core reads pages; use PagedDecodeEngine")})
-        self.params = params
-        self.cfg = cfg
-        self.num_slots = num_slots
-        self.max_len = max_len
-        if buckets is None:
-            buckets = default_buckets(max_len, min(128, max_len))
-        # clamp the ladder to the cache: prefill rejects buckets beyond
-        # S_max, and the top-of-ladder bucket may overshoot max_len
-        self.buckets = tuple(sorted({min(int(b), max_len)
-                                     for b in buckets}))
-        self.top_k = top_k
-        self.top_p = top_p
-        self.spec_k = spec_k
-        self._check_spec_config(draft_model, tree_spec, adaptive_spec)
-        self.draft_model = draft_model
-        self.tree_spec = tree_spec
-        self.adaptive_spec = adaptive_spec
-        self.injector = injector or FaultInjector()
-        self.tracer = _on_profiler_clock(tracer)
-        self.stats = ServingStats(registry=self.tracer.registry)
-        if jnp.dtype(cache_dtype) == jnp.int8:
-            raise ValueError(
-                "the dense cache has no int8 mode (per-page scales need "
-                "pages); use PagedDecodeEngine for kv_dtype=int8")
-        # weight-only int8 trees are auto-detected: the builders swap in
-        # the dequant-fused kernels, everything else is unchanged
-        quantized = is_quantized_tree(params)
-        self.cache = init_cache(cfg, num_slots, max_len, cache_dtype)
-        self._prefill = make_prefill_fn(cfg, compute_dtype, quantized)
-        self._chunk_prefill = make_chunk_prefill_fn(cfg, compute_dtype,
-                                                    quantized)
-        self._decode = make_decode_fn(cfg, compute_dtype, quantized)
-        self._verify = make_verify_fn(cfg, compute_dtype, quantized)
-        self._tree_verify = make_tree_verify_fn(
-            cfg, compute_dtype, quantized) if tree_spec else None
-        self._init_samplers()
-
-    def _check_spec_config(self, draft_model, tree_spec,
-                           adaptive_spec) -> None:
-        if (draft_model is not None or tree_spec or adaptive_spec) \
-                and self.spec_k < 1:
-            raise ValueError(
-                "draft_model / tree_spec / adaptive_spec require "
-                "spec_k >= 1 (speculation is otherwise disabled)")
-        if draft_model is not None:
-            if draft_model.num_slots != self.num_slots:
-                raise ValueError(
-                    f"draft model has {draft_model.num_slots} slots, "
-                    f"engine has {self.num_slots}")
-            if draft_model.cfg.vocab_size != self.cfg.vocab_size:
-                raise ValueError(
-                    "draft and target models must share a vocabulary "
-                    f"({draft_model.cfg.vocab_size} vs "
-                    f"{self.cfg.vocab_size})")
-
-    def _init_samplers(self) -> None:
-        self._sample = jax.jit(sample_stream_checked,
-                               static_argnames=("top_k", "top_p"))
-        self._sample_grid = jax.jit(sample_stream_grid_checked,
-                                    static_argnames=("top_k", "top_p"))
-
-    def prefill(self, slot: int, prompt: Sequence[int]) -> jax.Array:
-        """Run the full forward over ``prompt`` into cache row ``slot``;
-        returns the last-real-token logits (1, V). Raises
-        :class:`~apex_tpu.serving.health.PoolExhausted` when capacity
-        can't cover the prompt (paged engine) and
-        :class:`~apex_tpu.serving.faults.InjectedFault` under an armed
-        ``prefill_exec`` fault site — both with all transient resources
-        rolled back."""
-        fired, _ = self.injector.draw("prefill_exec")
-        if fired:
-            raise InjectedFault("prefill_exec",
-                                self.injector.calls("prefill_exec") - 1)
-        trc = self.tracer
-        trc.begin("prefill", request_id=trc.admitting, slot=slot,
-                  bucket=bucket_for(len(prompt), self.buckets),
-                  prompt_tokens=len(prompt))
-        ids, mask = _pad_on_host(prompt, self.buckets)
-        self.cache, logits = self._prefill(
-            self.params, self.cache, ids, mask, jnp.int32(slot))
-        trc.end("prefill")
-        return logits
-
-    # -- chunked prefill ------------------------------------------------
-
-    def begin_chunk_prefill(self, slot: int,
-                            prompt: Sequence[int]) -> Dict:
-        """Stage a chunked prefill of ``prompt`` into ``slot``; returns
-        the opaque per-request state :meth:`chunk_prefill` consumes.
-        The dense cache needs no staging (rows are slot-owned), so the
-        state only carries the chunking start offset."""
-        if len(prompt) > self.max_len:
-            raise ValueError(
-                f"prompt length {len(prompt)} exceeds cache max_len "
-                f"{self.max_len}")
-        return {"start": 0}
-
-    def chunk_prefill(self, slot: int, chunk: Sequence[int], pos: int,
-                      state: Dict, bucket: int,
-                      final: bool) -> jax.Array:
-        """Run ONE prompt chunk (rows ``pos .. pos+len(chunk)-1``) for
-        ``slot``; every call pads to ``bucket`` tokens, so exactly one
-        executable exists per chunk size. Returns the chunk's
-        last-real-token logits (1, V) — only the final chunk's feed the
-        first sampled token. An armed ``chunk_prefill_exec`` fault site
-        raises :class:`InjectedFault` BEFORE touching the cache."""
-        fired, _ = self.injector.draw("chunk_prefill_exec")
-        if fired:
-            raise InjectedFault(
-                "chunk_prefill_exec",
-                self.injector.calls("chunk_prefill_exec") - 1)
-        ids, mask = _pad_on_host(chunk, (bucket,))
-        trc = self.tracer
-        trc.begin("chunk_prefill", slot=slot, pos=pos, bucket=bucket,
-                  final=final)
-        self.cache, logits = self._chunk_prefill(
-            self.params, self.cache, ids, mask, jnp.int32(slot),
-            jnp.int32(pos))
-        trc.end("chunk_prefill")
-        return logits
-
-    def finish_chunk_prefill(self, slot: int, state: Dict) -> None:
-        """Post-final-chunk bookkeeping (prefix registration on the
-        paged engine); a no-op for the dense cache."""
-
-    def decode(self, tokens: jax.Array, active: jax.Array) -> jax.Array:
-        """One token for every slot; ``active`` gates length advance.
-        Returns (num_slots, V) fp32 logits. An armed ``decode_exec``
-        fault site overwrites one deterministic victim row with NaN
-        AFTER the jitted step — the compiled program and the other
-        rows stay bit-exact, and the finiteness gate in the sampler's
-        program (:func:`~apex_tpu.serving.sampling.finite_rows`) must
-        catch it."""
-        trc = self.tracer
-        trc.begin("exec", kind="decode", **self._exec_stats())
-        self.sync_table()
-        self.cache, logits = self._decode(self.params, self.cache,
-                                          tokens, active)
-        trc.end("exec")
-        fired, payload = self.injector.draw("decode_exec")
-        if fired:
-            victim = int(payload % logits.shape[0])
-            logits = logits.at[victim].set(jnp.nan)
-        return logits
-
-    def _exec_stats(self) -> Dict[str, int]:
-        """What the ``exec`` span says beyond its kind (nothing here)."""
-        return {}
-
-    def sync_table(self) -> None:
-        """Called before a decode, verify or tree-verify program is
-        launched: the paged engine sends its block table if the host
-        changed it (nothing here: a dense cache row is its own map)."""
-
-    def sample(self, logits, base, counts, temperature) -> jax.Array:
-        """LAUNCH the checked sampler on ``logits`` (B, V) and return its
-        result on the device, not waited for: (2, B) int32, ``[0]`` one
-        token per row — row b draws with ``fold_in(base[b], counts[b])``,
-        derived inside the program
-        (:func:`~apex_tpu.serving.sampling.stream_keys`) from the
-        request's base key and the number of the token — and ``[1]``
-        which rows are finite, i.e. safe to commit
-        (:func:`~apex_tpu.serving.sampling.finite_rows`). Called right
-        behind the step or prefill that made ``logits``, with host
-        arrays for the rest: they go up while the chip still runs the
-        step, the program queues behind it, and the copy down starts
-        when it ends. The caller's ``np.asarray`` is the one wait."""
-        return self._launched(self._sample(
-            logits, base, counts, temperature, top_k=self.top_k,
-            top_p=self.top_p))
-
-    def _launched(self, out: jax.Array) -> jax.Array:
-        """A checked sampler's result (2, B[, k1]) on its way down: the
-        ``sample`` fault site drawn (it writes into the victim slot's
-        token, on a grid its FIRST position), the copy to the host
-        started, nothing waited for."""
-        fired, payload = self.injector.draw("sample")
-        if fired:
-            # out-of-vocabulary id: negative, so it can never collide
-            # with a real token — the scheduler's range check quarantines
-            at = (0, int(payload % out.shape[1])) + (0,) * (out.ndim - 2)
-            out = out.at[at].set(jnp.int32(-1 - payload % 7))
-        out.copy_to_host_async()
-        return out
-
-    # -- speculative decoding -------------------------------------------
-
-    def draft(self, history: Sequence[int]) -> List[int]:
-        """Host-side n-gram draft of up to ``spec_k`` candidates from
-        one slot's prompt+generated history. An armed ``draft_exec``
-        fault site raises :class:`InjectedFault` — the scheduler
-        degrades that slot to an empty draft (plain decode pace) for
-        the tick; drafting is best-effort, so no retry budget is
-        charged."""
-        fired, _ = self.injector.draw("draft_exec")
-        if fired:
-            raise InjectedFault("draft_exec",
-                                self.injector.calls("draft_exec") - 1)
-        return ngram_draft(history, self.spec_k)
-
-    def _draft_ladder(self) -> bool:
-        """The model drafter's two-rung ``draft_exec`` ladder: one draw
-        decides whether the MODEL draft fails this tick; a fired draw
-        counts a draft fault and takes a second draw deciding whether
-        the n-gram fallback fails too (raising :class:`InjectedFault`,
-        which the scheduler turns into a plain tick). Returns True when
-        the caller should use the n-gram rung. No rung charges retry
-        budget — drafting is best-effort."""
-        fired, _ = self.injector.draw("draft_exec")
-        if not fired:
-            return False
-        self.stats.draft_faults += 1
-        fired, _ = self.injector.draw("draft_exec")
-        if fired:
-            raise InjectedFault("draft_exec",
-                                self.injector.calls("draft_exec") - 1)
-        return True
-
-    def draft_batch(self, histories, ks) -> List[List[int]]:
-        """Model-draft every slot in ONE batched call: up to ``ks[i]``
-        greedy continuation tokens of ``histories[i]`` from the
-        attached :class:`~apex_tpu.serving.draft_model.DraftModel`
-        (``None`` history or ``k = 0`` yields an empty draft). The
-        ``draft_exec`` ladder (:meth:`_draft_ladder`) degrades model →
-        n-gram → plain."""
-        if self._draft_ladder():
-            return [list(ngram_draft(h, k)) if h is not None else []
-                    for h, k in zip(histories, ks)]
-        return [[int(t) for t in c]
-                for c in self.draft_model.draft(histories, ks)]
-
-    def draft_tree_batch(self, histories, ks):
-        """Tree drafts (``(tokens, parents)`` per slot, ``None`` when
-        inactive) from the model drafter — a greedy chain plus an
-        alternate root branch, see :meth:`DraftModel.draft_tree`. The
-        same ``draft_exec`` ladder applies; its n-gram rung emits
-        single-chain trees."""
-        if self._draft_ladder():
-            out = []
-            for h, k in zip(histories, ks):
-                c = [int(t) for t in ngram_draft(h, k)] \
-                    if h is not None else []
-                out.append((c, [-1] + list(range(len(c) - 1)))
-                           if c else None)
-            return out
-        return self.draft_model.draft_tree(histories, ks)
-
-    def verify(self, tokens: jax.Array) -> jax.Array:
-        """One speculative verify step: ``tokens`` (num_slots, spec_k+1)
-        int32 — column 0 the pending token, columns 1.. the (0-padded)
-        drafts. Returns (num_slots, spec_k+1, V) fp32 logits; slot
-        lengths are committed separately (:meth:`commit`) once the host
-        accept walk knows each slot's count. The ``decode_exec`` fault
-        site covers this step too (the victim row goes NaN across all
-        positions, post-jit)."""
-        trc = self.tracer
-        trc.begin("exec", kind="verify", k1=int(tokens.shape[1]))
-        self.sync_table()
-        self.cache, logits = self._verify(self.params, self.cache,
-                                          tokens)
-        trc.end("exec")
-        fired, payload = self.injector.draw("decode_exec")
-        if fired:
-            victim = int(payload % logits.shape[0])
-            logits = logits.at[victim].set(jnp.nan)
-        return logits
-
-    def tree_verify(self, tokens: jax.Array, depth: jax.Array,
-                    anc: jax.Array) -> jax.Array:
-        """One tree-attention verify step over a packed draft grid (see
-        :func:`~apex_tpu.serving.draft.tree_arrays`): column j writes
-        K/V at physical row ``lengths + j`` with sequence position
-        ``lengths + depth[:, j]`` and attends committed rows plus its
-        ancestor columns under ``anc``. Returns (num_slots, k1, V) fp32
-        logits; commits stay host-side (:meth:`commit`). Shares the
-        ``decode_exec`` fault site with the other step kinds."""
-        trc = self.tracer
-        trc.begin("exec", kind="tree_verify", k1=int(tokens.shape[1]))
-        self.sync_table()
-        self.cache, logits = self._tree_verify(self.params, self.cache,
-                                               tokens, depth, anc)
-        trc.end("exec")
-        fired, payload = self.injector.draw("decode_exec")
-        if fired:
-            victim = int(payload % logits.shape[0])
-            logits = logits.at[victim].set(jnp.nan)
-        return logits
-
-    def commit(self, counts: Sequence[int]) -> None:
-        """Advance slot lengths by each slot's committed token count —
-        the host half of the verify step's rollback contract: rows
-        beyond ``lengths + count`` were written but are never admitted
-        by any mask before the next step re-writes them."""
-        trc = self.tracer
-        trc.begin("commit")
-        self.cache = self.cache._replace(
-            lengths=self.cache.lengths
-            + jnp.asarray(counts, jnp.int32))
-        trc.end("commit")
-
-    def sample_grid(self, logits, base, counts, temperature) -> jax.Array:
-        """:meth:`sample` over a verify step's (B, k1, V) logits: launches
-        the checked grid sampler and returns (2, B, k1) int32 on the
-        device, every (slot, position) drawn with its own key,
-        ``fold_in(base[b], counts[b, j])``, derived in the same program;
-        the ``sample`` fault site corrupts the victim slot's FIRST
-        position (the one a plain tick would have drawn), so the
-        scheduler's range gate quarantines before any commit."""
-        return self._launched(self._sample_grid(
-            logits, base, counts, temperature, top_k=self.top_k,
-            top_p=self.top_p))
-
-    # scheduler hooks, no-ops for the dense engine: a cache row needs
-    # no per-token capacity and frees by being overwritten
-    def _prefill_bytes(self, private_pages: int) -> Dict[str, int]:
-        """What a prefill of a model that brings its cores writes, by the
-        facts its config states (``serving.decode``, "the seam"): the slot's
-        recurrent state, its cycle of window pages, its private pages of a
-        latent pool; state AND latent pages where both facts hold."""
-        if not self.model_cores:
-            return {}
-        said = {}
-        if self.recurrent:
-            said["state_bytes"] = self._state_bytes
-        if self._window_bytes:
-            said["window_bytes"] = self._window_bytes
-        elif self._latent:
-            said["latent_bytes"] = private_pages * self._page_bytes
-        return said
-
-    def page_demand(self, total_len: int) -> None:
-        """Validate a request's worst-case capacity need at submit."""
-
-    def prepare_decode(self, positions: Dict[int, int],
-                       n_new: int = 1) -> List[int]:
-        """Make every slot's next ``n_new`` write targets exclusive;
-        returns slots that had to be preempted (none for the dense
-        cache)."""
-        return []
-
-    def free_slot(self, slot: int) -> None:
-        """Release slot-owned resources on eviction/preemption (the
-        attached draft model's lockstep cache row, when present)."""
-        if self.draft_model is not None:
-            self.draft_model.free_slot(slot)
-
-    def check_invariants(self) -> bool:
-        """Audit engine-owned bookkeeping (pool refcounts, block
-        tables); trivially true for the dense cache."""
-        return True
-
-    def pool_snapshot(self) -> Dict:
-        """Allocator state for diagnostics (LivelockError payloads)."""
-        return {}
-
-    def pool_gauges(self) -> Optional[Dict[str, float]]:
-        """Gauge sources for the tracer's end-of-tick rollup
-        (``None``: the dense cache has no page pool to meter)."""
-        return None
-
-    def pop_admit_charge(self, default: int) -> int:
-        """Tick-clock cost of the admission/prefill forward the
-        scheduler just ran — consumed (and reset) by
-        ``ContinuousBatchingScheduler._charge_work``. The base engine
-        charges the ``default`` (the forward's sequential depth);
-        engines that replaced part of that depth with cheaper work
-        stage a different charge here: a host-tier promotion prices
-        the skipped prefix at transfer ticks, the disaggregated
-        composite prices a remote prefill at handoff ticks, and the
-        pool composite at the per-link reshard horizon it extends
-        (so concurrent handoffs on different links overlap). Purely
-        accounting — sampling keys never see the clock."""
-        return default
-
-
-class PagedDecodeEngine(DecodeEngine):
-    """:class:`DecodeEngine` over the paged cache: a fixed page pool,
-    per-slot block tables, and a host-side :class:`PagePool` deciding
-    placement. Adds prefix sharing at admission (page runs keyed by the
-    chained prompt-prefix hash are retained instead of recomputed —
-    including a partial last page on an exact match) and copy-on-write:
+    Admission shares prefixes (page runs keyed by the chained
+    prompt-prefix hash are retained instead of recomputed — including a
+    partial last page on an exact match) and appends copy on write:
     ``prepare_decode`` runs before every decode tick to allocate
     page-boundary pages and clone any shared page a slot is about to
     append into, so the jitted decode step only ever writes
@@ -740,7 +338,12 @@ class PagedDecodeEngine(DecodeEngine):
     bit-identity tests drive different orders through this knob).
     """
 
-    paged = True
+    #: The tenant whose request the scheduler is currently admitting —
+    #: stamped (tenancy mode only) right before ``prefill`` /
+    #: ``begin_chunk_prefill`` so composite engines can thread it into
+    #: their routing observability and affinity tiebreaks
+    #: (``serving.router``). Host state, never read under trace.
+    admission_tenant: Optional[str] = None
 
     def __init__(self, params, cfg: GPTConfig, num_slots: int,
                  max_len: int, num_pages: int, page_size: int,
@@ -921,7 +524,28 @@ class PagedDecodeEngine(DecodeEngine):
             self._tree_verify = make_paged_tree_verify_fn(
                 cfg, compute_dtype, quantized) if tree_spec else None
         self._copy = make_copy_page_fn()
-        self._init_samplers()
+        self._sample = jax.jit(sample_stream_checked,
+                               static_argnames=("top_k", "top_p"))
+        self._sample_grid = jax.jit(sample_stream_grid_checked,
+                                    static_argnames=("top_k", "top_p"))
+
+    def _check_spec_config(self, draft_model, tree_spec,
+                           adaptive_spec) -> None:
+        if (draft_model is not None or tree_spec or adaptive_spec) \
+                and self.spec_k < 1:
+            raise ValueError(
+                "draft_model / tree_spec / adaptive_spec require "
+                "spec_k >= 1 (speculation is otherwise disabled)")
+        if draft_model is not None:
+            if draft_model.num_slots != self.num_slots:
+                raise ValueError(
+                    f"draft model has {draft_model.num_slots} slots, "
+                    f"engine has {self.num_slots}")
+            if draft_model.cfg.vocab_size != self.cfg.vocab_size:
+                raise ValueError(
+                    "draft and target models must share a vocabulary "
+                    f"({draft_model.cfg.vocab_size} vs "
+                    f"{self.cfg.vocab_size})")
 
     @staticmethod
     def full_pool_pages(num_slots: int, max_len: int,
@@ -952,14 +576,8 @@ class PagedDecodeEngine(DecodeEngine):
                 jax.ShapeDtypeStruct((self.num_slots,), jnp.bool_)),
         }
 
-    def _exec_stats(self) -> Dict[str, int]:
-        """A model with recurrent layers says how many slots' state the
-        step updates: the slots that hold a request (each maps pages)."""
-        if not self.recurrent:
-            return {}
-        return {"state_slots": sum(1 for p in self._slot_pages if p)}
-
     def page_demand(self, total_len: int) -> None:
+        """Validate a request's worst-case capacity need at submit."""
         need = max_pages_per_slot(min(total_len, self.max_len),
                                   self.page_size)
         usable = self.pool.num_pages - RESERVED_PAGES
@@ -967,6 +585,45 @@ class PagedDecodeEngine(DecodeEngine):
             raise ValueError(
                 f"request needs up to {need} pages but the pool only "
                 f"has {usable} usable pages")
+
+    def _stage_pages(self, prompt: Sequence[int]):
+        """The pages of an admission, all or nothing: share the longest
+        cached prefix run, promote what the host tier holds of the rest,
+        allocate private pages for what is left. Returns ``(toks, keys,
+        pages, row, covered, promote_ticks)`` — ``covered`` leading pages
+        hold rows already (shared or promoted), ``row`` is the slot's
+        NULL-padded block-table row — with one reference taken per page;
+        raises ``ValueError`` for a prompt beyond ``max_len`` BEFORE touching
+        the pool and :class:`PoolExhausted` with every reference released."""
+        toks = [int(t) for t in prompt]
+        if len(toks) > self.max_len:
+            raise ValueError(
+                f"prompt length {len(toks)} exceeds cache max_len "
+                f"{self.max_len}")
+        n_pages = max_pages_per_slot(len(toks), self.page_size)
+        keys = prefix_page_keys(toks, self.page_size)
+        pages = self.pool.match_prefix(keys) if self.prefix_sharing \
+            else []
+        promote_ticks = 0
+        if self.host_tier is not None and self.prefix_sharing \
+                and len(pages) < n_pages:
+            promoted, promote_ticks = self._promote_chain(keys, len(pages))
+            pages = pages + promoted
+        covered = len(pages)
+        for _ in range(n_pages - covered):
+            p = self.pool.alloc()
+            if p is None:
+                for q in pages:
+                    self.pool.release(q)
+                raise PoolExhausted(
+                    f"prompt needs {n_pages} pages; pool has "
+                    f"{self.pool.num_free} free and nothing left to "
+                    "evict", need=n_pages, free=self.pool.num_free,
+                    cached=self.pool.num_cached)
+            pages.append(p)
+        row = np.full((self.max_pages,), NULL_PAGE, np.int32)
+        row[:n_pages] = pages
+        return toks, keys, pages, row, covered, promote_ticks
 
     def prefill(self, slot: int, prompt: Sequence[int]) -> jax.Array:
         """Admit ``prompt`` into ``slot``: share the longest cached
@@ -983,35 +640,9 @@ class PagedDecodeEngine(DecodeEngine):
         ``max_len`` BEFORE touching the pool (the scheduler's submit
         check normally screens this, but the engine must not leak page
         references when driven directly)."""
-        toks = [int(t) for t in prompt]
-        if len(toks) > self.max_len:
-            raise ValueError(
-                f"prompt length {len(toks)} exceeds cache max_len "
-                f"{self.max_len}")
-        n_pages = max_pages_per_slot(len(toks), self.page_size)
-        keys = prefix_page_keys(toks, self.page_size)
-        shared = self.pool.match_prefix(keys) if self.prefix_sharing \
-            else []
-        promoted: List[int] = []
-        promote_ticks = 0
-        if self.host_tier is not None and self.prefix_sharing \
-                and len(shared) < n_pages:
-            promoted, promote_ticks = self._promote_chain(
-                keys, len(shared))
-        covered = len(shared) + len(promoted)
-        private: List[int] = []
-        for _ in range(n_pages - covered):
-            p = self.pool.alloc()
-            if p is None:
-                for q in shared + promoted + private:
-                    self.pool.release(q)
-                raise PoolExhausted(
-                    f"prompt needs {n_pages} pages; pool has "
-                    f"{self.pool.num_free} free and nothing left to "
-                    "evict", need=n_pages, free=self.pool.num_free,
-                    cached=self.pool.num_cached)
-            private.append(p)
-        pages = shared + promoted + private
+        toks, keys, pages, row, covered, promote_ticks = \
+            self._stage_pages(prompt)
+        n_pages = len(pages)
         fired, _ = self.injector.draw("prefill_exec")
         if fired:
             for q in pages:
@@ -1019,9 +650,6 @@ class PagedDecodeEngine(DecodeEngine):
             raise InjectedFault("prefill_exec",
                                 self.injector.calls("prefill_exec") - 1)
         self._slot_pages[slot] = list(pages)
-
-        row = np.full((self.max_pages,), NULL_PAGE, np.int32)
-        row[:n_pages] = pages
         # either program below stores ``row`` as the slot's row on the
         # device: the host mirrors it and nothing is left to upload
         self._table[slot] = row
@@ -1042,24 +670,15 @@ class PagedDecodeEngine(DecodeEngine):
                   bucket=bucket_for(len(toks) - start, self.buckets),
                   prompt_tokens=len(toks), shared_pages=covered,
                   page_size=self.page_size,
-                  **self._prefill_bytes(len(private)))
+                  **self._prefill_bytes(n_pages - covered))
+        ids, mask = _pad_on_host(toks[start:], self.buckets)
+        write = self._write_pages(ids.shape[1], skip, pages, covered)
         if skip:
-            ids, mask = _pad_on_host(toks[start:], self.buckets)
-            write = np.full((ids.shape[1] // self.page_size,),
-                            SCRATCH_PAGE, np.int32)
-            for j in range(write.shape[0]):
-                ai = skip + j
-                if covered <= ai < n_pages:
-                    write[j] = pages[ai]
             self.cache, logits = self._chunk_prefill(
                 self.params, self.cache, ids, mask, jnp.int32(slot),
                 jnp.int32(start), jnp.asarray(write), jnp.asarray(row),
                 jnp.asarray(row))
         else:
-            ids, mask = _pad_on_host(toks, self.buckets)
-            write = np.full((ids.shape[1] // self.page_size,),
-                            SCRATCH_PAGE, np.int32)
-            write[covered:n_pages] = private
             self.cache, logits = self._prefill(
                 self.params, self.cache, ids, mask, jnp.int32(slot),
                 jnp.asarray(write), jnp.asarray(row))
@@ -1072,6 +691,35 @@ class PagedDecodeEngine(DecodeEngine):
             # pop_admit_charge handshake the disagg handoff uses)
             self._admit_charge = (len(toks) - start) + promote_ticks
         return logits
+
+    def _write_pages(self, bucket: int, first_page: int,
+                     pages: Sequence[int], covered: int) -> np.ndarray:
+        """One physical page per page of a ``bucket``-token forward that
+        starts at the slot's logical page ``first_page``: the slot's own
+        page beyond the ``covered`` ones (shared and promoted pages hold
+        their rows already and are never rewritten), scratch for those and
+        for the pad beyond the prompt."""
+        write = np.full((bucket // self.page_size,), SCRATCH_PAGE, np.int32)
+        lo = max(covered, first_page)
+        hi = min(len(pages), first_page + len(write))
+        write[lo - first_page:hi - first_page] = pages[lo:hi]
+        return write
+
+    def _prefill_bytes(self, private_pages: int) -> Dict[str, int]:
+        """What a prefill of a model that brings its cores writes, by the
+        facts its config states (``serving.decode``, "the seam"): the slot's
+        recurrent state, its cycle of window pages, its private pages of a
+        latent pool; state AND latent pages where both facts hold."""
+        if not self.model_cores:
+            return {}
+        said = {}
+        if self.recurrent:
+            said["state_bytes"] = self._state_bytes
+        if self._window_bytes:
+            said["window_bytes"] = self._window_bytes
+        elif self._latent:
+            said["latent_bytes"] = private_pages * self._page_bytes
+        return said
 
     # -- chunked prefill ------------------------------------------------
 
@@ -1090,35 +738,9 @@ class PagedDecodeEngine(DecodeEngine):
         (their rows are the original owner's, reused verbatim); the
         last page always runs so the final chunk yields the
         first-token logits."""
-        toks = [int(t) for t in prompt]
-        if len(toks) > self.max_len:
-            raise ValueError(
-                f"prompt length {len(toks)} exceeds cache max_len "
-                f"{self.max_len}")
-        n_pages = max_pages_per_slot(len(toks), self.page_size)
-        keys = prefix_page_keys(toks, self.page_size)
-        shared = self.pool.match_prefix(keys) if self.prefix_sharing \
-            else []
-        promoted: List[int] = []
-        promote_ticks = 0
-        if self.host_tier is not None and self.prefix_sharing \
-                and len(shared) < n_pages:
-            promoted, promote_ticks = self._promote_chain(
-                keys, len(shared))
-        covered = len(shared) + len(promoted)
-        private: List[int] = []
-        for _ in range(n_pages - covered):
-            p = self.pool.alloc()
-            if p is None:
-                for q in shared + promoted + private:
-                    self.pool.release(q)
-                raise PoolExhausted(
-                    f"prompt needs {n_pages} pages; pool has "
-                    f"{self.pool.num_free} free and nothing left to "
-                    "evict", need=n_pages, free=self.pool.num_free,
-                    cached=self.pool.num_cached)
-            private.append(p)
-        pages = shared + promoted + private
+        toks, keys, pages, row, covered, promote_ticks = \
+            self._stage_pages(prompt)
+        n_pages = len(pages)
         self._slot_pages[slot] = list(pages)
         self._prefill_parked.add(slot)
         if promote_ticks:
@@ -1126,12 +748,9 @@ class PagedDecodeEngine(DecodeEngine):
             # per chunk, so the extra rides the next pop (additively —
             # several staged prefills may promote before one pops)
             self._admit_extra += promote_ticks
-        row = np.full((self.max_pages,), NULL_PAGE, np.int32)
-        row[:n_pages] = pages
         skip = min(covered, max(n_pages - 1, 0))
         return {"keys": keys, "pages": pages, "shared": covered,
-                "n_pages": n_pages, "row": row,
-                "start": skip * self.page_size}
+                "row": row, "start": skip * self.page_size}
 
     def chunk_prefill(self, slot: int, chunk: Sequence[int], pos: int,
                       state: Dict, bucket: int,
@@ -1152,13 +771,8 @@ class PagedDecodeEngine(DecodeEngine):
                 "chunk_prefill_exec",
                 self.injector.calls("chunk_prefill_exec") - 1)
         ids, mask = _pad_on_host(chunk, (bucket,))
-        first_page = pos // self.page_size
-        write = np.full((bucket // self.page_size,), SCRATCH_PAGE,
-                        np.int32)
-        for j in range(write.shape[0]):
-            ai = first_page + j
-            if state["shared"] <= ai < state["n_pages"]:
-                write[j] = state["pages"][ai]
+        write = self._write_pages(bucket, pos // self.page_size,
+                                  state["pages"], state["shared"])
         if final:
             store = state["row"]
             self._prefill_parked.discard(slot)
@@ -1182,10 +796,17 @@ class PagedDecodeEngine(DecodeEngine):
             self.pool.register_prefix(state["keys"], state["pages"])
 
     def pop_admit_charge(self, default: int) -> int:
-        """Pop the staged admission charge (see base class). A
-        host-tier prefill stages an ABSOLUTE charge (suffix depth +
-        promote ticks); chunked admissions accumulate promote ticks
-        ADDITIVELY on top of the per-chunk default."""
+        """Tick-clock cost of the admission/prefill forward the scheduler
+        just ran — consumed (and reset) by
+        ``ContinuousBatchingScheduler._charge_work``: the ``default`` (the
+        forward's sequential depth) unless cheaper work replaced part of
+        that depth. A host-tier prefill stages an ABSOLUTE charge (suffix
+        depth + promote ticks); chunked admissions accumulate promote ticks
+        ADDITIVELY on top of the per-chunk default; the disaggregated
+        composite prices a remote prefill at handoff ticks, and the pool
+        composite at the per-link reshard horizon it extends
+        (``serving.router``). Purely accounting — sampling keys never see
+        the clock."""
         charge, self._admit_charge = self._admit_charge, None
         extra, self._admit_extra = self._admit_extra, 0
         return (default if charge is None else charge) + extra
@@ -1375,7 +996,8 @@ class PagedDecodeEngine(DecodeEngine):
         preempted.append(slot)
 
     def free_slot(self, slot: int) -> None:
-        """Release the slot's page references and park its block-table
+        """Release the slot's page references (and the attached draft
+        model's lockstep cache row, when present) and park its block-table
         row on scratch (a freed slot's parked decode writes must never
         land in a page the allocator may hand to someone else). The
         parking is a host write: the decode program writes a row for
@@ -1424,6 +1046,183 @@ class PagedDecodeEngine(DecodeEngine):
             self._table.copy(), leaf.sharding if leaf.committed else None))
         self._table_dirty = False
         self.stats.block_table_uploads += 1
+
+    def decode(self, tokens: jax.Array, active: jax.Array) -> jax.Array:
+        """One token for every slot; ``active`` gates length advance.
+        Returns (num_slots, V) fp32 logits. An armed ``decode_exec``
+        fault site overwrites one deterministic victim row with NaN
+        AFTER the jitted step — the compiled program and the other
+        rows stay bit-exact, and the finiteness gate in the sampler's
+        program (:func:`~apex_tpu.serving.sampling.finite_rows`) must
+        catch it."""
+        return self._step("decode", self._decode, tokens, active,
+                          **self._exec_stats())
+
+    def _exec_stats(self) -> Dict[str, int]:
+        """What the ``exec`` span of a decode step says beyond its kind: a
+        model with recurrent layers says how many slots' state the step
+        updates, the slots that hold a request (each maps pages)."""
+        if not self.recurrent:
+            return {}
+        return {"state_slots": sum(1 for p in self._slot_pages if p)}
+
+    def _step(self, kind: str, program, *args, **said) -> jax.Array:
+        """Launch a step program on the cache under the ``exec`` span, the
+        block table uploaded first if the host changed it, and draw the
+        ``decode_exec`` fault site on its logits: one deterministic victim
+        row overwritten with NaN AFTER the jitted step (across all
+        positions of a verify grid) — the compiled program and the other
+        rows stay bit-exact."""
+        trc = self.tracer
+        trc.begin("exec", kind=kind, **said)
+        self.sync_table()
+        self.cache, logits = program(self.params, self.cache, *args)
+        trc.end("exec")
+        fired, payload = self.injector.draw("decode_exec")
+        if fired:
+            victim = int(payload % logits.shape[0])
+            logits = logits.at[victim].set(jnp.nan)
+        return logits
+
+    def sample(self, logits, base, counts, temperature) -> jax.Array:
+        """LAUNCH the checked sampler on ``logits`` (B, V) and return its
+        result on the device, not waited for: (2, B) int32, ``[0]`` one
+        token per row — row b draws with ``fold_in(base[b], counts[b])``,
+        derived inside the program
+        (:func:`~apex_tpu.serving.sampling.stream_keys`) from the
+        request's base key and the number of the token — and ``[1]``
+        which rows are finite, i.e. safe to commit
+        (:func:`~apex_tpu.serving.sampling.finite_rows`). Called right
+        behind the step or prefill that made ``logits``, with host
+        arrays for the rest: they go up while the chip still runs the
+        step, the program queues behind it, and the copy down starts
+        when it ends. The caller's ``np.asarray`` is the one wait."""
+        return self._launched(self._sample(
+            logits, base, counts, temperature, top_k=self.top_k,
+            top_p=self.top_p))
+
+    def sample_grid(self, logits, base, counts, temperature) -> jax.Array:
+        """:meth:`sample` over a verify step's (B, k1, V) logits: launches
+        the checked grid sampler and returns (2, B, k1) int32 on the
+        device, every (slot, position) drawn with its own key,
+        ``fold_in(base[b], counts[b, j])``, derived in the same program;
+        the ``sample`` fault site corrupts the victim slot's FIRST
+        position (the one a plain tick would have drawn), so the
+        scheduler's range gate quarantines before any commit."""
+        return self._launched(self._sample_grid(
+            logits, base, counts, temperature, top_k=self.top_k,
+            top_p=self.top_p))
+
+    def _launched(self, out: jax.Array) -> jax.Array:
+        """A checked sampler's result (2, B[, k1]) on its way down: the
+        ``sample`` fault site drawn (it writes into the victim slot's
+        token, on a grid its FIRST position), the copy to the host
+        started, nothing waited for."""
+        fired, payload = self.injector.draw("sample")
+        if fired:
+            # out-of-vocabulary id: negative, so it can never collide
+            # with a real token — the scheduler's range check quarantines
+            at = (0, int(payload % out.shape[1])) + (0,) * (out.ndim - 2)
+            out = out.at[at].set(jnp.int32(-1 - payload % 7))
+        out.copy_to_host_async()
+        return out
+
+    # -- speculative decoding -------------------------------------------
+
+    def draft(self, history: Sequence[int]) -> List[int]:
+        """Host-side n-gram draft of up to ``spec_k`` candidates from
+        one slot's prompt+generated history. An armed ``draft_exec``
+        fault site raises :class:`InjectedFault` — the scheduler
+        degrades that slot to an empty draft (plain decode pace) for
+        the tick; drafting is best-effort, so no retry budget is
+        charged."""
+        fired, _ = self.injector.draw("draft_exec")
+        if fired:
+            raise InjectedFault("draft_exec",
+                                self.injector.calls("draft_exec") - 1)
+        return ngram_draft(history, self.spec_k)
+
+    def _draft_ladder(self) -> bool:
+        """The model drafter's two-rung ``draft_exec`` ladder: one draw
+        decides whether the MODEL draft fails this tick; a fired draw
+        counts a draft fault and takes a second draw deciding whether
+        the n-gram fallback fails too (raising :class:`InjectedFault`,
+        which the scheduler turns into a plain tick). Returns True when
+        the caller should use the n-gram rung. No rung charges retry
+        budget — drafting is best-effort."""
+        fired, _ = self.injector.draw("draft_exec")
+        if not fired:
+            return False
+        self.stats.draft_faults += 1
+        fired, _ = self.injector.draw("draft_exec")
+        if fired:
+            raise InjectedFault("draft_exec",
+                                self.injector.calls("draft_exec") - 1)
+        return True
+
+    def draft_batch(self, histories, ks) -> List[List[int]]:
+        """Model-draft every slot in ONE batched call: up to ``ks[i]``
+        greedy continuation tokens of ``histories[i]`` from the
+        attached :class:`~apex_tpu.serving.draft_model.DraftModel`
+        (``None`` history or ``k = 0`` yields an empty draft). The
+        ``draft_exec`` ladder (:meth:`_draft_ladder`) degrades model →
+        n-gram → plain."""
+        if self._draft_ladder():
+            return [list(ngram_draft(h, k)) if h is not None else []
+                    for h, k in zip(histories, ks)]
+        return [[int(t) for t in c]
+                for c in self.draft_model.draft(histories, ks)]
+
+    def draft_tree_batch(self, histories, ks):
+        """Tree drafts (``(tokens, parents)`` per slot, ``None`` when
+        inactive) from the model drafter — a greedy chain plus an
+        alternate root branch, see :meth:`DraftModel.draft_tree`. The
+        same ``draft_exec`` ladder applies; its n-gram rung emits
+        single-chain trees."""
+        if self._draft_ladder():
+            out = []
+            for h, k in zip(histories, ks):
+                c = [int(t) for t in ngram_draft(h, k)] \
+                    if h is not None else []
+                out.append((c, [-1] + list(range(len(c) - 1)))
+                           if c else None)
+            return out
+        return self.draft_model.draft_tree(histories, ks)
+
+    def verify(self, tokens: jax.Array) -> jax.Array:
+        """One speculative verify step: ``tokens`` (num_slots, spec_k+1)
+        int32 — column 0 the pending token, columns 1.. the (0-padded)
+        drafts. Returns (num_slots, spec_k+1, V) fp32 logits; slot
+        lengths are committed separately (:meth:`commit`) once the host
+        accept walk knows each slot's count. The ``decode_exec`` fault
+        site covers this step too (the victim row goes NaN across all
+        positions, post-jit)."""
+        return self._step("verify", self._verify, tokens,
+                          k1=int(tokens.shape[1]))
+
+    def tree_verify(self, tokens: jax.Array, depth: jax.Array,
+                    anc: jax.Array) -> jax.Array:
+        """One tree-attention verify step over a packed draft grid (see
+        :func:`~apex_tpu.serving.draft.tree_arrays`): column j writes
+        K/V at physical row ``lengths + j`` with sequence position
+        ``lengths + depth[:, j]`` and attends committed rows plus its
+        ancestor columns under ``anc``. Returns (num_slots, k1, V) fp32
+        logits; commits stay host-side (:meth:`commit`). Shares the
+        ``decode_exec`` fault site with the other step kinds."""
+        return self._step("tree_verify", self._tree_verify, tokens, depth,
+                          anc, k1=int(tokens.shape[1]))
+
+    def commit(self, counts: Sequence[int]) -> None:
+        """Advance slot lengths by each slot's committed token count —
+        the host half of the verify step's rollback contract: rows
+        beyond ``lengths + count`` were written but are never admitted
+        by any mask before the next step re-writes them."""
+        trc = self.tracer
+        trc.begin("commit")
+        self.cache = self.cache._replace(
+            lengths=self.cache.lengths
+            + jnp.asarray(counts, jnp.int32))
+        trc.end("commit")
 
     def check_invariants(self) -> bool:
         """Full pool audit: host-side refcount/free-list/registry
@@ -1481,7 +1280,7 @@ class ContinuousBatchingScheduler:
     retry budgets, deterministic deadlines, bounded admission, a
     progress watchdog, and an optional per-tick invariant audit."""
 
-    def __init__(self, engine: DecodeEngine, eos_id: int, *,
+    def __init__(self, engine: PagedDecodeEngine, eos_id: int, *,
                  max_retries: int = 3, max_queue: Optional[int] = None,
                  watchdog_limit: int = 64, audit: bool = False,
                  chunk_tokens: Optional[int] = None,
@@ -1498,13 +1297,13 @@ class ContinuousBatchingScheduler:
         # per-tick token budget (see _prefill_phase). None keeps the
         # classic monolithic admission prefill.
         _refuse_for_recurrent(
-            getattr(engine, "cfg", None),
+            engine.cfg,
             **{"chunked prefill (chunk_tokens=)": (
                 chunk_tokens is not None, "a chunk would have to start "
                 "from the recurrent state the chunk before it left, which "
                 "no program carries")})
         _refuse_without_a_core(
-            getattr(engine, "cfg", None),
+            engine.cfg,
             **{"chunked prefill (chunk_tokens=)": (
                 chunk_tokens is not None, "a chunk attends the rows of the "
                 "chunks before it, which needs a core that reads them back "
@@ -1519,12 +1318,12 @@ class ContinuousBatchingScheduler:
                     f"chunk_tokens {chunk_tokens} must divide the "
                     f"cache max_len {engine.max_len} (chunk starts "
                     "must never overrun the cache row)")
-            if engine.paged and chunk_tokens % engine.page_size:
+            if chunk_tokens % engine.page_size:
                 raise ValueError(
                     f"paged chunks write whole pages: chunk_tokens "
                     f"{chunk_tokens} is not a multiple of page_size "
                     f"{engine.page_size}")
-            if getattr(engine.cache, "k_scale", None) is not None:
+            if engine.cache.k_scale is not None:
                 raise ValueError(
                     "chunked prefill is not offered over the int8 "
                     "page pool: incremental chunk writes would "
@@ -1575,14 +1374,7 @@ class ContinuousBatchingScheduler:
         # covers the reservation books.
         self.tenancy = tenancy
         if tenancy is not None:
-            if tenancy.needs_quota and not getattr(engine, "paged", False):
-                raise ValueError(
-                    "tenant page quotas price KV pages: they need a "
-                    "paged engine (drop the quotas or use "
-                    "PagedDecodeEngine)")
-            pool = getattr(engine, "pool", None)
-            if pool is not None:
-                pool.ledger = tenancy.ledger
+            engine.pool.ledger = tenancy.ledger
         # per-token streaming (serving.streaming): streams=True builds
         # a StreamMux on the engine's injector/tracer/stats; passing a
         # StreamMux keeps the caller's sink. None disables staging.
@@ -1781,7 +1573,7 @@ class ContinuousBatchingScheduler:
         budget. Purely an accounting change: sampling keys fold in
         token counts, never ticks, so committed streams are
         untouched. The engine may reprice the charge via
-        :meth:`DecodeEngine.pop_admit_charge` — a host-tier promote
+        :meth:`PagedDecodeEngine.pop_admit_charge` — a host-tier promote
         shrinks the forward to the suffix depth but adds transfer
         ticks, and the disaggregated router charges handoff ticks the
         same way."""
@@ -1899,15 +1691,11 @@ class ContinuousBatchingScheduler:
         """Worst-case page reservation for one request: the pages that
         hold prompt + ``max_new_tokens`` + the verify step's spec_k
         overshoot, capped at the cache row — the same sizing the
-        submit-time ``page_demand`` fail-fast prices. 0 on dense
-        engines (quotas price KV pages; dense caches are per-slot)."""
+        submit-time ``page_demand`` fail-fast prices."""
         eng = self.engine
-        page_size = getattr(eng, "page_size", None)
-        if page_size is None:
-            return 0
         total = min(len(req.prompt) + req.max_new_tokens + eng.spec_k,
                     eng.max_len)
-        return max_pages_per_slot(total, page_size)
+        return max_pages_per_slot(total, eng.page_size)
 
     def _promote_next(self) -> bool:
         """Tenancy admission selection: rotate the best queued
@@ -2338,7 +2126,7 @@ class ContinuousBatchingScheduler:
         sites — drafting is best-effort, so a fault degrades to plain
         pace without charging retry budget; model-drafter engines
         degrade down the ladder in
-        :meth:`DecodeEngine.draft_batch`)."""
+        :meth:`PagedDecodeEngine.draft_batch`)."""
         eng = self.engine
         hists = self._histories(ks)
         if eng.draft_model is not None:
@@ -2364,7 +2152,7 @@ class ContinuousBatchingScheduler:
         """One draft tree per slot (``None`` for free slots, depth-0
         slots, and fault-degraded ticks). Model-drafter engines walk
         the ``draft_exec`` ladder in
-        :meth:`DecodeEngine.draft_tree_batch`; n-gram engines chain
+        :meth:`PagedDecodeEngine.draft_tree_batch`; n-gram engines chain
         their linear drafts as single-branch trees."""
         eng = self.engine
         hists = self._histories(ks)

@@ -57,8 +57,8 @@ class Tenant:
     """One traffic class. ``weight`` is the fair-share ratio (tokens
     per tick converge to ``weight / sum(weights)`` among backlogged
     tenants); ``page_quota`` caps the worst-case KV pages its live
-    requests may reserve (``None`` = unlimited, dense engines ignore
-    it); ``priority`` rungs gate preemption — a strictly higher rung
+    requests may reserve (``None`` = unlimited); ``priority`` rungs gate
+    preemption — a strictly higher rung
     may requeue a resident lower rung; the ``*_slo_ticks`` bounds are
     checked at finish and stamp a typed
     :class:`~apex_tpu.serving.health.SloViolation` into
@@ -120,15 +120,6 @@ class TenancyPolicy:
 
     def has(self, tenant: str) -> bool:
         return tenant in self.tenants
-
-    @property
-    def needs_quota(self) -> bool:
-        """True when any tenant declares a page quota — the scheduler
-        requires a paged engine in that case (quotas price KV pages)."""
-        for name in sorted(self.tenants):
-            if self.tenants[name].page_quota is not None:
-                return True
-        return False
 
     def priority(self, tenant: str) -> int:
         return self.tenants[tenant].priority

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """KV-cached GPT generation CLI — drives ``apex_tpu.serving`` end to
 end: bf16 inference params (``amp`` O2 model cast), a preallocated
-donated KV cache, bucketed prefill, and continuous batching over a
+donated page pool, bucketed prefill, and continuous batching over a
 fixed slot set with greedy or temperature/top-k sampling.
 
 Synthetic weights + prompts (the in-tree models are test-scale); run on
@@ -28,10 +28,11 @@ sys.path.insert(0, __file__.rsplit("/examples/", 1)[0])
 from apex_tpu import amp  # noqa: E402
 from apex_tpu.models.gpt import GPTConfig, init_gpt  # noqa: E402
 from apex_tpu.serving import (  # noqa: E402
-    ContinuousBatchingScheduler, DecodeEngine, Request,
+    ContinuousBatchingScheduler, PagedDecodeEngine, Request,
 )
 from apex_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
+PAGE_SIZE = 16
 
 def parse_args():
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
@@ -47,7 +48,8 @@ def parse_args():
                         "KV cache)")
     s = p.add_argument_group("serving")
     s.add_argument("--num-slots", type=int, default=4)
-    s.add_argument("--max-len", type=int, default=128)
+    s.add_argument("--max-len", type=int, default=128,
+                   help=f"cache rows a slot: whole pages of {PAGE_SIZE}")
     s.add_argument("--top-k", type=int, default=0)
     r = p.add_argument_group("requests")
     r.add_argument("--prompt", action="append", default=None,
@@ -77,9 +79,12 @@ def main():
         params = amp.initialize("O2", verbosity=0).cast_model(params)
     cache_dtype = jnp.float32 if ns.fp32 else jnp.bfloat16
 
-    engine = DecodeEngine(params, cfg, num_slots=ns.num_slots,
-                          max_len=ns.max_len, cache_dtype=cache_dtype,
-                          top_k=ns.top_k)
+    # a full pool: every slot can hold max_len rows, nothing is preempted
+    engine = PagedDecodeEngine(
+        params, cfg, num_slots=ns.num_slots, max_len=ns.max_len,
+        num_pages=PagedDecodeEngine.full_pool_pages(
+            ns.num_slots, ns.max_len, PAGE_SIZE),
+        page_size=PAGE_SIZE, cache_dtype=cache_dtype, top_k=ns.top_k)
     sched = ContinuousBatchingScheduler(engine, eos_id=ns.eos_id)
 
     if ns.prompt:

@@ -241,11 +241,12 @@ def test_repo_costs_clean_under_committed_budgets(repo_reports):
     assert findings == [], "\n".join(f.render() for f in findings)
 
 
-def test_medium_decode_matches_r8_hand_roofline(repo_reports):
-    """The decode ceiling by hand: every param byte plus the parked K/V
-    history per step. The interpreter must
+def test_medium_decode_matches_hand_roofline(repo_reports):
+    """The decode ceiling by hand: every param byte plus the K/V rows the
+    ragged ladder holds, page by page, per step. The interpreter must
     land within 10% of that independent derivation."""
-    rep = {r.entry: r for r in repo_reports}["gpt_decode_step_medium"]
+    rep = {r.entry: r for r in repo_reports}[
+        "gpt_paged_decode_step_medium_ragged"]
 
     from apex_tpu.models.gpt import GPTConfig, init_gpt
     cfg = GPTConfig(use_rope=True)
@@ -254,8 +255,9 @@ def test_medium_decode_matches_r8_hand_roofline(repo_reports):
     param_bytes = sum(
         leaf.size * leaf.dtype.itemsize
         for leaf in jax.tree_util.tree_leaves(params))
-    kv_bytes = (32 * cfg.num_layers * cfg.num_heads * 512
-                * (cfg.hidden_size // cfg.num_heads) * 2 * 2)
+    # the registry entry's ladder: 32 slots, uniform 32..512, pages of 64
+    pages = sum(-(-(32 + round(i * 480 / 31)) // 64) for i in range(32))
+    kv_bytes = pages * 64 * cfg.num_layers * cfg.hidden_size * 2 * 2
     hand = param_bytes + kv_bytes
     assert abs(rep.hbm_total_bytes - hand) / hand < 0.10
 
@@ -286,7 +288,9 @@ def test_seeded_donation_removal_fires_apx601(tmp_path):
     """Strip ``donate_argnums=1`` from the decode jit: the KV cache now
     writes a full second buffer every step, which must blow through a
     manifest seeded from the donating version."""
-    from apex_tpu.lint.traced.registry import _serving_args, _serving_cfg
+    from apex_tpu.lint.traced.registry import (
+        _paged_serving_args, _serving_cfg,
+    )
     from apex_tpu.serving import decode
 
     seeded = _scratch_import(
@@ -299,11 +303,12 @@ def test_seeded_donation_removal_fires_apx601(tmp_path):
     # registry's 2x32 shape is param-bound and wouldn't clear the
     # 1.25x ceiling even doubled)
     cfg = _serving_cfg()
-    params, cache = _serving_args(cfg, num_slots=8, max_len=256)
+    params, cache = _paged_serving_args(cfg, num_slots=8, max_len=256,
+                                        num_pages=8 * 16 + 2)
     args = (params, cache, _sds((8,), "int32"), _sds((8,), "bool"))
 
     def rep_of(mod):
-        closed = jax.make_jaxpr(mod.make_decode_fn(cfg))(*args)
+        closed = jax.make_jaxpr(mod.make_paged_decode_fn(cfg))(*args)
         return cost.compute(closed, "decode.py", "decode_step")
 
     clean, bad = rep_of(decode), rep_of(seeded)

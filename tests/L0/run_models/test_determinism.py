@@ -154,18 +154,6 @@ def _lower_gpt(which: str):
     i32 = jnp.int32
     tokens, active = jnp.zeros((slots,), i32), jnp.ones((slots,), bool)
     ids, mask = jnp.zeros((1, 8), i32), jnp.ones((8,), i32)
-    if which in ("prefill", "decode", "verify", "chunk_prefill"):
-        cache = C.init_cache(cfg, slots, max_len, jnp.float32)
-        return {
-            "prefill": lambda: D.make_prefill_fn(cfg).lower(
-                params, cache, ids, mask, i32(0)),
-            "decode": lambda: D.make_decode_fn(cfg).lower(
-                params, cache, tokens, active),
-            "verify": lambda: D.make_verify_fn(cfg).lower(
-                params, cache, jnp.zeros((slots, 3), i32)),
-            "chunk_prefill": lambda: D.make_chunk_prefill_fn(cfg).lower(
-                params, cache, ids, mask, i32(0), i32(0)),
-        }[which]()
     dtype = jnp.int8 if which.endswith("_q8") else jnp.float32
     cache = C.init_paged_cache(cfg, slots, max_len, slots * 8 + 2, page,
                                dtype)
@@ -173,6 +161,10 @@ def _lower_gpt(which: str):
         "paged_prefill": lambda: D.make_paged_prefill_fn(cfg).lower(
             params, cache, ids, mask, i32(0), jnp.zeros((2,), i32),
             jnp.zeros((8,), i32)),
+        "paged_chunk_prefill": lambda: D.make_paged_chunk_prefill_fn(
+            cfg).lower(params, cache, ids, mask, i32(0), i32(0),
+                       jnp.zeros((2,), i32), jnp.zeros((8,), i32),
+                       jnp.zeros((8,), i32)),
         "paged_decode": lambda: D.make_paged_decode_fn(cfg).lower(
             params, cache, tokens, active),
         "paged_decode_q8": lambda: D.make_paged_decode_fn(cfg).lower(
@@ -190,20 +182,25 @@ def _lower_gpt(which: str):
 def _lower_model(family: str, which: str):
     """The prefill or decode program of a model that brings its cores, at
     its tiny size."""
-    from apex_tpu.models import deepseek, hybrid, nemotron_h
+    from apex_tpu.models import (bailing_hybrid, deepseek, exaone_moe, hybrid,
+                                 nemotron_h)
     from apex_tpu.serving import cache as C
     from apex_tpu.serving import decode as D
 
-    cfg, init, init_cache = {
+    cfg, init, init_pools = {
         "hybrid": (hybrid.hybrid_tiny(), hybrid.init_hybrid,
                    C.init_hybrid_cache),
         "nemotron_h": (nemotron_h.nemotron_h_tiny(), nemotron_h.init,
                        C.init_hybrid_cache),
         "deepseek": (deepseek.deepseek_tiny(), deepseek.init,
-                     C.init_latent_cache)}[family]
+                     C.init_latent_cache),
+        "exaone": (exaone_moe.exaone_moe_tiny(), exaone_moe.init,
+                   C.init_window_cache),
+        "ling": (bailing_hybrid.bailing_hybrid_tiny(), bailing_hybrid.init,
+                 C.init_hybrid_cache)}[family]
     params = init(jax.random.PRNGKey(0), cfg)
     slots, max_len, page = 2, 32, 4
-    cache = init_cache(cfg, slots, max_len, slots * 8 + 2, page, jnp.float32)
+    cache = init_pools(cfg, slots, max_len, slots * 8 + 2, page, jnp.float32)
     i32 = jnp.int32
     if which == "decode":
         return D.make_model_decode_fn(cfg).lower(
@@ -220,12 +217,10 @@ _SCOPED = [
     ("bert_train_step", lambda: _lower_bert_step(False), _TRAIN),
     ("bert_train_step_ddp", lambda: _lower_bert_step(True),
      _TRAIN | {"grad_sync"}),
-    ("gpt_prefill", lambda: _lower_gpt("prefill"), _GPT | {"cache_write"}),
-    ("gpt_decode", lambda: _lower_gpt("decode"), _GPT),
-    ("gpt_verify", lambda: _lower_gpt("verify"), _GPT),
-    ("gpt_chunk_prefill", lambda: _lower_gpt("chunk_prefill"), _GPT),
     ("gpt_paged_prefill", lambda: _lower_gpt("paged_prefill"),
      _GPT | {"cache_write"}),
+    ("gpt_paged_chunk_prefill", lambda: _lower_gpt("paged_chunk_prefill"),
+     _GPT),
     ("gpt_paged_decode", lambda: _lower_gpt("paged_decode"),
      _GPT | {"cache_write"}),
     ("gpt_paged_decode_q8", lambda: _lower_gpt("paged_decode_q8"), _GPT),
@@ -243,6 +238,15 @@ _SCOPED = [
     ("deepseek_prefill", lambda: _lower_model("deepseek", "prefill"),
      _SPARSE),
     ("deepseek_decode", lambda: _lower_model("deepseek", "decode"), _SPARSE),
+    # K-EXAONE's routing runs inside its ``experts`` region
+    ("exaone_prefill", lambda: _lower_model("exaone", "prefill"),
+     _SPARSE - {"router"}),
+    ("exaone_decode", lambda: _lower_model("exaone", "decode"),
+     _SPARSE - {"router"}),
+    ("ling_prefill", lambda: _lower_model("ling", "prefill"),
+     _SPARSE | {"mixer"}),
+    ("ling_decode", lambda: _lower_model("ling", "decode"),
+     _SPARSE | {"mixer"}),
 ]
 
 

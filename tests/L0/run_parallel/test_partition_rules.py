@@ -12,7 +12,6 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu.partition import (
     gpt_rules,
-    kv_cache_rules,
     make_mesh,
     make_shard_and_gather_fns,
     match_partition_rules,
@@ -98,17 +97,20 @@ def test_optimizer_state_specs_track_param_specs():
 
 
 def test_cache_partition_specs_derive_from_rules():
-    from apex_tpu.serving.cache import cache_partition_specs
+    from apex_tpu.serving.cache import paged_cache_partition_specs
 
-    specs = cache_partition_specs()
-    assert specs.k == P(None, None, ps.TENSOR_AXIS, None, None)
+    specs = paged_cache_partition_specs()
+    assert specs.k == P(None, None, None, ps.TENSOR_AXIS)
     assert specs.v == specs.k
-    assert specs.lengths == P()
+    assert specs.lengths == P() and specs.block_tables == P()
+    quant = paged_cache_partition_specs(quantized=True)
+    assert quant.k == specs.k
+    assert quant.k_scale == quant.v_scale == P(None, None, ps.TENSOR_AXIS)
     # a custom table flows through
-    flipped = ((r"(^|/)(k|v)$", P(None, None, None, ps.TENSOR_AXIS, None)),
-               (r"(^|/)lengths$", P()))
-    assert cache_partition_specs(flipped).k == \
-        P(None, None, None, ps.TENSOR_AXIS, None)
+    flipped = ((r"(^|/)(k|v)$", P(None, None, ps.TENSOR_AXIS, None)),
+               (r"(^|/)(lengths|block_tables)$", P()))
+    assert paged_cache_partition_specs(flipped).k == \
+        P(None, None, ps.TENSOR_AXIS, None)
 
 
 def test_fused_adam_state_partition_specs():

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import deepseek
-from apex_tpu.serving import (ContinuousBatchingScheduler, DecodeEngine,
+from apex_tpu.serving import (ContinuousBatchingScheduler,
                               DisaggregatedRouter, PagedDecodeEngine, Request,
                               Tracer)
 from apex_tpu.serving.cache import LatentKVCache
@@ -245,8 +245,6 @@ def test_the_rest_is_refused_where_it_is_asked_for(tiny):
     with pytest.raises(ValueError, match=r"chunked prefill \(chunk_tokens=\)"
                        r".*latent pool"):
         ContinuousBatchingScheduler(eng, eos_id=-1, chunk_tokens=16)
-    with pytest.raises(ValueError, match="dense cache.*latent pool"):
-        DecodeEngine(params, cfg, num_slots=2, max_len=32)
     quantized = {**params, "embedding": {"word": {
         **params["embedding"]["word"], "scale": jnp.ones((8,))}}}
     with pytest.raises(ValueError, match="weight-only int8.*latent pool"):

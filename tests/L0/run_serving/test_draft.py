@@ -226,3 +226,115 @@ def test_draft_model_tree_adds_second_best_root():
     assert toks[:2] == _greedy_reference(params, cfg, [5, 9, 3, 7], 2)
     assert parents == [-1, 0, -1]  # chain + the alternative root
     assert toks[2] != toks[0]  # genuinely second-best, not a duplicate
+
+
+# -- the drafter's private cache: one pool, an identity table ----------------
+
+def _identity_table(dm):
+    import numpy as np
+
+    from apex_tpu.serving.cache import RESERVED_PAGES
+
+    pages = dm.cache.block_tables.shape[1]
+    return RESERVED_PAGES + np.arange(dm.num_slots * pages).reshape(
+        dm.num_slots, pages)
+
+
+def test_draft_model_table_is_the_identity_and_never_changes():
+    """No ``PagePool``, nothing shared, no host table traffic: logical
+    page j of slot i is physical page RESERVED + i * P + j when the
+    drafter is built and after drafts, a roll-back and a freed slot; no
+    slot maps the null or the scratch page, and no two slots a page."""
+    import numpy as np
+
+    cfg, params, dm = _draft_setup()
+    want = _identity_table(dm)
+    assert dm.cache.block_tables.shape == (2, 3)    # 32 + 5 rows, pages of 16
+    assert dm.cache.k.shape[1] == want.max() + 1    # and no page more
+    np.testing.assert_array_equal(np.asarray(dm.cache.block_tables), want)
+    first = dm.draft([[5, 9, 3, 7], [11, 13, 2]], [3, 2])
+    dm.draft([[5, 9, 3, 7, first[0][0], 1], [11, 13, 2, first[1][0]]],
+             [3, 2])
+    dm.free_slot(1)
+    dm.draft([[5, 9, 3, 7, first[0][0], 1, 4], None], [2, 0])
+    np.testing.assert_array_equal(np.asarray(dm.cache.block_tables), want)
+    assert len(set(want.ravel())) == want.size
+
+
+def test_draft_model_rolls_back_a_rejected_tail():
+    """Two drafters reach the same committed history, one of them
+    through a draft whose tail the target rejected (its rows stand in
+    the pool past the common prefix): the rolled-back rows are
+    overwritten before any mask admits them, so both give the root
+    distribution of that history and the same next chains."""
+    import numpy as np
+
+    cfg, params, dm = _draft_setup()
+    _, _, fresh = _draft_setup()
+    h = [5, 9, 3, 7, 2, 8]
+    tail = dm.draft([h, None], [4, 0])[0]
+    # one draft accepted, then a token the drafter did not propose
+    h2 = h + [tail[0], (tail[1] + 1) % cfg.vocab_size]
+    assert len(dm._tokens[0]) == len(h) + 3    # h and three fed drafts
+    rolled = dm._sync([h2, None])
+    assert dm._tokens[0] == h2                  # the tail is forgotten
+    np.testing.assert_allclose(rolled[0], fresh._sync([h2, None])[0],
+                               rtol=1e-2, atol=1e-2)   # a bfloat16 pool
+    assert dm.draft([h2, None], [3, 0]) == fresh.draft([h2, None], [3, 0])
+
+
+def test_draft_model_resyncs_after_a_skipped_tick():
+    """A tick whose ``draft_exec`` site fired never calls the drafter
+    while the target goes on committing: the next call finds a backlog
+    longer than one chunk (7 tokens, chunks of 5: two rounds for one
+    slot, one for the other) and still matches the greedy reference."""
+    cfg, params, dm = _draft_setup()
+    h0, h1 = [5, 9, 3, 7], [11, 13, 2]
+    dm.draft([h0, h1], [3, 2])
+    h0 = h0 + [21, 22, 23, 24, 25, 26, 27]      # committed meanwhile
+    h1 = h1 + [31]
+    chains = dm.draft([h0, h1], [3, 2])
+    assert chains[0] == _greedy_reference(params, cfg, h0, 3)
+    assert chains[1] == _greedy_reference(params, cfg, h1, 2)
+    assert dm._tokens[0][:len(h0)] == h0
+
+
+def test_draft_model_full_row_stays_in_its_own_pages():
+    """A slot drafting from a history of ``max_len`` tokens writes its
+    chain into the chunk's slack at the end of ITS pages; its neighbour
+    drafts what it drafts alone."""
+    cfg, params, dm = _draft_setup()
+    _, _, alone = _draft_setup()
+    full = [(7 * i + 3) % cfg.vocab_size for i in range(32)]
+    other = [11, 13, 2]
+    want = alone.draft([None, other], [0, 2])[1]
+    got = dm.draft([full, other], [3, 2])
+    assert got[1] == want
+    assert got[0] == _greedy_reference(params, cfg, full, 3)
+    assert dm.draft([full, other + want[:1]], [3, 2])[1] == \
+        alone.draft([None, other + want[:1]], [0, 2])[1]
+
+
+def test_draft_model_tp_twin_drafts_the_same():
+    """``model=GPTModel(cfg, 2)`` runs the drafter's two forwards under
+    ``shard_map`` over the target's mesh (the pool's head axis sharded,
+    the identity table replicated): the chains are the unsharded
+    drafter's."""
+    import jax
+    import pytest
+
+    from apex_tpu.models.gpt import GPTModel
+    from apex_tpu.serving import DraftModel
+    from apex_tpu.transformer import parallel_state as ps
+
+    if jax.device_count() < 2:
+        pytest.skip("needs 2 devices")
+    cfg, params, dm = _draft_setup()
+    mesh = ps.initialize_model_parallel(tensor_model_parallel_size_=2)
+    tp = DraftModel(params, cfg, num_slots=2, max_len=32,
+                    model=GPTModel(cfg, tp_size=2), mesh=mesh)
+    hists, ks = [[5, 9, 3, 7], [11, 13, 2]], [3, 2]
+    want = dm.draft(hists, ks)
+    assert tp.draft(hists, ks) == want
+    hists = [h + c[:1] + [4] for h, c in zip(hists, want)]
+    assert tp.draft(hists, ks) == dm.draft(hists, ks)

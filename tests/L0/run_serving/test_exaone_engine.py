@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import exaone_moe
-from apex_tpu.serving import (ContinuousBatchingScheduler, DecodeEngine,
+from apex_tpu.serving import (ContinuousBatchingScheduler,
                               DisaggregatedRouter, PagedDecodeEngine, Request,
                               Tracer)
 from apex_tpu.serving.cache import (RESERVED_PAGES, WindowKVCache,
@@ -317,8 +317,6 @@ def test_the_rest_is_refused_where_it_is_asked_for(tiny):
     with pytest.raises(ValueError, match=r"chunked prefill \(chunk_tokens=\)"
                        r".*window pool.*out of its pools at prompt length"):
         ContinuousBatchingScheduler(eng, eos_id=-1, chunk_tokens=16)
-    with pytest.raises(ValueError, match="dense cache.*window pool"):
-        DecodeEngine(params, cfg, num_slots=2, max_len=32)
     quantized = {**params, "embedding": {"word": {
         **params["embedding"]["word"], "scale": jnp.ones((8,))}}}
     with pytest.raises(ValueError, match="weight-only int8.*window pool"):

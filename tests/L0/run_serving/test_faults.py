@@ -24,7 +24,7 @@ import pytest
 from apex_tpu.models.gpt import gpt_tiny, init_gpt
 from apex_tpu.serving import (
     AdmissionRejected, ContinuousBatchingScheduler, DeadlineExceeded,
-    DecodeEngine, FaultInjector, LivelockError, PagedDecodeEngine,
+    FaultInjector, LivelockError, PagedDecodeEngine,
     PoolInvariantError, Request, RetryBudgetExhausted, FINISH_REASONS,
     Tracer,
 )

@@ -238,8 +238,8 @@ def test_engine_refuses_by_name_what_needs_a_state_snapshot(tiny, name, kw):
 
 def test_the_rest_is_refused_where_it_is_asked_for(tiny):
     """Chunked prefill is the scheduler's option, page transfer the
-    router's, the dense cache and int8 weights the engines'."""
-    from apex_tpu.serving import DecodeEngine, DisaggregatedRouter
+    router's, int8 weights the engine's."""
+    from apex_tpu.serving import DisaggregatedRouter
 
     _, _, cfg, params = tiny
     eng = engine(cfg, params)
@@ -248,8 +248,6 @@ def test_the_rest_is_refused_where_it_is_asked_for(tiny):
         ContinuousBatchingScheduler(eng, eos_id=-1, chunk_tokens=16)
     with pytest.raises(ValueError, match="page transfer.*recurrent layers"):
         DisaggregatedRouter(eng, engine(cfg, params), eos_id=-1)
-    with pytest.raises(ValueError, match="dense cache.*recurrent layers"):
-        DecodeEngine(params, cfg, num_slots=2, max_len=32)
     quantized = {**params, "embedding": {"word": {
         **params["embedding"]["word"], "scale": jnp.ones((8,))}}}
     with pytest.raises(ValueError, match="weight-only int8.*recurrent"):

@@ -225,7 +225,7 @@ def test_engine_refuses_by_name_what_needs_a_state_snapshot(family, name, kw):
 
 
 def test_the_rest_is_refused_where_it_is_asked_for(family):
-    from apex_tpu.serving import DecodeEngine, DisaggregatedRouter
+    from apex_tpu.serving import DisaggregatedRouter
 
     _, cfg, params = family
     eng = engine(cfg, params)
@@ -234,8 +234,6 @@ def test_the_rest_is_refused_where_it_is_asked_for(family):
         ContinuousBatchingScheduler(eng, eos_id=-1, chunk_tokens=16)
     with pytest.raises(ValueError, match="page transfer.*recurrent layers"):
         DisaggregatedRouter(eng, engine(cfg, params), eos_id=-1)
-    with pytest.raises(ValueError, match="dense cache.*recurrent layers"):
-        DecodeEngine(params, cfg, num_slots=2, max_len=32)
     quantized = {**params, "embedding": {"word": {
         **params["embedding"]["word"], "scale": jnp.ones((8,))}}}
     with pytest.raises(ValueError, match="weight-only int8.*recurrent"):

@@ -5,7 +5,7 @@ teacher-forced decode under the int8 tiers stays within a fixed
 max-|logit-error| envelope of the fp32 full-sequence forward —
 ``W8_MAX_ABS`` for any weight-quantized config, ``KV8_MAX_ABS`` for an
 int8 cache under full-precision weights — on rope AND learned
-positions, dense AND paged caches, single-chip AND tp2. Speculative
+positions, single-chip AND tp2. Speculative
 decoding under int8 weights keeps the stream contract exactly:
 token-for-token identical to that config's plain decode.
 
@@ -25,8 +25,7 @@ import pytest
 from apex_tpu.models.gpt import apply_gpt_unsharded, gpt_tiny, init_gpt
 from apex_tpu.quant import kv_dequantize, kv_quantize, quantize_params
 from apex_tpu.serving import (
-    ContinuousBatchingScheduler, DecodeEngine, PagedDecodeEngine,
-    Request, init_cache, make_decode_fn, make_prefill_fn,
+    ContinuousBatchingScheduler, PagedDecodeEngine, Request,
 )
 
 # Compile-heavy (every test jits fresh prefill/decode programs per
@@ -53,22 +52,6 @@ def _full_logits(params, cfg, seq):
     hidden = apply_gpt_unsharded(params, cfg, seq)
     table = params["embedding"]["word"]["embedding"]
     return jnp.dot(hidden, table.T).astype(jnp.float32)
-
-
-def _teacher_forced(params, cfg, seq, quantized=False):
-    prefill = make_prefill_fn(cfg, quantized=quantized)
-    decode = make_decode_fn(cfg, quantized=quantized)
-    cache = init_cache(cfg, 2, S_MAX, jnp.float32)
-    cache, logits = prefill(params, cache, seq[:, :PROMPT],
-                            jnp.ones((PROMPT,), jnp.int32),
-                            jnp.int32(0))
-    rows = [logits[0]]
-    for t in range(PROMPT, seq.shape[1]):
-        tokens = jnp.asarray([int(seq[0, t]), 0], jnp.int32)
-        cache, logits = decode(params, cache, tokens,
-                               jnp.asarray([True, False]))
-        rows.append(logits[0])
-    return jnp.stack(rows)
 
 
 def _paged_teacher_forced(params, cfg, seq, cache_dtype,
@@ -98,27 +81,19 @@ def _seq(cfg, seed=1):
 
 # -- accuracy gates ---------------------------------------------------------
 
-@pytest.mark.parametrize("use_rope,paged",
-                         [(True, False), (False, True)],
-                         ids=["rope-dense", "learned_pos-paged"])
-def test_w8_teacher_forced_within_tolerance(use_rope, paged):
+@pytest.mark.parametrize("use_rope", [True, False],
+                         ids=["rope", "learned_pos"])
+def test_w8_teacher_forced_within_tolerance(use_rope):
     """Weight-only int8 over a full-precision cache: every
     teacher-forced logit stays inside W8_MAX_ABS of the fp32 golden.
     The lower bound proves the int8 kernels were actually in the loop —
-    a silent fall-through to the fp32 path would read as a pass.
-    Two diagonal combos cover both position modes and both cache
-    layouts; the remaining corners of the cross-product ride in the
-    w8+kv8 gate below (rope-paged, learned_pos-paged) and the tp2
-    gate (rope-dense + rope-paged)."""
+    a silent fall-through to the fp32 path would read as a pass."""
     cfg = _cfg(use_rope)
     params = init_gpt(jax.random.PRNGKey(0), cfg)
     seq = _seq(cfg)
     want = _golden(params, cfg, seq)
     qp = quantize_params(params)
-    if paged:
-        got = _paged_teacher_forced(qp, cfg, seq, jnp.float32)
-    else:
-        got = _teacher_forced(qp, cfg, seq, quantized=True)
+    got = _paged_teacher_forced(qp, cfg, seq, jnp.float32)
     err = np.abs(np.asarray(got) - want).max()
     assert err < W8_MAX_ABS, err
     assert err > 1e-4, "suspiciously exact: int8 path not exercised?"
@@ -155,14 +130,16 @@ def test_kv8_only_within_tolerance():
     assert err > 1e-5
 
 
-def test_tp2_w8_decode_matches_unsharded():
-    """tp=2 quantized decode (dense + paged/kv8): logits match the
-    single-chip quantized step to fp32 tolerance AND stay inside the
-    accuracy envelope — sharding the int8 tree (row/column shards of
-    the quantized kernels with their sibling scale shards) is a layout
-    change, never an accuracy one."""
+@pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.int8],
+                         ids=["w8", "w8kv8"])
+def test_tp2_w8_decode_matches_unsharded(kv_dtype):
+    """tp=2 quantized decode (over a float32 pool and over the int8
+    pool): logits match the single-chip quantized step to fp32
+    tolerance AND stay inside the accuracy envelope — sharding the int8
+    tree (row/column shards of the quantized kernels with their sibling
+    scale shards) is a layout change, never an accuracy one."""
     from apex_tpu.models.gpt import GPTModel
-    from apex_tpu.serving import make_tp_decode_fn, make_tp_paged_decode_fn
+    from apex_tpu.serving import make_tp_paged_decode_fn
     from apex_tpu.transformer import parallel_state as ps
 
     if jax.device_count() < 2:
@@ -177,31 +154,18 @@ def test_tp2_w8_decode_matches_unsharded():
     tokens = jnp.asarray([int(seq[0, PROMPT]), 0], jnp.int32)
     active = jnp.asarray([True, False])
 
-    # dense: one quantized-prefilled cache through both decode paths
-    prefill = make_prefill_fn(cfg, quantized=True)
-    cache = init_cache(cfg, 2, S_MAX, jnp.float32)
-    cache, _ = prefill(qp, cache, seq[:, :PROMPT],
-                       jnp.ones((PROMPT,), jnp.int32), jnp.int32(0))
-    clone = jax.tree.map(jnp.copy, cache)
-    _, ref = make_decode_fn(cfg, quantized=True)(qp, cache, tokens,
-                                                 active)
-    _, got = make_tp_decode_fn(model, quantized=True)(qp, clone, tokens,
-                                                      active)
-    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
-                               rtol=1e-4, atol=1e-4)
-    assert np.abs(np.asarray(got[0]) - want_row).max() < W8_MAX_ABS
-
-    # paged + int8 pool: engine-built cache, same contract
+    # an engine-built cache through both decode paths
     eng = PagedDecodeEngine(qp, cfg, num_slots=2, max_len=S_MAX,
                             num_pages=14, page_size=8,
-                            cache_dtype=jnp.int8, buckets=(8, 16, 32))
+                            cache_dtype=kv_dtype, buckets=(8, 16, 32))
     eng.prefill(0, [int(t) for t in np.asarray(seq[0, :PROMPT])])
     eng.prepare_decode({0: PROMPT})
+    eng.sync_table()    # the clone is launched by hand: upload first
     clone = jax.tree.map(jnp.copy, eng.cache)
     ref = eng.decode(tokens, active)
-    _, got = make_tp_paged_decode_fn(model, quantized=True,
-                                     kv_quantized=True)(qp, clone,
-                                                        tokens, active)
+    _, got = make_tp_paged_decode_fn(
+        model, quantized=True, kv_quantized=kv_dtype == jnp.int8)(
+            qp, clone, tokens, active)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]),
                                rtol=1e-4, atol=1e-4)
     assert np.abs(np.asarray(got[0]) - want_row).max() < W8_MAX_ABS
@@ -209,9 +173,7 @@ def test_tp2_w8_decode_matches_unsharded():
 
 # -- speculative decoding under int8 weights --------------------------------
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_spec_stream_w8_bit_identical_to_plain(paged):
+def test_spec_stream_w8_bit_identical_to_plain():
     """The stream contract survives quantization unchanged: spec_k
     draft/verify under int8 weights commits token-for-token the plain
     (spec_k=0) quantized streams — greedy AND seeded sampling. Exact
@@ -228,13 +190,9 @@ def test_spec_stream_w8_bit_identical_to_plain(paged):
             Request(prompt=(13, 17, 19), max_new_tokens=4)]
 
     def run(spec_k):
-        if paged:
-            eng = PagedDecodeEngine(qp, cfg, num_slots=2, max_len=S_MAX,
-                                    num_pages=24, page_size=4,
-                                    buckets=(16, 32), spec_k=spec_k)
-        else:
-            eng = DecodeEngine(qp, cfg, num_slots=2, max_len=S_MAX,
-                               buckets=(16, 32), spec_k=spec_k)
+        eng = PagedDecodeEngine(qp, cfg, num_slots=2, max_len=S_MAX,
+                                num_pages=24, page_size=4,
+                                buckets=(16, 32), spec_k=spec_k)
         sched = ContinuousBatchingScheduler(eng, eos_id=0)
         for r in reqs:
             sched.submit(r)
@@ -436,13 +394,3 @@ def test_int8_decode_bit_identical_across_page_placements():
             for order in orders]
     for other in runs[1:]:
         np.testing.assert_array_equal(runs[0], other)
-
-
-def test_dense_cache_rejects_int8():
-    """The dense cache has no scale plumbing — int8 must be a loud
-    constructor error, not a silently-garbage cache."""
-    cfg = _cfg(True)
-    params = init_gpt(jax.random.PRNGKey(0), cfg)
-    with pytest.raises(ValueError, match="int8"):
-        DecodeEngine(params, cfg, num_slots=1, max_len=S_MAX,
-                     cache_dtype=jnp.int8)

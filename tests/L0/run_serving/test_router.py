@@ -338,11 +338,6 @@ def test_router_validates_replica_pair(model):
         DisaggregatedRouter(eng(tracer=Tracer()), eng(), EOS)
     with pytest.raises(ValueError, match="chunked prefill"):
         DisaggregatedRouter(eng(), eng(), EOS, chunk_tokens=4)
-    with pytest.raises(ValueError, match="paged engine"):
-        from apex_tpu.serving import DecodeEngine
-        dense = DecodeEngine(params, cfg, num_slots=2, max_len=MAX_LEN,
-                             injector=inj, tracer=trc)
-        DisaggregatedRouter(dense, eng(), EOS)
 
 
 # -- randomized multi-fault sweep -------------------------------------------

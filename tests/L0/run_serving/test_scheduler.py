@@ -1,15 +1,18 @@
 """Continuous-batching scheduler: admit/evict lifecycle over a fixed
-slot pool, and output invariance to slot placement and pool size —
-including the paged engine (page placement, pool pressure, and
-preemption-by-requeue must all be invisible in the outputs)."""
+slot pool, and output invariance to slot placement and pool size
+(page placement, pool pressure, and preemption-by-requeue must all be
+invisible in the outputs). The oracle for WHAT a stream commits is the
+model's full forward (``full_forward.reference_stream``)."""
 
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import pytest
+from full_forward import reference_stream
 
 from apex_tpu.models.gpt import gpt_tiny, init_gpt
-from apex_tpu.serving import (ContinuousBatchingScheduler, DecodeEngine,
+from apex_tpu.serving import (ContinuousBatchingScheduler,
                               PagedDecodeEngine, Request)
 
 EOS = 0
@@ -25,13 +28,33 @@ def _params(cfg):
     return init_gpt(jax.random.PRNGKey(0), cfg)
 
 
+def _engine(params, cfg, num_slots, **kw):
+    """An engine over a full pool (nothing is ever preempted)."""
+    kw.setdefault("buckets", (16, 32))
+    return PagedDecodeEngine(
+        params, cfg, num_slots=num_slots, max_len=MAX_LEN,
+        num_pages=PagedDecodeEngine.full_pool_pages(num_slots, MAX_LEN, 4),
+        page_size=4, **kw)
+
+
 def _run(params, cfg, requests, num_slots, top_k=0):
-    engine = DecodeEngine(params, cfg, num_slots=num_slots,
-                          max_len=MAX_LEN, top_k=top_k)
-    sched = ContinuousBatchingScheduler(engine, eos_id=EOS)
+    sched = ContinuousBatchingScheduler(
+        _engine(params, cfg, num_slots, top_k=top_k), eos_id=EOS)
     for r in requests:
         sched.submit(r)
     return sched.run()
+
+
+_REFERENCE = {}
+
+
+def _reference(params, cfg, requests):
+    """What the full forward says each request commits (``_params`` are
+    the same in every test, so a request's stream is made once)."""
+    for r in requests:
+        if r not in _REFERENCE:
+            _REFERENCE[r] = reference_stream(params, cfg, r, EOS, MAX_LEN)
+    return [_REFERENCE[r] for r in requests]
 
 
 def test_more_requests_than_slots():
@@ -89,8 +112,7 @@ def test_max_new_tokens_respected():
 
 def test_submit_validates():
     cfg = _cfg()
-    engine = DecodeEngine(_params(cfg), cfg, num_slots=1,
-                          max_len=MAX_LEN)
+    engine = _engine(_params(cfg), cfg, 1)
     sched = ContinuousBatchingScheduler(engine, eos_id=EOS)
     with pytest.raises(ValueError):
         sched.submit(Request(prompt=()))
@@ -100,20 +122,20 @@ def test_submit_validates():
 
 def test_run_on_empty_queue():
     cfg = _cfg()
-    engine = DecodeEngine(_params(cfg), cfg, num_slots=1,
-                          max_len=MAX_LEN)
+    engine = _engine(_params(cfg), cfg, 1)
     sched = ContinuousBatchingScheduler(engine, eos_id=EOS)
     assert sched.run() == []
 
 
-# -- paged engine -----------------------------------------------------------
+# -- the page pool ----------------------------------------------------------
 
 def _run_paged(params, cfg, requests, num_slots, num_pages, page_size=4,
-               free_order=None):
+               free_order=None, cache_dtype=jnp.bfloat16):
     engine = PagedDecodeEngine(params, cfg, num_slots=num_slots,
                                max_len=MAX_LEN, num_pages=num_pages,
                                page_size=page_size, buckets=(16, 32),
-                               free_order=free_order)
+                               free_order=free_order,
+                               cache_dtype=cache_dtype)
     sched = ContinuousBatchingScheduler(engine, eos_id=EOS)
     for r in requests:
         sched.submit(r)
@@ -129,21 +151,19 @@ def _mixed_requests():
                     temperature=0.7, seed=9)]
 
 
-def test_paged_outputs_match_dense():
-    """The paged engine is a drop-in for the dense one: the same
-    request mix (greedy + seeded sampling, shared prompt prefixes)
-    through the same scheduler produces identical token streams."""
+def test_paged_outputs_match_full_forward():
+    """The engine commits what the model says: the request mix (greedy
+    + seeded sampling, shared prompt prefixes) through the scheduler
+    produces the token streams the full forward generates one token at
+    a time with the same keys (float32 pool: the comparison is the
+    math's, not a cache rounding's)."""
     cfg = _cfg()
     params = _params(cfg)
     reqs = _mixed_requests()
-    engine = DecodeEngine(params, cfg, num_slots=2, max_len=MAX_LEN,
-                          buckets=(16, 32))
-    sched = ContinuousBatchingScheduler(engine, eos_id=EOS)
-    for r in reqs:
-        sched.submit(r)
-    dense = sched.run()
-    paged, _ = _run_paged(params, cfg, reqs, num_slots=2, num_pages=20)
-    assert paged == dense
+    paged, engine = _run_paged(params, cfg, reqs, num_slots=2,
+                               num_pages=20, cache_dtype=jnp.float32)
+    assert paged == _reference(params, cfg, reqs)
+    assert engine.pool.num_cached > 0   # the shared prefix was shared
 
 
 def test_paged_outputs_independent_of_page_placement():
@@ -289,35 +309,40 @@ def _spec_requests():
             Request(prompt=(13, 17, 19), max_new_tokens=5)]
 
 
-def _spec_stats(params, cfg, requests, num_slots, spec_k, paged):
-    if paged:
-        engine = PagedDecodeEngine(params, cfg, num_slots=num_slots,
-                                   max_len=MAX_LEN, num_pages=24,
-                                   page_size=4, buckets=(16, 32),
-                                   spec_k=spec_k)
-    else:
-        engine = DecodeEngine(params, cfg, num_slots=num_slots,
-                              max_len=MAX_LEN, buckets=(16, 32),
-                              spec_k=spec_k)
-    sched = ContinuousBatchingScheduler(engine, eos_id=EOS,
-                                        audit=paged)
+def _spec_stats(params, cfg, requests, num_slots, spec_k,
+                cache_dtype=jnp.bfloat16):
+    engine = PagedDecodeEngine(params, cfg, num_slots=num_slots,
+                               max_len=MAX_LEN, num_pages=24,
+                               page_size=4, buckets=(16, 32),
+                               spec_k=spec_k, cache_dtype=cache_dtype)
+    sched = ContinuousBatchingScheduler(engine, eos_id=EOS, audit=True)
     for r in requests:
         sched.submit(r)
     return sched.run(), sched.stats
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
+#: What a speculating run's streams are held to: the plain ``spec_k=0``
+#: run of the same engine (bfloat16 pool, bit for bit), or the full
+#: forward's own streams (float32 pool).
+ORACLES = pytest.mark.parametrize("oracle", ["full_forward", "plain"])
+
+
+@ORACLES
 @pytest.mark.parametrize("spec_k", [1, 2, 3])
-def test_spec_stream_bit_identical_to_plain(spec_k, paged):
+def test_spec_stream_bit_identical_to_plain(spec_k, oracle):
     """Greedy + seeded-sampled requests through the draft→verify→accept
     loop: the committed streams equal the plain spec_k=0 streams
-    token-for-token, at every draft depth, on both cache layouts."""
+    token-for-token, and the full forward's, at every draft depth."""
     cfg = _cfg()
     params = _params(cfg)
     reqs = _spec_requests()
-    plain, _ = _spec_stats(params, cfg, reqs, 2, 0, paged)
-    spec, stats = _spec_stats(params, cfg, reqs, 2, spec_k, paged)
+    if oracle == "plain":
+        plain, _ = _spec_stats(params, cfg, reqs, 2, 0)
+        spec, stats = _spec_stats(params, cfg, reqs, 2, spec_k)
+    else:
+        plain = _reference(params, cfg, reqs)
+        spec, stats = _spec_stats(params, cfg, reqs, 2, spec_k,
+                                  jnp.float32)
     assert spec == plain
     assert stats.tokens_drafted > 0  # the drafter actually proposed
     assert stats.tokens_accepted >= 0
@@ -330,8 +355,8 @@ def test_spec_accepts_make_progress():
     cfg = _cfg()
     params = _params(cfg)
     reqs = [Request(prompt=(7, 11, 7, 11, 7, 11, 7), max_new_tokens=10)]
-    plain, _ = _spec_stats(params, cfg, reqs, 1, 0, True)
-    spec, stats = _spec_stats(params, cfg, reqs, 1, 3, True)
+    plain, _ = _spec_stats(params, cfg, reqs, 1, 0)
+    spec, stats = _spec_stats(params, cfg, reqs, 1, 3)
     assert spec == plain
     assert stats.tokens_accepted > 0
     assert 0.0 < stats.acceptance_rate <= 1.0
@@ -345,10 +370,10 @@ def test_spec_stream_independent_of_slot_placement():
     params = _params(cfg)
     probe = Request(prompt=(5, 7, 5, 7, 5), max_new_tokens=6,
                     temperature=0.8, seed=42)
-    alone, _ = _spec_stats(params, cfg, [probe], 1, 2, True)
+    alone, _ = _spec_stats(params, cfg, [probe], 1, 2)
     filler = [Request(prompt=(2, 3, 2, 3), max_new_tokens=6,
                       temperature=0.9, seed=i) for i in range(3)]
-    crowded, _ = _spec_stats(params, cfg, [probe] + filler, 4, 2, True)
+    crowded, _ = _spec_stats(params, cfg, [probe] + filler, 4, 2)
     assert alone[0] == crowded[0]
 
 
@@ -361,8 +386,8 @@ def test_spec_respects_max_new_tokens_and_eos():
     reqs = [Request(prompt=(7, 11, 7, 11), max_new_tokens=1),
             Request(prompt=(5, 3, 5, 3), max_new_tokens=2),
             Request(prompt=(13, 17, 13, 17), max_new_tokens=16)]
-    plain, _ = _spec_stats(params, cfg, reqs, 3, 0, True)
-    spec, _ = _spec_stats(params, cfg, reqs, 3, 3, True)
+    plain, _ = _spec_stats(params, cfg, reqs, 3, 0)
+    spec, _ = _spec_stats(params, cfg, reqs, 3, 3)
     assert spec == plain
     assert len(spec[0]) == 1 and len(spec[1]) <= 2
 
@@ -417,8 +442,9 @@ def _draft_for(params, cfg, num_slots):
     return DraftModel(params, cfg, num_slots=num_slots, max_len=MAX_LEN)
 
 
-def _model_spec_run(params, cfg, requests, num_slots, spec_k, paged,
-                    tree=False, adaptive=False, self_draft=True):
+def _model_spec_run(params, cfg, requests, num_slots, spec_k,
+                    tree=False, adaptive=False, self_draft=True,
+                    cache_dtype=jnp.bfloat16):
     if self_draft:
         dm = _draft_for(params, cfg, num_slots) if spec_k else None
     else:  # a genuinely different (randomly-initialised) draft net
@@ -429,49 +455,54 @@ def _model_spec_run(params, cfg, requests, num_slots, spec_k, paged,
               adaptive_spec=adaptive)
     if not spec_k:
         kw = {}
-    if paged:
-        engine = PagedDecodeEngine(params, cfg, num_slots=num_slots,
-                                   max_len=MAX_LEN, num_pages=24,
-                                   page_size=4, buckets=(16, 32), **kw)
-    else:
-        engine = DecodeEngine(params, cfg, num_slots=num_slots,
-                              max_len=MAX_LEN, buckets=(16, 32), **kw)
-    sched = ContinuousBatchingScheduler(engine, eos_id=EOS, audit=paged)
+    engine = PagedDecodeEngine(params, cfg, num_slots=num_slots,
+                               max_len=MAX_LEN, num_pages=24,
+                               page_size=4, buckets=(16, 32),
+                               cache_dtype=cache_dtype, **kw)
+    sched = ContinuousBatchingScheduler(engine, eos_id=EOS, audit=True)
     for r in requests:
         sched.submit(r)
     return sched.run(), sched.stats
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_model_draft_stream_bit_identical_to_plain(paged):
+def _model_spec_against(oracle, params, cfg, reqs, **kw):
+    """(streams, what they are held to, stats) of a model-drafted run."""
+    if oracle == "plain":
+        plain, _ = _model_spec_run(params, cfg, reqs, 2, 0)
+        spec, stats = _model_spec_run(params, cfg, reqs, 2, 3, **kw)
+    else:
+        plain = _reference(params, cfg, reqs)
+        spec, stats = _model_spec_run(params, cfg, reqs, 2, 3,
+                                      cache_dtype=jnp.float32, **kw)
+    return spec, plain, stats
+
+
+@ORACLES
+def test_model_draft_stream_bit_identical_to_plain(oracle):
     """Model-drafted linear speculation (greedy + seeded sampled): the
     committed streams equal the plain run token-for-token, and the
     self-draft actually lands accepts (the resync path is exercised on
     both full and partial acceptance)."""
     cfg = _cfg()
     params = _params(cfg)
-    reqs = _spec_requests()
-    plain, _ = _model_spec_run(params, cfg, reqs, 2, 0, paged)
-    spec, stats = _model_spec_run(params, cfg, reqs, 2, 3, paged)
+    spec, plain, stats = _model_spec_against(
+        oracle, params, cfg, _spec_requests())
     assert spec == plain
     assert stats.tokens_drafted > 0
     assert stats.tokens_accepted > 0  # self-draft must make progress
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_tree_spec_stream_bit_identical_to_plain(paged):
+@ORACLES
+def test_tree_spec_stream_bit_identical_to_plain(oracle):
     """Tree speculation: multi-branch drafts verified in ONE forward
     via the ancestor mask, the accept walk following the committed
     root-to-leaf path. Streams stay integer-identical to plain decode
-    on both layouts, and the tree path commits accepted tokens."""
+    and to the full forward's, and the tree path commits accepted
+    tokens."""
     cfg = _cfg()
     params = _params(cfg)
-    reqs = _spec_requests()
-    plain, _ = _model_spec_run(params, cfg, reqs, 2, 0, paged)
-    spec, stats = _model_spec_run(params, cfg, reqs, 2, 3, paged,
-                                  tree=True)
+    spec, plain, stats = _model_spec_against(
+        oracle, params, cfg, _spec_requests(), tree=True)
     assert spec == plain
     assert stats.spec_ticks > 0
     assert stats.tokens_accepted > 0
@@ -485,8 +516,8 @@ def test_tree_spec_with_mismatched_draft_still_exact():
     cfg = _cfg()
     params = _params(cfg)
     reqs = _spec_requests()
-    plain, _ = _model_spec_run(params, cfg, reqs, 2, 0, False)
-    spec, _ = _model_spec_run(params, cfg, reqs, 2, 3, False,
+    plain, _ = _model_spec_run(params, cfg, reqs, 2, 0)
+    spec, _ = _model_spec_run(params, cfg, reqs, 2, 3,
                               tree=True, self_draft=False)
     assert spec == plain
 
@@ -497,12 +528,11 @@ def test_ngram_tree_spec_matches_plain():
     cfg = _cfg()
     params = _params(cfg)
     reqs = _spec_requests()
-    engine = DecodeEngine(params, cfg, num_slots=2, max_len=MAX_LEN,
-                          buckets=(16, 32), spec_k=3, tree_spec=True)
+    engine = _engine(params, cfg, 2, spec_k=3, tree_spec=True)
     sched = ContinuousBatchingScheduler(engine, eos_id=EOS)
     for r in reqs:
         sched.submit(r)
-    plain, _ = _model_spec_run(params, cfg, reqs, 2, 0, False)
+    plain, _ = _model_spec_run(params, cfg, reqs, 2, 0)
     assert sched.run() == plain
 
 
@@ -519,8 +549,8 @@ def test_adaptive_controller_converges_to_plain():
                     temperature=5.0, seed=123),
             Request(prompt=(2, 7, 1, 8), max_new_tokens=20,
                     temperature=4.0, seed=77)]
-    plain, pstats = _model_spec_run(params, cfg, reqs, 2, 0, False)
-    out, stats = _model_spec_run(params, cfg, reqs, 2, 4, False,
+    plain, pstats = _model_spec_run(params, cfg, reqs, 2, 0)
+    out, stats = _model_spec_run(params, cfg, reqs, 2, 4,
                                  adaptive=True, self_draft=False)
     assert out == plain
     assert stats.plain_ticks > stats.spec_ticks  # converged toward plain
@@ -536,8 +566,8 @@ def test_adaptive_controller_keeps_speculating_when_accepted():
     params = _params(cfg)
     reqs = [Request(prompt=(7, 11, 7, 11, 7), max_new_tokens=12),
             Request(prompt=(13, 17, 19), max_new_tokens=12)]
-    plain, pstats = _model_spec_run(params, cfg, reqs, 2, 0, False)
-    out, stats = _model_spec_run(params, cfg, reqs, 2, 3, False,
+    plain, pstats = _model_spec_run(params, cfg, reqs, 2, 0)
+    out, stats = _model_spec_run(params, cfg, reqs, 2, 3,
                                  adaptive=True)
     assert out == plain
     assert stats.spec_ticks > 0
@@ -549,22 +579,76 @@ def test_spec_config_validation():
     """draft_model / tree_spec / adaptive_spec all require spec_k >= 1;
     the draft net must match the target's slot count and vocab; tree
     verify refuses the int8 page pool."""
-    import jax.numpy as jnp
     cfg = _cfg()
     params = _params(cfg)
     with pytest.raises(ValueError, match="spec_k"):
-        DecodeEngine(params, cfg, num_slots=1, max_len=MAX_LEN,
-                     tree_spec=True)
+        _engine(params, cfg, 1, tree_spec=True)
     with pytest.raises(ValueError, match="spec_k"):
-        DecodeEngine(params, cfg, num_slots=1, max_len=MAX_LEN,
-                     adaptive_spec=True)
+        _engine(params, cfg, 1, adaptive_spec=True)
     with pytest.raises(ValueError, match="slots"):
-        DecodeEngine(params, cfg, num_slots=2, max_len=MAX_LEN,
-                     spec_k=2, draft_model=_draft_for(params, cfg, 1))
+        _engine(params, cfg, 2, spec_k=2,
+                draft_model=_draft_for(params, cfg, 1))
     with pytest.raises(ValueError, match="int8"):
         PagedDecodeEngine(params, cfg, num_slots=1, max_len=MAX_LEN,
                           num_pages=24, page_size=4, spec_k=2,
                           tree_spec=True, cache_dtype=jnp.int8)
+
+
+def _other_vocab_draft(params, cfg):
+    from apex_tpu.serving import DraftModel
+    small = dataclasses.replace(cfg, vocab_size=cfg.vocab_size // 2)
+    return DraftModel(init_gpt(jax.random.PRNGKey(1), small), small,
+                      num_slots=1, max_len=MAX_LEN)
+
+
+#: Every refusal the ONE engine's constructor makes for a GPT config (the
+#: families on the model seam have theirs in ``test_*_engine.py``), by the
+#: words a user reads: name -> (what was asked for, the message).
+_REFUSED = {
+    "buckets_cut_pages": (dict(buckets=(6, 32)),
+                          r"buckets \[6\] are not multiples of page_size 4"),
+    "draft_without_spec_k": (
+        dict(draft_model=lambda p, c: _draft_for(p, c, 1)),
+        "draft_model / tree_spec / adaptive_spec require spec_k >= 1"),
+    "draft_of_another_vocabulary": (
+        dict(spec_k=2, draft_model=_other_vocab_draft),
+        r"must share a vocabulary \(256 vs 512\)"),
+    "tree_verify_over_int8": (
+        dict(spec_k=2, tree_spec=True, cache_dtype=jnp.int8),
+        "tree verify is not offered over the int8 page pool"),
+    "host_tier_without_a_wire_tag": (
+        dict(cache_dtype=jnp.float8_e4m3fn, host_tier="registry"),
+        "has no spill wire tag"),
+    "pool_of_reserved_pages_only": (
+        dict(num_pages=2), "must exceed the 2 reserved pages"),
+    "no_slots": (dict(num_slots=0), "need positive num_slots"),
+    "free_order_not_a_permutation": (
+        dict(free_order=[2, 2, 3]), "free_order must be a permutation"),
+    "learned_positions_too_few": (
+        dict(learned=True, max_len=1024),
+        "exceeds the learned position table"),
+}
+
+
+@pytest.mark.parametrize("asked,message", list(_REFUSED.values()),
+                         ids=list(_REFUSED))
+def test_engine_refuses_at_construction(asked, message):
+    from apex_tpu.serving import PrefixRegistry
+
+    asked = dict(asked)
+    cfg = _cfg()
+    if asked.pop("learned", False):
+        cfg = dataclasses.replace(cfg, use_rope=False)
+    params = _params(cfg)
+    if callable(asked.get("draft_model")):
+        asked["draft_model"] = asked["draft_model"](params, cfg)
+    if "host_tier" in asked:
+        asked["host_tier"] = PrefixRegistry(1 << 20)
+    kw = dict(num_slots=1, max_len=MAX_LEN, num_pages=10, page_size=4,
+              buckets=(16, 32))
+    kw.update(asked)
+    with pytest.raises(ValueError, match=message):
+        PagedDecodeEngine(params, cfg, **kw)
 
 
 # -- chunked prefill ---------------------------------------------------------
@@ -590,27 +674,18 @@ def _chunky_requests():
 
 
 def _run_chunked(params, cfg, requests, num_slots, chunk_tokens,
-                 paged=False, num_pages=24, spec_k=0,
-                 tick_token_budget=None):
+                 num_pages=24, spec_k=0, tick_token_budget=None):
     # fp32 cache on BOTH sides of every comparison: the identity
     # contract is "chunking moves when prompt work runs, never the
     # math" — at bf16 the cache itself rounds K/V, so a monolithic
     # forward (unrounded in-forward activations) and a chunked one
     # (re-read rounded cache) can legitimately differ in the last bit.
-    import jax.numpy as jnp
-
-    if paged:
-        engine = PagedDecodeEngine(params, cfg, num_slots=num_slots,
-                                   max_len=MAX_LEN, num_pages=num_pages,
-                                   page_size=4, buckets=(16, 32),
-                                   spec_k=spec_k,
-                                   cache_dtype=jnp.float32)
-    else:
-        engine = DecodeEngine(params, cfg, num_slots=num_slots,
-                              max_len=MAX_LEN,
-                              cache_dtype=jnp.float32)
+    engine = PagedDecodeEngine(params, cfg, num_slots=num_slots,
+                               max_len=MAX_LEN, num_pages=num_pages,
+                               page_size=4, buckets=(16, 32),
+                               spec_k=spec_k, cache_dtype=jnp.float32)
     sched = ContinuousBatchingScheduler(
-        engine, eos_id=EOS, audit=paged, chunk_tokens=chunk_tokens,
+        engine, eos_id=EOS, audit=True, chunk_tokens=chunk_tokens,
         tick_token_budget=tick_token_budget)
     for r in requests:
         sched.submit(r)
@@ -618,11 +693,11 @@ def _run_chunked(params, cfg, requests, num_slots, chunk_tokens,
 
 
 @pytest.mark.parametrize("chunk_tokens", [4, 8])
-def test_chunked_streams_match_sync_dense(chunk_tokens):
+def test_chunked_streams_match_full_forward(chunk_tokens):
     cfg = _cfg()
     params = _params(cfg)
     reqs = _chunky_requests()
-    want, _ = _run_chunked(params, cfg, reqs, 2, None)  # sync golden
+    want = _reference(params, cfg, reqs)
     got, sched = _run_chunked(params, cfg, reqs, 2, chunk_tokens)
     assert got == want
     # the prompts really were split, not admitted in one piece
@@ -634,9 +709,8 @@ def test_chunked_streams_match_sync_paged(chunk_tokens):
     cfg = _cfg()
     params = _params(cfg)
     reqs = _chunky_requests()
-    want, _ = _run_chunked(params, cfg, reqs, 2, None, paged=True)
-    got, sched = _run_chunked(params, cfg, reqs, 2, chunk_tokens,
-                              paged=True)
+    want, _ = _run_chunked(params, cfg, reqs, 2, None)
+    got, sched = _run_chunked(params, cfg, reqs, 2, chunk_tokens)
     assert got == want
     assert sched.stats.prefill_chunks > len(reqs)
 
@@ -648,8 +722,8 @@ def test_chunked_streams_invariant_to_tick_token_budget():
     cfg = _cfg()
     params = _params(cfg)
     reqs = _chunky_requests()
-    tight, _ = _run_chunked(params, cfg, reqs, 2, 4, paged=True)
-    wide, _ = _run_chunked(params, cfg, reqs, 2, 4, paged=True,
+    tight, _ = _run_chunked(params, cfg, reqs, 2, 4)
+    wide, _ = _run_chunked(params, cfg, reqs, 2, 4,
                            tick_token_budget=64)
     assert tight == wide
 
@@ -664,8 +738,8 @@ def test_chunked_spec_streams_match_plain_sync():
                     max_new_tokens=6),
             Request(prompt=(5, 3, 5, 3, 5, 3, 5, 3), max_new_tokens=6,
                     temperature=0.8, seed=3)]
-    want, _ = _run_chunked(params, cfg, reqs, 2, None, paged=True)
-    got, sched = _run_chunked(params, cfg, reqs, 2, 4, paged=True,
+    want, _ = _run_chunked(params, cfg, reqs, 2, None)
+    got, sched = _run_chunked(params, cfg, reqs, 2, 4,
                               spec_k=3)
     assert got == want
     assert sched.stats.prefill_chunks > len(reqs)
@@ -687,8 +761,6 @@ def test_chunked_final_logits_match_one_shot_paged():
     of this is the neighbouring tests': the committed token streams are
     identical."""
     import numpy as np
-
-    import jax.numpy as jnp
 
     cfg = _cfg()
     params = _params(cfg)
@@ -759,18 +831,15 @@ def test_chunk_config_validation():
     """chunk_tokens must be >= 1, divide max_len, be page-aligned on a
     paged engine, and is refused over the int8 page pool; the tick
     token budget must be positive."""
-    import jax.numpy as jnp
-
     cfg = _cfg()
     params = _params(cfg)
-    dense = DecodeEngine(params, cfg, num_slots=1, max_len=MAX_LEN)
     paged = PagedDecodeEngine(params, cfg, num_slots=1, max_len=MAX_LEN,
                               num_pages=8, page_size=4,
                               buckets=(16, 32))
     with pytest.raises(ValueError, match=">= 1"):
-        ContinuousBatchingScheduler(dense, eos_id=EOS, chunk_tokens=0)
+        ContinuousBatchingScheduler(paged, eos_id=EOS, chunk_tokens=0)
     with pytest.raises(ValueError, match="divide"):
-        ContinuousBatchingScheduler(dense, eos_id=EOS, chunk_tokens=5)
+        ContinuousBatchingScheduler(paged, eos_id=EOS, chunk_tokens=5)
     with pytest.raises(ValueError, match="page_size"):
         ContinuousBatchingScheduler(paged, eos_id=EOS, chunk_tokens=2)
     int8 = PagedDecodeEngine(params, cfg, num_slots=1, max_len=MAX_LEN,
@@ -779,5 +848,5 @@ def test_chunk_config_validation():
     with pytest.raises(ValueError, match="int8"):
         ContinuousBatchingScheduler(int8, eos_id=EOS, chunk_tokens=4)
     with pytest.raises(ValueError, match="tick_token_budget"):
-        ContinuousBatchingScheduler(dense, eos_id=EOS, chunk_tokens=4,
+        ContinuousBatchingScheduler(paged, eos_id=EOS, chunk_tokens=4,
                                     tick_token_budget=0)

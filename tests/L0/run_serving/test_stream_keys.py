@@ -28,11 +28,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from full_forward import reference_stream
 
 from apex_tpu.models.gpt import gpt_tiny, init_gpt
-from apex_tpu.serving import (ContinuousBatchingScheduler, DecodeEngine,
+from apex_tpu.serving import (ContinuousBatchingScheduler,
                               PagedDecodeEngine, Request, Tracer,
-                              sample_tokens, stream_keys)
+                              stream_keys)
 from apex_tpu.serving.draft_model import DraftModel
 from apex_tpu.serving.scheduler import _base_key
 
@@ -79,11 +80,8 @@ def test_stream_keys_equal_eager_fold_in(derive, seed, count):
                                       _eager_key(seed, count + j))
 
 
-def _engine(model, kind, num_slots, page_size=16, **kw):
+def _engine(model, num_slots, page_size=16, **kw):
     cfg, params = model
-    if kind == "dense":
-        return DecodeEngine(params, cfg, num_slots=num_slots,
-                            max_len=MAX_LEN, **kw)
     return PagedDecodeEngine(
         params, cfg, num_slots=num_slots, max_len=MAX_LEN,
         num_pages=PagedDecodeEngine.full_pool_pages(num_slots, MAX_LEN,
@@ -91,32 +89,23 @@ def _engine(model, kind, num_slots, page_size=16, **kw):
         page_size=page_size, **kw)
 
 
-def _reference_stream(model, req):
-    """The contract as a loop: one request alone, its keys derived eagerly,
-    the sampler given the keys themselves."""
-    eng = _engine(model, "dense", 1)
-    temps = jnp.asarray([req.temperature], jnp.float32)
-    logits = eng.prefill(0, req.prompt)
-    out = []
-    for n in range(req.max_new_tokens):
-        key = jax.random.fold_in(jax.random.PRNGKey(req.seed), n)
-        out.append(int(sample_tokens(logits, key[None, :], temps)[0]))
-        logits = eng.decode(jnp.asarray([out[-1]], jnp.int32),
-                            jnp.asarray([True]))
-    return out
-
-
-@pytest.mark.parametrize("kind", ["dense", "paged"])
-def test_scheduler_streams_equal_eager_key_reference(model, kind):
+@pytest.mark.parametrize("page_size", [16, 4])
+def test_scheduler_streams_equal_eager_key_reference(model, page_size):
+    """The contract as a loop (``full_forward.reference_stream``): one
+    request alone, no cache, its keys derived eagerly and the sampler given
+    the keys themselves. Pages of 16 hold a whole stream, pages of 4 are
+    crossed twice; a float32 pool, so that the logits are the model's."""
+    cfg, params = model
     reqs = [Request(prompt=(5 + i, 7, 11 + i), max_new_tokens=6,
                     temperature=0.9, seed=s)
             for i, s in enumerate(SEEDS)]
-    sched = ContinuousBatchingScheduler(_engine(model, kind, 3), eos_id=EOS)
+    sched = ContinuousBatchingScheduler(
+        _engine(model, 3, page_size, cache_dtype=jnp.float32), eos_id=EOS)
     for r in reqs:
         sched.submit(r)
     outs = sched.run()
     for r, got in zip(reqs, outs):
-        assert got == _reference_stream(model, r), r.seed
+        assert got == reference_stream(params, cfg, r, EOS, MAX_LEN), r.seed
     # streams of different seeds differ: the sampler did read the keys
     assert len({tuple(o) for o in outs}) > len(outs) // 2
 
@@ -168,7 +157,7 @@ def _steady_tick_counts(model, monkeypatch, num_slots, mode, page_size):
     if mode == "tree":
         kw.update(tree_spec=True, draft_model=DraftModel(
             params, cfg, num_slots=num_slots, max_len=MAX_LEN))
-    eng = _engine(model, "paged", num_slots, page_size, **kw)
+    eng = _engine(model, num_slots, page_size, **kw)
     sched = ContinuousBatchingScheduler(eng, eos_id=EOS)
     for i in range(num_slots):
         # a repeating prompt, so that the n-gram drafter proposes
@@ -287,15 +276,19 @@ def test_tick_programs_and_uploads_do_not_grow_with_slots(
 
 
 @pytest.mark.parametrize("mode", ["plain", "spec", "chunked"])
-@pytest.mark.parametrize("kind", ["dense", "paged"])
-def test_sampler_waits_once_a_tick_and_once_an_admission(model, kind, mode):
+@pytest.mark.parametrize("sharing", [True, False],
+                         ids=["shared", "private"])
+def test_sampler_waits_once_a_tick_and_once_an_admission(model, sharing,
+                                                         mode):
     """``stats.sampler_waits``, the counter that says the gate and the
     sampler are one program read back once: +1 for every first token
     sampled (an admission, monolithic or after the final chunk; a resumed
     request samples none) and +1 for every decode tick of any kind,
-    whatever the number of slots. It rides the registry as the others do."""
+    whatever the number of slots, and whether the two requests of one
+    prompt share its pages (and copy on their first write) or hold their
+    own. It rides the registry as the others do."""
     trc = Tracer()
-    eng = _engine(model, kind, 3, page_size=4, tracer=trc,
+    eng = _engine(model, 3, page_size=4, tracer=trc, prefix_sharing=sharing,
                   **({"spec_k": 2} if mode == "spec" else {}))
     sched = ContinuousBatchingScheduler(
         eng, eos_id=EOS, **({"chunk_tokens": 4} if mode == "chunked" else {}))
@@ -331,7 +324,7 @@ def test_sampler_waits_once_a_tick_and_once_an_admission(model, kind, mode):
 def test_sampler_waits_not_for_a_resumed_request(model):
     """A preempted request re-admitted with its progress samples no first
     token: its admission waits for no sampler."""
-    eng = _engine(model, "paged", 1)
+    eng = _engine(model, 1)
     sched = ContinuousBatchingScheduler(eng, eos_id=EOS)
     sched.submit(Request(prompt=(7, 11, 13), max_new_tokens=6))
     sched.step()
@@ -350,7 +343,7 @@ def test_sampler_waits_not_for_a_resumed_request(model):
 
 def test_build_inputs_span_counts_decoding_slots(model):
     trc = Tracer()
-    eng = _engine(model, "paged", 3, tracer=trc)
+    eng = _engine(model, 3, tracer=trc)
     sched = ContinuousBatchingScheduler(eng, eos_id=EOS)
     for i, n in enumerate((2, 5, 5)):
         sched.submit(Request(prompt=(3, 5 + i), max_new_tokens=n))

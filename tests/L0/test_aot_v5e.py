@@ -282,6 +282,30 @@ def test_paged_decode_attention_at_a_row_of_3840_lanes(v5e):
         ((16, 256), jnp.int32), ((16,), jnp.int32), ((), jnp.int32)) == 1
 
 
+def test_drafter_decodes_through_the_paged_kernel(v5e):
+    """The model drafter's per-token forward since PR 46: the paged decode
+    step over the drafter's own pool under its identity table
+    (``draft_gpt_medium``, 32 slots of 512 + 5 rows in pages of 16: heads
+    of 64 as the target's), with ``apex_paged_decode_fwd`` in it."""
+    from apex_tpu.models.gpt import draft_gpt_medium, init_gpt
+    from apex_tpu.serving.decode import make_paged_decode_fn
+    from apex_tpu.serving.draft_model import init_draft_cache
+
+    cfg, slots = draft_gpt_medium(), 32
+    sharding = SingleDeviceSharding(v5e)
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+    params = on(jax.eval_shape(
+        lambda k: init_gpt(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(functools.partial(
+        init_draft_cache, cfg, slots, 512 + 5)))
+    assert cache.block_tables.shape == (slots, 33)
+    text = make_paged_decode_fn(cfg).lower(
+        params, cache, on(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        on(jax.ShapeDtypeStruct((slots,), jnp.bool_))).compile().as_text()
+    assert "apex_paged_decode_fwd" in text
+
+
 def test_gated_delta_kernels(v5e):
     """Both Gated DeltaNet kernels at the published widths (30 heads, d_k
     96, d_v 192): the chunked form over the largest bucket, and the step

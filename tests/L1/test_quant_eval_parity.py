@@ -5,9 +5,9 @@ tier asks the question that matters for serving: after the model has
 actually LEARNED something (the fixed-batch overfit of the convergence
 smoke), does int8 inference reproduce the full-precision model's
 per-position eval loss? The curve here is the teacher-forced NLL at
-every decode position, run through the real serving paths (dense and
-paged, weight-only int8 and int8 KV pool), compared to the fp32 run of
-the same trained weights.
+every decode position, run through the real serving path (weight-only
+int8 and int8 KV pool), compared to the fp32 full forward of the same
+trained weights.
 
 Tolerance: 2% relative per position (documented in
 docs/source/quantization.rst; measured ~0.3% on this gate model — the
@@ -22,12 +22,10 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import gpt_loss_unsharded
-from apex_tpu.models.gpt import gpt_tiny, init_gpt
+from apex_tpu.models.gpt import apply_gpt_unsharded, gpt_tiny, init_gpt
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.quant import quantize_params
-from apex_tpu.serving import (
-    PagedDecodeEngine, init_cache, make_decode_fn, make_prefill_fn,
-)
+from apex_tpu.serving import PagedDecodeEngine
 
 # Trains the fixture model in-process: excluded from the driver's
 # `-m 'not slow'` tier; the PR gate runs this file by explicit path
@@ -65,35 +63,22 @@ def trained():
     return cfg, params, ids[:1, :S_TOTAL]
 
 
-def _teacher_forced_rows(cfg, params, seq, *, paged, cache_dtype,
-                         quantized):
-    if paged:
-        eng = PagedDecodeEngine(params, cfg, num_slots=2,
-                                max_len=S_MAX, num_pages=14,
-                                page_size=8, cache_dtype=cache_dtype,
-                                buckets=(8, 16, 32))
-        logits = eng.prefill(
-            0, [int(t) for t in np.asarray(seq[0, :PROMPT])])
-        rows = [logits[0]]
-        for t in range(PROMPT, S_TOTAL):
-            assert eng.prepare_decode({0: t}) == []
-            logits = eng.decode(
-                jnp.asarray([int(seq[0, t]), 0], jnp.int32),
-                jnp.asarray([True, False]))
-            rows.append(logits[0])
-        return jnp.stack(rows)
-    prefill = make_prefill_fn(cfg, quantized=quantized)
-    decode = make_decode_fn(cfg, quantized=quantized)
-    cache = init_cache(cfg, 2, S_MAX, jnp.float32)
-    cache, logits = prefill(params, cache, seq[:, :PROMPT],
-                            jnp.ones((PROMPT,), jnp.int32),
-                            jnp.int32(0))
+def _teacher_forced_rows(cfg, params, seq, *, cache_dtype):
+    """Rows PROMPT - 1 .. S_TOTAL - 1 through the engine; with no
+    ``cache_dtype``, of the full forward (the golden)."""
+    if cache_dtype is None:
+        hidden = apply_gpt_unsharded(params, cfg, seq)
+        table = params["embedding"]["word"]["embedding"]
+        return jnp.dot(hidden, table.T).astype(jnp.float32)[0, PROMPT - 1:]
+    eng = PagedDecodeEngine(params, cfg, num_slots=2, max_len=S_MAX,
+                            num_pages=14, page_size=8,
+                            cache_dtype=cache_dtype, buckets=(8, 16, 32))
+    logits = eng.prefill(0, [int(t) for t in np.asarray(seq[0, :PROMPT])])
     rows = [logits[0]]
     for t in range(PROMPT, S_TOTAL):
-        cache, logits = decode(params, cache,
-                               jnp.asarray([int(seq[0, t]), 0],
-                                           jnp.int32),
-                               jnp.asarray([True, False]))
+        assert eng.prepare_decode({0: t}) == []
+        logits = eng.decode(jnp.asarray([int(seq[0, t]), 0], jnp.int32),
+                            jnp.asarray([True, False]))
         rows.append(logits[0])
     return jnp.stack(rows)
 
@@ -110,8 +95,7 @@ def _nll_curve(cfg, params, seq, **kw):
 @pytest.fixture(scope="module")
 def golden_nll(trained):
     cfg, params, seq = trained
-    curve = _nll_curve(cfg, params, seq, paged=False, cache_dtype=None,
-                       quantized=False)
+    curve = _nll_curve(cfg, params, seq, cache_dtype=None)
     # the overfit actually bit: mean eval NLL is clearly under the
     # uniform floor, so the parity assertions compare real predictions
     assert np.all(np.isfinite(curve))
@@ -119,25 +103,17 @@ def golden_nll(trained):
     return curve
 
 
-@pytest.mark.parametrize("variant", ["w8_dense", "w8_paged",
-                                     "w8_kv8", "kv8_only"])
+@pytest.mark.parametrize("variant", ["w8_f32", "w8_bf16", "w8_kv8",
+                                     "kv8_only"])
 def test_quant_eval_curve_tracks_fp32(trained, golden_nll, variant):
     cfg, params, seq = trained
-    qp = quantize_params(params)
-    curve = {
-        "w8_dense": lambda: _nll_curve(cfg, qp, seq, paged=False,
-                                       cache_dtype=None,
-                                       quantized=True),
-        "w8_paged": lambda: _nll_curve(cfg, qp, seq, paged=True,
-                                       cache_dtype=jnp.float32,
-                                       quantized=True),
-        "w8_kv8": lambda: _nll_curve(cfg, qp, seq, paged=True,
-                                     cache_dtype=jnp.int8,
-                                     quantized=True),
-        "kv8_only": lambda: _nll_curve(cfg, params, seq, paged=True,
-                                       cache_dtype=jnp.int8,
-                                       quantized=False),
-    }[variant]()
+    weights, cache_dtype = {
+        "w8_f32": (quantize_params, jnp.float32),
+        "w8_bf16": (quantize_params, jnp.bfloat16),
+        "w8_kv8": (quantize_params, jnp.int8),
+        "kv8_only": (lambda p: p, jnp.int8),
+    }[variant]
+    curve = _nll_curve(cfg, weights(params), seq, cache_dtype=cache_dtype)
     assert np.all(np.isfinite(curve))
     np.testing.assert_allclose(curve, golden_nll,
                                rtol=QUANT_EVAL_RTOL)
