@@ -850,6 +850,13 @@ def _model_step_entry(family, which):
     ``apex_kda_decode_fwd`` and attends over the latent pool through
     ``apex_mla_decode_fwd``; both donate state, tails and ONE pool (no
     ``v``), lengths, block tables and three counters: 8 pairs.
+    ``glm`` (``models.glm_next``): four residual streams under hyper-
+    connections; prefill takes the prompt a stretch at a time through
+    ``apex_kda_chunk_fwd`` (from the state the stretch before left) and the
+    sparse layer's masked attention by query blocks; decode runs
+    ``apex_kda_decode_fwd``, ``apex_dsa_index_fwd``, an exact top-k, the gather
+    and ``apex_mla_decode_fwd``; both donate state, tails, ONE pool, lengths,
+    block tables, the index's keys and tails and five counters: 12 pairs.
     These entries are the kernel families' registration."""
     def build():
         import functools as ft
@@ -878,6 +885,9 @@ def _model_step_entry(family, which):
                 bailing_hybrid_tiny, init,
             )
             cfg = bailing_hybrid_tiny()
+        elif family == "glm":
+            from apex_tpu.models.glm_next import glm_next_tiny, init
+            cfg = glm_next_tiny()
         else:
             from apex_tpu.models.exaone_moe import exaone_moe_tiny, init
             cfg, init_pools = exaone_moe_tiny(), init_window_cache
@@ -1450,6 +1460,16 @@ def repo_entries() -> List[TraceEntry]:
                    _model_step_entry("ling", "decode"),
                    checks=("precision", "memory", "aliases"),
                    min_alias_pairs=8),
+        TraceEntry("glm_prefill_step",
+                   "apex_tpu.models.glm_next",
+                   _model_step_entry("glm", "prefill"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=12),
+        TraceEntry("glm_decode_step",
+                   "apex_tpu.transformer.functional.sparse_index",
+                   _model_step_entry("glm", "decode"),
+                   checks=("precision", "memory", "aliases"),
+                   min_alias_pairs=12),
         TraceEntry("gpt_paged_decode_step_tp2", "apex_tpu.serving.decode",
                    _paged_decode_step_entry(tp=2),
                    checks=("precision", "memory", "schedule", "aliases"),

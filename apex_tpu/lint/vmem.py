@@ -562,6 +562,23 @@ def _kda_cfg(which):
     return build
 
 
+def _dsa_index_cfg():
+    """The sparse attention's index kernel at the ``glm_next`` family's
+    published widths: 32 index heads of 128 a slot, 64 slots, against the one
+    sparse layer's cache of 65538 pages of 4 pooled keys, which stays where
+    it is; resident are two buffers of 64 pages' keys (64 KB each), the
+    queries and a slot's row of 4096 scores."""
+    def build():
+        from apex_tpu.transformer.functional.sparse_index import index_scores
+
+        return index_scores, (
+            _sds((64, 32, 128), "float32"), _sds((64, 32), "float32"),
+            _sds((1, 65538, 4, 128), "bfloat16"), _sds((64, 1024), "int32"),
+            _sds((64,), "int32"), _sds((), "int32"))
+
+    return build
+
+
 def _nemotron_kernel_cfg(which):
     """The two kernels of the ``nemotron_h`` family at the published widths.
     ``ssd_step``: the stacked Mamba-2 state of 5 layers x 128 slots (128
@@ -747,6 +764,9 @@ def repo_configs() -> List[Config]:
         cfgs.append(Config(f"kda_{which}_125b",
                            "apex_tpu.transformer.functional.gated_delta",
                            _kda_cfg(which)))
+    cfgs.append(Config("dsa_index_320b",
+                       "apex_tpu.transformer.functional.sparse_index",
+                       _dsa_index_cfg()))
     cfgs.append(Config("gpt_spec_verify_step", "apex_tpu.serving.decode",
                        _paged_serving_cfg("verify")))
     cfgs.append(Config("gpt_tree_verify_step", "apex_tpu.serving.decode",

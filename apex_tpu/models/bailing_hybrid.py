@@ -344,15 +344,24 @@ def init(key: jax.Array, cfg: BailingHybridConfig,
 
 def _kda_in(lp, x, cfg):
     """(rows, hidden) -> the convolution's input (rows, 3 H d), ``log a``
-    (rows, H, d), the output gate's input (rows, H d), ``b`` (rows, H)."""
+    (rows, H, d), the output gate's input (rows, H d), ``b`` (rows, H). A
+    config with a ``kda_gate_rank`` (``models.glm_next``) has the decay and
+    the output gate pass a bottleneck of that rank: ``in_proj`` then holds
+    their two narrow inputs where this family's holds the two wide ones, and
+    ``a_up`` / ``g_up`` widen them."""
     proj = _dense(lp["in_proj"], _rms(lp["norm"], x, cfg.rms_norm_eps))
     c, w = cfg.conv_channels, cfg.kda_width
     rows = x.shape[0]
-    a = (proj[:, c:c + w] + lp["dt_bias"]).reshape(rows, cfg.num_heads, -1)
+    r = getattr(cfg, "kda_gate_rank", 0)
+    n = r or w                  # the width of each gate's input in ``proj``
+    a = _dense(lp["a_up"], proj[:, c:c + n]) if r else proj[:, c:c + n]
+    a = (a + lp["dt_bias"]).reshape(rows, cfg.num_heads, -1)
     log_decay = cfg.kda_lower_bound * jax.nn.sigmoid(
         jnp.exp(lp["a_log"])[:, None] * a)
-    return proj[:, :c], log_decay, proj[:, c + w:c + 2 * w], \
-        jax.nn.sigmoid(proj[:, c + 2 * w:])
+    conv_in, gate = proj[:, :c], proj[:, c + n:c + 2 * n]
+    if r:
+        gate = _dense(lp["g_up"], gate)
+    return conv_in, log_decay, gate, jax.nn.sigmoid(proj[:, c + 2 * n:])
 
 
 def _kda_heads(conv_out, cfg):
@@ -372,19 +381,26 @@ def _kda_out(lp, o, gate, cfg):
     return _dense(lp["out"], o * jax.nn.sigmoid(gate))
 
 
-@region("mixer")
-def kda_prefill(lp, x, cfg, mask):
-    """One KDA layer over a prompt: ``x`` (s, hidden), ``mask`` (s,) with 1 =
-    real token and the padding at the end. Returns ``(x', state (H, d, d)
-    float32, tail (w-1, 3 H d))`` as the prompt's last real token leaves
-    them, the tail as the ring :func:`kda_decode` goes on from: padded
-    positions decay nothing (``log a = 0``) and write nothing (``b = 0``)."""
+def kda_mix_prefill(lp, x, cfg, mask, start=None, residual=None):
+    """What one KDA layer ADDS to its input over a prompt (the norm in front
+    is the layer's own): ``x`` (s, hidden), ``mask`` (s,) with 1 = real token
+    and the padding at the end. Returns ``(y, state (H, d, d) float32, tail
+    (w-1, 3 H d))`` as the prompt's last real token leaves them, the tail as
+    the ring :func:`kda_mix_decode` goes on from: padded positions decay
+    nothing (``log a = 0``) and write nothing (``b = 0``). With ``start``, a
+    ``(state, tail)`` pair, ``x`` is one STRETCH of a prompt taken a stretch
+    at a time (``models.glm_next``): the layer goes on from what the stretch
+    before left, and the tail comes back as it is carried, oldest first
+    (:func:`~apex_tpu.transformer.functional.gated_delta.ring_of_tail` makes
+    the ring of the last one). ``residual`` is added to ``y`` where this
+    family's block adds its input."""
     s = x.shape[0]
     real = mask.astype(bool)
     conv_in, log_decay, gate, beta = _kda_in(lp, x, cfg)
     length = jnp.sum(mask)
+    state0, tail0 = start or (None, None)
     conv_out, tail = causal_conv(
-        conv_in, lp["conv"]["weight"].astype(jnp.float32), length)
+        conv_in, lp["conv"]["weight"].astype(jnp.float32), length, tail0)
     q, k, v = _kda_heads(conv_out, cfg)
     log_decay = jnp.where(real[:, None, None], log_decay, 0.0)
     beta = jnp.where(real[:, None], beta, 0.0)
@@ -395,18 +411,27 @@ def kda_prefill(lp, x, cfg, mask):
         return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
 
     o, state = gated_delta_chunked(lead(q), lead(k), lead(v),
-                                   lead(log_decay), lead(beta))
+                                   lead(log_decay), lead(beta),
+                                   initial_state=state0)
     o = jnp.moveaxis(o[:, :s], 0, 1)
-    return x + _kda_out(lp, o, gate, cfg), state, ring_of_tail(tail, length)
+    y = _kda_out(lp, o, gate, cfg)
+    if residual is not None:
+        y = residual + y
+    return y, state, ring_of_tail(tail, length) if start is None else tail
 
 
 @region("mixer")
-def kda_decode(lp, x, cfg, state, conv, layer: int, pos, active):
-    """One token for every slot, at ``pos`` (b,): ``x`` (b, hidden); ``state``
-    and ``conv`` the WHOLE stacked arrays
-    (``BailingHybridConfig.state_shapes``), of which KDA layer ``layer`` is
-    read and written; the tail is a ring (``conv_ring_step``). Returns
-    ``(x', state', conv')``."""
+def kda_prefill(lp, x, cfg, mask):
+    """``x + `` :func:`kda_mix_prefill`: one KDA layer on one residual
+    stream. Returns ``(x', state, tail)``."""
+    return kda_mix_prefill(lp, x, cfg, mask, residual=x)
+
+
+def kda_mix_decode(lp, x, cfg, state, conv, layer: int, pos, active):
+    """What one KDA layer adds for one token of every slot, at ``pos`` (b,):
+    ``x`` (b, hidden); ``state`` and ``conv`` the WHOLE stacked arrays
+    (``state_shapes``), of which KDA layer ``layer`` is read and written; the
+    tail is a ring (``conv_ring_step``). Returns ``(y, state', conv')``."""
     conv_in, log_decay, gate, beta = _kda_in(lp, x, cfg)
     ring = lax.dynamic_index_in_dim(conv, layer, 0, keepdims=False)
     conv_out, ring = conv_ring_step(
@@ -415,7 +440,15 @@ def kda_decode(lp, x, cfg, state, conv, layer: int, pos, active):
     q, k, v = _kda_heads(conv_out, cfg)
     o, state = gated_delta_step(q, k, v, log_decay, beta, state,
                                 jnp.int32(layer), active)
-    return x + _kda_out(lp, o, gate, cfg), state, conv
+    return _kda_out(lp, o, gate, cfg), state, conv
+
+
+@region("mixer")
+def kda_decode(lp, x, cfg, state, conv, layer: int, pos, active):
+    """``x + `` :func:`kda_mix_decode`. Returns ``(x', state', conv')``."""
+    y, state, conv = kda_mix_decode(lp, x, cfg, state, conv, layer, pos,
+                                    active)
+    return x + y, state, conv
 
 
 # ---------------------------------------------------------------------------

@@ -335,9 +335,19 @@ def logits_of(params, cfg, x):
                   _rms(params["final_norm"], x, cfg.rms_norm_eps))
 
 
-def _swiglu(gate_up):
+def _swiglu(gate_up, limit: float = 0.0):
+    """``silu(gate) * up`` of ``[gate | up]``; with a ``limit`` (a config's
+    ``swiglu_limit``, ``models.glm_next``) the gate is held below it and the
+    up part inside ``+-limit`` first. No limit adds no operation."""
     f = gate_up.shape[-1] // 2
-    return jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+    if not limit:
+        return jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+    return jax.nn.silu(jnp.minimum(gate_up[:, :f], limit)) \
+        * jnp.clip(gate_up[:, f:], -limit, limit)
+
+
+def _limit(cfg) -> float:
+    return getattr(cfg, "swiglu_limit", 0.0)
 
 
 # What follows up to the MLPs is the latent attention itself, for any config
@@ -461,10 +471,11 @@ def attention_decode(lp, x, cfg, pool, layer, block_tables, pos, active):
 # the MLPs: dense, and the experts held here
 # ---------------------------------------------------------------------------
 
-def swiglu_mlp(lp, u):
+def swiglu_mlp(lp, u, limit: float = 0.0):
     """``W_down(silu(W_gate u) * W_up u)`` of rows ``u`` as the layer takes
-    them (normed, where the family norms in front)."""
-    return _dense(lp["down"], _swiglu(_dense(lp["gate_up"], u)))
+    them (normed, where the family norms in front); ``limit``:
+    :func:`_swiglu`'s."""
+    return _dense(lp["down"], _swiglu(_dense(lp["gate_up"], u), limit))
 
 
 @region("mlp")
@@ -503,7 +514,8 @@ def _experts(lp, u, cfg, real, held=None, first_group=None):
         d = moe.dispatch(chosen, weights, cfg.expert_offset,
                          cfg.experts_held, real)
         mid = _swiglu(moe.grouped_matmul(u[d.token], w_gate_up, d.sizes,
-                                         first_group=first_group))
+                                         first_group=first_group),
+                      _limit(cfg))
         out = moe.grouped_matmul(mid, w_down, d.sizes,
                                  first_group=first_group)
         return moe.combine(out, d, u.shape[0]), d.sizes, chosen
@@ -531,7 +543,7 @@ def expert_parts(lp, u, cfg, real, held=None, first_group=None):
         routed, sizes, chosen = _experts(lp, u, cfg, real, held, first_group)
     with region("mlp"):     # the shared expert
         shared = _dense(lp["shared_down"],
-                        _swiglu(_dense(lp["shared_gate_up"], u)))
+                        _swiglu(_dense(lp["shared_gate_up"], u), _limit(cfg)))
     return routed, shared, sizes, chosen
 
 

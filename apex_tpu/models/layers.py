@@ -154,3 +154,80 @@ def embedding(params: dict, ids: jax.Array, dtype=None) -> jax.Array:
     if dtype is not None:
         table = table.astype(dtype)
     return jnp.take(table, ids, axis=0)
+
+
+# -- hyper-connections ------------------------------------------------------
+
+def sinkhorn(m, iters: int):
+    """``m`` (..., n, n) positive -> its rows, then its columns, scaled to
+    sum 1, ``iters`` times over (unrolled): doubly stochastic in the limit."""
+    for _ in range(iters):
+        m = m / jnp.sum(m, -1, keepdims=True)
+        m = m / jnp.sum(m, -2, keepdims=True)
+    return m
+
+
+def init_hyper_connection(key, streams: int, width: int, spread: float = 0.0):
+    """One sub-layer's maps: ``proj`` (streams * width, 2 n + n * n) float32,
+    the scalars ``a_*`` 0.01, and biases that make ``Hpre = 1/n``, ``Hpost =
+    1`` and ``Hres`` near the identity; with a ``spread`` the biases are
+    drawn ``N(., spread)`` around those, so that no stream idles."""
+    n = streams
+    k_proj, k_pre, k_post, k_res = jax.random.split(key, 4)
+    fan_in = n * width
+    return {
+        "proj": lecun_normal(k_proj, (fan_in, 2 * n + n * n), fan_in),
+        "a_pre": jnp.float32(0.01), "a_post": jnp.float32(0.01),
+        "a_res": jnp.float32(0.01),
+        "b_pre": -jnp.log(n - 1.0) + spread * jax.random.normal(k_pre, (n,)),
+        "b_post": spread * jax.random.normal(k_post, (n,)),
+        "b_res": 4.0 * jnp.eye(n) + spread * jax.random.normal(k_res, (n, n)),
+    }
+
+
+def hyper_open(streams, hp, *, iters: int = 20, eps: float = 1e-6):
+    """The three maps of one sub-layer from ``streams`` (rows, n, width), and
+    the stream it reads: ``(Hpre streams (rows, width), Hpost (rows, n), Hres
+    (rows, n, n))``; :func:`hyper_connect` has the equations."""
+    rows, n, _ = streams.shape
+    flat = streams.reshape(rows, -1)
+    flat = flat * lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True) + eps)
+    maps = jnp.dot(flat, hp["proj"], precision=lax.Precision.HIGHEST)
+    pre = jax.nn.sigmoid(hp["a_pre"] * maps[:, :n] + hp["b_pre"])
+    post = 2.0 * jax.nn.sigmoid(hp["a_post"] * maps[:, n:2 * n] + hp["b_post"])
+    res = sinkhorn(jnp.exp(hp["a_res"] * maps[:, 2 * n:].reshape(rows, n, n)
+                           + hp["b_res"]), iters)
+    # sums over the n streams written out: nothing of (rows, n, n, width)
+    return sum(pre[:, j, None] * streams[:, j] for j in range(n)), post, res
+
+
+def hyper_close(streams, opened, y):
+    """``Hres streams + Hpost^T y``: what :func:`hyper_open` ``opened`` and
+    what the sub-layer made of the stream it read, back into the streams."""
+    _, post, res = opened
+    return post[..., None] * y[:, None] + sum(
+        res[:, :, j, None] * streams[:, j, None]
+        for j in range(streams.shape[1]))
+
+
+def hyper_connect(streams, hp, f, *, iters: int = 20, eps: float = 1e-6):
+    """One sub-layer ``f`` on ``n`` residual streams (manifold-constrained
+    hyper-connections, arXiv 2512.24880): ``streams`` (rows, n, width)
+    float32; with ``x~`` the rows' ``n * width`` values under an RMS norm
+    without gain,
+
+        Hpre  = sigmoid(a_pre (x~ P_pre) + b_pre)              (rows, n)
+        Hpost = 2 sigmoid(a_post (x~ P_post) + b_post)         (rows, n)
+        Hres  = sinkhorn(exp(a_res mat(x~ P_res) + b_res))     (rows, n, n)
+        streams' = Hres streams + Hpost^T f(Hpre streams)
+
+    ``f`` takes the ONE mixed stream (rows, width) and returns what the
+    sub-layer adds (its norm in front is its own), or a tuple whose first
+    entry that is; the rest is handed back after ``streams'``. The maps are
+    float32 products at full precision; plain ``jax.numpy``
+    (:func:`hyper_open`, ``f``, :func:`hyper_close`)."""
+    opened = hyper_open(streams, hp, iters=iters, eps=eps)
+    out = f(opened[0])
+    if not isinstance(out, tuple):
+        return hyper_close(streams, opened, out)
+    return (hyper_close(streams, opened, out[0]),) + out[1:]

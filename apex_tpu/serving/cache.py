@@ -96,7 +96,18 @@ class HybridKVCache(NamedTuple):
     ``cfg.latent`` both: ``apex_tpu.models.bailing_hybrid``): ``k`` is then
     the only pool, rows that are key and value at once as in
     :class:`LatentKVCache`, and ``v`` is ``None`` (a ``None`` leaf vanishes
-    from the pytree: 5 donated leaves and the counters instead of 6)."""
+    from the pytree: 5 donated leaves and the counters instead of 6).
+
+    Where the attention PICKS the rows it reads (``cfg.indexed``:
+    ``apex_tpu.models.glm_next``, an indexer over pooled keys), ``index`` is
+    the indexer's cache, else ``None``: ``rows`` ``[L_attn, num_pages,
+    page_size / index_kpool, index_head_dim]``, one pooled key for every
+    ``index_kpool`` positions, a page's keys one entry of the SAME block
+    table and page ids as ``k`` (a page of latents and its index keys live
+    and die together: no second allocator, no second table), in the pool's
+    dtype; and ``tail`` ``[L_attn, slots, index_kpool - 1, index_head_dim]``
+    float32, the keys of each slot's group that is not whole yet, a ring as
+    ``conv`` is (position ``t`` in row ``t % index_kpool``)."""
     k: jax.Array             # (L_attn, num_pages, page_size, kv_heads * hd)
     v: Optional[jax.Array]   # the same, or None beside a latent pool
     lengths: jax.Array       # (num_slots,) int32
@@ -104,6 +115,7 @@ class HybridKVCache(NamedTuple):
     state: jax.Array         # (L_rec, slots, heads, ...) float32
     conv: jax.Array          # (L_rec, slots, w - 1, channels) float32
     counters: Optional[dict] = None
+    index: Optional[dict] = None    # {"rows", "tail"} of an indexed pool
 
     # no quantized pool beside recurrent state (the engine refuses it)
     k_scale = None
@@ -261,12 +273,18 @@ def init_hybrid_cache(cfg, num_slots: int, max_len: int, num_pages: int,
     the model's attention is latent, ``cfg.latent``: ``v`` is left out), and
     zeroed per-slot recurrent state and convolution tails (float32 both,
     whatever the pool's ``dtype``) for the recurrent layers; zeroed counters
-    where the model keeps any."""
+    where the model keeps any; and the indexer's cache (``cfg.indexed``:
+    ``cfg.index_shapes``), zeroed, where the attention picks its rows."""
     _check_pool_sizes(num_slots, max_len, num_pages, page_size)
     if jnp.dtype(dtype) == jnp.int8:
         raise ValueError("no int8 pool beside recurrent state")
     shape = (cfg.kv_layers, num_pages, page_size, cfg.kv_row_width)
     state, conv = cfg.state_shapes(num_slots)
+    index = None
+    if getattr(cfg, "indexed", False):
+        rows, tail = cfg.index_shapes(num_slots, num_pages, page_size)
+        index = {"rows": jnp.zeros(rows, dtype),
+                 "tail": jnp.zeros(tail, jnp.float32)}
     return HybridKVCache(
         k=jnp.zeros(shape, dtype),
         v=None if getattr(cfg, "latent", False) else jnp.zeros(shape, dtype),
@@ -274,7 +292,7 @@ def init_hybrid_cache(cfg, num_slots: int, max_len: int, num_pages: int,
         block_tables=_parked_tables(num_slots, max_len, page_size),
         state=jnp.zeros(state, jnp.float32),
         conv=jnp.zeros(conv, jnp.float32),
-        counters=_zero_counters(cfg))
+        counters=_zero_counters(cfg), index=index)
 
 
 def init_latent_cache(cfg, num_slots: int, max_len: int, num_pages: int,

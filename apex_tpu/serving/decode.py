@@ -85,7 +85,7 @@ from apex_tpu.models.gpt import (
     _rope_or_none, _tied_lm_logits, _tiles_to_pages,
 )
 from apex_tpu.serving.cache import (
-    PagedKVCache, paged_cache_partition_specs, ring_page,
+    SCRATCH_PAGE, PagedKVCache, paged_cache_partition_specs, ring_page,
 )
 from apex_tpu.utils.profiler import region
 
@@ -266,6 +266,29 @@ def _write_new_rows(cache, k_rows, v_rows):
         at = _row_numbers(cache.k, pages, pos)
         return _put_rows(cache.k, k_rows, at), \
             None if cache.v is None else _put_rows(cache.v, v_rows, at)
+
+
+def _write_index_keys(cache, keys, tail, active):
+    """The indexer's cache after a decode step (``cfg.indexed``): ``keys``
+    ``(L, slots, width)`` is the pooled key of the group each slot's new
+    token CLOSES, which goes to that group's place in the page the block
+    table names (one scatter; a slot that closes no group, or is not
+    ``active``, writes to the scratch page, which no slot reads); ``tail`` the
+    slots' rings as the step left them."""
+    pos, bt = cache.lengths, cache.block_tables
+    rows = cache.index["rows"]
+    layers, _, keys_a_page, _ = rows.shape
+    page_size = cache.k.shape[2]
+    pool = page_size // keys_a_page
+    with region("cache_write"):
+        logical = jnp.clip(pos // page_size, 0, bt.shape[1] - 1)
+        pages = jnp.take_along_axis(bt, logical[:, None], 1)[:, 0]
+        closes = active & (pos % pool == pool - 1)
+        pages = jnp.where(closes, pages, SCRATCH_PAGE)
+        at = (pos % page_size) // pool
+        rows = rows.at[jnp.arange(layers)[:, None], pages[None, :],
+                       at[None, :]].set(keys.astype(rows.dtype))
+    return {"rows": rows, "tail": tail}
 
 
 def _row_numbers(pool, pages, pos):
@@ -642,7 +665,8 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
 #   ``kv_layers``, ``kv_row_width``        the page pool's leading axis and
 #                                          row
 #   ``counter_shapes()`` (optional)        int32 counters kept in the cache
-#   ``prefill_core(params, ids, mask, kv_dtype)`` -> (x (s, hidden), states,
+#   ``prefill_core(params, ids, mask, kv_dtype)`` -> (x (s, hidden) or the
+#       last real token's row alone (1, hidden), states,
 #       tails, k, v (kv_layers, s, kv_row_width))
 #   ``decode_core(params, cache, tokens, active)`` -> (x (slots, hidden),
 #       state', conv', counters', k_rows, v_rows (kv_layers, slots, width))
@@ -664,6 +688,15 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
 #                   shared, copied, preempted and shipped as any page; what
 #                   needs a program the model does not bring is refused
 #                   (``scheduler._refuse_without_a_core``).
+#   ``indexed``     (beside ``recurrent`` and ``latent``) its attention PICKS
+#                   the rows it reads by an indexer whose pooled keys are
+#                   cached too (``index_shapes(slots, pages, page_size)``:
+#                   ``HybridKVCache.index``, addressed by the pool's own
+#                   block table; ``index_bytes_per_page()``). Both cores give
+#                   LAST an ``index`` pair: a prefill the prompt's keys page
+#                   by page and the slot's tail, a decode step the key each
+#                   slot's token closes and the tails. Nothing that moves a
+#                   page without its keys is offered over such a pool.
 #   ``pools``       (a model with no state) a key of
 #                   ``serving.cache.MODEL_POOLS``: which cache holds what its
 #                   cores read, and the words a refusal names it by.
@@ -679,7 +712,8 @@ def make_paged_chunk_prefill_fn(cfg: GPTConfig, compute_dtype=None,
 #                   into the slot's cycle; the host never sees that pool.
 # ``models.hybrid`` and ``models.nemotron_h`` (recurrent),
 # ``models.deepseek`` (latent), ``models.exaone_moe`` (window) and
-# ``models.bailing_hybrid`` (recurrent AND latent) stand on it.
+# ``models.bailing_hybrid`` (recurrent AND latent) and ``models.glm_next``
+# (recurrent, latent AND indexed) stand on it.
 
 def model_cores(cfg) -> bool:
     """Does ``cfg`` bring its own prefill and decode cores (the seam)?"""
@@ -706,9 +740,11 @@ def _model_prefill_core(params, cfg, cache, ids, mask, slot, write_pages,
                          f"per bucket page ({s // page_size},)")
     x, states, tails, k, v, *windowed = cfg.prefill_core(
         params, ids[0], mask, cache.k.dtype)
+    index = windowed.pop() if getattr(cfg, "indexed", False) else None
     length = jnp.sum(mask).astype(jnp.int32)
-    logits = cfg.logits_of(
-        params, lax.dynamic_slice_in_dim(x, length - 1, 1, 0))
+    # (a core may hand back the last real token's row alone)
+    logits = cfg.logits_of(params, x if x.shape[0] == 1 and s > 1 else
+                           lax.dynamic_slice_in_dim(x, length - 1, 1, 0))
 
     def pages(t):
         # (L_full, s, width) -> whole pages, the pad tail zeroed
@@ -728,6 +764,17 @@ def _model_prefill_core(params, cfg, cache, ids, mask, slot, write_pages,
                 cache.state, states[:, None], (0, slot, 0, 0, 0))
             new["conv"] = lax.dynamic_update_slice(
                 cache.conv, tails[:, None], (0, slot, 0, 0))
+        if index is not None:
+            # the prompt's pooled keys (L, groups, width) page by page beside
+            # the latents of the same pages; the keys of its last group, if
+            # that is not whole, into the slot's tail
+            keys, tail = index
+            rows = cache.index["rows"]
+            new["index"] = {
+                "rows": rows.at[:, write_pages].set(keys.astype(
+                    rows.dtype).reshape(rows.shape[0], -1, *rows.shape[2:])),
+                "tail": lax.dynamic_update_slice(
+                    cache.index["tail"], tail[:, None], (0, slot, 0, 0))}
         if windowed:
             # the prompt's last ``ring`` logical pages (fewer in a bucket
             # that has fewer), from the one that holds its last token back:
@@ -753,12 +800,15 @@ def _model_decode_core(params, cfg, cache, tokens, active):
     are not ``active`` keep their recurrent state and their length."""
     x, state, conv, counters, k_rows, v_rows, *windowed = cfg.decode_core(
         params, cache, tokens, active)
+    index = windowed.pop() if getattr(cfg, "indexed", False) else None
     logits = cfg.logits_of(params, x)
     k, v = _write_new_rows(cache, k_rows, v_rows)
     pos = cache.lengths
     new = {"k": k}
     if v is not None:
         new["v"] = v
+    if index is not None:
+        new["index"] = _write_index_keys(cache, *index, active)
     if windowed:
         new["wk"], new["wv"] = _write_window_rows(cache, *windowed)
     new["lengths"] = jnp.where(active, pos + 1, pos)

@@ -168,6 +168,12 @@ def _validate_replicas(prefill_engines, decode_engines) -> None:
             "pool replica must be a DISTINCT engine (a shared instance "
             "would alias slots and page pools)")
     for _, eng in named:
+        if getattr(eng.cfg, "indexed", False):
+            raise ValueError(
+                "page transfer is not offered over an indexed pool "
+                f"({type(eng.cfg).__name__}): the handoff ships the pages a "
+                "block table names, and each page's index keys would have "
+                "to travel with it; such a model stays colocated")
         if getattr(eng.cfg, "recurrent", False):
             raise ValueError(
                 "page transfer is not offered for a model with recurrent "

@@ -235,6 +235,17 @@ def _refuse_for_recurrent(cfg, **features) -> None:
         _refuse(cfg, "for a model with recurrent layers", features)
 
 
+def _refuse_over_an_indexed_pool(cfg, **features) -> None:
+    """A model whose attention picks its rows (``cfg.indexed``) keeps a page's
+    index keys beside the page, under the same page id
+    (``HybridKVCache.index``). A page that is shared, copied on write or
+    shipped to another engine would have to take its keys along, and no
+    program here moves them: refused by name, whatever else would refuse
+    it too."""
+    if getattr(cfg, "indexed", False):
+        _refuse(cfg, "over an indexed pool", features)
+
+
 def _refuse(cfg, where: str, features) -> None:
     for name, (asked, needs) in features.items():
         if asked:
@@ -385,6 +396,12 @@ class PagedDecodeEngine:
                 "branch commit would re-round committed history at "
                 "branch-dependent scales; kv8 keeps linear speculation")
         self.recurrent = bool(getattr(cfg, "recurrent", False))
+        _refuse_over_an_indexed_pool(
+            cfg,
+            prefix_sharing=(prefix_sharing, "a shared page, and its copy on "
+                            "the first write, would have to carry the "
+                            "page's index keys; build the engine with "
+                            "prefix_sharing=False"))
         _refuse_for_recurrent(
             cfg,
             prefix_sharing=(prefix_sharing, "a shared page stands for "
@@ -509,6 +526,9 @@ class PagedDecodeEngine:
                 self.cache.wk.nbytes // self.cache.wk.shape[1]) \
                 if getattr(cfg, "window", 0) else None
             self._latent = self.cache.v is None
+            self._index_bytes = cfg.index_bytes_per_page(
+                page_size, jnp.dtype(cache_dtype).itemsize) \
+                if getattr(cfg, "indexed", False) else None
             self._prefill = make_model_prefill_fn(cfg)
             self._decode = make_model_decode_fn(cfg)
             self._chunk_prefill = self._verify = self._tree_verify = None
@@ -709,7 +729,8 @@ class PagedDecodeEngine:
         """What a prefill of a model that brings its cores writes, by the
         facts its config states (``serving.decode``, "the seam"): the slot's
         recurrent state, its cycle of window pages, its private pages of a
-        latent pool; state AND latent pages where both facts hold."""
+        latent pool; state AND latent pages where both facts hold, and the
+        index keys of those pages where the pool is indexed."""
         if not self.model_cores:
             return {}
         said = {}
@@ -719,6 +740,8 @@ class PagedDecodeEngine:
             said["window_bytes"] = self._window_bytes
         elif self._latent:
             said["latent_bytes"] = private_pages * self._page_bytes
+        if self._index_bytes:
+            said["index_bytes"] = private_pages * self._index_bytes
         return said
 
     # -- chunked prefill ------------------------------------------------

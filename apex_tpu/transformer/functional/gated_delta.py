@@ -92,14 +92,17 @@ def _group(heads: int, most: int) -> int:
 # the causal depthwise convolution in front of q, k and v
 # ---------------------------------------------------------------------------
 
-def causal_conv(x, weight, length):
+def causal_conv(x, weight, length, tail=None):
     """``x`` (s, c), ``weight`` (w, c): ``y_t = sum_j weight[j] x_{t-w+1+j}``
-    with zeros before the sequence. Returns ``(y, tail)``: ``tail`` (w-1, c)
-    holds the inputs at ``length - w + 1 .. length - 1`` (zeros before the
-    start), what :func:`conv_step` needs to go on from ``length``; rows of
-    ``x`` at or past ``length`` never reach it."""
+    with zeros before the sequence, or with ``tail`` (w-1, c) there: the
+    inputs before ``x`` of a sequence taken a stretch at a time. Returns
+    ``(y, tail)``: ``tail`` (w-1, c) holds the inputs at ``length - w + 1 ..
+    length - 1`` (zeros, or the ``tail`` given, before the start), what
+    :func:`conv_step` or the next stretch needs to go on from ``length``;
+    rows of ``x`` at or past ``length`` never reach it."""
     w = weight.shape[0]
-    xp = jnp.pad(x, ((w - 1, 0), (0, 0)))
+    xp = jnp.pad(x, ((w - 1, 0), (0, 0))) if tail is None \
+        else jnp.concatenate([tail.astype(x.dtype), x])
     y = sum(weight[j] * lax.dynamic_slice_in_dim(xp, j, x.shape[0], 0)
             for j in range(w))
     return y, lax.dynamic_slice_in_dim(xp, length, w - 1, 0)
@@ -184,14 +187,17 @@ def unit_lower_inverse(n):
     return inv.reshape(lead + (c, c))
 
 
-def _chunk_kernel(wv_ref, wk_ref, qg_ref, kt_ref, attn_ref, gl_ref, o_ref,
-                  s_ref, *, per_channel=False):
+def _chunk_kernel(wv_ref, wk_ref, qg_ref, kt_ref, attn_ref, gl_ref, *refs,
+                  per_channel=False):
     """``per_channel``: ``gl_ref`` holds the chunk's decay as a ``(1, d_k)``
     row, one factor a ROW of the state; else as a ``(1, d_v)`` row of one
-    number."""
+    number. ``refs``: the outputs ``o_ref, s_ref``, behind the state the walk
+    starts from where the call gives one (else it starts from zeros)."""
+    *start, o_ref, s_ref = refs
+
     @pl.when(pl.program_id(1) == 0)
     def _():
-        s_ref[...] = jnp.zeros_like(s_ref)
+        s_ref[...] = start[0][...] if start else jnp.zeros_like(s_ref)
 
     def dot(a, b):
         return lax.dot_general(a, b, (((1,), (0,)), ((), ())), precision=_HI,
@@ -268,8 +274,10 @@ def _channel_decay_operands(q, k, v, g, beta):
 
 
 def gated_delta_chunked(q, k, v, log_decay, beta, *, chunk=CHUNK,
-                        interpret=None):
-    """The recurrence over a whole sequence from a zero state.
+                        interpret=None, initial_state=None):
+    """The recurrence over a whole sequence from a zero state, or from
+    ``initial_state`` (heads, d_k, d_v) float32: a sequence taken a stretch
+    at a time.
 
     ``q``, ``k`` (heads, s, d_k) and ``v`` (heads, s, d_v) float32, ``q``
     already scaled and both already normalised; ``log_decay`` (heads, s) is
@@ -335,6 +343,9 @@ def gated_delta_chunked(q, k, v, log_decay, beta, *, chunk=CHUNK,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=pallas_interpret(interpret))
+    if initial_state is not None:
+        call["in_specs"].append(call["out_specs"][1])
+        operands += (initial_state.astype(f32),)
     # one body, two names: the name says which rule a traced call ran
     if per_channel:
         with jax.named_scope("apex_kda_chunk_fwd"):

@@ -19,6 +19,7 @@ NEG_INF = -1e30
 # ``chip_smoke.py`` refuses a kernel it does not name.
 KERNEL_NAMES = (
     "apex_flash_bwd_dkv", "apex_flash_bwd_dq", "apex_flash_fwd",
+    "apex_dsa_index_fwd",
     "apex_fmha_bwd", "apex_fmha_fwd", "apex_gdn_chunk_fwd", "apex_gdn_decode_fwd",
     "apex_kda_chunk_fwd", "apex_kda_decode_fwd", "apex_ln_bwd",
     "apex_ln_bwd_coldx", "apex_ln_bwd_colsum", "apex_ln_fwd",

@@ -14,7 +14,9 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.models import deepseek, exaone_moe, gpt, hybrid, nemotron_h
+from apex_tpu.models import (
+    bailing_hybrid, deepseek, exaone_moe, gpt, hybrid, nemotron_h,
+)
 from apex_tpu.serving import PagedDecodeEngine
 
 SLOTS, MAX_LEN, PAGES, PAGE, BUCKETS, SPEC_K, CHUNK = 3, 512, 100, 16, \
@@ -34,6 +36,8 @@ def families():
     yield "deepseek", deepseek.init(k, c), c, f32
     c = exaone_moe.exaone_moe_tiny()
     yield "exaone", exaone_moe.init(k, c), c, f32
+    c = bailing_hybrid.bailing_hybrid_tiny()
+    yield "ling", bailing_hybrid.init(k, c), c, dict(prefix_sharing=False)
 
 
 def i32(*shape):

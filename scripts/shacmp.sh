@@ -1,6 +1,6 @@
 #!/bin/bash
 # sha256 of the lowered text of every tiny configuration's serving programs
-# (sha.py: GPT, hybrid, Nemotron, DeepSeek, K-EXAONE; float32 and bfloat16
+# (sha.py: GPT, hybrid, Nemotron, DeepSeek, K-EXAONE, Ling; float32 and bfloat16
 # pools; prefill and decode, and the GPT family's verify, tree verify and
 # chunk prefill): a parent checkout against the working tree. A change that
 # says it leaves the served programs alone has to print SAME.

@@ -1,7 +1,8 @@
 """One vocabulary of device scopes (PR 38): every ``jax.named_scope`` /
 ``annotate`` / ``region`` the package opens is a device region
 (``utils.profiler.REGIONS``, through ``region()``), a Pallas kernel's own
-``apex_<kernel>`` scope, a ``layer{i}`` or an optimizer's ``<Name>.step``.
+``apex_<kernel>`` scope, a ``layer{i}``, an optimizer's ``<Name>.step`` or one
+of the sparse attention's four steps inside its region (``OTHER``).
 A scope outside these would be a second vocabulary the trace readers
 (``benchmark/regions.py``) do not know."""
 
@@ -15,8 +16,14 @@ from apex_tpu.utils.profiler import REGIONS
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-#: the scopes that are neither a region nor a kernel's, by file
-OTHER = {"FusedAdam.step": "apex_tpu/optimizers/fused_adam.py"}
+#: the scopes that are neither a region nor a kernel's, by file: an
+#: optimizer's step, and the four steps of learned-sparse attention INSIDE
+#: the ``attention`` region (an operation counts under the first region on its
+#: path, so the readers see them as attention; a trace says which step)
+_SPARSE = "apex_tpu/models/glm_next.py"
+OTHER = {"FusedAdam.step": "apex_tpu/optimizers/fused_adam.py",
+         "dsa_index": _SPARSE, "dsa_topk": _SPARSE, "dsa_gather": _SPARSE,
+         "dsa_attend": _SPARSE}
 OPENERS = ("named_scope", "annotate", "region")
 
 

@@ -892,6 +892,154 @@ def test_ling_decode_moves_no_row_of_its_tails(v5e, monkeypatch):
                for l in text.splitlines())
 
 
+def test_glm_kernels(v5e):
+    """The kernels at the ``glm_5_3_flash`` cell's shapes: both KDA kernels at
+    64 heads of 128 (the step over the stacked state of 4 layers x 64 slots,
+    aliased); ``apex_dsa_index_fwd`` at 32 index heads against the ONE sparse
+    layer's cache of 65538 pages of 4 pooled keys, 1024 pages a slot;
+    ``apex_mla_decode_fwd`` at 64 absorbed queries a slot against a 512-wide
+    row with no roped part (key width = value width), over the gathered
+    buffer of 129 pages a slot under its identity table; and the grouped
+    product at the expert's two shapes (hidden 4096, experts of 2048) for a
+    decode tick's rows and a prompt block's."""
+    from apex_tpu.transformer.functional import gated_delta as gd
+    from apex_tpu.transformer.functional import moe, sparse_index
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    text = compile_text(
+        v5e, gd.gated_delta_chunked, ((64, 4096, 128), f32),
+        ((64, 4096, 128), f32), ((64, 4096, 128), f32),
+        ((64, 4096, 128), f32), ((64, 4096), f32))
+    assert text.count(MOSAIC_CALL) == 1 and "apex_kda_chunk_fwd" in text
+    sharding = SingleDeviceSharding(v5e)
+    shapes = [((64, 64, 128), f32), ((64, 64, 128), f32),
+              ((64, 64, 128), f32), ((64, 64, 128), f32), ((64, 64), f32),
+              ((4, 64, 64, 128, 128), f32), ((), jnp.int32),
+              ((64,), jnp.bool_)]
+    compiled = jax.jit(gd.gated_delta_step, donate_argnums=5).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    ).compile()
+    assert compiled.as_text().count(MOSAIC_CALL) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 4 * 64 * 64 * 128 * 128 * 4
+    text = compile_text(
+        v5e, sparse_index.index_scores, ((64, 32, 128), f32), ((64, 32), f32),
+        ((1, 65538, 4, 128), bf16), ((64, 1024), jnp.int32),
+        ((64,), jnp.int32), ((), jnp.int32))
+    assert text.count(MOSAIC_CALL) == 1 and "apex_dsa_index_fwd" in text
+    # the cache stands in HBM as it is kept: no padded copy of it is made
+    assert "bf16[1,65538,4,128]{3,2,1,0:T(4,128)(2,1)}" in text
+    check_mla_decode(
+        v5e, ((64, 64, 512), f32), ((64, 512), f32),
+        ((1, 64 * 129, 16, 512), bf16), ((64, 129), jnp.int32),
+        ((64,), jnp.int32), ((), jnp.int32))
+    for rows in (64 * 8, 1024 * 8):
+        assert compile_on(
+            v5e, moe.grouped_matmul, ((rows, 4096), f32),
+            ((36, 4096, 4096), bf16), ((36,), jnp.int32)) == 1
+        assert compile_on(
+            v5e, moe.grouped_matmul, ((rows, 2048), f32),
+            ((36, 2048, 4096), bf16), ((36,), jnp.int32)) == 1
+
+
+GLM_SLOTS = 64
+
+
+def glm_full_size(v5e):
+    """``(cfg, params, cache, sds)`` of the ``glm_5_3_flash`` cell as shapes on
+    ``v5e``: layer 2 dense and layers 3-6 sparse, 36 of 288 experts, an
+    eighth of the vocabulary, 64 slots, the full pool of 16384 positions a
+    slot."""
+    from apex_tpu.models import glm_next
+    from apex_tpu.serving.cache import init_hybrid_cache
+
+    slots, max_len, page = GLM_SLOTS, 16384, 16
+    cfg = glm_next.GlmNextConfig(
+        vocab_size=19360, layer_types=glm_next.layer_types_of(2, 5),
+        first_k_dense=1, experts_held=36)
+    sharding = SingleDeviceSharding(v5e)
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+    params = on(jax.eval_shape(lambda k: glm_next.init(k, cfg, jnp.bfloat16),
+                               jax.random.PRNGKey(0)))
+    cache = on(jax.eval_shape(functools.partial(
+        init_hybrid_cache, cfg, slots, max_len,
+        slots * (max_len // page) + 2, page, jnp.bfloat16)))
+    return cfg, params, cache, lambda s, d: jax.ShapeDtypeStruct(
+        s, d, sharding=sharding)
+
+
+def test_glm_full_size_programs(v5e):
+    """The programs of ``glm_5_3_flash.long_resident_sparse_decode`` at full
+    size (layer 2 dense and layers 3-6 sparse, ``K | D K K K``; 36 of 288
+    experts, an eighth of the vocabulary, 64 slots, the full pool of 16384
+    positions a slot): decode and the prefill bucket compile for a v5e with
+    no chip; ``memory_analysis`` gives what the configuration file says (9.44
+    GB of weights; 1.07 GB of state, 1.07 GB of latents in ONE pool and 0.07
+    GB of index keys beside it, all of it aliased: 5 leaves, the index's 2
+    and 5 counters) and fits the chip; the kernel names are the engagement
+    counters the trace readers count. The two in-place writes this model
+    adds (the index keys' scatter and the index tail's ring) move no row
+    within their buffers, so that the compiler may run them twice
+    (``test_ling_decode_moves_no_row_of_its_tails``)."""
+    import re
+
+    from apex_tpu.serving.decode import (
+        make_model_decode_fn, make_model_prefill_fn,
+    )
+
+    slots, max_len, page = GLM_SLOTS, 16384, 16
+    cfg, params, cache, sds = glm_full_size(v5e)
+    size = lambda tree: sum(a.size * a.dtype.itemsize
+                            for a in jax.tree.leaves(tree))
+    matrices = sum(a.size for a in jax.tree.leaves(params)
+                   if a.ndim >= 2 and a.shape[-1] > 4) \
+        - 4 * 4 * 24576                     # the convolutions' taps
+    assert matrices == 4_717_674_496
+    assert round(size(params) / 1e9, 2) == 9.44
+    assert cache.v is None and cache.k.shape == (1, 65538, 16, 512)
+    assert cache.state.shape == (4, slots, 64, 128, 128)
+    assert cache.conv.shape == (4, slots, 3, 24576)
+    assert cache.index["rows"].shape == (1, 65538, 4, 128)
+    assert cache.index["tail"].shape == (1, slots, 3, 128)
+    assert round(cache.state.size * 4 / 1e9, 2) == 1.07
+    assert round(cache.k.size * 2 / 1e9, 2) == 1.07
+    assert len(jax.tree.leaves(cache)) == 5 + 2 + 5
+    i32 = jnp.int32
+    programs = {"decode": make_model_decode_fn(cfg).lower(
+        params, cache, sds((slots,), i32), sds((slots,), jnp.bool_))}
+    for bucket in (12288,):     # the cell's one bucket
+        programs[f"prefill_{bucket}"] = make_model_prefill_fn(cfg).lower(
+            params, cache, sds((1, bucket), i32), sds((bucket,), i32),
+            sds((), i32), sds((bucket // page,), i32),
+            sds((max_len // page,), i32))
+    within_mla_budget(programs["decode"].as_text())
+    want = {"decode": {"apex_kda_decode_fwd": 4, "apex_kda_chunk_fwd": 0,
+                       "apex_dsa_index_fwd": 1, "apex_mla_decode_fwd": 1,
+                       "apex_moe_gmm_fwd": 8, "apex_flash_fwd": 0},
+            "prefill": {"apex_kda_decode_fwd": 0, "apex_kda_chunk_fwd": 4,
+                        "apex_dsa_index_fwd": 0, "apex_mla_decode_fwd": 0,
+                        "apex_moe_gmm_fwd": 8, "apex_flash_fwd": 0}}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        kind = name.split("_")[0]
+        got = {k: len(re.findall(rf"%{k}(\.\d+)? = ", text))
+               for k in want[kind]}
+        assert got == want[kind], name
+        mem = compiled.memory_analysis()
+        print(name, "args", mem.argument_size_in_bytes / 1e9, "temp",
+              mem.temp_size_in_bytes / 1e9, "alias",
+              mem.alias_size_in_bytes / 1e9, "cache", size(cache) / 1e9,
+              (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2 ** 30)
+        assert mem.alias_size_in_bytes >= size(cache), name
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 0.96 * 16 * 2 ** 30, name
+        if kind == "decode":
+            for leaf in (cache.conv, cache.index["tail"]):
+                assert moved_rows(text, leaf.shape) == []
+
+
 def test_flat_adam(v5e):
     from apex_tpu.optimizers import FusedAdam
 

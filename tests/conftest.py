@@ -173,6 +173,34 @@ NEW_CASE_OF_A_PINNED_TEST[
     "asserts SERVING == the three serving cells PR 32 knew"
 
 
+# PR 47 (GLM-5.3-Flash) appends a configuration, a cell, six readers, and its
+# cell to the lists of twenty readers that read it unedited, the two KDA
+# readers PR 42 added among them. Two ids of
+# ``tests/L0/run_benchmark/test_ling_cell.py`` pin what that breaks: PR 42's
+# four readers as its cell's alone, and what may stand behind PR 40's cell in
+# a list as nothing or PR 42's cell. STRICT, as above; all they check besides
+# their pins is asserted again, by name, in
+# ``tests/L0/run_benchmark/test_glm_cell.py``
+# (``test_what_the_pinned_tests_of_test_ling_cell_check_besides``,
+# ``test_manifest_holds_the_configuration_the_cell_and_its_readers_by_name``).
+# 32 expected failures in all since PR 47 (29 before).
+_LING = "tests/L0/run_benchmark/test_ling_cell.py::"
+PINNED_BY_PR_42 = {
+    _LING
+    + "test_manifest_holds_the_configuration_the_cell_and_its_readers_by_name":
+        "asserts PR 42's four readers list its cell alone; PR 47's cell reads "
+        "the two KDA readers too (four KDA calls a step)",
+    _LING + "test_what_the_two_pinned_tests_of_test_exaone_cell_check_besides":
+        "asserts nothing but PR 42's cell stands behind PR 40's cell in a "
+        "list; PR 47 appends its cell to fifteen of those lists",
+}
+NEW_CASE_OF_A_PINNED_TEST[
+    "tests/L0/run_benchmark/test_rehearsal.py::"
+    "test_window_line_says_what_is_left_of_the_backlog"
+    "[glm_5_3_flash.long_resident_sparse_decode]"] = \
+    "asserts SERVING == the three serving cells PR 32 knew"
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         p = item.path
@@ -187,6 +215,9 @@ def pytest_collection_modifyitems(config, items):
         elif item.nodeid in PINNED_BY_PR_40:
             item.add_marker(pytest.mark.xfail(
                 reason=PINNED_BY_PR_40[item.nodeid], strict=True))
+        elif item.nodeid in PINNED_BY_PR_42:
+            item.add_marker(pytest.mark.xfail(
+                reason=PINNED_BY_PR_42[item.nodeid], strict=True))
         elif item.nodeid in PINNED_BY_PR_33:
             item.add_marker(pytest.mark.xfail(
                 reason=PINNED_BY_PR_33[item.nodeid], strict=True))
